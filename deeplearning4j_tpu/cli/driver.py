@@ -492,8 +492,8 @@ def _cmd_route(args) -> int:
 def _serve_child_argv(args, port: int, replica_id: str):
     """Child argv for one fleet replica: this same CLI's ``serve``
     subcommand on an ephemeral port with a stable replica id."""
-    argv = [sys.executable, "-m", "deeplearning4j_tpu.cli.driver",
-            "serve", "--model", args.model,
+    argv = [sys.executable, "-m", "deeplearning4j_tpu.cli", "serve",
+            "--model", args.model,
             "--host", "127.0.0.1", "--port", str(port),
             "--replica-id", replica_id,
             "--slots", str(args.slots),
@@ -549,13 +549,54 @@ def fleet_from_args(args):
         ReplicaProcess,
         free_port,
     )
+    from deeplearning4j_tpu.util.chips import chips_env, local_tpu_chips
+
+    # One process for each chip: a TPU chip serves one process at a
+    # time, so every child is started seeing only the chips it owns
+    # (this parent never initialises a jax backend and so holds none).
+    # On a host without a TPU the children inherit the environment.
+    chips = local_tpu_chips()
+    per = getattr(args, "tp", 1)
+    # tp=1: one chip each. A tp>1 replica takes the WHOLE host with the
+    # environment unchanged: two-chip sub-host groups came up in only
+    # one of two tries on the 2x2 host (CHANGES.md PR 21), so they are
+    # not relied on
+    groups = ([[c] for c in chips] if per == 1
+              else [chips] if per <= len(chips) else [])
+    if chips and args.replicas > len(groups):
+        raise ValueError(
+            f"--replicas {args.replicas} at --tp {per} does not fit "
+            f"this host's {len(chips)} TPU chip(s) {chips}: a chip "
+            "serves one process, a tp=1 replica takes one chip and a "
+            f"tp>1 replica the whole host (room for {len(groups)})")
+    max_replicas = args.max_replicas
+    if chips and max_replicas > len(groups):
+        max_replicas = len(groups)
+        print(f"--max-replicas {args.max_replicas} capped at "
+              f"{max_replicas}: {len(chips)} TPU chip(s), {per} per "
+              "replica", flush=True)
+    leased = {}  # index into groups -> the ReplicaProcess holding it
 
     def spawn(replica_id: str):
+        env = slot = None
+        if chips:
+            slot = next((i for i in range(len(groups))
+                         if i not in leased or not leased[i].alive),
+                        None)
+            if slot is None:
+                raise RuntimeError(
+                    f"no free TPU chip for replica {replica_id}: "
+                    f"all of {groups} are held by live replicas")
+            if per == 1:
+                env = dict(os.environ, **chips_env(groups[slot]))
         port = free_port()
-        return ReplicaProcess(
+        proc = ReplicaProcess(
             _serve_child_argv(args, port, replica_id),
             replica_id=replica_id, port=port,
-            ready_pattern="serving on")
+            ready_pattern="serving on", env=env)
+        if slot is not None:
+            leased[slot] = proc
+        return proc
 
     def factory(replica_id: str):
         proc = spawn(replica_id)
@@ -583,7 +624,7 @@ def fleet_from_args(args):
         controller = FleetController(
             router, replica_factory=factory,
             min_replicas=args.min_replicas,
-            max_replicas=args.max_replicas,
+            max_replicas=max_replicas,
             eval_interval_s=args.eval_interval,
             ttft_p99_slo_s=args.ttft_slo,
             pressure_high=args.pressure_high,
@@ -685,24 +726,38 @@ def _cmd_client(args) -> int:
 def _cmd_serve(args) -> int:
     import time as _time
 
+    import jax
+
     gw = gateway_from_args(args).start()
+    tp_ctx = gw.engine.tp_ctx
+    devices = (list(tp_ctx.mesh.devices.flat) if tp_ctx
+               else jax.devices()[:1])
     # flush: a fleet parent reads this line through a pipe as the
     # boot handshake (ReplicaProcess ready_pattern) — block-buffered
     # stdout would hold it until the buffer filled
+    stats = devices[0].memory_stats() or {}
     print(f"serving on {gw.address} "
-          f"(POST /v1/generate, GET /v1/healthz, GET /v1/metrics)",
-          flush=True)
+          f"(POST /v1/generate, GET /v1/healthz, GET /v1/metrics) "
+          f"device {','.join(str(d) for d in devices)} "
+          f"[{devices[0].device_kind}] bytes_in_use="
+          f"{stats.get('bytes_in_use')}", flush=True)
     try:
-        while True:
+        while gw.failure is None:
             _time.sleep(0.5)
     except KeyboardInterrupt:
         print("draining...")
-    finally:
-        summary = gw.drain(timeout_s=args.drain_timeout)
+    if gw.failure is not None:
+        # the stepping thread died (gateway._fail printed the
+        # traceback): nothing left to drain, and exit 0 would tell a
+        # supervisor the replica shut down cleanly
         gw.close()
-        if summary["snapshot"]:
-            print(f"snapshot ({summary['carried']} in-flight "
-                  f"requests) -> {summary['snapshot']}")
+        print(f"serve failed: {gw.failure}", file=sys.stderr)
+        return 1
+    summary = gw.drain(timeout_s=args.drain_timeout)
+    gw.close()
+    if summary["snapshot"]:
+        print(f"snapshot ({summary['carried']} in-flight "
+              f"requests) -> {summary['snapshot']}")
     return 0
 
 
@@ -1010,7 +1065,14 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    from deeplearning4j_tpu.util.compile_cache import (
+        enable_compile_cache,
+    )
+
     args = build_parser().parse_args(argv)
+    # sets a config value, initialises no backend: the fleet parent
+    # stays off the chip, and its serve children share the directory
+    enable_compile_cache()
     return args.fn(args)
 
 

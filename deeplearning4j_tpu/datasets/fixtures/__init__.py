@@ -1,6 +1,6 @@
 """Loaders for the bundled real-data fixtures (see README.md here).
 
-Round-4 VERDICT item 8: accuracy gates should run on REAL data when
+Round-4 review item 8: accuracy gates should run on REAL data when
 possible, synthetic fallback otherwise. These loaders provide three
 real datasets on a zero-egress machine:
 

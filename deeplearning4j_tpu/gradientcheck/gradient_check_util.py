@@ -19,8 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from deeplearning4j_tpu.util.jax_compat import enable_x64
+from jax import enable_x64
 
 
 def check_gradients(

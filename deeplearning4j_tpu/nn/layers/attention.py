@@ -381,9 +381,9 @@ class AttentionImpl(LayerImplBase):
         bb = jnp.take_along_axis(base, g % s_ring, axis=1)
         bval = (tb >= 0) & (bb == g * bt)          # ring slot holds g
         toggle = getattr(lc, "use_flash_paged", None)
-        if _should_use_flash_paged(toggle, bt, dh):
-            # fused pallas kernel (ISSUE 12): each (row, head) walks
-            # its block list INSIDE the kernel — no [B, ntab*bt, ...]
+        if _should_use_flash_paged(toggle, bt, dh, t):
+            # fused pallas kernel (ISSUE 12): each row walks its
+            # block list INSIDE the kernel — no [B, ntab*bt, ...]
             # gather ever materializes in HBM. Same validity rule,
             # same value-level NaN masking, online softmax; parity vs
             # the gather program is argmax-level (different float
@@ -760,19 +760,27 @@ def _flash_attention(q, k, v, causal):
         block_sizes=bs)
 
 
+#: query-tile rows of the paged kernel: a chunk that is a multiple of
+#: this walks the block list once per tile, so VMEM holds one tile's
+#: accumulators whatever the chunk length
+_PAGED_Q_TILE = 128
+
+
 def _should_use_flash_paged(toggle, block_tokens: int,
-                            head_dim: int) -> bool:
+                            head_dim: int, t: int = 1) -> bool:
     """Dispatch rule for the pallas paged-attention decode kernel
     (:func:`_paged_flash_attention`) vs the XLA gather-by-block-table
     program in :meth:`AttentionImpl._paged_attend`:
 
     - ``None`` (auto): the kernel on the TPU backend when the block
       shape tiles healthily — ``block_tokens`` a multiple of 8
-      (sublane) and ``head_dim`` a multiple of 128 (lane); toy/test
-      geometries below the native tile stay on the XLA gather, which
-      fuses fine at those sizes. Off-TPU always falls back to the
-      gather program (the kernel's DMA scheduling is TPU-specific;
-      interpret mode exists for parity testing, not serving).
+      (sublane), ``head_dim`` a multiple of 128 (lane), and a query
+      chunk ``t`` that is one tile (``t <= _PAGED_Q_TILE``: decode,
+      verify) or whole tiles (a pow2 prefill chunk); toy/test
+      geometries below the native tile and odd long chunks stay on
+      the XLA gather. Off-TPU always falls back to the gather program
+      (the kernel's DMA scheduling is TPU-specific; interpret mode
+      exists for parity testing, not serving).
     - ``True``: force the kernel — raises off-TPU or on unhealthy
       tiles instead of silently degrading.
     - ``False``: the XLA gather program always.
@@ -791,16 +799,19 @@ def _should_use_flash_paged(toggle, block_tokens: int,
         return False
     if toggle == "interpret":
         return True
-    tiles_ok = (block_tokens % 8 == 0 and head_dim % 128 == 0)
+    tiles_ok = (block_tokens % 8 == 0 and head_dim % 128 == 0
+                and (t <= _PAGED_Q_TILE or t % _PAGED_Q_TILE == 0))
     if toggle is None:
         return tiles_ok
     if jax.default_backend() != "tpu" or not tiles_ok:
         raise ValueError(
             "use_flash_paged=True requires the TPU backend, "
-            "block_tokens % 8 == 0 and head dim % 128 == 0 "
-            f"(got block_tokens={block_tokens}, head_dim={head_dim} "
-            f"on {jax.default_backend()!r}); use 'interpret' for "
-            "off-TPU parity testing or None for auto fallback")
+            "block_tokens % 8 == 0, head dim % 128 == 0 and a query "
+            f"chunk of at most {_PAGED_Q_TILE} or a multiple of it "
+            f"(got block_tokens={block_tokens}, head_dim={head_dim}, "
+            f"chunk={t} on {jax.default_backend()!r}); use "
+            "'interpret' for off-TPU parity testing or None for auto "
+            "fallback")
     return True
 
 
@@ -809,18 +820,26 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
                            interpret: bool = False):
     """Fused pallas paged-attention kernel (ISSUE 12; pallas_guide.md,
     boom_attention_tricks.md §8-12 — the in-repo flash kernel's decode
-    successor). One grid step = one (row, head, logical-block) visit:
+    successor). One grid step = one (row, query tile, logical-block)
+    visit, all heads of the block at once:
 
     - the BLOCK TABLE rides as scalar-prefetch operands, and the K/V
-      BlockSpec ``index_map`` reads it to map grid step ``(b, h, j)``
+      BlockSpec ``index_map`` reads it to map grid step ``(b, i, j)``
       to pool block ``bid[b, j]`` — pallas's pipeline then DMAs each
       (non-contiguous) block HBM→VMEM ahead of compute, exactly the
       double-buffered page walk of the reference paged kernel, with
-      NO ``[B, ntab*bt, H, dh]`` gather ever materialized.
+      NO ``[B, ntab*bt, H, dh]`` gather ever materialized. The K/V
+      block carries the WHOLE head axis, ``(1, bt, H, dh)``: the TPU
+      lowering wants a block's last two dimensions to be multiples of
+      (8, 128) or the array's own, and a one-head block in the
+      second-minor position is neither. Heads are walked inside the
+      body with a strided read per head.
     - online softmax over the block walk (running max / sum / output
       accumulator in VMEM scratch, rescaled per block) under the SAME
       validity rule as the XLA gather program: block mapped, causal,
-      last-``tm`` window, per-row floor.
+      last-``tm`` window, per-row floor. A block no query of the tile
+      can reach (unmapped, wholly in the future, wholly slid out)
+      skips its compute.
     - value-level masking: V lanes outside ``[floor, filled + len)``
       are zeroed BEFORE the weighted sum — a zero softmax weight does
       not kill a NaN (0 x NaN = NaN), so a recycled dirty block would
@@ -834,6 +853,8 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
     Shapes: q [B, H, t, dh]; pk/pv [nb, bt, H, dh] (post-scatter);
     bid/bval [B, ntab] int32 (pool block per logical block, validity);
     lo_blk/floor/filled/lengths [B] int32. Returns o [B, H, t, dh].
+    Queries tile by ``_PAGED_Q_TILE`` when ``t`` is a multiple of it
+    (a prefill chunk); shorter chunks (decode, verify) are one tile.
     Parity vs the gather program is argmax-level (one float reduction
     runs blockwise, the other over the flat gather — the PR 6
     paged-parity convention), gated per tier-1 workload in
@@ -842,16 +863,17 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
     from jax.experimental.pallas import tpu as pltpu
 
     b_sz, h_sz, t, dh = q.shape
-    nb, bt = pk.shape[0], pk.shape[1]
+    bt = pk.shape[1]
     ntab = bid.shape[1]
+    tq = _PAGED_Q_TILE if t % _PAGED_Q_TILE == 0 else t
     scale = dh ** -0.5
 
     def kernel(bid_ref, bval_ref, lo_ref, floor_ref, filled_ref,
                len_ref, q_ref, pk_ref, pv_ref, o_ref, m_ref, l_ref,
                acc_ref):
         b = pl.program_id(0)
+        i = pl.program_id(1)
         j = pl.program_id(2)
-        nj = pl.num_programs(2)
 
         @pl.when(j == 0)
         def _init():
@@ -859,66 +881,72 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        qb = q_ref[0, 0].astype(jnp.float32)          # [t, dh]
-        kb = pk_ref[0, :, 0, :].astype(jnp.float32)   # [bt, dh]
-        vb = pv_ref[0, :, 0, :].astype(jnp.float32)
-        kpos = ((lo_ref[b] + j) * bt
-                + jax.lax.broadcasted_iota(jnp.int32, (t, bt), 1))
-        qpos = (filled_ref[b]
-                + jax.lax.broadcasted_iota(jnp.int32, (t, bt), 0))
-        live = bval_ref[b, j] > 0
-        ok = (live & (kpos <= qpos) & (kpos > qpos - tm)
-              & (kpos >= floor_ref[b]))
-        # value-level masking (see docstring): one [1, bt] row — the
-        # written-span rule is q-position-independent
-        vlive = (live
-                 & (kpos[:1] < filled_ref[b] + len_ref[b])
-                 & (kpos[:1] >= floor_ref[b]))
-        vb = jnp.where(vlive.reshape(bt, 1), vb, 0.0)
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = jnp.where(ok, s, -1e30)
-        m_prev = jnp.max(m_ref[...], axis=1)          # [t]
-        l_prev = jnp.max(l_ref[...], axis=1)
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.where(ok, jnp.exp(s - m_next[:, None]), 0.0)
-        l_next = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_ref[...] = (alpha[:, None] * acc_ref[...]
-                        + jax.lax.dot_general(
-                            p, vb, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-        m_ref[...] = jnp.broadcast_to(m_next[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_next[:, None], l_ref.shape)
+        k0 = (lo_ref[b] + j) * bt           # block's first position
+        q0 = filled_ref[b] + i * tq         # tile's first position
+        reachable = ((bval_ref[b, j] > 0) & (k0 <= q0 + tq - 1)
+                     & (k0 + bt - 1 > q0 - tm))
 
-        @pl.when(j == nj - 1)
+        @pl.when(reachable)
+        def _block():
+            kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (tq, bt), 1)
+            qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, bt), 0)
+            ok = ((kpos <= qpos) & (kpos > qpos - tm)
+                  & (kpos >= floor_ref[b]))
+            # value-level masking (see docstring): one [bt, 1] column
+            # — the written-span rule is q-position-independent
+            vpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+            vlive = ((vpos < filled_ref[b] + len_ref[b])
+                     & (vpos >= floor_ref[b]))
+            for h in range(h_sz):
+                kb = pk_ref[0, :, h, :]                   # [bt, dh]
+                vb = pv_ref[0, :, h, :]
+                vb = jnp.where(vlive, vb, jnp.zeros_like(vb))
+                s = jax.lax.dot_general(
+                    q_ref[0, h], kb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(ok, s, -1e30)
+                m_prev = m_ref[h]                         # [tq, 128]
+                m_next = jnp.maximum(
+                    m_prev, jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_next)
+                p = jnp.where(ok, jnp.exp(s - m_next[:, :1]), 0.0)
+                l_ref[h] = (alpha * l_ref[h]
+                            + jnp.sum(p, axis=1, keepdims=True))
+                acc_ref[h] = (alpha[:, :1] * acc_ref[h]
+                              + jax.lax.dot_general(
+                                  p.astype(vb.dtype), vb,
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32))
+                m_ref[h] = m_next
+
+        @pl.when(j == pl.num_programs(2) - 1)
         def _finalize():
-            l = jnp.max(l_ref[...], axis=1)
-            o_ref[0, 0] = (
-                acc_ref[...] / jnp.where(l == 0, 1.0, l)[:, None]
-            ).astype(o_ref.dtype)
+            for h in range(h_sz):
+                l = l_ref[h][:, :1]
+                o_ref[0, h] = (
+                    acc_ref[h] / jnp.where(l == 0, 1.0, l)
+                ).astype(o_ref.dtype)
+
+    def q_map(b, i, j, *refs):
+        return (b, 0, i, 0)
+
+    def kv_map(b, i, j, bid, *refs):
+        # the page walk: scalar-prefetched table drives the DMA
+        return (bid[b, j], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(b_sz, h_sz, ntab),
+        grid=(b_sz, t // tq, ntab),
         in_specs=[
-            pl.BlockSpec((1, 1, t, dh),
-                         lambda b, h, j, *refs: (b, h, 0, 0)),
-            # the page walk: scalar-prefetched table drives the DMA
-            pl.BlockSpec((1, bt, 1, dh),
-                         lambda b, h, j, bid, *refs:
-                         (bid[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bt, 1, dh),
-                         lambda b, h, j, bid, *refs:
-                         (bid[b, j], 0, h, 0)),
+            pl.BlockSpec((1, h_sz, tq, dh), q_map),
+            pl.BlockSpec((1, bt, h_sz, dh), kv_map),
+            pl.BlockSpec((1, bt, h_sz, dh), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, t, dh),
-                               lambda b, h, j, *refs: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, h_sz, tq, dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((t, 128), jnp.float32),   # running max
-            pltpu.VMEM((t, 128), jnp.float32),   # running sum
-            pltpu.VMEM((t, dh), jnp.float32),    # output accumulator
+            pltpu.VMEM((h_sz, tq, 128), jnp.float32),   # running max
+            pltpu.VMEM((h_sz, tq, 128), jnp.float32),   # running sum
+            pltpu.VMEM((h_sz, tq, dh), jnp.float32),    # accumulator
         ],
     )
     return pl.pallas_call(

@@ -344,8 +344,8 @@ class MultiLayerNetwork:
     def _train_steps_scan(self):
         """K train steps as ONE XLA computation via ``lax.scan`` — one
         host dispatch per K batches instead of per batch. This is the
-        dispatch-latency killer for small models: per-step launches over
-        PCIe/tunnel otherwise dominate sub-millisecond step times."""
+        dispatch-latency killer for small models: per-step launches
+        otherwise dominate sub-millisecond step times."""
 
         def steps(params, state, upd_state, iteration, rng, feats, labels,
                   grad_scale=1.0):

@@ -39,9 +39,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-
-from deeplearning4j_tpu.util.jax_compat import axis_size, shard_map
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.nn.conf import layers as L

@@ -3898,8 +3898,5 @@ class DecodeEngine:
         if max_id >= 0:
             eng.scheduler.reserve_ids_through(max_id)
         key_data = np.asarray(snapshot["rng_key"], np.uint32)
-        try:
-            eng._key = jax.random.wrap_key_data(jnp.asarray(key_data))
-        except AttributeError:  # ancient jax: fresh key (greedy
-            pass                # requests are unaffected by the key)
+        eng._key = jax.random.wrap_key_data(jnp.asarray(key_data))
         return eng

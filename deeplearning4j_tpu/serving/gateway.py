@@ -82,8 +82,10 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
+import traceback
 from queue import Empty, Queue
 from typing import Any, Dict, List, Optional
 
@@ -306,6 +308,14 @@ class ServingGateway:
         self._draining = False
         self._paused = False
         self._stopped = False
+        #: set once, by the stepping thread, when ``engine.step()``
+        #: raised: ``"<ExceptionType>: <message>"``. The stepper is the
+        #: only source of progress, so after this nothing can finish —
+        #: every waiting and new request answers 500 with this text,
+        #: ``/v1/healthz`` reports ``ok: false`` / ``state: "failed"``,
+        #: and ``dl4j-tpu serve`` exits non-zero (a dead daemon thread
+        #: behind a healthy-looking socket reads as a hang)
+        self.failure: Optional[str] = None
         # idempotent drain (ISSUE 11 satellite): the first drain owns
         # the work; later/concurrent drains wait and return ITS
         # summary (same carried_ids) instead of double-draining
@@ -510,12 +520,42 @@ class ServingGateway:
                 if self._stopped:
                     return
                 t0 = time.perf_counter()
-                self.engine.step(self._step_sink)
+                try:
+                    self.engine.step(self._step_sink)
+                except Exception as e:  # thread boundary: report, stop
+                    self._fail(e)
+                    return
                 self._round_s = (0.8 * self._round_s
                                  + 0.2 * (time.perf_counter() - t0))
                 for rid, res in self._step_sink.items():
                     self._deliver_terminal(rid, res)
                 self._step_sink.clear()
+
+    def _fail(self, exc: Exception) -> None:
+        """The stepping thread's last act (lock held): record why
+        ``engine.step()`` raised, print the traceback, and release
+        every waiting handler so each answers 500 instead of blocking
+        until its client times out."""
+        self.failure = f"{type(exc).__name__}: {exc}"
+        print(f"gateway {self.replica_id}: engine step failed, "
+              "serving stops", file=sys.stderr)
+        traceback.print_exception(exc, file=sys.stderr)
+        sys.stderr.flush()
+        if self.engine.tracer is not None:
+            self.engine.tracer.incr("serving_gateway_step_failures")
+        self._stopped = True
+        for live in list(self._live.values()):
+            live.events.put(None)
+            live.done.set()
+        self._wake.notify_all()
+
+    def _failure_payload(self, rid: Optional[int] = None
+                         ) -> Dict[str, Any]:
+        out = {"error": f"engine step failed: {self.failure}",
+               "finish_reason": "fault", "status": 500}
+        if rid is not None:
+            out["id"] = rid
+        return out
 
     def _bump(self, key: str) -> None:
         # handler threads increment concurrently; '+=' is not atomic
@@ -590,6 +630,8 @@ class ServingGateway:
                 400, {"error": "tenant 'system' is reserved for "
                                "infrastructure traffic"}, ())
         with self._engine_access():
+            if self.failure is not None:
+                return None, None, (500, self._failure_payload(), ())
             if self._draining or self._stopped:
                 self._bump("rejected_503")
                 return None, None, (503, {"error": "draining"}, ())
@@ -679,16 +721,17 @@ class ServingGateway:
         try:
             while not live.done.is_set():
                 if self._stopped:
-                    handler.send_json(
-                        {"error": "gateway closed", "id": rid}, 503,
-                        close=True)
-                    return
+                    break
                 if deadline is not None and time.monotonic() > deadline:
                     self.cancel(rid)
                     live.done.wait(timeout=5.0)
                     break
                 live.done.wait(timeout=0.05)
             res = live.result
+            if res is None and self.failure is not None:
+                handler.send_json(self._failure_payload(rid), 500,
+                                  close=True)
+                return
             if res is None:  # gateway closed or drained mid-request
                 handler.send_json(
                     {"error": "gateway closed or drained; poll "
@@ -734,6 +777,13 @@ class ServingGateway:
                         break
                     handler.send_ping()
                     continue
+                if item is None and self.failure is not None:
+                    # the stepper died: nothing will ever finish this
+                    # request, so the stream gets a 500 terminal
+                    out = self._failure_payload(rid)
+                    out.update(done=True, tokens=list(live.tokens))
+                    handler.send_event(out, event_id=sent)
+                    break
                 if item is None:
                     # drained mid-request: the stream ends without a
                     # terminal event (the request finishes after the
@@ -936,12 +986,15 @@ class ServingGateway:
         # stayed true) until a request bounced with 503 — a router
         # must see the transition in the payload itself, together
         # with the live load figures its least-loaded fallback weighs
-        state = ("stopped" if self._stopped
+        state = ("failed" if self.failure is not None
+                 else "stopped" if self._stopped
                  else "draining" if self._draining else "live")
         tracer = self.engine.tracer
         return {
             "ok": not self._stopped,
             "state": state,
+            # why the stepping thread died (None while it lives)
+            "error": self.failure,
             "replica_id": self.replica_id,
             # this replica's tracer clock, in trace-event µs: a
             # router samples it inside a timed scrape to estimate

@@ -66,9 +66,14 @@ class ReplicaProcess:
         self.host = host
         self.address = f"{host}:{port}"
         self.ready_pattern = ready_pattern
+        #: the child's boot handshake line, once :meth:`wait_ready`
+        #: has seen it (``dl4j-tpu serve`` names its device there)
+        self.ready_line: Optional[str] = None
+        # stderr is INHERITED: a child that dies at boot (no chip, a
+        # kernel the compiler refuses, a bad model path) writes its
+        # traceback where the operator of the parent sees it
         self.proc = subprocess.Popen(
-            list(argv), stdout=subprocess.PIPE,
-            stderr=subprocess.DEVNULL, env=env, cwd=cwd)
+            list(argv), stdout=subprocess.PIPE, env=env, cwd=cwd)
 
     @property
     def alive(self) -> bool:
@@ -95,10 +100,20 @@ class ReplicaProcess:
         t.start()
         t.join(timeout=timeout_s)
         if result.get("line", "").lstrip().startswith(pattern):
+            self.ready_line = result["line"].strip()
             return
+        code = None
+        if result.get("line") == "":
+            # EOF on stdout: the child is exiting; let it be reaped so
+            # the error can name its exit code
+            with contextlib.suppress(subprocess.TimeoutExpired):
+                code = self.proc.wait(timeout=5.0)
         raise RuntimeError(
             f"replica {self.replica_id} never became ready within "
-            f"{timeout_s}s (last output {result.get('line')!r})")
+            f"{timeout_s}s (last output {result.get('line')!r}; "
+            + (f"the child exited with code {code}" if code is not None
+               else "the child is still running")
+            + "; its stderr is on this process's stderr)")
 
     def sigkill(self) -> None:
         """Chaos path: SIGKILL — no drain, no cleanup, no goodbye."""

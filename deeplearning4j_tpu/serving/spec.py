@@ -2,8 +2,8 @@
 
 Decode throughput is memory-bandwidth-bound: every autoregressive step
 re-reads the full model weights from HBM to emit ONE token — the
-canonical wall of serving (551 tok/s at B=1 on the flagship, BENCH_r05,
-is a weight-streaming rate, not a FLOP rate). Speculative decoding
+canonical wall of serving (B=1 decode on the flagship runs at a
+weight-streaming rate, not a FLOP rate). Speculative decoding
 (Leviathan et al. 2023, "Fast Inference from Transformers via
 Speculative Decoding") amortizes that wall: draft K candidate tokens
 cheaply, then VERIFY all K in ONE forward pass — the masked chunk

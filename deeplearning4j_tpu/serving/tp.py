@@ -6,7 +6,7 @@ this module the decode engine ran every executable on one chip. Here
 the engine's jitted computations — prefill, chunked continuation,
 decode, speculative verify, paged scatter, health, block movers —
 become **fully-manual ``shard_map`` programs** over a ``tp`` mesh axis
-(``parallel/mesh.py:make_mesh`` + ``util/jax_compat.py:shard_map``,
+(``parallel/mesh.py:make_mesh`` + ``jax.shard_map``,
 the same machinery the trainers ride), sharded Megatron-style over
 attention heads:
 
@@ -49,11 +49,11 @@ from typing import Any, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.nn.layers.attention import tp_head_shards
 from deeplearning4j_tpu.parallel.mesh import make_mesh
-from deeplearning4j_tpu.util.jax_compat import shard_map
 
 #: attention param leaf -> (sharded axis index, spec) under head
 #: sharding; params not listed (biases, LN, FFN, Wi) replicate

@@ -10,10 +10,10 @@ reduce, each as its own dispatch, analogous to the reference's phase
 structure) and the fused ParallelTrainer step that replaces them,
 emitting one JSON line bench.py re-emits as a bench row.
 
-Runs on the 8-virtual-device CPU mesh (multi-chip hardware is not
-available here; the mesh/collective code is identical on real ICI).
-Invoked by bench.py as a subprocess so the TPU process never has to
-re-init its jax backend.
+Runs on the 8-virtual-device CPU mesh — a CPU proxy for the call
+pattern, not a device number (the mesh/collective code is the same on
+real chips). Invoked by bench.py as a subprocess; it forces the CPU
+platform and so never asks for the chip its parent holds.
 """
 
 from __future__ import annotations
@@ -71,7 +71,7 @@ def main():
     # axis — the executor-local fit of the reference's phase structure.
     # A plain jitted grad would let GSPMD fuse the all-reduce INTO the
     # compute phase and the decomposition would time a no-op reduce.
-    from deeplearning4j_tpu.util.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec
 
     def _local_grads(p, f, y):
@@ -97,7 +97,7 @@ def main():
 
     def timed(fn, n=9):
         # 9 trials, inner-quartile trimmed median: CPU-host scheduling
-        # jitter put r4's min-max spread at 1.7x (VERDICT weak #2)
+        # jitter put r4's min-max spread at 1.7x (review weak #2)
         fn()  # warm/compile
         ts = []
         for _ in range(n):

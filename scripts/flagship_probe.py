@@ -1,4 +1,4 @@
-"""Flagship transformer tuning probe (round-4 VERDICT item 1).
+"""Flagship transformer tuning probe (round-4 review item 1).
 
 Trains transformer_lm_flagship on the Markov-chain task on the real
 chip, reporting per-epoch wall clock, tokens/sec, MFU, and held-out

@@ -1,4 +1,4 @@
-"""16k-context per-component breakdown (round-4 VERDICT item 2).
+"""16k-context per-component breakdown (round-4 review item 2).
 
 The round-3 16k row ran at 2.9% MFU. This script decomposes the step
 the way scripts/lenet_breakdown.py did for LeNet: flash kernel fwd and

@@ -2,7 +2,7 @@
 
 Protocol identical to the round-2 BENCHMARKS.md measurement (zipf 1M
 words, vocab 10k, d=128, window 5, single chip, warm) so rounds stay
-comparable; adds the negative-sampling row the VERDICT flagged as
+comparable; adds the negative-sampling row the review flagged as
 unmeasured, and a host-tokenization timing isolating the native
 dl4j_tokenize gain. Run: python scripts/w2v_bench.py [--words 1000000]
 """
@@ -89,6 +89,11 @@ def main():
     ap.add_argument("--subsampling", type=float, default=0.0)
     ap.add_argument("--trials", type=int, default=3)
     args = ap.parse_args()
+    from deeplearning4j_tpu.util.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     t0 = time.perf_counter()
     corpus = make_corpus(args.words, vocab=args.vocab)
     print(f"corpus: {args.words:,} words, vocab {args.vocab:,} "

@@ -1,0 +1,77 @@
+"""The pallas kernels the auto rules can select must LOWER for the TPU.
+
+Every other kernel gate in tier-1 runs ``interpret=True`` on the CPU,
+which never meets the TPU lowering's block-shape rules — that is how a
+K/V BlockSpec with a one-head block in the second-minor position passed
+every test and was refused at its first trace on a chip. Cross-lowering
+from the CPU (``jax.export`` with ``platforms=["tpu"]``) runs those rules
+without a chip; whether Mosaic then compiles the result is what
+``chip_smoke.py`` proves on the device.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.nn.layers.attention import (
+    _flash_attention,
+    _paged_flash_attention,
+    _should_use_flash,
+    _should_use_flash_paged,
+)
+
+
+def _tpu_module(fn, *avals) -> str:
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*avals)
+    return exported.mlir_module()
+
+
+def _paged_avals(b, h, t, dh, nb, bt, ntab, dtype, pool_dtype):
+    q = jax.ShapeDtypeStruct((b, h, t, dh), dtype)
+    pool = jax.ShapeDtypeStruct((nb, bt, h, dh), pool_dtype)
+    tab = jax.ShapeDtypeStruct((b, ntab), jnp.int32)
+    row = jax.ShapeDtypeStruct((b,), jnp.int32)
+    return (q, pool, pool, tab, tab, row, row, row, row)
+
+
+# the flagship serving geometry (width 1024, 8 heads, block_tokens 16,
+# 2048-token window): decode, a speculative verify chunk, one and two
+# query tiles of prefill; H=4 is the local head count under tp=2. The
+# engine keeps its pool at the master dtype, so the bf16 chunk meets a
+# float32 pool there; an all-bf16 pool must lower too.
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("h", [8, 4])
+@pytest.mark.parametrize("t", [1, 5, 128, 256])
+def test_paged_kernel_lowers_for_tpu(h, t, pool_dtype):
+    avals = _paged_avals(8, h, t, 128, 1200, 16, 130, jnp.bfloat16,
+                         pool_dtype)
+    text = _tpu_module(
+        lambda *a: _paged_flash_attention(*a, tm=2048), *avals)
+    assert "tpu_custom_call" in text
+
+
+def test_paged_auto_rule_only_selects_shapes_that_lower(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for t in (1, 5, 8, 64, 128, 256, 2048):
+        assert _should_use_flash_paged(None, 16, 128, t), t
+    # a long chunk that is not whole query tiles stays on the gather
+    # program, as do geometries below the native tile
+    assert not _should_use_flash_paged(None, 16, 128, 200)
+    assert not _should_use_flash_paged(None, 4, 128, 1)
+    assert not _should_use_flash_paged(None, 16, 32, 1)
+    with pytest.raises(ValueError, match="query"):
+        _should_use_flash_paged(True, 16, 128, 200)
+
+
+def test_flash_kernel_lowers_for_tpu_forward_and_backward(monkeypatch):
+    q = jax.ShapeDtypeStruct((2, 8, 2048, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(_flash_attention(q, k, v, True)
+                       .astype(jnp.float32))
+
+    text = _tpu_module(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    # forward, and the backward pass's dq and dkv kernels
+    assert text.count("tpu_custom_call") >= 3
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _should_use_flash(None, q, None)

@@ -7,6 +7,7 @@ TestCompGraphMulti}.java and ComputationGraphConfigurationTest
 
 import numpy as np
 import pytest
+from jax import enable_x64
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.datasets.iris import iris_dataset
@@ -21,7 +22,6 @@ from deeplearning4j_tpu.nn.conf.graph_conf import (
 )
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 from deeplearning4j_tpu.ops.losses import LossFunction
-from deeplearning4j_tpu.util.jax_compat import enable_x64
 
 
 def _simple_graph_conf():

@@ -1,4 +1,4 @@
-"""Fused on-device generation (round-5 VERDICT next #5 support):
+"""Fused on-device generation (round-5 review next #5 support):
 ``generate`` must reproduce the per-token ``rnn_time_step`` loop
 exactly — same ids, same final cache position."""
 

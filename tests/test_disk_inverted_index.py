@@ -1,4 +1,4 @@
-"""Disk-backed inverted index (round-5 VERDICT next #9): the Lucene
+"""Disk-backed inverted index (round-5 review next #9): the Lucene
 role — persists across process restarts, scales past RAM, same surface
 and numerics as the in-memory store."""
 

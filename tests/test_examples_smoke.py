@@ -14,8 +14,6 @@ import sys
 
 import pytest
 
-from deeplearning4j_tpu.util.jax_compat import NATIVE_SHARD_MAP
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 EXAMPLES = [
@@ -64,8 +62,8 @@ def test_all_examples_listed():
 #: router-restart soak (~+45 s of tier-1): the next-heaviest smokes
 #: (mnist_mlp ~5 s, fsdp_zero3_training ~4 s) join the slow tier —
 #: tier-1 covers the same paths through tests/test_mnist_e2e.py and
-#: tests/test_scaleout.py (FSDP composes validated in
-#: MULTICHIP_r05.json)
+#: tests/test_scaleout.py (FSDP composes are in
+#: __graft_entry__.dryrun_multichip)
 #: ISSUE 17 added tests/test_kv_tier.py + the tier paged-soak
 #: variant (~+45 s of tier-1): the next-heaviest smokes
 #: (long_context_transformer ~6 s, pipeline_4d_training ~7 s) join
@@ -91,12 +89,6 @@ SLOW_EXAMPLES = {"flagship_transformer.py", "streaming_decode.py",
      for n, a in EXAMPLES],
     ids=[n for n, _ in EXAMPLES])
 def test_example_runs(name, args):
-    if name == "pipeline_4d_training.py" and not NATIVE_SHARD_MAP:
-        # dp x pp x sp x tp lowers through partial-manual shard_map,
-        # which the jax<0.6 experimental fallback cannot SPMD-partition
-        # (util/jax_compat.py)
-        pytest.skip("partial-manual shard_map broken on jax<0.6 "
-                    "fallback")
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["DL4J_EXAMPLES_PLATFORM"] = "cpu"
     env["DL4J_EXAMPLES_TINY"] = "1"

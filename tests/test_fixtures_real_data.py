@@ -1,5 +1,5 @@
 """Bundled real-data fixtures + the W2V batched-update stability fix
-(round-4 VERDICT item 8 / missing #1: honest gates need real data).
+(round-4 review item 8 / missing #1: honest gates need real data).
 
 The reference ships 13 MB of real fixtures (dl4j-test-resources);
 datasets/fixtures mirrors the two that matter for gates: 200 real MNIST

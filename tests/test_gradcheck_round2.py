@@ -1,5 +1,5 @@
 """Finite-difference gradient checks for the round-2 layer families
-(VERDICT r2 item 7): ImageLSTM, RecursiveAutoEncoder pretrain,
+(review r2 item 7): ImageLSTM, RecursiveAutoEncoder pretrain,
 MultiHeadSelfAttention, and MoeDense with routing held away from
 decision boundaries.
 
@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import enable_x64
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.gradientcheck import check_gradients
@@ -19,7 +20,6 @@ from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.ops.losses import LossFunction
-from deeplearning4j_tpu.util.jax_compat import enable_x64
 
 
 def _rnn_ds(n=4, c_in=3, c_out=4, t_in=6, t_out=None, seed=0):

@@ -1,5 +1,5 @@
 """HomogeneousPipelineTrainer: dp x pp x tp on stage-stacked blocks
-(round-4 VERDICT item 3 — the packed-row trainer's documented tp wall,
+(round-4 review item 3 — the packed-row trainer's documented tp wall,
 closed for homogeneous-stage models).
 
 Same verification pattern as tests/test_pipeline_expert.py for the
@@ -18,16 +18,6 @@ from deeplearning4j_tpu.parallel.homogeneous_pipeline import (
     find_homogeneous_run,
 )
 from deeplearning4j_tpu.parallel.mesh import MeshSpec, make_mesh
-from deeplearning4j_tpu.util.jax_compat import NATIVE_SHARD_MAP
-
-# Multi-axis compositions lower through partial-manual shard_map
-# (axis_names= / auto=), which the jax<0.6 experimental fallback turns
-# into PartitionId ops 0.4.x XLA cannot SPMD-partition — UNIMPLEMENTED
-# at best, a process abort at worst (util/jax_compat.py). These tests
-# did not even collect before the compat shim existed.
-needs_partial_auto = pytest.mark.skipif(
-    not NATIVE_SHARD_MAP,
-    reason="partial-manual shard_map broken on jax<0.6 fallback")
 
 V, W, T = 8, 12, 12  # V != W so block 0 carries Wi (the pre group)
 
@@ -89,15 +79,12 @@ class TestTrajectoryParity:
     def test_pp_matches_single_device(self):
         self._parity({"pp": 2})
 
-    @needs_partial_auto
     def test_pp_tp_matches_single_device(self):
         self._parity({"pp": 2, "tp": 2}, tp_axis="tp")
 
-    @needs_partial_auto
     def test_dp_pp_tp_matches_single_device(self):
         self._parity({"dp": 2, "pp": 2, "tp": 2}, tp_axis="tp")
 
-    @needs_partial_auto
     def test_fit_scan_matches_fit(self):
         x, y = _batch(n=8)
         a = _net()
@@ -157,7 +144,6 @@ class TestMemoryAccounting:
 
 
 class TestMixedPrecisionAndRemat:
-    @needs_partial_auto
     def test_bf16_pp_tp_matches_bf16_single_device(self):
         """The homogeneous trainer's compute-dtype path (bf16 blocks,
         f32 master params + output head) must track single-device
@@ -300,7 +286,6 @@ class TestInterleavedSchedule:
         # run of 8 blocks over pp=2 x V=4 (one block per chunk)
         self._parity({"pp": 2}, interleave=4, n_layers=9)
 
-    @needs_partial_auto
     def test_interleave_dp_pp_tp_matches_single_device(self):
         self._parity({"dp": 2, "pp": 2, "tp": 2}, interleave=2,
                      tp_axis="tp")
@@ -435,11 +420,9 @@ class TestSequenceParallelComposition:
     def test_pp_sp_matches_single_device(self):
         self._parity({"pp": 2, "sp": 2})
 
-    @needs_partial_auto
     def test_dp_pp_sp_matches_single_device(self):
         self._parity({"dp": 2, "pp": 2, "sp": 2})
 
-    @needs_partial_auto
     def test_pp_sp_tp_matches_single_device(self):
         self._parity({"pp": 2, "sp": 2, "tp": 2}, tp_axis="tp")
 

@@ -1,4 +1,4 @@
-"""Host-fed training path (round-5 VERDICT next #1): disk-streaming
+"""Host-fed training path (round-5 review next #1): disk-streaming
 iterators -> C++ prefetch ring -> fit_stream window fusion.
 
 Numerics contract: fit_stream over an async disk iterator must produce

@@ -23,16 +23,6 @@ from deeplearning4j_tpu.scaleout.coordinator import (
     CoordinatorClient,
     CoordinatorServer,
 )
-from deeplearning4j_tpu.util.jax_compat import (
-    CPU_MULTIPROCESS_COLLECTIVES,
-)
-
-# every test here gang-schedules 2 OS processes on the CPU backend,
-# which jax<0.5 cannot do ("Multiprocess computations aren't
-# implemented on the CPU backend" — util/jax_compat.py)
-pytestmark = pytest.mark.skipif(
-    not CPU_MULTIPROCESS_COLLECTIVES,
-    reason="jax<0.5 CPU backend has no cross-process collectives")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -314,7 +304,7 @@ print(json.dumps({
 
 
 def test_two_process_tp_and_pp_mesh_spans_hosts(tmp_path):
-    """Round-2 VERDICT item 4: cross-host collective lowering beyond dp
+    """Round-2 review item 4: cross-host collective lowering beyond dp
     — a dp x tp step (Megatron all-reduces across the process boundary),
     a 4-stage pipeline whose ppermute ring and stage-sharded params
     span both processes, and a conf-level sequence-parallel transformer
@@ -448,7 +438,7 @@ print(json.dumps({"resume_scores": scores,
 
 
 def test_elastic_restart_resumes_on_shrunk_mesh(tmp_path):
-    """Round-2 VERDICT item 4 (elastic path): a 2-process gang trains
+    """Round-2 review item 4 (elastic path): a 2-process gang trains
     and checkpoints; one process crashes (no deregistration — the
     control plane must see the stale worker); a fresh single-process
     run restores the checkpoint and keeps training on a dp=1 mesh."""

@@ -389,7 +389,7 @@ class TestGraphParallelTrainer:
         with pytest.raises(ValueError, match="sequential layer chain"):
             ParallelTrainer(g, mesh, tp_axis="tp")
         # K-local-steps-then-average works for graphs now (round-2
-        # VERDICT item 2); trajectory parity is asserted in
+        # review item 2); trajectory parity is asserted in
         # test_pipeline_expert.py::TestGraphLocalSteps.
         g2 = ComputationGraph(self._graph_conf())
         mesh2 = make_mesh(MeshSpec({"dp": 4}))

