@@ -35,6 +35,7 @@ from deeplearning4j_tpu.optimize.telemetry import (
     grad_health,
     window_counts,
 )
+from deeplearning4j_tpu.profiler.tracer import annotate
 from deeplearning4j_tpu.nn.conf.enums import BackpropType, OptimizationAlgorithm
 from deeplearning4j_tpu.nn.conf.multi_layer import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.gradient import Gradient
@@ -426,10 +427,11 @@ class MultiLayerNetwork:
             step_fn = self._train_steps_scan
             extra = ()
         t0 = time.perf_counter()
-        (self.params, self.state, self.updater_state, scores,
-         health) = step_fn(
-            self.params, self.state, self.updater_state,
-            self.iteration, sub, feats, labels, *extra, grad_scale)
+        with annotate("train.dispatch", step=self.iteration):
+            (self.params, self.state, self.updater_state, scores,
+             health) = step_fn(
+                self.params, self.state, self.updater_state,
+                self.iteration, sub, feats, labels, *extra, grad_scale)
         k, examples, tokens = window_counts(feats.shape)
         self.train_telemetry.record_step(
             dispatch_s=time.perf_counter() - t0, steps=k,

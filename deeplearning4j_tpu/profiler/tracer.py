@@ -1,4 +1,5 @@
-"""Host-side span tracer (Chrome trace format) + device trace hook,
+"""Host-side span tracer (Chrome trace format) whose spans also land
+in a ``jax.profiler`` trace on the profiler's clock (:func:`annotate`),
 plus the streaming :class:`Histogram` track type the serving stack's
 latency distributions ride on (ISSUE 7)."""
 
@@ -13,7 +14,24 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-from deeplearning4j_tpu.optimize.listeners import IterationListener
+from jax.profiler import TraceAnnotation
+
+
+def annotate(name: str, **args: Any):
+    """Context manager that puts ``name`` into the ``jax.profiler``
+    trace being taken, on the calling thread's line of the host plane
+    and on the clock the device planes of the same ``.xplane.pb`` use.
+    Always entered: the profiler decides whether it is kept, and with
+    no profile being taken it costs one inactive ``TraceMe`` (under a
+    microsecond). Only scalar ``args`` (bool, int, float, str) travel;
+    lists and dicts (a span's ``rids``, ``traces``) are left out — an
+    annotation's arguments are encoded into its name on entry.
+
+    :meth:`Tracer.span` is built on it; call it directly where no
+    ``Tracer`` is at hand (``MultiLayerNetwork.fit_scan``)."""
+    return TraceAnnotation(name, **{
+        k: v for k, v in args.items()
+        if isinstance(v, (bool, int, float, str))})
 
 
 class Histogram:
@@ -362,7 +380,27 @@ class Tracer:
             ...
         tracer.counter("score", 0.42)
         tracer.save("trace.json")
-    """
+
+    Every span is also an annotation of the ``jax.profiler`` trace
+    being taken (:func:`annotate`): the event log's clock is
+    ``perf_counter`` since the tracer's birth, the profile's is the
+    one its device planes use, so there a device idle gap can be laid
+    against the span that covers it. The serving stack's spans: on
+    the gateway's stepper thread ``gateway.idle_wait``,
+    ``gateway.lock_yield``, ``gateway.deliver`` and one
+    ``serving.round`` per ``engine.step`` with the leaves
+    ``serving.sweeps``, ``serving.admit`` (children
+    ``serving.prompt_encode``, ``serving.prefill`` /
+    ``serving.prefill_chunk``, ``serving.first_token_sync``),
+    ``serving.reserve``, ``serving.tables``, ``serving.decode_chunk``
+    (children ``serving.decode_dispatch``, ``serving.token_sync``),
+    ``serving.commit``, ``serving.round_end``; on a handler's thread
+    ``gateway.submit`` with its child ``gateway.lock_wait`` (the
+    interval a result's ``timing.gateway_wait_s`` carries, as
+    ``timing.first_delta_s`` carries submit to first delta out). A
+    training loop feeds a tracer through
+    ``optimize/listeners.py:TracingIterationListener``; taking the
+    device trace itself is ``benchmark/common.py:SubTrace``."""
 
     #: ``max_events=None`` keeps every event (the Chrome-trace use
     #: case: finite runs you dump with ``save``). A long-lived SERVER
@@ -414,9 +452,15 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **args: Any):
+        """Record the body as one complete (``"X"``) event and, through
+        :func:`annotate`, as one annotation of the ``jax.profiler``
+        trace being taken (scalar ``args`` only). Yields the event's
+        ``args`` dict, so a caller can add what it learns inside the
+        span (``gateway.submit`` adds its ``rid``)."""
         start = self._us()
         try:
-            yield
+            with annotate(name, **args):
+                yield args
         finally:
             end = self._us()
             with self._lock:
@@ -853,45 +897,3 @@ class Tracer:
             self._last.clear()
             self._hists.clear()  # descriptions survive: they are
             #                      registrations, not measurements
-
-
-class ProfilerIterationListener(IterationListener):
-    """Feeds iteration timing + score into a Tracer via the standard
-    listener hook (the reference's only observability channel,
-    BaseOptimizer.java:218)."""
-
-    def __init__(self, tracer: Tracer, frequency: int = 1):
-        self.tracer = tracer
-        self.invoked_every = frequency
-        self._last_ts: Optional[float] = None
-
-    def iteration_done(self, model, iteration: int) -> None:
-        now = self.tracer.now_us()
-        if self._last_ts is not None:
-            self.tracer.complete("iteration", self._last_ts,
-                                 now - self._last_ts, iteration=iteration)
-        self._last_ts = now
-        self.tracer.counter("score", float(model.score_value))
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: str):
-    """XLA/TPU-level profiling via jax.profiler (TensorBoard format).
-    No-ops with a warning attribute when the profiler backend is
-    unavailable (e.g. CPU test environments without profiling support)."""
-    import jax
-
-    started = False
-    try:
-        jax.profiler.start_trace(log_dir)
-        started = True
-    except Exception:
-        pass
-    try:
-        yield
-    finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass

@@ -266,13 +266,18 @@ class _PhaseClock:
     MAX_EVENTS = 512
 
     __slots__ = ("submit_t", "enqueue_t", "attempts", "ttft_s",
-                 "last_commit_t", "rounds")
+                 "first_delta_s", "last_commit_t", "rounds")
 
     def __init__(self, submit_t: float):
         self.submit_t = submit_t
         self.enqueue_t = submit_t
         self.attempts: List[Dict[str, Any]] = [self._attempt()]
         self.ttft_s: Optional[float] = None
+        #: submit -> the request's first delta handed to ``on_delta``
+        #: / the delta buffer: when a streaming consumer can first see
+        #: a token, which is later than ``ttft_s`` (when admission
+        #: fetched it) by however long the engine held it
+        self.first_delta_s: Optional[float] = None
         self.last_commit_t: Optional[float] = None
         self.rounds = 0
 
@@ -333,6 +338,7 @@ class _PhaseClock:
             "verify_s": p.get("verify", 0.0),
             "stall_s": p.get("stall", 0.0),
             "ttft_s": self.ttft_s,
+            "first_delta_s": self.first_delta_s,
             "e2e_s": now - self.submit_t,
             "attempts": len(self.attempts),
             "rounds": self.rounds,
@@ -1481,8 +1487,17 @@ class DecodeEngine:
 
     def _span(self, name, **args):
         if self.tracer is None:
-            return contextlib.nullcontext()
+            return contextlib.nullcontext(args)
         return self.tracer.span(name, **args)
+
+    def _admit_span(self, request: Request, slot: int):
+        """``serving.admit``: one request's admission work of this
+        round. Its children are ``serving.prompt_encode``,
+        ``serving.prefill`` / ``serving.prefill_chunk`` and
+        ``serving.first_token_sync``; what is left is the scheduler's
+        part (prefix lookup, block allocation, slot bookkeeping)."""
+        return self._span("serving.admit", rid=request.id, slot=slot,
+                          **_targs(request))
 
     def _traces_of(self, slots) -> Dict[str, Any]:
         """Span-args fragment mapping request id -> fleet trace
@@ -1600,6 +1615,11 @@ class DecodeEngine:
         if not fresh:
             return
         self._delta_sent[rid] = len(tokens)
+        clock = self._clocks.get(rid) if sent == 0 else None
+        if clock is not None and clock.first_delta_s is None:
+            now = self._clock()
+            clock.first_delta_s = now - clock.submit_t
+            clock.event(now, "first_delta", n=len(fresh))
         if cb is not None:
             cb(rid, [int(t) for t in fresh])
         else:
@@ -2226,9 +2246,11 @@ class DecodeEngine:
         seg = list(pending.seq[lo:lo + max_tokens])
         width = (self.prefill_chunk
                  or self.scheduler.bucket_of(len(seg)))
-        x, mask = self._one_hot_prompt(seg, width)
-        temp = jnp.asarray([req.temperature], jnp.float32)
-        top_k = jnp.asarray([req.top_k or self.vocab], jnp.int32)
+        with self._span("serving.prompt_encode", rid=req.id,
+                        width=width, tokens=len(seg)):
+            x, mask = self._one_hot_prompt(seg, width)
+            temp = jnp.asarray([req.temperature], jnp.float32)
+            top_k = jnp.asarray([req.top_k or self.vocab], jnp.int32)
         clock = self._clock_of(req.id)
         if pending.tab is not None:
             # paged WARM admission: the suffix chunk streams straight
@@ -2327,13 +2349,9 @@ class DecodeEngine:
                     self._defer_admission(pending)
                     return
                 table_row, _ = tab.arrays(self._ring_slots)
-                with self._span("serving.admit", rid=request.id,
-                                slot=slot, paged=True,
-                                **_targs(request)):
-                    self._pool = self._scatter_jit(
-                        self._pool, pending.rnn,
-                        jnp.asarray(table_row),
-                        jnp.asarray(tab.length, jnp.int32))
+                self._pool = self._scatter_jit(
+                    self._pool, pending.rnn, jnp.asarray(table_row),
+                    jnp.asarray(tab.length, jnp.int32))
             else:
                 tab = pending.tab
                 pending.tab = None
@@ -2357,11 +2375,9 @@ class DecodeEngine:
                                         a.dtype), pending.rnn))
                 self._toks = self._place(
                     jnp.zeros((self.n_slots,), jnp.int32))
-            with self._span("serving.admit", rid=request.id,
-                            slot=slot, **_targs(request)):
-                self._pool, self._toks = self._admit_jit(
-                    self._pool, self._toks, pending.rnn, pending.tok,
-                    jnp.asarray(slot, jnp.int32))
+            self._pool, self._toks = self._admit_jit(
+                self._pool, self._toks, pending.rnn, pending.tok,
+                jnp.asarray(slot, jnp.int32))
             hit_row = None
             if self.prefix_cache is not None:
                 # release BEFORE insert: the fetched state is an
@@ -2377,7 +2393,8 @@ class DecodeEngine:
         # is the sync point that forces the in-flight prefill/admit
         # dispatches to completion (async dispatch would otherwise
         # report host-side dispatch time as time-to-first-token)
-        first = int(np.asarray(pending.tok)[0])
+        with self._span("serving.first_token_sync", rid=request.id):
+            first = int(np.asarray(pending.tok)[0])
         submit_t = self._submit_t.get(request.id)
         ttft = (self._clock() - submit_t
                 if submit_t is not None else None)
@@ -2902,6 +2919,45 @@ class DecodeEngine:
             k *= 2
         return k
 
+    def _reserve_round(self, active: List[int], drafts, spec_round,
+                       fuse_k: int):
+        """Paged engines, before a decode dispatch (the
+        ``serving.reserve`` span): the round's view of ``(active,
+        drafts, spec_round, fuse_k)`` after every block its writes
+        will cross into is reserved."""
+        # allocation on demand: reserve every block this round's
+        # writes will cross into (verify width + the decode chunk),
+        # CoW-ing tail blocks still shared with the trie — under pool
+        # pressure the youngest slot is preempted (requeued, ids
+        # regenerate identically)
+        ensured: set = set()
+        for slot in list(active):
+            if self._slots[slot] is None:
+                continue   # preempted by an earlier reserve
+            n_tok = max(fuse_k, 1) * self.decode_chunk
+            if spec_round:
+                n_tok += len(drafts.get(slot, ())) + 1
+            if self._ensure_tab(
+                    self._kv_tabs[slot], n_tok,
+                    protect=ensured | {slot},
+                    rid=self._slots[slot].request.id):
+                ensured.add(slot)
+            else:
+                self._preempt_slot(slot)
+        # preemption (by _ensure_tab or explicit) may have emptied
+        # slots mid-list — rebuild the round's view
+        active = [s for s in active if self._slots[s] is not None]
+        if drafts is not None:
+            drafts = {s: d for s, d in drafts.items() if s in active}
+            spec_round = any(drafts.values())
+        if fuse_k and self._requeue:
+            # a pool-pressure preemption during reservation is a
+            # scheduling decision: fall back to stepped (the extra
+            # reserved blocks stay table-owned for the following
+            # rounds — nothing leaks)
+            fuse_k = 0
+        return active, drafts, spec_round, fuse_k
+
     # -- the serving loop ----------------------------------------------
     def has_work(self) -> bool:
         """True while anything is queued, admitting, decoding,
@@ -2936,9 +2992,20 @@ class DecodeEngine:
         ``filled``) or freed-but-unreallocated (nothing allocates
         between dispatch and landing)."""
         t_sync0 = self._clock() if self.record_timing else 0.0
-        seq = np.asarray(inf.seq)
-        n_valid = (np.asarray(inf.n_valid)
-                   if inf.n_valid is not None else None)
+        # a synchronous round fetched inside ``serving.decode_chunk``;
+        # only a round left in flight still has the device to wait for
+        with (self._span("serving.token_sync") if self.async_rounds
+              else contextlib.nullcontext()):
+            seq = np.asarray(inf.seq)
+            n_valid = (np.asarray(inf.n_valid)
+                       if inf.n_valid is not None else None)
+        with self._span("serving.commit", active=len(inf.active)):
+            self._commit_round(inf, seq, n_valid, t_sync0)
+
+    def _commit_round(self, inf: _InflightRound, seq, n_valid,
+                      t_sync0: float) -> None:
+        """The host's half of landing a round, after the fetch: the
+        ``serving.commit`` span."""
         v_n = None
         v_rows = None
         if inf.verify_out is not None:
@@ -3069,9 +3136,22 @@ class DecodeEngine:
         quarantine, evictions. Public so a caller can interleave
         ``cancel()`` / ``snapshot()`` / fault assertions with progress;
         ``run()`` is exactly a ``step()`` loop. Terminal results
-        accumulate into (and are returned via) ``results``."""
+        accumulate into (and are returned via) ``results``.
+
+        The call is one ``serving.round`` span; inside it every
+        stretch of host work is one leaf span (``serving.sweeps``,
+        ``serving.admit`` and its children, ``serving.reserve``,
+        ``serving.tables``, ``serving.decode_dispatch``,
+        ``serving.token_sync``, ``serving.commit``,
+        ``serving.round_end``), so that a device idle gap in a
+        ``jax.profiler`` trace is named by what the host was doing."""
         if results is None:
             results = {}
+        with self._span("serving.round", round=self._round):
+            self._run_round(results)
+        return results
+
+    def _run_round(self, results: Dict[int, GenerationResult]) -> None:
         if self._inflight is not None:
             # async double-buffered rounds (ISSUE 14): land the round
             # the PREVIOUS step dispatched before any of this round's
@@ -3099,11 +3179,12 @@ class DecodeEngine:
         # this round fails"): one left unconsumed — no admission ran —
         # expires rather than ambushing an unrelated later workload
         self._admit_fail_pending = 0
-        self._drain_requeue()
-        self._inject_faults()
-        self._sweep_deadlines()
-        if self.tenants is not None:
-            self._qos_round()
+        with self._span("serving.sweeps"):
+            self._drain_requeue()
+            self._inject_faults()
+            self._sweep_deadlines()
+            if self.tenants is not None:
+                self._qos_round()
         for slot in range(self.n_slots):
             if (self._slots[slot] is None
                     and slot not in self._reserved
@@ -3127,7 +3208,8 @@ class DecodeEngine:
                 nxt = self.scheduler.pop_admissible()
                 if nxt is None:
                     break
-                self._start_admission(nxt, slot)
+                with self._admit_span(nxt, slot):
+                    self._start_admission(nxt, slot)
         if self._pending:
             if self.adaptive_prefill:
                 budget = self.scheduler.adapt_budget()
@@ -3152,7 +3234,10 @@ class DecodeEngine:
             for p in targets:
                 if id(p) in deferred:
                     continue
-                if not self._advance_prefill(p, self.prefill_chunk):
+                with self._admit_span(p.request, p.slot):
+                    advanced = self._advance_prefill(
+                        p, self.prefill_chunk)
+                if not advanced:
                     # paged pool pressure: back the admission out and
                     # retry next round (decode keeps its cadence)
                     self._defer_admission(p)
@@ -3163,7 +3248,8 @@ class DecodeEngine:
             finished = [p for p in self._pending
                         if p.remaining == 0]
             for p in finished:
-                self._complete_admission(p)
+                with self._admit_span(p.request, p.slot):
+                    self._complete_admission(p)
                 if p in self._pending:
                     self._pending.remove(p)
         active = [i for i, s in enumerate(self._slots)
@@ -3174,39 +3260,10 @@ class DecodeEngine:
             spec_round = drafts is not None and any(drafts.values())
             fuse_k = self._plan_fused(active, spec_round)
             if self.paged_kv:
-                # allocation on demand: reserve every block this
-                # round's writes will cross into (verify width + the
-                # decode chunk), CoW-ing tail blocks still shared with
-                # the trie — under pool pressure the youngest slot is
-                # preempted (requeued, ids regenerate identically)
-                ensured: set = set()
-                for slot in list(active):
-                    if self._slots[slot] is None:
-                        continue   # preempted by an earlier reserve
-                    n_tok = max(fuse_k, 1) * self.decode_chunk
-                    if spec_round:
-                        n_tok += len(drafts.get(slot, ())) + 1
-                    if self._ensure_tab(
-                            self._kv_tabs[slot], n_tok,
-                            protect=ensured | {slot},
-                            rid=self._slots[slot].request.id):
-                        ensured.add(slot)
-                    else:
-                        self._preempt_slot(slot)
-                # preemption (by _ensure_tab or explicit) may have
-                # emptied slots mid-list — rebuild the round's view
-                active = [s for s in active
-                          if self._slots[s] is not None]
-                if drafts is not None:
-                    drafts = {s: d for s, d in drafts.items()
-                              if s in active}
-                    spec_round = any(drafts.values())
-                if fuse_k and self._requeue:
-                    # a pool-pressure preemption during reservation is
-                    # a scheduling decision: fall back to stepped (the
-                    # extra reserved blocks stay table-owned for the
-                    # following rounds — nothing leaks)
-                    fuse_k = 0
+                with self._span("serving.reserve", active=len(active)):
+                    (active, drafts, spec_round,
+                     fuse_k) = self._reserve_round(
+                        active, drafts, spec_round, fuse_k)
                 if not active:
                     # every slot was preempted for blocks: the round
                     # ends with no decode (requeues drain next round)
@@ -3215,7 +3272,7 @@ class DecodeEngine:
                             > self.stall_threshold_s):
                         self._failure_event("slow_steps")
                     self._drain_terminal(results)
-                    return results
+                    return
             t0 = time.perf_counter()
             verify_out = None
             ver_dt = 0.0
@@ -3232,8 +3289,11 @@ class DecodeEngine:
                         clock = self._clocks.get(rid0)
                         if clock is not None:
                             clock.add(t_pre, "stall", t_pre - rt0)
-            pool_op = (self._paged_rnn_rows(self._kv_tabs)
-                       if self.paged_kv else self._pool)
+            with self._span("serving.tables", active=len(active)):
+                pool_op = (self._paged_rnn_rows(self._kv_tabs)
+                           if self.paged_kv else self._pool)
+                temps = jnp.asarray(self._temps)
+                top_ks = jnp.asarray(self._top_ks)
             if spec_round:
                 # verify dispatch chains into the decode dispatch
                 # below (the scan resumes from the verified state), so
@@ -3265,37 +3325,38 @@ class DecodeEngine:
                             rids=[self._slots[s].request.id
                                   for s in active],
                             **self._traces_of(active)):
-                if fuse_k:
-                    # fused K-round scan: draw the SAME K host keys K
-                    # stepped rounds would (RNG-stream parity), hand
-                    # eos ids + max_new headroom to the device for
-                    # on-device stop detection
-                    keys = jnp.stack([self._next_key()
-                                      for _ in range(fuse_k)])
-                    eos_ids = np.full(self.n_slots, -1, np.int32)
-                    remaining = np.zeros(self.n_slots, np.int32)
-                    for s in active:
-                        st = self._slots[s]
-                        if st.request.eos_id is not None:
-                            eos_ids[s] = int(st.request.eos_id)
-                        remaining[s] = (st.request.max_new_tokens
-                                        - len(st.tokens))
-                    (pool_op, self._toks, seq,
-                     n_valid) = self._fused_jit(
-                        self._params, self._state, pool_op,
-                        self._toks, jnp.asarray(self._temps),
-                        jnp.asarray(self._top_ks),
-                        jnp.asarray(eos_ids),
-                        jnp.asarray(remaining), keys)
-                    self._observe("serving_fused_rounds", fuse_k)
-                else:
-                    pool_op, self._toks, seq = self._decode_jit(
-                        self._params, self._state, pool_op,
-                        self._toks, jnp.asarray(self._temps),
-                        jnp.asarray(self._top_ks), self._next_key())
+                with self._span("serving.decode_dispatch"):
+                    if fuse_k:
+                        # fused K-round scan: draw the SAME K host keys K
+                        # stepped rounds would (RNG-stream parity), hand
+                        # eos ids + max_new headroom to the device for
+                        # on-device stop detection
+                        keys = jnp.stack([self._next_key()
+                                          for _ in range(fuse_k)])
+                        eos_ids = np.full(self.n_slots, -1, np.int32)
+                        remaining = np.zeros(self.n_slots, np.int32)
+                        for s in active:
+                            st = self._slots[s]
+                            if st.request.eos_id is not None:
+                                eos_ids[s] = int(st.request.eos_id)
+                            remaining[s] = (st.request.max_new_tokens
+                                            - len(st.tokens))
+                        (pool_op, self._toks, seq,
+                         n_valid) = self._fused_jit(
+                            self._params, self._state, pool_op,
+                            self._toks, temps, top_ks,
+                            jnp.asarray(eos_ids),
+                            jnp.asarray(remaining), keys)
+                        self._observe("serving_fused_rounds", fuse_k)
+                    else:
+                        pool_op, self._toks, seq = self._decode_jit(
+                            self._params, self._state, pool_op,
+                            self._toks, temps, top_ks,
+                            self._next_key())
                 if not self.async_rounds:
-                    seq = np.asarray(seq)  # [B, T]; forces the
-                    #               whole round (verify included) done
+                    with self._span("serving.token_sync"):
+                        seq = np.asarray(seq)  # [B, T]; forces the
+                        #           whole round (verify included) done
             self._pool = self._strip_pool(pool_op)
             inf = _InflightRound(
                 active=list(active),
@@ -3325,20 +3386,20 @@ class DecodeEngine:
                 if self.record_timing:
                     self._observe("serving_round_s",
                                   self._clock() - rt0)
-        if self._pending_spills:
-            # end-of-round spill drain (ISSUE 17): the gathers were
-            # dispatched at eviction time and the next round's device
-            # work is already in flight — the host copy + pack lands
-            # here, off the decode hot path
-            self.drain_spills()
-        if self.paged_kv:
-            self._paged_stats_refresh()
-        self._round += 1
-        if t_start is not None:
-            if self._clock() - t_start > self.stall_threshold_s:
-                self._failure_event("slow_steps")
-        self._drain_terminal(results)
-        return results
+        with self._span("serving.round_end"):
+            if self._pending_spills:
+                # end-of-round spill drain (ISSUE 17): the gathers were
+                # dispatched at eviction time and the next round's
+                # device work is already in flight — the host copy +
+                # pack lands here, off the decode hot path
+                self.drain_spills()
+            if self.paged_kv:
+                self._paged_stats_refresh()
+            self._round += 1
+            if t_start is not None:
+                if self._clock() - t_start > self.stall_threshold_s:
+                    self._failure_event("slow_steps")
+            self._drain_terminal(results)
 
     def run(self) -> Dict[int, GenerationResult]:
         """Drain the queue: admit into free slots (advancing chunked

@@ -137,9 +137,14 @@ class _Live:
     the stepping thread (producer: deltas, terminal) and the handler
     thread serving its connection (consumer)."""
 
-    __slots__ = ("events", "result", "done", "tokens")
+    __slots__ = ("events", "result", "done", "tokens",
+                 "gateway_wait_s")
 
-    def __init__(self):
+    def __init__(self, gateway_wait_s: float = 0.0):
+        #: handler has the parsed body -> ``engine.submit`` returned:
+        #: the wait for the stepper's lock and the submit itself;
+        #: rides the terminal's ``timing`` beside ``ttft_s``
+        self.gateway_wait_s = gateway_wait_s
         #: delta token lists and, last, the GenerationResult terminal
         self.events: Queue = Queue()
         self.result: Optional[GenerationResult] = None
@@ -498,25 +503,36 @@ class ServingGateway:
             return False
         return True
 
+    def _must_wait(self) -> bool:
+        """Nothing for the stepper to do (lock held). Terminals
+        minted while idle (cancel of a queued request, shed-oldest
+        victims) must drain without waiting for new work —
+        ``step()`` with an empty engine is exactly the drain."""
+        return not self._stopped and (
+            self._paused
+            or not (self.engine.has_work() or self.engine._terminal)
+            or self._hold_for_grace())
+
     def _loop(self) -> None:
+        # with ``engine.step``'s ``serving.round`` these spans cover
+        # the stepper thread's whole wall, so that a device idle gap
+        # in a ``jax.profiler`` trace is named by one of them
+        span = self.engine._span
         while True:
-            if self._waiters:
-                # hand the lock to queued submits/cancels/drains
-                # before the next round grabs it again
-                time.sleep(0.001)
-            with self._wake:
-                # terminals minted while idle (cancel of a queued
-                # request, shed-oldest victims) must drain without
-                # waiting for new work — ``step()`` with an empty
-                # engine is exactly the drain
-                while not self._stopped and (
-                        self._paused
-                        or not (self.engine.has_work()
-                                or self.engine._terminal)
-                        or self._hold_for_grace()):
-                    self._wake.wait(timeout=0.005
-                                    if self._grace_t0 is not None
-                                    else 0.05)
+            with span("gateway.lock_yield", waiters=self._waiters):
+                if self._waiters:
+                    # hand the lock to queued submits/cancels/drains
+                    # before the next round grabs it again
+                    time.sleep(0.001)
+                self._wake.acquire()
+            try:
+                if self._must_wait():
+                    with span("gateway.idle_wait"):
+                        while self._must_wait():
+                            self._wake.wait(
+                                timeout=0.005
+                                if self._grace_t0 is not None
+                                else 0.05)
                 if self._stopped:
                     return
                 t0 = time.perf_counter()
@@ -527,9 +543,12 @@ class ServingGateway:
                     return
                 self._round_s = (0.8 * self._round_s
                                  + 0.2 * (time.perf_counter() - t0))
-                for rid, res in self._step_sink.items():
-                    self._deliver_terminal(rid, res)
-                self._step_sink.clear()
+                with span("gateway.deliver", n=len(self._step_sink)):
+                    for rid, res in self._step_sink.items():
+                        self._deliver_terminal(rid, res)
+                    self._step_sink.clear()
+            finally:
+                self._wake.release()
 
     def _fail(self, exc: Exception) -> None:
         """The stepping thread's last act (lock held): record why
@@ -581,6 +600,8 @@ class ServingGateway:
             self._results.pop(next(iter(self._results)))
         live = self._live.get(rid)
         if live is not None:
+            if res.timing is not None:
+                res.timing["gateway_wait_s"] = live.gateway_wait_s
             live.result = res
             live.events.put(res)
             live.done.set()
@@ -598,6 +619,7 @@ class ServingGateway:
         ``X-DL4J-Trace`` header value (ISSUE 10); the JSON ``trace``
         field wins when both carriers are present (it is what a
         body-level relay forwards)."""
+        t0 = time.perf_counter()
         if body.get("trace") is not None:
             trace = str(body["trace"])[:256]
         try:
@@ -629,7 +651,10 @@ class ServingGateway:
             return None, None, (
                 400, {"error": "tenant 'system' is reserved for "
                                "infrastructure traffic"}, ())
-        with self._engine_access():
+        with self.engine._span("gateway.submit") as args, \
+                contextlib.ExitStack() as locked:
+            with self.engine._span("gateway.lock_wait"):
+                locked.enter_context(self._engine_access())
             if self.failure is not None:
                 return None, None, (500, self._failure_payload(), ())
             if self._draining or self._stopped:
@@ -668,7 +693,7 @@ class ServingGateway:
                 rid = self.engine.submit(req)
             except ValueError as e:
                 return None, None, (400, {"error": str(e)}, ())
-            live = _Live()
+            live = _Live(time.perf_counter() - t0)
             self._live[rid] = live
             if (self.admission_grace_s > 0 and self._grace_t0 is None
                     and not any(s is not None
@@ -679,6 +704,7 @@ class ServingGateway:
             # under shed-oldest a full queue just evicted someone
             # else; their terminal flows through the normal drain
             self._wake.notify_all()
+            args["rid"] = rid
         return rid, live, None
 
     def cancel(self, rid: int) -> bool:

@@ -383,8 +383,8 @@ def report_from_events(events) -> List[Dict[str, object]]:
     times (serving), and over the ``train.step`` span args (training —
     a K-step fused window contributes K per-step samples)."""
     series: Dict[str, List[float]] = {
-        "ttft": [], "itl": [], "queue_wait": [], "round": [],
-        "e2e": []}
+        "ttft": [], "first_delta": [], "gateway_wait": [], "itl": [],
+        "queue_wait": [], "round": [], "e2e": []}
     train: Dict[str, List[float]] = {
         "step": [], "data_wait": [], "sync": []}
     for event in events:
@@ -403,6 +403,11 @@ def report_from_events(events) -> List[Dict[str, object]]:
             timing = args.get("timing") or {}
             if timing.get("ttft_s") is not None:
                 series["ttft"].append(timing["ttft_s"])
+            # beside ttft: when the first delta left the engine, and
+            # (behind a gateway) the handler's wait for the stepper
+            for key in ("first_delta", "gateway_wait"):
+                if timing.get(f"{key}_s") is not None:
+                    series[key].append(timing[f"{key}_s"])
             series["queue_wait"].append(
                 timing.get("queue_wait_s", 0.0))
             if timing.get("e2e_s") is not None:
@@ -422,7 +427,8 @@ def report_from_events(events) -> List[Dict[str, object]]:
         **{f"p{int(q * 100)}_ms":
            1e3 * _exact_quantile(series[label], q)
            for q in QUANTILES},
-    } for label in ("ttft", "itl", "queue_wait", "round", "e2e")
+    } for label in ("ttft", "first_delta", "gateway_wait", "itl",
+                    "queue_wait", "round", "e2e")
         if series[label]]
     rows.extend({
         "phase": label,

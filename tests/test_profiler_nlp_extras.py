@@ -15,11 +15,7 @@ from deeplearning4j_tpu.nlp.document_iterator import (
     windows,
 )
 from deeplearning4j_tpu.nlp.inverted_index import InvertedIndex
-from deeplearning4j_tpu.profiler import (
-    ProfilerIterationListener,
-    Tracer,
-    device_trace,
-)
+from deeplearning4j_tpu.profiler import Tracer
 
 
 def _net(algo=None, iterations=5):
@@ -106,24 +102,6 @@ class TestTracer:
         data = json.loads(out.read_text())
         names = {e["name"] for e in data["traceEvents"]}
         assert names == {"work", "score", "marker"}
-
-    def test_profiler_listener_records_iterations(self):
-        tracer = Tracer()
-        net = _net(iterations=3)
-        net.set_listeners(ProfilerIterationListener(tracer))
-        rng = np.random.default_rng(1)
-        X = rng.normal(size=(16, 4)).astype(np.float32)
-        y = np.eye(3, dtype=np.float32)[rng.integers(0, 3, 16)]
-        net.fit(X, y)
-        assert len(tracer.spans("iteration")) >= 2  # n-1 gaps
-        counters = [e for e in tracer.events() if e["ph"] == "C"]
-        assert len(counters) >= 3
-
-    def test_device_trace_no_crash(self, tmp_path):
-        import jax.numpy as jnp
-
-        with device_trace(str(tmp_path / "jaxtrace")):
-            jnp.ones(4).sum().block_until_ready()
 
 
 class TestInvertedIndex:
