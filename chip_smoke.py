@@ -41,6 +41,7 @@ same directory reports warm times.
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
 import shutil
@@ -160,10 +161,11 @@ def forced_agreement(net, prompt, tokens):
 def kernel_phase(size, log) -> None:
     """``_paged_attend`` twice on the same random pool, block tables and
     chunk — once through the XLA gather program, once through the
-    compiled pallas kernel — at the serving geometry and the three query
-    lengths the engine dispatches (decode, a verify chunk, one prefill
-    tile). Rows sit at different fill levels (empty, mid-window, slid
-    past the window, raised floor), chunks are ragged, and one free
+    compiled pallas kernel — at the smoke's serving geometry and at the
+    benchmark cell's (48 rows, 16 heads), at the three query lengths the
+    engine dispatches (decode, a verify chunk, one prefill tile). Rows
+    sit at different fill levels (empty, mid-window, slid past the
+    window, raised floor, idle), chunks are ragged, and one free
     block is poisoned with NaN: the value-level masking must hold in
     the compiled kernel as it does in the interpreter."""
     import jax.numpy as jnp
@@ -173,20 +175,31 @@ def kernel_phase(size, log) -> None:
         MultiHeadSelfAttention,
     )
 
-    h = size["serve"]["n_heads"]
-    dh = size["serve"]["width"] // h
-    tm, bt, b = size["serve"]["window"], size["block_tokens"], 4
-    nb, s_ring = 4 * (tm // bt) + 64, 2 * (tm // bt) + 8
-    lc = MultiHeadSelfAttention(n_in=h * dh, n_out=h * dh, n_heads=h,
-                                stream_max_t=tm)
-    for t in (1, 5, 128):
-        rng = np.random.default_rng(t)
-        filled = np.array([0, tm + tm // 4, tm // 3, tm // 2], np.int32)
-        floor = np.array([0, 0, 0, 2 * bt], np.int32)
+    h0 = size["serve"]["n_heads"]
+    dh = size["serve"]["width"] // h0
+    tm, bt = size["serve"]["window"], size["block_tokens"]
+    s_ring = 2 * (tm // bt) + 8
+    # the smoke's own serving geometry at 4 rows, then the benchmark's
+    # serving cell: 48 rows of 16 heads (twice the heads at toy size),
+    # the same four fill levels, then every third row at a chat-length
+    # context with idle slots between
+    for (h, b), t in itertools.product(((h0, 4), (2 * h0, 48)),
+                                       (1, 5, 128)):
+        lc = MultiHeadSelfAttention(n_in=h * dh, n_out=h * dh, n_heads=h,
+                                    stream_max_t=tm)
+        rng = np.random.default_rng([t, b])
+        nb = 4 * (tm // bt) + 64 + 18 * b
+        filled = np.zeros(b, np.int32)
+        filled[::3] = rng.integers(2, tm // 3, len(filled[::3]))
+        filled[:4] = [0, tm + tm // 4, tm // 3, tm // 2]
+        floor = np.zeros(b, np.int32)
+        floor[3] = 2 * bt
         table = np.full((b, s_ring), -1, np.int32)
         base = np.full((b, s_ring), -1, np.int32)
         free = list(rng.permutation(nb))
         for r in range(b):
+            if r and not filled[r]:
+                continue            # an idle slot maps nothing
             lo = max(0, int(filled[r]) - tm) // bt
             for g in range(lo, (int(filled[r]) + t - 1) // bt + 1):
                 table[r, g % s_ring] = free.pop()
@@ -216,15 +229,16 @@ def kernel_phase(size, log) -> None:
             if mask is not None:  # pad queries are never read
                 o = o * np.asarray(mask)[:, None, :, None]
             outs[toggle] = o
+        what = f"{b} rows x {h} heads, t={t}"
         check(bool(np.isfinite(outs[True]).all()),
-              f"t={t}: NaN leaked through the kernel's masked lanes")
+              f"{what}: NaN leaked through the kernel's masked lanes")
         diff = float(np.abs(outs[True] - outs[False]).max())
-        log(f"kernel: t={t} paged kernel vs gather program max|diff| "
+        log(f"kernel: {what}: paged kernel vs gather program max|diff| "
             f"{diff:.4f} (mean|out| "
             f"{float(np.abs(outs[False]).mean()):.4f}; bf16 chunk, "
             "f32 pool)")
         # a few bf16 ulps at |out| ~ 1; an all-bf16 run read 0.016
-        check(diff <= 0.0625, f"t={t}: kernel differs from the gather "
+        check(diff <= 0.0625, f"{what}: kernel differs from the gather "
               f"program by {diff}")
 
 

@@ -15,11 +15,13 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.nn.conf.layers import BaseRecurrentLayer
 from deeplearning4j_tpu.nn.conf.serde import register_bean
@@ -301,8 +303,11 @@ class AttentionImpl(LayerImplBase):
         """Gather-by-block-table attention over the shared KV block
         pool (the serving engine's ``paged_kv=True`` layout — vLLM's
         PagedAttention memory model on the XLA level: the pallas
-        double-buffered kernel in boom_attention_tricks.md is the TPU
-        hot-path successor, this program is its semantics).
+        kernel :func:`_paged_flash_attention`, which walks the same
+        ``ntab`` table entries a compute block of several pool blocks
+        per grid step with its own double-buffered copies, is the TPU
+        hot path; this program is its semantics, its off-TPU path and
+        its parity oracle).
 
         The cache dict is NOT a per-slot row but a view into one pool
         shared by every slot and the radix prefix trie:
@@ -373,7 +378,7 @@ class AttentionImpl(LayerImplBase):
         # consecutive logical blocks from the earliest any query needs
         # (bounded per-executable: ~window + chunk tokens, NOT the
         # whole ring — the decode step reads ~window keys like dense)
-        ntab = min(s_ring, (tm + t - 2) // bt + 2)
+        ntab = _paged_table_entries(s_ring, tm, bt, t)
         lo = jnp.maximum(floor, jnp.maximum(filled - tm + 1, 0))
         lo_blk = lo // bt
         g = lo_blk[:, None] + jnp.arange(ntab)[None, :]    # [B, ntab]
@@ -382,8 +387,10 @@ class AttentionImpl(LayerImplBase):
         bval = (tb >= 0) & (bb == g * bt)          # ring slot holds g
         toggle = getattr(lc, "use_flash_paged", None)
         if _should_use_flash_paged(toggle, bt, dh, t):
-            # fused pallas kernel (ISSUE 12): each row walks its
-            # block list INSIDE the kernel — no [B, ntab*bt, ...]
+            # fused pallas kernel (ISSUE 12; ISSUE 25: a grid step
+            # is a compute block of several table entries): each row
+            # walks its block list INSIDE the kernel, copying only
+            # mapped and reachable pool blocks — no [B, ntab*bt, ...]
             # gather ever materializes in HBM. Same validity rule,
             # same value-level NaN masking, online softmax; parity vs
             # the gather program is argmax-level (different float
@@ -765,12 +772,92 @@ def _flash_attention(q, k, v, causal):
 #: accumulators whatever the chunk length
 _PAGED_Q_TILE = 128
 
+#: query tiles of at most this many rows (decode, a verify chunk) score
+#: a compute block on the vector unit, all heads at once; longer tiles
+#: keep one MXU product per head
+_PAGED_SHORT_TILE = 8
+
+#: VMEM the kernel's pool buffers (K and V, two slots each) may take,
+#: and the most table entries one compute block holds
+_PAGED_POOL_VMEM = 4 << 20
+_PAGED_MAX_BLOCKS = 16
+_PAGED_VMEM_LIMIT = 32 << 20
+
+#: first position of a table entry the walk skipped: past every query,
+#: so the copy that was never made is masked like a future key
+_PAGED_FAR = 1 << 30
+
+
+def _paged_q_tile(t: int) -> int:
+    """Query rows one grid step holds: whole ``_PAGED_Q_TILE`` tiles of
+    a prefill chunk, else the chunk itself (decode, verify)."""
+    return _PAGED_Q_TILE if t % _PAGED_Q_TILE == 0 else t
+
+
+def _paged_table_entries(ring_slots: int, window: int,
+                         block_tokens: int, t: int) -> int:
+    """ntab, the consecutive logical blocks a row's ``t`` queries can
+    reach through a ``window``-token sliding window (never the whole
+    ring): what the gather program gathers and the kernel walks."""
+    return min(ring_slots, (window + t - 2) // block_tokens + 2)
+
+
+def _paged_blocks_per_step(block_tokens: int, n_heads: int,
+                           head_dim: int, pool_dtype, ntab: int) -> int:
+    """P, the table entries (pool blocks) one grid step of the paged
+    kernel holds: as many as keep the double-buffered K and V scratch
+    inside ``_PAGED_POOL_VMEM`` at the pool's tiled size (the head axis
+    pads to the dtype's sublane tile, the head dim to 128 lanes), at
+    most ``_PAGED_MAX_BLOCKS`` and never more than the walk is long."""
+    itemsize = jnp.dtype(pool_dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    entry = (block_tokens * -(-n_heads // sublanes) * sublanes
+             * -(-head_dim // 128) * 128 * itemsize)
+    return max(1, min(_PAGED_POOL_VMEM // (4 * entry),
+                      _PAGED_MAX_BLOCKS, ntab))
+
+
+def paged_walk_counts(table, base, floor, filled, *, block_tokens: int,
+                      window: int, blocks_per_step: int,
+                      chunk: int = 1) -> Tuple[int, int]:
+    """What one call of the paged kernel does with a dispatch's host
+    tables (numpy; the arithmetic of :meth:`AttentionImpl._paged_attend`
+    and the kernel's ``span``/``reach``): ``live``, the pool blocks it
+    copies — entries mapped and reachable by some query of a tile,
+    summed over the query tiles — and ``walked``, the pool blocks'
+    worth of keys it scores, ``blocks_per_step`` for every compute
+    block that holds a live entry. ``live / walked`` is the share of
+    the kernel's arithmetic spent on keys that exist."""
+    bt, tm, t, p_blk = block_tokens, window, chunk, blocks_per_step
+    table, base = np.asarray(table), np.asarray(base)
+    floor, filled = np.asarray(floor), np.asarray(filled)
+    s_ring = table.shape[1]
+    ntab = _paged_table_entries(s_ring, tm, bt, t)
+    lo_blk = np.maximum(floor, np.maximum(filled - tm + 1, 0)) // bt
+    e = np.arange(-(-ntab // p_blk) * p_blk)[None, :]
+    g = lo_blk[:, None] + e
+    mapped = ((np.take_along_axis(table, g % s_ring, axis=1) >= 0)
+              & (np.take_along_axis(base, g % s_ring, axis=1) == g * bt)
+              & (e < ntab))
+    tq = _paged_q_tile(t)
+    live = walked = 0
+    for i in range(t // tq):
+        q0 = (filled + i * tq)[:, None]
+        hit = (mapped
+               & (e >= np.maximum(q0 - tm + 1, 0) // bt - lo_blk[:, None])
+               & (e <= (q0 + tq - 1) // bt - lo_blk[:, None]))
+        live += int(hit.sum())
+        walked += p_blk * int(
+            hit.reshape(len(filled), -1, p_blk).any(axis=2).sum())
+    return live, walked
+
 
 def _should_use_flash_paged(toggle, block_tokens: int,
                             head_dim: int, t: int = 1) -> bool:
-    """Dispatch rule for the pallas paged-attention decode kernel
-    (:func:`_paged_flash_attention`) vs the XLA gather-by-block-table
-    program in :meth:`AttentionImpl._paged_attend`:
+    """Dispatch rule for the pallas paged-attention kernel
+    (:func:`_paged_flash_attention`, one grid step = one compute block
+    of several pool blocks) vs the XLA gather-by-block-table program in
+    :meth:`AttentionImpl._paged_attend`:
 
     - ``None`` (auto): the kernel on the TPU backend when the block
       shape tiles healthily — ``block_tokens`` a multiple of 8
@@ -787,6 +874,11 @@ def _should_use_flash_paged(toggle, block_tokens: int,
     - ``"interpret"``: the kernel through the pallas interpreter on
       any backend — the CPU bit-parity testing hook (tier-1 gates the
       kernel's semantics against the gather program with it).
+
+    How many pool blocks a grid step holds and which unit scores them
+    is the kernel's own business (``_paged_blocks_per_step``,
+    ``_PAGED_SHORT_TILE``): every shape this rule admits lowers either
+    way (tests/test_attention_tpu_lowering.py).
 
     Both paths enforce the SAME value-level masking rule: gathered /
     DMA'd V lanes outside ``[floor, filled + written)`` are zeroed at
@@ -815,31 +907,44 @@ def _should_use_flash_paged(toggle, block_tokens: int,
     return True
 
 
+# jitted on its own so that a program of many layers traces and lowers
+# the kernel once (same shapes, same ``tm``), not once a layer: the
+# body's per-entry branches make a trace cost about a second
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
 def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
                            filled, lengths, *, tm: int,
                            interpret: bool = False):
-    """Fused pallas paged-attention kernel (ISSUE 12; pallas_guide.md,
-    boom_attention_tricks.md §8-12 — the in-repo flash kernel's decode
-    successor). One grid step = one (row, query tile, logical-block)
-    visit, all heads of the block at once:
+    """Fused pallas paged-attention kernel (ISSUE 12, regridded in
+    ISSUE 25; pallas_guide.md, boom_attention_tricks.md §8-12 — the
+    in-repo flash kernel's decode successor). One grid step = one
+    (row, query tile, COMPUTE BLOCK) visit, a compute block being
+    ``P = _paged_blocks_per_step(...)`` consecutive table entries
+    (``P x bt`` keys, all heads): the third grid axis is
+    ``ceil(ntab / P)`` long, not ``ntab``.
 
-    - the BLOCK TABLE rides as scalar-prefetch operands, and the K/V
-      BlockSpec ``index_map`` reads it to map grid step ``(b, i, j)``
-      to pool block ``bid[b, j]`` — pallas's pipeline then DMAs each
-      (non-contiguous) block HBM→VMEM ahead of compute, exactly the
-      double-buffered page walk of the reference paged kernel, with
-      NO ``[B, ntab*bt, H, dh]`` gather ever materialized. The K/V
-      block carries the WHOLE head axis, ``(1, bt, H, dh)``: the TPU
-      lowering wants a block's last two dimensions to be multiples of
-      (8, 128) or the array's own, and a one-head block in the
-      second-minor position is neither. Heads are walked inside the
-      body with a strided read per head.
-    - online softmax over the block walk (running max / sum / output
-      accumulator in VMEM scratch, rescaled per block) under the SAME
-      validity rule as the XLA gather program: block mapped, causal,
-      last-``tm`` window, per-row floor. A block no query of the tile
-      can reach (unmapped, wholly in the future, wholly slid out)
-      skips its compute.
+    - the BLOCK TABLE rides as scalar-prefetch operands and the pools
+      stay in HBM (``memory_space=ANY``). The kernel fetches a compute
+      block itself: one async copy per *mapped and reachable* entry —
+      a pool block ``[bt, H, dh]`` is contiguous — into a two-slot
+      VMEM scratch ``[2, P*bt, H, dh]`` each for K and V, and starts
+      the NEXT grid step's copies (the same row's next compute block,
+      or the next row's first) before it waits for its own, so the
+      fetch hides behind the arithmetic. No ``[B, ntab*bt, H, dh]``
+      gather ever materializes. A compute block no query of the tile
+      can reach (idle slot, entries past the row's length, wholly slid
+      out) costs two range tests: no copy, no wait, no arithmetic.
+    - an entry the walk skipped leaves stale scratch behind; its keys
+      take a position past every query (``_PAGED_FAR``), so they are
+      masked at the score AND the value level like any future key.
+    - scoring follows the query tile. A short tile
+      (``<= _PAGED_SHORT_TILE`` rows: decode, verify) works on the
+      vector unit in the pool's own ``[key, H, dh]`` layout — multiply
+      by the query row, reduce over lanes — all heads at once, float32;
+      a longer tile (prefill) keeps one MXU product per head against
+      the compute block's ``P x bt`` keys. Either way ONE online-softmax
+      rescale per compute block (running max / sum / accumulator in
+      VMEM scratch) under the SAME validity rule as the XLA gather
+      program: entry mapped, causal, last-``tm`` window, per-row floor.
     - value-level masking: V lanes outside ``[floor, filled + len)``
       are zeroed BEFORE the weighted sum — a zero softmax weight does
       not kill a NaN (0 x NaN = NaN), so a recycled dirty block would
@@ -851,29 +956,98 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
       the gather path's uniform-softmax-over-zeroed-values).
 
     Shapes: q [B, H, t, dh]; pk/pv [nb, bt, H, dh] (post-scatter);
-    bid/bval [B, ntab] int32 (pool block per logical block, validity);
-    lo_blk/floor/filled/lengths [B] int32. Returns o [B, H, t, dh].
-    Queries tile by ``_PAGED_Q_TILE`` when ``t`` is a multiple of it
-    (a prefill chunk); shorter chunks (decode, verify) are one tile.
-    Parity vs the gather program is argmax-level (one float reduction
-    runs blockwise, the other over the flat gather — the PR 6
-    paged-parity convention), gated per tier-1 workload in
-    tests/test_serving_tp.py via interpret mode."""
+    bid/bval [B, ntab] int32 (pool block per logical block, validity;
+    padded here to whole compute blocks); lo_blk/floor/filled/lengths
+    [B] int32. Returns o [B, H, t, dh]. Queries tile by
+    ``_PAGED_Q_TILE`` when ``t`` is a multiple of it (a prefill
+    chunk); shorter chunks (decode, verify) are one tile. Parity vs
+    the gather program is argmax-level (one float reduction runs
+    blockwise, the other over the flat gather — the PR 6 paged-parity
+    convention), gated per tier-1 workload in tests/test_serving_tp.py
+    via interpret mode."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b_sz, h_sz, t, dh = q.shape
     bt = pk.shape[1]
     ntab = bid.shape[1]
-    tq = _PAGED_Q_TILE if t % _PAGED_Q_TILE == 0 else t
+    tq = _paged_q_tile(t)
+    nq = t // tq
+    short = tq <= _PAGED_SHORT_TILE
+    p_blk = _paged_blocks_per_step(bt, h_sz, dh, pk.dtype, ntab)
+    nj = -(-ntab // p_blk)
+    n_keys = p_blk * bt
+    total = b_sz * nq * nj
     scale = dh ** -0.5
+    pad = nj * p_blk - ntab
+    if pad:
+        bid = jnp.pad(bid, ((0, 0), (0, pad)))
+        bval = jnp.pad(bval, ((0, 0), (0, pad)))
+    # a short tile is handed over (and returned) as [B, t, H, dh], the
+    # pool's own minor layout, so a query row is one [H, dh] read
+    if short:
+        q = jnp.swapaxes(q, 1, 2)
+    rows = (tq, h_sz) if short else (h_sz, tq)
 
     def kernel(bid_ref, bval_ref, lo_ref, floor_ref, filled_ref,
-               len_ref, q_ref, pk_ref, pv_ref, o_ref, m_ref, l_ref,
-               acc_ref):
+               len_ref, q_ref, pk_ref, pv_ref, o_ref, kbuf, vbuf, sem,
+               m_ref, l_ref, acc_ref):
         b = pl.program_id(0)
         i = pl.program_id(1)
         j = pl.program_id(2)
+        step = (b * nq + i) * nj + j
+        slot = step % 2
+
+        def span(bb, ii, jj):
+            """Entries of row ``bb`` that some query of tile ``ii`` can
+            reach (causal above, last-``tm`` window below; the floor is
+            in ``lo_blk`` already), and whether compute block ``jj``
+            holds any of them."""
+            q0 = filled_ref[bb] + ii * tq
+            lo_e = jnp.maximum(q0 - tm + 1, 0) // bt - lo_ref[bb]
+            hi_e = (q0 + tq - 1) // bt - lo_ref[bb]
+            return lo_e, hi_e, ((jj * p_blk <= hi_e)
+                                & (jj * p_blk + p_blk - 1 >= lo_e))
+
+        def reach(bb, jj, lo_e, hi_e):
+            """Per entry of the compute block: mapped and reachable —
+            the one predicate that decides copy, wait and mask."""
+            e0 = jj * p_blk
+            return [(bval_ref[bb, e0 + p] > 0) & (e0 + p >= lo_e)
+                    & (e0 + p <= hi_e) for p in range(p_blk)]
+
+        def copies(bb, jj, sl, live, go):
+            for p in range(p_blk):
+                @pl.when(live[p])
+                def _entry(p=p):
+                    blk = bid_ref[bb, jj * p_blk + p]
+                    rows = pl.ds(p * bt, bt)
+                    go(pltpu.make_async_copy(
+                        pk_ref.at[blk], kbuf.at[sl, rows], sem.at[0, sl]))
+                    go(pltpu.make_async_copy(
+                        pv_ref.at[blk], vbuf.at[sl, rows], sem.at[1, sl]))
+
+        def fetch(bb, ii, jj, sl):
+            lo_e, hi_e, some = span(bb, ii, jj)
+
+            @pl.when(some)
+            def _start():
+                copies(bb, jj, sl, reach(bb, jj, lo_e, hi_e),
+                       lambda dma: dma.start())
+
+        @pl.when(step == 0)
+        def _first():
+            fetch(b, i, j, slot)
+
+        @pl.when(step + 1 < total)
+        def _next():
+            # the grid is walked in order, so step + 1 is the next
+            # compute block, the next tile's first or the next row's
+            j_end = j + 1 == nj
+            i_end = j_end & (i + 1 == nq)
+            fetch(jnp.where(i_end, b + 1, b),
+                  jnp.where(i_end, 0, jnp.where(j_end, i + 1, i)),
+                  jnp.where(j_end, 0, j + 1), 1 - slot)
 
         @pl.when(j == 0)
         def _init():
@@ -881,31 +1055,68 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        k0 = (lo_ref[b] + j) * bt           # block's first position
-        q0 = filled_ref[b] + i * tq         # tile's first position
-        reachable = ((bval_ref[b, j] > 0) & (k0 <= q0 + tq - 1)
-                     & (k0 + bt - 1 > q0 - tm))
+        q0 = filled_ref[b] + i * tq            # tile's first position
+        written = filled_ref[b] + len_ref[b]
 
-        @pl.when(reachable)
-        def _block():
-            kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (tq, bt), 1)
-            qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, bt), 0)
+        def short_block(k0):
+            # [keys, H, dh] as the pool holds it; every per-key and
+            # per-(key, head) number is [keys, H, 1]
+            kpos = jnp.concatenate([
+                k0[p] + jax.lax.broadcasted_iota(
+                    jnp.int32, (bt, h_sz, 1), 0)
+                for p in range(p_blk)], axis=0)
+            vlive = (kpos < written) & (kpos >= floor_ref[b])
+            kb = kbuf[slot].astype(jnp.float32)
+            vb = jnp.where(vlive, vbuf[slot], 0).astype(jnp.float32)
+            reachable = kpos >= floor_ref[b]
+
+            def row(r, carry):
+                qpos = q0 + r
+                ok = reachable & (kpos <= qpos) & (kpos > qpos - tm)
+                qv = q_ref[0, r].astype(jnp.float32) * scale  # [H, dh]
+                s = jnp.sum(kb * qv[None], axis=2, keepdims=True)
+                s = jnp.where(ok, s, -1e30)                # [keys, H, 1]
+                m_prev = m_ref[r]                          # [H, 128]
+                m_next = jnp.maximum(m_prev, jnp.max(s, axis=0))
+                alpha = jnp.exp(m_prev - m_next)
+                p = jnp.where(ok, jnp.exp(s - m_next[:, :1][None]), 0.0)
+                l_ref[r] = alpha * l_ref[r] + jnp.sum(p, axis=0)
+                acc_ref[r] = (alpha[:, :1] * acc_ref[r]
+                              + jnp.sum(p * vb, axis=0))
+                m_ref[r] = m_next
+                return carry
+
+            if tq == 1:
+                row(0, None)
+            else:
+                jax.lax.fori_loop(0, tq, row, None)
+
+        def tile_block(k0):
+            def positions(shape, dim):
+                idx = jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+                pos = k0[0] + idx
+                for p in range(1, p_blk):
+                    pos = jnp.where(idx >= p * bt,
+                                    k0[p] - p * bt + idx, pos)
+                return pos
+
+            kpos = positions((1, n_keys), 1)
+            qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
             ok = ((kpos <= qpos) & (kpos > qpos - tm)
-                  & (kpos >= floor_ref[b]))
-            # value-level masking (see docstring): one [bt, 1] column
-            # — the written-span rule is q-position-independent
-            vpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
-            vlive = ((vpos < filled_ref[b] + len_ref[b])
-                     & (vpos >= floor_ref[b]))
+                  & (kpos >= floor_ref[b]))                # [tq, keys]
+            # value-level masking (see docstring): one [keys, 1]
+            # column — the written-span rule is q-position-independent
+            vpos = positions((n_keys, 1), 0)
+            vlive = (vpos < written) & (vpos >= floor_ref[b])
             for h in range(h_sz):
-                kb = pk_ref[0, :, h, :]                   # [bt, dh]
-                vb = pv_ref[0, :, h, :]
+                kb = kbuf[slot, :, h, :]                   # [keys, dh]
+                vb = vbuf[slot, :, h, :]
                 vb = jnp.where(vlive, vb, jnp.zeros_like(vb))
                 s = jax.lax.dot_general(
                     q_ref[0, h], kb, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
                 s = jnp.where(ok, s, -1e30)
-                m_prev = m_ref[h]                         # [tq, 128]
+                m_prev = m_ref[h]                          # [tq, 128]
                 m_next = jnp.maximum(
                     m_prev, jnp.max(s, axis=1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_next)
@@ -919,41 +1130,55 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
                                   preferred_element_type=jnp.float32))
                 m_ref[h] = m_next
 
-        @pl.when(j == pl.num_programs(2) - 1)
+        lo_e, hi_e, some = span(b, i, j)
+
+        @pl.when(some)
+        def _block():
+            live = reach(b, j, lo_e, hi_e)
+            copies(b, j, slot, live, lambda dma: dma.wait())
+            # an entry's first key position; a skipped entry's keys
+            # sit past every query
+            k0 = [jnp.where(live[p], (lo_ref[b] + j * p_blk + p) * bt,
+                            _PAGED_FAR) for p in range(p_blk)]
+            pl.when(functools.reduce(jnp.logical_or, live))(
+                lambda: (short_block if short else tile_block)(k0))
+
+        @pl.when(j == nj - 1)
         def _finalize():
-            for h in range(h_sz):
-                l = l_ref[h][:, :1]
-                o_ref[0, h] = (
-                    acc_ref[h] / jnp.where(l == 0, 1.0, l)
-                ).astype(o_ref.dtype)
+            l = l_ref[...][..., :1]
+            o_ref[0] = (acc_ref[...] / jnp.where(l == 0, 1.0, l)
+                        ).astype(o_ref.dtype)
 
     def q_map(b, i, j, *refs):
-        return (b, 0, i, 0)
-
-    def kv_map(b, i, j, bid, *refs):
-        # the page walk: scalar-prefetched table drives the DMA
-        return (bid[b, j], 0, 0, 0)
+        return (b, 0, 0, 0) if short else (b, 0, i, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(b_sz, t // tq, ntab),
+        grid=(b_sz, nq, nj),
         in_specs=[
-            pl.BlockSpec((1, h_sz, tq, dh), q_map),
-            pl.BlockSpec((1, bt, h_sz, dh), kv_map),
-            pl.BlockSpec((1, bt, h_sz, dh), kv_map),
+            pl.BlockSpec((1, *rows, dh), q_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, h_sz, tq, dh), q_map),
+        out_specs=pl.BlockSpec((1, *rows, dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((h_sz, tq, 128), jnp.float32),   # running max
-            pltpu.VMEM((h_sz, tq, 128), jnp.float32),   # running sum
-            pltpu.VMEM((h_sz, tq, dh), jnp.float32),    # accumulator
+            pltpu.VMEM((2, n_keys, h_sz, dh), pk.dtype),
+            pltpu.VMEM((2, n_keys, h_sz, dh), pv.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((*rows, 128), jnp.float32),      # running max
+            pltpu.VMEM((*rows, 128), jnp.float32),      # running sum
+            pltpu.VMEM((*rows, dh), jnp.float32),       # accumulator
         ],
     )
-    return pl.pallas_call(
+    o = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b_sz, h_sz, t, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=_PAGED_VMEM_LIMIT),
         interpret=interpret,
     )(bid, bval, lo_blk, floor, filled, lengths, q, pk, pv)
+    return jnp.swapaxes(o, 1, 2) if short else o
 
 
 def _dense_attention(q, k, v, causal, mask):

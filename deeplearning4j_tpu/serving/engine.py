@@ -133,7 +133,10 @@ import numpy as np
 
 from deeplearning4j_tpu.nn.layers.attention import (
     ATTENTION_BEANS,
+    _paged_blocks_per_step,
+    _paged_table_entries,
     guard_streamable,
+    paged_walk_counts,
 )
 from deeplearning4j_tpu.nn.streaming import (
     clear_state_rows,
@@ -1036,6 +1039,13 @@ class DecodeEngine:
             "cow_copies": 0, "prefix_blocks_spliced": 0,
             "frag_tokens": 0, "preempted": 0,
             "paged_admit_deferred": 0, "qos_preempted": 0,
+            # the paged kernel's walk (ISSUE 25): pool blocks a
+            # dispatch's tables make one layer's call copy, and the
+            # blocks' worth of keys it scores (whole compute blocks),
+            # summed over dispatches; the compute block's size and a
+            # decode row's grid steps land with the pool
+            "paged_blocks_live": 0, "paged_blocks_walked": 0,
+            "paged_blocks_per_step": 0, "paged_steps_per_row": 0,
             # KV transfer plane (ISSUE 14): cross-replica prefix
             # shipping counters (nonzero only when export/import run)
             "kv_exports": 0, "kv_exported_tokens": 0,
@@ -1876,11 +1886,11 @@ class DecodeEngine:
             else:
                 break
 
-    def _paged_rnn_rows(self, tabs):
-        """Assemble the paged rnn-state operand for a dispatch: the
-        shared pool leaves plus each row's ring-projected block table
-        (None rows — idle slots — map nothing; their writes drop and
-        their keys all mask)."""
+    def _paged_rnn_rows(self, tabs, chunk: int = 1):
+        """Assemble the paged rnn-state operand for a dispatch of
+        ``chunk`` query positions a row: the shared pool leaves plus
+        each row's ring-projected block table (None rows — idle slots —
+        map nothing; their writes drop and their keys all mask)."""
         b = len(tabs)
         s_ring = self._ring_slots
         table = np.full((b, s_ring), -1, np.int32)
@@ -1893,6 +1903,7 @@ class DecodeEngine:
             table[i], base[i] = tab.arrays(s_ring)
             floor[i] = tab.floor
             filled[i] = tab.length
+        self._count_paged_walk(table, base, floor, filled, chunk)
         # per-layer COPIES of the (tiny) table operands: the paged
         # dispatches donate their cache operand, and XLA rejects the
         # same buffer donated through two pytree leaves. Under tp the
@@ -1910,6 +1921,28 @@ class DecodeEngine:
                            floor=op(floor),
                            filled=op(filled))
                 for name, st in self._pool.items()}
+
+    def _count_paged_walk(self, table, base, floor, filled,
+                          chunk: int) -> None:
+        """``paged_blocks_live`` / ``paged_blocks_walked``: what the
+        widest-window layer's kernel call does with these tables
+        (``paged_walk_counts``; the gather program reads the same live
+        blocks). The geometry is the first pool leaf's, local to a
+        tp shard."""
+        pk = next(iter(self._pool.values()))["pk"]
+        bt = self.block_tokens
+        ntab = _paged_table_entries(self._ring_slots, self._wmax, bt,
+                                    chunk)
+        per_step = _paged_blocks_per_step(
+            bt, pk.shape[2] // self.tp, pk.shape[3], pk.dtype, ntab)
+        if chunk == 1:
+            self.stats["paged_blocks_per_step"] = per_step
+            self.stats["paged_steps_per_row"] = -(-ntab // per_step)
+        live, walked = paged_walk_counts(
+            table, base, floor, filled, block_tokens=bt,
+            window=self._wmax, blocks_per_step=per_step, chunk=chunk)
+        self.stats["paged_blocks_live"] += live
+        self.stats["paged_blocks_walked"] += walked
 
     def _strip_pool(self, rnn):
         """Back out the per-dispatch table operands, keeping only the
@@ -2261,7 +2294,7 @@ class DecodeEngine:
             if not self._ensure_tab(pending.tab, len(seg),
                                     rid=req.id):
                 return False
-            rnn_in = self._paged_rnn_rows([pending.tab])
+            rnn_in = self._paged_rnn_rows([pending.tab], chunk=width)
             t0 = self._clock()
             with self._span("serving.prefill_chunk", rid=req.id,
                             width=width, tokens=len(seg),
@@ -3432,7 +3465,9 @@ class DecodeEngine:
             self._paged_stats_refresh()
             for key in ("blocks_free", "blocks_used", "cow_copies",
                         "prefix_blocks_spliced", "frag_tokens",
-                        "preempted", "paged_admit_deferred"):
+                        "preempted", "paged_admit_deferred",
+                        "paged_blocks_live", "paged_blocks_walked",
+                        "paged_blocks_per_step", "paged_steps_per_row"):
                 self.tracer.counter(f"serving_{key}", self.stats[key])
         if self.prefix_cache is not None:
             for key in ("hits", "misses", "evictions"):

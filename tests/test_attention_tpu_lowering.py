@@ -50,6 +50,61 @@ def test_paged_kernel_lowers_for_tpu(h, t, pool_dtype):
     assert "tpu_custom_call" in text
 
 
+# the serving cell's own geometry (Cerebras-GPT-1.3B widths: 16 heads of
+# 128, 48 slots, 1400 pool blocks of 16 tokens, a 129-entry walk), at
+# every chunk length the auto rule admits there: decode, a verify chunk,
+# the prompt buckets 32-2048. 129 is not whole compute blocks.
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("t", [1, 5, 8, 32, 64, 128, 256, 2048])
+def test_paged_kernel_lowers_at_the_serving_cell(t, pool_dtype):
+    avals = _paged_avals(48 if t <= 8 else 1, 16, t, 128, 1400, 16, 129,
+                         jnp.bfloat16, pool_dtype)
+    text = _tpu_module(
+        lambda *a: _paged_flash_attention(*a, tm=2048), *avals)
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip: libtpu compiles for it,
+    Mosaic included, which the cross-lowering above never reaches."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# Mosaic's own objections (a broadcast it cannot lay out, a scratch that
+# does not fit) show only in a real compile: the decode and one-tile
+# prefill programs of the serving cell, a verify chunk at the tp=4 local
+# head count, and decode on an all-bf16 pool
+@pytest.mark.parametrize("b,h,t,pool_dtype", [
+    (48, 16, 1, jnp.float32), (1, 16, 128, jnp.float32),
+    (8, 4, 5, jnp.float32), (48, 16, 1, jnp.bfloat16)])
+def test_paged_kernel_compiles_for_v5e(one_chip, b, h, t, pool_dtype):
+    from jax.experimental.compilation_cache import compilation_cache
+    avals = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+             for a in _paged_avals(b, h, t, 128, 1400, 16, 129,
+                                   jnp.bfloat16, pool_dtype)]
+    # a described device's executable cannot be read back from the
+    # persistent cache; keep it out
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda *a: _paged_flash_attention(*a, tm=2048)
+        ).lower(*avals).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_paged_auto_rule_only_selects_shapes_that_lower(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for t in (1, 5, 8, 64, 128, 256, 2048):

@@ -30,6 +30,7 @@ from deeplearning4j_tpu.models.zoo import transformer_lm
 from deeplearning4j_tpu.nn.layers.attention import (
     AttentionImpl,
     MultiHeadSelfAttention,
+    _paged_blocks_per_step,
     _should_use_flash_paged,
 )
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
@@ -313,6 +314,128 @@ class TestPagedFlashKernel:
             "NaN leaked through the kernel's masked lanes")
         np.testing.assert_allclose(outs["interpret"], outs[False],
                                    rtol=2e-5, atol=2e-5)
+
+
+    # ntab = min(s_ring, (tm + t - 2) // bt + 2) is tied to the window
+    # and the chunk, so the 9-entry walk (the rehearsal's) exists for
+    # short chunks only; 129 is the serving cell's, 130 the flagship's.
+    # ``cap`` bounds the compute block so that no ntab is a multiple
+    @pytest.mark.parametrize("ntab,cap,t", [
+        (9, 4, 1), (9, 4, 5),
+        (129, 16, 1), (129, 16, 5), (129, 16, 128), (129, 16, 256),
+        (130, 8, 1), (130, 8, 5), (130, 8, 128), (130, 8, 256)])
+    def test_kernel_parity_on_ragged_tables(self, monkeypatch, ntab,
+                                            cap, t):
+        """Interpret parity with the gather program where the walk is
+        not whole compute blocks: idle rows between live ones, a slid
+        window, a raised floor inside a block, a hole (an unmapped
+        entry between mapped ones), ragged chunks, and NaN in every
+        pool position no row has written (free blocks, a tail block
+        past its row's length, the floor block below the floor)."""
+        from deeplearning4j_tpu.nn.layers import attention as att
+
+        monkeypatch.setattr(att, "_PAGED_MAX_BLOCKS", cap)
+        h, dh, bt, nb = 2, 8, 8, 640
+        tm = (ntab - 2) * bt - t + 2 + 3     # (tm + t - 2) // bt + 2
+        s_ring = 2 * ntab + 3
+        assert min(s_ring, (tm + t - 2) // bt + 2) == ntab
+        assert ntab % att._paged_blocks_per_step(
+            bt, h, dh, jnp.float32, ntab)
+        lc = MultiHeadSelfAttention(n_in=h * dh, n_out=h * dh,
+                                    n_heads=h, stream_max_t=tm)
+        rng = np.random.default_rng([ntab, t])
+        #         idle  mid     idle  slid          floor   idle  short
+        filled = [0, tm // 2, 0, tm + 3 * bt + 5, 3 * bt + tm // 3, 0, 3]
+        floor = [0, 0, 0, 0, 2 * bt + 3, 0, 0]
+        b = len(filled)
+        lens = rng.integers(1, t + 1, b)
+        lens[1] = t
+        table = np.full((b, s_ring), -1, np.int32)
+        base = np.full((b, s_ring), -1, np.int32)
+        pk = np.full((nb, bt, h, dh), np.nan, np.float32)
+        pv = np.full((nb, bt, h, dh), np.nan, np.float32)
+        free = list(rng.permutation(nb))
+        for r in range(b):
+            if r in (0, 2, 5):
+                continue
+            lo = max(floor[r], filled[r] - tm + 1, 0) // bt
+            for g in range(lo, (filled[r] + t - 1) // bt + 1):
+                bid = free.pop()
+                table[r, g % s_ring], base[r, g % s_ring] = bid, g * bt
+                pos = g * bt + np.arange(bt)
+                held = (pos >= floor[r]) & (pos < filled[r])
+                pk[bid, held] = rng.normal(size=(held.sum(), h, dh))
+                pv[bid, held] = rng.normal(size=(held.sum(), h, dh))
+        # the hole: one of row 1's middle blocks loses its mapping
+        hole = (filled[1] // bt) // 2
+        table[1, hole % s_ring] = base[1, hole % s_ring] = -1
+        q, k, v = (jnp.asarray(rng.normal(size=(b, h, t, dh)),
+                               jnp.float32) for _ in range(3))
+        mask = (None if t == 1 else jnp.asarray(
+            np.arange(t)[None] < lens[:, None], jnp.float32))
+        cache = {"pk": jnp.asarray(pk), "pv": jnp.asarray(pv),
+                 "table": jnp.asarray(table), "base": jnp.asarray(base),
+                 "floor": jnp.asarray(floor, jnp.int32),
+                 "filled": jnp.asarray(filled, jnp.int32)}
+        outs = {}
+        for toggle in (False, "interpret"):
+            lc.use_flash_paged = toggle
+            o, _ = AttentionImpl._paged_attend(lc, q, k, v,
+                                               dict(cache), mask)
+            o = np.asarray(o)
+            if mask is not None:
+                # pad queries are never read (and may see the NaN
+                # their own unwritten chunk positions still hold)
+                o = np.where(np.asarray(mask)[:, None, :, None] > 0,
+                             o, 0.0)
+            outs[toggle] = o
+        assert np.isfinite(outs["interpret"]).all(), (
+            "NaN leaked through the kernel's masked lanes")
+        assert np.abs(outs[False][[1, 3, 4, 6]]).min(axis=(1, 2, 3)
+                                                      ).max() > 0
+        np.testing.assert_allclose(outs["interpret"], outs[False],
+                                   rtol=5e-5, atol=5e-5)
+
+    def test_walk_counters_match_the_tables(self):
+        """``paged_blocks_live`` / ``paged_blocks_walked`` are what the
+        dispatched tables imply: counted again here from the block
+        tables themselves, entry by entry."""
+        eng = DecodeEngine(_net(), n_slots=3, decode_chunk=2, seed=0,
+                           paged_kv=True, block_tokens=8,
+                           prefill_chunk=4, prefix_cache_rows=4)
+        bt, tm = eng.block_tokens, eng._wmax
+        want = {"live": 0, "walked": 0}
+        inner = eng._paged_rnn_rows
+
+        def spy(tabs, chunk=1):
+            out = inner(tabs, chunk)
+            pk = next(iter(eng._pool.values()))["pk"]
+            per_step = _paged_blocks_per_step(
+                bt, pk.shape[2], pk.shape[3], pk.dtype,
+                min(eng._ring_slots, (tm + chunk - 2) // bt + 2))
+            for tab in tabs:
+                if tab is None:
+                    continue
+                lo_blk = max(tab.floor, tab.length - tm + 1, 0) // bt
+                hit = [g - lo_blk for g in tab.blocks
+                       if g >= lo_blk
+                       and g * bt <= tab.length + chunk - 1
+                       and (g + 1) * bt - 1 > tab.length - tm]
+                want["live"] += len(hit)
+                want["walked"] += per_step * len(
+                    {e // per_step for e in hit})
+            return out
+
+        eng._paged_rnn_rows = spy
+        _submit_run(eng)
+        assert eng.stats["paged_blocks_per_step"] >= 1
+        assert eng.stats["paged_steps_per_row"] == -(-min(
+            eng._ring_slots, (tm - 1) // bt + 2)
+            // eng.stats["paged_blocks_per_step"])
+        assert want["live"] > 0
+        assert eng.stats["paged_blocks_live"] == want["live"]
+        assert eng.stats["paged_blocks_walked"] == want["walked"]
+        assert want["live"] <= want["walked"]
 
 
 class TestTpObservability:
