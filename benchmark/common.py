@@ -1,7 +1,6 @@
 """What every cell's runner shares: finding a cell's files by the names
-in ``BENCHMARK.json``, the device gate, the program's net with the
-benchmark's weights, the traced sub-window, the per-layer readers and
-the one result line."""
+in ``BENCHMARK.json``, the model's adapter, the device gate, the traced
+sub-window, the per-layer readers and the one result line."""
 
 from __future__ import annotations
 
@@ -9,7 +8,9 @@ import contextlib
 import gc
 import importlib.util
 import json
+import math
 import os
+import re
 import shutil
 import sys
 import time
@@ -20,9 +21,18 @@ ROOT = os.path.dirname(HERE)
 OUT_DIR = os.path.join(ROOT, ".bench_out")
 
 
+#: a name as ``BENCHMARK.json`` allows it: it is joined into a path
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+#: what a runner calls of a model's adapter (``models/__init__.py``)
+TRAIN_API = ("build_net", "describe", "encode_batch", "start_params",
+             "train_reference", "flops")
+SERVE_API = ("build_net", "describe", "served_gaps", "flops")
+
+
 class Refused(Exception):
     """The run may not start (no accelerator, too few chips, unknown
-    cell): exit code 2, no result line."""
+    cell, a configuration that names no model): exit code 2, no result
+    line."""
 
 
 def log(msg: str) -> None:
@@ -46,9 +56,42 @@ def overlay(base: dict, over: dict) -> dict:
     return out
 
 
+def load_by_path(kind: str, name: str):
+    """The module ``<kind>/<name>.py``, loaded by its path: a later PR
+    brings one as a new file and edits none."""
+    path = os.path.join(HERE, kind, f"{name}.py")
+    if not NAME.match(name) or not os.path.isfile(path):
+        raise Refused(f"no file {kind}/{name}.py under {HERE}")
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark_{kind}_{name.replace('-', '_').replace('.', '_')}",
+        path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_model(cfg: dict, path: str):
+    """The adapter of the model a configuration names. A configuration
+    without ``model`` is refused: there is no default model."""
+    name = cfg.get("model")
+    if not isinstance(name, str):
+        raise Refused(f"{path} states no \"model\": the adapter under "
+                      "benchmark/models/ that builds and checks it")
+    return load_by_path("models", name)
+
+
+def need(model, api) -> None:
+    """Refuse a model whose adapter lacks what this kind of cell calls."""
+    missing = [name for name in api if not hasattr(model, name)]
+    if missing:
+        raise Refused(f"{model.__file__} lacks {missing}, which this "
+                      "kind of cell calls (benchmark/models/__init__.py)")
+
+
 def find_cell(bench: dict, name: str, rehearse: bool):
-    """(cell, configuration, traffic mix) for a workload's name. Under
-    ``rehearse`` each file's ``rehearsal`` group overrides its sizes."""
+    """(cell, configuration, traffic mix, model adapter) for a
+    workload's name. Under ``rehearse`` each file's ``rehearsal`` group
+    overrides its sizes."""
     from benchmark import traffic
 
     cell = next((w for w in bench["workloads"] if w["name"] == name), None)
@@ -58,11 +101,12 @@ def find_cell(bench: dict, name: str, rehearse: bool):
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(ROOT, conf["file"])) as f:
         cfg = json.load(f)
+    model = load_model(cfg, conf["file"])
     mix = traffic.load(cell["traffic"])
     if rehearse:
         cfg = overlay(cfg, cfg.get("rehearsal", {}))
         mix = overlay(mix, mix.get("rehearsal", {}))
-    return cell, cfg, mix
+    return cell, cfg, mix, model
 
 
 def metrics_for(bench: dict, cell: dict, group: str) -> list:
@@ -81,13 +125,7 @@ def metrics_for(bench: dict, cell: dict, group: str) -> list:
 
 def load_reader(name: str):
     """The reader ``metrics/<name>.py``: a module with ``read(obs)``."""
-    path = os.path.join(HERE, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"benchmark_metric_{name.replace('-', '_').replace('.', '_')}",
-        path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_by_path("metrics", name).read
 
 
 def read_per_layer(bench: dict, cell: dict, obs: dict,
@@ -180,65 +218,19 @@ def free_device_memory() -> None:
 
 
 # ---------------------------------------------------------------------
-# the program's net, holding the benchmark's weights
-# ---------------------------------------------------------------------
-def build_net(cfg: dict, seed: int, optimizer: dict = None):
-    """The program's flagship net at the configuration's sizes, with the
-    weights ``weights.py`` makes from the seed. With ``optimizer`` the
-    net gets its updater state (a training job); without, none (a
-    served model carries no moments)."""
-    import jax.numpy as jnp
-
-    from benchmark import weights
-    from deeplearning4j_tpu.models.zoo import transformer_lm_flagship
-    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-
-    if cfg["n_inner"] != 4 * cfg["n_embd"]:
-        raise ValueError("the program's block has a feed-forward of four "
-                         "times the width; the configuration says "
-                         f"{cfg['n_inner']} for {cfg['n_embd']}")
-    opt = optimizer or {}
-    conf = transformer_lm_flagship(
-        vocab=cfg["vocab_size"], width=cfg["n_embd"],
-        n_layers=cfg["n_layer"], n_heads=cfg["n_head"],
-        lr=opt.get("learning_rate", 3e-4),
-        warmup_steps=opt.get("lr_warmup_steps", 100),
-        total_steps=opt.get("lr_total_steps", 1000),
-        seed=seed & 0x7FFFFFFF)
-    for c in conf.confs:
-        c.compute_dtype = cfg["compute_dtype"]
-        for key in ("lr_min_fraction", "adam_mean_decay",
-                    "adam_var_decay", "epsilon"):
-            if key in opt:
-                setattr(c, key, opt[key])
-        if hasattr(c.layer, "stream_max_t"):
-            c.layer.stream_max_t = cfg["n_positions"]
-    net = MultiLayerNetwork(conf)
-    # adopt the seeded weights in place of init(): init() would draw its
-    # own leaf by leaf and allocate Adam's moments for a served model
-    net.params = weights.make_params(
-        seed, cfg["vocab_size"], cfg["n_embd"], cfg["n_inner"],
-        cfg["n_layer"])
-    net.state = {}
-    net.updater_state = {
-        str(i): (upd.init(net.params[str(i)]) if optimizer else {})
-        for i, upd in enumerate(net._updaters)}
-    net._initialized = True
-    if net._compute_dtype != jnp.dtype(cfg["compute_dtype"]):
-        raise ValueError(f"the net computes in {net._compute_dtype}, the "
-                         f"configuration states {cfg['compute_dtype']}")
-    return net
-
-
-# ---------------------------------------------------------------------
 # the traced sub-window
 # ---------------------------------------------------------------------
+def trace_dir(cell_name: str) -> str:
+    """Where a cell's traced run leaves its trace."""
+    return os.path.join(OUT_DIR, "trace", cell_name)
+
+
 class SubTrace:
     """Profile a stretch of the window and reduce it. ``start`` and
     ``stop`` are called from the thread that drives the window."""
 
     def __init__(self, cell_name: str):
-        self.dir = os.path.join(OUT_DIR, "trace", cell_name)
+        self.dir = trace_dir(cell_name)
         self.t_start = self.t_stop = None
         self.reduction = None
 
@@ -282,7 +274,12 @@ class SubTrace:
 # the result line
 # ---------------------------------------------------------------------
 def result_line(correct: bool, attempted: int, failed: int, metrics: dict,
-                device: dict, trace: SubTrace = None) -> str:
+                device: dict, compared: dict,
+                trace: SubTrace = None) -> str:
+    """The run's one result line. ``compared`` (each number that decided
+    ``correct``, with its limit) comes last in it and, before it, as the
+    last lines of standard error: what is kept of a run that was not
+    correct."""
     from benchmark import xplane
 
     out = {"correct": bool(correct), "attempted": int(attempted),
@@ -294,6 +291,13 @@ def result_line(correct: bool, attempted: int, failed: int, metrics: dict,
         out["device"]["window_s"] = trace.window_s
         out["breakdown"] = {"device_ops": xplane.top(red["ops"]),
                             "idle_gaps": xplane.top(red["gaps"])}
+    for name, row in compared.items():
+        print(f"compared {name}: {row['value']:.6g} (limit "
+              f"{row['limit']})", file=sys.stderr, flush=True)
+    # a number that is not finite (nothing to compare) has no JSON form
+    out["compared"] = {
+        k: {"value": row["value"] if math.isfinite(row["value"]) else None,
+            "limit": row["limit"]} for k, row in compared.items()}
     return json.dumps(out)
 
 

@@ -20,8 +20,6 @@ from before they existed), reduces to ``None``: no reader reports.
 from __future__ import annotations
 
 import bisect
-import glob
-import os
 import statistics
 
 from benchmark import common, xplane
@@ -220,23 +218,15 @@ def reduce_trace(path: str):
             "groups": groups, "clock": clock}
 
 
-def newest_trace():
-    """The trace ``SubTrace`` left: one process runs one cell, and
-    ``SubTrace.start`` empties that cell's directory, so the newest
-    file under ``OUT_DIR/trace`` is this run's."""
-    found = [p for d in glob.glob(os.path.join(common.OUT_DIR, "trace",
-                                               "*"))
-             for p in [xplane.find_trace(d)] if p]
-    return max(found, key=os.path.getmtime) if found else None
-
-
 def of(obs):
     """The run's reduction, made once and kept on ``obs`` for the
-    readers that share it. ``None`` where the run's own reduction
+    readers that share it, from the trace ``SubTrace`` left for the
+    cell (``obs["cell"]``). ``None`` where the run's own reduction
     (``obs["trace"]``) found no device."""
     if "hostspans" not in obs:
         red = None
-        path = newest_trace() if obs.get("trace") is not None else None
+        path = (xplane.find_trace(common.trace_dir(obs["cell"]))
+                if obs.get("trace") is not None else None)
         if path is not None:
             red = reduce_trace(path)
         if red is not None:

@@ -12,7 +12,8 @@ reduced, see the configuration files), as is recomputation.
 
 from __future__ import annotations
 
-BYTES_AT = {"bfloat16": 2, "float16": 2, "float32": 4}
+# readers reach both as ``obs["flops"].<name>``; they are every model's
+from benchmark.peaks import BYTES_AT, roofline_seconds  # noqa: F401
 
 
 def block_matmul_params(width: int, ffn: int) -> int:
@@ -70,8 +71,12 @@ def decode_step_bytes(width: int, ffn: int, n_layers: int, contexts,
     return weights + kv
 
 
-def roofline_seconds(flops: float, nbytes: float, peaks: dict):
-    """The least time the chip could take, and which peak bounds it."""
-    t_f = flops / peaks["bf16_flops"]
-    t_b = nbytes / peaks["hbm_bytes_per_s"]
-    return (t_f, "flops") if t_f >= t_b else (t_b, "bytes")
+def paged_live_bytes(width: int, n_layers: int, block_tokens: int,
+                     live_blocks: float, compute_dtype: str) -> float:
+    """Bytes the paged-attention kernel has to read in one decode step:
+    the keys and values of every live pool block (``block_tokens``
+    positions of ``width`` each), once per layer. ``live_blocks`` is
+    what one layer's call copies, the engine's ``paged_blocks_live``
+    for one dispatch."""
+    return (n_layers * live_blocks * block_tokens * 2 * width
+            * BYTES_AT[compute_dtype])

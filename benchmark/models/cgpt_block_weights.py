@@ -1,9 +1,9 @@
 """Seeded weights of the block stack, made on the device.
 
 The benchmark owns the weights: the program under test is handed them,
-and the plain reference (``reference.py``) makes the same values again
-from the same seed, layer by layer, so that it never holds more than
-one block and takes nothing the program has made.
+and the plain reference (``cgpt_block_reference.py``) makes the same
+values again from the same seed, layer by layer, so that it never holds
+more than one block and takes nothing the program has made.
 
 Values follow the GPT-2 convention the Cerebras-GPT paper trains from:
 matrices N(0, 0.02), the two matrices that write into the residual

@@ -5,7 +5,8 @@
         --seconds <s> --trace <0|1>
 
 The cell's configuration, traffic mix and per-layer readers are found by
-the names in ``BENCHMARK.json``. The last line of standard output is the
+the names in ``BENCHMARK.json``, the model's adapter by the name in the
+configuration file. The last line of standard output is the
 result, one JSON object; everything else goes on earlier lines. Without
 an accelerator (or with fewer chips than the cell asks for) the run
 exits 2 and prints no result. ``--rehearse`` is the tiny rehearsal for
@@ -48,11 +49,11 @@ def main(argv=None) -> int:
 
     try:
         bench = common.load_benchmark()
-        cell, cfg, mix = common.find_cell(bench, args.workload,
-                                          args.rehearse)
+        cell, cfg, mix, model = common.find_cell(bench, args.workload,
+                                                 args.rehearse)
         runner = importlib.import_module(
             f"benchmark.{RUNNERS[mix['kind']]}")
-        return runner.run(args, bench, cell, cfg, mix)
+        return runner.run(args, bench, cell, cfg, mix, model)
     except common.Refused as e:
         print(f"benchmark/run.py: {e}", file=sys.stderr, flush=True)
         return 2

@@ -13,6 +13,7 @@ sample of the requests the window finished, the longest among them.
 from __future__ import annotations
 
 import json
+import numbers
 import os
 import subprocess
 import sys
@@ -21,19 +22,32 @@ import time
 
 import numpy as np
 
-from benchmark import common, flops, loadgen, peaks, reference, stats
-from benchmark import traffic
+from benchmark import common, loadgen, peaks, stats, traffic
 from benchmark.common import log
 
-STAT_KEYS = ("tokens_generated", "decode_time_s", "chunks",
-             "occupancy_sum", "admitted", "prefill_tokens", "preempted",
-             "paged_admit_deferred", "requests_finished")
+
+def counters(engine) -> dict:
+    """Every numeric entry of ``engine.stats``, whole: a counter the
+    program gains reaches its reader with no edit here."""
+    # int and float first: that check is the cheap one, and a traced
+    # run makes it for every entry twice a round
+    return {k: v for k, v in engine.stats.items()
+            if isinstance(v, (int, float, numbers.Real))
+            and not isinstance(v, bool)}
+
+
+def moved(before: dict, after: dict) -> dict:
+    """What was added to each counter that changed."""
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
 
 
 class RoundProbe:
     """Wraps ``engine.step`` (traced runs only) and notes, after every
     round, when it ended, how many slots were live, their cached
-    lengths and the pool blocks that slot and pending tables hold."""
+    lengths, the pool blocks that slot and pending tables hold, and
+    what the round added to each of the engine's counters
+    (``counted``, the counters that moved)."""
 
     def __init__(self, engine):
         self.engine = engine
@@ -43,23 +57,25 @@ class RoundProbe:
 
     def _wrapped(self, results=None):
         eng = self.engine
-        chunks = eng.stats["chunks"]
+        before = counters(eng)
         out = self._step(results)
+        counted = moved(before, counters(eng))
         tabs = [t for t in eng._kv_tabs if t is not None]
         live = sum(len(t.blocks) for t in tabs) + sum(
             len(p.tab.blocks) for p in eng._pending if p.tab is not None)
         self.rounds.append({
-            "t": time.monotonic(), "decoded": eng.stats["chunks"] > chunks,
+            "t": time.monotonic(), "decoded": "chunks" in counted,
+            "counted": counted,
             "active": sum(1 for s in eng._slots if s is not None),
             "contexts": [t.length for t in tabs], "live_blocks": live})
         return out
 
 
-def build_gateway(cfg: dict, seed: int):
+def build_gateway(model, cfg: dict, seed: int):
     from deeplearning4j_tpu.serving import DecodeEngine, ServingGateway
 
-    net = common.build_net(cfg, seed)
-    log(f"net built: {cfg['n_layer']} layers, "
+    net = model.build_net(cfg, seed)
+    log(f"net built: {model.describe(cfg)}; "
         f"{common.bytes_in_use() / 2**30:.2f} GiB in use")
     dep = dict(cfg["deployment"])
     dep.pop("why", None)
@@ -107,7 +123,7 @@ def warm_up(gw, cfg: dict, mix: dict, seed: int) -> None:
 
 
 def snapshot(engine) -> dict:
-    snap = {k: engine.stats[k] for k in STAT_KEYS}
+    snap = counters(engine)
     snap["compiles"] = sum(engine.compile_counts().values())
     return snap
 
@@ -202,25 +218,27 @@ def gap_numbers(gaps) -> dict:
             "served_gap_mean": float(gaps.mean())}
 
 
-def check_outputs(samples, seed: int, cfg: dict) -> dict:
+def check_outputs(model, samples, seed: int, cfg: dict) -> dict:
     """Each number compared, beside its limit."""
     limits = cfg["check"]["limits"]
     if not samples:
         return {k: {"value": float("inf"), "limit": v, "ok": False,
                     "tokens": 0} for k, v in limits.items()}
-    gaps, _ = reference.served_gaps(seed, cfg, samples)
+    gaps, _ = model.served_gaps(seed, cfg, samples)
     return {k: {"value": v, "limit": limits[k],
                 "ok": bool(v <= limits[k]), "tokens": int(gaps.size)}
             for k, v in gap_numbers(gaps).items()}
 
 
-def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
+def run(args, bench: dict, cell: dict, cfg: dict, mix: dict,
+        model) -> int:
+    common.need(model, common.SERVE_API)
     device = common.setup_jax(cell, args.rehearse)
     seed = args.seed
     schedule = traffic.serving_schedule(mix, seed, float(args.seconds),
                                         cfg["vocab_size"])
     log(f"offered in the window: {traffic.offered(schedule)}")
-    gw = build_gateway(cfg, seed)
+    gw = build_gateway(model, cfg, seed)
     trace = common.SubTrace(cell["name"]) if args.trace else None
     with common.stopped_at_exit(gw.close):
         eng = gw.engine
@@ -242,7 +260,7 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
     errors = sorted({r["error"] for r in records if r["error"]})
     log(f"window: {n}, e2e {e2e}, setup {setup_s:.2f}s, generator late "
         f"max {max(late):.2f} ms, peak {peak / 2**30:.2f} GiB, engine "
-        f"delta { {k: after[k] - before[k] for k in before} }"
+        f"delta {moved(before, after)}"
         + (f", errors {errors[:3]}" if errors else ""))
 
     # ---- correct: free the pool and the weights, then the reference --
@@ -254,7 +272,7 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
     common.free_device_memory()
     log(f"program freed: {common.bytes_in_use() / 2**30:.2f} GiB in use")
     t0 = time.perf_counter()
-    rows = check_outputs(samples, seed, cfg)
+    rows = check_outputs(model, samples, seed, cfg)
     for name, row in rows.items():
         log(f"compared {name}: {row['value']:.6g} (limit {row['limit']}) "
             f"over {row['tokens']} served tokens of {len(samples)} "
@@ -264,7 +282,8 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
                    and n["failed"] == 0 and n["attempted"] > 0)
 
     if args.trace:
-        obs = {"kind": mix["kind"], "cfg": cfg, "mix": mix,
+        obs = {"kind": mix["kind"], "cell": cell["name"], "model": model,
+               "cfg": cfg, "mix": mix,
                "records": records, "window_s": float(args.seconds),
                "before": before, "after": after,
                "rounds": [r for r in probe.rounds
@@ -273,7 +292,7 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
                "traced_rounds": [r for r in probe.rounds if r["decoded"]
                                  and trace.t_start <= r["t"]
                                  <= trace.t_stop],
-               "late_ms": late, "stats": stats, "flops": flops,
+               "late_ms": late, "stats": stats, "flops": model.flops,
                "trace": trace.reduce() if not args.rehearse else None,
                "trace_window_s": trace.window_s,
                "peaks": (peaks.peaks_of(device["kind"])
@@ -289,7 +308,7 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
                    if m["name"] in values}
     device["memory_peak_bytes"] = peak
     print(common.result_line(correct, n["attempted"], n["failed"],
-                             metrics, device,
+                             metrics, device, rows,
                              trace if not args.rehearse else None),
           flush=True)
     return 0

@@ -28,41 +28,41 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import common, reference, traffic  # noqa: E402
+from benchmark import common, traffic  # noqa: E402
 from benchmark.common import log  # noqa: E402
 
 
-def train_seed(seed, cfg, mix, with_control, prec):
+def train_seed(model, seed, cfg, mix, with_control, prec):
     from benchmark import train_cell
 
     opt = cfg["optimizer"]
     n = int(mix["checked_steps"])
     pool = traffic.train_pool(mix, seed, cfg["vocab_size"])
-    net = common.build_net(cfg, seed, optimizer=opt)
-    feed = train_cell.Feed(pool, cfg["vocab_size"], 1)
-    program = train_cell.first_steps(net, feed, seed, cfg, n,
+    net = model.build_net(cfg, seed, optimizer=opt)
+    feed = train_cell.Feed(pool, model, cfg, 1)
+    program = train_cell.first_steps(net, model, feed, seed, cfg, n,
                                      opt["adam_mean_decay"])
     del net, feed
     common.free_device_memory()
-    ref = reference.train_reference(seed, cfg, opt, pool[:n], "highest")
+    ref = model.train_reference(seed, cfg, opt, pool[:n], "highest")
     limits = cfg["check"]["limits"]
     out = {"seed": seed, "program": {
         k: v["value"] for k, v in
         train_cell.compare(program, ref, limits).items()}}
     if with_control:
-        low = reference.train_reference(seed, cfg, opt, pool[:n], prec)
+        low = model.train_reference(seed, cfg, opt, pool[:n], prec)
         out["control"] = {k: v["value"] for k, v in
                           train_cell.compare(low, ref, limits).items()}
     return out
 
 
-def serve_seed(seed, cfg, mix, with_control, prec, seconds, t0):
+def serve_seed(model, seed, cfg, mix, with_control, prec, seconds, t0):
     from benchmark import serve_cell, stats
 
     args = argparse.Namespace(t0=t0, seed=seed, seconds=seconds, trace=0)
     schedule = traffic.serving_schedule(mix, seed, seconds,
                                         cfg["vocab_size"])
-    gw = serve_cell.build_gateway(cfg, seed)
+    gw = serve_cell.build_gateway(model, cfg, seed)
     with common.stopped_at_exit(gw.close):
         serve_cell.warm_up(gw, cfg, mix, seed)
         records, _, _, _ = serve_cell.measure(gw, schedule, args, mix,
@@ -72,7 +72,7 @@ def serve_seed(seed, cfg, mix, with_control, prec, seconds, t0):
     n = stats.counts(records)
     del gw
     common.free_device_memory()
-    prog, low = reference.served_gaps(
+    prog, low = model.served_gaps(
         seed, cfg, samples, control=prec if with_control else None)
     out = {"seed": seed, "counts": n, "served_tokens": int(prog.size),
            "program": serve_cell.gap_numbers(prog)}
@@ -90,7 +90,8 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     bench = common.load_benchmark()
-    cell, cfg, mix = common.find_cell(bench, args.workload, args.rehearse)
+    cell, cfg, mix, model = common.find_cell(bench, args.workload,
+                                             args.rehearse)
     common.setup_jax(cell, args.rehearse)
     seeds = [int(s) for s in args.seeds.split(",") if s]
     ctl = {int(s) for s in args.control_seeds.split(",") if s}
@@ -102,9 +103,9 @@ def main(argv=None) -> int:
     for seed in seeds:
         t0 = time.perf_counter()
         if mix["kind"] == "train_job":
-            row = train_seed(seed, cfg, mix, seed in ctl, prec)
+            row = train_seed(model, seed, cfg, mix, seed in ctl, prec)
         else:
-            row = serve_seed(seed, cfg, mix, seed in ctl, prec,
+            row = serve_seed(model, seed, cfg, mix, seed in ctl, prec,
                              args.seconds, t0)
         row["seconds"] = time.perf_counter() - t0
         rows.append(row)

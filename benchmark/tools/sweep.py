@@ -78,11 +78,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     args.t0, args.trace = time.perf_counter(), 0
     bench = common.load_benchmark()
-    cell, cfg, mix = common.find_cell(bench, args.workload, args.rehearse)
+    cell, cfg, mix, model = common.find_cell(bench, args.workload,
+                                             args.rehearse)
     common.setup_jax(cell, args.rehearse)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     path = os.path.join(ROOT, "chiprun_out", f"sweep_{cell['name']}.jsonl")
-    gw = serve_cell.build_gateway(cfg, args.seed)
+    gw = serve_cell.build_gateway(model, cfg, args.seed)
     with common.stopped_at_exit(gw.close):
         serve_cell.warm_up(gw, cfg, mix, args.seed)
         probe = serve_cell.RoundProbe(gw.engine)

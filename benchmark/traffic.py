@@ -281,11 +281,3 @@ def train_pool(mix: dict, seed: int, vocab: int) -> np.ndarray:
     n, b, t = mix["pool_batches"], mix["batch"], mix["seq_len"]
     toks = sample_chain(make_chain(vocab, seed), n * b, t, _rng(seed, 12))
     return toks.reshape(n, b, t + 1)
-
-
-def one_hot_batch(tokens: np.ndarray, vocab: int):
-    """``[B, T + 1]`` ids to the program's ``[B, V, T]`` uint8 features
-    (tokens 0..T-1) and labels (tokens 1..T)."""
-    eye = np.eye(vocab, dtype=np.uint8)
-    return (np.ascontiguousarray(eye[tokens[:, :-1]].transpose(0, 2, 1)),
-            np.ascontiguousarray(eye[tokens[:, 1:]].transpose(0, 2, 1)))

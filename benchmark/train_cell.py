@@ -15,18 +15,21 @@ import time
 
 import numpy as np
 
-from benchmark import common, flops, peaks, reference, traffic, weights
+from benchmark import common, norms, peaks, traffic
 from benchmark.common import log
 from benchmark.loadgen import Heartbeat
 
 
 class Feed:
     """The job's input pipeline: cycles through the seeded token pool,
-    one-hots a batch on the host and puts it on the device. Counts the
-    time the trainer waited for it."""
+    encodes a batch on the host as the model takes it (the adapter's
+    ``encode_batch``) and puts it on the device. Counts the time the
+    trainer waited for it."""
 
-    def __init__(self, pool: np.ndarray, vocab: int, scan_steps: int):
-        self.pool, self.vocab, self.k = pool, vocab, scan_steps
+    def __init__(self, pool: np.ndarray, model, cfg: dict,
+                 scan_steps: int):
+        self.pool, self.k = pool, scan_steps
+        self.model, self.cfg = model, cfg
         self.at = 0
         self.wait_s = 0.0
 
@@ -36,8 +39,8 @@ class Feed:
         t0 = time.perf_counter()
         feats, labels = [], []
         for _ in range(self.k):
-            f, y = traffic.one_hot_batch(
-                self.pool[self.at % len(self.pool)], self.vocab)
+            f, y = self.model.encode_batch(
+                self.pool[self.at % len(self.pool)], self.cfg)
             feats.append(f)
             labels.append(y)
             self.at += 1
@@ -47,32 +50,20 @@ class Feed:
         return out
 
 
-def program_norms(net, seed: int, cfg: dict, what: str, b1: float = 0.0):
+def program_norms(net, model, seed: int, cfg: dict, what: str,
+                  b1: float = 0.0):
     """Norms by leaf read from the program's state. ``grad``: the first
     gradient as Adam got it, ``m / (1 - b1)`` after one step. ``delta``:
-    each leaf's distance from its seeded start, the start made again
-    layer by layer."""
-    n_layers = cfg["n_layer"]
+    each leaf's distance from its seeded start, the start made again a
+    layer at a time (the adapter's ``start_params``)."""
     if what == "grad":
         tree = {si: st["m"] for si, st in net.updater_state.items()}
         return {k: v / (1.0 - b1) for k, v in
-                reference.flat_norms(reference.leaf_norms(tree)).items()}
-    key = weights.root_key(seed)
-    ends = weights.make_ends(key, cfg["vocab_size"], cfg["n_embd"],
-                             n_layers)
+                norms.flat_norms(norms.leaf_norms(tree)).items()}
     out = {}
-    for i in range(n_layers):
-        start = dict(weights.make_block(
-            weights.layer_key(key, i), cfg["n_embd"], cfg["n_inner"],
-            n_layers))
-        if i == 0:
-            start["Wi"] = ends["Wi"]
-        out.update(reference.flat_norms(reference.delta_norms(
-            {str(i): net.params[str(i)]}, {str(i): start})))
-    tail = {str(n_layers): {"g": ends["g"], "b": ends["b"]},
-            str(n_layers + 1): {"W": ends["W"], "b": ends["b_out"]}}
-    out.update(reference.flat_norms(reference.delta_norms(
-        {k: net.params[k] for k in tail}, tail)))
+    for start in model.start_params(seed, cfg):
+        out.update(norms.flat_norms(norms.delta_norms(
+            {k: net.params[k] for k in start}, start)))
     return out
 
 
@@ -98,8 +89,8 @@ def compare(program: dict, ref: dict, limits: dict) -> dict:
             for k, v in rows.items()}
 
 
-def first_steps(net, feed: Feed, seed: int, cfg: dict, n_steps: int,
-                b1: float) -> dict:
+def first_steps(net, model, feed: Feed, seed: int, cfg: dict,
+                n_steps: int, b1: float) -> dict:
     """Drive the program's object through its first steps by the
     window's call and feed; read each loss, the first gradient's norms
     and the parameters' change."""
@@ -109,13 +100,16 @@ def first_steps(net, feed: Feed, seed: int, cfg: dict, n_steps: int,
         scores = np.asarray(net.fit_scan(feats, labels), np.float64)
         out["losses"].extend(float(v) for v in scores)
         if s == 0:
-            out["grad_norms"] = program_norms(net, seed, cfg, "grad", b1)
+            out["grad_norms"] = program_norms(net, model, seed, cfg,
+                                              "grad", b1)
     out["losses"] = out["losses"][:n_steps]
-    out["delta_norms"] = program_norms(net, seed, cfg, "delta")
+    out["delta_norms"] = program_norms(net, model, seed, cfg, "delta")
     return out
 
 
-def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
+def run(args, bench: dict, cell: dict, cfg: dict, mix: dict,
+        model) -> int:
+    common.need(model, common.TRAIN_API)
     device = common.setup_jax(cell, args.rehearse)
     import jax
 
@@ -129,12 +123,13 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
     log(f"device ready ({time.perf_counter() - args.t0:.1f}s)")
     pool = traffic.train_pool(mix, seed, cfg["vocab_size"])
     log(f"token pool drawn ({time.perf_counter() - args.t0:.1f}s)")
-    net = common.build_net(cfg, seed, optimizer=opt)
-    log(f"net built: {cfg['n_layer']} layers, "
+    net = model.build_net(cfg, seed, optimizer=opt)
+    log(f"net built: {model.describe(cfg)}; "
         f"{common.bytes_in_use() / 2**30:.2f} GiB in use "
         f"({time.perf_counter() - args.t0:.1f}s)")
-    feed = Feed(pool, cfg["vocab_size"], k)
-    program = first_steps(net, feed, seed, cfg, int(mix["checked_steps"]),
+    feed = Feed(pool, model, cfg, k)
+    program = first_steps(net, model, feed, seed, cfg,
+                          int(mix["checked_steps"]),
                           opt["adam_mean_decay"])
     log(f"first steps done, losses {program['losses']} "
         f"({time.perf_counter() - args.t0:.1f}s); "
@@ -195,7 +190,7 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
     del net, step_fn, pending, scores, feats, labels
     common.free_device_memory()
     t0 = time.perf_counter()
-    ref = reference.train_reference(
+    ref = model.train_reference(
         seed, cfg, opt, pool[:int(mix["checked_steps"])], "highest")
     rows = compare(program, ref, cfg["check"]["limits"])
     finite = bool(np.isfinite(last_loss))
@@ -207,7 +202,8 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
     correct = finite and all(r["ok"] for r in rows.values())
 
     if args.trace:
-        obs = {"kind": "train_job", "cfg": cfg, "mix": mix,
+        obs = {"kind": "train_job", "cell": cell["name"], "model": model,
+               "cfg": cfg, "mix": mix,
                "window_s": window_s, "steps": calls * k,
                "tokens_per_s": tok_per_s, "data_wait_s": feed.wait_s,
                "compiles_before": compiles_before,
@@ -216,7 +212,7 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
                "trace_window_s": trace.window_s,
                "peaks": (peaks.peaks_of(device["kind"])
                          if not args.rehearse else None),
-               "flops": flops}
+               "flops": model.flops}
         metrics = common.read_per_layer(bench, cell, obs, args.rehearse)
     elif args.rehearse:
         metrics = {}
@@ -226,7 +222,7 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict) -> int:
                                "unit": m["unit"]}
                    for m in common.metrics_for(bench, cell, "end_to_end")}
     device["memory_peak_bytes"] = peak
-    print(common.result_line(correct, calls, 0, metrics, device,
+    print(common.result_line(correct, calls, 0, metrics, device, rows,
                              trace if not args.rehearse else None),
           flush=True)
     return 0

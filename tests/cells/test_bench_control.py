@@ -17,12 +17,15 @@ import os
 import numpy as np
 import pytest
 
-from benchmark import reference, traffic
+from benchmark import common, traffic
+from benchmark.models import cgpt_block_reference as block_reference
 from benchmark.train_cell import compare
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-CFG = dict(vocab_size=64, n_embd=256, n_inner=1024, n_layer=2, n_head=2)
+CFG = dict(model="cgpt_block", vocab_size=64, n_embd=256, n_inner=1024,
+           n_layer=2, n_head=2)
+MODEL = common.load_model(CFG, "this test's CFG")
 MIX = dict(pool_batches=3, batch=4, seq_len=64)
 LIMITS = {"loss_gap": 1.3e-4, "grad_norm_gap": 1.8e-3,
           "delta_norm_gap": 1.4e-3}
@@ -34,8 +37,8 @@ HYPER = json.load(open(os.path.join(
 def readings(request):
     seed = request.param
     pool = traffic.train_pool(MIX, seed, CFG["vocab_size"])
-    ref = reference.train_reference(seed, CFG, HYPER, pool, "highest")
-    return {prec: compare(reference.train_reference(
+    ref = MODEL.train_reference(seed, CFG, HYPER, pool, "highest")
+    return {prec: compare(MODEL.train_reference(
         seed, CFG, HYPER, pool, prec), ref, LIMITS)
         for prec in ("bf16", "fp8")}
 
@@ -63,19 +66,19 @@ def test_served_control_picks_tokens_below_the_reference_best():
     seed = 5
     rng = np.random.default_rng(seed)
     prompt = rng.integers(0, 64, 40).tolist()
-    ref = reference.forward_logits(
+    ref = block_reference.forward_logits(
         seed, CFG, np.asarray([prompt + [0] * 88]), "highest")
     served = []
     seq = list(prompt)
     for _ in range(48):     # greedy by the reference itself
-        logits = reference.forward_logits(
+        logits = block_reference.forward_logits(
             seed, CFG, np.asarray([seq + [0] * (128 - len(seq))]),
             "highest")
         served.append(int(logits[0, len(seq) - 1].argmax()))
         seq.append(served[-1])
     assert ref.shape == (1, 128, 64)
-    prog, ctrl = reference.served_gaps(seed, CFG, [(prompt, served)],
-                                       control="fp8")
+    prog, ctrl = MODEL.served_gaps(seed, CFG, [(prompt, served)],
+                                   control="fp8")
     assert prog.shape == ctrl.shape == (48,)
     assert prog.max() < 1e-5
     assert ctrl.max() >= prog.max()

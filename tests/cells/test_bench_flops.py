@@ -1,9 +1,11 @@
-"""Operation and byte counts against hand counts for one block, and the
-peaks table."""
+"""Operation and byte counts against hand counts for one block, the
+peaks table, and the two readers of the paged kernel's walk on
+hand-made observations."""
 
 import pytest
 
-from benchmark import flops, peaks
+from benchmark import common, peaks
+from benchmark.models import cgpt_block_flops as flops
 
 D, F = 2048, 8192
 
@@ -75,3 +77,84 @@ def test_v5e_peaks_and_their_source():
 def test_unknown_device_kind_is_an_error(kind):
     with pytest.raises(KeyError, match="no published peaks"):
         peaks.peaks_of(kind)
+
+
+# ---- the paged kernel's walk ------------------------------------------
+def test_paged_live_bytes_by_hand():
+    # 100 live blocks of 16 positions: K and V, 2048 wide, in 24 layers
+    assert flops.paged_live_bytes(D, 24, 16, 100, "bfloat16") == \
+        24 * 100 * 16 * 2 * 2048 * 2
+    assert flops.paged_live_bytes(D, 24, 16, 100, "float32") == \
+        2 * flops.paged_live_bytes(D, 24, 16, 100, "bfloat16")
+    # the same bytes decode_step_bytes counts beside the weights
+    assert flops.paged_live_bytes(D, 24, 16, 100, "bfloat16") == \
+        flops.decode_step_bytes(D, F, 24, [1600], "bfloat16") \
+        - flops.decode_step_bytes(D, F, 24, [], "bfloat16")
+
+
+KERNEL = "_paged_flash_attention_tpu_custom_call"
+CFG = {"n_embd": D, "n_inner": F, "n_layer": 24,
+       "compute_dtype": "bfloat16",
+       "deployment": {"block_tokens": 16, "decode_chunk": 8}}
+
+
+def walk_obs(**over):
+    """A served run whose traced stretch holds four decode programs and
+    two probed rounds of 100 and 140 live blocks a call."""
+    obs = {"kind": "open_loop", "cfg": CFG, "flops": flops,
+           "peaks": peaks.peaks_of("TPU v5 lite"),
+           "before": {"paged_blocks_live": 1000,
+                      "paged_blocks_walked": 1600},
+           "after": {"paged_blocks_live": 4000,
+                     "paged_blocks_walked": 5600},
+           "traced_rounds": [
+               {"counted": {"chunks": 1, "paged_blocks_live": 100}},
+               {"counted": {"admitted": 1}},      # an admission only
+               {"counted": {"chunks": 1, "paged_blocks_live": 140}}],
+           "trace": {"ops": {KERNEL: 0.05, "fusion_fusion": 1.0},
+                     "programs": {"jit_decode": {"count": 4,
+                                                 "seconds": 0.4}}}}
+    obs.update(over)
+    return obs
+
+
+def test_walk_live_share_is_live_over_walked_in_the_window():
+    read = common.load_reader("paged_walk_live_share")
+    assert read(walk_obs()) == pytest.approx(100.0 * 3000 / 4000)
+
+
+def test_paged_roofline_by_hand():
+    # 120 blocks a call on average: bytes bound; 8 steps a round, four
+    # rounds in the trace, against 0.05 s of the kernel's device time
+    per_step = 24 * 120 * 16 * 2 * 2048 * 2 / 819e9
+    assert common.load_reader("paged_attn_roofline")(walk_obs()) == \
+        pytest.approx(100.0 * per_step * 8 * 4 / 0.05)
+
+
+def test_a_float32_pool_is_counted_at_the_stated_dtype():
+    """The bytes follow the configuration, not the program: a kernel
+    that streams a float32 pool at the HBM peak reads 50."""
+    per_step_f32 = flops.paged_live_bytes(D, 24, 16, 120, "float32")
+    at_peak = per_step_f32 * 8 * 4 / 819e9
+    obs = walk_obs(trace={"ops": {KERNEL: at_peak}, "programs": {
+        "jit_decode": {"count": 4, "seconds": 1.0}}})
+    assert common.load_reader("paged_attn_roofline")(obs) == \
+        pytest.approx(50.0)
+
+
+@pytest.mark.parametrize("name,over", [
+    ("paged_walk_live_share", {"kind": "train_job"}),
+    ("paged_walk_live_share", {"before": {}, "after": {}}),
+    ("paged_walk_live_share",
+     {"after": {"paged_blocks_live": 1000, "paged_blocks_walked": 1600}}),
+    ("paged_attn_roofline", {"kind": "train_job"}),
+    ("paged_attn_roofline", {"trace": None}),
+    ("paged_attn_roofline", {"peaks": None}),
+    ("paged_attn_roofline", {"traced_rounds": []}),
+    ("paged_attn_roofline", {"trace": {"ops": {"fusion_fusion": 1.0},
+                                       "programs": {}}}),
+], ids=["share-training", "share-no-counters", "share-no-walk",
+        "roofline-training", "roofline-untraced", "roofline-rehearsal",
+        "roofline-no-rounds", "roofline-no-kernel"])
+def test_walk_readers_report_nothing_where_there_is_nothing(name, over):
+    assert common.load_reader(name)(walk_obs(**over)) is None

@@ -6,6 +6,7 @@ a two-layer engine behind the gateway serving a few streamed requests;
 recorded by ``benchmark/tools/record_span_fixture.py``)."""
 
 import os
+import shutil
 
 import jax.profiler
 import pytest
@@ -240,6 +241,30 @@ def test_shares_are_of_the_traced_stretch():
         pytest.approx(5.0)
     assert common.load_reader("idle_unattributed_share")(obs) == \
         pytest.approx(0.2)
+
+
+def test_the_reduction_is_of_the_cells_own_trace(tmp_path, monkeypatch):
+    """``of`` reads the trace ``SubTrace`` left for ``obs["cell"]``,
+    not whatever cell's trace is newest under the output directory."""
+    if not os.path.exists(FIXTURE):
+        pytest.skip("no recorded trace")
+    monkeypatch.setattr(common, "OUT_DIR", str(tmp_path))
+    for cell, content in (("mine.cell", FIXTURE), ("other.cell", None)):
+        run_dir = os.path.join(common.trace_dir(cell), "plugins",
+                               "profile", "2026_01_01")
+        os.makedirs(run_dir)
+        path = os.path.join(run_dir, "host.xplane.pb")
+        if content:
+            shutil.copy(content, path)
+        else:                      # newer, and no trace at all
+            with open(path, "wb") as f:
+                f.write(b"not a trace")
+    obs = {"kind": "open_loop", "cell": "mine.cell", "trace": {},
+           "trace_window_s": 3.0}
+    red = hostspans.of(obs)
+    assert red is not None and red["clock"]["checked"] >= 3
+    assert hostspans.of({"kind": "open_loop", "cell": "no.such-cell",
+                         "trace": {}}) is None
 
 
 # ---- the trace recorded on the chip ----------------------------------

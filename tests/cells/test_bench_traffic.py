@@ -7,7 +7,7 @@ from collections import Counter
 
 import pytest
 
-from benchmark import traffic
+from benchmark import common, traffic
 
 SEEDS = [0, 1, 2, 3, 7, 11, 1234, 99991, 2**31 + 5, 3000000019]
 MIX = traffic.load("chat-steady")
@@ -216,7 +216,9 @@ def test_train_pool_rows_all_differ(seed):
     rows = {tuple(r) for r in pool.reshape(12, 17).tolist()}
     assert len(rows) == 12
     assert pool.min() >= 0 and pool.max() < 32
-    f, y = traffic.one_hot_batch(pool[0], 32)
+    # the block's adapter one-hots a batch for the program's net
+    f, y = common.load_by_path("models", "cgpt_block").encode_batch(
+        pool[0], {"vocab_size": 32})
     assert f.shape == y.shape == (4, 32, 16)
     assert (f.argmax(axis=1) == pool[0][:, :-1]).all()
     assert (y.argmax(axis=1) == pool[0][:, 1:]).all()
