@@ -322,6 +322,90 @@ def transformer_lm_flagship(
     return b.remat(remat).build()
 
 
+def granite_moe_hybrid_lm(
+    vocab_size: int = 64,
+    hidden_size: int = 64,
+    layer_types: Sequence[str] = ("mamba", "attention", "mamba"),
+    num_attention_heads: int = 4,
+    num_key_value_heads: int = 2,
+    attention_multiplier: float = 0.0,
+    mamba_n_heads: int = 8,
+    mamba_d_head: int = 16,
+    mamba_d_state: int = 16,
+    mamba_n_groups: int = 1,
+    mamba_d_conv: int = 4,
+    mamba_chunk_size: int = 256,
+    num_local_experts: int = 8,
+    num_experts_per_tok: int = 2,
+    intermediate_size: int = 32,
+    shared_intermediate_size: int = 64,
+    experts_held=None,
+    embedding_multiplier: float = 1.0,
+    residual_multiplier: float = 1.0,
+    logits_scaling: float = 1.0,
+    rms_norm_eps: float = 1e-5,
+    max_position_embeddings: int = 512,
+    initializer_range: float = 0.02,
+    dtype: str = "float32",
+    seed: int = 12345,
+):
+    """A ``granitemoehybrid`` LM (HF ``GraniteMoeHybridForCausalLM``),
+    served only, under its config's own key names: the token embedding
+    (``EmbeddingLayer``, ``sequence``), one ``HybridMoeBlock`` a ``layer_types`` entry (``"mamba"`` = the
+    Mamba-2 mixer, ``"attention"`` = grouped-KV attention with no
+    positional term), a tied head (nn/layers/hybrid.py).
+    ``num_local_experts`` is the router's width; ``experts_held`` the
+    ``[lo, hi)`` of them whose experts this chip holds (None = all);
+    ``vocab_size`` the rows of the vocabulary held. ``dtype`` is the
+    resident and compute dtype (the published one is bfloat16).
+    ``max_position_embeddings`` bounds the attention cache window."""
+    from deeplearning4j_tpu.nn.conf.distribution import NormalDistribution
+    from deeplearning4j_tpu.nn.layers.hybrid import (
+        HybridMoeBlock,
+        TiedLMHead,
+    )
+
+    mixers = {"mamba": "mamba2", "attention": "attention"}
+    b = (
+        NeuralNetConfiguration.Builder()
+        .seed(seed)
+        .updater(Updater.ADAM)
+        .activation("identity")
+        .list()
+    )
+    b.layer(0, L.EmbeddingLayer(
+        n_in=vocab_size, n_out=hidden_size, sequence=True,
+        multiplier=embedding_multiplier,
+        weight_init=WeightInit.DISTRIBUTION,
+        dist=NormalDistribution(0.0, initializer_range)))
+    for i, kind in enumerate(layer_types):
+        b.layer(i + 1, HybridMoeBlock(
+            n_in=hidden_size, n_out=hidden_size, mixer=mixers[kind],
+            rms_eps=rms_norm_eps,
+            residual_multiplier=residual_multiplier,
+            n_heads=num_attention_heads,
+            n_kv_heads=num_key_value_heads,
+            attention_multiplier=attention_multiplier,
+            stream_max_t=max_position_embeddings,
+            ssm_heads=mamba_n_heads, ssm_d_head=mamba_d_head,
+            ssm_d_state=mamba_d_state, ssm_groups=mamba_n_groups,
+            ssm_d_conv=mamba_d_conv, ssm_chunk=mamba_chunk_size,
+            n_router=num_local_experts, top_k=num_experts_per_tok,
+            d_expert=intermediate_size,
+            d_shared=shared_intermediate_size,
+            experts_held=(None if experts_held is None
+                          else tuple(experts_held)),
+            init_std=initializer_range))
+    b.layer(len(layer_types) + 1, TiedLMHead(
+        n_in=hidden_size, n_out=vocab_size, tie_to=0,
+        logits_scaling=logits_scaling, rms_eps=rms_norm_eps,
+        activation="softmax", loss_function=LossFunction.MCXENT))
+    conf = b.build()
+    for c in conf.confs:
+        c.dtype = dtype
+    return conf
+
+
 def moe_transformer_lm(
     n_in: int = 64,
     width: int = 128,

@@ -202,7 +202,20 @@ class ImageLSTM(BaseRecurrentLayer):
 @dataclasses.dataclass
 class EmbeddingLayer(FeedForwardLayer):
     """Index -> dense row lookup (reference nn/conf/layers/EmbeddingLayer.java).
-    On TPU this is a one-hot matmul / ``take`` that XLA lowers to a gather."""
+    On TPU this is a one-hot matmul / ``take`` that XLA lowers to a gather.
+
+    ``sequence``: a language model's first layer. ``[N, T]`` token ids ->
+    ``[N, n_out, T]``, a row of ``W`` a token times ``multiplier``, no
+    bias and no activation. A net whose first layer this is takes ids,
+    not one-hot columns (``takes_token_ids``: the serving engine
+    gathers, ``MultiLayerNetwork.generate`` refuses it)."""
+
+    sequence: bool = False
+    multiplier: float = 1.0
+
+    @property
+    def takes_token_ids(self) -> bool:
+        return self.sequence
 
 
 @register_bean("ConvolutionLayer")

@@ -21,6 +21,7 @@ from deeplearning4j_tpu.nn.layers import (
     convolution,
     dense,
     embedding,
+    hybrid,
     moe,
     normalization,
     pretrain,
@@ -47,6 +48,8 @@ _IMPLS = {
     attention.MultiHeadSelfAttention: attention.AttentionImpl,
     attention.TransformerBlock: attention.TransformerBlockImpl,
     moe.MoeDense: moe.MoeDenseImpl,
+    hybrid.HybridMoeBlock: hybrid.HybridMoeBlockImpl,
+    hybrid.TiedLMHead: hybrid.TiedLMHeadImpl,
 }
 
 

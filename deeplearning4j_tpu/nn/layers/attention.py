@@ -117,6 +117,10 @@ class MultiHeadSelfAttention(BaseRecurrentLayer):
     # tokens older than this many steps fall out of the window
     stream_max_t: int = 512
 
+    #: what the serving engine reads off a recurrent bean: "kv" = an
+    #: attention cache it may page; "slot" = one state row a slot
+    serving_state = "kv"
+
 
 class AttentionImpl(LayerImplBase):
     @classmethod
@@ -230,10 +234,14 @@ class AttentionImpl(LayerImplBase):
                     f"sp_mode {lc.sp_mode!r}: expected 'ring' or "
                     "'ulysses'")
             return o, None
+        # grouped KV heads: the dense and flash programs take one key
+        # head a query head, so the group's keys are repeated for them;
+        # the cache below keeps the KV heads only
+        ke, ve = _repeat_kv_heads(q, k, v)
         if _should_use_flash(lc.use_flash, q, mask):
-            o = _flash_attention(q, k, v, lc.causal)
+            o = _flash_attention(q, ke, ve, lc.causal)
         else:
-            o = _dense_attention(q, k, v, lc.causal, mask)
+            o = _dense_attention(q, ke, ve, lc.causal, mask)
         new_state = None
         if not train:
             # Prefill: expose the (right-aligned, fixed-size) KV
@@ -344,7 +352,8 @@ class AttentionImpl(LayerImplBase):
         every block written here has refcount 1 (copy-on-write happens
         before dispatch), so shared prefix blocks are never mutated."""
         tm = lc.stream_max_t
-        b, h, t, dh = q.shape
+        b, hq, t, dh = q.shape
+        h = k.shape[1]          # the pool holds the KV heads
         if not lc.causal:
             raise ValueError(
                 "non-causal (bidirectional) attention cannot stream: "
@@ -386,7 +395,9 @@ class AttentionImpl(LayerImplBase):
         bb = jnp.take_along_axis(base, g % s_ring, axis=1)
         bval = (tb >= 0) & (bb == g * bt)          # ring slot holds g
         toggle = getattr(lc, "use_flash_paged", None)
-        if _should_use_flash_paged(toggle, bt, dh, t):
+        # the kernel scores one key head a query head: grouped KV heads
+        # take the gather program below (ROADMAP.md M2)
+        if hq == h and _should_use_flash_paged(toggle, bt, dh, t):
             # fused pallas kernel (ISSUE 12; ISSUE 25: a grid step
             # is a compute block of several table entries): each row
             # walks its block list INSIDE the kernel, copying only
@@ -435,7 +446,7 @@ class AttentionImpl(LayerImplBase):
                  & (kpos >= floor[:, None]))
         ev = jnp.where(vlive[:, None, :, None], ev, 0)
         qpos = filled[:, None] + jnp.arange(t)[None, :]
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, ek) / jnp.sqrt(
+        scores = _grouped_scores(q, ek) / jnp.sqrt(
             jnp.asarray(dh, q.dtype))
         ok = (kval[:, None, :]
               & (kpos[:, None, :] <= qpos[:, :, None])      # causal
@@ -444,7 +455,7 @@ class AttentionImpl(LayerImplBase):
         neg = jnp.asarray(-1e30, q.dtype)
         scores = jnp.where(ok[:, None], scores, neg)
         w = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", w, ev)
+        o = _grouped_values(w, ev)
         return o, {"pk": pkf.reshape(nb, bt, h, dh),
                    "pv": pvf.reshape(nb, bt, h, dh),
                    "table": table, "base": base, "floor": floor,
@@ -509,7 +520,7 @@ class AttentionImpl(LayerImplBase):
         else:
             lengths = jnp.sum(mask.astype(jnp.int32), axis=1)  # [N]
         filled = jnp.minimum(prev + lengths, tm)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, ek) / jnp.sqrt(
+        scores = _grouped_scores(q, ek) / jnp.sqrt(
             jnp.asarray(q.shape[-1], q.dtype)
         )
         j = jnp.arange(tm + t)                    # extension positions
@@ -533,7 +544,7 @@ class AttentionImpl(LayerImplBase):
         neg = jnp.asarray(-1e30, q.dtype)
         scores = jnp.where(ok[:, None], scores, neg)
         w = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", w, ev)
+        o = _grouped_values(w, ev)
         if mask is None:
             ck, cv = ek[:, :, -tm:, :], ev[:, :, -tm:, :]
         else:
@@ -577,6 +588,8 @@ class TransformerBlock(BaseRecurrentLayer):
     use_flash: Optional[bool] = None
     use_flash_paged: Optional[object] = None
     stream_max_t: int = 512
+
+    serving_state = "kv"
 
 
 def _layer_norm(x, g, b, eps=1e-5):
@@ -1179,6 +1192,40 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
         interpret=interpret,
     )(bid, bval, lo_blk, floor, filled, lengths, q, pk, pv)
     return jnp.swapaxes(o, 1, 2) if short else o
+
+
+def _repeat_kv_heads(q, k, v):
+    """``k``/``v`` with each KV head repeated for the query heads it
+    serves; the arrays themselves where the counts agree."""
+    group = q.shape[1] // k.shape[1]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+
+
+def _grouped_scores(q, k):
+    """``q k^T`` ``[B, Hq, Q, K]`` for ``q`` ``[B, Hq, Q, dh]`` against
+    ``k`` ``[B, Hkv, K, dh]``: query head ``h`` reads KV head
+    ``h // (Hq / Hkv)``. Equal counts keep the plain product."""
+    b, hq, t, dh = q.shape
+    hk = k.shape[1]
+    if hq == hk:
+        return jnp.einsum("bhqd,bhkd->bhqk", q, k)
+    s = jnp.einsum("bhgqd,bhkd->bhgqk",
+                   q.reshape(b, hk, hq // hk, t, dh), k)
+    return s.reshape(b, hq, t, k.shape[2])
+
+
+def _grouped_values(w, v):
+    """``w v`` for weights ``[B, Hq, Q, K]`` and ``v`` ``[B, Hkv, K,
+    dh]``, the counterpart of :func:`_grouped_scores`."""
+    b, hq, t, nk = w.shape
+    hk = v.shape[1]
+    if hq == hk:
+        return jnp.einsum("bhqk,bhkd->bhqd", w, v)
+    o = jnp.einsum("bhgqk,bhkd->bhgqd",
+                   w.reshape(b, hk, hq // hk, t, nk), v)
+    return o.reshape(b, hq, t, v.shape[3])
 
 
 def _dense_attention(q, k, v, causal, mask):

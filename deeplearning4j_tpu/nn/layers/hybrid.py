@@ -1,0 +1,300 @@
+"""The layers of a hybrid state-space / attention mixture-of-experts LM
+(the ``granitemoehybrid`` family): after the token embedding
+(nn/layers/embedding.py, ``sequence``), a block whose mixer is either
+grouped-KV attention or Mamba-2 and whose feed-forward is a dropless
+share of gated experts, and a tied head out.
+
+Both keep the framework's ``[N, C, T]`` recurrent layout at their
+edges, so they compose with ``MultiLayerNetwork._forward_fn``, the
+streaming state channel (``rnn_state``) and the serving engine::
+
+    x = E[ids] * embedding_multiplier      (EmbeddingLayer, ``sequence``)
+    x = x + r * mixer(RMSNorm(x))                          (HybridMoeBlock)
+    h = RMSNorm(x);  x = x + r * (routed(h) + shared(h))
+    logits = RMSNorm(x) @ E^T / logits_scaling             (TiedLMHead)
+
+The attention mixer has no positional term and scales its scores by
+``attention_multiplier`` (not ``1 / sqrt(head)``); its ``n_kv_heads``
+key/value heads each serve ``n_heads / n_kv_heads`` query heads, and the
+cache holds the KV heads only (``AttentionImpl._attend_core``). The
+Mamba-2 mixer is nn/layers/mamba2.py, the experts nn/layers/moe.py
+``dropless_moe``.
+
+**State, rows and counters.** A block's streaming state is its mixer's
+and nothing else (the attention cache, or ``{"conv", "ssm"}``). What a
+caller that batches slots has to say and wants to know travels beside
+it, as two keywords of ``apply`` that ``_forward_fn`` hands to a layer
+whose bean has ``wants_live``: ``live`` ``[B]``, which rows exist (an
+idle serving slot routes to no expert and its recurrent state is left
+alone), and ``counters``, a dict the block adds this call's int32
+scalars into (``moe_picks``, ``moe_picks_held``,
+``moe_experts_touched``, ``moe_load_max``, ``moe_layer_steps``,
+``ssm_state_rows``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.nn.conf.layers import (
+    BaseOutputLayer,
+    BaseRecurrentLayer,
+)
+from deeplearning4j_tpu.nn.conf.serde import register_bean
+from deeplearning4j_tpu.nn.layers import mamba2
+from deeplearning4j_tpu.nn.layers.attention import AttentionImpl
+from deeplearning4j_tpu.nn.layers.base import LayerImplBase
+from deeplearning4j_tpu.nn.layers.moe import dropless_moe, moe_shapes
+
+MIXERS = ("attention", "mamba2")
+
+
+def rms_norm(x, w, eps: float):
+    """``x / sqrt(mean(x^2) + eps) * w`` over the last axis, the mean
+    square in float32."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _residual(x, branch, multiplier: float):
+    """``x + multiplier * branch``, the product and the sum in float32
+    and ONE rounding to ``x``'s dtype (a bfloat16 ``0.22`` would be off
+    by a part in 800 in every layer)."""
+    return (x.astype(jnp.float32)
+            + multiplier * branch.astype(jnp.float32)).astype(x.dtype)
+
+
+def _normal(key, shape, std, dtype):
+    return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+
+# ---------------------------------------------------------------------
+# the tied head out
+# ---------------------------------------------------------------------
+@register_bean("TiedLMHead")
+@dataclasses.dataclass
+class TiedLMHead(BaseOutputLayer):
+    """Conf bean: ``softmax(RMSNorm(x) @ E^T / logits_scaling)`` over
+    ``[N, n_in, T]``, ``E`` being layer ``tie_to``'s ``W`` ``[n_out,
+    n_in]`` (``MultiLayerNetwork._forward_fn`` hands it over as
+    ``params["E"]``; the head's own leaf is the norm's weight)."""
+
+    tie_to: int = 0
+    logits_scaling: float = 1.0
+    rms_eps: float = 1e-5
+
+
+class TiedLMHeadImpl(LayerImplBase):
+    @classmethod
+    def init(cls, key, conf, dtype=jnp.float32) -> dict:
+        return {"norm_w": jnp.ones((conf.layer.n_in,), dtype)}
+
+    @classmethod
+    def logits(cls, conf, params, x):
+        lc = conf.layer
+        hn = rms_norm(jnp.transpose(x, (0, 2, 1)), params["norm_w"],
+                      lc.rms_eps)
+        e = params["E"]
+        z = jax.lax.dot_general(
+            hn.astype(e.dtype), e, (((2,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [N, T, V]
+        return z / lc.logits_scaling
+
+    @classmethod
+    def apply(cls, conf, params, x, state=None, train=False, rng=None,
+              mask=None):
+        probs = jax.nn.softmax(cls.logits(conf, params, x), axis=-1)
+        return jnp.transpose(probs, (0, 2, 1)), state
+
+
+# ---------------------------------------------------------------------
+# the block
+# ---------------------------------------------------------------------
+@register_bean("HybridMoeBlock")
+@dataclasses.dataclass
+class HybridMoeBlock(BaseRecurrentLayer):
+    """Conf bean: one pre-RMSNorm residual block of width ``n_in ==
+    n_out``: ``mixer`` ("attention" or "mamba2"), then dropless top-k
+    routing over ``n_router`` outputs of which this chip holds the
+    experts ``experts_held = [lo, hi)`` (None = all), plus a shared
+    expert of width ``d_shared`` (0 = none)."""
+
+    mixer: str = "attention"
+    rms_eps: float = 1e-5
+    residual_multiplier: float = 1.0
+    # attention mixer
+    n_heads: int = 4
+    n_kv_heads: int = 4
+    d_head: int = 0                 # 0 => n_out / n_heads
+    attention_multiplier: float = 0.0   # 0 => 1 / sqrt(d_head)
+    causal: bool = True
+    use_flash: Optional[bool] = None
+    use_flash_paged: Optional[object] = None
+    stream_max_t: int = 512
+    # mamba2 mixer
+    ssm_heads: int = 8
+    ssm_d_head: int = 16
+    ssm_d_state: int = 16
+    ssm_groups: int = 1
+    ssm_d_conv: int = 4
+    ssm_chunk: int = 256
+    # experts
+    n_router: int = 8
+    top_k: int = 2
+    d_expert: int = 0
+    d_shared: int = 0
+    experts_held: Optional[tuple] = None
+    #: the two Pallas kernels (the one-step state update, the grouped
+    #: expert product): None = on a TPU, the plain programs elsewhere;
+    #: True / False force; "interpret" = Pallas interpret mode
+    use_kernels: Optional[object] = None
+    init_std: float = 0.02
+
+    #: what the serving engine reads off a bean (it never asks for a
+    #: class): the forward pass takes ``live`` and ``counters``; the
+    #: layer is not sharded over ``tp``
+    wants_live = True
+    shards_over_tp = False
+
+    @property
+    def serving_state(self) -> str:
+        """``"kv"``: an attention cache, paged by the engine;
+        ``"slot"``: one row a slot, carried whole (the Mamba-2 mixer's
+        convolution tail and SSM state)."""
+        return "kv" if self.mixer == "attention" else "slot"
+
+    @property
+    def held(self):
+        lo, hi = self.experts_held or (0, self.n_router)
+        return int(lo), int(hi)
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_head or self.n_out // self.n_heads
+
+
+class HybridMoeBlockImpl(LayerImplBase):
+    @classmethod
+    def shapes(cls, lc) -> dict:
+        d = lc.n_out
+        if lc.mixer == "attention":
+            dh = lc.head_dim
+            mix = {"Wq": (d, lc.n_heads * dh), "Wk": (d, lc.n_kv_heads * dh),
+                   "Wv": (d, lc.n_kv_heads * dh),
+                   "Wo": (lc.n_heads * dh, d)}
+        elif lc.mixer == "mamba2":
+            mix = mamba2.mixer_shapes(d, lc.ssm_heads, lc.ssm_d_head,
+                                      lc.ssm_d_state, lc.ssm_groups,
+                                      lc.ssm_d_conv)
+        else:
+            raise ValueError(
+                f"mixer {lc.mixer!r}: expected one of {MIXERS}")
+        lo, hi = lc.held
+        return {"norm1_w": (d,), **mix, "norm2_w": (d,),
+                **moe_shapes(d, lc.n_router, hi - lo, lc.d_expert,
+                             lc.d_shared)}
+
+    @classmethod
+    def init(cls, key, conf, dtype=jnp.float32) -> dict:
+        """The family's initialisation: N(0, ``init_std``) matrices,
+        unit norm weights; Mamba-2's own for what its config does not
+        carry (``A`` uniform in [1, 16], ``dt`` log-uniform in
+        [1e-3, 1e-1] through the inverse softplus, ``D`` = 1)."""
+        lc = conf.layer
+        if lc.n_in != lc.n_out:
+            raise ValueError(
+                f"HybridMoeBlock needs n_in == n_out, got "
+                f"{lc.n_in}/{lc.n_out}")
+        params = {}
+        for j, (name, shape) in enumerate(cls.shapes(lc).items()):
+            k = jax.random.fold_in(key, j)
+            if name in ("norm1_w", "norm2_w", "norm_w", "D"):
+                params[name] = jnp.ones(shape, dtype)
+            elif name == "A_log":
+                params[name] = jnp.log(jax.random.uniform(
+                    k, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
+            elif name == "dt_bias":
+                dt = jnp.exp(jax.random.uniform(
+                    k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+                params[name] = (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+            elif name == "conv_b":
+                params[name] = jnp.zeros(shape, dtype)
+            else:
+                params[name] = _normal(k, shape, lc.init_std, dtype)
+        return params
+
+    @classmethod
+    def _attention(cls, lc, params, hn, state, train, mask):
+        n, t, _ = hn.shape
+        dh = lc.head_dim
+
+        def heads(w, h):
+            y = hn @ w
+            return jnp.transpose(y.reshape(n, t, h, dh), (0, 2, 1, 3))
+
+        q = heads(params["Wq"], lc.n_heads)
+        k = heads(params["Wk"], lc.n_kv_heads)
+        v = heads(params["Wv"], lc.n_kv_heads)
+        if lc.attention_multiplier:
+            # the core divides by sqrt(d_head): hand it q scaled so
+            # that the scores come out times the multiplier
+            q = (q.astype(jnp.float32) * (
+                lc.attention_multiplier * math.sqrt(dh))).astype(q.dtype)
+        o, state = AttentionImpl._attend_core(lc, q, k, v, state, train,
+                                              mask)
+        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(n, t, lc.n_heads * dh)
+        return o @ params["Wo"], state
+
+    @classmethod
+    def apply(cls, conf, params, x, state=None, train=False, rng=None,
+              mask=None, live=None, counters=None):
+        lc = conf.layer
+        xt = jnp.transpose(x, (0, 2, 1))                     # [N, T, D]
+        n, t, d = xt.shape
+        hn = rms_norm(xt, params["norm1_w"], lc.rms_eps)
+        counts = {}
+        if lc.mixer == "attention":
+            if live is None and state is not None:
+                live = state["filled"] > 0   # an idle slot caches nothing
+            mixed, new_state = cls._attention(lc, params, hn, state,
+                                              train, mask)
+        else:
+            mixed, new_state = mamba2.mamba2_mixer(
+                params, hn, state, mask, n_heads=lc.ssm_heads,
+                d_head=lc.ssm_d_head, d_state=lc.ssm_d_state,
+                n_groups=lc.ssm_groups, chunk=lc.ssm_chunk,
+                eps=lc.rms_eps, live=live, kernel=lc.use_kernels)
+            counts["ssm_state_rows"] = (
+                jnp.asarray(n, jnp.int32) if live is None
+                else jnp.sum((live > 0).astype(jnp.int32)))
+        xt = _residual(xt, mixed, lc.residual_multiplier)
+
+        valid = None
+        if mask is not None:
+            valid = mask > 0
+        if live is not None:
+            rows = jnp.broadcast_to((live > 0)[:, None], (n, t))
+            valid = rows if valid is None else valid & rows
+        h2 = rms_norm(xt, params["norm2_w"], lc.rms_eps)
+        y, moe_counts = dropless_moe(
+            params, h2.reshape(n * t, d),
+            None if valid is None else valid.reshape(n * t),
+            top_k=lc.top_k, experts_held=lc.held,
+            kernel=lc.use_kernels)
+        xt = _residual(xt, y.reshape(n, t, d), lc.residual_multiplier)
+        if counters is not None:
+            counts.update(moe_counts,
+                          moe_layer_steps=jnp.asarray(1, jnp.int32))
+            for name, v in counts.items():
+                counters[name] = counters.get(name, 0) + v
+
+        out = jnp.transpose(xt, (0, 2, 1))
+        if mask is not None:
+            out = out * mask[:, None, :].astype(out.dtype)
+        return out, (None if train else new_state)

@@ -17,12 +17,26 @@ layer contract:
   parallelism when the surrounding train step runs under shard_map
   (same convention as MultiHeadSelfAttention.ring_axis), with
   ``W_up/W_down`` holding the local expert slice.
+
+Beside it, :func:`dropless_moe` is the served form of today's sparse
+models (nn/layers/hybrid.py holds it in a block): top-k routing over
+ALL of the router's outputs with no capacity and no dropped token,
+gated (SwiGLU) experts, a shared expert every token passes, and
+``experts_held``, the range of the router's outputs whose experts this
+chip holds. The chip computes ``sum over held picked experts of gate x
+expert(h)``; a pick that falls on an expert held elsewhere adds nothing
+here. The (token, pick) pairs are sorted by expert and ONE grouped
+product a matrix runs over the held experts (:func:`grouped_product`:
+the Pallas grouped matmul ``megablox.gmm`` on a TPU, which reads the
+weights of an expert once for every tile of rows that reaches it and
+never for an expert no row picked; ``jax.lax.ragged_dot`` elsewhere).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -98,3 +112,140 @@ class MoeDenseImpl(LayerImplBase):
             if mask is not None:
                 y = y * mask[:, None, :]
         return y, {"aux_loss": aux}
+
+
+# ---------------------------------------------------------------------
+# dropless top-k routing over a share of gated experts
+# ---------------------------------------------------------------------
+#: rows of the sorted (token, pick) pairs one grid step of the grouped
+#: product takes: few rows (decode) want small tiles, so that a tile
+#: reaches few experts; many rows (prefill) want large ones, so that an
+#: expert's weights are read for few tiles
+_GROUP_TILE_ROWS = (128, 512)
+_GROUP_TILE_ROWS_FROM = 4096
+
+
+def _tile(size: int, want: int) -> int:
+    """The largest divisor of ``size`` that is at most ``want`` and a
+    multiple of 128, else ``size`` whole."""
+    for t in range(min(want, size) // 128 * 128, 0, -128):
+        if size % t == 0:
+            return t
+    return size
+
+
+def use_grouped_kernel(toggle) -> bool:
+    """The block's ``use_kernels``: None = the kernel on a TPU, ``ragged_dot``
+    elsewhere; True / ``"interpret"`` force the kernel."""
+    if toggle is None:
+        return jax.default_backend() == "tpu"
+    return bool(toggle)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _moe_grouped_product(xs, w, group_sizes, *, interpret: bool = False):
+    """``xs[rows of group e] @ w[e]`` for every held expert ``e``, as
+    ONE Pallas call (jitted on its own: one trace for all layers; in
+    the device trace the call carries the name of the function that
+    makes it, ``gmm``). ``xs`` ``[M, K]`` sorted by expert, rows
+    past ``sum(group_sizes)`` belonging to no held expert; ``w``
+    ``[E, K, N]``. The result's rows past the groups are not written."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = xs.shape
+    n = w.shape[2]
+    tm = _GROUP_TILE_ROWS[m >= _GROUP_TILE_ROWS_FROM]
+    if interpret:
+        tm = 8
+    pad = -m % tm
+    if pad:
+        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+    # the weights' tile: about 3 MB of bf16, whole rows of K first
+    tk = _tile(k, 2048)
+    tn = _tile(n, max(128, (3 << 20) // (2 * tk) // 128 * 128))
+    out = gmm(xs, w, group_sizes, preferred_element_type=xs.dtype,
+              tiling=(tm, tk, tn), interpret=interpret)
+    return out[:m]
+
+
+def grouped_product(xs, w, group_sizes, kernel=None):
+    if use_grouped_kernel(kernel):
+        return _moe_grouped_product(xs, w, group_sizes,
+                                    interpret=(kernel == "interpret"))
+    return jax.lax.ragged_dot(xs, w, group_sizes)
+
+
+def gated_ffn(x, w_in, w_out):
+    """``W_out (silu(g) * u)`` with ``[g | u] = x W_in``."""
+    gu = x @ w_in
+    f = gu.shape[-1] // 2
+    return (jax.nn.silu(gu[..., :f]) * gu[..., f:]) @ w_out
+
+
+def moe_shapes(width: int, n_router: int, n_held: int, d_expert: int,
+               d_shared: int) -> dict:
+    shapes = {"router": (width, n_router),
+              "We_in": (n_held, width, 2 * d_expert),
+              "We_out": (n_held, d_expert, width)}
+    if d_shared:
+        shapes.update(Ws_in=(width, 2 * d_shared),
+                      Ws_out=(d_shared, width))
+    return shapes
+
+
+def dropless_moe(params, tokens, valid=None, *, top_k: int,
+                 experts_held: Tuple[int, int], kernel=None):
+    """Routed plus shared experts on ``tokens`` ``[M, D]``.
+
+    ``params``: ``router`` ``[D, E]`` (all ``E`` outputs, whatever is
+    held), ``We_in`` ``[E_held, D, 2 F]``, ``We_out`` ``[E_held, F, D]``
+    for the experts ``experts_held = (lo, hi)`` of the router's outputs,
+    and, where the layer has a shared expert, ``Ws_in`` / ``Ws_out``.
+    ``valid`` ``[M]`` marks the tokens that exist (padding and idle
+    rows route nowhere and count nowhere).
+
+    Returns ``(y [M, D], counts)``: the gates are the softmax over each
+    token's ``top_k`` router logits (float32); no token is dropped,
+    whatever the load. ``counts`` are int32 scalars: ``moe_picks`` (token
+    x pick pairs routed), ``moe_picks_held`` (those on held experts),
+    ``moe_experts_touched`` (held experts with at least one row),
+    ``moe_load_max`` (the fullest held expert's rows)."""
+    m, d = tokens.shape
+    lo, hi = experts_held
+    n_held = hi - lo
+    if params["We_in"].shape[0] != n_held:
+        raise ValueError(
+            f"experts_held {experts_held} names {n_held} experts, the "
+            f"layer holds {params['We_in'].shape[0]}")
+    logits = jnp.dot(tokens, params["router"],
+                     preferred_element_type=jnp.float32)
+    top, idx = jax.lax.top_k(logits, top_k)                  # [M, k]
+    gates = jax.nn.softmax(top, axis=-1)
+    routed = (jnp.ones((m, 1), bool) if valid is None
+              else valid.astype(bool)[:, None])
+    held = (idx >= lo) & (idx < hi) & routed
+    # sort the pairs by held expert; what is not held sorts last
+    expert = jnp.where(held, idx - lo, n_held).reshape(-1)
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.bincount(expert, length=n_held + 1)[:n_held].astype(
+        jnp.int32)
+    xs = tokens[order // top_k]
+    gu = grouped_product(xs, params["We_in"], sizes, kernel)
+    f = gu.shape[-1] // 2
+    act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(tokens.dtype)
+    ys = grouped_product(act, params["We_out"], sizes, kernel)
+    # rows past the groups were never written: select, do not scale
+    ys = jnp.where((jnp.arange(m * top_k) < jnp.sum(sizes))[:, None],
+                   ys, 0)
+    back = jnp.argsort(order)
+    picked = ys[back].reshape(m, top_k, d).astype(jnp.float32)
+    y = jnp.sum(picked * jnp.where(held, gates, 0.0)[..., None], axis=1)
+    if "Ws_in" in params:
+        y = y + gated_ffn(tokens, params["Ws_in"],
+                          params["Ws_out"]).astype(jnp.float32)
+    counts = {
+        "moe_picks": jnp.sum(routed.astype(jnp.int32)) * top_k,
+        "moe_picks_held": jnp.sum(held.astype(jnp.int32)),
+        "moe_experts_touched": jnp.sum((sizes > 0).astype(jnp.int32)),
+        "moe_load_max": jnp.max(sizes)}
+    return y.astype(tokens.dtype), counts

@@ -194,8 +194,18 @@ class MultiLayerNetwork:
         feature_mask=None,
         rnn_state=None,
         collect: bool = False,
+        head_at=None,
+        live=None,
+        counters=None,
     ):
-        """Returns (final_or_all_activations, new_state, new_rnn_state)."""
+        """Returns (final_or_all_activations, new_state, new_rnn_state).
+
+        ``head_at`` ``[N]`` (serving prefill): the LAST layer sees only
+        that position of each row, so a head over a wide vocabulary
+        runs once a row and not once a position. ``live`` ``[N]`` (a
+        caller that batches slots): which rows exist; ``counters``: a
+        dict that layers add what they counted in this pass into, by
+        name. Both go to the layers whose bean has ``wants_live``."""
         cd = self._compute_dtype
         # The OUTPUT layer always runs at the master dtype: a bf16
         # softmax quantizes probabilities coarsely enough to stall
@@ -240,18 +250,33 @@ class MultiLayerNetwork:
             is_recurrent = isinstance(c.layer, L.RECURRENT_LAYER_TYPES)
             mask = feature_mask if is_recurrent else None
 
-            def _apply(p, xin, lst, lrng, lmask, _c=c, _impl=impl):
+            rows = ({"live": live,
+                     "counters": None if train else counters}
+                    if getattr(c.layer, "wants_live", False) else {})
+
+            def _apply(p, xin, lst, lrng, lmask, _c=c, _impl=impl,
+                       _rows=rows):
                 return _impl.apply(
                     _c, p, xin, state=lst, train=train, rng=lrng,
-                    mask=lmask,
+                    mask=lmask, **_rows,
                 )
 
             if self.conf.remat:
                 _apply = jax.checkpoint(_apply)
             if out_f32 and si == last_si:
                 x = _cast_floating(x, self._dtype)
+            layer_params = params[si]
+            tie = getattr(c.layer, "tie_to", None)
+            if tie is not None:
+                # a tied head reads the embedding's rows as its own
+                layer_params = dict(layer_params,
+                                    E=params[str(tie)]["W"])
+            if head_at is not None and si == last_si:
+                x = jnp.take_along_axis(
+                    x, head_at.astype(jnp.int32)[:, None, None], axis=2)
+                mask = None
             x, st = _apply(
-                params[si], x, layer_state,
+                layer_params, x, layer_state,
                 rngs[i] if train else None, mask,
             )
             if st is not None:
@@ -696,9 +721,23 @@ class MultiLayerNetwork:
     # ------------------------------------------------------------------
     # Inference (reference output/feedForward :578-715)
     # ------------------------------------------------------------------
+    @property
+    def takes_token_ids(self) -> bool:
+        """True where the first layer embeds token ids (``[N, T]``
+        int32 in) rather than projecting one-hot columns."""
+        return bool(getattr(self.conf.confs[0].layer,
+                            "takes_token_ids", False))
+
+    def _as_input(self, x) -> Array:
+        """Features at the net's dtype; token ids stay whole numbers (a
+        bf16 net would round an id over 256)."""
+        if self.takes_token_ids:
+            return jnp.asarray(x, jnp.int32)
+        return jnp.asarray(x, self._dtype)
+
     def output(self, x, train: bool = False) -> Array:
         self.init()
-        x = jnp.asarray(x, self._dtype)
+        x = self._as_input(x)
         return self._output_fn(self.params, self.state, x)
 
     def feed_forward(self, x, train: bool = False) -> List[Array]:
@@ -780,8 +819,8 @@ class MultiLayerNetwork:
 
         guard_streamable(
             (str(i), c.layer) for i, c in enumerate(self.conf.confs))
-        x = jnp.asarray(x, self._dtype)
-        if x.ndim == 2:
+        x = self._as_input(x)
+        if x.ndim == 2 and not self.takes_token_ids:
             x = x[:, :, None]
         out, _, new_rnn = self._rnn_step_jit(
             self.params, self.state, x, self._rnn_state)
@@ -828,6 +867,13 @@ class MultiLayerNetwork:
 
         if n_tokens < 1:
             raise ValueError(f"n_tokens {n_tokens} < 1")
+        if self.takes_token_ids:
+            raise ValueError(
+                "generate() feeds one-hot columns; layer 0 "
+                f"({type(self.conf.confs[0].layer).__name__}, "
+                "sequence) takes token ids. Serve this net "
+                "through serving.DecodeEngine, or step it with "
+                "rnn_time_step on [N, T] ids")
         self.init()
         vocab = self.conf.confs[0].layer.n_in
         out = self.rnn_time_step(prompt)  # prefill (guards streamable)

@@ -132,7 +132,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.layers.attention import (
-    ATTENTION_BEANS,
     _paged_blocks_per_step,
     _paged_table_entries,
     guard_streamable,
@@ -208,6 +207,9 @@ class _Pending:
     #: THROUGH it, zero-copy), or None until a cold admission's dense
     #: prefill completes and scatters into freshly allocated blocks
     tab: Optional[BlockTable] = None
+    #: what each prefill program counted (device scalars), added to
+    #: ``stats`` when the first token is fetched
+    counts: List[Any] = dataclasses.field(default_factory=list)
 
     def __post_init__(self):
         if not self.seq:
@@ -245,6 +247,8 @@ class _InflightRound:
     n_rounds: int = 1
     decode_tokens: int = 0
     n_valid: Any = None
+    #: what the decode program counted (device scalars by name)
+    counts: Any = None
 
 
 class _PhaseClock:
@@ -480,18 +484,23 @@ def _lm_shape_of(net):
     """(forward, vocab, named layer beans) for a MultiLayerNetwork or
     an LM-shaped single-input/single-output ComputationGraph. The
     forward signature is ``(params, state, x, mask, rnn) ->
-    (out [B, V, T], new_rnn)``."""
+    (out [B, V, T], new_rnn, counts)``, ``counts`` being what the
+    layers counted in the pass (int32 scalars by name, {} for most
+    nets); a MultiLayerNetwork's also takes ``head_at`` ``[B]`` (the
+    head at one position a row, ``T`` = 1 out) and ``live`` ``[B]``
+    (which rows hold a request, for layers that ask)."""
     from deeplearning4j_tpu.nn.graph import ComputationGraph
 
     if isinstance(net, ComputationGraph):
         in_name, out_name, vocab = net.lm_shape()
 
-        def forward(params, state, x, mask, rnn):
+        def forward(params, state, x, mask, rnn, live=None):
+            # (no graph layer asks which rows are live)
             acts, _, new_rnn = net._forward_fn(
                 params, state, {in_name: x}, None, False,
                 masks=None if mask is None else {in_name: mask},
                 rnn_state=rnn)
-            return acts[out_name], new_rnn
+            return acts[out_name], new_rnn, {}
 
         beans = [(name, lv.conf.layer)
                  for name, lv in net._layer_vertices.items()]
@@ -505,11 +514,12 @@ def _lm_shape_of(net):
             f"== output n_out; got {vocab} vs "
             f"{getattr(out_bean, 'n_out', None)})")
 
-    def forward(params, state, x, mask, rnn):
+    def forward(params, state, x, mask, rnn, head_at=None, live=None):
+        counts = {}
         out, _, new_rnn = net._forward_fn(
             params, state, x, None, False, feature_mask=mask,
-            rnn_state=rnn)
-        return out, new_rnn
+            rnn_state=rnn, head_at=head_at, live=live, counters=counts)
+        return out, new_rnn, counts
 
     beans = [(str(i), c.layer) for i, c in enumerate(net.conf.confs)]
     return forward, vocab, beans
@@ -743,31 +753,82 @@ class DecodeEngine:
         from deeplearning4j_tpu.nn.conf.layers import BaseRecurrentLayer
 
         windows = []
+        #: layers whose streaming state is one row a SLOT (a Mamba-2
+        #: mixer's convolution tail and SSM state), kept slot-major
+        #: beside the paged KV leaves; only attention layers get block
+        #: tables. Their prefill is masked (nn/layers/mamba2.py), which
+        #: is what a right-padded bucket asks of a carried state
+        self._state_layers: List[str] = []
+        #: some layer is told which slots hold a request (a block whose
+        #: experts route live rows only): the decode program takes the
+        #: ``live`` operand
+        self._wants_live = any(getattr(bean, "wants_live", False)
+                               for _, bean in beans)
+        attn_items = []
         for name, bean in beans:
             # carried-state recurrents only: RnnOutputLayer is
             # recurrent-typed but stateless, so it streams fine
             if not isinstance(bean, BaseRecurrentLayer):
                 continue
-            if not isinstance(bean, ATTENTION_BEANS):
+            # the engine reads what a bean says of itself, never its
+            # class: "kv" = an attention cache (paged here), "slot" =
+            # one row a slot
+            kind = getattr(bean, "serving_state", None)
+            if kind == "slot":
+                self._state_layers.append(name)
+                continue
+            if kind != "kv":
                 raise ValueError(
                     f"DecodeEngine streams through the attention KV "
                     f"cache; layer {name} "
                     f"({type(bean).__name__}) carries a recurrent "
                     "state this engine's masked slot prefill does not "
                     "support")
+            attn_items.append((name, bean))
             windows.append(bean.stream_max_t)
         if not windows:
             raise ValueError(
                 "DecodeEngine requires at least one attention layer")
         self.window = min(windows)
+        #: token ids in through a gather (the first layer embeds them)
+        #: or, for a net whose first layer takes ``n_in == vocab``
+        #: columns, one-hot
+        self._ids_in = bool(getattr(net, "takes_token_ids", False))
+        # what a state-carrying or expert net cannot have yet is
+        # refused here by the option's name, not served wrongly
+        refused = []
+        if self._state_layers:
+            refused += [
+                ("prefix_cache_rows", prefix_cache_rows,
+                 "a cached prefix would need the recurrent state at "
+                 "its end, which the trie does not keep"),
+                ("kv_host_tier_bytes", kv_host_tier_bytes,
+                 "the spill tier moves KV blocks only"),
+                ("kv_disk_tier_path", kv_disk_tier_path,
+                 "the spill tier moves KV blocks only"),
+                ("spec_draft_len", spec_draft_len,
+                 "a rejected draft cannot be rewound out of a "
+                 "recurrent state"),
+                ("fused_rounds", fused_rounds,
+                 "the fused scan has no counters or live-row operand")]
+        unsharded = [name for name, bean in beans
+                     if not getattr(bean, "shards_over_tp", True)]
+        if unsharded:
+            refused.append(
+                ("tp", tp if tp > 1 else 0,
+                 "experts and grouped KV heads are not sharded over tp"))
+        for option, value, why in refused:
+            if value:
+                raise ValueError(
+                    f"{option}={value!r} is not supported for this net "
+                    f"(layers {self._state_layers or unsharded}): "
+                    f"{why}")
         # -- tensor-parallel head sharding (ISSUE 12; default tp=1 =
         # the bit-identical single-chip engine) -----------------------
         if tp < 1:
             raise ValueError(f"tp {tp} < 1")
         self.tp = int(tp)
         self.tp_ctx: Optional[TPContext] = None
-        attn_items = [(name, bean) for name, bean in beans
-                      if isinstance(bean, ATTENTION_BEANS)]
         if self.tp > 1:
             for name, bean in attn_items:
                 if bean.n_heads % self.tp:
@@ -1013,6 +1074,10 @@ class DecodeEngine:
         self._reserved: set = set()       # slots held by _pending
         self._submit_t: Dict[int, float] = {}
         self._pool = None                 # rnn-state pytree, [B, ...]
+        #: paged engines: the slot-major state of ``_state_layers``
+        #: ({layer: {"conv", "ssm"}}, [n_slots, ...]); it rides every
+        #: decode dispatch beside the pool's KV leaves
+        self._slot_state: Dict[str, Any] = {}
         self._toks = None                 # [B] int32 current tokens
         self._temps = np.zeros(self.n_slots, np.float32)
         self._top_ks = np.full(self.n_slots, self.vocab, np.int32)
@@ -1046,6 +1111,17 @@ class DecodeEngine:
             # decode row's grid steps land with the pool
             "paged_blocks_live": 0, "paged_blocks_walked": 0,
             "paged_blocks_per_step": 0, "paged_steps_per_row": 0,
+            # what the jitted programs count themselves and return
+            # with the tokens (nn/layers/hybrid.py ``counters``):
+            # routed (row, pick) pairs, those on held experts, held
+            # experts with a row and the fullest one's rows (both
+            # summed over layers and steps), layers x steps, and live
+            # rows x state layers x steps; ``prefill_<name>`` is the
+            # part of each that prefill programs counted
+            **{prefix + name: 0 for prefix in ("", "prefill_")
+               for name in ("moe_picks", "moe_picks_held",
+                            "moe_experts_touched", "moe_layer_steps",
+                            "moe_load_max", "ssm_state_rows")},
             # KV transfer plane (ISSUE 14): cross-replica prefix
             # shipping counters (nonzero only when export/import run)
             "kv_exports": 0, "kv_exported_tokens": 0,
@@ -1092,18 +1168,35 @@ class DecodeEngine:
 
     def _build_jits(self):
         forward, chunk = self._forward, self.decode_chunk
+        ids_in = self._ids_in
+
+        def encode(tok):
+            # one position a row for the net's first layer: the ids
+            # themselves where it embeds them, else one-hot columns
+            if ids_in:
+                return tok[:, None]
+            return jax.nn.one_hot(
+                tok, self.vocab, dtype=self.net._dtype)[:, :, None]
 
         def chunk_prefill(params, state, x, mask, rnn, temp, top_k,
                           key):
             # masked prefill resuming a carried cache (a prefix-cache
             # hit's fetched state, or the previous chunk's): forward,
             # then sample at each row's last VALID position
-            out, new_rnn = forward(params, state, x, mask, rnn)
             length = jnp.sum(mask.astype(jnp.int32), axis=1)
-            probs = jnp.take_along_axis(
-                out, (length - 1)[:, None, None], axis=2)[:, :, 0]
+            if ids_in:
+                # the head at the sampled position only: a vocabulary
+                # this wide is not worth a column per prompt position
+                out, new_rnn, counts = forward(
+                    params, state, x, mask, rnn, head_at=length - 1)
+                probs = out[:, :, 0]
+            else:
+                out, new_rnn, counts = forward(params, state, x, mask,
+                                               rnn)
+                probs = jnp.take_along_axis(
+                    out, (length - 1)[:, None, None], axis=2)[:, :, 0]
             tok = sample_tokens(probs, temp, top_k, key)
-            return tok, new_rnn
+            return tok, new_rnn, counts
 
         def prefill(params, state, x, mask, temp, top_k, key):
             # cold prefill = the continuation body with no carried
@@ -1121,19 +1214,24 @@ class DecodeEngine:
                     jax.lax.dynamic_update_slice(
                         toks, tok1.astype(toks.dtype), (slot,)))
 
-        def decode(params, state, pool, toks, temps, top_ks, key):
+        def decode(params, state, pool, toks, temps, top_ks, key,
+                   live=None):
+            # ``live`` [B]: which slots hold a request, for the layers
+            # that ask (one operand a dispatch)
             keys = jax.random.split(key, chunk)
 
             def body(carry, k):
                 rnn, tok = carry
-                x = jax.nn.one_hot(
-                    tok, self.vocab, dtype=self.net._dtype)[:, :, None]
-                out, new_rnn = forward(params, state, x, None, rnn)
+                out, new_rnn, counts = forward(
+                    params, state, encode(tok), None, rnn, live=live)
                 nxt = sample_tokens(out[:, :, -1], temps, top_ks, k)
-                return (new_rnn, nxt), nxt
+                return (new_rnn, nxt), (nxt, counts)
 
-            (pool, tok), seq = jax.lax.scan(body, (pool, toks), keys)
-            return pool, tok, jnp.swapaxes(seq, 0, 1)  # [B, chunk]
+            (pool, tok), (seq, counts) = jax.lax.scan(
+                body, (pool, toks), keys)
+            # what the layers counted, summed over the chunk's steps
+            counts = {name: jnp.sum(v) for name, v in counts.items()}
+            return pool, tok, jnp.swapaxes(seq, 0, 1), counts
 
         def fused_decode(params, state, pool, toks, temps, top_ks,
                          eos_ids, remaining, keys):
@@ -1159,9 +1257,8 @@ class DecodeEngine:
 
             def body(carry, k):
                 rnn, tok = carry
-                x = jax.nn.one_hot(
-                    tok, self.vocab, dtype=self.net._dtype)[:, :, None]
-                out, new_rnn = forward(params, state, x, None, rnn)
+                out, new_rnn, _ = forward(params, state, encode(tok),
+                                          None, rnn)
                 nxt = sample_tokens(out[:, :, -1], temps, top_ks, k)
                 return (new_rnn, nxt), nxt
 
@@ -1198,6 +1295,18 @@ class DecodeEngine:
                 self._jit(fused_decode, donate_argnums=(2,))
                 if self.paged_kv else self._jit(fused_decode))
         self._admit_jit = self._jit(admit)
+        self._state_admit_jit = None
+        if self._state_layers and self.paged_kv:
+            def state_admit(slots, row, slot):
+                # a prefilled row's recurrent state into its slot
+                def put(p, o):
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        p, o.astype(p.dtype), slot, axis=0)
+
+                return jax.tree_util.tree_map(put, slots, row)
+
+            self._state_admit_jit = self._jit(state_admit,
+                                              donate_argnums=(0,))
         self._verify_jit = None
         if self.spec_draft_len:
             vocab, dtype = self.vocab, self.net._dtype
@@ -1216,12 +1325,12 @@ class DecodeEngine:
                 # argmax targets accepts precisely the tokens plain
                 # greedy decode would emit.
                 seq = jnp.concatenate([toks[:, None], draft], axis=1)
-                x = jnp.swapaxes(
-                    jax.nn.one_hot(seq, vocab, dtype=dtype), 1, 2)
+                x = (seq if ids_in else jnp.swapaxes(
+                    jax.nn.one_hot(seq, vocab, dtype=dtype), 1, 2))
                 pos = jnp.arange(seq.shape[1])
                 mask = (pos[None, :]
                         <= lens[:, None]).astype(jnp.float32)
-                out, new_pool = forward(params, state, x, mask, pool)
+                out, new_pool, _ = forward(params, state, x, mask, pool)
                 # acceptance (ISSUE 16): greedy rows keep the equality
                 # rule (bit-parity with plain greedy decode); sampling
                 # rows accept each draft token with probability
@@ -1279,7 +1388,7 @@ class DecodeEngine:
                 # cold-path cost is unchanged; warm admissions skip
                 # this entirely via the zero-copy splice)
                 out = {}
-                for name, st in pool.items():
+                for name, st in pool.items():   # the KV layers
                     k1, v1 = rnn1[name]["k"], rnn1[name]["v"]
                     fd = rnn1[name]["filled"][0]
                     w = k1.shape[2]
@@ -1412,6 +1521,8 @@ class DecodeEngine:
         if self._health_jit is not None:
             counts["health_check"] = n(self._health_jit)
         if self.paged_kv:
+            if self._state_admit_jit is not None:
+                counts["state_admit"] = n(self._state_admit_jit)
             counts["paged_scatter"] = n(self._scatter_jit)
             counts["paged_tok"] = n(self._tok_jit)
             counts["kv_import"] = n(self._kv_import_jit)
@@ -1886,6 +1997,48 @@ class DecodeEngine:
             else:
                 break
 
+    def _split_row(self, rnn1):
+        """A dense B=1 prefill state split into (its attention layers'
+        caches, its slot-state layers' rows)."""
+        kv = {n: st for n, st in rnn1.items()
+              if n not in self._state_layers}
+        return kv, {n: rnn1[n] for n in self._state_layers}
+
+    def _write_row(self, rnn1, tab: BlockTable, slot: int) -> None:
+        """A cold admission's one whole-row write: the prefilled row's
+        keys and values into the slot's freshly allocated blocks, its
+        recurrent state into the slot's row."""
+        kv, row = self._split_row(rnn1)
+        table_row, _ = tab.arrays(self._ring_slots)
+        self._pool = self._scatter_jit(
+            self._pool, kv, jnp.asarray(table_row),
+            jnp.asarray(tab.length, jnp.int32))
+        if row:
+            with self._span("serving.state_admit", slot=slot):
+                self._slot_state = self._state_admit_jit(
+                    self._slot_state, row, jnp.asarray(slot, jnp.int32))
+
+    def _live_operand(self):
+        """``(live,)`` for the decode program of a net some layer of
+        which asks which slots hold a request (an idle slot's row
+        routes to no expert and its recurrent state is left alone,
+        nn/layers/hybrid.py); ``()`` for every other net, whose program
+        has no such operand."""
+        if not self._wants_live:
+            return ()
+        return (jnp.asarray([int(s is not None) for s in self._slots],
+                            jnp.int32),)
+
+    def _add_counts(self, counts, prefill: bool = False) -> None:
+        """What a program counted (device scalars, ready with its
+        tokens) onto ``stats``; a prefill program's also under
+        ``prefill_<name>``, so that a reader can take the decode
+        program's part of a round."""
+        for name, v in jax.device_get(counts).items():  # one fetch
+            self.stats[name] += int(v)
+            if prefill:
+                self.stats["prefill_" + name] += int(v)
+
     def _paged_rnn_rows(self, tabs, chunk: int = 1):
         """Assemble the paged rnn-state operand for a dispatch of
         ``chunk`` query positions a row: the shared pool leaves plus
@@ -1949,8 +2102,12 @@ class DecodeEngine:
         device pool leaves the engine owns between rounds."""
         if not self.paged_kv:
             return rnn
+        if self._state_layers:
+            self._slot_state = {name: rnn[name]
+                                for name in self._state_layers}
         return {name: {"pk": st["pk"], "pv": st["pv"]}
-                for name, st in rnn.items()}
+                for name, st in rnn.items()
+                if name not in self._state_layers}
 
     def _alloc_window_tab(self, length: int) -> Optional[BlockTable]:
         """A fresh BlockTable covering the last ``min(length, wmax)``
@@ -2146,11 +2303,19 @@ class DecodeEngine:
             pass
         return True
 
-    def _one_hot_prompt(self, prompt, bucket):
-        x = np.zeros((1, self.vocab, bucket), np.float32)
-        x[0, list(prompt), np.arange(len(prompt))] = 1.0
+    def _encode_prompt(self, prompt, bucket):
+        """A prompt segment right-padded to ``bucket`` and its mask:
+        ids ``[1, bucket]`` for a net that embeds them, one-hot columns
+        ``[1, V, bucket]`` for one whose first layer takes ``n_in ==
+        vocab``."""
         mask = np.zeros((1, bucket), np.float32)
         mask[0, :len(prompt)] = 1.0
+        if self._ids_in:
+            x = np.zeros((1, bucket), np.int32)
+            x[0, :len(prompt)] = prompt
+        else:
+            x = np.zeros((1, self.vocab, bucket), np.float32)
+            x[0, list(prompt), np.arange(len(prompt))] = 1.0
         return jnp.asarray(x), jnp.asarray(mask)
 
     def _start_admission(self, request: Request, slot: int):
@@ -2281,7 +2446,7 @@ class DecodeEngine:
                  or self.scheduler.bucket_of(len(seg)))
         with self._span("serving.prompt_encode", rid=req.id,
                         width=width, tokens=len(seg)):
-            x, mask = self._one_hot_prompt(seg, width)
+            x, mask = self._encode_prompt(seg, width)
             temp = jnp.asarray([req.temperature], jnp.float32)
             top_k = jnp.asarray([req.top_k or self.vocab], jnp.int32)
         clock = self._clock_of(req.id)
@@ -2300,7 +2465,7 @@ class DecodeEngine:
                             width=width, tokens=len(seg),
                             done=pending.done, paged=True,
                             **_targs(req)):
-                tok, rnn = self._chunk_jit(
+                tok, rnn, counts = self._chunk_jit(
                     self._params, self._state, x, mask, rnn_in,
                     temp, top_k, self._next_key())
             if clock is not None:
@@ -2310,6 +2475,7 @@ class DecodeEngine:
             self._pool = self._strip_pool(rnn)
             pending.tab.length += len(seg)
             pending.tok = tok
+            pending.counts.append(counts)
             pending.done += len(seg)
             self.stats["prefill_tokens"] += len(seg)
             self.stats["chunks_scheduled"] += 1
@@ -2321,7 +2487,7 @@ class DecodeEngine:
             with self._span("serving.prefill", rid=req.id,
                             bucket=width, tokens=len(seg),
                             **_targs(req)):
-                tok, rnn = self._prefill_jit(
+                tok, rnn, counts = self._prefill_jit(
                     self._params, self._state, x, mask, temp,
                     top_k, self._next_key())
             if clock is not None:
@@ -2332,7 +2498,7 @@ class DecodeEngine:
             with self._span("serving.prefill_chunk", rid=req.id,
                             width=width, tokens=len(seg),
                             done=pending.done, **_targs(req)):
-                tok, rnn = self._chunk_jit(
+                tok, rnn, counts = self._chunk_jit(
                     self._params, self._state, x, mask,
                     pending.rnn, temp, top_k, self._next_key())
             if clock is not None:
@@ -2340,6 +2506,7 @@ class DecodeEngine:
                 clock.add(now, "admit_chunk", now - t0,
                           tokens=len(seg))
         pending.rnn, pending.tok = rnn, tok
+        pending.counts.append(counts)
         pending.done += len(seg)
         self.stats["prefill_tokens"] += len(seg)
         self.stats["chunks_scheduled"] += 1
@@ -2359,8 +2526,12 @@ class DecodeEngine:
             return {"pk": jnp.zeros(shape, k.dtype),
                     "pv": jnp.zeros(shape, st["v"].dtype)}
 
+        kv, row = self._split_row(rnn1)
         self._pool = self._place(
-            {name: make(st) for name, st in rnn1.items()})
+            {name: make(st) for name, st in kv.items()})
+        self._slot_state = jax.tree_util.tree_map(
+            lambda a: jnp.zeros((self.n_slots,) + a.shape[1:], a.dtype),
+            row)
         self._toks = self._place(jnp.zeros((self.n_slots,), jnp.int32))
 
     def _complete_admission(self, pending: _Pending):
@@ -2381,10 +2552,7 @@ class DecodeEngine:
                 if tab is None:
                     self._defer_admission(pending)
                     return
-                table_row, _ = tab.arrays(self._ring_slots)
-                self._pool = self._scatter_jit(
-                    self._pool, pending.rnn, jnp.asarray(table_row),
-                    jnp.asarray(tab.length, jnp.int32))
+                self._write_row(pending.rnn, tab, slot)
             else:
                 tab = pending.tab
                 pending.tab = None
@@ -2428,6 +2596,8 @@ class DecodeEngine:
         # report host-side dispatch time as time-to-first-token)
         with self._span("serving.first_token_sync", rid=request.id):
             first = int(np.asarray(pending.tok)[0])
+            for counts in pending.counts:
+                self._add_counts(counts, prefill=True)
         submit_t = self._submit_t.get(request.id)
         ttft = (self._clock() - submit_t
                 if submit_t is not None else None)
@@ -3032,6 +3202,7 @@ class DecodeEngine:
             seq = np.asarray(inf.seq)
             n_valid = (np.asarray(inf.n_valid)
                        if inf.n_valid is not None else None)
+            self._add_counts(inf.counts or {})
         with self._span("serving.commit", active=len(inf.active)):
             self._commit_round(inf, seq, n_valid, t_sync0)
 
@@ -3325,6 +3496,11 @@ class DecodeEngine:
             with self._span("serving.tables", active=len(active)):
                 pool_op = (self._paged_rnn_rows(self._kv_tabs)
                            if self.paged_kv else self._pool)
+                if self.paged_kv and self._slot_state:
+                    # the slot-state layers' rows ride the dispatch
+                    # beside the KV leaves (``_strip_pool`` parts them)
+                    pool_op = dict(pool_op, **self._slot_state)
+                live = self._live_operand()
                 temps = jnp.asarray(self._temps)
                 top_ks = jnp.asarray(self._top_ks)
             if spec_round:
@@ -3352,7 +3528,7 @@ class DecodeEngine:
                 # dispatch — the per-round cost a fused scan amortizes
                 self._observe("serving_host_step_s",
                               td0 - self._last_sync_end)
-            n_valid = None
+            n_valid, counts = None, {}
             with self._span("serving.decode_chunk",
                             active=len(active), fused=fuse_k,
                             rids=[self._slots[s].request.id
@@ -3382,10 +3558,11 @@ class DecodeEngine:
                             jnp.asarray(remaining), keys)
                         self._observe("serving_fused_rounds", fuse_k)
                     else:
-                        pool_op, self._toks, seq = self._decode_jit(
+                        (pool_op, self._toks, seq,
+                         counts) = self._decode_jit(
                             self._params, self._state, pool_op,
                             self._toks, temps, top_ks,
-                            self._next_key())
+                            self._next_key(), *live)
                 if not self.async_rounds:
                     with self._span("serving.token_sync"):
                         seq = np.asarray(seq)  # [B, T]; forces the
@@ -3401,7 +3578,7 @@ class DecodeEngine:
                 ver_dt=ver_dt,
                 n_rounds=max(fuse_k, 1),
                 decode_tokens=max(fuse_k, 1) * self.decode_chunk,
-                n_valid=n_valid)
+                n_valid=n_valid, counts=counts)
             if self.async_rounds:
                 # round N's fetch waits for the NEXT step: stash the
                 # dispatched round and return. The round-time
@@ -3682,13 +3859,10 @@ class DecodeEngine:
                     "paged restore could not allocate blocks for a "
                     "snapshotted slot — kv_blocks is smaller than the "
                     "snapshot's working set")
-            table_row, _ = tab.arrays(self._ring_slots)
             with self._span("serving.admit", rid=request.id,
                             slot=slot, paged=True,
                             **_targs(request)):
-                self._pool = self._scatter_jit(
-                    self._pool, rnn, jnp.asarray(table_row),
-                    jnp.asarray(tab.length, jnp.int32))
+                self._write_row(rnn, tab, slot)
             self._toks = self._tok_jit(self._toks, tok,
                                        jnp.asarray(slot, jnp.int32))
             self._kv_tabs[slot] = tab

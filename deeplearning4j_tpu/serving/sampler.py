@@ -60,10 +60,17 @@ def sample_tokens(probs, temps, top_ks, key):
     ``jax.random.categorical`` is invariant to, and top-k on
     log-probabilities equals top-k on logits (monotone map)."""
     greedy = jnp.argmax(probs, axis=1).astype(jnp.int32)
-    scaled = _scaled_filtered_logits(probs, temps, top_ks)
-    sampled = jax.random.categorical(key, scaled, axis=-1).astype(
-        jnp.int32)
-    return jnp.where(temps > 0, sampled, greedy)
+
+    def draw():
+        scaled = _scaled_filtered_logits(probs, temps, top_ks)
+        sampled = jax.random.categorical(key, scaled, axis=-1).astype(
+            jnp.int32)
+        return jnp.where(temps > 0, sampled, greedy)
+
+    # the filter's two sorts are a cost of every step: a batch whose
+    # rows all decode greedily skips them (the ids are the same either
+    # way)
+    return jax.lax.cond(jnp.any(temps > 0), draw, lambda: greedy)
 
 
 def greedy_acceptance(targets, draft, lens):

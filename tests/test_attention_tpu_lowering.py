@@ -86,23 +86,63 @@ def one_chip():
     (48, 16, 1, jnp.float32), (1, 16, 128, jnp.float32),
     (8, 4, 5, jnp.float32), (48, 16, 1, jnp.bfloat16)])
 def test_paged_kernel_compiles_for_v5e(one_chip, b, h, t, pool_dtype):
+    text = _compile_for(
+        one_chip, lambda *a: _paged_flash_attention(*a, tm=2048),
+        *_paged_avals(b, h, t, 128, 1400, 16, 129, jnp.bfloat16,
+                      pool_dtype))
+    assert "tpu_custom_call" in text
+
+
+def _compile_for(one_chip, fn, *avals):
+    """``fn`` compiled for the described chip; its HLO text. A described
+    device's executable cannot be read back from the persistent cache:
+    keep it out."""
     from jax.experimental.compilation_cache import compilation_cache
     avals = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-             for a in _paged_avals(b, h, t, 128, 1400, 16, 129,
-                                   jnp.bfloat16, pool_dtype)]
-    # a described device's executable cannot be read back from the
-    # persistent cache; keep it out
+             for a in avals]
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        compiled = jax.jit(
-            lambda *a: _paged_flash_attention(*a, tm=2048)
-        ).lower(*avals).compile()
+        return jax.jit(fn).lower(*avals).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
-    assert "tpu_custom_call" in compiled.as_text()
+
+
+# the hybrid cell's two Pallas kernels at its published widths
+# (granite-4.0-h-small: 128 Mamba-2 heads of 64, state 128, 64 slots;
+# 36 held experts of 4096 x 1536 and 768 x 4096): the one-step state
+# update, and the grouped expert product at decode rows (64 slots x 10
+# picks) and at a 2,048-token prefill's
+def test_ssm_step_kernel_compiles_for_v5e(one_chip):
+    from deeplearning4j_tpu.nn.layers.mamba2 import _ssm_step_update
+
+    f32 = jnp.float32
+    S = jax.ShapeDtypeStruct
+    text = _compile_for(
+        one_chip, _ssm_step_update, S((64, 128, 64, 128), f32),
+        S((64, 128, 128), f32), S((64, 128, 128), f32),
+        S((64, 1, 64, 128), f32), S((64, 1, 64, 128), f32),
+        S((64,), jnp.int32))
+    assert "_ssm_step_update" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [640, 20480])
+def test_grouped_expert_product_compiles_for_v5e(one_chip, rows):
+    from deeplearning4j_tpu.nn.layers.moe import _moe_grouped_product
+
+    bf16 = jnp.bfloat16
+    S = jax.ShapeDtypeStruct
+
+    def both(xs, w_in, w_out, sizes):
+        gu = _moe_grouped_product(xs, w_in, sizes)
+        return _moe_grouped_product(gu[:, :768], w_out, sizes)
+
+    text = _compile_for(
+        one_chip, both, S((rows, 4096), bf16), S((36, 4096, 1536), bf16),
+        S((36, 768, 4096), bf16), S((36,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2
 
 
 def test_paged_auto_rule_only_selects_shapes_that_lower(monkeypatch):
