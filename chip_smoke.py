@@ -127,8 +127,9 @@ def decode_pallas_calls(eng) -> int:
     import jax.numpy as jnp
 
     lowered = eng._decode_jit.lower(
-        eng._params, eng._state, eng._paged_rnn_rows(eng._kv_tabs),
-        eng._toks, jnp.asarray(eng._temps), jnp.asarray(eng._top_ks),
+        eng._params, eng._state, eng._pool,
+        eng._paged_tables(eng._kv_tabs), eng._toks,
+        jnp.asarray(eng._temps), jnp.asarray(eng._top_ks),
         jax.random.key(0)).as_text()
     return lowered.count("tpu_custom_call")
 

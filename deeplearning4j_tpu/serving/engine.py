@@ -525,6 +525,24 @@ def _lm_shape_of(net):
     return forward, vocab, beans
 
 
+def _unpack_tables(tabs):
+    """The four block-table operands of a paged dispatch
+    (``AttentionImpl._paged_attend`` says what each holds) out of the
+    ONE int32 array ``[B, 2 S + 2]`` they travel in: ``table`` and
+    ``base`` ``[B, S]``, then a column each of ``floor`` and
+    ``filled``. Slices of a device array inside a program, writable
+    views of a numpy array on the host."""
+    s = (tabs.shape[1] - 2) // 2
+    return {"table": tabs[:, :s], "base": tabs[:, s:2 * s],
+            "floor": tabs[:, 2 * s], "filled": tabs[:, 2 * s + 1]}
+
+
+def _filled_of(tabs):
+    """``filled`` of the packed operand (its last column); None for
+    the dense layout's None."""
+    return None if tabs is None else tabs[:, -1]
+
+
 class DecodeEngine:
     """Slot-multiplexed batched decoding for one LM-shaped network.
 
@@ -1111,6 +1129,10 @@ class DecodeEngine:
             # decode row's grid steps land with the pool
             "paged_blocks_live": 0, "paged_blocks_walked": 0,
             "paged_blocks_per_step": 0, "paged_steps_per_row": 0,
+            # host-to-device transfers made for the block-table
+            # operand, summed over paged dispatches: one a dispatch,
+            # whatever the number of paged layers (ISSUE 28)
+            "table_uploads": 0,
             # what the jitted programs count themselves and return
             # with the tokens (nn/layers/hybrid.py ``counters``):
             # routed (row, pick) pairs, those on held experts, held
@@ -1178,12 +1200,43 @@ class DecodeEngine:
             return jax.nn.one_hot(
                 tok, self.vocab, dtype=self.net._dtype)[:, :, None]
 
-        def chunk_prefill(params, state, x, mask, rnn, temp, top_k,
-                          key):
+        def seen(pool, tabs, filled=None):
+            # the per-layer state the forward pass sees: every paged
+            # layer's pool leaves beside the dispatch's ONE set of
+            # block tables (``tabs``: ``_paged_tables``' packed
+            # operand, unpacked here; None = dense rows, which carry
+            # their own). ``filled`` is a scan's carried copy of the
+            # only table operand a step advances
+            if tabs is None:
+                return pool
+            shared = _unpack_tables(tabs)
+            if filled is not None:
+                shared["filled"] = filled
+            return {name: dict(st, **shared) if "pk" in st else st
+                    for name, st in pool.items()}
+
+        def kept(rnn, tabs):
+            # a pass's new state parted into what the engine carries
+            # between dispatches (pool leaves, slot-state rows) and
+            # the advanced ``filled``: every paged layer added the
+            # same lengths, so the first one's stands for all and no
+            # program hands a layer's tables back
+            if tabs is None:
+                return rnn, None
+            filled = next(st["filled"] for st in rnn.values()
+                          if "pk" in st)
+            return {name: ({"pk": st["pk"], "pv": st["pv"]}
+                           if "pk" in st else st)
+                    for name, st in rnn.items()}, filled
+
+        def chunk_prefill(params, state, x, mask, rnn, tabs, temp,
+                          top_k, key):
             # masked prefill resuming a carried cache (a prefix-cache
-            # hit's fetched state, or the previous chunk's): forward,
-            # then sample at each row's last VALID position
+            # hit's fetched state, the previous chunk's, or a paged
+            # warm admission's pool leaves under its block table):
+            # forward, then sample at each row's last VALID position
             length = jnp.sum(mask.astype(jnp.int32), axis=1)
+            rnn = seen(rnn, tabs)
             if ids_in:
                 # the head at the sampled position only: a vocabulary
                 # this wide is not worth a column per prompt position
@@ -1196,14 +1249,14 @@ class DecodeEngine:
                 probs = jnp.take_along_axis(
                     out, (length - 1)[:, None, None], axis=2)[:, :, 0]
             tok = sample_tokens(probs, temp, top_k, key)
-            return tok, new_rnn, counts
+            return tok, kept(new_rnn, tabs)[0], counts
 
         def prefill(params, state, x, mask, temp, top_k, key):
             # cold prefill = the continuation body with no carried
             # cache (separate jit wrapper keeps its own executable
             # cache, so compile_counts stays per-path)
-            return chunk_prefill(params, state, x, mask, None, temp,
-                                 top_k, key)
+            return chunk_prefill(params, state, x, mask, None, None,
+                                 temp, top_k, key)
 
         def admit(pool, toks, rnn1, tok1, slot):
             def put(p, o):
@@ -1214,27 +1267,31 @@ class DecodeEngine:
                     jax.lax.dynamic_update_slice(
                         toks, tok1.astype(toks.dtype), (slot,)))
 
-        def decode(params, state, pool, toks, temps, top_ks, key,
-                   live=None):
-            # ``live`` [B]: which slots hold a request, for the layers
-            # that ask (one operand a dispatch)
+        def decode(params, state, pool, tabs, toks, temps, top_ks,
+                   key, live=None):
+            # ``tabs``: the paged dispatch's block tables (None for
+            # the dense layout); ``live`` [B]: which slots hold a
+            # request, for the layers that ask (one operand each a
+            # dispatch). Of the tables only ``filled`` is carried:
+            # the rest is the same at every step
             keys = jax.random.split(key, chunk)
 
             def body(carry, k):
-                rnn, tok = carry
+                pool, filled, tok = carry
                 out, new_rnn, counts = forward(
-                    params, state, encode(tok), None, rnn, live=live)
+                    params, state, encode(tok), None,
+                    seen(pool, tabs, filled), live=live)
                 nxt = sample_tokens(out[:, :, -1], temps, top_ks, k)
-                return (new_rnn, nxt), (nxt, counts)
+                return (*kept(new_rnn, tabs), nxt), (nxt, counts)
 
-            (pool, tok), (seq, counts) = jax.lax.scan(
-                body, (pool, toks), keys)
+            (pool, _, tok), (seq, counts) = jax.lax.scan(
+                body, (pool, _filled_of(tabs), toks), keys)
             # what the layers counted, summed over the chunk's steps
             counts = {name: jnp.sum(v) for name, v in counts.items()}
             return pool, tok, jnp.swapaxes(seq, 0, 1), counts
 
-        def fused_decode(params, state, pool, toks, temps, top_ks,
-                         eos_ids, remaining, keys):
+        def fused_decode(params, state, pool, tabs, toks, temps,
+                         top_ks, eos_ids, remaining, keys):
             # fused multi-round decode (ISSUE 16): K stepped rounds
             # as ONE scan over K * chunk positions. ``keys`` carries
             # the K per-round host keys (the exact keys K stepped
@@ -1256,13 +1313,14 @@ class DecodeEngine:
             flat = flat.reshape(k_rounds * chunk)
 
             def body(carry, k):
-                rnn, tok = carry
+                pool, filled, tok = carry
                 out, new_rnn, _ = forward(params, state, encode(tok),
-                                          None, rnn)
+                                          None, seen(pool, tabs, filled))
                 nxt = sample_tokens(out[:, :, -1], temps, top_ks, k)
-                return (new_rnn, nxt), nxt
+                return (*kept(new_rnn, tabs), nxt), nxt
 
-            (pool, tok), seq = jax.lax.scan(body, (pool, toks), flat)
+            (pool, _, tok), seq = jax.lax.scan(
+                body, (pool, _filled_of(tabs), toks), flat)
             seq = jnp.swapaxes(seq, 0, 1)       # [B, K * chunk]
             t = k_rounds * chunk
             pos = jnp.arange(t)
@@ -1282,7 +1340,13 @@ class DecodeEngine:
             # write one round's blocks (measured 1.8x warm-TTFT
             # regression on the CPU proxy; the dense path keeps its
             # original no-donation behavior — callers there may hold
-            # the old buffers)
+            # the old buffers). What is donated is what a dispatch
+            # writes and the engine keeps: the pool leaves and the
+            # slot-state rows. The block tables are NOT: they are the
+            # argument beside it, made anew on the host each dispatch
+            # and read by every paged layer (XLA rejects one buffer
+            # donated through two pytree leaves, so what the layers
+            # share cannot ride inside the donated pytree)
             self._chunk_jit = self._jit(chunk_prefill,
                                         donate_argnums=(4,))
             self._decode_jit = self._jit(decode, donate_argnums=(2,))
@@ -1311,8 +1375,8 @@ class DecodeEngine:
         if self.spec_draft_len:
             vocab, dtype = self.vocab, self.net._dtype
 
-            def verify(params, state, pool, toks, draft, lens, temps,
-                       top_ks, key):
+            def verify(params, state, pool, tabs, toks, draft, lens,
+                       temps, top_ks, key):
                 # ONE forward scores every slot's draft: the chunk fed
                 # per row is [current token | draft], right-padded to
                 # the round's pow2 width bucket; the mask keeps each
@@ -1330,7 +1394,9 @@ class DecodeEngine:
                 pos = jnp.arange(seq.shape[1])
                 mask = (pos[None, :]
                         <= lens[:, None]).astype(jnp.float32)
-                out, new_pool, _ = forward(params, state, x, mask, pool)
+                out, new_rnn, _ = forward(params, state, x, mask,
+                                          seen(pool, tabs))
+                new_pool, filled = kept(new_rnn, tabs)
                 # acceptance (ISSUE 16): greedy rows keep the equality
                 # rule (bit-parity with plain greedy decode); sampling
                 # rows accept each draft token with probability
@@ -1362,14 +1428,22 @@ class DecodeEngine:
                 # the committed cache then holds exactly
                 # context + accepted prefix, with the bonus token as
                 # the slot's new current (not-yet-cached) token
-                new_pool = drop_newest_tokens(new_pool, lens - acc)
+                # (paged: tokens stay where they lie in their blocks
+                # and the rewind is the ONE ``filled`` moving back, the
+                # contract ``drop_newest_tokens`` states; the tables
+                # go out with it for the decode dispatch to chain on,
+                # as device arrays, no second upload)
+                if tabs is None:
+                    new_pool = drop_newest_tokens(new_pool, lens - acc)
+                else:
+                    tabs = tabs.at[:, -1].set(filled - (lens - acc))
                 dpad = jnp.concatenate(
                     [draft, jnp.zeros_like(draft[:, :1])], axis=1)
                 emitted = jnp.where(
                     pos[None, :] < acc[:, None], dpad,
                     jnp.where(pos[None, :] == acc[:, None],
                               bonus[:, None], 0))
-                return new_pool, bonus, emitted, acc
+                return new_pool, tabs, bonus, emitted, acc
 
             self._verify_jit = (
                 self._jit(verify, donate_argnums=(2,))
@@ -2039,41 +2113,35 @@ class DecodeEngine:
             if prefill:
                 self.stats["prefill_" + name] += int(v)
 
-    def _paged_rnn_rows(self, tabs, chunk: int = 1):
-        """Assemble the paged rnn-state operand for a dispatch of
-        ``chunk`` query positions a row: the shared pool leaves plus
-        each row's ring-projected block table (None rows — idle slots —
-        map nothing; their writes drop and their keys all mask)."""
-        b = len(tabs)
-        s_ring = self._ring_slots
-        table = np.full((b, s_ring), -1, np.int32)
-        base = np.full((b, s_ring), -1, np.int32)
-        floor = np.zeros(b, np.int32)
-        filled = np.zeros(b, np.int32)
+    def _paged_tables(self, tabs, chunk: int = 1):
+        """The block-table operand of a paged dispatch of ``chunk``
+        query positions a row: each row's ring-projected block table,
+        its floor and its length (None rows — idle slots — map
+        nothing; their writes drop and their keys all mask), packed
+        into ONE int32 array (``_unpack_tables``) and uploaded ONCE,
+        whatever the number of paged layers. It enters the program as
+        an argument of its own beside the donated pool: every paged
+        layer reads the same arrays, and nothing about them needs
+        donating. Under tp it COMMITS replicated
+        (``TPContext.replicate``), so that a plain round's operand
+        and a spec round's chained verify output share one decode
+        lowering."""
+        packed = np.full((len(tabs), 2 * self._ring_slots + 2), -1,
+                         np.int32)
+        packed[:, -2:] = 0                       # floor, filled
+        rows = _unpack_tables(packed)
         for i, tab in enumerate(tabs):
             if tab is None:
                 continue
-            table[i], base[i] = tab.arrays(s_ring)
-            floor[i] = tab.floor
-            filled[i] = tab.length
-        self._count_paged_walk(table, base, floor, filled, chunk)
-        # per-layer COPIES of the (tiny) table operands: the paged
-        # dispatches donate their cache operand, and XLA rejects the
-        # same buffer donated through two pytree leaves. Under tp the
-        # copies COMMIT replicated (TPContext.replicate) so a plain
-        # round's operands and a spec round's chained verify-output
-        # pool share one decode lowering
-        def op(host_array):
-            if self.tp_ctx is not None:
-                return self.tp_ctx.replicate(host_array)
-            return jnp.asarray(host_array)
-
-        return {name: dict(st,
-                           table=op(table),
-                           base=op(base),
-                           floor=op(floor),
-                           filled=op(filled))
-                for name, st in self._pool.items()}
+            rows["table"][i], rows["base"][i] = tab.arrays(
+                self._ring_slots)
+            rows["floor"][i] = tab.floor
+            rows["filled"][i] = tab.length
+        self._count_paged_walk(chunk=chunk, **rows)
+        self.stats["table_uploads"] += 1
+        if self.tp_ctx is not None:
+            return self.tp_ctx.replicate(packed)
+        return jnp.asarray(packed)
 
     def _count_paged_walk(self, table, base, floor, filled,
                           chunk: int) -> None:
@@ -2098,15 +2166,16 @@ class DecodeEngine:
         self.stats["paged_blocks_walked"] += walked
 
     def _strip_pool(self, rnn):
-        """Back out the per-dispatch table operands, keeping only the
-        device pool leaves the engine owns between rounds."""
-        if not self.paged_kv:
+        """What a paged program hands back (pool leaves and, for a net
+        with slot-state layers, the state rows that rode the same
+        donated operand) parted into ``_slot_state`` and the returned
+        pool (the dense layout keeps both in its one slot-major
+        pool). No program returns table operands."""
+        if not (self.paged_kv and self._state_layers):
             return rnn
-        if self._state_layers:
-            self._slot_state = {name: rnn[name]
-                                for name in self._state_layers}
-        return {name: {"pk": st["pk"], "pv": st["pv"]}
-                for name, st in rnn.items()
+        self._slot_state = {name: rnn[name]
+                            for name in self._state_layers}
+        return {name: st for name, st in rnn.items()
                 if name not in self._state_layers}
 
     def _alloc_window_tab(self, length: int) -> Optional[BlockTable]:
@@ -2459,15 +2528,15 @@ class DecodeEngine:
             if not self._ensure_tab(pending.tab, len(seg),
                                     rid=req.id):
                 return False
-            rnn_in = self._paged_rnn_rows([pending.tab], chunk=width)
+            tables = self._paged_tables([pending.tab], chunk=width)
             t0 = self._clock()
             with self._span("serving.prefill_chunk", rid=req.id,
                             width=width, tokens=len(seg),
                             done=pending.done, paged=True,
                             **_targs(req)):
                 tok, rnn, counts = self._chunk_jit(
-                    self._params, self._state, x, mask, rnn_in,
-                    temp, top_k, self._next_key())
+                    self._params, self._state, x, mask, self._pool,
+                    tables, temp, top_k, self._next_key())
             if clock is not None:
                 now = self._clock()
                 clock.add(now, "admit_chunk", now - t0,
@@ -2500,7 +2569,7 @@ class DecodeEngine:
                             done=pending.done, **_targs(req)):
                 tok, rnn, counts = self._chunk_jit(
                     self._params, self._state, x, mask,
-                    pending.rnn, temp, top_k, self._next_key())
+                    pending.rnn, None, temp, top_k, self._next_key())
             if clock is not None:
                 now = self._clock()
                 clock.add(now, "admit_chunk", now - t0,
@@ -2990,14 +3059,17 @@ class DecodeEngine:
                             else [])
         return drafts
 
-    def _dispatch_verify(self, drafts: Dict[int, List[int]], pool_op):
+    def _dispatch_verify(self, drafts: Dict[int, List[int]], pool_op,
+                         tables):
         """Dispatch one batched draft-verify pass over the whole slot
         pool: pad every slot's draft to the round's pow2 width bucket
         (compile counts stay O(log K)) and run the single verify
         executable (forward + greedy acceptance + per-slot rewind +
         bonus token in one program). The pool/current-token state is
         updated in place with the (still in-flight) device outputs so
-        the round's decode chunk chains onto the committed state —
+        the round's decode chunk chains onto the committed state (and,
+        paged, onto the tables the program hands back with each row's
+        rejected tail rewound out of ``filled``) —
         NOTHING syncs here; ``_land_verify`` fetches the results after
         the decode dispatch so a speculative round still costs ONE
         host round-trip."""
@@ -3017,12 +3089,13 @@ class DecodeEngine:
                               for s, d in drafts.items() if d],
                         **self._traces_of(
                             s for s, d in drafts.items() if d)):
-            pool_op, self._toks, emitted, acc = self._verify_jit(
-                self._params, self._state, pool_op,
+            (pool_op, tables, self._toks, emitted,
+             acc) = self._verify_jit(
+                self._params, self._state, pool_op, tables,
                 self._toks, jnp.asarray(draft), jnp.asarray(lens),
                 jnp.asarray(self._temps), jnp.asarray(self._top_ks),
                 self._next_key())
-        return pool_op, (lens, emitted, acc)
+        return pool_op, tables, (lens, emitted, acc)
 
     def _land_verify(self, drafts: Dict[int, List[int]], lens,
                      emitted, acc):
@@ -3494,8 +3567,12 @@ class DecodeEngine:
                         if clock is not None:
                             clock.add(t_pre, "stall", t_pre - rt0)
             with self._span("serving.tables", active=len(active)):
-                pool_op = (self._paged_rnn_rows(self._kv_tabs)
-                           if self.paged_kv else self._pool)
+                # the round's ONE upload of the block tables (the
+                # dense layout has none), shared by every layer and
+                # by the verify and decode dispatches
+                tables = (self._paged_tables(self._kv_tabs)
+                          if self.paged_kv else None)
+                pool_op = self._pool
                 if self.paged_kv and self._slot_state:
                     # the slot-state layers' rows ride the dispatch
                     # beside the KV leaves (``_strip_pool`` parts them)
@@ -3510,11 +3587,11 @@ class DecodeEngine:
                 # + a full decode chunk in ONE host round-trip — the
                 # round count can never exceed the spec-off engine's
                 # (paged: the rewind travels inside the executable as
-                # a filled decrement, and the post-verify filled rides
-                # the chained pytree into the decode scan)
+                # a filled decrement, and the post-verify tables chain
+                # into the decode scan as the verify program's output)
                 tv0 = self._clock() if self.record_timing else 0.0
-                pool_op, verify_out = self._dispatch_verify(drafts,
-                                                            pool_op)
+                pool_op, tables, verify_out = self._dispatch_verify(
+                    drafts, pool_op, tables)
                 if self.record_timing:
                     ver_dt = self._clock() - tv0
             elif self.spec is not None:
@@ -3553,7 +3630,7 @@ class DecodeEngine:
                         (pool_op, self._toks, seq,
                          n_valid) = self._fused_jit(
                             self._params, self._state, pool_op,
-                            self._toks, temps, top_ks,
+                            tables, self._toks, temps, top_ks,
                             jnp.asarray(eos_ids),
                             jnp.asarray(remaining), keys)
                         self._observe("serving_fused_rounds", fuse_k)
@@ -3561,7 +3638,7 @@ class DecodeEngine:
                         (pool_op, self._toks, seq,
                          counts) = self._decode_jit(
                             self._params, self._state, pool_op,
-                            self._toks, temps, top_ks,
+                            tables, self._toks, temps, top_ks,
                             self._next_key(), *live)
                 if not self.async_rounds:
                     with self._span("serving.token_sync"):
@@ -3644,7 +3721,8 @@ class DecodeEngine:
                         "prefix_blocks_spliced", "frag_tokens",
                         "preempted", "paged_admit_deferred",
                         "paged_blocks_live", "paged_blocks_walked",
-                        "paged_blocks_per_step", "paged_steps_per_row"):
+                        "paged_blocks_per_step", "paged_steps_per_row",
+                        "table_uploads"):
                 self.tracer.counter(f"serving_{key}", self.stats[key])
         if self.prefix_cache is not None:
             for key in ("hits", "misses", "evictions"):

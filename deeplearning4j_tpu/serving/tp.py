@@ -38,8 +38,9 @@ tests/test_serving_tp.py and the ``bench_decode_tp`` row).
 In-spec/out-spec pytrees are derived from leaf KEY PATHS at trace
 time (``pk``/``pv``/``k``/``v`` under an attention layer's key ride
 the head sharding; everything else replicates), so the polymorphic
-cache dicts — dense rows during a cold paged admission, paged dicts
-with ring tables during decode — wrap without per-structure plumbing.
+cache dicts — dense rows during a cold paged admission, paged pool
+leaves beside one replicated block-table operand during decode — wrap
+without per-structure plumbing.
 """
 
 from __future__ import annotations
@@ -151,16 +152,16 @@ class TPContext:
 
     def replicate(self, host_array):
         """Commit one host array onto the mesh fully replicated. The
-        engine's per-round table/base/floor/filled operands must enter
-        every dispatch with the SAME (committed) sharding: a spec
-        round chains the verify executable's OUTPUT pool (committed
-        ``P()`` leaves) into the decode dispatch, while a plain round
-        builds the operands fresh on the host — uncommitted vs
+        engine's per-dispatch block-table operand must enter every
+        dispatch with the SAME (committed) sharding: a spec round
+        chains the verify executable's OUTPUT tables (a committed
+        ``P()`` array) into the decode dispatch, while a plain round
+        builds the operand fresh on the host — uncommitted vs
         committed hash as different jit keys, which cost the spec+tp
         engine a second decode lowering (caught by the compile-budget
-        gate). Called per layer on the HOST array so every layer gets
-        a distinct buffer (the donated dispatches reject one buffer
-        aliased through two pytree leaves)."""
+        gate). One array a dispatch, read by every layer: it is an
+        argument of its own beside the donated pool (its key path
+        names no attention layer, so ``_leaf_spec`` replicates it)."""
         return jax.device_put(host_array,
                               NamedSharding(self.mesh, P()))
 

@@ -32,9 +32,9 @@ from deeplearning4j_tpu.serving import (
 V = 12
 
 
-def _net(seed=7, stream_max_t=64):
+def _net(seed=7, stream_max_t=64, n_layers=2):
     net = MultiLayerNetwork(transformer_lm(
-        n_in=V, width=32, n_layers=2, n_heads=4, n_classes=V,
+        n_in=V, width=32, n_layers=n_layers, n_heads=4, n_classes=V,
         seed=seed)).init()
     for c in net.conf.confs:
         if hasattr(c.layer, "stream_max_t"):
@@ -559,3 +559,109 @@ class TestPagedUnits:
                 streamed[rid].extend(toks)
         for rid in ids:
             assert streamed[rid] == res[rid].tokens
+
+
+def _count_paged_calls(eng, name, pos=3):
+    """Wrap one of the engine's jitted programs, whose argument
+    ``pos`` is the table operand; the returned list grows by one a
+    call that was handed one."""
+    inner, calls = getattr(eng, name), []
+
+    def counted(*args):
+        if args[pos] is not None:
+            calls.append(args[pos])
+        return inner(*args)
+
+    setattr(eng, name, counted)
+    return calls
+
+
+class TestSharedTables:
+    """ISSUE 28: the block tables of a paged dispatch are uploaded
+    ONCE and enter the program beside the donated pool; every layer
+    reads the same arrays and no program hands them back a layer."""
+
+    @pytest.mark.parametrize("n_layers", [2, 6])
+    def test_one_upload_a_dispatch_at_any_depth(self, n_layers):
+        import jax
+        import jax.numpy as jnp
+
+        tracer = Tracer()
+        eng = DecodeEngine(_net(n_layers=n_layers), n_slots=2,
+                           decode_chunk=2, seed=0, paged_kv=True,
+                           block_tokens=8, tracer=tracer)
+        for p, n in CASES:
+            eng.submit(Request(p, n))
+        eng.run()
+        assert len(eng._pool) == n_layers
+        # cold blocking admissions take no table: the decode rounds
+        # are the paged dispatches, one upload each
+        assert eng.stats["chunks"] > 0
+        assert eng.stats["table_uploads"] == eng.stats["chunks"]
+        assert (tracer.latest_counters()["serving_table_uploads"]
+                == eng.stats["table_uploads"])
+        # what the decode program hands back: pool leaves, tokens,
+        # counters — no layer's table operands
+        out = jax.eval_shape(
+            eng._decode_jit, eng._params, eng._state, eng._pool,
+            eng._paged_tables(eng._kv_tabs), eng._toks,
+            jnp.asarray(eng._temps), jnp.asarray(eng._top_ks),
+            jax.random.key(0))
+        names = {str(getattr(k, "key", k))
+                 for path, _ in jax.tree_util.tree_flatten_with_path(
+                     out)[0] for k in path}
+        assert not names & {"table", "base", "floor", "filled"}, names
+        assert {"pk", "pv"} <= names
+        assert set(out[0]) == set(eng._pool)
+
+    def test_spec_round_rewinds_the_one_filled(self):
+        """A verify dispatch chained into the decode dispatch, with a
+        rejected draft: the rewind reaches the decode scan through the
+        tables the verify program hands back, and the round still
+        makes one upload."""
+        eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
+                           paged_kv=True, block_tokens=8,
+                           spec_draft_len=3)
+        uploaded = []
+        upload = eng._paged_tables
+
+        def uploading(tabs, chunk=1):
+            uploaded.append(upload(tabs, chunk))
+            return uploaded[-1]
+
+        eng._paged_tables = uploading
+        verified = _count_paged_calls(eng, "_verify_jit")
+        decoded = _count_paged_calls(eng, "_decode_jit")
+        ids = [eng.submit(Request(p, n)) for p, n in CASES]
+        res = eng.run()
+        for rid, (p, n) in zip(ids, CASES):
+            assert res[rid].tokens == _solo_generate(p, n)
+        spec_rounds = eng.stats["spec_rounds"]
+        assert spec_rounds > 0
+        assert eng.stats["spec_drafted"] > eng.stats["spec_accepted"]
+        # one upload a round, spec or plain
+        assert len(decoded) == eng.stats["chunks"] == len(uploaded)
+        assert eng.stats["table_uploads"] == len(uploaded)
+        # a spec round's upload goes to the verify program, and what
+        # its decode dispatch takes is that program's output
+        assert len(verified) == spec_rounds
+        assert all(any(t is u for u in uploaded) for t in verified)
+        fresh = sum(any(t is u for u in uploaded) for t in decoded)
+        assert fresh == len(decoded) - spec_rounds
+        assert eng.stats["spec_fallback_rounds"] == fresh > 0
+
+    def test_warm_chunked_admission_through_the_shared_operand(self):
+        eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
+                           paged_kv=True, block_tokens=8,
+                           prefix_cache_rows=4, prefill_chunk=4)
+        warm = _count_paged_calls(eng, "_chunk_jit", pos=5)
+        ids = [eng.submit(Request(p, n)) for p, n in CASES]
+        res = eng.run()
+        for rid, (p, n) in zip(ids, CASES):
+            assert res[rid].tokens == _solo_generate(p, n)
+        assert eng.stats["prefix_blocks_spliced"] > 0
+        assert warm, "no admission streamed through a block table"
+        assert all(t.shape == (1, 2 * eng._ring_slots + 2)
+                   for t in warm)
+        assert (eng.stats["table_uploads"]
+                == eng.stats["chunks"] + len(warm))

@@ -160,6 +160,24 @@ class TestTpCompileDiscipline:
             again = _submit_run(eng)
         assert list(again.values()) == list(first.values())
 
+    @pytest.mark.parametrize("tp", [1, 2])
+    def test_plain_and_spec_rounds_share_one_decode(self, rig, tp):
+        """ISSUE 28: a plain round hands the decode program the tables
+        the host just uploaded (committed replicated under tp), a
+        spec round the verify program's output: one lowering takes
+        both, and each round made one upload."""
+        eng, _ = rig(tp, True, 3, 4, "decode")
+        assert eng.stats["spec_rounds"] > 0
+        assert eng.stats["spec_fallback_rounds"] > 0
+        counts = eng.compile_counts()
+        assert counts["decode"] == 1, counts
+        assert 1 <= counts["verify"] <= 3, counts
+        assert eng.stats["table_uploads"] >= eng.stats["chunks"]
+        tables = eng._paged_tables(eng._kv_tabs)
+        assert tables.shape == (2, 2 * eng._ring_slots + 2)
+        assert len(tables.sharding.device_set) == tp
+        assert tables.sharding.is_fully_replicated
+
 
 class TestTpSharding:
     """Device-side acceptance: per-shard KV bytes == total/TP, every
@@ -405,7 +423,7 @@ class TestPagedFlashKernel:
                            prefill_chunk=4, prefix_cache_rows=4)
         bt, tm = eng.block_tokens, eng._wmax
         want = {"live": 0, "walked": 0}
-        inner = eng._paged_rnn_rows
+        inner = eng._paged_tables
 
         def spy(tabs, chunk=1):
             out = inner(tabs, chunk)
@@ -426,7 +444,7 @@ class TestPagedFlashKernel:
                     {e // per_step for e in hit})
             return out
 
-        eng._paged_rnn_rows = spy
+        eng._paged_tables = spy
         _submit_run(eng)
         assert eng.stats["paged_blocks_per_step"] >= 1
         assert eng.stats["paged_steps_per_row"] == -(-min(
