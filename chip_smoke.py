@@ -16,8 +16,8 @@ weights from a seed, data from ``datasets/markov.py``):
    ``GatewayClient``: prompt lengths in several pow2 buckets, two prompts
    sharing a long prefix. Checked: every request ends ``length``/``eos``;
    ids agree with the same net's teacher-forced forward at >= 0.9, cold
-   and warm; free-running agreement with ``net.generate`` and with a
-   ``paged_kv=False`` engine is printed exactly; on a TPU the decode
+   and warm; free-running agreement with ``net.generate`` is printed
+   exactly; on a TPU the decode
    executable's lowered program contains the pallas call;
    ``/v1/healthz`` is ok; a repeat of the warm round compiles nothing.
 2. **train** — the width-2048 x 8 flagship at B=16, T=512: one
@@ -52,7 +52,7 @@ import time
 
 import numpy as np
 
-#: full = the sizes bench.py's decode and flagship rows use; tiny = the
+#: full = the flagship models at a width the chip is needed for; tiny = the
 #: same program at sizes a CPU finishes in a minute
 SIZES = {
     "full": dict(
@@ -104,8 +104,8 @@ def flagship(vocab, width, n_layers, n_heads, window=None, **conf_kw):
 
 
 def release_device_memory() -> None:
-    """Drop dead nets and executables before the next model is built
-    (what bench.py:_release_device_memory does between heavy rows)."""
+    """Drop dead nets and executables before the next model is
+    built."""
     import gc
 
     import jax
@@ -251,11 +251,7 @@ def serve_phase(size, log, on_tpu: bool, workdir: str) -> None:
         build_parser,
         gateway_from_args,
     )
-    from deeplearning4j_tpu.serving import (
-        DecodeEngine,
-        GatewayClient,
-        Request,
-    )
+    from deeplearning4j_tpu.serving import GatewayClient
     from deeplearning4j_tpu.util.model_serializer import write_model
 
     vocab, n_new = size["vocab"], size["n_new"]
@@ -279,7 +275,7 @@ def serve_phase(size, log, on_tpu: bool, workdir: str) -> None:
     # DecodeEngine -> ServingGateway; kernel choice left on auto
     args = build_parser().parse_args([
         "serve", "--model", model_path, "--port", "0",
-        "--paged-kv", "--block-tokens", str(size["block_tokens"]),
+        "--block-tokens", str(size["block_tokens"]),
         "--slots", str(size["slots"]), "--prefix-cache-rows", "8"])
     gw = gateway_from_args(args).start()
     try:
@@ -385,16 +381,10 @@ def serve_phase(size, log, on_tpu: bool, workdir: str) -> None:
             "argmax_cold": cold[0], "argmax_warm": warm[0],
             "worst_tie": min(cold[2], warm[2])}
 
-    # Free-running, against the repo's two other decoders on the same
-    # weights: net.generate and the dense engine (paged_kv=False). After
-    # the first bf16 near-tie the sequences part for good, so the rate
-    # is the position of that first flip; the two references part from
-    # EACH OTHER the same way (ref_vs_ref) — printed exactly, not gated.
-    net.rnn_clear_previous_state()
-    dense = DecodeEngine(net, n_slots=size["slots"], paged_kv=False)
-    rids = {name: dense.submit(Request(prompt=p, max_new_tokens=n_new))
-            for name, p in prompts.items()}
-    done = dense.run()
+    # Free-running, against the repo's other decoder on the same
+    # weights, net.generate. After the first bf16 near-tie the
+    # sequences part for good, so the rate is the position of that
+    # first flip — printed exactly, not gated.
     for name, prompt in prompts.items():
         net.rnn_clear_previous_state()
         ref = np.asarray(net.generate(one_hot(prompt, vocab),
@@ -402,8 +392,6 @@ def serve_phase(size, log, on_tpu: bool, workdir: str) -> None:
         got = first[name]["tokens"]
         rates[name].update(
             generate=match_rate(got, ref),
-            dense_engine=match_rate(got, done[rids[name]].tokens),
-            ref_vs_ref=match_rate(ref, done[rids[name]].tokens),
             warm_vs_cold=match_rate(got, second[name]["tokens"]))
         log(f"serve: id agreement {name}: " + ", ".join(
             f"{k} {v:.4f}" for k, v in rates[name].items()))

@@ -411,7 +411,6 @@ def gateway_from_args(args):
             max_queue=args.max_queue,
             paranoid=args.paranoid,
             spec_draft_len=args.spec_draft_len,
-            paged_kv=args.paged_kv,
             block_tokens=args.block_tokens,
             kv_blocks=args.kv_blocks,
             tp=getattr(args, "tp", 1),
@@ -501,22 +500,20 @@ def _serve_child_argv(args, port: int, replica_id: str):
             "--prefix-cache-rows", str(args.prefix_cache_rows),
             "--prefill-chunk", str(args.prefill_chunk),
             "--admission-policy", args.admission_policy]
-    if args.paged_kv:
-        argv += ["--paged-kv", "--block-tokens",
-                 str(args.block_tokens)]
-        if args.kv_blocks is not None:
-            argv += ["--kv-blocks", str(args.kv_blocks)]
-        if getattr(args, "kv_host_tier_bytes", 0):
-            argv += ["--kv-host-tier-bytes",
-                     str(args.kv_host_tier_bytes)]
-        if getattr(args, "kv_disk_tier_path", None):
-            # per-replica subdirectory: ring files are engine-local
-            argv += ["--kv-disk-tier-path",
-                     os.path.join(args.kv_disk_tier_path,
-                                  replica_id)]
-            if getattr(args, "kv_disk_tier_bytes", None) is not None:
-                argv += ["--kv-disk-tier-bytes",
-                         str(args.kv_disk_tier_bytes)]
+    argv += ["--block-tokens", str(args.block_tokens)]
+    if args.kv_blocks is not None:
+        argv += ["--kv-blocks", str(args.kv_blocks)]
+    if getattr(args, "kv_host_tier_bytes", 0):
+        argv += ["--kv-host-tier-bytes",
+                 str(args.kv_host_tier_bytes)]
+    if getattr(args, "kv_disk_tier_path", None):
+        # per-replica subdirectory: ring files are engine-local
+        argv += ["--kv-disk-tier-path",
+                 os.path.join(args.kv_disk_tier_path,
+                              replica_id)]
+        if getattr(args, "kv_disk_tier_bytes", None) is not None:
+            argv += ["--kv-disk-tier-bytes",
+                     str(args.kv_disk_tier_bytes)]
     if getattr(args, "tp", 1) != 1:
         argv += ["--tp", str(args.tp)]
     if getattr(args, "use_flash_paged", "auto") != "auto":
@@ -852,15 +849,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="per-round health check + quarantine")
     s.add_argument("--spec-draft-len", type=int, default=0,
                    help="speculative n-gram draft length K (0 = off)")
-    s.add_argument("--paged-kv", action="store_true",
-                   help="paged KV memory: one block pool shared by "
-                        "slots and the prefix trie (zero-copy prefix "
-                        "hits, more concurrent slots per byte)")
     s.add_argument("--block-tokens", type=int, default=16,
-                   help="tokens per KV block (pow2; paged mode)")
+                   help="tokens per block of the KV pool that slots "
+                        "and the prefix trie share (pow2)")
     s.add_argument("--kv-blocks", type=int, default=None,
-                   help="block-pool size (default: the dense "
-                        "layout's byte budget)")
+                   help="block-pool size (default: a window for "
+                        "every slot and every trie entry)")
     s.add_argument("--tp", type=int, default=1,
                    help="tensor-parallel shards: decode/verify/chunk "
                         "run as shard_map programs over attention "
@@ -896,8 +890,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "(ISSUE 17): trie victims evicted under HBM "
                         "pressure pack into a host LRU this large "
                         "and reload via the jitted KV import instead "
-                        "of recomputing (0 = off; needs --paged-kv "
-                        "and --prefix-cache-rows > 0)")
+                        "of recomputing (0 = off; needs "
+                        "--prefix-cache-rows > 0)")
     s.add_argument("--kv-disk-tier-path", default=None,
                    help="disk-ring directory for spill-tier "
                         "overflow (ISSUE 17): payloads past the "
@@ -961,7 +955,6 @@ def build_parser() -> argparse.ArgumentParser:
     fl.add_argument("--prefill-chunk", type=int, default=0)
     fl.add_argument("--admission-policy", default="ttft",
                     choices=("ttft", "decode"))
-    fl.add_argument("--paged-kv", action="store_true")
     fl.add_argument("--block-tokens", type=int, default=16)
     fl.add_argument("--kv-blocks", type=int, default=None)
     fl.add_argument("--kv-host-tier-bytes", type=int, default=0,

@@ -1,7 +1,7 @@
 """Synthetic Markov-chain language-modeling data with an ANALYTIC
 entropy floor.
 
-The flagship transformer bench (bench.py) needs a convergence gate that
+The flagship transformer bench (an earlier round's bench.py) needs a convergence gate that
 is honest on a zero-egress machine: random-noise sequences (the old
 utilization rows) have nothing to learn, and any tiny real corpus would
 be memorized by a width-1024 model. An order-1 Markov chain solves both:

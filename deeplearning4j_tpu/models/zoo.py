@@ -117,7 +117,7 @@ def wide_cnn(
     conv-MFU control experiment — same conv machinery as lenet5 but
     with contraction sizes the 128x128 MXU can fill, demonstrating the
     framework's conv ceiling when the ARCHITECTURE permits
-    (BENCHMARKS.md conv-MFU section)."""
+    (an earlier round's BENCHMARKS.md conv-MFU section)."""
     return (
         NeuralNetConfiguration.Builder()
         .seed(seed)
@@ -282,8 +282,8 @@ def transformer_lm_flagship(
     (attention + 4x FFN + residuals, nn/layers/attention.py) with Adam
     and linear-warmup + cosine lr decay. Unlike the bare-attention
     ``transformer_lm`` (which diverges at width >= 1024 under any flat
-    lr — BENCHMARKS.md flagship section), this configuration trains
-    stably at MXU-filling widths; bench.py gates it against the
+    lr — an earlier round's BENCHMARKS.md flagship section), this configuration trains
+    stably at MXU-filling widths; an earlier round's bench.py gates it against the
     analytic Markov entropy floor (datasets/markov.py) at >= 40% MFU.
     """
     from deeplearning4j_tpu.nn.layers.attention import TransformerBlock

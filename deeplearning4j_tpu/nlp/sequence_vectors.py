@@ -117,7 +117,7 @@ class SequenceVectors:
         # table[uniform_int] is O(1) per draw, where categorical over
         # [V] logits materializes (B, K, V) gumbel noise — 4e9 floats
         # per batch at V=100k (measured ~130 ms/batch, the large-vocab
-        # NS wall; BENCHMARKS.md W2V section). Table quantization of
+        # NS wall; an earlier round's BENCHMARKS.md W2V section). Table quantization of
         # p^0.75 matches the reference's sampling semantics exactly.
         probs = np.asarray(unigram_table_probs(self.vocab), np.float64)
         tsize = int(min(2 ** 24, max(2 ** 20, 16 * v)))

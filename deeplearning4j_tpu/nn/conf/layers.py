@@ -275,7 +275,7 @@ class LayerNormalization(FeedForwardLayer):
     activations; ``n_in == n_out`` (a pure normalizer). The standard
     final-norm for pre-LN transformer stacks: without it the residual
     stream reaches the output head at depth-growing magnitude (measured:
-    width-1024 x 8 init loss 9.1 vs ln V = 4.16 — BENCHMARKS.md
+    width-1024 x 8 init loss 9.1 vs ln V = 4.16 — an earlier round's BENCHMARKS.md
     flagship section)."""
 
     eps: float = 1e-5

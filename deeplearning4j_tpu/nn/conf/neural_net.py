@@ -50,7 +50,7 @@ class NeuralNetConfiguration:
     # linearly from 0 over ``lr_warmup_steps`` then follows a cosine to
     # ``lr_min_fraction``*lr at ``lr_total_steps`` — the standard
     # schedule for transformer convergence at width >= 1024, where a
-    # flat lr diverges (BENCHMARKS.md flagship section). Mutually
+    # flat lr diverges (an earlier round's BENCHMARKS.md flagship section). Mutually
     # exclusive with learning_rate_schedule. jit-safe: pure jnp ops on
     # the iteration counter.
     lr_policy: Optional[str] = None

@@ -103,7 +103,7 @@ class MultiHeadSelfAttention(BaseRecurrentLayer):
     # engages at T >= 2048 when T % 512 == 0 (healthy kernel blocks),
     # and at T >= 8192 unconditionally (dense OOMs long before 32k)
     use_flash: Optional[bool] = None
-    # pallas PAGED-attention decode kernel (serving paged_kv engines;
+    # pallas PAGED-attention decode kernel (the serving engine's pool;
     # ISSUE 12): True forces it (TPU), False forces the XLA
     # gather-by-block-table program, "interpret" runs the kernel in
     # pallas interpret mode (the CPU parity-testing hook), None = auto
@@ -309,7 +309,7 @@ class AttentionImpl(LayerImplBase):
     @classmethod
     def _paged_attend(cls, lc, q, k, v, cache, mask=None):
         """Gather-by-block-table attention over the shared KV block
-        pool (the serving engine's ``paged_kv=True`` layout — vLLM's
+        pool (the serving engine's KV layout — vLLM's
         PagedAttention memory model on the XLA level: the pallas
         kernel :func:`_paged_flash_attention`, which walks the same
         ``ntab`` table entries a compute block of several pool blocks
@@ -347,8 +347,8 @@ class AttentionImpl(LayerImplBase):
         the gather inside one program, so position ``p``'s content is
         committed before any query with ``qpos >= p`` reads it; stale
         garbage past ``filled`` is causally masked and overwritten by
-        the next append (the rewind contract of
-        ``nn.streaming.drop_newest_tokens``). The host guarantees
+        the next append (which is what lets a speculative round rewind
+        a rejected tail by moving ``filled`` back). The host guarantees
         every block written here has refcount 1 (copy-on-write happens
         before dispatch), so shared prefix blocks are never mutated."""
         tm = lc.stream_max_t
@@ -486,14 +486,14 @@ class AttentionImpl(LayerImplBase):
         exactly the logits sequential decode would have produced after
         its first ``i`` chunk tokens (the property speculative
         acceptance rests on — serving/engine.py rewinds rejected
-        tails afterwards via ``nn.streaming.drop_newest_tokens``).
+        tails afterwards by moving ``filled`` back).
         ``mask=None`` (the decode hot path) keeps the original,
         roll-free program."""
         if isinstance(cache, dict) and "pk" in cache:
-            # paged block-pool layout (serving paged_kv engines): same
+            # block-pool layout (the serving engine's): same
             # streaming contract, storage indirected through per-row
-            # block tables — the dense row path below stays untouched
-            # for paged=False
+            # block tables — the dense row path below is the net's own
+            # streaming cache (``generate``, a cold admission's row)
             return cls._paged_attend(lc, q, k, v, cache, mask)
         tm = lc.stream_max_t
         t = q.shape[2]
@@ -565,7 +565,7 @@ class TransformerBlock(BaseRecurrentLayer):
     This is the convergence-grade building unit the bare
     ``MultiHeadSelfAttention`` stack lacks: without the residual path
     and pre-LN, width ≥ 1024 stacks diverge at any useful lr (measured,
-    BENCHMARKS.md flagship section), which is the standard
+    an earlier round's BENCHMARKS.md flagship section), which is the standard
     transformer-training result. NEW capability vs the 2015 reference
     (predates attention; SURVEY.md §5.7 mandates first-class
     long-context), layered on the framework's [N, C, T] recurrent
@@ -759,7 +759,7 @@ def _flash_attention(q, k, v, causal):
     >= 512 blocks BELOW 8192; at T >= 8192 it engages unconditionally
     (degraded 128/256-blocks included — dense's O(T²) scores OOM there,
     so a slow flash beats no flash). A forced use_flash=True accepts
-    whatever divisor T offers. Measured in BENCHMARKS.md."""
+    whatever divisor T offers. Measured in an earlier round's BENCHMARKS.md."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes,
         flash_attention,

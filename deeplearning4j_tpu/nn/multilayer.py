@@ -486,7 +486,7 @@ class MultiLayerNetwork:
         normalized compute dtype, token ids → one-hot), keeping the
         wire format minimal. ``sync_each_window`` fetches each window's
         last score before uploading the next — on transports where H2D
-        cannot overlap compute (BENCHMARKS.md "host-fed" notes), a
+        cannot overlap compute (seen on an earlier round's host), a
         serialized upload is faster than a degraded concurrent one for
         byte-heavy windows.
 

@@ -2,13 +2,13 @@
 
 Shared by the network-level rnn-state APIs
 (``MultiLayerNetwork.rnn_clear_previous_state`` /
-``ComputationGraph.rnn_clear_previous_state``) and the serving decode
-engine (``serving/engine.py``).
+``ComputationGraph.rnn_clear_previous_state``) and, for the length
+buckets, the serving decode engine (``serving/engine.py``).
 
 CONTRACT — streaming state is batch-major: every leaf of an rnn-state
 pytree (attention ``k``/``v``/``filled``, GravesLSTM/GRU carried
 ``(h, c)``) has the batch dimension on axis 0, one row per batch
-element. The serving engine treats those rows as KV-cache *slots*;
+element (a *slot*);
 ``clear_state_rows`` relies on the contract to reset individual slots
 without touching their neighbours. A zeroed attention row is exactly
 the empty-cache state (``filled == 0`` masks every cached position in
@@ -87,73 +87,13 @@ def reset_streaming_state(rnn_state: Any, slots) -> Any:
     return clear_state_rows(rnn_state, slots)
 
 
-def drop_newest_tokens(rnn_state: Any, drop) -> Any:
-    """Rewind every attention KV-cache in a streaming-state pytree by
-    ``drop`` tokens (0 or more, static or traced), returning the state
-    as it was before the newest ``drop`` tokens streamed in. ``drop``
-    may be a scalar (every batch row rewinds equally — the prefix-cache
-    fetch path) or a per-row ``[N]`` vector (each row rewinds its own
-    count — the speculative-verify path, where every slot keeps its
-    accepted prefix and sheds its own rejected tail).
-
-    Valid because K/V at a position are per-token projections of that
-    token alone: removing the newest entries and re-right-aligning
-    reproduces the shorter prefix's cache exactly. The roll wraps the
-    dropped K/V into the left region that the decremented ``filled``
-    already invalidates (the same mask argument as
-    ``AttentionImpl._prefill_cache``), so they never receive attention
-    weight. Used by the serving prefix cache (an exact-match prompt
-    rewinds the cached state one token so the final prompt token can be
-    re-streamed to produce first-token logits) and by the speculative
-    verify step (rejected draft tails roll back before the bonus token
-    commits). The caller guarantees ``drop <= filled`` per row AND that
-    none of the dropped tokens pushed an older token out of the sliding
-    window (a slid-out token cannot be recovered by rewind; the serving
-    engine caps draft lengths at ``window - filled - 1`` for exactly
-    this reason). Raises on non-attention state (an LSTM carry has no
-    per-token axis to rewind)."""
-    drop = jnp.asarray(drop)
-    if drop.ndim > 1:
-        raise ValueError(
-            f"drop must be a scalar or per-row vector; got shape "
-            f"{drop.shape}")
-    if drop.ndim == 1:
-        roll = jax.vmap(lambda a, s: jnp.roll(a, s, axis=1))
-    else:
-        def roll(a, s):
-            return jnp.roll(a, s, axis=2)
-    out = {}
-    for name, st in (rnn_state or {}).items():
-        if not (isinstance(st, dict) and "filled" in st):
-            raise ValueError(
-                f"streaming state for layer {name!r} carries no "
-                "KV-cache 'filled' vector — only attention caches can "
-                "be rewound by token")
-        if "pk" in st:
-            # paged block-pool cache (serving/block_pool.py): tokens
-            # live at fixed absolute positions in pool blocks, so a
-            # rewind is "pop blocks + mask tail" — the length counter
-            # moves back and the stale tail is masked by the causal
-            # position check in AttentionImpl._paged_attend (the next
-            # append overwrites it in place). Block bookkeeping (the
-            # pop) is host-side, in the engine's BlockTable.
-            out[name] = dict(st, filled=st["filled"] - drop)
-            continue
-        out[name] = {
-            "k": roll(st["k"], drop),
-            "v": roll(st["v"], drop),
-            "filled": st["filled"] - drop,
-        }
-    return out
-
-
 def clear_state_rows(rnn_state: Any, slots: Iterable[int]) -> Any:
     """Zero the given batch rows of every leaf in a streaming-state
     pytree, leaving all other rows untouched.
 
-    This is the per-slot counterpart of the whole-batch state wipe: the
-    serving engine evicts a finished request by clearing its slot while
-    the other slots keep decoding mid-flight. Slot indices are
+    This is the per-slot counterpart of the whole-batch state wipe: a
+    caller streaming a batch of sequences restarts one of them while
+    the others keep going. Slot indices are
     validated against the state's batch size; a scalar leaf violates
     the batch-major contract and raises."""
     idx = sorted({int(s) for s in slots})

@@ -1,5 +1,5 @@
-"""Serving subsystem: continuous-batching decode over slot-based KV
-caches (ISSUE 1 tentpole; the layer that multiplexes many concurrent
+"""Serving subsystem: continuous-batching decode over ONE paged KV
+block pool (ISSUE 1 tentpole; the layer that multiplexes many concurrent
 requests onto one compiled batched decode step), the radix prefix
 cache and chunked-prefill admission that make admissions prefix-aware
 and non-blocking (ISSUE 2 tentpole), the fault-tolerant runtime —
@@ -8,9 +8,11 @@ and crash-safe snapshot/resume (ISSUE 3 tentpole) — and
 self-speculative decoding: n-gram drafting with single-pass K-token
 verification (ISSUE 4 tentpole) — and the streaming HTTP serving
 gateway + client that turn the engine into a deployable server
-(ISSUE 5 tentpole) — and paged KV memory: one block-pool cache shared
-by decode slots and the prefix trie, with zero-copy prefix splices and
-copy-on-write divergence (ISSUE 6 tentpole, ``paged_kv=True``) — and
+(ISSUE 5 tentpole) — and paged KV memory, the engine's only KV
+layout: one block-pool cache shared by decode slots and the prefix
+trie (whose entries lease its blocks), with zero-copy prefix splices,
+copy-on-write divergence, and a cold admission that prefills a dense
+B=1 row and scatters it into blocks (ISSUE 6 tentpole) — and
 the multi-replica router tier: a failure-tolerant prefix-affinity
 front door over N gateway replicas with journaled in-flight replay
 onto survivors (ISSUE 9 tentpole) — and fleet-wide distributed
@@ -81,7 +83,6 @@ from deeplearning4j_tpu.serving.router import (
     ServingRouter,
 )
 from deeplearning4j_tpu.serving.prefix_cache import (
-    PagedPrefixCache,
     PrefixHit,
     RadixPrefixCache,
 )
@@ -128,7 +129,6 @@ __all__ = [
     "ManualClock",
     "NgramDraftTable",
     "ReplicaProcess",
-    "PagedPrefixCache",
     "PrefixHit",
     "REPLICA_STATES",
     "ROLES",

@@ -1,12 +1,11 @@
 """Paged KV memory: ONE device-resident block pool shared by decode
 slots and the radix prefix trie (ISSUE 6 tentpole).
 
-The dense serving layout gives every decode slot a whole window-sized
-KV row and the prefix cache a SECOND whole-row pool, so concurrency is
-bound by ``B x window`` contiguous rows and every prefix hit pays a
-full-row ``prefix_fetch`` copy. This module replaces both with the
-PagedAttention memory model (Kwon et al. 2023; RadixAttention sharing,
-Zheng et al. 2024):
+A layout of one window-sized KV row a slot (and a second whole-row
+pool for cached prefixes) bounds concurrency by ``B x window``
+contiguous rows and makes every prefix hit a full-row copy. The
+engine's one KV layout is the PagedAttention memory model instead
+(Kwon et al. 2023; RadixAttention sharing, Zheng et al. 2024):
 
 - **Blocks** — the pool is ``kv_blocks`` fixed-size token blocks per
   attention layer (``[n_blocks, block_tokens, H, dh]``); a block holds
@@ -25,16 +24,14 @@ Zheng et al. 2024):
 - **Allocation on demand** — the engine reserves blocks only as
   ``filled`` crosses a block boundary, so short requests hold short
   tables and the same device bytes serve strictly more concurrent
-  slots than the dense row layout (the ``decode_paged_max_slots``
-  bench gate).
+  slots than a row a slot would.
 
 The pool itself holds only host bookkeeping; device arrays live in the
 engine's rnn-state pytree (``{"pk","pv"}`` per attention layer) so the
 existing jitted decode/verify/chunk executables thread them through
 ``AttentionImpl._paged_attend`` unchanged. The two jits owned here
 (``copy_block`` for CoW, ``zero_block`` for quarantine scrubbing)
-compile once each — the bounded-compile-count discipline of the dense
-engine carries over.
+compile once each, under the engine's bounded-compile-count discipline.
 """
 
 from __future__ import annotations

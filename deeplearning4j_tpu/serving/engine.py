@@ -1,11 +1,14 @@
-"""Continuous-batching decode engine over a slot-based KV cache.
+"""Continuous-batching decode engine over a paged KV block pool.
 
 The serving counterpart of ``MultiLayerNetwork.generate``: instead of
-one request owning the whole batch (and the chip), a fixed pool of
-``n_slots`` KV-cache slots is multiplexed across many concurrent
-requests — the continuous-batching pattern of modern inference stacks,
-grown out of the reference's streaming ``rnnTimeStep`` contract
-(SURVEY §1 L1).
+one request owning the whole batch (and the chip), ``n_slots`` decode
+slots are multiplexed across many concurrent requests — the
+continuous-batching pattern of modern inference stacks, grown out of
+the reference's streaming ``rnnTimeStep`` contract (SURVEY §1 L1).
+Keys and values live in ONE layout: a device pool of fixed-size blocks
+per attention layer (``[kv_blocks, block_tokens, H, dh]``,
+serving/block_pool.py); a slot owns a host-side block table, and the
+radix prefix trie (serving/prefix_cache.py) leases the same blocks.
 
 Dataflow per scheduling round (one ``step()``):
 
@@ -13,20 +16,21 @@ Dataflow per scheduling round (one ``step()``):
    bit-identical PR 2 engine) — requeue fault victims whose backoff
    elapsed, apply the round's scheduled :class:`FaultPlan` events,
    sweep deadlines/queue-timeouts (expired requests terminate wherever
-   they are: queued, mid-admission, or mid-decode — eviction reuses
-   the per-slot row-zeroing path, so neighbours never stall).
+   they are: queued, mid-admission, or mid-decode — eviction only
+   releases block references, so neighbours never stall).
 1. **Admit** — while a slot is free and requests are queued, prefill
    the next prompt at batch 1 (right-padded to a pow2 length bucket,
    masked — streams identically to an unpadded prefill, see
-   ``AttentionImpl._prefill_cache``), then scatter the resulting cache
-   row and first sampled token into the pool at the free slot index
-   (one ``dynamic_update_slice`` computation; the slot index is a
-   traced operand, so admission never retraces). With the radix prefix
-   cache enabled (``prefix_cache_rows``, serving/prefix_cache.py), the
-   longest cached prefix of the prompt is fetched from a second
-   device-resident row pool instead of recomputed, and only the
-   *suffix* prefills; every completed admission stores its post-prefill
-   state back, so shared system prompts/templates prefill once.
+   ``AttentionImpl._prefill_cache``) through the net's own streaming
+   cache, then scatter that dense B=1 row into freshly allocated pool
+   blocks (``paged_scatter``; the block table is a traced operand, so
+   admission never retraces) and the first sampled token into the
+   slot. With the radix prefix cache enabled (``prefix_cache_rows``,
+   serving/prefix_cache.py), the longest cached prefix of the prompt
+   is SPLICED — its blocks referenced from the new slot's table, no
+   copy — and only the *suffix* prefills, straight into the table;
+   every completed admission leaves its blocks leased to the trie, so
+   shared system prompts/templates prefill once.
 2. **Chunked prefill** (``prefill_chunk > 0``) — suffix prefill splits
    into fixed-width masked chunks that resume the carried cache
    (``AttentionImpl._stream_attend`` with a chunk mask), scheduled
@@ -50,9 +54,10 @@ Dataflow per scheduling round (one ``step()``):
    ``AttentionImpl._stream_attend`` path chunked prefill uses) scores
    all B slots' drafts at once, per-slot accepted-prefix lengths are
    computed on device (serving/sampler.py ``greedy_acceptance``),
-   rejected tails are rolled back with the per-row
-   ``drop_newest_tokens`` rewind, the model's own token at the first
-   divergence commits as the bonus token, and the decode scan resumes
+   rejected tails are rolled back by moving each row's ``filled``
+   back (the tokens stay where they lie in their blocks, masked), the
+   model's own token at the first divergence commits as the bonus
+   token, and the decode scan resumes
    from the verified state — both dispatches land in ONE host
    round-trip, so a speculative round commits
    ``decode_chunk + accepted + 1`` tokens per slot where a plain round
@@ -69,17 +74,19 @@ Dataflow per scheduling round (one ``step()``):
    prefill chunks do (``Scheduler.plan_chunks``).
 4. **Detect & quarantine** (``paranoid=True``) — ONE extra jitted
    finiteness check over the pool + sampled ids (the single new
-   executable of the failure-handling layer). A non-finite slot is
-   quarantined: rows zeroed, poisoned prefix-cache entries
-   invalidated, the victim re-queued with capped retry + exponential
-   backoff (terminal ``finish_reason="fault"`` past the cap). Healthy
+   executable of the failure-handling layer; its verdict is per
+   BLOCK, mapped to slots through the host tables). A non-finite slot
+   is quarantined: its block references released (a poisoned block is
+   scrubbed when its last reference drops), poisoned prefix-cache
+   entries invalidated, the victim re-queued with capped retry +
+   exponential backoff (terminal ``finish_reason="fault"`` past the
+   cap). Healthy
    slots are bit-unaffected — the same row-independence that lets
    idle slots ride along.
 5. **Evict** — requests that hit ``max_new_tokens`` (or ``eos_id``)
-   free their slot without stalling the batch; the slot's rows are
-   zeroed via the per-slot state reset
-   (``rnn_clear_previous_state(slots=...)`` semantics,
-   nn/streaming.py) and the next admission overwrites them.
+   free their slot without stalling the batch; the slot's blocks
+   return to the free list (those the trie or another slot still
+   references stay resident).
 
 **Incremental delivery** (ISSUE 5; default off = bit-identical): with
 ``on_delta=callback`` (or ``emit_deltas=True`` + ``drain_deltas()``),
@@ -105,8 +112,9 @@ tests/test_serving_faults.py).
 
 Compile-count guarantees (asserted in tests/test_serving_engine.py,
 tests/test_serving_prefix_cache.py, tests/test_serving_faults.py and
-tests/test_serving_spec.py): ONE decode-step executable, ONE admit
-executable, ONE prefix-fetch and ONE prefix-store executable, ONE
+tests/test_serving_spec.py): ONE decode-step executable, ONE
+``paged_scatter`` and ONE ``paged_tok`` executable (a cold admission's
+row and first token), ONE
 health-check executable (paranoid mode only — the only addition of the
 failure layer), ONE verify executable per pow2 draft-width bucket
 (speculative mode only — O(log spec_draft_len) total), ONE
@@ -137,17 +145,10 @@ from deeplearning4j_tpu.nn.layers.attention import (
     guard_streamable,
     paged_walk_counts,
 )
-from deeplearning4j_tpu.nn.streaming import (
-    clear_state_rows,
-    drop_newest_tokens,
-    scan_length_bucket,
-)
+from deeplearning4j_tpu.nn.streaming import scan_length_bucket
 from deeplearning4j_tpu.serving.block_pool import BlockPool, BlockTable
 from deeplearning4j_tpu.serving.faults import FaultEvent, FaultPlan, poison_rows
-from deeplearning4j_tpu.serving.prefix_cache import (
-    PagedPrefixCache,
-    RadixPrefixCache,
-)
+from deeplearning4j_tpu.serving.prefix_cache import RadixPrefixCache
 from deeplearning4j_tpu.serving.sampler import (
     residual_sample,
     sample_tokens,
@@ -176,8 +177,9 @@ class _Slot:
     tokens: List[int]
     prefix_reused: int = 0
     ttft_s: Optional[float] = None
-    #: prefix-cache row this admission fetched from (quarantine scrubs
-    #: it if the slot turns out poisoned), or None on a cold admission
+    #: prefix-cache entry this admission spliced from (quarantine
+    #: scrubs it if the slot turns out poisoned), or None on a cold
+    #: admission
     hit_row: Optional[int] = None
     #: speculative-decoding counters: tokens drafted for / accepted by
     #: this request (surface on its GenerationResult)
@@ -188,9 +190,10 @@ class _Slot:
 @dataclasses.dataclass
 class _Pending:
     """An admission in flight: the slot is reserved, the suffix is
-    part-way through (chunked) prefill, and ``rnn`` carries the B=1
-    streaming state accumulated so far (None before the first cold
-    chunk; the fetched prefix state on a cache hit). ``seq`` is the
+    part-way through (chunked) prefill, and ``rnn`` carries a COLD
+    admission's dense B=1 streaming state accumulated so far (None
+    before the first cold chunk, and throughout a warm admission,
+    whose chunks append through ``tab``). ``seq`` is the
     token sequence being prefilled — the request's prompt for a live
     admission, prompt + generated ids for a snapshot-restore rebuild."""
 
@@ -202,10 +205,10 @@ class _Pending:
     matched: int                  # prompt tokens reused from the cache
     hit: Any                      # PrefixHit lease to release, or None
     seq: List[int] = dataclasses.field(default_factory=list)
-    #: paged admissions (``paged_kv=True``): the slot's block table —
-    #: spliced trie blocks on a warm hit (suffix chunks then append
-    #: THROUGH it, zero-copy), or None until a cold admission's dense
-    #: prefill completes and scatters into freshly allocated blocks
+    #: the slot's block table: spliced trie blocks on a warm hit
+    #: (suffix chunks then append THROUGH it, zero-copy), or None
+    #: until a cold admission's dense prefill completes and scatters
+    #: into freshly allocated blocks
     tab: Optional[BlockTable] = None
     #: what each prefill program counted (device scalars), added to
     #: ``stats`` when the first token is fetched
@@ -255,7 +258,7 @@ class _PhaseClock:
     """Host-side per-request phase clock (ISSUE 7 tentpole): every
     request accumulates a monotone, DISJOINT-interval phase breakdown
     — queue wait, admission (split cold-prefill / chunked-suffix /
-    prefix-splice / prefix-fetch), per-round decode / verify / stall —
+    prefix-splice), per-round decode / verify / stall —
     plus an ordered event timeline, one entry per phase transition
     (capped: a pathological million-round request cannot grow the
     recorder without bound). Because every attributed interval is a
@@ -332,15 +335,13 @@ class _PhaseClock:
         p = self.phase_totals()
         admission = (p.get("admit_cold", 0.0)
                      + p.get("admit_chunk", 0.0)
-                     + p.get("admit_splice", 0.0)
-                     + p.get("admit_fetch", 0.0))
+                     + p.get("admit_splice", 0.0))
         return {
             "queue_wait_s": p.get("queue_wait", 0.0),
             "admission_s": admission,
             "admission_cold_s": p.get("admit_cold", 0.0),
             "admission_chunked_s": p.get("admit_chunk", 0.0),
-            "admission_splice_s": (p.get("admit_splice", 0.0)
-                                   + p.get("admit_fetch", 0.0)),
+            "admission_splice_s": p.get("admit_splice", 0.0),
             "decode_s": p.get("decode", 0.0),
             "verify_s": p.get("verify", 0.0),
             "stall_s": p.get("stall", 0.0),
@@ -405,7 +406,7 @@ SERVING_TRACK_HELP = {
                            "prefix; ISSUE 14)",
     "serving_admission_warm_s": "admission device-work wall for "
                                 "requests that reused a cached "
-                                "prefix (splice/fetch + suffix "
+                                "prefix (splice + suffix "
                                 "prefill) — the warm half of the "
                                 "warm-vs-recompute comparison",
     "serving_admission_cold_s": "admission device-work wall for "
@@ -537,12 +538,6 @@ def _unpack_tables(tabs):
             "floor": tabs[:, 2 * s], "filled": tabs[:, 2 * s + 1]}
 
 
-def _filled_of(tabs):
-    """``filled`` of the packed operand (its last column); None for
-    the dense layout's None."""
-    return None if tabs is None else tabs[:, -1]
-
-
 class DecodeEngine:
     """Slot-multiplexed batched decoding for one LM-shaped network.
 
@@ -557,9 +552,14 @@ class DecodeEngine:
     advances that many tokens per dispatch (amortizing host round
     trips) and admissions/evictions happen at chunk boundaries.
 
-    ``prefix_cache_rows > 0`` enables the radix prefix cache (a second
-    device pool of that many KV rows; serving/prefix_cache.py):
-    admissions reuse the longest cached prefix of their prompt and
+    Keys and values live in a pool of ``kv_blocks`` blocks of
+    ``block_tokens`` tokens per attention layer (the engine's only KV
+    layout); a slot's block table grows a block at a time and frees
+    blocks that slide out of every layer's window.
+    ``prefix_cache_rows > 0`` enables the radix prefix cache (a trie
+    of at most that many entries, each leasing pool blocks;
+    serving/prefix_cache.py):
+    admissions splice the longest cached prefix of their prompt and
     prefill only the suffix. ``prefill_chunk > 0`` enables chunked
     (non-blocking) admission: suffix prefill runs in fixed-width chunks
     between decode rounds, paced by ``admission_policy`` ("ttft" or
@@ -634,7 +634,7 @@ class DecodeEngine:
     bit-identical and the executable set is unchanged, while the
     inter-round host gap (lock yields, submit handling) overlaps
     device compute instead of inflating decode ITL under admission
-    storms (``bench_kv_transfer`` row 2). ``export_kv``/``import_kv``
+    storms. ``export_kv``/``import_kv``
     ship warmed prefixes across replicas (serving/kv_transfer.py).
 
     ``snapshot()``/``DecodeEngine.restore()`` round-trip the full
@@ -645,7 +645,7 @@ class DecodeEngine:
     in-flight round before snapshotting.
 
     An optional ``profiler.tracer.Tracer`` receives prefill/admit/
-    decode/prefix-fetch spans plus per-round counters (admitted,
+    decode/prefix-splice spans plus per-round counters (admitted,
     evicted, prefix hits/misses, chunks scheduled, tokens decoded,
     occupancy, tokens/sec) and cumulative failure-event tracks
     (``serving_deadline_expired``, ``serving_shed``,
@@ -675,8 +675,8 @@ class DecodeEngine:
       one back — the gateway's ``GET /v1/requests/<id>/trace``.
     - every serving span carries the request id(s) in its args
       (``serving.admit``/``prefill``/``prefill_chunk``/
-      ``decode_chunk``/``spec_verify``/``prefix_fetch``/
-      ``prefix_splice``/``cow_copy``), so a Chrome trace is
+      ``decode_chunk``/``spec_verify``/``prefix_splice``/
+      ``cow_copy``), so a Chrome trace is
       filterable by request."""
 
     #: valid shed policies for a full admission queue: reject the new
@@ -721,7 +721,9 @@ class DecodeEngine:
                  draft_source: str = "ngram",
                  on_delta=None,
                  emit_deltas: bool = False,
-                 paged_kv: bool = False,
+                 # accepted only because the benchmark's configuration
+                 # files pass it: the block pool is the one KV layout
+                 paged_kv: bool = True,
                  block_tokens: int = 16,
                  kv_blocks: Optional[int] = None,
                  record_timing: bool = True,
@@ -734,6 +736,10 @@ class DecodeEngine:
                  kv_host_tier_bytes: int = 0,
                  kv_disk_tier_path: Optional[str] = None,
                  kv_disk_tier_bytes: Optional[int] = None):
+        if not paged_kv:
+            raise ValueError(
+                "paged_kv=False: the dense KV layout was removed in "
+                "PR 29; the block pool is the engine's only layout")
         if n_slots < 1:
             raise ValueError(f"n_slots {n_slots} < 1")
         if decode_chunk < 1:
@@ -885,7 +891,7 @@ class DecodeEngine:
         self.prefill_chunk = int(prefill_chunk)
         # -- multi-tenant QoS (ISSUE 13; default off = the seed FIFO
         # scheduler, zero per-tenant bookkeeping — tenancy must be
-        # free when unused, gated by bench_tenant_qos_overhead) ------
+        # free when unused) ------------------------------------------
         self.tenants = tenants
         sched_kwargs = dict(min_bucket=min_prompt_bucket,
                             prefill_chunk=self.prefill_chunk,
@@ -907,75 +913,62 @@ class DecodeEngine:
         #: shows ``{replica=...,tenant=...}``
         self._tenant_hists: Dict[str, Any] = {}
         self.tenant_stats: Dict[str, Dict[str, int]] = {}
-        # -- paged KV block pool (ISSUE 6; default off = the
-        # bit-identical dense engine) ---------------------------------
-        self.paged_kv = bool(paged_kv)
-        self.block_tokens = int(block_tokens)
+        # -- the KV block pool (ISSUE 6) ------------------------------
+        self.block_tokens = bt = int(block_tokens)
         self._wmax = max(windows)      # widest layer window (block
         #                                lifetimes honour every layer)
-        self.block_pool: Optional[BlockPool] = None
         self._kv_tabs: List[Optional[BlockTable]] = (
             [None] * self.n_slots)
-        self._ring_slots = 0
-        self.kv_blocks = 0
-        if self.paged_kv:
-            bt = self.block_tokens
-            if bt < 1 or (bt & (bt - 1)):
-                raise ValueError(
-                    f"block_tokens {bt} must be a power of two")
-            if bt > self.window:
-                raise ValueError(
-                    f"block_tokens {bt} exceeds the cache window "
-                    f"({self.window}) — a block must fit inside it")
-            # ring width: the window, plus the widest single dispatch
-            # (a blocking-mode suffix chunk can be a whole window) plus
-            # one round's decode/verify writes — sized so a logical
-            # block is never recycled while any in-flight query can
-            # still reach it (see AttentionImpl._paged_attend)
-            # a fused scan writes K rounds of decode tokens before the
-            # host sees any of them — the ring must cover the widest
-            # single dispatch, whichever path issues it
-            round_write = max(
-                self.decode_chunk + self.spec_draft_len + 1,
-                self.fused_rounds * self.decode_chunk)
-            self._ring_slots = (
-                -(-self._wmax // bt) + -(-self.window // bt)
-                + -(-round_write // bt) + 3)
-            # one slot's worst-case residency: a full window of
-            # blocks, one round of decode/verify appends, plus
-            # boundary slack (ring width above is ADDRESSING span,
-            # not occupancy — slid-out blocks free as they expire)
-            slot_worst = (-(-self._wmax // bt)
-                          + -(-round_write // bt) + 3)
-            if kv_blocks is None:
-                # default: the DENSE layout's device bytes — n_slots
-                # window rows plus the dense prefix pool's rows — with
-                # per-slot append slack, so paged-on is an
-                # apples-to-apples swap that frees capacity instead of
-                # consuming more
-                kv_blocks = max(
-                    -(-self._wmax // bt)
-                    * (self.n_slots + int(prefix_cache_rows))
-                    + self.n_slots * (-(-round_write // bt) + 2),
-                    slot_worst)
-            self.kv_blocks = int(kv_blocks)
-            if self.kv_blocks < slot_worst:
-                raise ValueError(
-                    f"kv_blocks {self.kv_blocks} cannot hold one "
-                    f"slot's window + one round of writes "
-                    f"({slot_worst} blocks of {bt} tokens)")
-            self.block_pool = BlockPool(self.kv_blocks, bt,
-                                        jit_wrap=self._jit)
-        if prefix_cache_rows and self.paged_kv:
-            # paged trie: entries lease pool BLOCKS (zero-copy); the
-            # row count caps entries, the block pool caps bytes
-            self.prefix_cache = PagedPrefixCache(
-                prefix_cache_rows, self.block_tokens,
-                ref_block=self.block_pool.ref,
-                release_block=self._release_block)
-        else:
-            self.prefix_cache = (RadixPrefixCache(prefix_cache_rows)
-                                 if prefix_cache_rows else None)
+        if bt < 1 or (bt & (bt - 1)):
+            raise ValueError(
+                f"block_tokens {bt} must be a power of two")
+        if bt > self.window:
+            raise ValueError(
+                f"block_tokens {bt} exceeds the cache window "
+                f"({self.window}) — a block must fit inside it")
+        # ring width: the window, plus the widest single dispatch
+        # (a blocking-mode suffix chunk can be a whole window) plus
+        # one round's decode/verify writes — sized so a logical
+        # block is never recycled while any in-flight query can
+        # still reach it (see AttentionImpl._paged_attend). A fused
+        # scan writes K rounds of decode tokens before the host sees
+        # any of them: the ring covers the widest single dispatch,
+        # whichever path issues it
+        round_write = max(
+            self.decode_chunk + self.spec_draft_len + 1,
+            self.fused_rounds * self.decode_chunk)
+        self._ring_slots = (
+            -(-self._wmax // bt) + -(-self.window // bt)
+            + -(-round_write // bt) + 3)
+        # one slot's worst-case residency: a full window of
+        # blocks, one round of decode/verify appends, plus
+        # boundary slack (ring width above is ADDRESSING span,
+        # not occupancy — slid-out blocks free as they expire)
+        slot_worst = (-(-self._wmax // bt)
+                      + -(-round_write // bt) + 3)
+        if kv_blocks is None:
+            # default: a whole window for every slot and every trie
+            # entry, with per-slot append slack
+            kv_blocks = max(
+                -(-self._wmax // bt)
+                * (self.n_slots + int(prefix_cache_rows))
+                + self.n_slots * (-(-round_write // bt) + 2),
+                slot_worst)
+        self.kv_blocks = int(kv_blocks)
+        if self.kv_blocks < slot_worst:
+            raise ValueError(
+                f"kv_blocks {self.kv_blocks} cannot hold one "
+                f"slot's window + one round of writes "
+                f"({slot_worst} blocks of {bt} tokens)")
+        self.block_pool = BlockPool(self.kv_blocks, bt,
+                                    jit_wrap=self._jit)
+        #: the prefix trie: entries lease pool BLOCKS (zero-copy); the
+        #: row count caps entries, the block pool caps bytes
+        self.prefix_cache = (
+            RadixPrefixCache(prefix_cache_rows, bt,
+                             ref_block=self.block_pool.ref,
+                             release_block=self._release_block)
+            if prefix_cache_rows else None)
         # -- tiered KV spill store (ISSUE 17; default off = the
         # evict-to-recompute engine). Trie victims export via the
         # jitted kv_gather into packed DKV1 payloads held in a
@@ -993,11 +986,10 @@ class DecodeEngine:
         #: the END of step() so spilling never blocks the decode round
         self._pending_spills: List[Tuple] = []
         if (self.kv_host_tier_bytes or kv_disk_tier_path):
-            if not isinstance(self.prefix_cache, PagedPrefixCache):
+            if self.prefix_cache is None:
                 raise ValueError(
-                    "the KV spill tier needs paged_kv=True and "
-                    "prefix_cache_rows > 0 (it spills paged trie "
-                    "victims)")
+                    "the KV spill tier needs prefix_cache_rows > 0 "
+                    "(it spills trie victims)")
             from deeplearning4j_tpu.serving.kv_tier import KVTierStore
 
             self.kv_tier = KVTierStore(
@@ -1091,8 +1083,11 @@ class DecodeEngine:
         self._pending: List[_Pending] = []
         self._reserved: set = set()       # slots held by _pending
         self._submit_t: Dict[int, float] = {}
-        self._pool = None                 # rnn-state pytree, [B, ...]
-        #: paged engines: the slot-major state of ``_state_layers``
+        #: {attention layer: {"pk", "pv"}}, each
+        #: ``[kv_blocks, block_tokens, H, dh]``; made by the first
+        #: admission (``_ensure_paged_pool``)
+        self._pool = None
+        #: the slot-major state of ``_state_layers``
         #: ({layer: {"conv", "ssm"}}, [n_slots, ...]); it rides every
         #: decode dispatch beside the pool's KV leaves
         self._slot_state: Dict[str, Any] = {}
@@ -1116,8 +1111,7 @@ class DecodeEngine:
             "prefill_tokens_skipped": 0, "chunks_scheduled": 0,
             "spec_rounds": 0, "spec_fallback_rounds": 0,
             "spec_drafted": 0, "spec_accepted": 0,
-            # paged block-pool gauges (always present; nonzero only
-            # with paged_kv=True — gateway /v1/metrics exports them)
+            # block-pool gauges (gateway /v1/metrics exports them)
             "blocks_free": self.kv_blocks, "blocks_used": 0,
             "cow_copies": 0, "prefix_blocks_spliced": 0,
             "frag_tokens": 0, "preempted": 0,
@@ -1170,7 +1164,7 @@ class DecodeEngine:
         step functions become fully-manual sharded programs over the
         ``tp`` mesh axis with per-leaf specs derived from key paths
         (serving/tp.py). Every jitted computation the engine (or its
-        block pool / dense prefix trie) owns is built through here, so
+        block pool) owns is built through here, so
         the compile-count discipline reads through unchanged."""
         if self.tp_ctx is not None:
             return self.tp_ctx.wrap(fn, donate_argnums=donate_argnums)
@@ -1179,8 +1173,8 @@ class DecodeEngine:
     def _place(self, tree):
         """Commit a fresh device pytree onto the TP mesh under its
         derived sharding (no-op at ``tp == 1``). Persistent state the
-        engine creates EAGERLY (the slot pool, the paged block pool,
-        the current-token vector) must be placed at creation: an
+        engine creates EAGERLY (the block pool, the current-token
+        vector) must be placed at creation: an
         uncommitted array entering a sharded executable would compile
         a second specialization the round its committed successor
         returns (the retrace the spike caught)."""
@@ -1204,25 +1198,20 @@ class DecodeEngine:
             # the per-layer state the forward pass sees: every paged
             # layer's pool leaves beside the dispatch's ONE set of
             # block tables (``tabs``: ``_paged_tables``' packed
-            # operand, unpacked here; None = dense rows, which carry
-            # their own). ``filled`` is a scan's carried copy of the
-            # only table operand a step advances
-            if tabs is None:
-                return pool
+            # operand, unpacked here). ``filled`` is a scan's carried
+            # copy of the only table operand a step advances
             shared = _unpack_tables(tabs)
             if filled is not None:
                 shared["filled"] = filled
             return {name: dict(st, **shared) if "pk" in st else st
                     for name, st in pool.items()}
 
-        def kept(rnn, tabs):
+        def kept(rnn):
             # a pass's new state parted into what the engine carries
             # between dispatches (pool leaves, slot-state rows) and
             # the advanced ``filled``: every paged layer added the
             # same lengths, so the first one's stands for all and no
             # program hands a layer's tables back
-            if tabs is None:
-                return rnn, None
             filled = next(st["filled"] for st in rnn.values()
                           if "pk" in st)
             return {name: ({"pk": st["pk"], "pv": st["pv"]}
@@ -1231,12 +1220,16 @@ class DecodeEngine:
 
         def chunk_prefill(params, state, x, mask, rnn, tabs, temp,
                           top_k, key):
-            # masked prefill resuming a carried cache (a prefix-cache
-            # hit's fetched state, the previous chunk's, or a paged
-            # warm admission's pool leaves under its block table):
-            # forward, then sample at each row's last VALID position
+            # masked prefill resuming a carried cache: a warm
+            # admission's pool leaves under its block table, or
+            # (``tabs`` None) a COLD admission's dense B=1 row, the
+            # net's own streaming cache, from the chunk before (None
+            # at its first). Forward, then sample at each row's last
+            # VALID position
             length = jnp.sum(mask.astype(jnp.int32), axis=1)
-            rnn = seen(rnn, tabs)
+            cold = tabs is None
+            if not cold:
+                rnn = seen(rnn, tabs)
             if ids_in:
                 # the head at the sampled position only: a vocabulary
                 # this wide is not worth a column per prompt position
@@ -1249,7 +1242,7 @@ class DecodeEngine:
                 probs = jnp.take_along_axis(
                     out, (length - 1)[:, None, None], axis=2)[:, :, 0]
             tok = sample_tokens(probs, temp, top_k, key)
-            return tok, kept(new_rnn, tabs)[0], counts
+            return tok, new_rnn if cold else kept(new_rnn)[0], counts
 
         def prefill(params, state, x, mask, temp, top_k, key):
             # cold prefill = the continuation body with no carried
@@ -1258,19 +1251,10 @@ class DecodeEngine:
             return chunk_prefill(params, state, x, mask, None, None,
                                  temp, top_k, key)
 
-        def admit(pool, toks, rnn1, tok1, slot):
-            def put(p, o):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    p, o.astype(p.dtype), slot, axis=0)
-
-            return (jax.tree_util.tree_map(put, pool, rnn1),
-                    jax.lax.dynamic_update_slice(
-                        toks, tok1.astype(toks.dtype), (slot,)))
-
         def decode(params, state, pool, tabs, toks, temps, top_ks,
                    key, live=None):
-            # ``tabs``: the paged dispatch's block tables (None for
-            # the dense layout); ``live`` [B]: which slots hold a
+            # ``tabs``: the dispatch's block tables; ``live`` [B]:
+            # which slots hold a
             # request, for the layers that ask (one operand each a
             # dispatch). Of the tables only ``filled`` is carried:
             # the rest is the same at every step
@@ -1282,10 +1266,10 @@ class DecodeEngine:
                     params, state, encode(tok), None,
                     seen(pool, tabs, filled), live=live)
                 nxt = sample_tokens(out[:, :, -1], temps, top_ks, k)
-                return (*kept(new_rnn, tabs), nxt), (nxt, counts)
+                return (*kept(new_rnn), nxt), (nxt, counts)
 
             (pool, _, tok), (seq, counts) = jax.lax.scan(
-                body, (pool, _filled_of(tabs), toks), keys)
+                body, (pool, tabs[:, -1], toks), keys)
             # what the layers counted, summed over the chunk's steps
             counts = {name: jnp.sum(v) for name, v in counts.items()}
             return pool, tok, jnp.swapaxes(seq, 0, 1), counts
@@ -1317,10 +1301,10 @@ class DecodeEngine:
                 out, new_rnn, _ = forward(params, state, encode(tok),
                                           None, seen(pool, tabs, filled))
                 nxt = sample_tokens(out[:, :, -1], temps, top_ks, k)
-                return (*kept(new_rnn, tabs), nxt), nxt
+                return (*kept(new_rnn), nxt), nxt
 
             (pool, _, tok), seq = jax.lax.scan(
-                body, (pool, _filled_of(tabs), toks), flat)
+                body, (pool, tabs[:, -1], toks), flat)
             seq = jnp.swapaxes(seq, 0, 1)       # [B, K * chunk]
             t = k_rounds * chunk
             pos = jnp.arange(t)
@@ -1333,34 +1317,24 @@ class DecodeEngine:
             return pool, tok, seq, n_valid
 
         self._prefill_jit = self._jit(prefill)
-        if self.paged_kv:
-            # donate the carried cache: the block pool rides EVERY
-            # paged dispatch as an operand, and without input-output
-            # aliasing each call would copy the whole pool just to
-            # write one round's blocks (measured 1.8x warm-TTFT
-            # regression on the CPU proxy; the dense path keeps its
-            # original no-donation behavior — callers there may hold
-            # the old buffers). What is donated is what a dispatch
-            # writes and the engine keeps: the pool leaves and the
-            # slot-state rows. The block tables are NOT: they are the
-            # argument beside it, made anew on the host each dispatch
-            # and read by every paged layer (XLA rejects one buffer
-            # donated through two pytree leaves, so what the layers
-            # share cannot ride inside the donated pytree)
-            self._chunk_jit = self._jit(chunk_prefill,
-                                        donate_argnums=(4,))
-            self._decode_jit = self._jit(decode, donate_argnums=(2,))
-        else:
-            self._chunk_jit = self._jit(chunk_prefill)
-            self._decode_jit = self._jit(decode)
-        self._fused_jit = None
-        if self.fused_rounds:
-            self._fused_jit = (
-                self._jit(fused_decode, donate_argnums=(2,))
-                if self.paged_kv else self._jit(fused_decode))
-        self._admit_jit = self._jit(admit)
+        # donate the carried cache: the block pool rides EVERY
+        # dispatch as an operand, and without input-output
+        # aliasing each call would copy the whole pool just to
+        # write one round's blocks (measured 1.8x warm-TTFT
+        # regression on the CPU proxy). What is donated is what a
+        # dispatch writes and the engine keeps: the pool leaves and
+        # the slot-state rows. The block tables are NOT: they are the
+        # argument beside it, made anew on the host each dispatch
+        # and read by every paged layer (XLA rejects one buffer
+        # donated through two pytree leaves, so what the layers
+        # share cannot ride inside the donated pytree)
+        self._chunk_jit = self._jit(chunk_prefill, donate_argnums=(4,))
+        self._decode_jit = self._jit(decode, donate_argnums=(2,))
+        self._fused_jit = (
+            self._jit(fused_decode, donate_argnums=(2,))
+            if self.fused_rounds else None)
         self._state_admit_jit = None
-        if self._state_layers and self.paged_kv:
+        if self._state_layers:
             def state_admit(slots, row, slot):
                 # a prefilled row's recurrent state into its slot
                 def put(p, o):
@@ -1396,7 +1370,7 @@ class DecodeEngine:
                         <= lens[:, None]).astype(jnp.float32)
                 out, new_rnn, _ = forward(params, state, x, mask,
                                           seen(pool, tabs))
-                new_pool, filled = kept(new_rnn, tabs)
+                new_pool, filled = kept(new_rnn)
                 # acceptance (ISSUE 16): greedy rows keep the equality
                 # rule (bit-parity with plain greedy decode); sampling
                 # rows accept each draft token with probability
@@ -1427,16 +1401,13 @@ class DecodeEngine:
                 # roll each row's rejected tail back out of the cache;
                 # the committed cache then holds exactly
                 # context + accepted prefix, with the bonus token as
-                # the slot's new current (not-yet-cached) token
-                # (paged: tokens stay where they lie in their blocks
-                # and the rewind is the ONE ``filled`` moving back, the
-                # contract ``drop_newest_tokens`` states; the tables
-                # go out with it for the decode dispatch to chain on,
-                # as device arrays, no second upload)
-                if tabs is None:
-                    new_pool = drop_newest_tokens(new_pool, lens - acc)
-                else:
-                    tabs = tabs.at[:, -1].set(filled - (lens - acc))
+                # the slot's new current (not-yet-cached) token:
+                # tokens stay where they lie in their blocks and the
+                # rewind is the ONE ``filled`` moving back (a key past
+                # it is masked, and the next write lands on it); the
+                # tables go out with it for the decode dispatch to
+                # chain on, as device arrays, no second upload
+                tabs = tabs.at[:, -1].set(filled - (lens - acc))
                 dpad = jnp.concatenate(
                     [draft, jnp.zeros_like(draft[:, :1])], axis=1)
                 emitted = jnp.where(
@@ -1445,103 +1416,97 @@ class DecodeEngine:
                               bonus[:, None], 0))
                 return new_pool, tabs, bonus, emitted, acc
 
-            self._verify_jit = (
-                self._jit(verify, donate_argnums=(2,))
-                if self.paged_kv else self._jit(verify))
-        self._scatter_jit = None
-        self._tok_jit = None
-        if self.paged_kv:
-            bt, s_ring = self.block_tokens, self._ring_slots
+            self._verify_jit = self._jit(verify, donate_argnums=(2,))
+        bt, s_ring = self.block_tokens, self._ring_slots
 
-            def scatter_row(pool, rnn1, table_row, length):
-                # paged admit: write a dense B=1 post-prefill row's
-                # valid window tokens to their ABSOLUTE positions in
-                # the slot's freshly-allocated blocks (the one
-                # whole-row write a COLD admission pays — dense mode
-                # pays the same row scatter into its slot pool, so
-                # cold-path cost is unchanged; warm admissions skip
-                # this entirely via the zero-copy splice)
-                out = {}
-                for name, st in pool.items():   # the KV layers
-                    k1, v1 = rnn1[name]["k"], rnn1[name]["v"]
-                    fd = rnn1[name]["filled"][0]
-                    w = k1.shape[2]
-                    nbk = st["pk"].shape[0]
-                    n_tok = nbk * bt
-                    absp = length - w + jnp.arange(w)
-                    safe = jnp.clip(absp, 0)
-                    blk = table_row[(safe // bt) % s_ring]
-                    idx = jnp.where((absp >= length - fd) & (blk >= 0),
-                                    blk * bt + safe % bt, n_tok)
-                    kt = jnp.transpose(k1[0], (1, 0, 2))   # [W, H, dh]
-                    vt = jnp.transpose(v1[0], (1, 0, 2))
-                    h, dh = kt.shape[1], kt.shape[2]
-                    pkf = st["pk"].reshape(n_tok, h, dh).at[idx].set(
-                        kt.astype(st["pk"].dtype), mode="drop")
-                    pvf = st["pv"].reshape(n_tok, h, dh).at[idx].set(
-                        vt.astype(st["pv"].dtype), mode="drop")
-                    out[name] = {"pk": pkf.reshape(nbk, bt, h, dh),
-                                 "pv": pvf.reshape(nbk, bt, h, dh)}
-                return out
+        def scatter_row(pool, rnn1, table_row, length):
+            # a cold admission's one whole-row write: a dense B=1
+            # post-prefill row's valid window tokens to their
+            # ABSOLUTE positions in the slot's freshly-allocated
+            # blocks (warm admissions skip this entirely via the
+            # zero-copy splice)
+            out = {}
+            for name, st in pool.items():   # the KV layers
+                k1, v1 = rnn1[name]["k"], rnn1[name]["v"]
+                fd = rnn1[name]["filled"][0]
+                w = k1.shape[2]
+                nbk = st["pk"].shape[0]
+                n_tok = nbk * bt
+                absp = length - w + jnp.arange(w)
+                safe = jnp.clip(absp, 0)
+                blk = table_row[(safe // bt) % s_ring]
+                idx = jnp.where((absp >= length - fd) & (blk >= 0),
+                                blk * bt + safe % bt, n_tok)
+                kt = jnp.transpose(k1[0], (1, 0, 2))   # [W, H, dh]
+                vt = jnp.transpose(v1[0], (1, 0, 2))
+                h, dh = kt.shape[1], kt.shape[2]
+                pkf = st["pk"].reshape(n_tok, h, dh).at[idx].set(
+                    kt.astype(st["pk"].dtype), mode="drop")
+                pvf = st["pv"].reshape(n_tok, h, dh).at[idx].set(
+                    vt.astype(st["pv"].dtype), mode="drop")
+                out[name] = {"pk": pkf.reshape(nbk, bt, h, dh),
+                             "pv": pvf.reshape(nbk, bt, h, dh)}
+            return out
 
-            def put_tok(toks, tok1, slot):
-                return jax.lax.dynamic_update_slice(
-                    toks, tok1.astype(toks.dtype), (slot,))
+        def put_tok(toks, tok1, slot):
+            return jax.lax.dynamic_update_slice(
+                toks, tok1.astype(toks.dtype), (slot,))
 
-            def kv_import(pool, new, ids):
-                # KV transfer import (ISSUE 14): scatter shipped
-                # block stacks [n, bt, H, dh] into the pool at the
-                # freshly-allocated ids; pad lanes carry an
-                # out-of-range id and drop. One executable per pow2
-                # block-count bucket (serving/kv_transfer.py pads),
-                # the engine's standing compile discipline. Under tp
-                # the shipped leaves shard on their head axis exactly
-                # like the pool (same pk/pv key paths).
-                out = {}
-                for name, st in pool.items():
-                    npk = new[name]["pk"].astype(st["pk"].dtype)
-                    npv = new[name]["pv"].astype(st["pv"].dtype)
-                    out[name] = {
-                        "pk": st["pk"].at[ids].set(npk, mode="drop"),
-                        "pv": st["pv"].at[ids].set(npv, mode="drop"),
-                    }
-                return out
+        def kv_import(pool, new, ids):
+            # KV transfer import (ISSUE 14): scatter shipped
+            # block stacks [n, bt, H, dh] into the pool at the
+            # freshly-allocated ids; pad lanes carry an
+            # out-of-range id and drop. One executable per pow2
+            # block-count bucket (serving/kv_transfer.py pads),
+            # the engine's standing compile discipline. Under tp
+            # the shipped leaves shard on their head axis exactly
+            # like the pool (same pk/pv key paths).
+            out = {}
+            for name, st in pool.items():
+                npk = new[name]["pk"].astype(st["pk"].dtype)
+                npv = new[name]["pv"].astype(st["pv"].dtype)
+                out[name] = {
+                    "pk": st["pk"].at[ids].set(npk, mode="drop"),
+                    "pv": st["pv"].at[ids].set(npv, mode="drop"),
+                }
+            return out
 
-            def kv_gather(pool, ids):
-                # KV transfer export (ISSUE 14): pull the selected
-                # blocks [W, bt, H, dh] out of the pool so only the
-                # exported slice crosses to host (a whole-pool host
-                # copy would scale with pool size, not export size,
-                # under the engine lock). Pad ids are out of range
-                # and fill zero; one executable per pow2 bucket,
-                # like the import twin.
-                out = {}
-                for name, st in pool.items():
-                    out[name] = {
-                        "pk": jnp.take(st["pk"], ids, axis=0,
-                                       mode="fill", fill_value=0),
-                        "pv": jnp.take(st["pv"], ids, axis=0,
-                                       mode="fill", fill_value=0),
-                    }
-                return out
+        def kv_gather(pool, ids):
+            # KV transfer export (ISSUE 14): pull the selected
+            # blocks [W, bt, H, dh] out of the pool so only the
+            # exported slice crosses to host (a whole-pool host
+            # copy would scale with pool size, not export size,
+            # under the engine lock). Pad ids are out of range
+            # and fill zero; one executable per pow2 bucket,
+            # like the import twin.
+            out = {}
+            for name, st in pool.items():
+                out[name] = {
+                    "pk": jnp.take(st["pk"], ids, axis=0,
+                                   mode="fill", fill_value=0),
+                    "pv": jnp.take(st["pv"], ids, axis=0,
+                                   mode="fill", fill_value=0),
+                }
+            return out
 
-            self._scatter_jit = self._jit(scatter_row,
-                                          donate_argnums=(0,))
-            self._tok_jit = self._jit(put_tok)
-            self._kv_import_jit = self._jit(kv_import,
-                                            donate_argnums=(0,))
-            self._kv_gather_jit = self._jit(kv_gather)
+        self._scatter_jit = self._jit(scatter_row,
+                                      donate_argnums=(0,))
+        self._tok_jit = self._jit(put_tok)
+        self._kv_import_jit = self._jit(kv_import,
+                                        donate_argnums=(0,))
+        self._kv_gather_jit = self._jit(kv_gather)
         self._health_jit = None
-        if self.paranoid and self.paged_kv:
+        if self.paranoid:
             vocab = self.vocab
 
             def paged_health(pool, toks):
-                # per-BLOCK finiteness (ISSUE 6 satellite): the pool
-                # axis is blocks, not slots, so the sweep's verdict is
-                # per block and the HOST maps blocks -> victims via
-                # the block tables — quarantining a victim then
-                # releases references without scrubbing blocks shared
-                # with innocent slots
+                # per-BLOCK finiteness (ISSUE 6 satellite) + sampled-id
+                # range check, the failure layer's only compile-count
+                # addition: the pool axis is blocks, not slots, so the
+                # sweep's verdict is per block and the HOST maps
+                # blocks -> victims via the block tables —
+                # quarantining a victim then releases references
+                # without scrubbing blocks shared with innocent slots
                 oks = []
                 for st in pool.values():
                     for leaf in (st["pk"], st["pv"]):
@@ -1552,27 +1517,10 @@ class DecodeEngine:
                 return blocks_ok, (toks >= 0) & (toks < vocab)
 
             self._health_jit = self._jit(paged_health)
-        elif self.paranoid:
-            vocab = self.vocab
-
-            def health(pool, toks):
-                # per-slot finiteness over every pool leaf + sampled-id
-                # range check: ONE masked reduction executable — the
-                # failure layer's only compile-count addition
-                def row_ok(a):
-                    fin = jnp.isfinite(a.astype(jnp.float32))
-                    return jnp.all(fin.reshape(a.shape[0], -1), axis=1)
-
-                oks = [row_ok(leaf)
-                       for leaf in jax.tree_util.tree_leaves(pool)]
-                ok = functools.reduce(jnp.logical_and, oks)
-                return ok & (toks >= 0) & (toks < vocab)
-
-            self._health_jit = self._jit(health)
 
     def compile_counts(self) -> Dict[str, int]:
         """Executable counts per jitted computation (the no-retrace
-        guarantee: decode, admit, prefix_fetch, prefix_store, and the
+        guarantee: decode, paged_scatter, paged_tok, and the
         paranoid health_check stay at 1; prefill equals the number of
         distinct cold prompt-length buckets seen; chunk_prefill equals
         the number of distinct suffix widths — exactly 1 in chunked
@@ -1584,7 +1532,6 @@ class DecodeEngine:
 
         counts = {"prefill": n(self._prefill_jit),
                   "chunk_prefill": n(self._chunk_jit),
-                  "admit": n(self._admit_jit),
                   "decode": n(self._decode_jit)}
         if self._fused_jit is not None:
             # one executable per pow2 K-bucket actually dispatched —
@@ -1594,16 +1541,13 @@ class DecodeEngine:
             counts["verify"] = n(self._verify_jit)
         if self._health_jit is not None:
             counts["health_check"] = n(self._health_jit)
-        if self.paged_kv:
-            if self._state_admit_jit is not None:
-                counts["state_admit"] = n(self._state_admit_jit)
-            counts["paged_scatter"] = n(self._scatter_jit)
-            counts["paged_tok"] = n(self._tok_jit)
-            counts["kv_import"] = n(self._kv_import_jit)
-            counts["kv_gather"] = n(self._kv_gather_jit)
-            counts.update(self.block_pool.compile_counts())
-        if self.prefix_cache is not None:
-            counts.update(self.prefix_cache.compile_counts())
+        if self._state_admit_jit is not None:
+            counts["state_admit"] = n(self._state_admit_jit)
+        counts["paged_scatter"] = n(self._scatter_jit)
+        counts["paged_tok"] = n(self._tok_jit)
+        counts["kv_import"] = n(self._kv_import_jit)
+        counts["kv_gather"] = n(self._kv_gather_jit)
+        counts.update(self.block_pool.compile_counts())
         return counts
 
     # -- request lifecycle ---------------------------------------------
@@ -1907,30 +1851,27 @@ class DecodeEngine:
         self._pending.remove(pending)
 
     def _evict_slot(self, slot: int) -> None:
-        """Zero the slot's rows (per-slot eviction — the whole-pool
-        analogue of ``rnn_clear_previous_state(slots=[slot])``); the
-        next admission overwrites them. This keeps stale K/V from ever
-        being observable, and doubles as quarantine: a zeroed row is
-        finite and masked, so a poisoned slot stops existing. The
-        slot's speculative draft state dies with it (a quarantined or
+        """Free the slot. Eviction releases REFERENCES:
+        exclusively-owned blocks return to the free list (scrubbed
+        there if the paranoid sweep poisoned them, so a poisoned slot
+        stops existing), blocks shared with the trie or other slots
+        stay resident and untouched — the per-block quarantine
+        contract (ISSUE 6 satellite). A slot-state layer's row is left
+        as it is: the next admission overwrites it whole. The slot's
+        speculative draft state dies with it (a quarantined or
         cancelled slot must never donate drafts to its successor)."""
-        if self.paged_kv:
-            # paged eviction releases REFERENCES: exclusively-owned
-            # blocks return to the free list (scrubbed there if the
-            # paranoid sweep poisoned them), blocks shared with the
-            # trie or other slots stay resident and untouched — the
-            # per-block quarantine contract (ISSUE 6 satellite)
-            tab = self._kv_tabs[slot]
-            self._kv_tabs[slot] = None
-            self._free_table(tab)
-        else:
-            self._pool = clear_state_rows(self._pool, [slot])
+        self._release_slot(slot)
+        self.stats["evicted"] += 1
+
+    def _release_slot(self, slot: int) -> None:
+        """What eviction and preemption share."""
+        self._free_table(self._kv_tabs[slot])
+        self._kv_tabs[slot] = None
         self._slots[slot] = None
         self._temps[slot] = 0.0
         self._top_ks[slot] = self.vocab
         if self.spec is not None:
             self.spec.drop(slot)
-        self.stats["evicted"] += 1
 
     # -- paged block-pool plumbing (ISSUE 6) ---------------------------
     def _release_block(self, bid: int) -> None:
@@ -1991,21 +1932,7 @@ class DecodeEngine:
                     f'serving_preempted{{tenant='
                     f'"{state.request.tenant}"}}')
         self._tenant_count(state.request.tenant, "preempted")
-        self._slots[slot] = None
-        self._temps[slot] = 0.0
-        self._top_ks[slot] = self.vocab
-        if self.spec is not None:
-            self.spec.drop(slot)
-        if self.paged_kv:
-            tab = self._kv_tabs[slot]
-            self._kv_tabs[slot] = None
-            self._free_table(tab)
-        elif self._pool is not None:
-            # dense-layout preemption (ISSUE 13 extends the PR 6
-            # paged path to both layouts): zero the slot's rows so
-            # the freed slot's stale K/V can never be observed —
-            # the same per-slot reset eviction uses
-            self._pool = clear_state_rows(self._pool, [slot])
+        self._release_slot(slot)
         if ((self.on_delta is not None or self.emit_deltas)
                 and state.request.temperature > 0
                 and self._delta_sent.get(state.request.id, 0) > 0):
@@ -2078,10 +2005,24 @@ class DecodeEngine:
               if n not in self._state_layers}
         return kv, {n: rnn1[n] for n in self._state_layers}
 
-    def _write_row(self, rnn1, tab: BlockTable, slot: int) -> None:
-        """A cold admission's one whole-row write: the prefilled row's
-        keys and values into the slot's freshly allocated blocks, its
-        recurrent state into the slot's row."""
+    def _write_row(self, rnn1, length: int,
+                   slot: Optional[int] = None) -> Optional[BlockTable]:
+        """A cold admission's (or a restore's) one whole-row write: a
+        fresh BlockTable covering the last ``min(length, wmax)``
+        absolute positions (what a dense B=1 prefill row holds), the
+        row's keys and values scattered into its blocks, its recurrent
+        state into the slot's row (a trie entry being re-primed has no
+        slot, and no such state). None when the pool cannot be
+        relieved."""
+        self._ensure_paged_pool(rnn1)
+        bt = self.block_tokens
+        floor = max(0, length - self._wmax)
+        gs = list(range(floor // bt, (length - 1) // bt + 1))
+        if not self._paged_reserve(len(gs)):
+            return None
+        tab = BlockTable(bt, length=length, floor=floor)
+        for g in gs:
+            tab.blocks[g] = self.block_pool.alloc()
         kv, row = self._split_row(rnn1)
         table_row, _ = tab.arrays(self._ring_slots)
         self._pool = self._scatter_jit(
@@ -2091,6 +2032,7 @@ class DecodeEngine:
             with self._span("serving.state_admit", slot=slot):
                 self._slot_state = self._state_admit_jit(
                     self._slot_state, row, jnp.asarray(slot, jnp.int32))
+        return tab
 
     def _live_operand(self):
         """``(live,)`` for the decode program of a net some layer of
@@ -2166,32 +2108,16 @@ class DecodeEngine:
         self.stats["paged_blocks_walked"] += walked
 
     def _strip_pool(self, rnn):
-        """What a paged program hands back (pool leaves and, for a net
+        """What a program hands back (pool leaves and, for a net
         with slot-state layers, the state rows that rode the same
         donated operand) parted into ``_slot_state`` and the returned
-        pool (the dense layout keeps both in its one slot-major
-        pool). No program returns table operands."""
-        if not (self.paged_kv and self._state_layers):
+        pool. No program returns table operands."""
+        if not self._state_layers:
             return rnn
         self._slot_state = {name: rnn[name]
                             for name in self._state_layers}
         return {name: st for name, st in rnn.items()
                 if name not in self._state_layers}
-
-    def _alloc_window_tab(self, length: int) -> Optional[BlockTable]:
-        """A fresh BlockTable covering the last ``min(length, wmax)``
-        absolute positions (what a dense B=1 prefill row holds) —
-        the cold-admission / restore-rebuild target for the jitted
-        scatter. None when the pool cannot be relieved."""
-        bt = self.block_tokens
-        floor = max(0, length - self._wmax)
-        gs = list(range(floor // bt, (length - 1) // bt + 1))
-        if not self._paged_reserve(len(gs)):
-            return None
-        tab = BlockTable(bt, length=length, floor=floor)
-        for g in gs:
-            tab.blocks[g] = self.block_pool.alloc()
-        return tab
 
     def _paged_stats_refresh(self) -> None:
         pool = self.block_pool
@@ -2200,7 +2126,7 @@ class DecodeEngine:
         self.stats["cow_copies"] = pool.stats["cow_copies"]
         self.stats["prefix_blocks_spliced"] = pool.stats["spliced"]
         tabs = list(self._kv_tabs) + [p.tab for p in self._pending]
-        if isinstance(self.prefix_cache, PagedPrefixCache):
+        if self.prefix_cache is not None:
             tabs.extend(self.prefix_cache._payloads.values())
         self.stats["frag_tokens"] = pool.fragmentation_tokens(tabs)
         if self.kv_tier is not None:
@@ -2220,7 +2146,7 @@ class DecodeEngine:
         """Serialize the longest cached prefix of ``prompt`` as a
         framed binary payload any peer replica can import
         (serving/kv_transfer.py). None when nothing reusable is
-        cached or the engine is not paged; ``cap_bytes`` raises
+        cached or the engine has no trie; ``cap_bytes`` raises
         :class:`~deeplearning4j_tpu.serving.kv_transfer
         .KVTransferTooLarge` from size arithmetic BEFORE any device
         gather. Layout-invariant: a TP=N engine exports full logical
@@ -2389,7 +2315,8 @@ class DecodeEngine:
 
     def _start_admission(self, request: Request, slot: int):
         """Begin admitting ``request`` into ``slot``: look up the radix
-        prefix cache, fetch the matched prefix's state, and either
+        prefix cache, splice the matched prefix's blocks into the
+        slot's table, and either
         prefill the whole suffix now (blocking mode) or enqueue a
         pending admission for chunk-by-chunk progress between decode
         rounds (chunked mode)."""
@@ -2404,7 +2331,7 @@ class DecodeEngine:
                                  now - clock.enqueue_t)
             clock.add(now, "queue_wait", now - clock.enqueue_t,
                       slot=slot)
-        rnn, matched, hit, tab = None, 0, None, None
+        matched, hit, tab = 0, None, None
         if self.prefix_cache is not None:
             hit = self.prefix_cache.lookup(request.prompt)
             if (self.kv_tier is not None
@@ -2422,13 +2349,13 @@ class DecodeEngine:
                     hit = None
                 if self._tier_reload(request.prompt):
                     hit = self.prefix_cache.lookup(request.prompt)
-            if hit is not None and self.paged_kv:
+            if hit is not None:
                 payload = self.prefix_cache.payload(hit.row)
                 if hit.matched > payload.floor:
                     # ZERO-COPY warm hit: reference the entry's blocks
-                    # up to the matched length — no prefix_fetch
-                    # gather, no row copy; the dense path's exact
-                    # one-token rewind is subsumed by referencing only
+                    # up to the matched length — no gather, no row
+                    # copy; a stored entry rewinds exactly to any
+                    # shorter prefix of itself by referencing only
                     # blocks below `matched` (suffix chunks append
                     # through the table, CoW-ing the boundary block on
                     # first write if it is still shared)
@@ -2456,20 +2383,7 @@ class DecodeEngine:
                 else:
                     self.prefix_cache.release(hit)
                     hit = None
-            elif hit is not None:
-                matched = hit.matched
-                t_fetch = self._clock()
-                with self._span("serving.prefix_fetch",
-                                rid=request.id, row=hit.row,
-                                matched=matched, drop=hit.drop,
-                                **_targs(request)):
-                    rnn = self.prefix_cache.fetch(hit)
-                if clock is not None:
-                    now = self._clock()
-                    clock.add(now, "admit_fetch", now - t_fetch,
-                              matched=matched)
-                self.stats["prefill_tokens_skipped"] += matched
-        pending = _Pending(request, slot, rnn, None, 0, matched, hit,
+        pending = _Pending(request, slot, None, None, 0, matched, hit,
                            tab=tab)
         if self.prefill_chunk:
             self._reserved.add(slot)
@@ -2484,8 +2398,8 @@ class DecodeEngine:
         self._complete_admission(pending)
 
     def _defer_admission(self, pending: _Pending) -> None:
-        """Back out an admission the block pool cannot currently hold
-        (paged mode only): release the trie lease and any spliced or
+        """Back out an admission the block pool cannot currently
+        hold: release the trie lease and any spliced or
         written blocks, free the reserved slot, and requeue the
         request for the next round — decode drains slots and frees
         blocks, so capacity recovers without shedding."""
@@ -2519,8 +2433,9 @@ class DecodeEngine:
             temp = jnp.asarray([req.temperature], jnp.float32)
             top_k = jnp.asarray([req.top_k or self.vocab], jnp.int32)
         clock = self._clock_of(req.id)
-        if pending.tab is not None:
-            # paged WARM admission: the suffix chunk streams straight
+        warm = pending.tab is not None
+        if warm:
+            # WARM admission: the suffix chunk streams straight
             # into the slot's block table (spliced trie blocks +
             # freshly allocated ones) — no dense scratch row ever
             # materializes, which is what makes the warm path
@@ -2528,53 +2443,38 @@ class DecodeEngine:
             if not self._ensure_tab(pending.tab, len(seg),
                                     rid=req.id):
                 return False
+            carried = self._pool
             tables = self._paged_tables([pending.tab], chunk=width)
-            t0 = self._clock()
-            with self._span("serving.prefill_chunk", rid=req.id,
-                            width=width, tokens=len(seg),
-                            done=pending.done, paged=True,
-                            **_targs(req)):
-                tok, rnn, counts = self._chunk_jit(
-                    self._params, self._state, x, mask, self._pool,
-                    tables, temp, top_k, self._next_key())
-            if clock is not None:
-                now = self._clock()
-                clock.add(now, "admit_chunk", now - t0,
-                          tokens=len(seg))
-            self._pool = self._strip_pool(rnn)
-            pending.tab.length += len(seg)
-            pending.tok = tok
-            pending.counts.append(counts)
-            pending.done += len(seg)
-            self.stats["prefill_tokens"] += len(seg)
-            self.stats["chunks_scheduled"] += 1
-            return True
+        else:
+            carried, tables = pending.rnn, None
         t0 = self._clock()
-        if pending.rnn is None:
+        if carried is None:
             # first cold segment: no carried state yet — the bucketed
             # cold-prefill executable establishes it
+            phase = "admit_cold"
             with self._span("serving.prefill", rid=req.id,
                             bucket=width, tokens=len(seg),
                             **_targs(req)):
                 tok, rnn, counts = self._prefill_jit(
                     self._params, self._state, x, mask, temp,
                     top_k, self._next_key())
-            if clock is not None:
-                now = self._clock()
-                clock.add(now, "admit_cold", now - t0,
-                          tokens=len(seg))
         else:
+            phase = "admit_chunk"
             with self._span("serving.prefill_chunk", rid=req.id,
                             width=width, tokens=len(seg),
                             done=pending.done, **_targs(req)):
                 tok, rnn, counts = self._chunk_jit(
-                    self._params, self._state, x, mask,
-                    pending.rnn, None, temp, top_k, self._next_key())
-            if clock is not None:
-                now = self._clock()
-                clock.add(now, "admit_chunk", now - t0,
-                          tokens=len(seg))
-        pending.rnn, pending.tok = rnn, tok
+                    self._params, self._state, x, mask, carried,
+                    tables, temp, top_k, self._next_key())
+        if clock is not None:
+            now = self._clock()
+            clock.add(now, phase, now - t0, tokens=len(seg))
+        if warm:
+            self._pool = self._strip_pool(rnn)
+            pending.tab.length += len(seg)
+        else:
+            pending.rnn = rnn
+        pending.tok = tok
         pending.counts.append(counts)
         pending.done += len(seg)
         self.stats["prefill_tokens"] += len(seg)
@@ -2583,8 +2483,8 @@ class DecodeEngine:
 
     def _ensure_paged_pool(self, rnn1) -> None:
         """Create the device block pool lazily from the first dense
-        B=1 streaming state (mirrors the dense pool's lazy creation;
-        shapes per layer: ``[kv_blocks, block_tokens, H, dh]``)."""
+        B=1 streaming state, which says each layer's heads and dtype
+        (shapes per layer: ``[kv_blocks, block_tokens, H, dh]``)."""
         if self._pool is not None:
             return
         bt = self.block_tokens
@@ -2604,61 +2504,34 @@ class DecodeEngine:
         self._toks = self._place(jnp.zeros((self.n_slots,), jnp.int32))
 
     def _complete_admission(self, pending: _Pending):
-        """Suffix fully prefilled: scatter the state + first token into
-        the slot pool, store the prompt's state in the prefix cache,
-        and release the hit lease. Paged mode stores nothing twice:
-        the slot's blocks ARE the cache entry (zero-copy insert via
-        refcount bumps), and a cold admission's one scatter replaces
-        the dense admit row-write."""
+        """Suffix fully prefilled: the first token into the slot and,
+        for a cold admission, the one scatter of its dense B=1 row
+        into freshly allocated blocks; then lease the prompt's blocks
+        to the prefix cache and release the hit lease. Nothing is
+        stored twice: the slot's blocks ARE the cache entry (zero-copy
+        insert via refcount bumps)."""
         request, slot = pending.request, pending.slot
-        if self.paged_kv:
-            if pending.tab is None:
-                # cold: the dense B=1 prefill row scatters into
-                # freshly allocated blocks (cost parity with the
-                # dense admit scatter)
-                self._ensure_paged_pool(pending.rnn)
-                tab = self._alloc_window_tab(len(pending.seq))
-                if tab is None:
-                    self._defer_admission(pending)
-                    return
-                self._write_row(pending.rnn, tab, slot)
-            else:
-                tab = pending.tab
-                pending.tab = None
-            self._toks = self._tok_jit(self._toks, pending.tok,
-                                       jnp.asarray(slot, jnp.int32))
-            hit_row = None
-            if self.prefix_cache is not None:
-                if pending.hit is not None:
-                    hit_row = pending.hit.row
-                    self.prefix_cache.release(pending.hit)
-                # zero-copy insert: the trie references the slot's own
-                # blocks; the slot's next append CoWs the shared
-                # boundary block instead of corrupting the entry
-                self.prefix_cache.insert_blocks(request.prompt, tab)
-            self._kv_tabs[slot] = tab
-            self._reserved.discard(slot)
+        if pending.tab is None:
+            tab = self._write_row(pending.rnn, len(pending.seq), slot)
+            if tab is None:
+                self._defer_admission(pending)
+                return
         else:
-            if self._pool is None:
-                self._pool = self._place(jax.tree_util.tree_map(
-                    lambda a: jnp.zeros((self.n_slots,) + a.shape[1:],
-                                        a.dtype), pending.rnn))
-                self._toks = self._place(
-                    jnp.zeros((self.n_slots,), jnp.int32))
-            self._pool, self._toks = self._admit_jit(
-                self._pool, self._toks, pending.rnn, pending.tok,
-                jnp.asarray(slot, jnp.int32))
-            hit_row = None
-            if self.prefix_cache is not None:
-                # release BEFORE insert: the fetched state is an
-                # immutable snapshot, and on a tight cache the freed
-                # row lets the insert evict the stale ancestor instead
-                # of declining
-                if pending.hit is not None:
-                    hit_row = pending.hit.row
-                    self.prefix_cache.release(pending.hit)
-                self.prefix_cache.insert(request.prompt, pending.rnn)
-            self._reserved.discard(slot)
+            tab = pending.tab
+            pending.tab = None
+        self._toks = self._tok_jit(self._toks, pending.tok,
+                                   jnp.asarray(slot, jnp.int32))
+        hit_row = None
+        if self.prefix_cache is not None:
+            if pending.hit is not None:
+                hit_row = pending.hit.row
+                self.prefix_cache.release(pending.hit)
+            # zero-copy insert: the trie references the slot's own
+            # blocks; the slot's next append CoWs the shared
+            # boundary block instead of corrupting the entry
+            self.prefix_cache.insert_blocks(request.prompt, tab)
+        self._kv_tabs[slot] = tab
+        self._reserved.discard(slot)
         # fetch the first token BEFORE stamping TTFT: the value fetch
         # is the sync point that forces the in-flight prefill/admit
         # dispatches to completion (async dispatch would otherwise
@@ -2684,8 +2557,7 @@ class DecodeEngine:
             # whether a cached prefix (local OR imported) was reused
             phases = clock.attempts[-1]["phases"]
             adm = (phases.get("admit_cold", 0.0)
-                   + phases.get("admit_chunk", 0.0)
-                   + phases.get("admit_fetch", 0.0))
+                   + phases.get("admit_chunk", 0.0))
             self._observe("serving_admission_warm_s" if pending.matched
                           else "serving_admission_cold_s", adm)
         state = _Slot(request, [first], prefix_reused=pending.matched,
@@ -2697,8 +2569,8 @@ class DecodeEngine:
         if self._finished(state):
             # PR 3 blind spot (ISSUE 4 satellite): a request finishing
             # AT admission never reaches the post-decode health sweep,
-            # so a fault injected the same round (poisoned prefix row
-            # riding the fetch in) would be delivered as a healthy
+            # so a fault injected the same round (a poisoned prefix
+            # block riding the splice in) would be delivered as a healthy
             # terminal. Check the admitted row BEFORE draining its
             # terminal — same health executable, same shapes, so
             # compile counts are untouched.
@@ -2749,7 +2621,7 @@ class DecodeEngine:
         """Expire deadlines/queue-timeouts wherever the request is.
         Queued: removed before any device work. Mid-admission: the
         reserved slot is freed and the lease released. Running: the
-        slot evicts via the normal row-zeroing path (neighbours keep
+        slot evicts via the normal path (neighbours keep
         decoding), partial tokens are returned. No-op (and zero cost)
         unless some submitted request carried a deadline."""
         if not self._has_deadlines:
@@ -2824,7 +2696,7 @@ class DecodeEngine:
         """Apply one scheduled fault. All injection is host-side (see
         serving/faults.py) — compile counts cannot change. Events whose
         target does not exist this round (no active slot to NaN, no
-        stored cache row to corrupt) are skipped and NOT recorded."""
+        stored cache entry to corrupt) are skipped and NOT recorded."""
         if event.kind == "stall":
             if hasattr(self._clock, "advance"):
                 self._clock.advance(event.seconds)
@@ -2841,48 +2713,32 @@ class DecodeEngine:
             if (slot is None or slot >= self.n_slots
                     or self._slots[slot] is None or self._pool is None):
                 return
-            if self.paged_kv:
-                # poison the slot's EXCLUSIVELY-owned blocks (the ones
-                # its own decode writes touch — a sampler NaN lands
-                # there); shared prefix blocks model a different fault
-                # (cache_corrupt) and are immutable to this slot
-                tab = self._kv_tabs[slot]
-                excl = [b for b in (tab.blocks.values() if tab else [])
-                        if self.block_pool.refcount(b) == 1]
-                if not excl:
-                    return
-                self._pool = poison_rows(self._pool, excl)
-            else:
-                self._pool = poison_rows(self._pool, [slot])
-        elif event.kind == "cache_corrupt":
-            if self.prefix_cache is None:
+            # poison the slot's EXCLUSIVELY-owned blocks (the ones
+            # its own decode writes touch — a sampler NaN lands
+            # there); shared prefix blocks model a different fault
+            # (cache_corrupt) and are immutable to this slot
+            tab = self._kv_tabs[slot]
+            excl = [b for b in (tab.blocks.values() if tab else [])
+                    if self.block_pool.refcount(b) == 1]
+            if not excl:
                 return
-            if self.paged_kv:
-                if self._pool is None:
-                    return
-                rows = self.prefix_cache.stored_rows()
-                row = event.row if event.row is not None else (
-                    rows[0] if rows else None)
-                if row is None or row not in rows:
-                    return
-                # bit-rot one block of the stored entry; the paranoid
-                # per-block sweep (or the splice victim's probe)
-                # catches it and invalidates the entry
-                blocks = self.prefix_cache.payload(row).blocks
-                if not blocks:
-                    return
-                bid = blocks[min(blocks)]
-                self._pool = poison_rows(self._pool, [bid])
-            else:
-                if self.prefix_cache.pool is None:
-                    return
-                rows = self.prefix_cache.stored_rows()
-                row = event.row if event.row is not None else (
-                    rows[0] if rows else None)
-                if row is None or row not in rows:
-                    return
-                self.prefix_cache.pool = poison_rows(
-                    self.prefix_cache.pool, [row])
+            self._pool = poison_rows(self._pool, excl)
+        elif event.kind == "cache_corrupt":
+            if self.prefix_cache is None or self._pool is None:
+                return
+            rows = self.prefix_cache.stored_rows()
+            row = event.row if event.row is not None else (
+                rows[0] if rows else None)
+            if row is None or row not in rows:
+                return
+            # bit-rot one block of the stored entry; the paranoid
+            # per-block sweep (or the splice victim's probe)
+            # catches it and invalidates the entry
+            blocks = self.prefix_cache.payload(row).blocks
+            if not blocks:
+                return
+            bid = blocks[min(blocks)]
+            self._pool = poison_rows(self._pool, [bid])
         self.fault_plan.record(event)
         self._failure_event("faults_injected")
 
@@ -2938,17 +2794,16 @@ class DecodeEngine:
         """One slot's verdict from the (single) jitted health check —
         the at-admission probe for requests that finish before any
         decode round could sweep them."""
-        if self.paged_kv:
-            bad, toks_ok = self._paged_health()
-            return bool(toks_ok[slot]) and not self._slot_blocks_bad(
-                slot, bad)
-        ok = np.asarray(self._health_jit(self._pool, self._toks))
-        return bool(ok[slot])
+        bad, toks_ok = self._paged_health()
+        return bool(toks_ok[slot]) and not self._slot_blocks_bad(
+            slot, bad)
 
     def _quarantine_victim(self, slot: int, state: _Slot) -> None:
-        """Quarantine one poisoned slot: rows zeroed (the pool is
-        finite again), its prefix-cache footprint invalidated (both
-        the row the admission fetched from and the entry it inserted,
+        """Quarantine one poisoned slot: its blocks released (a
+        poisoned one is scrubbed as its last reference drops, so the
+        pool is finite again), its prefix-cache footprint invalidated
+        (both the entry the admission spliced from and the entry it
+        inserted,
         since either end may carry the corruption), draft state
         dropped, and the victim re-queued with backoff. Shared by the
         post-decode sweep and the finish-at-admission probe."""
@@ -2956,11 +2811,11 @@ class DecodeEngine:
         self._failure_event("quarantined")
         if self.prefix_cache is not None:
             if state.hit_row is not None:
-                # only scrub the fetched row if it still shares
+                # only scrub the spliced entry if it still shares
                 # the matched prefix with this prompt (the stored
                 # entry may extend past it — rewind semantics) —
-                # LRU may have recycled the row for an unrelated
-                # healthy entry since the admission fetched it
+                # LRU may have recycled the id for an unrelated
+                # healthy entry since the admission spliced it
                 held = self.prefix_cache.row_prefix(state.hit_row)
                 prompt = tuple(int(t)
                                for t in state.request.prompt)
@@ -2998,34 +2853,25 @@ class DecodeEngine:
         ``_quarantine_victim``. Returns the healthy subset of
         ``active`` — the poisoned round's tokens never reach a
         result."""
-        if self.paged_kv:
-            bad, toks_ok = self._paged_health()
-            healthy, victims = [], []
-            for slot in active:
-                if bool(toks_ok[slot]) and not self._slot_blocks_bad(
-                        slot, bad):
-                    healthy.append(slot)
-                else:
-                    victims.append(slot)
-            for slot in victims:
-                self._quarantine_victim(slot, self._slots[slot])
-            if bad and self.prefix_cache is not None:
-                # entries still holding poisoned blocks (cache bit-rot
-                # caught BEFORE any splice — the shared pool makes
-                # corruption visible immediately, a strictly smaller
-                # blast radius than the dense fetch-then-detect path)
-                for row in list(self.prefix_cache.stored_rows()):
-                    payload = self.prefix_cache.payload(row)
-                    if set(payload.blocks.values()) & bad:
-                        self.prefix_cache.invalidate_row(row)
-                        self._failure_event("faults_detected")
-            return healthy
-        ok = np.asarray(self._health_jit(self._pool, self._toks))
-        healthy = [s for s in active if bool(ok[s])]
+        bad, toks_ok = self._paged_health()
+        healthy, victims = [], []
         for slot in active:
-            if bool(ok[slot]):
-                continue
+            if bool(toks_ok[slot]) and not self._slot_blocks_bad(
+                    slot, bad):
+                healthy.append(slot)
+            else:
+                victims.append(slot)
+        for slot in victims:
             self._quarantine_victim(slot, self._slots[slot])
+        if bad and self.prefix_cache is not None:
+            # entries still holding poisoned blocks (cache bit-rot
+            # caught BEFORE any splice — the shared pool makes
+            # corruption visible immediately)
+            for row in list(self.prefix_cache.stored_rows()):
+                payload = self.prefix_cache.payload(row)
+                if set(payload.blocks.values()) & bad:
+                    self.prefix_cache.invalidate_row(row)
+                    self._failure_event("faults_detected")
         return healthy
 
     # -- speculative draft & verify (ISSUE 4) --------------------------
@@ -3067,8 +2913,8 @@ class DecodeEngine:
         executable (forward + greedy acceptance + per-slot rewind +
         bonus token in one program). The pool/current-token state is
         updated in place with the (still in-flight) device outputs so
-        the round's decode chunk chains onto the committed state (and,
-        paged, onto the tables the program hands back with each row's
+        the round's decode chunk chains onto the committed state (and
+        onto the tables the program hands back with each row's
         rejected tail rewound out of ``filled``) —
         NOTHING syncs here; ``_land_verify`` fetches the results after
         the decode dispatch so a speculative round still costs ONE
@@ -3197,7 +3043,7 @@ class DecodeEngine:
 
     def _reserve_round(self, active: List[int], drafts, spec_round,
                        fuse_k: int):
-        """Paged engines, before a decode dispatch (the
+        """Before a decode dispatch (the
         ``serving.reserve`` span): the round's view of ``(active,
         drafts, spec_round, fuse_k)`` after every block its writes
         will cross into is reserved."""
@@ -3250,7 +3096,7 @@ class DecodeEngine:
 
     def _land_round(self, inf: _InflightRound) -> None:
         """Commit one dispatched decode round: fetch the tokens (the
-        sync point), mirror paged table advances, run the paranoid
+        sync point), mirror table advances, run the paranoid
         sweep, append/stream committed tokens, finish/evict, and do
         the round's accounting. Synchronous engines call this inline
         right after dispatch (behavior identical to the pre-ISSUE-14
@@ -3330,17 +3176,15 @@ class DecodeEngine:
         if self.record_timing:
             self._last_sync_end = self._clock()
         dt = time.perf_counter() - inf.t0
-        if self.paged_kv:
-            # mirror the device-side filled advance (decode writes —
-            # n_rounds * decode_chunk under a fused scan — + verify's
-            # accepted+bonus) into the host tables, and release blocks
-            # that slid out of every window — the "pop blocks" half of
-            # the paged rewind contract
-            for slot in active:
-                tab = self._kv_tabs[slot]
-                tab.length += inf.decode_tokens + (
-                    int(v_n[slot]) if v_n is not None else 0)
-                self._free_expired_blocks(tab)
+        # mirror the device-side filled advance (decode writes —
+        # n_rounds * decode_chunk under a fused scan — + verify's
+        # accepted+bonus) into the host tables, and release blocks
+        # that slid out of every window
+        for slot in active:
+            tab = self._kv_tabs[slot]
+            tab.length += inf.decode_tokens + (
+                int(v_n[slot]) if v_n is not None else 0)
+            self._free_expired_blocks(tab)
         if self.paranoid:
             active = self._quarantine(active)
         emitted = 0
@@ -3536,20 +3380,13 @@ class DecodeEngine:
                       if self.spec is not None else None)
             spec_round = drafts is not None and any(drafts.values())
             fuse_k = self._plan_fused(active, spec_round)
-            if self.paged_kv:
-                with self._span("serving.reserve", active=len(active)):
-                    (active, drafts, spec_round,
-                     fuse_k) = self._reserve_round(
-                        active, drafts, spec_round, fuse_k)
-                if not active:
-                    # every slot was preempted for blocks: the round
-                    # ends with no decode (requeues drain next round)
-                    self._round += 1
-                    if (t_start is not None and self._clock() - t_start
-                            > self.stall_threshold_s):
-                        self._failure_event("slow_steps")
-                    self._drain_terminal(results)
-                    return
+            with self._span("serving.reserve", active=len(active)):
+                (active, drafts, spec_round,
+                 fuse_k) = self._reserve_round(
+                    active, drafts, spec_round, fuse_k)
+        if active:
+            # (with every slot preempted for blocks the round ends with
+            # no decode; requeues drain next round)
             t0 = time.perf_counter()
             verify_out = None
             ver_dt = 0.0
@@ -3567,13 +3404,12 @@ class DecodeEngine:
                         if clock is not None:
                             clock.add(t_pre, "stall", t_pre - rt0)
             with self._span("serving.tables", active=len(active)):
-                # the round's ONE upload of the block tables (the
-                # dense layout has none), shared by every layer and
-                # by the verify and decode dispatches
-                tables = (self._paged_tables(self._kv_tabs)
-                          if self.paged_kv else None)
+                # the round's ONE upload of the block tables, shared
+                # by every layer and by the verify and decode
+                # dispatches
+                tables = self._paged_tables(self._kv_tabs)
                 pool_op = self._pool
-                if self.paged_kv and self._slot_state:
+                if self._slot_state:
                     # the slot-state layers' rows ride the dispatch
                     # beside the KV leaves (``_strip_pool`` parts them)
                     pool_op = dict(pool_op, **self._slot_state)
@@ -3586,7 +3422,7 @@ class DecodeEngine:
                 # a speculative round commits accepted drafts + bonus
                 # + a full decode chunk in ONE host round-trip — the
                 # round count can never exceed the spec-off engine's
-                # (paged: the rewind travels inside the executable as
+                # (the rewind travels inside the executable as
                 # a filled decrement, and the post-verify tables chain
                 # into the decode scan as the verify program's output)
                 tv0 = self._clock() if self.record_timing else 0.0
@@ -3680,8 +3516,7 @@ class DecodeEngine:
                 # device work is already in flight — the host copy +
                 # pack lands here, off the decode hot path
                 self.drain_spills()
-            if self.paged_kv:
-                self._paged_stats_refresh()
+            self._paged_stats_refresh()
             self._round += 1
             if t_start is not None:
                 if self._clock() - t_start > self.stall_threshold_s:
@@ -3712,18 +3547,17 @@ class DecodeEngine:
                     "spec_fallback_rounds", "spec_drafted",
                     "spec_accepted"):
             self.tracer.counter(f"serving_{key}", self.stats[key])
-        if self.paged_kv:
-            # block-pool gauges (ISSUE 6 satellite): the gateway's
-            # /v1/metrics exports these tracks verbatim, so pool
-            # health is visible from the HTTP front door
-            self._paged_stats_refresh()
-            for key in ("blocks_free", "blocks_used", "cow_copies",
-                        "prefix_blocks_spliced", "frag_tokens",
-                        "preempted", "paged_admit_deferred",
-                        "paged_blocks_live", "paged_blocks_walked",
-                        "paged_blocks_per_step", "paged_steps_per_row",
-                        "table_uploads"):
-                self.tracer.counter(f"serving_{key}", self.stats[key])
+        # block-pool gauges (ISSUE 6 satellite): the gateway's
+        # /v1/metrics exports these tracks verbatim, so pool
+        # health is visible from the HTTP front door
+        self._paged_stats_refresh()
+        for key in ("blocks_free", "blocks_used", "cow_copies",
+                    "prefix_blocks_spliced", "frag_tokens",
+                    "preempted", "paged_admit_deferred",
+                    "paged_blocks_live", "paged_blocks_walked",
+                    "paged_blocks_per_step", "paged_steps_per_row",
+                    "table_uploads"):
+            self.tracer.counter(f"serving_{key}", self.stats[key])
         if self.prefix_cache is not None:
             for key in ("hits", "misses", "evictions"):
                 self.tracer.counter(f"serving_prefix_{key}",
@@ -3844,15 +3678,13 @@ class DecodeEngine:
         for shard, nbytes in per_shard.items():
             self.tracer.gauge(
                 f'serving_tp_kv_bytes{{shard="{shard}"}}', nbytes)
-            if self.paged_kv:
-                for key in ("blocks_free", "blocks_used",
-                            "frag_tokens"):
-                    self.tracer.gauge(
-                        f'serving_{key}{{shard="{shard}"}}',
-                        self.stats[key])
+            for key in ("blocks_free", "blocks_used", "frag_tokens"):
+                self.tracer.gauge(
+                    f'serving_{key}{{shard="{shard}"}}',
+                    self.stats[key])
 
     def kv_shard_bytes(self) -> Dict[int, int]:
-        """Per-shard addressable KV-cache bytes (slot pool only): the
+        """Per-shard addressable KV-cache bytes (the block pool): the
         ``total/TP`` acceptance arithmetic and the per-shard gauges
         read this. At ``tp == 1`` shard 0 holds everything."""
         if self._pool is None:
@@ -3889,27 +3721,19 @@ class DecodeEngine:
 
     def _prime_prefix(self, prefix) -> None:
         """Recompute one snapshotted prefix-cache entry: prefill is
-        deterministic, so the re-primed row is bit-identical to the
-        stored state the crash destroyed."""
+        deterministic, so the re-primed blocks are bit-identical to
+        the stored state the crash destroyed."""
         if self.prefix_cache is None or not len(prefix):
             return
         rnn, _ = self._prefill_sequence([int(t) for t in prefix])
-        if self.paged_kv:
-            # re-prime into fresh blocks, hand ownership to the trie
-            # (the restore-path twin of the zero-copy live insert)
-            self._ensure_paged_pool(rnn)
-            tab = self._alloc_window_tab(len(prefix))
-            if tab is None:
-                return    # pool too small for this entry: skip —
-                #           the cache is a cache, not state
-            table_row, _ = tab.arrays(self._ring_slots)
-            self._pool = self._scatter_jit(
-                self._pool, rnn, jnp.asarray(table_row),
-                jnp.asarray(tab.length, jnp.int32))
-            self.prefix_cache.insert_blocks(prefix, tab)
-            self._free_table(tab)
-            return
-        self.prefix_cache.insert(prefix, rnn)
+        # re-prime into fresh blocks, hand ownership to the trie
+        # (the restore-path twin of the zero-copy live insert)
+        tab = self._write_row(rnn, len(prefix))
+        if tab is None:
+            return    # pool too small for this entry: skip —
+            #           the cache is a cache, not state
+        self.prefix_cache.insert_blocks(prefix, tab)
+        self._free_table(tab)
 
     def _rebuild_slot(self, slot: int, request: Request,
                       tokens: List[int], prefix_reused: int,
@@ -3929,33 +3753,17 @@ class DecodeEngine:
         rnn, _ = self._prefill_sequence(seq, request.temperature,
                                         request.top_k)
         tok = jnp.asarray([int(tokens[-1])], jnp.int32)
-        if self.paged_kv:
-            self._ensure_paged_pool(rnn)
-            tab = self._alloc_window_tab(len(seq))
-            if tab is None:
-                raise RuntimeError(
-                    "paged restore could not allocate blocks for a "
-                    "snapshotted slot — kv_blocks is smaller than the "
-                    "snapshot's working set")
-            with self._span("serving.admit", rid=request.id,
-                            slot=slot, paged=True,
-                            **_targs(request)):
-                self._write_row(rnn, tab, slot)
-            self._toks = self._tok_jit(self._toks, tok,
-                                       jnp.asarray(slot, jnp.int32))
-            self._kv_tabs[slot] = tab
-        else:
-            if self._pool is None:
-                self._pool = self._place(jax.tree_util.tree_map(
-                    lambda a: jnp.zeros((self.n_slots,) + a.shape[1:],
-                                        a.dtype), rnn))
-                self._toks = self._place(
-                    jnp.zeros((self.n_slots,), jnp.int32))
-            with self._span("serving.admit", rid=request.id,
-                            slot=slot, **_targs(request)):
-                self._pool, self._toks = self._admit_jit(
-                    self._pool, self._toks, rnn, tok,
-                    jnp.asarray(slot, jnp.int32))
+        with self._span("serving.admit", rid=request.id,
+                        slot=slot, **_targs(request)):
+            tab = self._write_row(rnn, len(seq), slot)
+        if tab is None:
+            raise RuntimeError(
+                "restore could not allocate blocks for a "
+                "snapshotted slot — kv_blocks is smaller than the "
+                "snapshot's working set")
+        self._toks = self._tok_jit(self._toks, tok,
+                                   jnp.asarray(slot, jnp.int32))
+        self._kv_tabs[slot] = tab
         self._slots[slot] = _Slot(request, [int(t) for t in tokens],
                                   prefix_reused=prefix_reused,
                                   ttft_s=None,
@@ -4041,7 +3849,7 @@ class DecodeEngine:
                 "stall_threshold_s": self.stall_threshold_s,
                 "spec_draft_len": self.spec_draft_len,
                 "draft_source": self.draft_source,
-                "paged_kv": self.paged_kv,
+                "paged_kv": True,
                 "block_tokens": self.block_tokens,
                 "kv_blocks": self.kv_blocks,
                 "record_timing": self.record_timing,
@@ -4061,11 +3869,11 @@ class DecodeEngine:
                 "kv_disk_tier_path": self.kv_disk_tier_path,
                 "kv_disk_tier_bytes": self.kv_disk_tier_bytes,
             },
-            # paged bookkeeping rides the snapshot for inspection and
+            # block bookkeeping rides the snapshot for inspection and
             # exact-capacity restores (restore REBUILDS device blocks
-            # by re-prefilling recorded tokens — same as the dense
-            # engine — so tables here are provenance, not payload)
-            "paged": ({
+            # by re-prefilling recorded tokens, so tables here are
+            # provenance, not payload)
+            "paged": {
                 "block_tokens": self.block_tokens,
                 "kv_blocks": self.kv_blocks,
                 "tables": {
@@ -4080,7 +3888,7 @@ class DecodeEngine:
                     str(b): self.block_pool.refcount(b)
                     for b in range(self.kv_blocks)
                     if self.block_pool.refcount(b) > 0},
-            } if self.paged_kv else None),
+            },
             # tenant registry (ISSUE 13): quotas/priorities survive a
             # drain/restore without the booting host re-plumbing them
             # (restore(tenants=) still overrides)
@@ -4120,7 +3928,7 @@ class DecodeEngine:
                 ) -> "DecodeEngine":
         """Rebuild an engine from ``snapshot()`` output in a fresh
         process: same config, prefix cache re-primed (deterministic
-        prefill reproduces each stored row), every in-flight slot's KV
+        prefill reproduces each stored entry), every in-flight slot's KV
         state re-prefilled from its recorded ids, queue/retry state and
         RNG key restored — ``run()`` then finishes the same ids a
         crash-free engine would have (greedy: bit-identical). In-flight
@@ -4137,6 +3945,11 @@ class DecodeEngine:
         TPU-taken snapshot restores on a CPU host with the gather
         fallback)."""
         cfg = snapshot["config"]
+        if not cfg.get("paged_kv", False):
+            raise ValueError(
+                "this snapshot was taken by an engine with the dense "
+                "KV layout (config.paged_kv false), which was removed "
+                "in PR 29; it cannot be restored")
         if tp is None:
             tp = int(cfg.get("tp", 1))
         if use_flash_paged is _UNSET:
@@ -4162,7 +3975,6 @@ class DecodeEngine:
             stall_threshold_s=cfg["stall_threshold_s"], clock=clock,
             spec_draft_len=cfg.get("spec_draft_len", 0),
             draft_source=cfg.get("draft_source", "ngram"),
-            paged_kv=cfg.get("paged_kv", False),
             block_tokens=cfg.get("block_tokens", 16),
             kv_blocks=cfg.get("kv_blocks") or None,
             record_timing=cfg.get("record_timing", True),

@@ -1042,11 +1042,10 @@ class ServingGateway:
                 eng.stats["prefill_tokens_skipped"],
             # disaggregation surface (ISSUE 14): the role this
             # replica declared, and whether its engine can speak the
-            # KV transfer plane (paged + trie — the router reads
+            # KV transfer plane (it has a trie — the router reads
             # this instead of paying a 404 round-trip per miss)
             "role": self.role,
-            "kv_transfer": bool(eng.paged_kv
-                                and eng.prefix_cache is not None),
+            "kv_transfer": eng.prefix_cache is not None,
             # spill-tier block (ISSUE 17): entry counts + budgets so
             # the router's donor pick can prefer a tier-warm replica
             # over a cold one. KVTierStore.health() is lock-free by
@@ -1084,7 +1083,7 @@ class ServingGateway:
         """``GET /v1/kv/export?tokens=1,2,3``: the longest cached
         prefix of the given prompt as a framed binary payload
         (serving/kv_transfer.py wire format). 404 when nothing
-        reusable is cached (or the engine is not paged — the caller
+        reusable is cached (or the engine has no trie — the caller
         recomputes), 413 when the payload would exceed the transfer
         cap, 400 on a malformed query."""
         tokens: Optional[List[int]] = None
@@ -1156,7 +1155,7 @@ class ServingGateway:
         if payload is None:
             handler.send_json(
                 {"error": "no cached prefix to export (cold, or "
-                          "not a paged engine)"}, 404, close=True)
+                          "an engine with no trie)"}, 404, close=True)
             return
         handler.send_binary(payload)
 

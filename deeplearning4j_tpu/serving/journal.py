@@ -71,9 +71,9 @@ append (strongest: survives power loss at per-record latency),
 most once per ``batch_fsync_s`` (survives process SIGKILL exactly like
 per_record — the OS has the bytes — and loses at most one batch window
 to a kernel panic), ``off`` never fsyncs (still flushes, still
-SIGKILL-safe; for tests and throwaway fleets). The acceptance bench
-(``bench_router_wal_overhead``) prices ``batched`` at >= 0.97x WAL-off
-throughput.
+SIGKILL-safe; for tests and throwaway fleets). An earlier round
+priced ``batched`` at >= 0.97x WAL-off throughput (not on today's
+chip).
 
 The journal is the ROUTER's: replicas have their own drain/restore
 snapshots (PR 3/5) and the two layers compose — a router recovery

@@ -1,7 +1,7 @@
 """Tiered KV cache: host-DRAM (and disk) spill store for evicted
 prefix-trie entries (ISSUE 17 tentpole — ROADMAP item 2).
 
-Before this module, a :class:`~.prefix_cache.PagedPrefixCache` victim
+Before this module, a :class:`~.prefix_cache.RadixPrefixCache` victim
 under HBM pressure was simply dropped and a later hit on that prefix
 paid a full prefill recompute — yet PR 14 measured warm admission at
 5.8x faster than recompute and already built the machinery that makes

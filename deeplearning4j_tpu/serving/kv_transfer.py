@@ -4,7 +4,7 @@
 A prefix warmed on one replica is cold everywhere else, so affinity
 misses, failover replay, and rolling-upgrade warmup all recompute the
 full prompt on the receiver — correct (the PR 9 replay discipline),
-but wrong for long-prompt traffic at fleet scale. The paged engine
+but wrong for long-prompt traffic at fleet scale. The block pool
 already gives KV a serializable block-granular identity
 (:class:`~deeplearning4j_tpu.serving.block_pool.BlockTable` + pool
 block slices), so a warmed prefix can be a fleet-level resource:
@@ -211,8 +211,8 @@ def unpack_prefix(payload: bytes) -> Dict[str, Any]:
 def export_prefix(engine, prompt: Sequence[int],
                   cap_bytes: Optional[int] = None) -> Optional[bytes]:
     """Serialize the longest cached prefix of ``prompt`` from
-    ``engine``'s paged radix trie (None when nothing reusable is
-    cached, or the engine is not paged / has no pool yet). The lease
+    ``engine``'s radix trie (None when nothing reusable is
+    cached, or the engine has no trie / no pool yet). The lease
     taken by the lookup pins the entry while the device blocks are
     sliced to host; device arrays are immutable, so the snapshot is
     consistent even against concurrent rounds. Per-shard aware by
@@ -221,10 +221,7 @@ def export_prefix(engine, prompt: Sequence[int],
     axis), so the payload is identical at any donor width.
     ``cap_bytes`` raises :class:`KVTransferTooLarge` from the block
     arithmetic alone — before any device work runs."""
-    from deeplearning4j_tpu.serving.prefix_cache import PagedPrefixCache
-
-    if (not engine.paged_kv or engine._pool is None
-            or not isinstance(engine.prefix_cache, PagedPrefixCache)):
+    if engine._pool is None or engine.prefix_cache is None:
         return None
     hit = engine.prefix_cache.lookup(prompt)
     if hit is None:
@@ -300,13 +297,9 @@ def import_prefix(engine, payload: bytes) -> Dict[str, Any]:
     geometry mismatch with this engine) raise
     :class:`KVTransferError` instead: those are deployment bugs the
     HTTP layer maps to 400, and recompute still covers correctness."""
-    from deeplearning4j_tpu.serving.prefix_cache import PagedPrefixCache
-
-    if not engine.paged_kv or not isinstance(engine.prefix_cache,
-                                             PagedPrefixCache):
+    if engine.prefix_cache is None:
         raise KVTransferError(
-            "receiver is not a paged engine with a prefix trie "
-            "(paged_kv=True + prefix_cache_rows required)")
+            "receiver has no prefix trie (prefix_cache_rows required)")
     parsed = unpack_prefix(payload)
     header, shipped = parsed["header"], parsed["layers"]
     bt = int(header["block_tokens"])

@@ -1,70 +1,72 @@
 """Radix prefix cache: host-side trie over prompt token ids mapping
-matched prefixes to device-resident KV rows (ISSUE 2 tentpole).
+matched prefixes to blocks of the engine's KV pool (ISSUE 2 tentpole;
+block leases since ISSUE 6).
 
 The serving observation (RadixAttention — SGLang, Zheng et al. 2023):
 real traffic shares long prompt prefixes (system prompts, few-shot
 templates), so the KV state of a prefix computed for one request can
 seed the next request's admission, leaving only the divergent *suffix*
-to prefill. This module owns both halves of that reuse:
+to prefill.
 
-- **Host side** — a radix trie (path-compressed: edges carry token
-  runs, split on divergence) keyed by prompt token ids. Stored nodes
-  map a prefix to one row of the device pool, with LRU eviction over
-  unleased rows and ref-count leases that pin a row while an in-flight
-  admission still reads it.
-- **Device side** — a second fixed pool alongside the engine's slot
-  pool: one row per cached prefix, same pytree structure as the
-  network's streaming state (per attention layer ``k``/``v``/
-  ``filled``), allocated lazily from the first stored state. TWO jitted
-  executables move rows, each compiled exactly once (the engine's
-  bounded-compile-count invariant): ``prefix_store`` scatters a B=1
-  post-prefill state into a row (``dynamic_update_slice`` at a traced
-  row index), ``prefix_fetch`` gathers a row back to B=1
-  (``dynamic_slice``), rewinding ``drop`` trailing tokens in the same
-  program (``nn.streaming.drop_newest_tokens``).
+The trie is path-compressed (edges carry token runs, split on
+divergence) and keyed by prompt token ids. A stored node maps a prefix
+to an entry id (``row``) whose payload is a frozen
+:class:`~.block_pool.BlockTable`: references to the admitted slot's
+own blocks in the shared pool, never a copy. LRU eviction runs over
+unleased entries, and ref-count leases pin an entry while an in-flight
+admission still reads it. Nothing here touches the device:
 
-Why ``drop``: K/V at a position are projections of that token alone,
-so a stored state rewinds EXACTLY to any shorter prefix of itself.
-That serves two purposes. (1) A prompt that diverges ``m`` tokens into
-a cached entry still reuses those ``m`` tokens — the entry's divergent
-tail is rewound away — so the hit criterion is any-shared-prefix, not
+- **insert is zero-copy** — the entry references the admitted slot's
+  blocks (refcount bumps via ``ref_block``); the slot's subsequent
+  appends copy-on-write the shared boundary block instead of mutating
+  it.
+- **a hit is zero-copy** — the engine splices the payload's block ids
+  into the new slot's table.
+- **eviction frees references, not bytes** — dropping an entry derefs
+  its blocks via ``release_block``; a block shared with a live slot
+  stays resident until the slot finishes, so evicting an entry mid-use
+  can never corrupt a reader.
+
+Why a hit may be shorter than the entry: K/V at a position are
+projections of that token alone, so a stored state rewinds EXACTLY to
+any shorter prefix of itself (the engine references only the blocks
+below ``matched``). That serves two purposes. (1) A prompt that
+diverges ``m`` tokens into a cached entry still reuses those ``m``
+tokens, so the hit criterion is any-shared-prefix, not
 whole-stored-prompt. (2) Sampling a request's first token needs the
 logits at its LAST prompt position, which a cached state does not
 carry — so a lookup never consumes the whole prompt: an exact match
-rewinds one token and the engine re-streams the final prompt token as
-a one-token suffix, producing those logits on the regular suffix path.
+stops one token short and the engine re-streams the final prompt token
+as a one-token suffix, producing those logits on the regular suffix
+path.
 
-Leases and JAX immutability: fetched states are snapshots (device
-arrays are immutable — a later eviction/overwrite builds a NEW pool and
-cannot corrupt an earlier fetch). The lease exists for bookkeeping
-honesty: an admission that matched a prefix holds its row until the
-admission completes, so LRU eviction never recycles a row the engine
-still considers live (asserted in tests).
+The lease exists for bookkeeping honesty: an admission that matched a
+prefix holds its entry until the admission completes, so LRU eviction
+never recycles an entry the engine still considers live (asserted in
+tests).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
-import jax
-import jax.numpy as jnp
+from deeplearning4j_tpu.serving.block_pool import BlockTable
 
 
 @dataclasses.dataclass
 class PrefixHit:
     """One successful lookup: ``matched`` prompt tokens are served from
-    cache row ``row`` (after rewinding ``drop`` trailing tokens); the
-    row stays leased until ``release``."""
+    cache entry ``row`` (which may hold more); the entry stays leased
+    until ``release``."""
 
     row: int
     matched: int
-    drop: int
 
 
 class _Node:
     """Radix-trie node: ``edge`` is the token run from the parent,
-    ``depth`` the total prefix length here, ``row`` the device pool row
+    ``depth`` the total prefix length here, ``row`` the entry id
     when this exact prefix is cached (structural nodes carry None)."""
 
     __slots__ = ("edge", "children", "parent", "depth", "row",
@@ -81,22 +83,29 @@ class _Node:
 
 
 class RadixPrefixCache:
-    """Fixed-capacity prefix cache: ``rows`` device-resident KV rows
-    behind a radix trie over prompt token ids.
+    """Fixed-capacity prefix cache: at most ``rows`` entries behind a
+    radix trie over prompt token ids, each entry a lease on blocks of
+    the engine's :class:`~.block_pool.BlockPool` (``ref_block`` /
+    ``release_block`` take and drop one reference to a block id).
 
     ``lookup`` returns the longest cached prefix of a prompt (capped at
-    ``len(prompt) - 1`` — see module docstring) and leases its row;
-    ``fetch`` copies the row to a B=1 streaming state; ``insert``
-    stores a post-prefill state under its full prompt, evicting the
-    least-recently-used unleased row when full (declining, not
-    evicting, when every row is leased). All device movement happens in
-    two jitted executables compiled once each."""
+    ``len(prompt) - 1`` — see module docstring) and leases its entry;
+    ``payload`` is the entry's block table for the engine to splice;
+    ``insert_blocks`` stores an admitted slot's table under its full
+    prompt, evicting the least-recently-used unleased entry when full
+    (declining, not evicting, when every entry is leased). ``rows``
+    caps the number of ENTRIES; device capacity is governed by the
+    block pool itself (``evict_one`` relieves it)."""
 
-    def __init__(self, rows: int):
+    def __init__(self, rows: int, block_tokens: int, ref_block,
+                 release_block):
         if rows < 1:
             raise ValueError(f"prefix cache rows {rows} < 1")
         self.rows = int(rows)
-        self.pool = None                      # [rows, ...] pytree
+        self.block_tokens = int(block_tokens)
+        self._ref_block = ref_block
+        self._release_block = release_block
+        self._payloads: Dict[int, BlockTable] = {}
         self._root = _Node((), None, 0)
         self._free: List[int] = list(range(self.rows))
         self._by_row: Dict[int, _Node] = {}
@@ -105,7 +114,7 @@ class RadixPrefixCache:
         #: pressure-eviction hook (ISSUE 17): called as
         #: ``on_evict(prefix_tokens, payload)`` just before an LRU
         #: victim's payload is dropped, so the engine can spill it to
-        #: the host/disk KV tier. Fires ONLY for ``_evict_lru``
+        #: the host/disk KV tier. Fires ONLY for ``evict_one``
         #: pressure evictions — quarantine invalidations bypass it by
         #: design (poisoned state must never be spilled and reloaded).
         self.on_evict = None
@@ -113,34 +122,6 @@ class RadixPrefixCache:
             "hits": 0, "misses": 0, "inserts": 0, "evictions": 0,
             "declined": 0, "tokens_matched": 0, "invalidations": 0,
         }
-        self._build_jits()
-
-    # -- jitted row movement (one executable each) ---------------------
-    def _build_jits(self):
-        from deeplearning4j_tpu.nn.streaming import drop_newest_tokens
-
-        def fetch(pool, row, drop):
-            one = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_slice_in_dim(a, row, 1,
-                                                       axis=0), pool)
-            return drop_newest_tokens(one, drop)
-
-        def store(pool, rnn1, row):
-            def put(p, o):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    p, o.astype(p.dtype), row, axis=0)
-
-            return jax.tree_util.tree_map(put, pool, rnn1)
-
-        self._fetch_jit = jax.jit(fetch)
-        self._store_jit = jax.jit(store)
-
-    def compile_counts(self) -> Dict[str, int]:
-        def n(f):
-            return int(getattr(f, "_cache_size", lambda: -1)())
-
-        return {"prefix_fetch": n(self._fetch_jit),
-                "prefix_store": n(self._store_jit)}
 
     # -- trie ----------------------------------------------------------
     def _walk(self, tokens: Tuple[int, ...]):
@@ -163,9 +144,9 @@ class RadixPrefixCache:
         node.last_use = self._clock
 
     def _shallowest_stored(self, node: _Node) -> Optional[_Node]:
-        """Closest stored node at or below ``node`` (the one needing
-        the smallest rewind when its subtree shares a prefix with a
-        query that diverged above it)."""
+        """Closest stored node at or below ``node`` (the one holding
+        the fewest tokens past the shared prefix when its subtree
+        shares a prefix with a query that diverged above it)."""
         frontier = [node]
         best: Optional[_Node] = None
         while frontier:
@@ -178,22 +159,22 @@ class RadixPrefixCache:
         return best
 
     def lookup(self, prompt: Sequence[int]) -> Optional[PrefixHit]:
-        """Longest reusable cached prefix of ``prompt``; leases the row
-        (pair every hit with ``release``).
+        """Longest reusable cached prefix of ``prompt``; leases the
+        entry (pair every hit with ``release``).
 
         A stored state need not BE a prefix of the prompt to serve it:
         when the prompt diverges ``m`` tokens into a cached entry (or
-        ends inside it), ``fetch`` rewinds the entry's trailing
-        ``depth - m`` tokens (``drop_newest_tokens`` — K/V are
-        per-token, so the rewound state is exactly the state after
-        ``prompt[:m]``). That makes the hit criterion RadixAttention's:
+        ends inside it), the engine references the entry's blocks
+        below ``m`` only (K/V are per-token, so that is exactly the
+        state after ``prompt[:m]``). That makes the hit criterion
+        RadixAttention's:
         any shared prefix with anything cached, not just whole stored
         prompts. Returns None on miss, or when the reusable part is
         empty (a 1-token prompt can never hit: its first token's
         logits must come from a real prefill)."""
         tokens = tuple(int(t) for t in prompt)
         node, depth = self._root, 0
-        best: Optional[_Node] = None      # stored node to fetch from
+        best: Optional[_Node] = None      # stored node to splice from
         best_m = 0                        # prompt tokens it covers
         while depth < len(tokens):
             child = node.children.get(tokens[depth])
@@ -233,23 +214,20 @@ class RadixPrefixCache:
                 self._ref[best.row] = self._ref.get(best.row, 0) + 1
                 self.stats["hits"] += 1
                 self.stats["tokens_matched"] += matched
-                return PrefixHit(row=best.row, matched=matched,
-                                 drop=best.depth - matched)
+                return PrefixHit(row=best.row, matched=matched)
         self.stats["misses"] += 1
         return None
 
-    def fetch(self, hit: PrefixHit):
-        """Jitted gather: cache row -> B=1 streaming state, rewound by
-        ``hit.drop`` tokens."""
-        return self._fetch_jit(self.pool,
-                               jnp.asarray(hit.row, jnp.int32),
-                               jnp.asarray(hit.drop, jnp.int32))
+    def payload(self, row: int) -> BlockTable:
+        """The frozen block table stored under an entry id returned by
+        ``lookup``."""
+        return self._payloads[row]
 
     def release(self, hit: PrefixHit) -> None:
-        """Drop the lease taken by ``lookup`` (the row becomes
-        evictable again once unreferenced). A row invalidated WHILE
+        """Drop the lease taken by ``lookup`` (the entry becomes
+        evictable again once unreferenced). An entry invalidated WHILE
         leased (fault quarantine) was only unmapped at that point; the
-        last release returns it to the free list."""
+        last release returns its id to the free list."""
         left = self._ref.get(hit.row, 0) - 1
         if left > 0:
             self._ref[hit.row] = left
@@ -260,15 +238,19 @@ class RadixPrefixCache:
                 self._free.append(hit.row)
 
     def _drop_node(self, node: _Node) -> int:
-        """Unmap a stored node (any already-fetched snapshot stays
-        valid — device arrays are immutable) and prune now-empty leaf
-        chains. The row returns to the free list immediately when
-        unleased; a row another in-flight admission still leases is
+        """Unmap a stored node, drop its references to its blocks (a
+        slot that spliced them holds its own) and prune now-empty leaf
+        chains. The id returns to the free list immediately when
+        unleased; an id another in-flight admission still leases is
         only UNMAPPED here (no new lookups can hit it) and ``release``
         frees it when the last lease drops — freeing it now would let
-        an insert reuse a row whose lease bookkeeping still points at
+        an insert reuse an id whose lease bookkeeping still points at
         the old occupant. The quarantine path for corrupted entries."""
         row = node.row
+        payload = self._payloads.pop(row, None)
+        if payload is not None:
+            for bid in payload.blocks.values():
+                self._release_block(bid)
         node.row = None
         del self._by_row[row]
         if self._ref.get(row, 0) == 0:
@@ -282,9 +264,9 @@ class RadixPrefixCache:
         return row
 
     def invalidate_row(self, row: int) -> bool:
-        """Drop the entry stored in ``row`` (fault quarantine: the
-        engine detected NaN state traced back to this row). Returns
-        False when the row holds nothing."""
+        """Drop the entry stored under id ``row`` (fault quarantine:
+        the engine detected NaN state traced back to it). Returns
+        False when the id holds nothing."""
         node = self._by_row.get(row)
         if node is None:
             return False
@@ -294,9 +276,9 @@ class RadixPrefixCache:
 
     def invalidate(self, prompt: Sequence[int]) -> bool:
         """Drop the entry stored under exactly ``prompt`` (fault
-        quarantine: an admission built on a corrupt fetch re-inserted
+        quarantine: an admission built on a corrupt splice re-inserted
         its poisoned state under its full prompt — both ends must be
-        scrubbed before the retry, or the retry re-fetches the
+        scrubbed before the retry, or the retry re-splices the
         poison)."""
         tokens = tuple(int(t) for t in prompt)
         node, depth = self._walk(tokens)
@@ -307,16 +289,16 @@ class RadixPrefixCache:
         return True
 
     def stored_rows(self) -> List[int]:
-        """Rows currently holding entries (fault injection picks its
+        """Ids currently holding entries (fault injection picks its
         corruption target from these)."""
         return sorted(self._by_row)
 
     def row_prefix(self, row: int) -> Optional[Tuple[int, ...]]:
-        """The token prefix currently stored in ``row`` (None when the
-        row holds nothing). Quarantine uses this to confirm a
-        suspect row still holds an ancestor of the poisoned prompt
-        before invalidating — the row may have been LRU-recycled for
-        an unrelated healthy entry since the admission fetched it."""
+        """The token prefix currently stored under id ``row`` (None
+        when it holds nothing). Quarantine uses this to confirm a
+        suspect entry still holds an ancestor of the poisoned prompt
+        before invalidating — the id may have been LRU-recycled for
+        an unrelated healthy entry since the admission spliced it."""
         node = self._by_row.get(row)
         if node is None:
             return None
@@ -327,36 +309,52 @@ class RadixPrefixCache:
         return tuple(t for edge in reversed(parts) for t in edge)
 
     def _spill_victim(self, node: _Node) -> None:
-        """Give ``on_evict`` the victim's prefix + payload BEFORE the
-        drop (pressure evictions only — the spill seam the KV tier
-        rides; a no-op here because the dense cache's row payloads are
-        cheap to recompute and the tier speaks block tables)."""
+        """Spill seam (ISSUE 17): hand the pressure victim's prefix
+        tokens + frozen block table to ``on_evict`` while its blocks
+        are still referenced — the hook dispatches the jitted
+        ``kv_gather`` against the CURRENT pool value (device arrays
+        are immutable, so the gathered snapshot survives the blocks'
+        recycling). A hook fault must never turn an eviction into an
+        engine fault: the tier is an optimization, the drop proceeds
+        regardless."""
+        prefix = self.row_prefix(node.row)
+        payload = self._payloads.get(node.row)
+        if prefix is None or payload is None:
+            return
+        try:
+            self.on_evict(prefix, payload)
+        except Exception:
+            pass
 
-    def _evict_lru(self) -> Optional[int]:
+    def evict_one(self) -> bool:
+        """Evict the LRU unleased entry: to make room for an insert,
+        or to relieve BLOCK-pool pressure (the engine calls this when
+        allocation fails). Returns False when nothing is evictable.
+        The freed resource is the blocks' references; the entry id
+        goes back to the free list."""
         victims = [nd for row, nd in self._by_row.items()
                    if self._ref.get(row, 0) == 0]
         if not victims:
-            return None
+            return False
         node = min(victims, key=lambda nd: nd.last_use)
         if self.on_evict is not None:
             self._spill_victim(node)
-        # one prune implementation: _drop_node unmaps + prunes, and —
-        # the victim being unleased — puts the row on the free list;
-        # take it straight back for the caller's immediate reuse
-        row = self._drop_node(node)
-        self._free.remove(row)
+        self._drop_node(node)
         self.stats["evictions"] += 1
-        return row
+        return True
 
     def _alloc_row(self) -> Optional[int]:
-        if self._free:
-            return self._free.pop()
-        return self._evict_lru()
+        if not self._free and not self.evict_one():
+            return None
+        return self._free.pop()
 
-    def insert(self, prompt: Sequence[int], rnn1: Any) -> bool:
-        """Store a B=1 post-prefill state under its full prompt.
-        Duplicate prompts refresh LRU only; a full cache with every row
-        leased declines (never blocks, never evicts a leased row)."""
+    def insert_blocks(self, prompt: Sequence[int], tab) -> bool:
+        """Store a prompt's KV footprint as references to ``tab``'s
+        blocks (a frozen snapshot of the admitted slot's table —
+        refcount +1 per block, zero device work). Duplicate prompts
+        refresh recency only; a full cache evicts the LRU unleased
+        entry, and with every entry leased declines (never blocks,
+        never evicts a leased entry)."""
         tokens = tuple(int(t) for t in prompt)
         if not tokens:
             return False
@@ -368,17 +366,16 @@ class RadixPrefixCache:
         if row is None:
             self.stats["declined"] += 1
             return False
-        # re-walk AFTER allocation: evicting the LRU row may have
+        # re-walk AFTER allocation: evicting the LRU entry may have
         # pruned nodes on the first walk's path — grafting from the
         # stale node would extend a detached subtree (unreachable
         # entry now, corrupted prune bookkeeping later)
         node, depth = self._walk(tokens)
-        if self.pool is None:
-            self.pool = jax.tree_util.tree_map(
-                lambda a: jnp.zeros((self.rows,) + a.shape[1:],
-                                    a.dtype), rnn1)
-        self.pool = self._store_jit(self.pool, rnn1,
-                                    jnp.asarray(row, jnp.int32))
+        frozen = BlockTable(self.block_tokens, dict(tab.blocks),
+                            tab.length, tab.floor)
+        for bid in frozen.blocks.values():
+            self._ref_block(bid)
+        self._payloads[row] = frozen
         node = self._graft(node, depth, tokens)
         node.row = row
         self._by_row[row] = node
@@ -415,7 +412,7 @@ class RadixPrefixCache:
         return node
 
     def clear(self) -> int:
-        """Drop every stored entry (rows still leased by an in-flight
+        """Drop every stored entry (ids still leased by an in-flight
         admission are unmapped now and freed at the last release).
         Returns the number of entries dropped — the soak's
         pool-fully-free gate empties the trie through this."""
@@ -446,140 +443,6 @@ class RadixPrefixCache:
 
         rec(self._root, ())
         return sorted(out)
-
-    def leased_rows(self) -> Dict[int, int]:
-        return dict(self._ref)
-
-
-class PagedPrefixCache(RadixPrefixCache):
-    """Radix prefix trie over the SHARED paged KV block pool (ISSUE 6):
-    the same path-compressed trie, leases, LRU and invalidation
-    machinery as the dense cache, but an entry's payload is a list of
-    block ids leased from the engine's :class:`~.block_pool.BlockPool`
-    instead of a private device row.
-
-    Consequences of the paged payload:
-
-    - **insert is zero-copy** — the entry references the admitted
-      slot's own blocks (refcount bumps via ``ref_block``); no
-      ``prefix_store`` executable exists, and the slot's subsequent
-      appends copy-on-write the shared boundary block instead of
-      mutating it.
-    - **a hit is zero-copy** — the engine splices the payload's block
-      ids into the new slot's table (no ``prefix_fetch`` gather); the
-      dense cache's exact one-token rewind survives as "reference one
-      block fewer / CoW the boundary block" (drop_newest_tokens
-      semantics moved to the host).
-    - **eviction frees references, not bytes** — dropping an entry
-      derefs its blocks via ``release_block``; a block shared with a
-      live slot stays resident until the slot finishes, so evicting an
-      entry mid-use can never corrupt a reader.
-
-    ``rows`` caps the number of ENTRIES (ids recycle through the base
-    machinery); device capacity is governed by the block pool itself.
-    The base class's jitted row movers are never invoked —
-    ``compile_counts`` is empty, which the bench's zero-whole-row-copy
-    gate asserts."""
-
-    def __init__(self, rows: int, block_tokens: int, ref_block,
-                 release_block):
-        super().__init__(rows)
-        self.block_tokens = int(block_tokens)
-        self._ref_block = ref_block
-        self._release_block = release_block
-        self._payloads: Dict[int, Any] = {}
-
-    def compile_counts(self) -> Dict[str, int]:
-        return {}
-
-    def fetch(self, hit: PrefixHit):
-        raise NotImplementedError(
-            "paged prefix hits are spliced (zero-copy block-table "
-            "reference), not fetched — see DecodeEngine paged "
-            "admission")
-
-    def insert(self, prompt: Sequence[int], rnn1: Any) -> bool:
-        raise NotImplementedError(
-            "paged prefix entries reference pool blocks — use "
-            "insert_blocks")
-
-    def payload(self, row: int):
-        """The :class:`~.block_pool.BlockTable` payload stored under
-        an entry id returned by ``lookup``."""
-        return self._payloads[row]
-
-    def insert_blocks(self, prompt: Sequence[int], tab) -> bool:
-        """Store a prompt's KV footprint as references to ``tab``'s
-        blocks (a frozen snapshot of the admitted slot's table —
-        refcount +1 per block, zero device work). Duplicate prompts
-        refresh recency only; an exhausted entry table evicts LRU
-        unleased entries exactly like the dense cache."""
-        tokens = tuple(int(t) for t in prompt)
-        if not tokens:
-            return False
-        node, depth = self._walk(tokens)
-        if depth == len(tokens) and node.row is not None:
-            self._touch(node)
-            return False
-        row = self._alloc_row()
-        if row is None:
-            self.stats["declined"] += 1
-            return False
-        # re-walk after allocation (LRU eviction may have pruned the
-        # first walk's path — same hazard as the dense insert)
-        node, depth = self._walk(tokens)
-        from deeplearning4j_tpu.serving.block_pool import BlockTable
-
-        frozen = BlockTable(self.block_tokens, dict(tab.blocks),
-                            tab.length, tab.floor)
-        for bid in frozen.blocks.values():
-            self._ref_block(bid)
-        self._payloads[row] = frozen
-        node = self._graft(node, depth, tokens)
-        node.row = row
-        self._by_row[row] = node
-        self._touch(node)
-        self.stats["inserts"] += 1
-        return True
-
-    def _spill_victim(self, node: _Node) -> None:
-        """Paged spill seam (ISSUE 17): hand the pressure victim's
-        prefix tokens + frozen block table to ``on_evict`` while its
-        blocks are still referenced — the hook dispatches the jitted
-        ``kv_gather`` against the CURRENT pool value (device arrays
-        are immutable, so the gathered snapshot survives the blocks'
-        recycling). A hook fault must never turn an eviction into an
-        engine fault: the tier is an optimization, the drop proceeds
-        regardless."""
-        prefix = self.row_prefix(node.row)
-        payload = self._payloads.get(node.row)
-        if prefix is None or payload is None:
-            return
-        try:
-            self.on_evict(prefix, payload)
-        except Exception:
-            pass
-
-    def _drop_node(self, node: _Node) -> int:
-        payload = self._payloads.pop(node.row, None)
-        if payload is not None:
-            for bid in payload.blocks.values():
-                self._release_block(bid)
-        return super()._drop_node(node)
-
-    def evict_one(self) -> bool:
-        """Evict the LRU unleased entry to relieve BLOCK-pool pressure
-        (the engine calls this when allocation fails). Returns False
-        when nothing is evictable. Unlike the dense path the freed
-        resource is the blocks' references — the entry id goes back to
-        the free list."""
-        row = self._evict_lru()
-        if row is None:
-            return False
-        # _evict_lru pulls the row off the free list for immediate
-        # dense-pool reuse; here the id itself is the only resource
-        self._free.append(row)
-        return True
 
     def block_ids(self) -> List[int]:
         """Every block id currently referenced by a stored entry

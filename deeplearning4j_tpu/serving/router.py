@@ -469,7 +469,7 @@ class ServingRouter:
     - ``max_replays`` — replay budget per request across replica
       deaths; past it the request terminates ``fault``.
     - ``fleet_trace`` — fleet observability master switch (default
-      ON; priced >= 0.97x by ``bench_fleet_trace_overhead``):
+      ON; priced >= 0.97x in an earlier round):
       trace-context propagation, router spans, the incremental
       per-replica trace cache, and clock-offset estimation.
     - ``kv_transfer`` — KV transfer plane master switch (ISSUE 14;
@@ -492,8 +492,8 @@ class ServingRouter:
       and serves client resumes from the recovered breadcrumbs.
     - ``fsync`` — the WAL durability policy (``per_record`` /
       ``batched`` / ``off``; serving/journal.py). ``batched``
-      (default) is SIGKILL-safe and priced >= 0.97x WAL-off by
-      ``bench_router_wal_overhead``.
+      (default) is SIGKILL-safe and was priced >= 0.97x WAL-off in
+      an earlier round.
     - ``wal_compact_bytes`` — compaction threshold: past it the live
       state folds into one snapshot record and the file rewrites
       atomically, so the WAL stays bounded like ``journal_cap``.
@@ -557,8 +557,8 @@ class ServingRouter:
         #: throttled (warmup must always land)
         self.tenants = tenants
         self._buckets: Dict[str, Any] = {}
-        #: fleet observability master switch (ISSUE 10; default ON —
-        #: priced by bench_fleet_trace_overhead): trace-context
+        #: fleet observability master switch (ISSUE 10; default ON):
+        #: trace-context
         #: propagation to replicas, router route/replay spans, the
         #: per-replica trace cache, and clock-offset estimation. Off,
         #: the router is the span-silent ISSUE 9 router (the

@@ -38,8 +38,8 @@ differentiated service:
   stays untouched.
 
 Tenancy is FREE when unused: an engine built without a registry keeps
-the seed FIFO scheduler and does zero per-tenant bookkeeping (gated
->= 0.97x by ``bench.py:bench_tenant_qos_overhead``), and a registry
+the seed FIFO scheduler and does zero per-tenant bookkeeping, and a
+registry
 whose only traffic is the ``default`` tenant admits in arrival order
 exactly like FIFO (one backlogged tenant's fair order IS arrival
 order)."""

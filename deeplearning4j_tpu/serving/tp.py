@@ -15,15 +15,16 @@ attention heads:
   ``Wo`` row-sliced (``P("tp", None)``); everything else replicated.
   The layer body runs on local heads and all-reduces the output
   projection once (``nn/layers/attention.py:tp_head_shards``).
-- **KV state**: every cache leaf shards on its HEAD axis — dense rows
-  ``[B, H, W, dh]`` at ``P(None, "tp", None, None)``, paged pool
+- **KV state**: every cache leaf shards on its HEAD axis — a cold
+  admission's dense row ``[1, H, W, dh]`` at
+  ``P(None, "tp", None, None)``, the pool's
   blocks ``[n_blocks, block_tokens, H, dh]`` at
   ``P(None, None, "tp", None)`` — so per-shard KV bytes are exactly
   ``total / TP``, which is what lets a model whose KV working set
   exceeds one chip serve at all.
 - **host bookkeeping is layout-invariant**: block ids, refcounts,
   CoW, quarantine, the radix trie, and the snapshot wire format never
-  see the head axis, so ``BlockTable``/``PagedPrefixCache``/the PR 6
+  see the head axis, so ``BlockTable``/``RadixPrefixCache``/the PR 6
   pressure ladder work unchanged, and a snapshot taken at one TP
   width restores at any other (device state is rebuilt by re-prefill).
 
@@ -33,12 +34,12 @@ are completed by the psum before sampling, and the health reduction
 all-reduces its verdict, so the engine's control flow — and therefore
 greedy ids — is bit-identical to the single-chip engine at the argmax
 level (the PR 6 paged-parity convention; gated by
-tests/test_serving_tp.py and the ``bench_decode_tp`` row).
+tests/test_serving_tp.py).
 
 In-spec/out-spec pytrees are derived from leaf KEY PATHS at trace
 time (``pk``/``pv``/``k``/``v`` under an attention layer's key ride
 the head sharding; everything else replicates), so the polymorphic
-cache dicts — dense rows during a cold paged admission, paged pool
+cache dicts — a dense row during a cold admission, the pool's
 leaves beside one replicated block-table operand during decode — wrap
 without per-structure plumbing.
 """
@@ -124,7 +125,7 @@ class TPContext:
                 # paged pool blocks [n_blocks, block_tokens, H, dh]
                 return self._norm((None, None, self.axis, None))
             if last in ("k", "v") and getattr(leaf, "ndim", 0) == 4:
-                # dense cache rows [B, H, W, dh]
+                # a cold admission's dense row [1, H, W, dh]
                 return self._norm((None, self.axis, None, None))
         return P()
 

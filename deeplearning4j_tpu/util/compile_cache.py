@@ -2,9 +2,9 @@
 
 The one place in the tree that names a compilation-cache directory.
 Every entry point that compiles for the accelerator (``chip_smoke.py``,
-the ``dl4j-tpu`` CLI, ``bench.py``, ``scripts/*_bench.py``) calls
+the ``dl4j-tpu`` CLI, ``benchmark/run.py``, ``scripts/*_bench.py``) calls
 :func:`enable_compile_cache` before its first trace, so a second
-process — a fleet replica, a rerun, the next bench row's child — loads
+process — a fleet replica, a rerun, the next benchmark run — loads
 executables instead of recompiling them.
 """
 

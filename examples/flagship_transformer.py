@@ -1,6 +1,6 @@
 """The converging flagship: a width-1024 pre-LN transformer trained to
 the analytic entropy floor of a Markov language — the configuration
-bench.py gates at >= 40% MFU (measures 55-69% on a v5e chip depending
+an earlier round's bench.py gates at >= 40% MFU (measures 55-69% on a v5e chip depending
 on width).
 
 Demonstrates the round-4 pieces working together:

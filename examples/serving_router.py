@@ -372,8 +372,7 @@ def main():
 
     def paged_replica(i):
         engine = DecodeEngine(net, n_slots=4, decode_chunk=2,
-                              paged_kv=True, block_tokens=4,
-                              prefix_cache_rows=4)
+                              block_tokens=4, prefix_cache_rows=4)
         return ServingGateway(engine, replica_id=f"kv-{i}",
                               keepalive_s=0.1).start()
 

@@ -6,7 +6,7 @@ three ways, fastest first:
 1. **Fused ``generate()``** — ONE jitted ``lax.scan`` emits every token
    with the fixed-size KV cache (`MultiHeadSelfAttention.stream_max_t`)
    riding in the scan carry; no host round-trip per token. This is the
-   serving-throughput path (bench.py ``decode_tokens_per_sec``).
+   serving-throughput path (an earlier round's bench.py ``decode_tokens_per_sec``).
 2. **One ``rnn_time_step`` step** — the per-token path (the reference's
    rnnTimeStep serving contract, extended to attention), kept as a
    parity check that the fused scan streams the same computation.
@@ -147,7 +147,7 @@ def main():
     # the workload the radix prefix cache exists for. The first
     # admission prefills the whole prompt (cold, in chunks between
     # decode rounds so neighbours never stall); every later admission
-    # fetches the shared prefix's KV rows from the cache and prefills
+    # splices the shared prefix's KV blocks from the cache and prefills
     # ONLY its tail. Greedy ids stay identical to solo generate().
     warm = DecodeEngine(net, n_slots=4, decode_chunk=4,
                         prefix_cache_rows=4, prefill_chunk=8)
@@ -210,8 +210,9 @@ def main():
           " drafts accepted overall")
     print("spec compile counts:", spec.compile_counts())
 
-    # Paged KV memory: the same shared-system-prompt workload on the
-    # block-pool layout (paged_kv=True) — slots and the prefix trie
+    # The KV block pool: the same shared-system-prompt workload with
+    # smaller blocks, and the pool's counters printed — slots and the
+    # prefix trie
     # share ONE pool of fixed-size token blocks, so a warm hit is a
     # ZERO-COPY block-table splice (refcount bumps, no row copy) and
     # the only device copy sharing ever pays is a copy-on-write of
@@ -219,7 +220,7 @@ def main():
     # Greedy ids stay identical to solo generate().
     paged = DecodeEngine(net, n_slots=4, decode_chunk=4,
                          prefix_cache_rows=4, prefill_chunk=8,
-                         paged_kv=True, block_tokens=8)
+                         block_tokens=8)
     paged_reqs = {
         paged.submit(Request(prompt=system_prompt + tail,
                              max_new_tokens=8)): tail
@@ -283,8 +284,7 @@ def main():
     # either way — the tier only moves the admission wall.
     tier = DecodeEngine(net, n_slots=2, decode_chunk=4,
                         prefix_cache_rows=2, prefill_chunk=8,
-                        paged_kv=True, block_tokens=8,
-                        kv_host_tier_bytes=1 << 20)
+                        block_tokens=8, kv_host_tier_bytes=1 << 20)
     long_prompt = (PATTERN * 4)[:30]
 
     def tier_admit(prompt):
@@ -338,7 +338,7 @@ def main():
         return
     tp_eng = DecodeEngine(net, n_slots=4, decode_chunk=4,
                           prefix_cache_rows=4, prefill_chunk=8,
-                          paged_kv=True, block_tokens=8, tp=2)
+                          block_tokens=8, tp=2)
     tp_reqs = {
         tp_eng.submit(Request(prompt=system_prompt + tail,
                               max_new_tokens=8)): tail

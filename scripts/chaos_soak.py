@@ -139,9 +139,12 @@ def run_soak(n_requests: int = 200, seed: int = 0, vocab: int = 12,
         f"{len(mismatched)} healthy finishes diverged from the "
         f"fault-free run: {mismatched[:5]}")
     counts = eng.compile_counts()
-    assert counts["decode"] == 1 and counts["admit"] == 1, counts
+    assert counts["decode"] == 1, counts
+    assert counts["paged_scatter"] == 1 and counts["paged_tok"] == 1, (
+        counts)
     assert counts["health_check"] == 1, counts
-    assert counts["chunk_prefill"] == 1, counts
+    # a cold row's continuation, a warm table's
+    assert 1 <= counts["chunk_prefill"] <= 2, counts
 
     summary = {
         "n_requests": n_requests,

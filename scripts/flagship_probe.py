@@ -2,8 +2,8 @@
 
 Trains transformer_lm_flagship on the Markov-chain task on the real
 chip, reporting per-epoch wall clock, tokens/sec, MFU, and held-out
-loss vs the analytic entropy floor — the tuning loop for the bench.py
-flagship row. Run: python scripts/flagship_probe.py [--width 1024 ...]
+loss vs the analytic entropy floor — the tuning loop for the flagship
+block. Run: python scripts/flagship_probe.py [--width 1024 ...]
 """
 
 from __future__ import annotations

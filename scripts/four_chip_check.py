@@ -169,8 +169,8 @@ def stage_tp() -> None:
                if size()["serve"]["n_heads"] % w == 0):
         before = device_bytes()
         t0 = time.perf_counter()
-        eng = DecodeEngine(net, n_slots=8, paged_kv=True,
-                           block_tokens=16, prefix_cache_rows=8, tp=tp)
+        eng = DecodeEngine(net, n_slots=8, block_tokens=16,
+                           prefix_cache_rows=8, tp=tp)
         rids = [eng.submit(Request(prompt=p,
                                    max_new_tokens=size()["n_new"]))
                 for p in prompts(2)]
@@ -300,7 +300,7 @@ def stage_fleet() -> None:
          model] + ["--tiny"] * TINY, check=True)
     args = build_parser().parse_args([
         "fleet", "--model", model, "--replicas", "4", "--port", "0",
-        "--paged-kv", "--block-tokens", "16", "--slots", "8"])
+        "--block-tokens", "16", "--slots", "8"])
     t0 = time.perf_counter()
     seeds, router, controller = fleet_from_args(args)
     try:
@@ -347,8 +347,7 @@ def stage_local4() -> None:
     engines = []
     for i in range(4):
         before = device_bytes()
-        eng = DecodeEngine(net, n_slots=8, paged_kv=True,
-                           block_tokens=16)
+        eng = DecodeEngine(net, n_slots=8, block_tokens=16)
         rid = eng.submit(Request(prompt=prompts()[0],
                                  max_new_tokens=8))
         eng.run()

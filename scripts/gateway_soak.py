@@ -263,9 +263,12 @@ def run_soak(n_clients: int = 48, seed: int = 0, vocab: int = 12,
     assert eng.scheduler.pending == 0 and not eng._requeue
 
     counts = eng.compile_counts()
-    assert counts["decode"] == 1 and counts["admit"] == 1, counts
+    assert counts["decode"] == 1, counts
+    assert counts["paged_scatter"] == 1 and counts["paged_tok"] == 1, (
+        counts)
     assert counts["health_check"] == 1, counts
-    assert counts["chunk_prefill"] == 1, counts
+    # a cold row's continuation, a warm table's
+    assert 1 <= counts["chunk_prefill"] <= 2, counts
 
     gw.close()
     # zero leaked threads (shared settle-loop gate —

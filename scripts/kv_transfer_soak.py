@@ -54,11 +54,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 VOCAB = 12
 NET_SEED = 11
-#: paged + chunked + ASYNC double-buffered rounds: the full ISSUE 14
+#: a trie + chunked + ASYNC double-buffered rounds: the full ISSUE 14
 #: engine configuration, under churn
 ENGINE = dict(n_slots=3, decode_chunk=2, prefix_cache_rows=4, seed=0,
-              paged_kv=True, block_tokens=8, prefill_chunk=4,
-              async_rounds=True)
+              block_tokens=8, prefill_chunk=4, async_rounds=True)
 AFFINITY_BLOCK = 8  # matches block_tokens: cohort prefixes are keys
 
 

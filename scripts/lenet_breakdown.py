@@ -1,12 +1,11 @@
 """Per-op LeNet-5 train-step breakdown on the real TPU chip.
 
-Attributes the LeNet step time (BENCH `mnist_lenet5_train_throughput`,
-~13-14% MFU) to its constituent blocks, substantiating BENCHMARKS.md's
-"the 1998 architecture, not the conv machinery" claim next to the
-wide_cnn control row (~47% MFU on the same machinery).
+Attributes the LeNet step time (an earlier round measured ~13-14% MFU)
+to its constituent blocks, to tell "the 1998 architecture" from "the
+conv machinery" (a wide CNN on the same machinery measured ~47%).
 
 Method: ablation over conf-built subnets timed on the IDENTICAL
-fit_scan path bench.py uses (K fused steps per dispatch, value-fetch
+fit_scan path (K fused steps per dispatch, value-fetch
 sync, bf16 compute + f32 head). Subtracting a minimal head-only net's
 time isolates each block, so scan plumbing/updater/dispatch overheads
 cancel instead of being mis-attributed (a naive per-op microbench pays
@@ -46,7 +45,7 @@ def _build(layers, input_type, lr=0.002):
 
 def _time_net(net, feats, labels, k, reps=3, calls=20):
     """ms/step over `calls` BACK-TO-BACK fit_scan dispatches with one
-    value-fetch sync at the end (bench.py's estimator): a per-call sync
+    value-fetch sync at the end: a per-call sync
     pays a host round trip per call and would swamp sub-ms steps."""
 
     def run():
@@ -79,7 +78,7 @@ def kernel_compare(B=2048, K=64, calls=10, reps=3):
     without a (slow) global reduce; the accumulator-only floor is
     printed so the conv share is readable.
 
-    Round-5 measurement (BENCHMARKS.md conv section): XLA 0.292 ms vs
+    Round-5 measurement: XLA 0.292 ms vs
     pallas 1.244 ms vs floor 0.120 ms — conv-only ~0.17 vs ~1.12 ms,
     XLA's packed-MXU conv beats the VPU hand kernel ~6.5x on the real
     MACs; C_in 1->8 zero-packing and NHWC layouts measured as no-ops

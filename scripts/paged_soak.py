@@ -1,19 +1,20 @@
-"""Fragmentation soak for the paged KV block pool (ISSUE 6).
+"""Fragmentation soak for the KV block pool (ISSUE 6).
 
 Churns seeded ragged-length requests — several shared-prefix cohorts
-plus unique-prompt traffic — through a ``paged_kv=True`` engine on a
+plus unique-prompt traffic — through a ``DecodeEngine`` on a
 DELIBERATELY tight ``kv_blocks`` budget, so every pressure path runs
 hot: zero-copy splices, boundary-block CoW, trie evictions for blocks,
 admission defers, and youngest-slot preemption. The pass criteria:
 
 - every request reaches a terminal state and every greedy finish is
-  BIT-IDENTICAL to the same workload on the DENSE engine (preemption,
-  deferral, and sharing must all be invisible in ids);
+  BIT-IDENTICAL to ``net.generate`` of the same prompt, a request at
+  a time (preemption, deferral, and sharing must all be invisible in
+  ids);
 - zero leaked blocks: once idle, the pool holds exactly the prefix
   trie's references — and after clearing the trie it is FULLY free,
   with every refcount at zero;
-- compile counts stay at the paged budget (one paged decode, one
-  scatter, one token put, <= 2 chunk-continuation variants).
+- compile counts stay at their budget (one decode, one scatter, one
+  token put, <= 2 chunk-continuation variants).
 
 Run standalone (``python scripts/paged_soak.py [--fast]``) or via the
 registered tests (tests/test_paged_soak.py: fast variant tier-1, the
@@ -74,8 +75,8 @@ def run_soak(n_requests: int = 160, seed: int = 0, vocab: int = 12,
              verbose: bool = False) -> Dict[str, Any]:
     """One seeded soak; returns a summary dict and raises
     AssertionError on any gate violation. ``tp > 1`` (ISSUE 12) runs
-    the paged engine SHARDED over attention heads — same pressure
-    ladder, same dense-reference parity gate, plus per-shard gates:
+    the engine SHARDED over attention heads — same pressure
+    ladder, same ``net.generate`` parity gate, plus per-shard gates:
     the head-sliced pool shards hold identical byte counts
     (total/TP), and zero blocks leak per shard (block ids are
     shard-invariant, so the host leak audit IS the per-shard audit —
@@ -84,7 +85,7 @@ def run_soak(n_requests: int = 160, seed: int = 0, vocab: int = 12,
     ``host_tier_bytes > 0`` (ISSUE 17) arms the host-DRAM spill tier
     under the same pressure churn: trie victims spill, later cohort
     hits reload, and the gates extend with — ids STILL bit-identical
-    to the dense engine (spill/reload must be invisible), resident
+    to the reference (spill/reload must be invisible), resident
     host bytes never exceed the budget (peak-tracked every round),
     the tier actually exercised (spills and reloads both non-zero),
     and the tier counters reconcile: spills == reloads + drops +
@@ -97,23 +98,23 @@ def run_soak(n_requests: int = 160, seed: int = 0, vocab: int = 12,
     cases = _workload(rng, n_requests, vocab, window)
     baseline = leak_baseline()
 
-    def build(paged: bool):
-        return DecodeEngine(
-            _build_net(vocab, 7, window), n_slots=n_slots,
-            decode_chunk=4, prefix_cache_rows=8, prefill_chunk=4,
-            admission_policy="decode", max_queue=4 * n_requests,
-            paged_kv=paged, block_tokens=block_tokens,
-            kv_blocks=kv_blocks if paged else None,
-            tp=tp if paged else 1,
-            use_flash_paged=use_flash_paged if paged else None,
-            kv_host_tier_bytes=host_tier_bytes if paged else 0)
+    # the reference: the net's own generate, a request at a time —
+    # the ids every finish must match
+    ref_net = _build_net(vocab, 7, window)
+    ref = []
+    for prompt, n in cases:
+        ref_net.rnn_clear_previous_state()
+        x = np.zeros((1, vocab, len(prompt)), np.float32)
+        x[0, prompt, np.arange(len(prompt))] = 1.0
+        ref.append(np.asarray(ref_net.generate(x, n))[0].tolist())
 
-    # dense reference: the ids every paged finish must match
-    ref_eng = build(False)
-    ref_ids = [ref_eng.submit(Request(list(p), n)) for p, n in cases]
-    ref = ref_eng.run()
-
-    eng = build(True)
+    eng = DecodeEngine(
+        _build_net(vocab, 7, window), n_slots=n_slots,
+        decode_chunk=4, prefix_cache_rows=8, prefill_chunk=4,
+        admission_policy="decode", max_queue=4 * n_requests,
+        block_tokens=block_tokens, kv_blocks=kv_blocks, tp=tp,
+        use_flash_paged=use_flash_paged,
+        kv_host_tier_bytes=host_tier_bytes)
     ids = [eng.submit(Request(list(p), n)) for p, n in cases]
     t0 = time.perf_counter()
     results: Dict[int, Any] = {}
@@ -131,15 +132,15 @@ def run_soak(n_requests: int = 160, seed: int = 0, vocab: int = 12,
     assert set(results) == set(ids), (
         f"lost requests: {sorted(set(ids) - set(results))[:5]}")
     mismatched = []
-    for rid, ref_rid in zip(ids, ref_ids):
+    for rid, ref_tokens in zip(ids, ref):
         r = results[rid]
         assert r.finish_reason in ("length", "eos"), (
             f"request {rid}: unexpected terminal {r.finish_reason!r}")
-        if r.tokens != ref[ref_rid].tokens:
+        if r.tokens != ref_tokens:
             mismatched.append(rid)
     assert not mismatched, (
-        f"{len(mismatched)} paged finishes diverged from the dense "
-        f"engine: {mismatched[:5]}")
+        f"{len(mismatched)} finishes diverged from net.generate: "
+        f"{mismatched[:5]}")
 
     # zero leaked blocks: idle pool holds exactly the trie's blocks;
     # clearing the trie frees EVERYTHING and every refcount is zero
@@ -164,7 +165,6 @@ def run_soak(n_requests: int = 160, seed: int = 0, vocab: int = 12,
 
     counts = eng.compile_counts()
     assert counts["decode"] == 1, counts
-    assert counts["admit"] == 0, counts
     assert counts["paged_scatter"] == 1, counts
     assert counts["paged_tok"] == 1, counts
     assert counts["chunk_prefill"] <= 2, counts
@@ -230,7 +230,7 @@ def main(argv=None) -> int:
     ap.add_argument("--kv-blocks", type=int, default=18)
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel shards (ISSUE 12): the "
-                         "paged engine runs sharded over attention "
+                         "engine runs sharded over attention "
                          "heads; parity/leak gates gain per-shard "
                          "checks")
     ap.add_argument("--use-flash-paged", default="auto",

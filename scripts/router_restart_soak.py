@@ -75,10 +75,9 @@ from scripts.router_soak import (  # noqa: E402
     _throttle,
 )
 
-#: paged twin of the router_soak engine config (full mode): the same
-#: net and geometry, block-pooled so replicas are KV-transfer capable
-PAGED_ENGINE = dict(ENGINE, paged_kv=True, block_tokens=4,
-                    kv_blocks=96)
+#: the router_soak engine config with small blocks and a stated pool
+#: (full mode): the same net and geometry
+PAGED_ENGINE = dict(ENGINE, block_tokens=4, kv_blocks=96)
 
 
 # ---------------------------------------------------------------------------

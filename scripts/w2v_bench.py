@@ -1,6 +1,6 @@
 """Word2Vec end-to-end words/sec on the real TPU chip: HS and NS rows.
 
-Protocol identical to the round-2 BENCHMARKS.md measurement (zipf 1M
+Protocol identical to the round-2 BENCHMARKS.md measurement (an earlier round's file) (zipf 1M
 words, vocab 10k, d=128, window 5, single chip, warm) so rounds stay
 comparable; adds the negative-sampling row the review flagged as
 unmeasured, and a host-tokenization timing isolating the native

@@ -34,7 +34,7 @@ def pytest_configure(config):
 def _compile_counts_of(target):
     """Executable counts for a no-retrace target: a jitted callable
     (``jax.jit`` cache size), anything exposing ``compile_counts()``
-    (DecodeEngine, RadixPrefixCache), or a zero-arg callable returning
+    (DecodeEngine, BlockPool), or a zero-arg callable returning
     a counts dict."""
     if hasattr(target, "compile_counts"):
         return dict(target.compile_counts())

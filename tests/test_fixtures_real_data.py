@@ -47,7 +47,8 @@ class TestFixtureLoaders:
 
 class TestRealDataTraining:
     def test_mlp_learns_real_digits(self):
-        """Held-out accuracy on REAL images — the gate bench.py uses."""
+        """Held-out accuracy on REAL images — the gate an earlier
+        round's bench.py used."""
         from deeplearning4j_tpu.models.zoo import mlp
         from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 

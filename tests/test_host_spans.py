@@ -82,8 +82,7 @@ def stub(monkeypatch):
 def _run_gateway(requests=5, **engine_kwargs):
     """A few streamed requests through a gateway; returns (results by
     id, the tracer's events, the stepper thread's tid)."""
-    kwargs = dict(n_slots=3, decode_chunk=3, seed=0, paged_kv=True,
-                  block_tokens=8)
+    kwargs = dict(n_slots=3, decode_chunk=3, seed=0, block_tokens=8)
     kwargs.update(engine_kwargs)
     engine = DecodeEngine(_net(), **kwargs)
     gw = ServingGateway(engine, keepalive_s=0.1)
@@ -178,7 +177,7 @@ class TestRoundSpans:
     def test_engine_round_has_every_leaf_under_one_parent(self):
         tracer = Tracer()
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=3, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            tracer=tracer)
         ids = [eng.submit(Request(p, n))
                for p, n in zip(PROMPTS[:3], LENS[:3])]
@@ -325,7 +324,7 @@ class TestRequestStamps:
     def test_greedy_ids_same_with_a_profile_being_taken(self, tmp_path):
         def ids():
             eng = DecodeEngine(_net(), n_slots=2, decode_chunk=3,
-                               seed=0, paged_kv=True, block_tokens=8,
+                               seed=0, block_tokens=8,
                                tracer=Tracer())
             rids = [eng.submit(Request(p, n))
                     for p, n in zip(PROMPTS, LENS)]

@@ -65,7 +65,7 @@ def prompts(lengths, seed=0):
 def engine(net, **kw):
     kw.setdefault("n_slots", 3)
     kw.setdefault("decode_chunk", 4)
-    return DecodeEngine(net, paged_kv=True, block_tokens=16,
+    return DecodeEngine(net, block_tokens=16,
                         kv_blocks=64, **kw)
 
 
@@ -348,7 +348,7 @@ def test_cli_serves_the_zoo_model_from_token_ids(tmp_path):
     write_model(tiny, path)
     args = build_parser().parse_args(
         ["serve", "--model", path, "--port", "0", "--slots", "2",
-         "--paged-kv", "--kv-blocks", "32", "--use-flash-paged", "off"])
+         "--kv-blocks", "32", "--use-flash-paged", "off"])
     gw = gateway_from_args(args).start()
     try:
         prompt = [3, 1, 4, 1, 5, 9, 2, 6]

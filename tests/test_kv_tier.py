@@ -44,7 +44,6 @@ def _net(seed=7, stream_max_t=64):
 
 
 def _engine(**kw):
-    kw.setdefault("paged_kv", True)
     kw.setdefault("block_tokens", 4)
     kw.setdefault("prefix_cache_rows", 4)
     kw.setdefault("kv_host_tier_bytes", 1 << 20)
@@ -286,7 +285,7 @@ class TestEngineSurface:
         # the export is read-only: the payload stays tier-resident
         assert len(donor.kv_tier) > 0
         recv = DecodeEngine(_net(), n_slots=2, decode_chunk=2,
-                            seed=0, paged_kv=True, block_tokens=4,
+                            seed=0, block_tokens=4,
                             prefix_cache_rows=4)
         out = recv.import_kv(payload)
         assert out["imported"], out
@@ -358,7 +357,7 @@ class TestGatewayTier:
 
     def test_healthz_tier_none_when_off(self):
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2,
-                           seed=0, paged_kv=True, block_tokens=4,
+                           seed=0, block_tokens=4,
                            prefix_cache_rows=4)
         gw = ServingGateway(eng).start()
         try:
@@ -407,7 +406,7 @@ class TestGatewayTier:
 
     def test_post_export_404_when_cold(self):
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2,
-                           seed=0, paged_kv=True, block_tokens=4,
+                           seed=0, block_tokens=4,
                            prefix_cache_rows=4)
         gw = ServingGateway(eng).start()
         try:

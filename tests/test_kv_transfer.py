@@ -53,7 +53,6 @@ def _net(seed=7, stream_max_t=64):
 
 
 def _engine(tp=1, **kw):
-    kw.setdefault("paged_kv", True)
     kw.setdefault("block_tokens", 8)
     kw.setdefault("prefix_cache_rows", 4)
     return DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
@@ -164,21 +163,19 @@ class TestEngineTransfer:
         for rid, (p, n) in zip(rids, CASES):
             assert res[rid].tokens == _reference(p, n)
 
-    def test_export_cold_and_dense_none(self):
+    def test_export_cold_and_no_trie_none(self):
         eng = _engine()
         assert eng.export_kv(PROMPT) is None  # nothing cached yet
-        dense = DecodeEngine(_net(), n_slots=2, decode_chunk=2,
-                             seed=0, prefix_cache_rows=4)
-        rid = dense.submit(Request(list(PROMPT), 4))
-        dense.run()
-        assert dense.export_kv(PROMPT) is None  # dense: no plane
+        bare = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0)
+        bare.submit(Request(list(PROMPT), 4))
+        bare.run()
+        assert bare.export_kv(PROMPT) is None  # no trie: no plane
 
-    def test_import_into_dense_raises(self):
+    def test_import_into_engine_without_trie_raises(self):
         payload = _export_payload()
-        dense = DecodeEngine(_net(), n_slots=2, decode_chunk=2,
-                             seed=0, prefix_cache_rows=4)
-        with pytest.raises(KVTransferError):
-            dense.import_kv(payload)
+        bare = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0)
+        with pytest.raises(KVTransferError, match="no prefix trie"):
+            bare.import_kv(payload)
 
     def test_already_warm_declines(self):
         payload = _export_payload()
@@ -207,7 +204,7 @@ class TestEngineTransfer:
     def test_geometry_mismatch_raises(self):
         payload = _export_payload()
         recv = DecodeEngine(_net(), n_slots=2, decode_chunk=2,
-                            seed=0, paged_kv=True, block_tokens=16,
+                            seed=0, block_tokens=16,
                             prefix_cache_rows=4)
         with pytest.raises(KVTransferError):
             recv.import_kv(payload)  # block_tokens 8 vs 16
@@ -268,7 +265,7 @@ class TestCrossWidthTransfer:
 class TestAsyncRounds:
     @pytest.mark.parametrize("kwargs", [
         dict(),
-        dict(paged_kv=True, block_tokens=8, prefix_cache_rows=4,
+        dict(block_tokens=8, prefix_cache_rows=4,
              prefill_chunk=4, spec_draft_len=3),
     ])
     def test_bit_parity_and_compile_counts(self, kwargs):
@@ -470,10 +467,9 @@ class TestGatewayEndpoints:
         finally:
             small.close()
 
-    def test_dense_gateway_404(self):
-        dense = DecodeEngine(_net(), n_slots=2, decode_chunk=2,
-                             seed=0, prefix_cache_rows=4)
-        gw = ServingGateway(dense).start()
+    def test_gateway_without_trie_404(self):
+        bare = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0)
+        gw = ServingGateway(bare).start()
         try:
             client = GatewayClient(gw.address)
             client.generate(PROMPT, 4)
@@ -601,12 +597,11 @@ class TestRouterTransfer:
         finally:
             newcomer.close()
 
-    def test_dense_fleet_never_transfers(self):
-        dense = [ServingGateway(
-            DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                         prefix_cache_rows=4),
+    def test_fleet_without_tries_never_transfers(self):
+        bare = [ServingGateway(
+            DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0),
             replica_id=f"d{i}").start() for i in range(2)]
-        router = ServingRouter([g.address for g in dense],
+        router = ServingRouter([g.address for g in bare],
                                affinity_block_tokens=8,
                                health_interval_s=0.05).start()
         try:
@@ -623,7 +618,7 @@ class TestRouterTransfer:
             assert router.stats["kv_transfer_failures"] == 0
         finally:
             router.close()
-            for g in dense:
+            for g in bare:
                 g.close()
 
 
@@ -665,7 +660,7 @@ class TestCliKnobs:
 
         args = build_parser().parse_args(
             ["serve", "--model", "m.zip", "--role", "prefill",
-             "--async-rounds", "--paged-kv"])
+             "--async-rounds"])
         assert args.role == "prefill"
         assert args.async_rounds is True
         args = build_parser().parse_args(
@@ -674,6 +669,10 @@ class TestCliKnobs:
         with pytest.raises(SystemExit):
             build_parser().parse_args(
                 ["serve", "--model", "m.zip", "--role", "turbo"])
+        # the layout switch went from the CLI with the dense layout
+        with pytest.raises(SystemExit):
+            build_parser().parse_args(
+                ["serve", "--model", "m.zip", "--paged-kv"])
 
     def test_fleet_child_argv_carries_async_rounds(self):
         from deeplearning4j_tpu.cli.driver import (
@@ -682,11 +681,9 @@ class TestCliKnobs:
         )
 
         args = build_parser().parse_args(
-            ["fleet", "--model", "m.zip", "--paged-kv",
-             "--async-rounds"])
+            ["fleet", "--model", "m.zip", "--async-rounds"])
         argv = _serve_child_argv(args, 9999, "child-0")
         assert "--async-rounds" in argv
-        assert "--paged-kv" in argv
 
 
 # -- per-tenant gauge retirement (ISSUE 14 satellite) -----------------

@@ -146,7 +146,7 @@ class TestMixedPrecisionTbptt:
 
 class TestFitScan:
     """Scanned multi-step training (K steps = one XLA computation): the
-    dispatch-latency fast path bench.py uses."""
+    dispatch-latency fast path the training cell runs."""
 
     def test_trains_and_matches_sequential_shape(self):
         x, y = _data(n=128)
@@ -227,8 +227,8 @@ class TestFitScan:
 class TestF32OutputHead:
     """Under mixed precision the OUTPUT layer runs at the master dtype:
     a bf16 softmax quantizes probabilities coarsely enough to stall
-    training at a calibration plateau (measured on LeNet/MNIST —
-    BENCHMARKS.md mixed-precision note)."""
+    training at a calibration plateau (measured on LeNet/MNIST in
+    an earlier round)."""
 
     def test_mln_output_layer_runs_f32(self):
         import jax.numpy as jnp

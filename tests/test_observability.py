@@ -306,7 +306,7 @@ class TestEnginePhaseClock:
         for span in tracer.spans("serving.decode_chunk"):
             assert set(span["args"]["rids"]) <= set(ids)
         assert any(s["args"]["rid"] in ids
-                   for s in tracer.spans("serving.prefix_fetch"))
+                   for s in tracer.spans("serving.prefix_splice"))
         done = [e for e in tracer.events()
                 if e["name"] == "serving.request_done"]
         assert sorted(e["args"]["rid"] for e in done) == sorted(ids)

@@ -1,7 +1,7 @@
 """The paged-pool fragmentation soak (scripts/paged_soak.py)
 registered as tests: the fast variants ride tier-1, the full churns
 are ``slow``. The soak itself asserts the ISSUE 6 gates (bit-parity
-vs the dense engine under sharing/CoW/preemption, zero leaked blocks
+vs ``net.generate`` under sharing/CoW/preemption, zero leaked blocks
 — pool fully free once idle and the trie cleared, bounded compile
 counts) and, with ``tp > 1`` (ISSUE 12), the per-shard gates: the
 head-sliced pool shards stay byte-symmetric and the host leak audit
@@ -35,7 +35,7 @@ def test_paged_soak_tier_fast():
     """ISSUE 17 satellite: the same pressure churn with the host-DRAM
     spill tier armed — trie victims spill instead of dropping, cohort
     re-hits reload through the jitted import, and the soak's tier
-    gates assert bit-parity with the dense engine (spill/reload
+    gates assert bit-parity with ``net.generate`` (spill/reload
     invisible in ids), the budget held at every sampled peak, both
     churn directions exercised, and the conservation invariant
     spills == reloads + drops + resident."""

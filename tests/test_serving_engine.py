@@ -166,7 +166,8 @@ class TestCompileCounts:
     def test_no_retrace_after_warmup_across_admissions(
             self, assert_no_retrace):
         """The tentpole's compile guarantee: one decode executable,
-        one admit executable, one prefill executable per prompt-length
+        one scatter of an admitted row and one put of its first token,
+        one prefill executable per prompt-length
         bucket — further admissions (any slot, any order, any length
         in a seen bucket, any sampling config) never retrace."""
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0)
@@ -176,7 +177,9 @@ class TestCompileCounts:
         eng.run()
         warm = eng.compile_counts()
         assert warm["decode"] == 1
-        assert warm["admit"] == 1
+        assert warm["paged_scatter"] == 1
+        assert warm["paged_tok"] == 1
+        assert "admit" not in warm
         assert warm["prefill"] == 2
         # same buckets, new lengths/slots/configs: no new executables
         with assert_no_retrace(eng):

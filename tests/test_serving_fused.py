@@ -36,18 +36,16 @@ PROMPT = SHARED + [1, 6, 2, 0]
 CASES = [(SHARED + [1, 6], 8), (SHARED + [2, 0], 5),
          ([9, 3, 3], 11), (SHARED + [4, 8], 7), ([2, 2], 9)]
 
-#: the matrix dimensions (paged x spec x tp x async); each config is
+#: the matrix dimensions (block size x spec x tp x async); each config is
 #: ONE stepped reference engine + ONE fused engine, module-cached —
 #: the K sweep reuses the fused engine by lowering ``fused_rounds``
 #: (a host-side knob: ring and executables were sized for the max)
 CONFIGS = {
-    "dense": dict(),
-    "paged_spec": dict(paged_kv=True, block_tokens=8,
-                       prefix_cache_rows=4, prefill_chunk=4,
-                       spec_draft_len=3),
-    "paged_tp2": dict(paged_kv=True, block_tokens=8, tp=2),
-    "paged_async": dict(paged_kv=True, block_tokens=8,
-                        async_rounds=True),
+    "plain": dict(),
+    "paged_spec": dict(block_tokens=8, prefix_cache_rows=4,
+                       prefill_chunk=4, spec_draft_len=3),
+    "paged_tp2": dict(block_tokens=8, tp=2),
+    "paged_async": dict(block_tokens=8, async_rounds=True),
 }
 
 _STEPPED = {}
@@ -99,7 +97,7 @@ class TestFusedParity:
         assert eng.compile_counts()["fused_decode"] <= 4
 
     def test_fused_path_actually_dispatches(self):
-        eng = _fused_engine("dense")
+        eng = _fused_engine("plain")
         eng.fused_rounds = 8
         for p, n in CASES:
             eng.submit(Request(list(p), n))
@@ -109,7 +107,7 @@ class TestFusedParity:
         assert eng.histograms["serving_host_step_s"].count > 0
 
     def test_zero_retrace_on_repeat_traffic(self):
-        eng = _fused_engine("dense")
+        eng = _fused_engine("plain")
         eng.fused_rounds = 8
         for p, n in CASES:
             eng.submit(Request(list(p), n))
@@ -137,7 +135,7 @@ class TestFusedParity:
         # must truncate at the eos token exactly like stepped mode
         stepped = DecodeEngine(_net(), n_slots=2, decode_chunk=2,
                                seed=0)
-        fused = _fused_engine("dense")
+        fused = _fused_engine("plain")
         fused.fused_rounds = 8
         kw = dict(max_new_tokens=16, eos_id=3)
         i_s = stepped.submit(Request(list(CASES[2][0]), **kw))
@@ -175,14 +173,14 @@ class TestFusedFallback:
         # a live deadline forbids fusing (expiry must be able to land
         # between ROUNDS) — and once the timed request drains, fusing
         # resumes: one deadline must not disable the fast path forever
-        eng = _fused_engine("dense")
+        eng = _fused_engine("plain")
         eng.fused_rounds = 8
         before = eng.histograms["serving_fused_rounds"].count
         rid = eng.submit(Request(list(CASES[0][0]), CASES[0][1],
                                  deadline_s=600.0))
         res = eng.run()
         assert (res[rid].tokens, res[rid].finish_reason) \
-            == _stepped_results("dense")[0]
+            == _stepped_results("plain")[0]
         assert eng.histograms["serving_fused_rounds"].count == before
         rid2 = eng.submit(Request(list(CASES[0][0]), CASES[0][1]))
         eng.run()
@@ -229,8 +227,7 @@ class TestCliKnob:
         )
 
         args = build_parser().parse_args(
-            ["fleet", "--model", "m.zip", "--paged-kv",
-             "--fused-rounds", "4"])
+            ["fleet", "--model", "m.zip", "--fused-rounds", "4"])
         argv = _serve_child_argv(args, 9999, "child-0")
         i = argv.index("--fused-rounds")
         assert argv[i + 1] == "4"

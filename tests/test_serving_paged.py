@@ -1,14 +1,13 @@
 """Paged KV memory: one block-pool cache shared by decode slots and
 the prefix trie (ISSUE 6 tentpole).
 
-The contract under test: ``DecodeEngine(paged_kv=True)`` swaps the
-dense per-slot KV rows + dense prefix-row pool for ONE block-granular
-device pool (fixed-size token blocks, per-slot block tables, zero-copy
-prefix splices with refcounts, copy-on-write on divergence) — and
-every greedy request's ids stay BIT-IDENTICAL to the dense engine (and
-therefore to sequential B=1 ``generate()``) across all four admission
+The contract under test: ``DecodeEngine`` keeps keys and values in
+ONE block-granular device pool (fixed-size token blocks, per-slot
+block tables, zero-copy prefix splices with refcounts, copy-on-write
+on divergence) — and every greedy request's ids stay BIT-IDENTICAL to
+sequential B=1 ``generate()`` across all four admission
 modes x prefix cache on/off x speculation on/off, with compile counts
-bounded at one paged decode executable plus one paged verify per pow2
+bounded at one decode executable plus one verify per pow2
 draft bucket."""
 
 import json
@@ -25,7 +24,7 @@ from deeplearning4j_tpu.serving import (
     DecodeEngine,
     FaultEvent,
     FaultPlan,
-    PagedPrefixCache,
+    RadixPrefixCache,
     Request,
 )
 
@@ -68,8 +67,9 @@ CASES = [(SHARED + [1, 6], 8), (SHARED + [2, 0], 5),
 
 
 class TestPagedParityMatrix:
-    """ISSUE 6 acceptance gate: greedy id bit-parity paged vs dense
-    across all 4 admission modes x prefix on/off x spec on/off."""
+    """ISSUE 6 acceptance gate: greedy id bit-parity with sequential
+    generate across all 4 admission modes x prefix on/off x spec
+    on/off."""
 
     @pytest.mark.parametrize("prefill_chunk,policy", [
         (0, "ttft"), (0, "decode"), (4, "ttft"), (4, "decode")])
@@ -78,7 +78,7 @@ class TestPagedParityMatrix:
     def test_greedy_bit_parity(self, prefill_chunk, policy,
                                prefix_rows, spec):
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=prefix_rows,
                            prefill_chunk=prefill_chunk,
                            admission_policy=policy,
@@ -92,21 +92,21 @@ class TestPagedParityMatrix:
                 f"prefix={prefix_rows} spec={spec}")
         counts = eng.compile_counts()
         assert counts["decode"] == 1, counts
-        assert counts["admit"] == 0          # dense admit never runs
+        assert "admit" not in counts
         assert counts["paged_scatter"] == 1
         assert counts["paged_tok"] == 1
         if spec:
             # one verify executable per pow2 draft-width bucket
             assert 1 <= counts["verify"] <= spec.bit_length() + 1
         if prefix_rows:
-            # the paged trie owns NO jitted movers: a warm hit is a
+            # the trie owns NO jitted movers: a warm hit is a
             # host-side block-table splice
             assert "prefix_fetch" not in counts
             assert "prefix_store" not in counts
 
     def test_no_retrace_once_warm(self, assert_no_retrace):
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=3,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4, prefill_chunk=4,
                            spec_draft_len=3)
         ids = [eng.submit(Request(p, n)) for p, n in CASES]
@@ -147,7 +147,7 @@ class TestPagedParityMatrix:
         solo.rnn_clear_previous_state()
         want = np.asarray(solo.generate(_one_hot_seq(prompt), n))
         eng = DecodeEngine(gnet(), n_slots=2, decode_chunk=4,
-                           paged_kv=True, block_tokens=4)
+                           block_tokens=4)
         rid = eng.submit(Request(prompt, n))
         assert eng.run()[rid].tokens == want[0].tolist()
 
@@ -157,8 +157,7 @@ class TestPagedParityMatrix:
         prompt = [1, 4, 7, 2, 5, 9, 3, 3, 8, 6, 0, 2] * 2  # 24 tokens
         n = 24                            # 48 total > window 32
         eng = DecodeEngine(_net(stream_max_t=32), n_slots=2,
-                           decode_chunk=3, seed=0, paged_kv=True,
-                           block_tokens=4)
+                           decode_chunk=3, seed=0, block_tokens=4)
         rid = eng.submit(Request(prompt, n))
         res = eng.run()
         assert res[rid].tokens == _solo_generate(prompt, n,
@@ -175,7 +174,7 @@ class TestZeroCopySharing:
         splice counters move, no prefix_fetch executable exists, and
         the only device copy is the CoW of the boundary block."""
         eng = DecodeEngine(_net(), n_slots=1, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4)
         r1 = eng.submit(Request(SHARED + [1, 6], 6))
         eng.run()
@@ -197,7 +196,7 @@ class TestZeroCopySharing:
         ZERO device work: appends start a fresh block."""
         prompt_a = SHARED[:]              # 8 tokens == 1 full block
         eng = DecodeEngine(_net(), n_slots=1, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4)
         eng.submit(Request(prompt_a + [5, 2], 4))
         eng.run()
@@ -218,7 +217,7 @@ class TestZeroCopySharing:
         and a third request re-hitting the prefix still gets exact
         ids — the entry's block was never mutated."""
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4)
         tails = ([1, 6], [2, 0], [4, 8])
         ids = [eng.submit(Request(SHARED + t, 7)) for t in tails]
@@ -228,7 +227,7 @@ class TestZeroCopySharing:
 
     def test_pool_fully_free_when_idle_without_cache(self):
         eng = DecodeEngine(_net(), n_slots=3, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8)
+                           block_tokens=8)
         for p, n in CASES:
             eng.submit(Request(p, n))
         eng.run()
@@ -237,7 +236,7 @@ class TestZeroCopySharing:
 
     def test_idle_pool_holds_only_trie_blocks(self):
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4)
         for p, n in CASES:
             eng.submit(Request(p, n))
@@ -258,7 +257,7 @@ class TestOversubscription:
         kv_blocks = n_dense * (window // bt)       # equal device bytes
         n_paged = 5
         eng = DecodeEngine(_net(), n_slots=n_paged, decode_chunk=2,
-                           seed=0, paged_kv=True, block_tokens=bt,
+                           seed=0, block_tokens=bt,
                            kv_blocks=kv_blocks)
         cases = [([1 + i, 4, 7 + (i % 3), 2], 6) for i in range(n_paged)]
         ids = [eng.submit(Request(p, n)) for p, n in cases]
@@ -276,8 +275,7 @@ class TestOversubscription:
         preemption, invisible in results)."""
         window, bt = 32, 4
         eng = DecodeEngine(_net(stream_max_t=window), n_slots=4,
-                           decode_chunk=2, seed=0, paged_kv=True,
-                           block_tokens=bt, kv_blocks=26)
+                           decode_chunk=2, seed=0, block_tokens=bt, kv_blocks=26)
         cases = [([1, 4, 7, 2, 5, 9, 3, 3, 8, 6][: 6 + (i % 4)], 18)
                  for i in range(6)]
         ids = [eng.submit(Request(p, n)) for p, n in cases]
@@ -297,7 +295,7 @@ class TestPagedQuarantine:
         finish bit-identical (the shared block is released by
         reference, never zeroed under it) while the victim retries."""
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4, paranoid=True,
                            fault_plan=FaultPlan(
                                [FaultEvent(4, "nan", slot=0)]),
@@ -325,7 +323,7 @@ class TestPagedQuarantine:
         SHARED pool; the per-block sweep invalidates the entry and the
         workload still finishes exact."""
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4, paranoid=True,
                            fault_plan=FaultPlan(
                                [FaultEvent(3, "cache_corrupt")]),
@@ -338,11 +336,11 @@ class TestPagedQuarantine:
         assert eng.prefix_cache.stats["invalidations"] >= 1
         assert eng.block_pool.poisoned == set()
 
-    def test_undetected_without_paranoid_like_dense(self):
-        """Paged mode keeps the dense contract: no paranoid sweep, no
-        detection — the knob, not the layout, buys the checks."""
+    def test_undetected_without_paranoid(self):
+        """No paranoid sweep, no detection: the knob buys the
+        checks."""
         eng = DecodeEngine(_net(), n_slots=1, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            fault_plan=FaultPlan(
                                [FaultEvent(1, "nan", slot=0)]))
         rid = eng.submit(Request([1, 4, 7, 2], 8))
@@ -353,13 +351,13 @@ class TestPagedQuarantine:
     def test_recycled_dirty_block_cannot_corrupt_next_owner(self):
         """Review regression: with paranoid OFF, eviction releases a
         NaN-poisoned victim's blocks UNSCRUBBED (nothing marked them
-        poisoned). The dense engine zeroes rows on evict; the paged
-        engine instead value-masks every lane outside a row's written
+        poisoned). The
+        engine value-masks every lane outside a row's written
         span — so a later request reallocating the dirty block must
         still produce exact ids (0 x NaN = NaN would otherwise leak
         through its unwritten tail)."""
         eng = DecodeEngine(_net(), n_slots=1, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            fault_plan=FaultPlan(
                                [FaultEvent(1, "nan", slot=0)]))
         victim = eng.submit(Request([1, 4, 7, 2], 8))
@@ -379,7 +377,7 @@ class TestPagedSnapshotRestore:
         records the paged bookkeeping (tables + refcounts) alongside
         the recorded tokens that rebuild them."""
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4, prefill_chunk=4)
         ids = [eng.submit(Request(p, n)) for p, n in CASES]
         res = {}
@@ -397,26 +395,33 @@ class TestPagedSnapshotRestore:
             assert tab["blocks"]
         assert paged["refcounts"]
         eng2 = DecodeEngine.restore(_net(), snap)
-        assert eng2.paged_kv and eng2.kv_blocks == eng.kv_blocks
+        assert eng2.kv_blocks == eng.kv_blocks
         res.update(eng2.run())
         for rid, (p, n) in zip(ids, CASES):
             assert res[rid].tokens == _solo_generate(p, n), (
                 f"restored paged engine diverged on request {rid}")
 
-    def test_dense_snapshot_restores_dense(self):
-        eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2)
-        snap = eng.snapshot()
-        assert snap["config"]["paged_kv"] is False
-        assert snap["paged"] is None
-        eng2 = DecodeEngine.restore(_net(), snap)
-        assert not eng2.paged_kv
+    @pytest.mark.parametrize("says", [False, None])
+    def test_dense_snapshot_is_refused_by_name(self, says):
+        """A snapshot an engine with the dense layout took (its config
+        says ``paged_kv: false``, or, older, says nothing) names what
+        became of that layout instead of restoring into another."""
+        snap = DecodeEngine(_net(), n_slots=2, decode_chunk=2).snapshot()
+        assert snap["config"]["paged_kv"] is True
+        assert snap["paged"] is not None
+        if says is None:
+            del snap["config"]["paged_kv"]
+        else:
+            snap["config"]["paged_kv"] = says
+        with pytest.raises(ValueError, match="removed in PR 29"):
+            DecodeEngine.restore(_net(), snap)
 
 
 class TestPagedObservability:
     def test_engine_stats_and_tracer_gauges(self):
         tracer = Tracer()
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4, tracer=tracer)
         for p, n in CASES:
             eng.submit(Request(p, n))
@@ -442,7 +447,7 @@ class TestPagedObservability:
         )
 
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4)
         gw = ServingGateway(eng).start()
         try:
@@ -461,7 +466,7 @@ class TestPagedObservability:
         16 allocated tokens, 7 of them pad — the frag gauge must see
         exactly the allocated-but-masked tail."""
         eng = DecodeEngine(_net(), n_slots=1, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8)
+                           block_tokens=8)
         rid = eng.submit(Request([1, 4, 7, 2, 5, 9, 3], 40))
         res = {}
         eng.step(res)                  # admission + one decode chunk
@@ -481,14 +486,15 @@ class TestPagedUnits:
         with pytest.raises(ValueError, match="kv_blocks"):
             BlockPool(0, 8)
         with pytest.raises(ValueError, match="power of two"):
-            DecodeEngine(_net(), n_slots=1, paged_kv=True,
-                         block_tokens=12)
+            DecodeEngine(_net(), n_slots=1, block_tokens=12)
         with pytest.raises(ValueError, match="kv_blocks"):
-            DecodeEngine(_net(), n_slots=1, paged_kv=True,
-                         block_tokens=8, kv_blocks=2)
+            DecodeEngine(_net(), n_slots=1, block_tokens=8, kv_blocks=2)
         with pytest.raises(ValueError, match="block_tokens"):
             DecodeEngine(_net(stream_max_t=16), n_slots=1,
-                         paged_kv=True, block_tokens=32)
+                         block_tokens=32)
+        with pytest.raises(ValueError, match="removed in PR 29"):
+            DecodeEngine(_net(), n_slots=1, paged_kv=False)
+        assert DecodeEngine(_net(), n_slots=1).block_pool is not None
 
     def test_block_pool_refcounts_and_scrub_marking(self):
         pool = BlockPool(4, 8)
@@ -514,27 +520,9 @@ class TestPagedUnits:
         assert tab.new_logical_blocks(4) == []      # fits in tail
         assert tab.new_logical_blocks(5) == [2]
 
-    def test_drop_newest_tokens_paged_masks_tail(self):
-        import jax.numpy as jnp
-
-        from deeplearning4j_tpu.nn.streaming import drop_newest_tokens
-
-        st = {"attn": {"pk": jnp.ones((2, 4, 1, 2)),
-                       "pv": jnp.ones((2, 4, 1, 2)),
-                       "table": jnp.zeros((1, 3), jnp.int32),
-                       "base": jnp.zeros((1, 3), jnp.int32),
-                       "floor": jnp.zeros((1,), jnp.int32),
-                       "filled": jnp.asarray([7], jnp.int32)}}
-        out = drop_newest_tokens(st, jnp.asarray([3], jnp.int32))
-        assert int(out["attn"]["filled"][0]) == 4
-        # pool bytes untouched: the rewind is pop-blocks + mask-tail
-        assert bool(jnp.all(out["attn"]["pk"] == 1))
-
-    def test_paged_trie_rejects_dense_api(self):
+    def test_trie_entry_references_pool_blocks(self):
         pool = BlockPool(8, 8)
-        trie = PagedPrefixCache(4, 8, pool.ref, lambda b: None)
-        with pytest.raises(NotImplementedError):
-            trie.insert([1, 2, 3], None)
+        trie = RadixPrefixCache(4, 8, pool.ref, pool.deref)
         tab = BlockTable(8)
         tab.blocks = {0: pool.alloc()}
         tab.length = 3
@@ -542,13 +530,14 @@ class TestPagedUnits:
         assert pool.refcount(tab.blocks[0]) == 2
         hit = trie.lookup([1, 2, 3, 4])
         assert hit is not None and hit.matched == 3
-        with pytest.raises(NotImplementedError):
-            trie.fetch(hit)
+        assert trie.payload(hit.row).blocks == tab.blocks
         trie.release(hit)
+        assert trie.invalidate([1, 2, 3])
+        assert pool.refcount(tab.blocks[0]) == 1
 
     def test_deltas_concat_equals_terminal_paged(self):
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            emit_deltas=True)
         ids = [eng.submit(Request(p, n)) for p, n in CASES[:3]]
         streamed = {r: [] for r in ids}
@@ -588,8 +577,7 @@ class TestSharedTables:
 
         tracer = Tracer()
         eng = DecodeEngine(_net(n_layers=n_layers), n_slots=2,
-                           decode_chunk=2, seed=0, paged_kv=True,
-                           block_tokens=8, tracer=tracer)
+                           decode_chunk=2, seed=0, block_tokens=8, tracer=tracer)
         for p, n in CASES:
             eng.submit(Request(p, n))
         eng.run()
@@ -620,7 +608,7 @@ class TestSharedTables:
         tables the verify program hands back, and the round still
         makes one upload."""
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            spec_draft_len=3)
         uploaded = []
         upload = eng._paged_tables
@@ -652,7 +640,7 @@ class TestSharedTables:
 
     def test_warm_chunked_admission_through_the_shared_operand(self):
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefix_cache_rows=4, prefill_chunk=4)
         warm = _count_paged_calls(eng, "_chunk_jit", pos=5)
         ids = [eng.submit(Request(p, n)) for p, n in CASES]

@@ -10,12 +10,12 @@ round budget."""
 import numpy as np
 import pytest
 
-import jax.numpy as jnp
-
 from deeplearning4j_tpu.models.zoo import transformer_lm
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.profiler.tracer import Tracer
 from deeplearning4j_tpu.serving import (
+    BlockPool,
+    BlockTable,
     DecodeEngine,
     RadixPrefixCache,
     Request,
@@ -48,57 +48,76 @@ def _solo_generate(prompt, n, seed=7, stream_max_t=64):
     return np.asarray(net.generate(_one_hot_seq(prompt), n))[0].tolist()
 
 
-def _fake_state(fill, tokens_axis=8):
-    """A B=1 attention-cache pytree shaped like real engine state."""
-    k = jnp.arange(1 * 2 * tokens_axis * 4, dtype=jnp.float32).reshape(
-        1, 2, tokens_axis, 4) + fill
-    return {"0": {"k": k, "v": k + 0.5,
-                  "filled": jnp.asarray([fill], jnp.int32)}}
+BT = 4  # block size of the trie unit tests
+
+
+def _trie(rows, kv_blocks=64):
+    """A trie over a real block pool, wired as the engine wires it."""
+    pool = BlockPool(kv_blocks, BT)
+    cache = RadixPrefixCache(rows, BT, ref_block=pool.ref,
+                             release_block=pool.deref)
+    return cache, pool
+
+
+def _put(cache, pool, prompt):
+    """Insert ``prompt`` the way a finished admission does: the slot's
+    freshly allocated blocks are leased to the trie, then the slot
+    lets go of its own references."""
+    tab = BlockTable(BT, length=len(prompt))
+    for g in range(-(-len(prompt) // BT)):
+        tab.blocks[g] = pool.alloc()
+    ok = cache.insert_blocks(prompt, tab)
+    for bid in tab.blocks.values():
+        pool.deref(bid)
+    return ok
 
 
 class TestRadixTrie:
     def test_miss_then_hit_after_insert(self):
-        cache = RadixPrefixCache(rows=2)
+        cache, pool = _trie(rows=2)
         assert cache.lookup([1, 2, 3, 4]) is None
-        assert cache.insert([1, 2, 3, 4], _fake_state(4))
+        assert _put(cache, pool, [1, 2, 3, 4])
         hit = cache.lookup([1, 2, 3, 4, 5, 6])
         assert hit is not None
-        assert (hit.matched, hit.drop) == (4, 0)
+        assert hit.matched == 4
+        assert cache.payload(hit.row).length == 4
         cache.release(hit)
 
     def test_exact_match_rewinds_one_token(self):
         """A full-prefix hit never consumes the whole prompt: the last
         token re-streams to produce first-token logits (zero-length
         suffixes cannot exist by construction)."""
-        cache = RadixPrefixCache(rows=2)
-        cache.insert([1, 2, 3, 4], _fake_state(4))
+        cache, pool = _trie(rows=2)
+        _put(cache, pool, [1, 2, 3, 4])
         hit = cache.lookup([1, 2, 3, 4])
-        assert (hit.matched, hit.drop) == (3, 1)
+        assert hit.matched == 3
+        assert cache.payload(hit.row).length == 4   # the entry, whole
         cache.release(hit)
 
     def test_divergent_tail_is_rewound(self):
         """RadixAttention-style sharing: a prompt diverging m tokens
         into a cached entry reuses those m tokens via rewind — stored
         prompts need not be prefixes of the query."""
-        cache = RadixPrefixCache(rows=2)
-        cache.insert(SHARED + [0, 0], _fake_state(10))
+        cache, pool = _trie(rows=2)
+        _put(cache, pool, SHARED + [0, 0])
         hit = cache.lookup(SHARED + [3])
-        assert (hit.matched, hit.drop) == (len(SHARED), 2)
+        assert hit.matched == len(SHARED)
+        assert cache.payload(hit.row).length == len(SHARED) + 2
         cache.release(hit)
         # query that is a proper prefix of the stored prompt
         hit = cache.lookup(SHARED)
-        assert (hit.matched, hit.drop) == (len(SHARED) - 1, 3)
+        assert hit.matched == len(SHARED) - 1
         cache.release(hit)
 
     def test_one_token_prompt_never_hits(self):
-        cache = RadixPrefixCache(rows=2)
-        cache.insert([5], _fake_state(1))
+        cache, pool = _trie(rows=2)
+        _put(cache, pool, [5])
         assert cache.lookup([5]) is None
 
     def test_edge_split_preserves_both_prompts(self):
-        cache = RadixPrefixCache(rows=4)
-        cache.insert(SHARED + [0], _fake_state(9))
-        cache.insert(SHARED + [1], _fake_state(9))
+        cache, pool = _trie(rows=4)
+        _put(cache, pool, SHARED + [0])
+        _put(cache, pool, SHARED + [1])
         assert cache.cached_prefixes() == sorted(
             [tuple(SHARED + [0]), tuple(SHARED + [1])])
         for tail, m in [([0], 9), ([1], 9), ([2], 8)]:
@@ -107,19 +126,20 @@ class TestRadixTrie:
             cache.release(hit)
 
     def test_duplicate_insert_refreshes_not_duplicates(self):
-        cache = RadixPrefixCache(rows=2)
-        assert cache.insert([1, 2, 3], _fake_state(3))
-        assert not cache.insert([1, 2, 3], _fake_state(3))
+        cache, pool = _trie(rows=2)
+        assert _put(cache, pool, [1, 2, 3])
+        assert not _put(cache, pool, [1, 2, 3])
         assert cache.stats["inserts"] == 1
         assert len(cache.cached_prefixes()) == 1
+        assert pool.used_blocks == 1    # the duplicate leased nothing
 
     def test_lru_eviction_order(self):
-        cache = RadixPrefixCache(rows=2)
-        cache.insert([1, 1, 1], _fake_state(3))
-        cache.insert([2, 2, 2], _fake_state(3))
+        cache, pool = _trie(rows=2)
+        _put(cache, pool, [1, 1, 1])
+        _put(cache, pool, [2, 2, 2])
         hit = cache.lookup([1, 1, 1, 9])   # refreshes [1,1,1]
         cache.release(hit)
-        cache.insert([3, 3, 3], _fake_state(3))  # evicts LRU [2,2,2]
+        _put(cache, pool, [3, 3, 3])       # evicts LRU [2,2,2]
         assert cache.stats["evictions"] == 1
         assert tuple([2, 2, 2]) not in cache.cached_prefixes()
         assert tuple([1, 1, 1]) in cache.cached_prefixes()
@@ -127,17 +147,18 @@ class TestRadixTrie:
     def test_leased_row_survives_eviction_pressure(self):
         """Satellite edge case: evicting a ref-counted prefix while a
         slot still reads it must be refused — the insert declines
-        instead when no unleased row exists."""
-        cache = RadixPrefixCache(rows=1)
-        cache.insert([1, 2, 3], _fake_state(3))
-        hit = cache.lookup([1, 2, 3, 4])   # lease row 0
+        instead when no unleased entry exists."""
+        cache, pool = _trie(rows=1)
+        _put(cache, pool, [1, 2, 3])
+        hit = cache.lookup([1, 2, 3, 4])   # lease entry 0
         assert hit is not None
-        assert not cache.insert([7, 8, 9], _fake_state(3))
+        assert not _put(cache, pool, [7, 8, 9])
+        assert not cache.evict_one()       # nor for block pressure
         assert cache.stats["declined"] == 1
         assert cache.stats["evictions"] == 0
         assert tuple([1, 2, 3]) in cache.cached_prefixes()
         cache.release(hit)                 # lease dropped: evictable
-        assert cache.insert([7, 8, 9], _fake_state(3))
+        assert _put(cache, pool, [7, 8, 9])
         assert cache.stats["evictions"] == 1
 
     def test_insert_survives_eviction_pruning_walk_path(self):
@@ -146,14 +167,14 @@ class TestRadixTrie:
         re-walk the live trie or the new entry lands detached
         (unreachable, and a later eviction KeyErrors in the prune
         loop). Multi-turn prompts each extending the last hit exactly
-        this on a 1-row cache."""
-        cache = RadixPrefixCache(rows=1)
+        this on a 1-entry cache."""
+        cache, pool = _trie(rows=1)
         turns = [SHARED, SHARED + [0, 1], SHARED + [0, 1, 2, 3]]
         for i, t in enumerate(turns):
             hit = cache.lookup(t)
             if hit is not None:
                 cache.release(hit)
-            assert cache.insert(t, _fake_state(len(t)))
+            assert _put(cache, pool, t)
             assert cache.cached_prefixes() == [tuple(t)], (
                 f"turn {i}: entry detached from the trie")
 
@@ -170,40 +191,40 @@ class TestRadixTrie:
             assert res[rid].tokens == _solo_generate(t, 4)
         assert eng.prefix_cache.stats["hits"] >= 2
 
-    def test_fetch_rewind_matches_shorter_prefill(self):
-        """drop_newest_tokens ground truth: fetching with drop=d must
-        equal the state of the d-tokens-shorter prefill (valid region
-        and filled; the masked left region is don't-care)."""
-        net = _net()
-        net.rnn_clear_previous_state()
-        net.rnn_time_step(jnp.asarray(_one_hot_seq(SHARED)))
-        full = net._rnn_state
-        net.rnn_clear_previous_state()
-        net.rnn_time_step(jnp.asarray(_one_hot_seq(SHARED[:-2])))
-        short = net._rnn_state
-
-        cache = RadixPrefixCache(rows=1)
-        cache.insert(SHARED, full)
-        hit = cache.lookup(SHARED[:-2] + [11])  # matched 6, drop 2
-        assert (hit.matched, hit.drop) == (6, 2)
-        got = cache.fetch(hit)
-        for name, st in short.items():
-            n_valid = int(np.asarray(st["filled"])[0])
-            assert int(np.asarray(got[name]["filled"])[0]) == n_valid
-            np.testing.assert_allclose(
-                np.asarray(got[name]["k"])[:, :, -n_valid:, :],
-                np.asarray(st["k"])[:, :, -n_valid:, :], rtol=1e-6)
-            np.testing.assert_allclose(
-                np.asarray(got[name]["v"])[:, :, -n_valid:, :],
-                np.asarray(st["v"])[:, :, -n_valid:, :], rtol=1e-6)
+    def test_entries_lease_blocks_and_eviction_returns_them(self):
+        """An entry is a lease on pool blocks, never a copy: insert
+        takes one reference a block, a block the admitted slot still
+        holds survives the entry's eviction, and an evicted entry's
+        exclusively held blocks go back to the free list."""
+        cache, pool = _trie(rows=2, kv_blocks=8)
+        tab = BlockTable(BT, length=6)
+        tab.blocks = {0: pool.alloc(), 1: pool.alloc()}
+        assert cache.insert_blocks([1, 2, 3, 4, 5, 6], tab)
+        assert [pool.refcount(b) for b in tab.blocks.values()] == [2, 2]
+        assert sorted(cache.block_ids()) == sorted(tab.blocks.values())
+        # the payload is a frozen copy: the slot's table moving on
+        # does not move the entry
+        tab.length += 3
+        tab.blocks[2] = pool.alloc()
+        hit = cache.lookup([1, 2, 3, 4, 5, 6, 7])
+        assert cache.payload(hit.row).length == 6
+        assert sorted(cache.payload(hit.row).blocks) == [0, 1]
         cache.release(hit)
+        assert cache.evict_one()
+        assert cache.cached_prefixes() == []
+        assert [pool.refcount(b) for b in tab.blocks.values()] == [1, 1, 1]
+        assert pool.used_blocks == 3       # the slot's, still resident
+        _put(cache, pool, [9, 9, 9, 9, 9])
+        assert pool.used_blocks == 5
+        assert cache.clear() == 1
+        assert pool.used_blocks == 3
 
     def test_invalidate_scrubs_entry(self):
         """Fault quarantine: invalidate drops exactly the named entry
-        (exact prompt or row) and frees its row for reuse."""
-        cache = RadixPrefixCache(rows=2)
-        cache.insert([1, 2, 3], _fake_state(3))
-        cache.insert([1, 2, 3, 4, 5], _fake_state(5))
+        (exact prompt or id) and frees its id and blocks for reuse."""
+        cache, pool = _trie(rows=2)
+        _put(cache, pool, [1, 2, 3])
+        _put(cache, pool, [1, 2, 3, 4, 5])
         assert cache.invalidate([1, 2, 3])
         assert not cache.invalidate([1, 2, 3])   # already gone
         assert cache.cached_prefixes() == [(1, 2, 3, 4, 5)]
@@ -212,29 +233,30 @@ class TestRadixTrie:
         assert cache.row_prefix(row) == (1, 2, 3, 4, 5)
         assert cache.invalidate_row(row)
         assert cache.cached_prefixes() == []
-        # both rows free again: two fresh inserts succeed, no eviction
-        assert cache.insert([7, 7], _fake_state(2))
-        assert cache.insert([8, 8], _fake_state(2))
+        assert pool.used_blocks == 0
+        # both ids free again: two fresh inserts succeed, no eviction
+        assert _put(cache, pool, [7, 7])
+        assert _put(cache, pool, [8, 8])
         assert cache.stats["evictions"] == 0
 
     def test_invalidate_leased_row_defers_free(self):
-        """Invalidating a row another in-flight admission still leases
-        must NOT hand the row to the free list: a concurrent insert
-        reusing it would corrupt the old lease's bookkeeping. The row
-        is unmapped immediately (no new lookups hit it) and freed by
-        the LAST release."""
-        cache = RadixPrefixCache(rows=2)
-        cache.insert([1, 2, 3, 4], _fake_state(4))
-        hit = cache.lookup([1, 2, 3, 4, 9])      # leases the row
+        """Invalidating an entry another in-flight admission still
+        leases must NOT hand its id to the free list: a concurrent
+        insert reusing it would corrupt the old lease's bookkeeping.
+        The entry is unmapped immediately (no new lookups hit it) and
+        its id freed by the LAST release."""
+        cache, pool = _trie(rows=2)
+        _put(cache, pool, [1, 2, 3, 4])
+        hit = cache.lookup([1, 2, 3, 4, 9])      # leases the entry
         assert cache.invalidate([1, 2, 3, 4])
         assert cache.lookup([1, 2, 3, 4, 9]) is None  # unmapped now
         assert hit.row not in cache._free        # ...but NOT freed
-        # an insert while the lease is live must take the OTHER row
-        assert cache.insert([5, 5, 5], _fake_state(3))
+        # an insert while the lease is live must take the OTHER id
+        assert _put(cache, pool, [5, 5, 5])
         assert cache.stored_rows() != [hit.row]
         cache.release(hit)                       # last lease frees it
         assert hit.row in cache._free
-        assert cache.insert([6, 6], _fake_state(2))
+        assert _put(cache, pool, [6, 6])
         assert sorted(cache.stored_rows()) == [0, 1]
 
 
@@ -428,7 +450,7 @@ class TestHitRateAndCounters:
             eng.prefix_cache.stats["hits"]
         assert last["serving_prefix_misses"] == \
             eng.prefix_cache.stats["misses"]
-        assert tracer.spans("serving.prefix_fetch")
+        assert tracer.spans("serving.prefix_splice")
         assert tracer.spans("serving.prefill_chunk")
 
     def test_ttft_recorded_and_warm_reuse_reported(self):
@@ -484,8 +506,10 @@ class TestNonBlockingAdmission:
 
 class TestBoundedCompiles:
     def test_warm_engine_never_retraces(self, assert_no_retrace):
-        """decode=1, admit=1, prefix-copy (fetch/store)=1 each, ONE
-        chunk executable, one cold prefill per bucket — then arbitrary
+        """decode=1, a cold row's scatter and the first token's put 1
+        each, no row movers (a hit is a splice, an insert a lease),
+        TWO chunk executables (a cold row's continuation, a warm
+        table's), one cold prefill per bucket — then arbitrary
         admissions (hit, miss, full hit, new slots, sampling configs)
         reuse them all."""
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
@@ -495,10 +519,10 @@ class TestBoundedCompiles:
         eng.run()
         counts = eng.compile_counts()
         assert counts["decode"] == 1
-        assert counts["admit"] == 1
-        assert counts["prefix_fetch"] == 1
-        assert counts["prefix_store"] == 1
-        assert counts["chunk_prefill"] == 1   # every chunk same width
+        assert counts["paged_scatter"] == 1
+        assert counts["paged_tok"] == 1
+        assert not {"admit", "prefix_fetch", "prefix_store"} & set(counts)
+        assert counts["chunk_prefill"] == 2   # every chunk same width
         assert counts["prefill"] == 1         # cold first-chunk shape
         with assert_no_retrace(eng):
             eng.submit(Request(SHARED + [9, 9], 7))
@@ -545,7 +569,6 @@ class TestPrefixSoak:
             assert res[rid].tokens == _solo_generate(p, n, seed=13)
         assert eng.prefix_cache.hit_rate >= 0.5
         counts = eng.compile_counts()
-        assert counts["decode"] == 1 and counts["admit"] == 1
-        assert counts["prefix_fetch"] == 1
-        assert counts["prefix_store"] == 1
-        assert counts["chunk_prefill"] == 1
+        assert counts["decode"] == 1
+        assert counts["paged_scatter"] == 1 and counts["paged_tok"] == 1
+        assert 1 <= counts["chunk_prefill"] <= 2

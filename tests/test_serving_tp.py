@@ -74,14 +74,13 @@ def rig():
     the shared workload once (warm — compile counts are frozen)."""
     cache = {}
 
-    def get(tp=1, paged=True, spec=0, prefill_chunk=0, policy="ttft",
+    def get(tp=1, spec=0, prefill_chunk=0, policy="ttft",
             use_flash_paged=None):
-        key = (tp, paged, spec, prefill_chunk, policy,
-               use_flash_paged)
+        key = (tp, spec, prefill_chunk, policy, use_flash_paged)
         if key not in cache:
             eng = DecodeEngine(
                 _net(), n_slots=2, decode_chunk=2, seed=0,
-                prefix_cache_rows=4, paged_kv=paged, block_tokens=8,
+                prefix_cache_rows=4, block_tokens=8,
                 spec_draft_len=spec, prefill_chunk=prefill_chunk,
                 admission_policy=policy, tp=tp,
                 use_flash_paged=use_flash_paged)
@@ -93,36 +92,33 @@ def rig():
 
 class TestTpParityMatrix:
     """Acceptance gate: greedy bit-parity vs the single-chip engine
-    across TP width x paged x spec x admission mode."""
+    across TP width x spec x admission mode."""
 
-    @pytest.mark.parametrize("paged,spec,prefill_chunk,policy", [
-        (False, 0, 0, "ttft"),      # dense, blocking admission
-        (True, 0, 0, "ttft"),       # paged
-        (True, 3, 4, "decode"),     # paged + spec + chunked
+    @pytest.mark.parametrize("spec,prefill_chunk,policy", [
+        (0, 0, "ttft"),             # blocking admission
+        (3, 4, "decode"),           # spec + chunked
     ])
-    def test_tp2_bit_parity(self, rig, paged, spec, prefill_chunk,
-                            policy):
-        _, ref = rig(1, paged, spec, prefill_chunk, policy)
-        eng, got = rig(2, paged, spec, prefill_chunk, policy)
+    def test_tp2_bit_parity(self, rig, spec, prefill_chunk, policy):
+        _, ref = rig(1, spec, prefill_chunk, policy)
+        eng, got = rig(2, spec, prefill_chunk, policy)
         assert got == ref
         assert eng.tp == 2 and eng.tp_ctx is not None
 
     @pytest.mark.slow
     @pytest.mark.parametrize("spec,prefill_chunk,policy", [
         (0, 0, "decode"), (3, 4, "ttft")])
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_tp2_bit_parity_full_matrix(self, rig, paged, spec,
+    def test_tp2_bit_parity_full_matrix(self, rig, spec,
                                         prefill_chunk, policy):
-        """The remaining admission-mode x layout combinations (slow
-        tier: tier-1 keeps the three structurally distinct corners
+        """The remaining admission-mode combinations (slow
+        tier: tier-1 keeps the two structurally distinct corners
         above within the wall-time budget)."""
-        _, ref = rig(1, paged, spec, prefill_chunk, policy)
-        _, got = rig(2, paged, spec, prefill_chunk, policy)
+        _, ref = rig(1, spec, prefill_chunk, policy)
+        _, got = rig(2, spec, prefill_chunk, policy)
         assert got == ref
 
     def test_tp4_bit_parity_paged_spec(self, rig):
-        _, ref = rig(1, True, 3, 4, "decode")
-        _, got = rig(4, True, 3, 4, "decode")
+        _, ref = rig(1, 3, 4, "decode")
+        _, got = rig(4, 3, 4, "decode")
         assert got == ref
 
     def test_tp_width_validation(self):
@@ -143,7 +139,7 @@ class TestTpCompileDiscipline:
 
     @pytest.mark.parametrize("tp", [2, 4])
     def test_no_retrace_and_budget(self, assert_no_retrace, rig, tp):
-        eng, first = rig(tp, True, 3 if tp == 4 else 0,
+        eng, first = rig(tp, 3 if tp == 4 else 0,
                          4 if tp == 4 else 0,
                          "decode" if tp == 4 else "ttft")
         # a second pass admits through the now-warm prefix trie — the
@@ -166,7 +162,7 @@ class TestTpCompileDiscipline:
         the host just uploaded (committed replicated under tp), a
         spec round the verify program's output: one lowering takes
         both, and each round made one upload."""
-        eng, _ = rig(tp, True, 3, 4, "decode")
+        eng, _ = rig(tp, 3, 4, "decode")
         assert eng.stats["spec_rounds"] > 0
         assert eng.stats["spec_fallback_rounds"] > 0
         counts = eng.compile_counts()
@@ -187,7 +183,7 @@ class TestTpSharding:
         eng1, _ = rig(1)
         total = sum(eng1.kv_shard_bytes().values())
         for tp in (2, 4):
-            eng, _ = rig(tp, True, 3 if tp == 4 else 0,
+            eng, _ = rig(tp, 3 if tp == 4 else 0,
                          4 if tp == 4 else 0,
                          "decode" if tp == 4 else "ttft")
             per = eng.kv_shard_bytes()
@@ -202,9 +198,13 @@ class TestTpSharding:
                 spec = leaf.sharding.spec
                 assert "tp" in spec, spec      # head axis (index 2)
                 assert spec.index("tp") == 2
-        dense, _ = rig(2, paged=False)
-        for st in dense._pool.values():
-            assert st["k"].sharding.spec.index("tp") == 1  # [B,H,W,dh]
+        # a cold admission's dense row [1, H, W, dh]: its head axis
+        row = jnp.zeros((1, 4, 64, 8))
+        for leaf in ("k", "v"):
+            spec = eng.tp_ctx._leaf_spec(
+                (jax.tree_util.DictKey("0"),
+                 jax.tree_util.DictKey(leaf)), row)
+            assert spec.index("tp") == 1
 
     def test_params_head_sliced(self, rig):
         eng, _ = rig(2)
@@ -240,8 +240,7 @@ class TestSnapshotLayoutInvariance:
 
     def _crash_restore(self, snap_tp, restore_tp, rig):
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           prefix_cache_rows=4, paged_kv=True,
-                           block_tokens=8, tp=snap_tp)
+                           prefix_cache_rows=4, block_tokens=8, tp=snap_tp)
         for p, n in CASES:
             eng.submit(Request(list(p), n))
         res = {}
@@ -279,8 +278,8 @@ class TestPagedFlashKernel:
         assert got == ref
 
     def test_kernel_bit_parity_spec_chunked(self, rig):
-        _, ref = rig(1, True, 3, 4, "decode")
-        _, got = rig(1, True, 3, 4, "decode",
+        _, ref = rig(1, 3, 4, "decode")
+        _, got = rig(1, 3, 4, "decode",
                      use_flash_paged="interpret")
         assert got == ref
 
@@ -419,7 +418,7 @@ class TestPagedFlashKernel:
         dispatched tables imply: counted again here from the block
         tables themselves, entry by entry."""
         eng = DecodeEngine(_net(), n_slots=3, decode_chunk=2, seed=0,
-                           paged_kv=True, block_tokens=8,
+                           block_tokens=8,
                            prefill_chunk=4, prefix_cache_rows=4)
         bt, tm = eng.block_tokens, eng._wmax
         want = {"live": 0, "walked": 0}
@@ -463,8 +462,7 @@ class TestTpObservability:
 
     def test_per_shard_gauges_over_http(self):
         eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
-                           prefix_cache_rows=4, paged_kv=True,
-                           block_tokens=8, tp=2)
+                           prefix_cache_rows=4, block_tokens=8, tp=2)
         gw = ServingGateway(eng)
         gw.start()
         try:
@@ -499,7 +497,9 @@ class TestTpObservability:
         eng.submit(Request([1, 4, 7, 2], 4))
         eng.run()
         text = tracer.prometheus_text()
-        assert "{shard=" not in text
+        # (the pool gauges' HELP lines name the label; no SAMPLE does)
+        assert not any("{shard=" in ln for ln in text.splitlines()
+                       if not ln.startswith("#"))
         assert "\nserving_tp_shards 1" in text
         for ln in text.splitlines():
             if ln.startswith("serving_tp_dispatch_s_count"):

@@ -275,8 +275,8 @@ class TestMarkovTask:
 
     def test_flagship_converges_toward_floor(self):
         """Tiny flagship on the Markov task: held-out loss must move
-        from ~log V toward the analytic floor — the bench.py
-        convergence-gate mechanism, in miniature."""
+        from ~log V toward the analytic floor — the convergence
+        gate of the flagship block, in miniature."""
         V, T = 16, 32
         feats, labels, floor = markov_lm_batches(
             V, n_seq=128, seq_len=T, seed=0, sample_seed=1)
