@@ -313,7 +313,7 @@ class AttentionImpl(LayerImplBase):
         PagedAttention memory model on the XLA level: the pallas
         kernel :func:`_paged_flash_attention`, which walks the same
         ``ntab`` table entries a compute block of several pool blocks
-        per grid step with its own double-buffered copies, is the TPU
+        per trip of its loop with its own double-buffered copies, is the TPU
         hot path; this program is its semantics, its off-TPU path and
         its parity oracle).
 
@@ -398,8 +398,9 @@ class AttentionImpl(LayerImplBase):
         # the kernel scores one key head a query head: grouped KV heads
         # take the gather program below (ROADMAP.md M2)
         if hq == h and _should_use_flash_paged(toggle, bt, dh, t):
-            # fused pallas kernel (ISSUE 12; ISSUE 25: a grid step
-            # is a compute block of several table entries): each row
+            # fused pallas kernel (ISSUE 12; ISSUE 25: a compute
+            # block is several table entries; ISSUE 30: a grid step is
+            # a row and query tile, the walk a loop inside it): each row
             # walks its block list INSIDE the kernel, copying only
             # mapped and reachable pool blocks — no [B, ntab*bt, ...]
             # gather ever materializes in HBM. Same validity rule,
@@ -807,6 +808,11 @@ def _paged_q_tile(t: int) -> int:
     return _PAGED_Q_TILE if t % _PAGED_Q_TILE == 0 else t
 
 
+def _paged_grid(n_rows: int, t: int) -> Tuple[int, int]:
+    """The paged kernel's grid: one step a (row, query tile)."""
+    return n_rows, t // _paged_q_tile(t)
+
+
 def _paged_table_entries(ring_slots: int, window: int,
                          block_tokens: int, t: int) -> int:
     """ntab, the consecutive logical blocks a row's ``t`` queries can
@@ -817,7 +823,7 @@ def _paged_table_entries(ring_slots: int, window: int,
 
 def _paged_blocks_per_step(block_tokens: int, n_heads: int,
                            head_dim: int, pool_dtype, ntab: int) -> int:
-    """P, the table entries (pool blocks) one grid step of the paged
+    """P, the table entries (pool blocks) one compute block of the paged
     kernel holds: as many as keep the double-buffered K and V scratch
     inside ``_PAGED_POOL_VMEM`` at the pool's tiled size (the head axis
     pads to the dtype's sublane tile, the head dim to 128 lanes), at
@@ -830,46 +836,78 @@ def _paged_blocks_per_step(block_tokens: int, n_heads: int,
                       _PAGED_MAX_BLOCKS, ntab))
 
 
+def _paged_walk_tiles(table, base, floor, filled, bt, tm, p_blk, t):
+    """Per query tile of a dispatch's host tables (numpy; the
+    arithmetic of :meth:`AttentionImpl._paged_attend` and the kernel's
+    ``span``/``reach``): ``hit`` [B, whole compute blocks], the entries
+    mapped and reachable by some query of the tile, and ``trips`` [B],
+    the compute blocks the kernel's loop walks for them, of the rows
+    that map anything."""
+    table = np.asarray(table)
+    # a row that maps nothing (an idle slot) has no hit and no trip
+    rows = np.flatnonzero((table >= 0).any(axis=1))
+    table, base, floor, filled = (np.asarray(a)[rows] for a in (
+        table, base, floor, filled))
+    s_ring = table.shape[1]
+    ntab = _paged_table_entries(s_ring, tm, bt, t)
+    lo_blk = np.maximum(floor, np.maximum(filled - tm + 1, 0)) // bt
+    e = np.arange(-(-ntab // p_blk) * p_blk, dtype=np.int32)[None, :]
+    g = lo_blk.astype(np.int32)[:, None] + e
+    at = np.arange(len(filled))[:, None], g % s_ring
+    mapped = (table[at] >= 0) & (base[at] == g * bt) & (e < ntab)
+    e_lo = np.where(mapped, e, ntab).min(axis=1)
+    e_hi = np.where(mapped, e, -1).max(axis=1)
+    tq = _paged_q_tile(t)
+    for i in range(t // tq):
+        q0 = filled + i * tq
+        lo = np.maximum(np.maximum(q0 - tm + 1, 0) // bt - lo_blk, e_lo)
+        hi = np.minimum((q0 + tq - 1) // bt - lo_blk, e_hi)
+        yield (mapped & (e >= lo[:, None]) & (e <= hi[:, None]),
+               np.where(hi >= lo, hi // p_blk - lo // p_blk + 1, 0))
+
+
 def paged_walk_counts(table, base, floor, filled, *, block_tokens: int,
                       window: int, blocks_per_step: int,
                       chunk: int = 1) -> Tuple[int, int]:
     """What one call of the paged kernel does with a dispatch's host
-    tables (numpy; the arithmetic of :meth:`AttentionImpl._paged_attend`
-    and the kernel's ``span``/``reach``): ``live``, the pool blocks it
-    copies — entries mapped and reachable by some query of a tile,
-    summed over the query tiles — and ``walked``, the pool blocks'
-    worth of keys it scores, ``blocks_per_step`` for every compute
-    block that holds a live entry. ``live / walked`` is the share of
-    the kernel's arithmetic spent on keys that exist."""
-    bt, tm, t, p_blk = block_tokens, window, chunk, blocks_per_step
-    table, base = np.asarray(table), np.asarray(base)
-    floor, filled = np.asarray(floor), np.asarray(filled)
-    s_ring = table.shape[1]
-    ntab = _paged_table_entries(s_ring, tm, bt, t)
-    lo_blk = np.maximum(floor, np.maximum(filled - tm + 1, 0)) // bt
-    e = np.arange(-(-ntab // p_blk) * p_blk)[None, :]
-    g = lo_blk[:, None] + e
-    mapped = ((np.take_along_axis(table, g % s_ring, axis=1) >= 0)
-              & (np.take_along_axis(base, g % s_ring, axis=1) == g * bt)
-              & (e < ntab))
-    tq = _paged_q_tile(t)
+    tables: ``live``, the pool blocks it copies — entries mapped and
+    reachable by some query of a tile, summed over the query tiles —
+    and ``walked``, the pool blocks' worth of keys it scores,
+    ``blocks_per_step`` for every compute block that holds a live
+    entry. ``live / walked`` is the share of the kernel's arithmetic
+    spent on keys that exist."""
     live = walked = 0
-    for i in range(t // tq):
-        q0 = (filled + i * tq)[:, None]
-        hit = (mapped
-               & (e >= np.maximum(q0 - tm + 1, 0) // bt - lo_blk[:, None])
-               & (e <= (q0 + tq - 1) // bt - lo_blk[:, None]))
+    for hit, _ in _paged_walk_tiles(table, base, floor, filled,
+                                    block_tokens, window,
+                                    blocks_per_step, chunk):
         live += int(hit.sum())
-        walked += p_blk * int(
-            hit.reshape(len(filled), -1, p_blk).any(axis=2).sum())
+        walked += blocks_per_step * int(hit.reshape(
+            len(hit), hit.shape[1] // blocks_per_step, blocks_per_step
+        ).any(axis=2).sum())
     return live, walked
+
+
+def paged_steps_paid(table, base, floor, filled, *, block_tokens: int,
+                     window: int, blocks_per_step: int,
+                     chunk: int = 1) -> int:
+    """The steps one call of the paged kernel pays for a dispatch's
+    host tables: its grid (:func:`_paged_grid`, a step a row and query
+    tile, idle rows too) plus the trips of the loop inside a step, one
+    a compute block between the first and the last entry the tile
+    reaches. Those that score keys are ``walked / blocks_per_step`` of
+    :func:`paged_walk_counts`."""
+    rows, tiles = _paged_grid(len(np.asarray(filled)), chunk)
+    return rows * tiles + sum(
+        int(trips.sum()) for _, trips in _paged_walk_tiles(
+            table, base, floor, filled, block_tokens, window,
+            blocks_per_step, chunk))
 
 
 def _should_use_flash_paged(toggle, block_tokens: int,
                             head_dim: int, t: int = 1) -> bool:
     """Dispatch rule for the pallas paged-attention kernel
-    (:func:`_paged_flash_attention`, one grid step = one compute block
-    of several pool blocks) vs the XLA gather-by-block-table program in
+    (:func:`_paged_flash_attention`, a loop over a row's compute blocks
+    of several pool blocks each) vs the XLA gather-by-block-table program in
     :meth:`AttentionImpl._paged_attend`:
 
     - ``None`` (auto): the kernel on the TPU backend when the block
@@ -888,7 +926,7 @@ def _should_use_flash_paged(toggle, block_tokens: int,
       any backend — the CPU bit-parity testing hook (tier-1 gates the
       kernel's semantics against the gather program with it).
 
-    How many pool blocks a grid step holds and which unit scores them
+    How many pool blocks a compute block holds and which unit scores them
     is the kernel's own business (``_paged_blocks_per_step``,
     ``_PAGED_SHORT_TILE``): every shape this rule admits lowers either
     way (tests/test_attention_tpu_lowering.py).
@@ -928,24 +966,35 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
                            filled, lengths, *, tm: int,
                            interpret: bool = False):
     """Fused pallas paged-attention kernel (ISSUE 12, regridded in
-    ISSUE 25; pallas_guide.md, boom_attention_tricks.md §8-12 — the
-    in-repo flash kernel's decode successor). One grid step = one
-    (row, query tile, COMPUTE BLOCK) visit, a compute block being
+    ISSUE 25, the walk moved into the body in ISSUE 30; pallas_guide.md,
+    boom_attention_tricks.md §8-12 — the in-repo flash kernel's decode
+    successor). One grid step = one (row, query tile):
+    ``grid = _paged_grid(B, t)``. Inside it a loop walks the row's
+    COMPUTE BLOCKS, a compute block being
     ``P = _paged_blocks_per_step(...)`` consecutive table entries
-    (``P x bt`` keys, all heads): the third grid axis is
-    ``ceil(ntab / P)`` long, not ``ntab``.
+    (``P x bt`` keys, all heads), in ascending order, from the first to
+    the last that holds an entry some query of the tile can reach
+    (causal above, the window's lower edge below) between the row's
+    first and last mapped entry. The bounds are read from the row's own
+    tables, so the trips follow the data: an idle slot, or a tile below
+    its row's floor, runs none — it initialises, emits 0 and costs its
+    grid step — and a row of 400 tokens walks 4 compute blocks of the
+    17 its table has room for (:func:`paged_steps_paid` counts both).
 
     - the BLOCK TABLE rides as scalar-prefetch operands and the pools
       stay in HBM (``memory_space=ANY``). The kernel fetches a compute
       block itself: one async copy per *mapped and reachable* entry —
       a pool block ``[bt, H, dh]`` is contiguous — into a two-slot
-      VMEM scratch ``[2, P*bt, H, dh]`` each for K and V, and starts
-      the NEXT grid step's copies (the same row's next compute block,
-      or the next row's first) before it waits for its own, so the
-      fetch hides behind the arithmetic. No ``[B, ntab*bt, H, dh]``
-      gather ever materializes. A compute block no query of the tile
-      can reach (idle slot, entries past the row's length, wholly slid
-      out) costs two range tests: no copy, no wait, no arithmetic.
+      VMEM scratch ``[2, P*bt, H, dh]`` each for K and V. A trip starts
+      the NEXT trip's copies into the other slot before it waits for
+      its own; a row's last trip looks ahead over the grid for the next
+      (row, tile) that walks anything and starts ITS first compute
+      block, and the grid's first step does the same for the first, so
+      no row exposes a copy's latency at its start whatever idle slots
+      lie between. The slot parity is a loop carry inside a row and an
+      SMEM scalar across grid steps; both grid axes stay
+      ``"arbitrary"``, the prefetch depends on the order. No
+      ``[B, ntab*bt, H, dh]`` gather ever materializes.
     - an entry the walk skipped leaves stale scratch behind; its keys
       take a position past every query (``_PAGED_FAR``), so they are
       masked at the score AND the value level like any future key.
@@ -970,7 +1019,8 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
 
     Shapes: q [B, H, t, dh]; pk/pv [nb, bt, H, dh] (post-scatter);
     bid/bval [B, ntab] int32 (pool block per logical block, validity;
-    padded here to whole compute blocks); lo_blk/floor/filled/lengths
+    padded here to whole compute blocks, and reduced here to each
+    row's first and last mapped entry); lo_blk/floor/filled/lengths
     [B] int32. Returns o [B, H, t, dh]. Queries tile by
     ``_PAGED_Q_TILE`` when ``t`` is a multiple of it (a prefill
     chunk); shorter chunks (decode, verify) are one tile. Parity vs
@@ -985,14 +1035,19 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
     bt = pk.shape[1]
     ntab = bid.shape[1]
     tq = _paged_q_tile(t)
-    nq = t // tq
+    grid = _paged_grid(b_sz, t)
+    nq = grid[1]
+    total = b_sz * nq
     short = tq <= _PAGED_SHORT_TILE
     p_blk = _paged_blocks_per_step(bt, h_sz, dh, pk.dtype, ntab)
-    nj = -(-ntab // p_blk)
     n_keys = p_blk * bt
-    total = b_sz * nq * nj
     scale = dh ** -0.5
-    pad = nj * p_blk - ntab
+    # a row's first and last mapped entry bound its walk (none mapped,
+    # an idle slot: ntab and -1, an empty walk)
+    entry = jnp.arange(ntab, dtype=jnp.int32)
+    e_lo = jnp.min(jnp.where(bval > 0, entry, ntab), axis=1)
+    e_hi = jnp.max(jnp.where(bval > 0, entry, -1), axis=1)
+    pad = -ntab % p_blk
     if pad:
         bid = jnp.pad(bid, ((0, 0), (0, pad)))
         bval = jnp.pad(bval, ((0, 0), (0, pad)))
@@ -1003,31 +1058,34 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
     rows = (tq, h_sz) if short else (h_sz, tq)
 
     def kernel(bid_ref, bval_ref, lo_ref, floor_ref, filled_ref,
-               len_ref, q_ref, pk_ref, pv_ref, o_ref, kbuf, vbuf, sem,
-               m_ref, l_ref, acc_ref):
+               len_ref, elo_ref, ehi_ref, q_ref, pk_ref, pv_ref, o_ref,
+               kbuf, vbuf, sem, m_ref, l_ref, acc_ref, slot_ref):
         b = pl.program_id(0)
         i = pl.program_id(1)
-        j = pl.program_id(2)
-        step = (b * nq + i) * nj + j
-        slot = step % 2
+        cur = b * nq + i
 
-        def span(bb, ii, jj):
-            """Entries of row ``bb`` that some query of tile ``ii`` can
-            reach (causal above, last-``tm`` window below; the floor is
-            in ``lo_blk`` already), and whether compute block ``jj``
-            holds any of them."""
+        def span(bb, ii):
+            """Entries ``[lo, hi]`` of row ``bb`` that some query of
+            tile ``ii`` can reach (causal above, last-``tm`` window
+            below; the floor is in ``lo_blk`` already) between the
+            row's first and last mapped entry, and the compute blocks
+            ``[j_lo, j_end)`` that hold them: none (``j_end == j_lo``)
+            for an idle slot or a tile below its row's floor."""
             q0 = filled_ref[bb] + ii * tq
-            lo_e = jnp.maximum(q0 - tm + 1, 0) // bt - lo_ref[bb]
-            hi_e = (q0 + tq - 1) // bt - lo_ref[bb]
-            return lo_e, hi_e, ((jj * p_blk <= hi_e)
-                                & (jj * p_blk + p_blk - 1 >= lo_e))
+            lo = jnp.maximum(jnp.maximum(q0 - tm + 1, 0) // bt
+                             - lo_ref[bb], elo_ref[bb])
+            hi = jnp.minimum((q0 + tq - 1) // bt - lo_ref[bb],
+                             ehi_ref[bb])
+            j_lo = lo // p_blk
+            return lo, hi, j_lo, jnp.where(hi >= lo, hi // p_blk + 1,
+                                           j_lo)
 
-        def reach(bb, jj, lo_e, hi_e):
+        def reach(bb, jj, lo, hi):
             """Per entry of the compute block: mapped and reachable —
             the one predicate that decides copy, wait and mask."""
             e0 = jj * p_blk
-            return [(bval_ref[bb, e0 + p] > 0) & (e0 + p >= lo_e)
-                    & (e0 + p <= hi_e) for p in range(p_blk)]
+            return [(bval_ref[bb, e0 + p] > 0) & (e0 + p >= lo)
+                    & (e0 + p <= hi) for p in range(p_blk)]
 
         def copies(bb, jj, sl, live, go):
             for p in range(p_blk):
@@ -1040,38 +1098,37 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
                     go(pltpu.make_async_copy(
                         pv_ref.at[blk], vbuf.at[sl, rows], sem.at[1, sl]))
 
-        def fetch(bb, ii, jj, sl):
-            lo_e, hi_e, some = span(bb, ii, jj)
+        def fetch_from(c0, sl):
+            """Start, into slot ``sl``, the copies of the first compute
+            block of the first (row, tile) at or after grid step ``c0``
+            that walks one; nothing when no later step does."""
+            def idle(c):
+                _, _, j_lo, j_end = span(
+                    jnp.minimum(c, total - 1) // nq, c % nq)
+                return (c < total) & (j_end == j_lo)
 
-            @pl.when(some)
+            c = jax.lax.while_loop(idle, lambda c: c + 1, c0)
+
+            @pl.when(c < total)
             def _start():
-                copies(bb, jj, sl, reach(bb, jj, lo_e, hi_e),
+                bb = c // nq
+                lo, hi, j_lo, _ = span(bb, c % nq)
+                copies(bb, j_lo, sl, reach(bb, j_lo, lo, hi),
                        lambda dma: dma.start())
 
-        @pl.when(step == 0)
+        @pl.when(cur == 0)
         def _first():
-            fetch(b, i, j, slot)
+            slot_ref[0] = 0
+            fetch_from(cur, 0)
 
-        @pl.when(step + 1 < total)
-        def _next():
-            # the grid is walked in order, so step + 1 is the next
-            # compute block, the next tile's first or the next row's
-            j_end = j + 1 == nj
-            i_end = j_end & (i + 1 == nq)
-            fetch(jnp.where(i_end, b + 1, b),
-                  jnp.where(i_end, 0, jnp.where(j_end, i + 1, i)),
-                  jnp.where(j_end, 0, j + 1), 1 - slot)
-
-        @pl.when(j == 0)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, -1e30)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
         q0 = filled_ref[b] + i * tq            # tile's first position
         written = filled_ref[b] + len_ref[b]
 
-        def short_block(k0):
+        def short_block(k0, slot):
             # [keys, H, dh] as the pool holds it; every per-key and
             # per-(key, head) number is [keys, H, 1]
             kpos = jnp.concatenate([
@@ -1104,7 +1161,7 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             else:
                 jax.lax.fori_loop(0, tq, row, None)
 
-        def tile_block(k0):
+        def tile_block(k0, slot):
             def positions(shape, dim):
                 idx = jax.lax.broadcasted_iota(jnp.int32, shape, dim)
                 pos = k0[0] + idx
@@ -1143,31 +1200,46 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
                                   preferred_element_type=jnp.float32))
                 m_ref[h] = m_next
 
-        lo_e, hi_e, some = span(b, i, j)
+        lo, hi, j_lo, j_end = span(b, i)
 
-        @pl.when(some)
-        def _block():
-            live = reach(b, j, lo_e, hi_e)
+        def trip(j, slot):
+            # the walk is in order, so what comes next is this row's
+            # next compute block or, after its last, the first one of
+            # the next (row, tile) that has any: started before this
+            # trip waits for its own, in the slot the last trip left
+            last = j + 1 == j_end
+
+            @pl.when(jnp.logical_not(last))
+            def _next():
+                copies(b, j + 1, 1 - slot, reach(b, j + 1, lo, hi),
+                       lambda dma: dma.start())
+
+            pl.when(last)(lambda: fetch_from(cur + 1, 1 - slot))
+            live = reach(b, j, lo, hi)
             copies(b, j, slot, live, lambda dma: dma.wait())
             # an entry's first key position; a skipped entry's keys
             # sit past every query
             k0 = [jnp.where(live[p], (lo_ref[b] + j * p_blk + p) * bt,
                             _PAGED_FAR) for p in range(p_blk)]
             pl.when(functools.reduce(jnp.logical_or, live))(
-                lambda: (short_block if short else tile_block)(k0))
+                lambda: (short_block if short else tile_block)(k0, slot))
+            return 1 - slot
 
-        @pl.when(j == nj - 1)
-        def _finalize():
-            l = l_ref[...][..., :1]
-            o_ref[0] = (acc_ref[...] / jnp.where(l == 0, 1.0, l)
-                        ).astype(o_ref.dtype)
+        # the slot parity outlives the grid step: the next (row, tile)
+        # that walks finds its first compute block where the last trip
+        # before it put it
+        slot_ref[0] = jax.lax.fori_loop(j_lo, j_end, trip, slot_ref[0])
 
-    def q_map(b, i, j, *refs):
+        l = l_ref[...][..., :1]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0, 1.0, l)
+                    ).astype(o_ref.dtype)
+
+    def q_map(b, i, *refs):
         return (b, 0, 0, 0) if short else (b, 0, i, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(b_sz, nq, nj),
+        num_scalar_prefetch=8,
+        grid=grid,
         in_specs=[
             pl.BlockSpec((1, *rows, dh), q_map),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -1181,16 +1253,17 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             pltpu.VMEM((*rows, 128), jnp.float32),      # running max
             pltpu.VMEM((*rows, 128), jnp.float32),      # running sum
             pltpu.VMEM((*rows, dh), jnp.float32),       # accumulator
+            pltpu.SMEM((1,), jnp.int32),                # K/V slot parity
         ],
     )
     o = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",) * 3,
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=_PAGED_VMEM_LIMIT),
         interpret=interpret,
-    )(bid, bval, lo_blk, floor, filled, lengths, q, pk, pv)
+    )(bid, bval, lo_blk, floor, filled, lengths, e_lo, e_hi, q, pk, pv)
     return jnp.swapaxes(o, 1, 2) if short else o
 
 

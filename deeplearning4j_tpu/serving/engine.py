@@ -143,6 +143,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
     _paged_blocks_per_step,
     _paged_table_entries,
     guard_streamable,
+    paged_steps_paid,
     paged_walk_counts,
 )
 from deeplearning4j_tpu.nn.streaming import scan_length_bucket
@@ -1119,10 +1120,12 @@ class DecodeEngine:
             # the paged kernel's walk (ISSUE 25): pool blocks a
             # dispatch's tables make one layer's call copy, and the
             # blocks' worth of keys it scores (whole compute blocks),
-            # summed over dispatches; the compute block's size and a
-            # decode row's grid steps land with the pool
+            # summed over dispatches, with the grid steps and loop
+            # trips the call pays (ISSUE 30); the compute block's size
+            # and a decode row's longest walk land with the pool
             "paged_blocks_live": 0, "paged_blocks_walked": 0,
             "paged_blocks_per_step": 0, "paged_steps_per_row": 0,
+            "paged_steps_paid": 0,
             # host-to-device transfers made for the block-table
             # operand, summed over paged dispatches: one a dispatch,
             # whatever the number of paged layers (ISSUE 28)
@@ -2090,8 +2093,9 @@ class DecodeEngine:
         """``paged_blocks_live`` / ``paged_blocks_walked``: what the
         widest-window layer's kernel call does with these tables
         (``paged_walk_counts``; the gather program reads the same live
-        blocks). The geometry is the first pool leaf's, local to a
-        tp shard."""
+        blocks), and ``paged_steps_paid``, the grid steps and loop
+        trips it pays for them (``paged_steps_paid``). The geometry is
+        the first pool leaf's, local to a tp shard."""
         pk = next(iter(self._pool.values()))["pk"]
         bt = self.block_tokens
         ntab = _paged_table_entries(self._ring_slots, self._wmax, bt,
@@ -2101,11 +2105,14 @@ class DecodeEngine:
         if chunk == 1:
             self.stats["paged_blocks_per_step"] = per_step
             self.stats["paged_steps_per_row"] = -(-ntab // per_step)
-        live, walked = paged_walk_counts(
-            table, base, floor, filled, block_tokens=bt,
-            window=self._wmax, blocks_per_step=per_step, chunk=chunk)
+        geometry = dict(block_tokens=bt, window=self._wmax,
+                        blocks_per_step=per_step, chunk=chunk)
+        live, walked = paged_walk_counts(table, base, floor, filled,
+                                         **geometry)
         self.stats["paged_blocks_live"] += live
         self.stats["paged_blocks_walked"] += walked
+        self.stats["paged_steps_paid"] += paged_steps_paid(
+            table, base, floor, filled, **geometry)
 
     def _strip_pool(self, rnn):
         """What a program hands back (pool leaves and, for a net
@@ -3556,7 +3563,7 @@ class DecodeEngine:
                     "preempted", "paged_admit_deferred",
                     "paged_blocks_live", "paged_blocks_walked",
                     "paged_blocks_per_step", "paged_steps_per_row",
-                    "table_uploads"):
+                    "paged_steps_paid", "table_uploads"):
             self.tracer.counter(f"serving_{key}", self.stats[key])
         if self.prefix_cache is not None:
             for key in ("hits", "misses", "evictions"):

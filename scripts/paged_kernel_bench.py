@@ -5,19 +5,29 @@ Builds one layer's KV pool, a block table for ``n_slots`` rows of which
 distributions (the rest are idle, scattered between the live ones), and
 times three programs on the chip, ``--iters`` back-to-back calls each:
 
-- ``kernel``: ``_paged_flash_attention`` alone (no scatter);
+- ``kernel``: ``_paged_flash_attention`` alone (no scatter), a program
+  a call: under ~0.22 ms a call this reads the host's time to enqueue
+  one, not the device's;
+- ``kernel_chain``: ``CHAIN`` (24, a decode step's layers) calls of it
+  in ONE program, each call's output the next one's queries, so the
+  device is the bound at any occupancy;
 - ``attend_kernel`` / ``attend_gather``: ``AttentionImpl._paged_attend``
   through the kernel and through the XLA gather program it replaces,
   pool donated so the chunk's scatter is in place, as in the engine.
 
-Per line: milliseconds a call, the bytes of the live (mapped and
-reachable) pool blocks the call has to read, and those bytes over the
-time as a share of the chip's HBM peak (``benchmark/peaks.py``). The
+Per line: milliseconds a call, the steps the kernel pays for the tables
+(grid steps plus the trips of its loop over a row's compute blocks,
+``steps_paid``) beside those that score keys (``steps_scoring``), the
+bytes of the live (mapped and reachable) pool blocks the call has to
+read, and those bytes over the time as a share of the chip's HBM peak
+(``benchmark/peaks.py``). ``--context N`` gives every live row N tokens
+(``--live 48 --context 2047``: a full batch of full-window rows). The
 geometry comes from a benchmark configuration file (heads, width,
 window, ``deployment`` slots, block size and pool blocks); nothing here
 is read by the benchmark.
 
     python scripts/paged_kernel_bench.py --live 5,24,36
+    python scripts/paged_kernel_bench.py --live 48 --context 2047
     python scripts/paged_kernel_bench.py --package-root _parent  # another tree
 
 Fails off the TPU; ``--rehearse`` runs the configuration's rehearsal
@@ -37,6 +47,7 @@ import time
 import numpy as np
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CHAIN = 24      # kernel calls inside the kernel_chain program
 
 
 def draw_contexts(rng, traffic: dict, n: int, cap: int) -> np.ndarray:
@@ -65,13 +76,24 @@ def build_case(rng, geo: dict, contexts: np.ndarray, t: int):
     table = np.full((b, s_ring), -1, np.int32)
     base = np.full((b, s_ring), -1, np.int32)
     free = list(rng.permutation(nb))
-    live_blocks = 0
+    span = {r: range(max(0, int(filled[r]) - tm + 1) // bt,
+                     (int(filled[r]) + t - 1) // bt + 1) for r in rows}
+    live_blocks = sum(len(x) for x in span.values())
+    # a case larger than the pool (a full batch of full-window rows)
+    # keeps the blocks its chunk writes to itself and reads the rest
+    # from a shared set: the same bytes a call, rows that alias
+    written = {r: int(filled[r]) // bt for r in rows}
+    shared = []
+    if live_blocks > nb:
+        own = sum(x.stop - written[r] for r, x in span.items())
+        shared, free = free[:nb - own], free[nb - own:]
     for r in rows:
-        lo = max(0, int(filled[r]) - tm + 1) // bt
-        for g in range(lo, (int(filled[r]) + t - 1) // bt + 1):
-            table[r, g % s_ring] = free.pop()
+        for g in span[r]:
+            if shared and g < written[r]:
+                table[r, g % s_ring] = shared[(r * tm + g) % len(shared)]
+            else:
+                table[r, g % s_ring] = free.pop()
             base[r, g % s_ring] = g * bt
-            live_blocks += 1
     ntab = min(s_ring, (tm + t - 2) // bt + 2)
     lo_blk = np.maximum(filled - tm + 1, 0) // bt
     g = lo_blk[:, None] + np.arange(ntab)[None, :]
@@ -99,8 +121,31 @@ def build_case(rng, geo: dict, contexts: np.ndarray, t: int):
         "mask": jnp.asarray(
             (np.arange(t)[None] < lengths[:, None]).astype(np.float32)),
         "live_blocks": live_blocks, "ntab": ntab,
+        "shared_blocks": bool(shared),
+        "host_tables": (table, base, floor, filled),
         "block_bytes": 2 * bt * h * dh * pool_dtype.itemsize,
     }
+
+
+def walk_steps(att, geo: dict, case: dict, t: int) -> dict:
+    """What the timed tree's kernel pays for the case's tables, by the
+    tree's own count: the compute block's size, the steps a call pays
+    and those that score keys. A tree from before the loop inside a
+    grid step has no count of paid steps: its grid is a step a (row,
+    query tile, compute block), computed here."""
+    per_step = att._paged_blocks_per_step(
+        geo["block_tokens"], geo["n_head"], geo["head_dim"],
+        geo["pool_dtype"], case["ntab"])
+    geometry = dict(block_tokens=geo["block_tokens"], window=geo["window"],
+                    blocks_per_step=per_step, chunk=t)
+    _, walked = att.paged_walk_counts(*case["host_tables"], **geometry)
+    if hasattr(att, "paged_steps_paid"):
+        paid = att.paged_steps_paid(*case["host_tables"], **geometry)
+    else:
+        paid = (geo["n_slots"] * (t // att._paged_q_tile(t))
+                * -(-case["ntab"] // per_step))
+    return {"blocks_per_step": per_step, "steps_paid": int(paid),
+            "steps_scoring": walked // per_step}
 
 
 def time_calls(fn, iters: int) -> float:
@@ -126,6 +171,9 @@ def main() -> int:
         ROOT, "benchmark", "traffic", "chat-steady.json"))
     ap.add_argument("--live", default="24",
                     help="live rows, comma-separated for several cases")
+    ap.add_argument("--context", type=int, default=None,
+                    help="tokens every live row holds (default: drawn "
+                         "from the traffic file's lengths)")
     ap.add_argument("--chunk", type=int, default=1,
                     help="query rows a call (1 = decode)")
     ap.add_argument("--pool-dtype", default="float32")
@@ -171,7 +219,7 @@ def main() -> int:
     }
     toggle = "interpret" if args.rehearse else True
     iters = 2 if args.rehearse else args.iters
-    per_step = getattr(att, "_paged_blocks_per_step", None)
+    n_chain = 2 if args.rehearse else CHAIN
     peak = None if args.rehearse else peaks_of(dev.device_kind)
     lc = att.MultiHeadSelfAttention(
         n_in=conf["n_embd"], n_out=conf["n_embd"], n_heads=conf["n_head"],
@@ -180,8 +228,11 @@ def main() -> int:
         rng = np.random.default_rng([args.seed, n_live])
         n_live = min(n_live, geo["n_slots"])
         ctx = draw_contexts(rng, traffic, n_live, geo["window"])
+        if args.context is not None:
+            ctx = np.full(n_live, args.context, np.int32)
         case = build_case(rng, geo, ctx, args.chunk)
         live_bytes = case["live_blocks"] * case["block_bytes"]
+        steps = walk_steps(att, geo, case, args.chunk)
 
         kernel = jax.jit(lambda c: att._paged_flash_attention(
             c["q"], c["pk"], c["pv"], c["bid"], c["bval"], c["lo_blk"],
@@ -190,7 +241,11 @@ def main() -> int:
         ops = {k: case[k] for k in (
             "q", "pk", "pv", "bid", "bval", "lo_blk", "floor", "filled",
             "lengths")}
-        programs = {"kernel": lambda: kernel(ops)}
+        chain = jax.jit(lambda c: jax.lax.scan(
+            lambda q, _: (kernel(dict(c, q=q)), None), c["q"], None,
+            length=n_chain)[0])
+        programs = {"kernel": lambda: kernel(ops),
+                    "kernel_chain": lambda: chain(ops)}
 
         def attend(flag):
             bean = dataclasses.replace(lc, use_flash_paged=flag)
@@ -213,6 +268,8 @@ def main() -> int:
         programs["attend_gather"] = attend(False)
         for name, fn in programs.items():
             ms = time_calls(fn, iters)
+            if name == "kernel_chain":
+                ms /= n_chain
             line = {
                 "program": name, "live_rows": n_live,
                 "mean_context": round(float(ctx.mean()), 1) if n_live else 0,
@@ -220,10 +277,9 @@ def main() -> int:
                 "ntab": case["ntab"], "live_blocks": case["live_blocks"],
                 "live_bytes": live_bytes,
             }
-            if per_step is not None:
-                line["blocks_per_step"] = per_step(
-                    geo["block_tokens"], geo["n_head"], geo["head_dim"],
-                    args.pool_dtype, case["ntab"])
+            if case["shared_blocks"]:
+                line["shared_blocks"] = True
+            line.update(steps)
             if args.rehearse:
                 line["rehearsal"] = "interpreter; no device number"
             else:

@@ -30,8 +30,11 @@ from deeplearning4j_tpu.models.zoo import transformer_lm
 from deeplearning4j_tpu.nn.layers.attention import (
     AttentionImpl,
     MultiHeadSelfAttention,
+    _PAGED_Q_TILE,
     _paged_blocks_per_step,
     _should_use_flash_paged,
+    paged_steps_paid,
+    paged_walk_counts,
 )
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.profiler.tracer import Tracer
@@ -336,19 +339,83 @@ class TestPagedFlashKernel:
     # ntab = min(s_ring, (tm + t - 2) // bt + 2) is tied to the window
     # and the chunk, so the 9-entry walk (the rehearsal's) exists for
     # short chunks only; 129 is the serving cell's, 130 the flagship's.
-    # ``cap`` bounds the compute block so that no ntab is a multiple
-    @pytest.mark.parametrize("ntab,cap,t", [
-        (9, 4, 1), (9, 4, 5),
-        (129, 16, 1), (129, 16, 5), (129, 16, 128), (129, 16, 256),
-        (130, 8, 1), (130, 8, 5), (130, 8, 128), (130, 8, 256)])
-    def test_kernel_parity_on_ragged_tables(self, monkeypatch, ntab,
-                                            cap, t):
-        """Interpret parity with the gather program where the walk is
-        not whole compute blocks: idle rows between live ones, a slid
-        window, a raised floor inside a block, a hole (an unmapped
-        entry between mapped ones), ragged chunks, and NaN in every
-        pool position no row has written (free blocks, a tail block
-        past its row's length, the floor block below the floor)."""
+    # ``cap`` bounds the compute block so that no ntab is a multiple.
+    # ``rows`` names the tables (``_ragged_rows``): what the bounds of
+    # the kernel's loop over a row's compute blocks have to get right
+    RAGGED = [
+        (9, 4, 1, "mixed"), (9, 4, 5, "mixed"),
+        (129, 16, 1, "mixed"), (129, 16, 5, "mixed"),
+        (129, 16, 128, "mixed"), (129, 16, 256, "mixed"),
+        (130, 8, 1, "mixed"), (130, 8, 5, "mixed"),
+        (130, 8, 128, "mixed"), (130, 8, 256, "mixed"),
+        (9, 4, 1, "idle"), (9, 4, 4, "idle"),
+        (17, 4, 1, "first"), (17, 4, 1, "last"), (17, 4, 8, "last"),
+        (17, 4, 1, "bottom"), (17, 4, 4, "bottom"),
+        (17, 4, 1, "gap"), (17, 4, 8, "gap"),
+        (17, 4, 1, "full"), (17, 4, 8, "full"),
+        (17, 4, 4, "cross"), (17, 4, 8, "cross"),
+        (41, 4, 256, "tiles")]
+
+    @staticmethod
+    def _ragged_rows(rows, tm, bt, t, p_blk):
+        """``filled``, ``floor``, the live rows, and the (row, logical
+        block) mappings to take away again, for a named set of tables:
+
+        - ``mixed``: idle rows between live ones, a slid window, a
+          raised floor inside a block, a short row, a hole (an unmapped
+          entry between mapped ones);
+        - ``idle``: no live row at all;
+        - ``first`` / ``last``: one live row, in the first / the last
+          slot, every other idle;
+        - ``bottom``: a slid window whose oldest compute block (and one
+          entry more) is unmapped, so the walk starts above block 0;
+        - ``gap``: a whole unmapped compute block between live ones;
+        - ``full``: a row whose walk ends in the table's last entry;
+        - ``cross``: chunks that cross a pool block's and a compute
+          block's boundary;
+        - ``tiles``: two query tiles that reach different compute
+          blocks of one slid row, beside a row below its own floor."""
+        span = p_blk * bt
+        if rows == "mixed":
+            filled = [0, tm // 2, 0, tm + 3 * bt + 5, 3 * bt + tm // 3,
+                      0, 3]
+            floor = [0, 0, 0, 0, 2 * bt + 3, 0, 0]
+            return filled, floor, [1, 3, 4, 6], [
+                (1, (filled[1] // bt) // 2)]
+        if rows == "idle":
+            return [0, 0, 0], [0, 0, 0], [], []
+        if rows in ("first", "last"):
+            filled = [0, 0, 0, 0]
+            filled[0 if rows == "first" else -1] = tm // 2 + 1
+            return filled, [0] * 4, [0 if rows == "first" else 3], []
+        if rows == "bottom":
+            filled = [0, tm + 2 * span + 3, 0]
+            lo = (filled[1] - tm + 1) // bt
+            return filled, [0] * 3, [1], [
+                (1, lo + e) for e in range(p_blk + 1)]
+        if rows == "gap":
+            filled = [3 * span + 2, 0, 3 * span + bt]
+            return filled, [0] * 3, [0, 2], [
+                (0, p_blk + e) for e in range(p_blk)] + [
+                (2, 2 * p_blk + e) for e in range(p_blk)]
+        if rows == "full":
+            ntab = (tm + t - 2) // bt + 2
+            top = next(f for f in range(tm, tm + 2 * bt)
+                       if (f + t - 1) // bt - (f - tm + 1) // bt
+                       == ntab - 1)
+            return [0, top, tm // 3], [0] * 3, [1, 2], []
+        if rows == "cross":
+            return ([2 * bt - 2, 0, span - 2, 2 * span - 1], [0] * 4,
+                    [0, 2, 3], [])
+        assert rows == "tiles"
+        return ([tm + span + 3, 0, 5], [0, 0, 5 + 2 * _PAGED_Q_TILE],
+                [0, 2], [])
+
+    def _ragged_case(self, monkeypatch, ntab, cap, t, rows):
+        """The layer, its operands and the live rows of one RAGGED
+        case: NaN in every pool position no row has written (free
+        blocks, a tail block past its row's length, the floor block
+        below the floor, a block that lost its mapping)."""
         from deeplearning4j_tpu.nn.layers import attention as att
 
         monkeypatch.setattr(att, "_PAGED_MAX_BLOCKS", cap)
@@ -356,36 +423,35 @@ class TestPagedFlashKernel:
         tm = (ntab - 2) * bt - t + 2 + 3     # (tm + t - 2) // bt + 2
         s_ring = 2 * ntab + 3
         assert min(s_ring, (tm + t - 2) // bt + 2) == ntab
-        assert ntab % att._paged_blocks_per_step(
-            bt, h, dh, jnp.float32, ntab)
+        p_blk = att._paged_blocks_per_step(bt, h, dh, jnp.float32, ntab)
+        assert ntab % p_blk
         lc = MultiHeadSelfAttention(n_in=h * dh, n_out=h * dh,
                                     n_heads=h, stream_max_t=tm)
         rng = np.random.default_rng([ntab, t])
-        #         idle  mid     idle  slid          floor   idle  short
-        filled = [0, tm // 2, 0, tm + 3 * bt + 5, 3 * bt + tm // 3, 0, 3]
-        floor = [0, 0, 0, 0, 2 * bt + 3, 0, 0]
+        filled, floor, live, holes = self._ragged_rows(
+            rows, tm, bt, t, p_blk)
         b = len(filled)
         lens = rng.integers(1, t + 1, b)
-        lens[1] = t
+        lens[live[:1]] = t
         table = np.full((b, s_ring), -1, np.int32)
         base = np.full((b, s_ring), -1, np.int32)
         pk = np.full((nb, bt, h, dh), np.nan, np.float32)
         pv = np.full((nb, bt, h, dh), np.nan, np.float32)
         free = list(rng.permutation(nb))
-        for r in range(b):
-            if r in (0, 2, 5):
-                continue
+        for r in live:
             lo = max(floor[r], filled[r] - tm + 1, 0) // bt
             for g in range(lo, (filled[r] + t - 1) // bt + 1):
                 bid = free.pop()
                 table[r, g % s_ring], base[r, g % s_ring] = bid, g * bt
                 pos = g * bt + np.arange(bt)
                 held = (pos >= floor[r]) & (pos < filled[r])
+                if (r, g) in holes:
+                    continue
                 pk[bid, held] = rng.normal(size=(held.sum(), h, dh))
                 pv[bid, held] = rng.normal(size=(held.sum(), h, dh))
-        # the hole: one of row 1's middle blocks loses its mapping
-        hole = (filled[1] // bt) // 2
-        table[1, hole % s_ring] = base[1, hole % s_ring] = -1
+        for r, g in holes:
+            assert table[r, g % s_ring] >= 0, (r, g)
+            table[r, g % s_ring] = base[r, g % s_ring] = -1
         q, k, v = (jnp.asarray(rng.normal(size=(b, h, t, dh)),
                                jnp.float32) for _ in range(3))
         mask = (None if t == 1 else jnp.asarray(
@@ -394,11 +460,21 @@ class TestPagedFlashKernel:
                  "table": jnp.asarray(table), "base": jnp.asarray(base),
                  "floor": jnp.asarray(floor, jnp.int32),
                  "filled": jnp.asarray(filled, jnp.int32)}
+        return lc, (q, k, v), cache, mask, live, p_blk
+
+    @pytest.mark.parametrize("ntab,cap,t,rows", RAGGED)
+    def test_kernel_parity_on_ragged_tables(self, monkeypatch, ntab,
+                                            cap, t, rows):
+        """Interpret parity with the gather program where the walk is
+        not whole compute blocks and its ends are not the table's
+        (``_ragged_rows``), finite whatever the unwritten pool holds."""
+        lc, qkv, cache, mask, live, _ = self._ragged_case(
+            monkeypatch, ntab, cap, t, rows)
         outs = {}
         for toggle in (False, "interpret"):
             lc.use_flash_paged = toggle
-            o, _ = AttentionImpl._paged_attend(lc, q, k, v,
-                                               dict(cache), mask)
+            o, _ = AttentionImpl._paged_attend(lc, *qkv, dict(cache),
+                                               mask)
             o = np.asarray(o)
             if mask is not None:
                 # pad queries are never read (and may see the NaN
@@ -408,10 +484,80 @@ class TestPagedFlashKernel:
             outs[toggle] = o
         assert np.isfinite(outs["interpret"]).all(), (
             "NaN leaked through the kernel's masked lanes")
-        assert np.abs(outs[False][[1, 3, 4, 6]]).min(axis=(1, 2, 3)
-                                                      ).max() > 0
+        if live:
+            assert np.abs(outs[False][live]).min(axis=(1, 2, 3)
+                                                 ).max() > 0
+        idle = sorted(set(range(len(outs[False]))) - set(live))
+        assert not outs["interpret"][idle].any()
         np.testing.assert_allclose(outs["interpret"], outs[False],
                                    rtol=5e-5, atol=5e-5)
+
+    @pytest.mark.parametrize("ntab,cap,t,rows", RAGGED)
+    def test_steps_paid_match_a_brute_force_count(self, monkeypatch,
+                                                  ntab, cap, t, rows):
+        """``paged_steps_paid`` is the kernel's grid plus, for every
+        (row, query tile), the compute blocks between the first and
+        the last table entry that is inside the tile's reach and inside
+        the row's mapped span: counted here entry by entry. The steps
+        that score keys (``walked`` over the compute block) are among
+        them."""
+        lc, _, cache, _, live, p_blk = self._ragged_case(
+            monkeypatch, ntab, cap, t, rows)
+        table, base, floor, filled = (np.asarray(cache[k]) for k in (
+            "table", "base", "floor", "filled"))
+        bt, tm, s_ring = cache["pk"].shape[1], lc.stream_max_t, table.shape[1]
+        tq = t if t % _PAGED_Q_TILE else _PAGED_Q_TILE
+        want = scoring = 0
+        for r in range(len(filled)):
+            lo_blk = max(floor[r], filled[r] - tm + 1, 0) // bt
+            mapped = [e for e in range(ntab)
+                      if table[r, (lo_blk + e) % s_ring] >= 0
+                      and base[r, (lo_blk + e) % s_ring]
+                      == (lo_blk + e) * bt]
+            for i in range(t // tq):
+                q0 = filled[r] + i * tq
+                reached = [e for e in range(ntab)
+                           if (lo_blk + e + 1) * bt - 1 > q0 - tm
+                           and (lo_blk + e) * bt <= q0 + tq - 1]
+                want += 1                              # the grid step
+                if not (mapped and reached):
+                    continue
+                lo = max(mapped[0], reached[0])
+                hi = min(mapped[-1], reached[-1])
+                want += len({e // p_blk for e in range(lo, hi + 1)})
+                scoring += len({e // p_blk for e in mapped
+                                if e in reached})
+        geometry = dict(block_tokens=bt, window=tm,
+                        blocks_per_step=p_blk, chunk=t)
+        paid = paged_steps_paid(table, base, floor, filled, **geometry)
+        assert paid == want
+        _, walked = paged_walk_counts(table, base, floor, filled,
+                                      **geometry)
+        assert walked == scoring * p_blk
+        grid = len(filled) * (t // tq)
+        assert paid >= grid + scoring
+        assert (paid == grid) == (not live)
+        if rows == "gap":          # the unmapped compute block's trip
+            assert paid == grid + scoring + 2
+
+    def test_steps_paid_of_an_idle_dispatch_is_the_grid(self):
+        """A dispatch whose slots are all idle pays one grid step a
+        slot and query tile, and nothing else."""
+        eng = DecodeEngine(_net(), n_slots=3, decode_chunk=2, seed=0,
+                           block_tokens=8)
+        assert eng.stats["paged_steps_paid"] == 0
+        _submit_run(eng)                  # the pool says the geometry
+        served = dict(eng.stats)
+        assert (served["paged_steps_paid"] * served["paged_blocks_per_step"]
+                > served["paged_blocks_walked"] > 0)
+        eng._paged_tables([None] * 3)
+        assert eng.stats["paged_steps_paid"] - served[
+            "paged_steps_paid"] == 3
+        eng._paged_tables([None] * 3, chunk=2 * _PAGED_Q_TILE)
+        assert eng.stats["paged_steps_paid"] - served[
+            "paged_steps_paid"] == 3 + 3 * 2
+        assert (eng.stats["paged_blocks_walked"]
+                == served["paged_blocks_walked"])
 
     def test_walk_counters_match_the_tables(self):
         """``paged_blocks_live`` / ``paged_blocks_walked`` are what the
