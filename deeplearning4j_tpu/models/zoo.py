@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-from typing import Sequence
+from typing import Optional, Sequence
 
 from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration, Updater
 from deeplearning4j_tpu.nn.conf import layers as L
@@ -399,6 +399,96 @@ def granite_moe_hybrid_lm(
     b.layer(len(layer_types) + 1, TiedLMHead(
         n_in=hidden_size, n_out=vocab_size, tie_to=0,
         logits_scaling=logits_scaling, rms_eps=rms_norm_eps,
+        activation="softmax", loss_function=LossFunction.MCXENT))
+    conf = b.build()
+    for c in conf.confs:
+        c.dtype = dtype
+    return conf
+
+
+def afmoe_lm(
+    vocab_size: int = 64,
+    hidden_size: int = 64,
+    layer_types: Sequence[str] = ("sliding_attention", "sliding_attention",
+                                  "sliding_attention", "full_attention"),
+    layers: Optional[Sequence[int]] = None,
+    num_dense_layers: int = 1,
+    num_attention_heads: int = 4,
+    num_key_value_heads: int = 2,
+    head_dim: int = 16,
+    sliding_window: int = 32,
+    rope_theta: float = 10000.0,
+    intermediate_size: int = 128,
+    moe_intermediate_size: int = 32,
+    num_experts: int = 8,
+    num_experts_per_tok: int = 2,
+    num_shared_experts: int = 1,
+    route_scale: float = 1.0,
+    experts_held=None,
+    mup_enabled: bool = True,
+    rms_norm_eps: float = 1e-5,
+    max_position_embeddings: int = 512,
+    initializer_range: float = 0.02,
+    dtype: str = "float32",
+    seed: int = 12345,
+):
+    """An ``afmoe`` LM (HF ``AfmoeForCausalLM``, Trinity), served only,
+    under its config's own key names: the token embedding (times
+    ``sqrt(hidden_size)`` where ``mup_enabled``), one ``HybridMoeBlock``
+    a layer with QK-norm, gated attention and the four sandwich norms,
+    an untied head. ``layer_types[i]`` says layer ``i``'s attention:
+    ``"sliding_attention"`` (window ``sliding_window``, rotary
+    positions) or ``"full_attention"`` (no positional term, the whole
+    context up to ``max_position_embeddings``); layers below
+    ``num_dense_layers`` have a dense gated feed-forward of width
+    ``intermediate_size``, the others sigmoid top-k routing over
+    ``num_experts`` outputs plus ``num_shared_experts`` shared experts
+    of width ``moe_intermediate_size``. ``layers`` picks which published
+    layer indices are built (None = all of ``layer_types``), each keeping
+    the kind its index has; ``experts_held`` the ``[lo, hi)`` of the
+    router's outputs whose experts this chip holds."""
+    from deeplearning4j_tpu.nn.conf.distribution import NormalDistribution
+    from deeplearning4j_tpu.nn.layers.hybrid import (
+        HybridMoeBlock,
+        TiedLMHead,
+    )
+
+    picked = list(range(len(layer_types))) if layers is None else list(
+        layers)
+    b = (
+        NeuralNetConfiguration.Builder()
+        .seed(seed)
+        .updater(Updater.ADAM)
+        .activation("identity")
+        .list()
+    )
+    b.layer(0, L.EmbeddingLayer(
+        n_in=vocab_size, n_out=hidden_size, sequence=True,
+        multiplier=(hidden_size ** 0.5 if mup_enabled else 1.0),
+        weight_init=WeightInit.DISTRIBUTION,
+        dist=NormalDistribution(0.0, initializer_range)))
+    for i, idx in enumerate(picked):
+        sliding = layer_types[idx] == "sliding_attention"
+        dense = idx < num_dense_layers
+        b.layer(i + 1, HybridMoeBlock(
+            n_in=hidden_size, n_out=hidden_size, mixer="attention",
+            rms_eps=rms_norm_eps, n_heads=num_attention_heads,
+            n_kv_heads=num_key_value_heads, d_head=head_dim,
+            stream_max_t=(sliding_window if sliding
+                          else max_position_embeddings),
+            sliding=sliding, rope_theta=rope_theta if sliding else 0.0,
+            qk_norm=True, gated_attention=True, post_norms=True,
+            n_router=0 if dense else num_experts,
+            top_k=num_experts_per_tok, d_expert=moe_intermediate_size,
+            d_shared=(intermediate_size if dense
+                      else num_shared_experts * moe_intermediate_size),
+            experts_held=(None if dense or experts_held is None
+                          else tuple(experts_held)),
+            gate_rule="sigmoid_bias", route_scale=route_scale,
+            init_std=initializer_range))
+    b.layer(len(picked) + 1, TiedLMHead(
+        n_in=hidden_size, n_out=vocab_size, tie_to=None,
+        rms_eps=rms_norm_eps, init_std=initializer_range,
         activation="softmax", loss_function=LossFunction.MCXENT))
     conf = b.build()
     for c in conf.confs:

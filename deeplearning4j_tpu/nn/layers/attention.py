@@ -238,10 +238,15 @@ class AttentionImpl(LayerImplBase):
         # head a query head, so the group's keys are repeated for them;
         # the cache below keeps the KV heads only
         ke, ve = _repeat_kv_heads(q, k, v)
-        if _should_use_flash(lc.use_flash, q, mask):
+        # a SLIDING layer (its bean says so) bands every program by its
+        # window; the flash program takes no band, so a sequence past
+        # the window stays on the plain one
+        band = (lc.stream_max_t if getattr(lc, "sliding", False)
+                and q.shape[2] > lc.stream_max_t else None)
+        if band is None and _should_use_flash(lc.use_flash, q, mask):
             o = _flash_attention(q, ke, ve, lc.causal)
         else:
-            o = _dense_attention(q, ke, ve, lc.causal, mask)
+            o = _dense_attention(q, ke, ve, lc.causal, mask, band)
         new_state = None
         if not train:
             # Prefill: expose the (right-aligned, fixed-size) KV
@@ -395,12 +400,11 @@ class AttentionImpl(LayerImplBase):
         bb = jnp.take_along_axis(base, g % s_ring, axis=1)
         bval = (tb >= 0) & (bb == g * bt)          # ring slot holds g
         toggle = getattr(lc, "use_flash_paged", None)
-        # the kernel scores one key head a query head: grouped KV heads
-        # take the gather program below (ROADMAP.md M2)
-        if hq == h and _should_use_flash_paged(toggle, bt, dh, t):
+        if _should_use_flash_paged(toggle, bt, dh, t):
             # fused pallas kernel (ISSUE 12; ISSUE 25: a compute
             # block is several table entries; ISSUE 30: a grid step is
-            # a row and query tile, the walk a loop inside it): each row
+            # a row and query tile, the walk a loop inside it; ISSUE 31:
+            # a KV head's group of query heads rides its tile): each row
             # walks its block list INSIDE the kernel, copying only
             # mapped and reachable pool blocks — no [B, ntab*bt, ...]
             # gather ever materializes in HBM. Same validity rule,
@@ -866,41 +870,43 @@ def _paged_walk_tiles(table, base, floor, filled, bt, tm, p_blk, t):
                np.where(hi >= lo, hi // p_blk - lo // p_blk + 1, 0))
 
 
-def paged_walk_counts(table, base, floor, filled, *, block_tokens: int,
-                      window: int, blocks_per_step: int,
-                      chunk: int = 1) -> Tuple[int, int]:
+def paged_walk_stats(table, base, floor, filled, *, block_tokens: int,
+                     window: int, blocks_per_step: int,
+                     chunk: int = 1) -> Tuple[int, int, int]:
     """What one call of the paged kernel does with a dispatch's host
-    tables: ``live``, the pool blocks it copies — entries mapped and
-    reachable by some query of a tile, summed over the query tiles —
-    and ``walked``, the pool blocks' worth of keys it scores,
-    ``blocks_per_step`` for every compute block that holds a live
-    entry. ``live / walked`` is the share of the kernel's arithmetic
-    spent on keys that exist."""
+    tables, in one pass over them: ``live``, the pool blocks it copies
+    — entries mapped and reachable by some query of a tile, summed
+    over the query tiles; ``walked``, the pool blocks' worth of keys
+    it scores, ``blocks_per_step`` for every compute block that holds
+    a live entry (``live / walked`` is the share of the kernel's
+    arithmetic spent on keys that exist); and ``steps``, the steps it
+    pays: its grid (:func:`_paged_grid`, a step a row and query tile,
+    idle rows too) plus the trips of the loop inside a step, one a
+    compute block between the first and the last entry the tile
+    reaches (those that score keys are ``walked / blocks_per_step``)."""
+    rows, tiles = _paged_grid(len(np.asarray(filled)), chunk)
     live = walked = 0
-    for hit, _ in _paged_walk_tiles(table, base, floor, filled,
-                                    block_tokens, window,
-                                    blocks_per_step, chunk):
+    steps = rows * tiles
+    for hit, trips in _paged_walk_tiles(table, base, floor, filled,
+                                        block_tokens, window,
+                                        blocks_per_step, chunk):
         live += int(hit.sum())
         walked += blocks_per_step * int(hit.reshape(
             len(hit), hit.shape[1] // blocks_per_step, blocks_per_step
         ).any(axis=2).sum())
-    return live, walked
+        steps += int(trips.sum())
+    return live, walked, steps
 
 
-def paged_steps_paid(table, base, floor, filled, *, block_tokens: int,
-                     window: int, blocks_per_step: int,
-                     chunk: int = 1) -> int:
-    """The steps one call of the paged kernel pays for a dispatch's
-    host tables: its grid (:func:`_paged_grid`, a step a row and query
-    tile, idle rows too) plus the trips of the loop inside a step, one
-    a compute block between the first and the last entry the tile
-    reaches. Those that score keys are ``walked / blocks_per_step`` of
-    :func:`paged_walk_counts`."""
-    rows, tiles = _paged_grid(len(np.asarray(filled)), chunk)
-    return rows * tiles + sum(
-        int(trips.sum()) for _, trips in _paged_walk_tiles(
-            table, base, floor, filled, block_tokens, window,
-            blocks_per_step, chunk))
+def paged_walk_counts(table, base, floor, filled, **geometry
+                      ) -> Tuple[int, int]:
+    """``(live, walked)`` of :func:`paged_walk_stats`."""
+    return paged_walk_stats(table, base, floor, filled, **geometry)[:2]
+
+
+def paged_steps_paid(table, base, floor, filled, **geometry) -> int:
+    """``steps`` of :func:`paged_walk_stats`."""
+    return paged_walk_stats(table, base, floor, filled, **geometry)[2]
 
 
 def _should_use_flash_paged(toggle, block_tokens: int,
@@ -1017,7 +1023,21 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
       them — rows with NO valid key anywhere, idle slots, emit 0 like
       the gather path's uniform-softmax-over-zeroed-values).
 
-    Shapes: q [B, H, t, dh]; pk/pv [nb, bt, H, dh] (post-scatter);
+    - GROUPED KV HEADS (ISSUE 31): the pool holds ``Hkv`` heads and
+      ``q`` ``Hq = grp x Hkv``; query head ``h`` reads KV head
+      ``h // grp``. A compute block is copied once for all of them. A
+      short tile of grouped heads takes the FLAT form: the compute
+      block lands in VMEM as one 2-D array of (key, KV head) rows, ONE
+      MXU product scores all ``t x Hq`` query rows against all of them
+      and a row keeps its own KV head's columns (``Hkv`` times the
+      products needed, no slice and no re-layout; the vector-unit form
+      at ``grp`` rows a key head read 12% of the HBM peak at group 6, a
+      head-by-head MXU form 10%: my chip run, PR 31). A longer tile is
+      handed over with a KV head's ``grp x tq`` query rows together, so
+      one MXU product a KV head scores the whole group against its
+      keys. ``grp`` 1 is the kernel of ISSUE 30, trace for trace.
+
+    Shapes: q [B, Hq, t, dh]; pk/pv [nb, bt, Hkv, dh] (post-scatter);
     bid/bval [B, ntab] int32 (pool block per logical block, validity;
     padded here to whole compute blocks, and reduced here to each
     row's first and last mapped entry); lo_blk/floor/filled/lengths
@@ -1031,14 +1051,23 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b_sz, h_sz, t, dh = q.shape
-    bt = pk.shape[1]
+    b_sz, hq_sz, t, dh = q.shape
+    bt, h_sz = pk.shape[1], pk.shape[2]
+    # grouped KV heads: query head ``h`` reads KV head ``h // grp``
+    grp = hq_sz // h_sz
     ntab = bid.shape[1]
     tq = _paged_q_tile(t)
     grid = _paged_grid(b_sz, t)
     nq = grid[1]
     total = b_sz * nq
-    short = tq <= _PAGED_SHORT_TILE
+    # a KV head's rows of a tile: its group's query heads
+    n_rows = grp * tq
+    # the three forms of a compute block's scoring (the docstring):
+    # short, one key head a query head, on the vector unit; flat, a
+    # short tile of GROUPED heads, all heads in one MXU product; tile
+    short = grp == 1 and tq <= _PAGED_SHORT_TILE
+    flat = (grp > 1 and tq <= _PAGED_SHORT_TILE
+            and h_sz & (h_sz - 1) == 0)
     p_blk = _paged_blocks_per_step(bt, h_sz, dh, pk.dtype, ntab)
     n_keys = p_blk * bt
     scale = dh ** -0.5
@@ -1052,10 +1081,30 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
         bid = jnp.pad(bid, ((0, 0), (0, pad)))
         bval = jnp.pad(bval, ((0, 0), (0, pad)))
     # a short tile is handed over (and returned) as [B, t, H, dh], the
-    # pool's own minor layout, so a query row is one [H, dh] read
+    # pool's own minor layout, so a query row is one [H, dh] read.
+    # A flat tile as [B, 1, t x Hq, dh], row ``(i x grp + g) x Hkv + h``
+    # being position ``i`` of query head ``h x grp + g``: its KV head is
+    # the row number's low bits; the pools as [nb, bt x Hkv, dh], the
+    # same bytes, so that a compute block lands in VMEM as ONE 2-D
+    # array of (key, head) rows with nothing to re-lay.
+    # A longer tile as [B x tiles, Hkv, grp x tq, dh]: a KV head's rows
+    # are its group's heads, tile by tile, so ONE MXU product a KV head
+    # scores them all
     if short:
         q = jnp.swapaxes(q, 1, 2)
-    rows = (tq, h_sz) if short else (h_sz, tq)
+    elif flat:
+        q = jnp.transpose(q.reshape(b_sz, h_sz, grp, t, dh),
+                          (0, 3, 2, 1, 4)).reshape(b_sz, 1, t * hq_sz, dh)
+        pk = pk.reshape(pk.shape[0], bt * h_sz, dh)
+        pv = pv.reshape(pv.shape[0], bt * h_sz, dh)
+    elif grp > 1:
+        q = jnp.transpose(q.reshape(b_sz, h_sz, grp, nq, tq, dh),
+                          (0, 3, 1, 2, 4, 5)).reshape(
+                              b_sz * nq, h_sz, n_rows, dh)
+    rows = ((n_rows, h_sz) if short else (1, t * hq_sz) if flat
+            else (h_sz, n_rows))
+    # a pool block's rows in the K/V scratch
+    blk_rows = bt * h_sz if flat else bt
 
     def kernel(bid_ref, bval_ref, lo_ref, floor_ref, filled_ref,
                len_ref, elo_ref, ehi_ref, q_ref, pk_ref, pv_ref, o_ref,
@@ -1092,7 +1141,7 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
                 @pl.when(live[p])
                 def _entry(p=p):
                     blk = bid_ref[bb, jj * p_blk + p]
-                    rows = pl.ds(p * bt, bt)
+                    rows = pl.ds(p * blk_rows, blk_rows)
                     go(pltpu.make_async_copy(
                         pk_ref.at[blk], kbuf.at[sl, rows], sem.at[0, sl]))
                     go(pltpu.make_async_copy(
@@ -1161,22 +1210,66 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             else:
                 jax.lax.fori_loop(0, tq, row, None)
 
-        def tile_block(k0, slot):
-            def positions(shape, dim):
-                idx = jax.lax.broadcasted_iota(jnp.int32, shape, dim)
-                pos = k0[0] + idx
-                for p in range(1, p_blk):
-                    pos = jnp.where(idx >= p * bt,
-                                    k0[p] - p * bt + idx, pos)
-                return pos
+        def positions(k0, idx):
+            """The position of key ``idx`` of the compute block, whose
+            entries start at ``k0``."""
+            pos = k0[0] + idx
+            for p in range(1, p_blk):
+                pos = jnp.where(idx >= p * bt, k0[p] - p * bt + idx, pos)
+            return pos
 
-            kpos = positions((1, n_keys), 1)
-            qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+        def flat_block(k0, slot):
+            # [key x Hkv + head, dh] as the copies left it: ONE product
+            # scores every query row against every (key, head) row, and
+            # a row keeps the columns of its own KV head. Eight times
+            # the products a head-by-head form would make, on a unit
+            # that has them to spare, and no slice or re-layout at all
+            n_q, n_col = t * hq_sz, n_keys * h_sz
+            shift = h_sz.bit_length() - 1
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, n_col), 1)
+            kpos = positions(k0, col >> shift)
+            row = jax.lax.broadcasted_iota(jnp.int32, (n_q, 1), 0)
+            qpos = q0 + functools.reduce(
+                jnp.add, [(row >= i * hq_sz).astype(jnp.int32)
+                          for i in range(1, t)], 0)
+            ok = (((col & (h_sz - 1)) == (row & (h_sz - 1)))
+                  & (kpos <= qpos) & (kpos > qpos - tm)
+                  & (kpos >= floor_ref[b]))             # [rows, cols]
+            vrow = jax.lax.broadcasted_iota(jnp.int32, (n_col, 1), 0)
+            vpos = positions(k0, vrow >> shift)
+            vlive = (vpos < written) & (vpos >= floor_ref[b])
+            vb = vbuf[slot]
+            vb = jnp.where(vlive, vb, jnp.zeros_like(vb))
+            s = jax.lax.dot_general(
+                q_ref[0, 0], kbuf[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok, s, -1e30)
+            m_prev = m_ref[0]                          # [rows, 128]
+            m_next = jnp.maximum(
+                m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.where(ok, jnp.exp(s - m_next[:, :1]), 0.0)
+            l_ref[0] = alpha * l_ref[0] + jnp.sum(p, axis=1,
+                                                  keepdims=True)
+            acc_ref[0] = (alpha[:, :1] * acc_ref[0]
+                          + jax.lax.dot_general(
+                              p.astype(vb.dtype), vb,
+                              (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32))
+            m_ref[0] = m_next
+
+        def tile_block(k0, slot):
+
+            kpos = positions(k0, jax.lax.broadcasted_iota(
+                jnp.int32, (1, n_keys), 1))
+            qrow = jax.lax.broadcasted_iota(jnp.int32, (n_rows, 1), 0)
+            qpos = q0 + (qrow % tq if grp > 1 else qrow)
             ok = ((kpos <= qpos) & (kpos > qpos - tm)
-                  & (kpos >= floor_ref[b]))                # [tq, keys]
+                  & (kpos >= floor_ref[b]))              # [rows, keys]
             # value-level masking (see docstring): one [keys, 1]
             # column — the written-span rule is q-position-independent
-            vpos = positions((n_keys, 1), 0)
+            vpos = positions(k0, jax.lax.broadcasted_iota(
+                jnp.int32, (n_keys, 1), 0))
             vlive = (vpos < written) & (vpos >= floor_ref[b])
             for h in range(h_sz):
                 kb = kbuf[slot, :, h, :]                   # [keys, dh]
@@ -1186,7 +1279,7 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
                     q_ref[0, h], kb, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
                 s = jnp.where(ok, s, -1e30)
-                m_prev = m_ref[h]                          # [tq, 128]
+                m_prev = m_ref[h]                        # [rows, 128]
                 m_next = jnp.maximum(
                     m_prev, jnp.max(s, axis=1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_next)
@@ -1222,7 +1315,8 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             k0 = [jnp.where(live[p], (lo_ref[b] + j * p_blk + p) * bt,
                             _PAGED_FAR) for p in range(p_blk)]
             pl.when(functools.reduce(jnp.logical_or, live))(
-                lambda: (short_block if short else tile_block)(k0, slot))
+                lambda: (short_block if short else flat_block if flat
+                         else tile_block)(k0, slot))
             return 1 - slot
 
         # the slot parity outlives the grid step: the next (row, tile)
@@ -1235,8 +1329,11 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
                     ).astype(o_ref.dtype)
 
     def q_map(b, i, *refs):
-        return (b, 0, 0, 0) if short else (b, 0, i, 0)
+        if short or flat:
+            return (b, 0, 0, 0)
+        return (b * nq + i, 0, 0, 0) if grp > 1 else (b, 0, i, 0)
 
+    pool_rows = (n_keys * h_sz,) if flat else (n_keys, h_sz)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=8,
         grid=grid,
@@ -1247,8 +1344,8 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
         ],
         out_specs=pl.BlockSpec((1, *rows, dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((2, n_keys, h_sz, dh), pk.dtype),
-            pltpu.VMEM((2, n_keys, h_sz, dh), pv.dtype),
+            pltpu.VMEM((2, *pool_rows, dh), pk.dtype),
+            pltpu.VMEM((2, *pool_rows, dh), pv.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((*rows, 128), jnp.float32),      # running max
             pltpu.VMEM((*rows, 128), jnp.float32),      # running sum
@@ -1264,7 +1361,16 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             vmem_limit_bytes=_PAGED_VMEM_LIMIT),
         interpret=interpret,
     )(bid, bval, lo_blk, floor, filled, lengths, e_lo, e_hi, q, pk, pv)
-    return jnp.swapaxes(o, 1, 2) if short else o
+    if short:
+        return jnp.swapaxes(o, 1, 2)
+    if flat:
+        return jnp.transpose(o.reshape(b_sz, t, grp, h_sz, dh),
+                             (0, 3, 2, 1, 4)).reshape(b_sz, hq_sz, t, dh)
+    if grp > 1:
+        return jnp.transpose(o.reshape(b_sz, nq, h_sz, grp, tq, dh),
+                             (0, 2, 3, 1, 4, 5)).reshape(
+                                 b_sz, hq_sz, t, dh)
+    return o
 
 
 def _repeat_kv_heads(q, k, v):
@@ -1301,7 +1407,7 @@ def _grouped_values(w, v):
     return o.reshape(b, hq, t, v.shape[3])
 
 
-def _dense_attention(q, k, v, causal, mask):
+def _dense_attention(q, k, v, causal, mask, window=None):
     t = q.shape[2]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(
         jnp.asarray(q.shape[-1], q.dtype)
@@ -1309,6 +1415,9 @@ def _dense_attention(q, k, v, causal, mask):
     neg = jnp.asarray(-1e30, q.dtype)
     if causal:
         cm = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            # key j seen by query i iff i - window < j <= i
+            cm = cm & ~jnp.tril(jnp.ones((t, t), bool), -window)
         scores = jnp.where(cm, scores, neg)
     if mask is not None:
         scores = jnp.where(mask[:, None, None, :] > 0, scores, neg)

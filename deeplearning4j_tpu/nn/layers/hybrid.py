@@ -2,7 +2,9 @@
 (the ``granitemoehybrid`` family): after the token embedding
 (nn/layers/embedding.py, ``sequence``), a block whose mixer is either
 grouped-KV attention or Mamba-2 and whose feed-forward is a dropless
-share of gated experts, and a tied head out.
+share of gated experts, and a tied head out. The same block, by its
+bean's options, is the ``afmoe`` family's (sliding-window and full
+attention layers in one net, below).
 
 Both keep the framework's ``[N, C, T]`` recurrent layout at their
 edges, so they compose with ``MultiLayerNetwork._forward_fn``, the
@@ -19,6 +21,25 @@ key/value heads each serve ``n_heads / n_kv_heads`` query heads, and the
 cache holds the KV heads only (``AttentionImpl._attend_core``). The
 Mamba-2 mixer is nn/layers/mamba2.py, the experts nn/layers/moe.py
 ``dropless_moe``.
+
+**The ``afmoe`` options** (all off by default). ``qk_norm``: RMSNorm
+over ``d_head`` of every query and key head, before the rotation.
+``rope_theta`` > 0: rotary positions (rotate-half) on queries and keys
+at their ABSOLUTE positions, so the cache holds rotated keys; 0 = no
+positional term. ``sliding``: ``stream_max_t`` is the model's sliding
+window (key ``j`` seen by query ``i`` iff ``i - window < j <= i``) in
+every program, the cold prefill too. ``gated_attention``: the heads'
+output times ``sigmoid(Wg a)``, ``a`` the mixer's normed input, before
+``Wo``. ``post_norms``: a second RMSNorm on each branch before it joins
+the residual (``x + N2(mixer(N1 x))``, ``x + N4(ffn(N3 x))``).
+``n_router`` 0: a DENSE layer, whose only feed-forward is the shared
+gated one of width ``d_shared``. ``gate_rule``: how the router's
+outputs become picks and gates (nn/layers/moe.py ``route``):
+``"softmax_topk"`` (the softmax over the picked logits) or
+``"sigmoid_bias"`` (sigmoid scores, picked with the per-expert
+``expert_bias`` added, normalised over the picks, times
+``route_scale``). ``TiedLMHead(tie_to=None)`` is an untied head with
+its own ``E``.
 
 **State, rows and counters.** A block's streaming state is its mixer's
 and nothing else (the attention cache, or ``{"conv", "ssm"}``). What a
@@ -49,7 +70,11 @@ from deeplearning4j_tpu.nn.conf.serde import register_bean
 from deeplearning4j_tpu.nn.layers import mamba2
 from deeplearning4j_tpu.nn.layers.attention import AttentionImpl
 from deeplearning4j_tpu.nn.layers.base import LayerImplBase
-from deeplearning4j_tpu.nn.layers.moe import dropless_moe, moe_shapes
+from deeplearning4j_tpu.nn.layers.moe import (
+    dropless_moe,
+    gated_ffn,
+    moe_shapes,
+)
 
 MIXERS = ("attention", "mamba2")
 
@@ -74,6 +99,26 @@ def _normal(key, shape, std, dtype):
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
+def rope(q, k, start, theta: float):
+    """Rotary positions (rotate-half) on ``q``/``k`` ``[N, H, T, dh]``
+    whose first position is ``start`` ``[N]``: the angles in float32,
+    one rounding back to the inputs' dtype."""
+    dh, t = q.shape[-1], q.shape[2]
+    inv = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    pos = (start[:, None] + jnp.arange(t)[None, :]).astype(jnp.float32)
+    ang = pos[:, None, :, None] * inv                   # [N, 1, T, dh/2]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
+
+    def turn(x):
+        xf = x.astype(jnp.float32)
+        half = jnp.concatenate([-xf[..., dh // 2:], xf[..., :dh // 2]],
+                               axis=-1)
+        return (xf * cos + half * sin).astype(x.dtype)
+
+    return turn(q), turn(k)
+
+
 # ---------------------------------------------------------------------
 # the tied head out
 # ---------------------------------------------------------------------
@@ -83,9 +128,11 @@ class TiedLMHead(BaseOutputLayer):
     """Conf bean: ``softmax(RMSNorm(x) @ E^T / logits_scaling)`` over
     ``[N, n_in, T]``, ``E`` being layer ``tie_to``'s ``W`` ``[n_out,
     n_in]`` (``MultiLayerNetwork._forward_fn`` hands it over as
-    ``params["E"]``; the head's own leaf is the norm's weight)."""
+    ``params["E"]``; the head's own leaf is the norm's weight).
+    ``tie_to=None``: an untied head, ``E`` its own leaf."""
 
-    tie_to: int = 0
+    tie_to: Optional[int] = 0
+    init_std: float = 0.02
     logits_scaling: float = 1.0
     rms_eps: float = 1e-5
 
@@ -93,7 +140,12 @@ class TiedLMHead(BaseOutputLayer):
 class TiedLMHeadImpl(LayerImplBase):
     @classmethod
     def init(cls, key, conf, dtype=jnp.float32) -> dict:
-        return {"norm_w": jnp.ones((conf.layer.n_in,), dtype)}
+        lc = conf.layer
+        params = {"norm_w": jnp.ones((lc.n_in,), dtype)}
+        if lc.tie_to is None:
+            params["E"] = _normal(key, (lc.n_out, lc.n_in), lc.init_std,
+                                  dtype)
+        return params
 
     @classmethod
     def logits(cls, conf, params, x):
@@ -123,7 +175,13 @@ class HybridMoeBlock(BaseRecurrentLayer):
     n_out``: ``mixer`` ("attention" or "mamba2"), then dropless top-k
     routing over ``n_router`` outputs of which this chip holds the
     experts ``experts_held = [lo, hi)`` (None = all), plus a shared
-    expert of width ``d_shared`` (0 = none)."""
+    expert of width ``d_shared`` (0 = none). The gates follow
+    ``gate_rule``: ``"softmax_topk"``, the softmax over the picked
+    logits, or ``"sigmoid_bias"``, sigmoid scores picked with a
+    per-expert bias, normalised and scaled by ``route_scale``
+    (nn/layers/moe.py ``route``). ``n_router`` 0 is a dense layer (the
+    shared feed-forward alone). The module docstring has the other
+    options."""
 
     mixer: str = "attention"
     rms_eps: float = 1e-5
@@ -137,6 +195,13 @@ class HybridMoeBlock(BaseRecurrentLayer):
     use_flash: Optional[bool] = None
     use_flash_paged: Optional[object] = None
     stream_max_t: int = 512
+    #: ``stream_max_t`` is a sliding window the model attends through
+    #: in every program (see the module docstring)
+    sliding: bool = False
+    rope_theta: float = 0.0         # 0 => no positional term
+    qk_norm: bool = False
+    gated_attention: bool = False
+    post_norms: bool = False
     # mamba2 mixer
     ssm_heads: int = 8
     ssm_d_head: int = 16
@@ -150,6 +215,8 @@ class HybridMoeBlock(BaseRecurrentLayer):
     d_expert: int = 0
     d_shared: int = 0
     experts_held: Optional[tuple] = None
+    gate_rule: str = "softmax_topk"
+    route_scale: float = 1.0
     #: the two Pallas kernels (the one-step state update, the grouped
     #: expert product): None = on a TPU, the plain programs elsewhere;
     #: True / False force; "interpret" = Pallas interpret mode
@@ -188,6 +255,10 @@ class HybridMoeBlockImpl(LayerImplBase):
             mix = {"Wq": (d, lc.n_heads * dh), "Wk": (d, lc.n_kv_heads * dh),
                    "Wv": (d, lc.n_kv_heads * dh),
                    "Wo": (lc.n_heads * dh, d)}
+            if lc.qk_norm:
+                mix.update(q_norm_w=(dh,), k_norm_w=(dh,))
+            if lc.gated_attention:
+                mix["Wg"] = (d, lc.n_heads * dh)
         elif lc.mixer == "mamba2":
             mix = mamba2.mixer_shapes(d, lc.ssm_heads, lc.ssm_d_head,
                                       lc.ssm_d_state, lc.ssm_groups,
@@ -196,9 +267,16 @@ class HybridMoeBlockImpl(LayerImplBase):
             raise ValueError(
                 f"mixer {lc.mixer!r}: expected one of {MIXERS}")
         lo, hi = lc.held
-        return {"norm1_w": (d,), **mix, "norm2_w": (d,),
-                **moe_shapes(d, lc.n_router, hi - lo, lc.d_expert,
-                             lc.d_shared)}
+        ffn = moe_shapes(d, lc.n_router, hi - lo, lc.d_expert,
+                         lc.d_shared)
+        if not lc.n_router:
+            # a dense layer: the shared feed-forward alone
+            ffn = {k: v for k, v in ffn.items() if k.startswith("Ws_")}
+        elif lc.gate_rule == "sigmoid_bias":
+            ffn["expert_bias"] = (lc.n_router,)
+        post = ({"post1_w": (d,), "post2_w": (d,)} if lc.post_norms
+                else {})
+        return {"norm1_w": (d,), **mix, "norm2_w": (d,), **ffn, **post}
 
     @classmethod
     def init(cls, key, conf, dtype=jnp.float32) -> dict:
@@ -214,7 +292,8 @@ class HybridMoeBlockImpl(LayerImplBase):
         params = {}
         for j, (name, shape) in enumerate(cls.shapes(lc).items()):
             k = jax.random.fold_in(key, j)
-            if name in ("norm1_w", "norm2_w", "norm_w", "D"):
+            if name in ("norm1_w", "norm2_w", "norm_w", "D", "post1_w",
+                        "post2_w", "q_norm_w", "k_norm_w"):
                 params[name] = jnp.ones(shape, dtype)
             elif name == "A_log":
                 params[name] = jnp.log(jax.random.uniform(
@@ -223,7 +302,7 @@ class HybridMoeBlockImpl(LayerImplBase):
                 dt = jnp.exp(jax.random.uniform(
                     k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
                 params[name] = (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-            elif name == "conv_b":
+            elif name in ("conv_b", "expert_bias"):
                 params[name] = jnp.zeros(shape, dtype)
             else:
                 params[name] = _normal(k, shape, lc.init_std, dtype)
@@ -241,6 +320,16 @@ class HybridMoeBlockImpl(LayerImplBase):
         q = heads(params["Wq"], lc.n_heads)
         k = heads(params["Wk"], lc.n_kv_heads)
         v = heads(params["Wv"], lc.n_kv_heads)
+        if lc.qk_norm:
+            q = rms_norm(q, params["q_norm_w"], lc.rms_eps)
+            k = rms_norm(k, params["k_norm_w"], lc.rms_eps)
+        start = None
+        if lc.rope_theta:
+            # the chunk's first absolute position, a row: the paged
+            # tables' ``filled``, the dense row cache's ``pos``
+            start = (jnp.zeros((n,), jnp.int32) if state is None
+                     else state["filled" if "pk" in state else "pos"])
+            q, k = rope(q, k, start, lc.rope_theta)
         if lc.attention_multiplier:
             # the core divides by sqrt(d_head): hand it q scaled so
             # that the scores come out times the multiplier
@@ -248,7 +337,16 @@ class HybridMoeBlockImpl(LayerImplBase):
                 lc.attention_multiplier * math.sqrt(dh))).astype(q.dtype)
         o, state = AttentionImpl._attend_core(lc, q, k, v, state, train,
                                               mask)
+        if start is not None and state is not None and "pk" not in state:
+            # the dense row cache caps ``filled`` at the window: the
+            # absolute position rides beside it
+            written = (t if mask is None
+                       else jnp.sum(mask.astype(jnp.int32), axis=1))
+            state = dict(state, pos=start + written)
         o = jnp.transpose(o, (0, 2, 1, 3)).reshape(n, t, lc.n_heads * dh)
+        if lc.gated_attention:
+            gate = jax.nn.sigmoid((hn @ params["Wg"]).astype(jnp.float32))
+            o = (o.astype(jnp.float32) * gate).astype(o.dtype)
         return o @ params["Wo"], state
 
     @classmethod
@@ -273,6 +371,8 @@ class HybridMoeBlockImpl(LayerImplBase):
             counts["ssm_state_rows"] = (
                 jnp.asarray(n, jnp.int32) if live is None
                 else jnp.sum((live > 0).astype(jnp.int32)))
+        if lc.post_norms:
+            mixed = rms_norm(mixed, params["post1_w"], lc.rms_eps)
         xt = _residual(xt, mixed, lc.residual_multiplier)
 
         valid = None
@@ -282,15 +382,22 @@ class HybridMoeBlockImpl(LayerImplBase):
             rows = jnp.broadcast_to((live > 0)[:, None], (n, t))
             valid = rows if valid is None else valid & rows
         h2 = rms_norm(xt, params["norm2_w"], lc.rms_eps)
-        y, moe_counts = dropless_moe(
-            params, h2.reshape(n * t, d),
-            None if valid is None else valid.reshape(n * t),
-            top_k=lc.top_k, experts_held=lc.held,
-            kernel=lc.use_kernels)
-        xt = _residual(xt, y.reshape(n, t, d), lc.residual_multiplier)
-        if counters is not None:
+        if lc.n_router:
+            y, moe_counts = dropless_moe(
+                params, h2.reshape(n * t, d),
+                None if valid is None else valid.reshape(n * t),
+                top_k=lc.top_k, experts_held=lc.held,
+                kernel=lc.use_kernels, gate_rule=lc.gate_rule,
+                route_scale=lc.route_scale)
             counts.update(moe_counts,
                           moe_layer_steps=jnp.asarray(1, jnp.int32))
+        else:
+            y = gated_ffn(h2, params["Ws_in"], params["Ws_out"])
+        y = y.reshape(n, t, d)
+        if lc.post_norms:
+            y = rms_norm(y, params["post2_w"], lc.rms_eps)
+        xt = _residual(xt, y, lc.residual_multiplier)
+        if counters is not None:
             for name, v in counts.items():
                 counters[name] = counters.get(name, 0) + v
 

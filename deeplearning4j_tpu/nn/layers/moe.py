@@ -23,10 +23,13 @@ models (nn/layers/hybrid.py holds it in a block): top-k routing over
 ALL of the router's outputs with no capacity and no dropped token,
 gated (SwiGLU) experts, a shared expert every token passes, and
 ``experts_held``, the range of the router's outputs whose experts this
-chip holds. The chip computes ``sum over held picked experts of gate x
-expert(h)``; a pick that falls on an expert held elsewhere adds nothing
-here. The (token, pick) pairs are sorted by expert and ONE grouped
-product a matrix runs over the held experts (:func:`grouped_product`:
+chip holds. The gates follow one of two rules (:func:`route`): the
+softmax over the picked logits, or sigmoid scores picked with a
+per-expert selection bias, normalised and scaled. The chip computes
+``sum over held picked experts of gate x expert(h)``; a pick that falls
+on an expert held elsewhere adds nothing here. The (token, pick) pairs
+are sorted by expert and ONE grouped product a matrix runs over the
+held experts (:func:`grouped_product`:
 the Pallas grouped matmul ``megablox.gmm`` on a TPU, which reads the
 weights of an expert once for every tile of rows that reaches it and
 never for an expert no row picked; ``jax.lax.ragged_dot`` elsewhere).
@@ -193,8 +196,38 @@ def moe_shapes(width: int, n_router: int, n_held: int, d_expert: int,
     return shapes
 
 
+#: the gate rules of :func:`dropless_moe`, by name
+GATE_RULES = ("softmax_topk", "sigmoid_bias")
+
+
+def route(logits, top_k: int, rule: str = "softmax_topk", bias=None,
+          scale: float = 1.0):
+    """``(gates [M, k] float32, idx [M, k])`` from the router's float32
+    ``logits`` ``[M, E]`` under a gate rule:
+
+    - ``"softmax_topk"``: pick the ``top_k`` largest logits, the gates
+      are the softmax over the picked logits (granitemoehybrid);
+    - ``"sigmoid_bias"``: score ``s = sigmoid(logits)``, pick the
+      ``top_k`` largest ``s + bias`` (``bias`` ``[E]``, a selection
+      term only), the gates are the picked SCORES over their sum
+      (plus 1e-20), times ``scale`` (afmoe, DeepSeek-V3's router)."""
+    if rule == "softmax_topk":
+        top, idx = jax.lax.top_k(logits, top_k)
+        return jax.nn.softmax(top, axis=-1), idx
+    if rule != "sigmoid_bias":
+        raise ValueError(f"gate rule {rule!r}: expected one of "
+                         f"{GATE_RULES}")
+    score = jax.nn.sigmoid(logits)
+    pick = score if bias is None else score + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(pick, top_k)
+    g = jnp.take_along_axis(score, idx, axis=-1)
+    return g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20) * scale, idx
+
+
 def dropless_moe(params, tokens, valid=None, *, top_k: int,
-                 experts_held: Tuple[int, int], kernel=None):
+                 experts_held: Tuple[int, int], kernel=None,
+                 gate_rule: str = "softmax_topk",
+                 route_scale: float = 1.0):
     """Routed plus shared experts on ``tokens`` ``[M, D]``.
 
     ``params``: ``router`` ``[D, E]`` (all ``E`` outputs, whatever is
@@ -204,8 +237,9 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
     ``valid`` ``[M]`` marks the tokens that exist (padding and idle
     rows route nowhere and count nowhere).
 
-    Returns ``(y [M, D], counts)``: the gates are the softmax over each
-    token's ``top_k`` router logits (float32); no token is dropped,
+    Returns ``(y [M, D], counts)``: the gates follow ``gate_rule``
+    (:func:`route`; ``"sigmoid_bias"`` reads ``params["expert_bias"]``
+    ``[E]`` and ``route_scale``), in float32; no token is dropped,
     whatever the load. ``counts`` are int32 scalars: ``moe_picks`` (token
     x pick pairs routed), ``moe_picks_held`` (those on held experts),
     ``moe_experts_touched`` (held experts with at least one row),
@@ -219,8 +253,8 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
             f"layer holds {params['We_in'].shape[0]}")
     logits = jnp.dot(tokens, params["router"],
                      preferred_element_type=jnp.float32)
-    top, idx = jax.lax.top_k(logits, top_k)                  # [M, k]
-    gates = jax.nn.softmax(top, axis=-1)
+    gates, idx = route(logits, top_k, gate_rule,              # [M, k]
+                       params.get("expert_bias"), route_scale)
     routed = (jnp.ones((m, 1), bool) if valid is None
               else valid.astype(bool)[:, None])
     held = (idx >= lo) & (idx < hi) & routed

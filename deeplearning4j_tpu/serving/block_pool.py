@@ -94,15 +94,19 @@ class BlockTable:
         bug, not load."""
         table = np.full(ring_slots, -1, np.int32)
         base = np.full(ring_slots, -1, np.int32)
-        for g, bid in self.blocks.items():
-            s = g % ring_slots
-            if table[s] != -1:
-                raise AssertionError(
-                    f"ring collision at slot {s}: logical blocks "
-                    f"{base[s] // self.block_tokens} and {g} both "
-                    "live — expired blocks were not freed")
-            table[s] = bid
-            base[s] = g * self.block_tokens
+        n = len(self.blocks)
+        if not n:
+            return table, base
+        # (one pass of numpy: a long context maps a thousand blocks)
+        gs = np.fromiter(self.blocks.keys(), np.int64, n)
+        s = gs % ring_slots
+        table[s] = np.fromiter(self.blocks.values(), np.int64, n)
+        base[s] = gs * self.block_tokens
+        if np.count_nonzero(table >= 0) != n:
+            raise AssertionError(
+                f"ring collision: logical blocks {sorted(self.blocks)} "
+                f"do not fit {ring_slots} ring slots — expired blocks "
+                "were not freed")
         return table, base
 
     def coverage(self, g: int) -> int:
@@ -113,6 +117,44 @@ class BlockTable:
         lo = max(self.floor, g * bt)
         hi = min(self.length, (g + 1) * bt)
         return max(0, hi - lo)
+
+
+class KindTables:
+    """One logical sequence's block tables as the engine holds them for
+    a slot or an admission: a :class:`BlockTable` a layer KIND (the
+    attention layers of one window), widest window first; a list of
+    one for a net whose layers agree. Every kind's table has the
+    sequence's ``length``; each holds only the blocks its window can
+    still reach, ids of ITS kind's pool. Where the engine does not care
+    about kinds this reads like a ``BlockTable``: ``length``, ``floor``
+    (the widest kind's) and ``blocks``, every kind's, keyed
+    ``(kind, g)``. (The trie, the tiers, transfer and snapshots hold
+    plain ``BlockTable``s: one kind's, ``kinds[0]``.)"""
+
+    def __init__(self, tabs):
+        self.kinds = list(tabs)
+
+    @property
+    def block_tokens(self) -> int:
+        return self.kinds[0].block_tokens
+
+    @property
+    def length(self) -> int:
+        return self.kinds[0].length
+
+    @length.setter
+    def length(self, n: int) -> None:
+        for tab in self.kinds:
+            tab.length = n
+
+    @property
+    def floor(self) -> int:
+        return self.kinds[0].floor
+
+    @property
+    def blocks(self) -> Dict[Tuple[int, int], int]:
+        return {(k, g): bid for k, tab in enumerate(self.kinds)
+                for g, bid in tab.blocks.items()}
 
 
 class BlockPool:
@@ -242,16 +284,17 @@ class BlockPool:
         out of windows). ``tables`` iterates every live
         :class:`BlockTable` (slots, pending admissions, trie entries);
         shared blocks count once."""
-        best: Dict[int, int] = {}
+        bt = self.block_tokens
+        best = np.zeros(self.n_blocks, np.int64)
         for tab in tables:
-            if tab is None:
+            if tab is None or not tab.blocks:
                 continue
-            for g, bid in tab.blocks.items():
-                cov = tab.coverage(g)
-                if cov > best.get(bid, -1):
-                    best[bid] = cov
-        frag = 0
-        for bid in range(self.n_blocks):
-            if self._ref[bid] > 0:
-                frag += self.block_tokens - best.get(bid, 0)
-        return frag
+            # (``BlockTable.coverage`` over all of a table's blocks in
+            # one pass of numpy: this runs every round)
+            n = len(tab.blocks)
+            gs = np.fromiter(tab.blocks.keys(), np.int64, n)
+            bids = np.fromiter(tab.blocks.values(), np.int64, n)
+            cov = (np.minimum(tab.length, (gs + 1) * bt)
+                   - np.maximum(tab.floor, gs * bt))
+            np.maximum.at(best, bids, cov)
+        return int((bt - best[self._ref > 0]).sum())

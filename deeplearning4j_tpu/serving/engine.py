@@ -131,6 +131,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import itertools
 import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
@@ -143,11 +144,14 @@ from deeplearning4j_tpu.nn.layers.attention import (
     _paged_blocks_per_step,
     _paged_table_entries,
     guard_streamable,
-    paged_steps_paid,
-    paged_walk_counts,
+    paged_walk_stats,
 )
 from deeplearning4j_tpu.nn.streaming import scan_length_bucket
-from deeplearning4j_tpu.serving.block_pool import BlockPool, BlockTable
+from deeplearning4j_tpu.serving.block_pool import (
+    BlockPool,
+    BlockTable,
+    KindTables,
+)
 from deeplearning4j_tpu.serving.faults import FaultEvent, FaultPlan, poison_rows
 from deeplearning4j_tpu.serving.prefix_cache import RadixPrefixCache
 from deeplearning4j_tpu.serving.sampler import (
@@ -210,7 +214,7 @@ class _Pending:
     #: (suffix chunks then append THROUGH it, zero-copy), or None
     #: until a cold admission's dense prefill completes and scatters
     #: into freshly allocated blocks
-    tab: Optional[BlockTable] = None
+    tab: Optional[KindTables] = None
     #: what each prefill program counted (device scalars), added to
     #: ``stats`` when the first token is fetched
     counts: List[Any] = dataclasses.field(default_factory=list)
@@ -527,16 +531,41 @@ def _lm_shape_of(net):
     return forward, vocab, beans
 
 
-def _unpack_tables(tabs):
+def _unpack_tables(tabs, rings=None):
     """The four block-table operands of a paged dispatch
-    (``AttentionImpl._paged_attend`` says what each holds) out of the
-    ONE int32 array ``[B, 2 S + 2]`` they travel in: ``table`` and
-    ``base`` ``[B, S]``, then a column each of ``floor`` and
-    ``filled``. Slices of a device array inside a program, writable
-    views of a numpy array on the host."""
-    s = (tabs.shape[1] - 2) // 2
-    return {"table": tabs[:, :s], "base": tabs[:, s:2 * s],
-            "floor": tabs[:, 2 * s], "filled": tabs[:, 2 * s + 1]}
+    (``AttentionImpl._paged_attend`` says what each holds), a dict a
+    layer KIND, out of the ONE int32 array they travel in: for each
+    kind ``table`` and ``base`` ``[B, S_k]``, then a ``floor`` column a
+    kind, then ONE ``filled`` column, the same for every kind (one
+    kind: ``[B, 2 S + 2]``). ``rings`` are the kinds' ring widths
+    ``S_k`` (None: one kind, its width read off the shape). Slices of a
+    device array inside a program, writable views of a numpy array on
+    the host."""
+    if rings is None:
+        rings = ((tabs.shape[1] - 2) // 2,)
+    floors = 2 * sum(rings)
+    out, at = [], 0
+    for k, s in enumerate(rings):
+        out.append({"table": tabs[:, at:at + s],
+                    "base": tabs[:, at + s:at + 2 * s],
+                    "floor": tabs[:, floors + k],
+                    "filled": tabs[:, floors + len(rings)]})
+        at += 2 * s
+    return out
+
+
+@dataclasses.dataclass
+class _KvKind:
+    """The attention layers of one window (``stream_max_t``): they
+    share a block table a slot, and a pool of blocks that no other kind
+    allocates from. ``ring`` is the table's ring width, ``slot_worst``
+    the most blocks one slot can hold of it."""
+
+    window: int
+    layers: List[str]
+    ring: int = 0
+    slot_worst: int = 0
+    pool: Optional[BlockPool] = None
 
 
 class DecodeEngine:
@@ -814,7 +843,20 @@ class DecodeEngine:
         if not windows:
             raise ValueError(
                 "DecodeEngine requires at least one attention layer")
-        self.window = min(windows)
+        #: the attention layers by KIND, one a window, widest first: a
+        #: slot has a block table a kind, and a kind its own pool of
+        #: blocks, so that a narrow window's layers hold only what they
+        #: can still reach (one kind: every net whose layers agree)
+        self._kinds: List[_KvKind] = [
+            _KvKind(w, [name for name, bean in attn_items
+                        if bean.stream_max_t == w])
+            for w in sorted(set(windows), reverse=True)]
+        # the longest prompt: the narrowest window where a cold
+        # admission prefills a dense row (it takes no band); the widest
+        # where several kinds make every admission a paged one, whose
+        # programs band each layer by its own window
+        self.window = (min(windows) if len(self._kinds) == 1
+                       else max(windows))
         #: token ids in through a gather (the first layer embeds them)
         #: or, for a net whose first layer takes ``n_in == vocab``
         #: columns, one-hot
@@ -836,6 +878,30 @@ class DecodeEngine:
                  "recurrent state"),
                 ("fused_rounds", fused_rounds,
                  "the fused scan has no counters or live-row operand")]
+        if len(self._kinds) > 1:
+            two = "over two kinds of KV block"
+            refused += [
+                ("prefix_cache_rows", prefix_cache_rows,
+                 f"the prefix trie leases one kind of block, not a "
+                 f"prefix {two}"),
+                ("kv_host_tier_bytes", kv_host_tier_bytes,
+                 f"the spill tier has no payload {two}"),
+                ("kv_disk_tier_path", kv_disk_tier_path,
+                 f"the spill tier has no payload {two}"),
+                ("spec_draft_len", spec_draft_len,
+                 f"a rejected draft's rewind {two} is untested"),
+                ("fused_rounds", fused_rounds,
+                 f"blocks expire between a fused scan's rounds {two}"),
+                ("paranoid", paranoid,
+                 f"the health sweep reads one pool, not one {two}"),
+                ("fault_plan", fault_plan,
+                 f"fault injection poisons one pool, not one {two}")]
+            if self._state_layers:
+                raise ValueError(
+                    f"layers {self._state_layers} carry a slot state "
+                    f"and the attention layers have windows "
+                    f"{[k.window for k in self._kinds]}: a paged "
+                    "admission does not carry slot state yet")
         unsharded = [name for name, bean in beans
                      if not getattr(bean, "shards_over_tp", True)]
         if unsharded:
@@ -844,10 +910,11 @@ class DecodeEngine:
                  "experts and grouped KV heads are not sharded over tp"))
         for option, value, why in refused:
             if value:
+                layers = (self._state_layers or unsharded
+                          or [k.layers for k in self._kinds])
                 raise ValueError(
                     f"{option}={value!r} is not supported for this net "
-                    f"(layers {self._state_layers or unsharded}): "
-                    f"{why}")
+                    f"(layers {layers}): {why}")
         # -- tensor-parallel head sharding (ISSUE 12; default tp=1 =
         # the bit-identical single-chip engine) -----------------------
         if tp < 1:
@@ -916,9 +983,8 @@ class DecodeEngine:
         self.tenant_stats: Dict[str, Dict[str, int]] = {}
         # -- the KV block pool (ISSUE 6) ------------------------------
         self.block_tokens = bt = int(block_tokens)
-        self._wmax = max(windows)      # widest layer window (block
-        #                                lifetimes honour every layer)
-        self._kv_tabs: List[Optional[BlockTable]] = (
+        self._wmax = max(windows)      # the widest kind's window
+        self._kv_tabs: List[Optional[KindTables]] = (
             [None] * self.n_slots)
         if bt < 1 or (bt & (bt - 1)):
             raise ValueError(
@@ -938,31 +1004,54 @@ class DecodeEngine:
         round_write = max(
             self.decode_chunk + self.spec_draft_len + 1,
             self.fused_rounds * self.decode_chunk)
-        self._ring_slots = (
-            -(-self._wmax // bt) + -(-self.window // bt)
-            + -(-round_write // bt) + 3)
-        # one slot's worst-case residency: a full window of
-        # blocks, one round of decode/verify appends, plus
-        # boundary slack (ring width above is ADDRESSING span,
-        # not occupancy — slid-out blocks free as they expire)
-        slot_worst = (-(-self._wmax // bt)
-                      + -(-round_write // bt) + 3)
+        # (with several kinds every admission is paged, and a chunked
+        # one's widest dispatch is its chunk)
+        dispatch = (self.window if len(self._kinds) == 1
+                    else self.prefill_chunk or self.window)
+        for kind in self._kinds:
+            kind.ring = (
+                -(-kind.window // bt) + -(-dispatch // bt)
+                + -(-round_write // bt) + 3)
+            # one slot's worst-case residency: a full window of
+            # blocks, one dispatch of appends, plus boundary slack
+            # (the ring width is ADDRESSING span, not occupancy:
+            # slid-out blocks free as they expire). A one-kind net's
+            # prompts fit its window, so there the dispatch is one
+            # round of decode/verify writes
+            kind.slot_worst = (-(-kind.window // bt)
+                               + -(-(round_write if kind.window
+                                     >= self.window else
+                                     max(round_write, dispatch)) // bt)
+                               + 3)
+        self._ring_slots = self._kinds[0].ring
+        slot_worst = sum(k.slot_worst for k in self._kinds)
         if kv_blocks is None:
             # default: a whole window for every slot and every trie
-            # entry, with per-slot append slack
+            # entry, with per-slot append slack, of every kind
             kv_blocks = max(
-                -(-self._wmax // bt)
+                sum(-(-k.window // bt) for k in self._kinds)
                 * (self.n_slots + int(prefix_cache_rows))
-                + self.n_slots * (-(-round_write // bt) + 2),
+                + len(self._kinds) * self.n_slots
+                * (-(-round_write // bt) + 2),
                 slot_worst)
+        #: blocks of all kinds together; several kinds share them out
+        #: by what a slot can hold of each (``slot_worst``), so that
+        #: every kind runs out at the same number of full slots
         self.kv_blocks = int(kv_blocks)
         if self.kv_blocks < slot_worst:
             raise ValueError(
                 f"kv_blocks {self.kv_blocks} cannot hold one "
                 f"slot's window + one round of writes "
                 f"({slot_worst} blocks of {bt} tokens)")
-        self.block_pool = BlockPool(self.kv_blocks, bt,
-                                    jit_wrap=self._jit)
+        left = self.kv_blocks
+        for i, kind in enumerate(self._kinds):
+            n = (left if i == len(self._kinds) - 1 else max(
+                kind.slot_worst,
+                self.kv_blocks * kind.slot_worst // slot_worst))
+            kind.pool = BlockPool(n, bt, jit_wrap=self._jit)
+            left -= n
+        #: the widest kind's allocator (a one-kind net's only one)
+        self.block_pool = self._kinds[0].pool
         #: the prefix trie: entries lease pool BLOCKS (zero-copy); the
         #: row count caps entries, the block pool caps bytes
         self.prefix_cache = (
@@ -1093,6 +1182,8 @@ class DecodeEngine:
         #: decode dispatch beside the pool's KV leaves
         self._slot_state: Dict[str, Any] = {}
         self._toks = None                 # [B] int32 current tokens
+        #: ``_uploaded``'s (host copy, device array) by operand
+        self._sent: Dict[str, Tuple[np.ndarray, Any]] = {}
         self._temps = np.zeros(self.n_slots, np.float32)
         self._top_ks = np.full(self.n_slots, self.vocab, np.int32)
         self._round = 0
@@ -1130,6 +1221,14 @@ class DecodeEngine:
             # operand, summed over paged dispatches: one a dispatch,
             # whatever the number of paged layers (ISSUE 28)
             "table_uploads": 0,
+            # by layer kind (its window): the kind's part of
+            # ``paged_blocks_live`` and, summed over rounds, the blocks
+            # its live contexts span and hold (``_count_kv_held``)
+            **{f"{name}_w{k.window}": 0
+               for k in self._kinds
+               for name in ("paged_blocks_live",
+                            "prefill_paged_blocks_live",
+                            "kv_blocks_spanned", "kv_blocks_held")},
             # what the jitted programs count themselves and return
             # with the tokens (nn/layers/hybrid.py ``counters``):
             # routed (row, pick) pairs, those on held experts, held
@@ -1188,6 +1287,7 @@ class DecodeEngine:
     def _build_jits(self):
         forward, chunk = self._forward, self.decode_chunk
         ids_in = self._ids_in
+        rings = tuple(k.ring for k in self._kinds)
 
         def encode(tok):
             # one position a row for the net's first layer: the ids
@@ -1199,14 +1299,17 @@ class DecodeEngine:
 
         def seen(pool, tabs, filled=None):
             # the per-layer state the forward pass sees: every paged
-            # layer's pool leaves beside the dispatch's ONE set of
-            # block tables (``tabs``: ``_paged_tables``' packed
-            # operand, unpacked here). ``filled`` is a scan's carried
-            # copy of the only table operand a step advances
-            shared = _unpack_tables(tabs)
-            if filled is not None:
-                shared["filled"] = filled
-            return {name: dict(st, **shared) if "pk" in st else st
+            # layer's pool leaves beside its KIND's block tables, ONE
+            # set a kind and dispatch (``tabs``: ``_paged_tables``'
+            # packed operand, unpacked here). ``filled`` is a scan's
+            # carried copy of the only table operand a step advances
+            shared = {}
+            for kind, ops in zip(self._kinds,
+                                 _unpack_tables(tabs, rings)):
+                if filled is not None:
+                    ops["filled"] = filled
+                shared.update(dict.fromkeys(kind.layers, ops))
+            return {name: dict(st, **shared[name]) if "pk" in st else st
                     for name, st in pool.items()}
 
         def kept(rnn):
@@ -1231,17 +1334,24 @@ class DecodeEngine:
             # VALID position
             length = jnp.sum(mask.astype(jnp.int32), axis=1)
             cold = tabs is None
+            rows = {}
             if not cold:
                 rnn = seen(rnn, tabs)
+                if self._wants_live:
+                    # an admission's row holds a request, whatever its
+                    # tables say (a paged one's first chunk starts at
+                    # ``filled`` 0, which reads as an idle slot)
+                    rows["live"] = jnp.ones(x.shape[:1], jnp.int32)
             if ids_in:
                 # the head at the sampled position only: a vocabulary
                 # this wide is not worth a column per prompt position
                 out, new_rnn, counts = forward(
-                    params, state, x, mask, rnn, head_at=length - 1)
+                    params, state, x, mask, rnn, head_at=length - 1,
+                    **rows)
                 probs = out[:, :, 0]
             else:
                 out, new_rnn, counts = forward(params, state, x, mask,
-                                               rnn)
+                                               rnn, **rows)
                 probs = jnp.take_along_axis(
                     out, (length - 1)[:, None, None], axis=2)[:, :, 0]
             tok = sample_tokens(probs, temp, top_k, key)
@@ -1877,31 +1987,37 @@ class DecodeEngine:
             self.spec.drop(slot)
 
     # -- paged block-pool plumbing (ISSUE 6) ---------------------------
-    def _release_block(self, bid: int) -> None:
-        """Drop one reference to a pool block; a block whose LAST
-        reference drops is returned to the free list — scrubbed first
-        if the paranoid sweep flagged it (never scrubbed while an
-        innocent sharer still reads it)."""
-        if self.block_pool.deref(bid):
-            if bid in self.block_pool.poisoned and self._pool is not None:
-                self._pool = self.block_pool.scrub_block_device(
-                    self._pool, bid)
+    def _release_block(self, bid: int,
+                       kind: Optional[_KvKind] = None) -> None:
+        """Drop one reference to a block of ``kind``'s pool (the
+        widest's, a one-kind net's only one, where none is named); a
+        block whose LAST reference drops is returned to the free list —
+        scrubbed first if the paranoid sweep flagged it (never scrubbed
+        while an innocent sharer still reads it; the sweep runs for
+        one-kind nets only)."""
+        pool = (kind or self._kinds[0]).pool
+        if pool.deref(bid):
+            if bid in pool.poisoned and self._pool is not None:
+                self._pool = pool.scrub_block_device(self._pool, bid)
 
-    def _free_table(self, tab: Optional[BlockTable]) -> None:
+    def _free_table(self, tab: Optional[KindTables]) -> None:
         if tab is None:
             return
-        for bid in list(tab.blocks.values()):
-            self._release_block(bid)
-        tab.blocks.clear()
+        for kind, t in zip(self._kinds, tab.kinds):
+            for bid in list(t.blocks.values()):
+                self._release_block(bid, kind)
+            t.blocks.clear()
 
-    def _paged_reserve(self, n: int, protect=()) -> bool:
-        """Make ``n`` blocks allocatable: first evict LRU prefix-trie
+    def _paged_reserve(self, n: int, protect=(),
+                       kind: Optional[_KvKind] = None) -> bool:
+        """Make ``n`` blocks of ``kind``'s pool allocatable: first
+        evict LRU prefix-trie
         entries (references only — shared blocks stay resident), then
         preempt the youngest unprotected slot(s), requeueing their
         requests (greedy re-admissions regenerate identical ids, so
         preemption is invisible to results — the continuous-batching
         analogue of vLLM's recompute preemption)."""
-        pool = self.block_pool
+        pool = (kind or self._kinds[0]).pool
         while pool.free_blocks < n and self.prefix_cache is not None:
             if not self.prefix_cache.evict_one():
                 break
@@ -1949,7 +2065,7 @@ class DecodeEngine:
             clock.new_attempt(self._clock(), "preempted")
         self._requeue.append((self._round + 1, state.request))
 
-    def _ensure_tab(self, tab: BlockTable, n_tokens: int,
+    def _ensure_tab(self, tab: KindTables, n_tokens: int,
                     protect=(), rid: Optional[int] = None) -> bool:
         """Make ``tab`` writable for the next ``n_tokens`` appends:
         copy-on-write the partial tail block if the trie or another
@@ -1965,11 +2081,19 @@ class DecodeEngine:
         construction — after evicting/preempting everything else a
         lone admission can always proceed (no defer livelock) — and
         one dispatch can never wrap the ring onto itself."""
-        pool = self.block_pool
+        return all(self._ensure_kind(kind, t, n_tokens, protect, rid)
+                   for kind, t in zip(self._kinds, tab.kinds))
+
+    def _ensure_kind(self, kind: _KvKind, tab: BlockTable,
+                     n_tokens: int, protect, rid) -> bool:
+        """:meth:`_ensure_tab` for one kind's table and pool (a block
+        is shared, and so copied on write, in a one-kind net only: the
+        trie is refused to any other)."""
+        pool = kind.pool
         tail = tab.tail_block() if n_tokens > 0 else None
         cow = tail is not None and pool.refcount(tail[1]) > 1
         need = len(tab.new_logical_blocks(n_tokens)) + (1 if cow else 0)
-        if need and not self._paged_reserve(need, protect):
+        if need and not self._paged_reserve(need, protect, kind):
             return False
         if cow:
             g, src = tab.tail_block()
@@ -1979,27 +2103,51 @@ class DecodeEngine:
                 self._pool = pool.copy_block_device(self._pool, src,
                                                     dst)
             tab.blocks[g] = dst
-            self._release_block(src)
+            self._release_block(src, kind)
         for g in tab.new_logical_blocks(n_tokens):
-            old = g - self._ring_slots
+            old = g - kind.ring
             if old in tab.blocks:   # safety: expired ring predecessor
-                self._release_block(tab.blocks.pop(old))
+                self._release_block(tab.blocks.pop(old), kind)
             bid = pool.alloc()
             if bid is None:
                 raise AssertionError("reserved allocation failed")
             tab.blocks[g] = bid
         return True
 
-    def _free_expired_blocks(self, tab: BlockTable) -> None:
-        """Release blocks that slid entirely out of every layer's
-        window (length is monotone within a round — the verify rewind
-        lands before this runs — so a released block can never swing
-        back into reach)."""
-        for g in sorted(tab.blocks):
-            if (g + 1) * self.block_tokens <= tab.length - self._wmax:
-                self._release_block(tab.blocks.pop(g))
-            else:
-                break
+    def _free_expired_blocks(self, tab: KindTables) -> None:
+        """Release, kind by kind, the blocks that slid entirely out of
+        the kind's window, each to its kind's pool (length is monotone
+        within a round — the verify rewind lands before this runs — so
+        a released block can never swing back into reach)."""
+        for kind, t in zip(self._kinds, tab.kinds):
+            edge = t.length - kind.window
+            if edge < self.block_tokens:
+                continue    # the context has not left the window yet
+            for g in itertools.takewhile(
+                    lambda g: (g + 1) * self.block_tokens <= edge,
+                    sorted(t.blocks)):
+                self._release_block(t.blocks.pop(g), kind)
+
+    def _count_kv_held(self, active: List[int]) -> None:
+        """By kind, summed over rounds as ``occupancy_sum`` is:
+        ``kv_blocks_spanned_w<window>``, the blocks the live contexts
+        span, and ``kv_blocks_held_w<window>``, those of them the
+        slots' tables still map (blocks reserved ahead of the context
+        are neither). The difference is what the kind's window
+        released."""
+        bt = self.block_tokens
+        for k, kind in enumerate(self._kinds):
+            spanned = held = 0
+            for slot in active:
+                t = self._kv_tabs[slot].kinds[k]
+                span = -(-t.length // bt)
+                ahead = span
+                while ahead in t.blocks:
+                    ahead += 1
+                spanned += span
+                held += len(t.blocks) - (ahead - span)
+            self.stats[f"kv_blocks_spanned_w{kind.window}"] += spanned
+            self.stats[f"kv_blocks_held_w{kind.window}"] += held
 
     def _split_row(self, rnn1):
         """A dense B=1 prefill state split into (its attention layers'
@@ -2009,7 +2157,7 @@ class DecodeEngine:
         return kv, {n: rnn1[n] for n in self._state_layers}
 
     def _write_row(self, rnn1, length: int,
-                   slot: Optional[int] = None) -> Optional[BlockTable]:
+                   slot: Optional[int] = None) -> Optional[KindTables]:
         """A cold admission's (or a restore's) one whole-row write: a
         fresh BlockTable covering the last ``min(length, wmax)``
         absolute positions (what a dense B=1 prefill row holds), the
@@ -2017,6 +2165,8 @@ class DecodeEngine:
         state into the slot's row (a trie entry being re-primed has no
         slot, and no such state). None when the pool cannot be
         relieved."""
+        self._one_kind_only("writing a dense prefill row into block "
+                            "tables (restore, a trie entry's re-priming)")
         self._ensure_paged_pool(rnn1)
         bt = self.block_tokens
         floor = max(0, length - self._wmax)
@@ -2035,7 +2185,7 @@ class DecodeEngine:
             with self._span("serving.state_admit", slot=slot):
                 self._slot_state = self._state_admit_jit(
                     self._slot_state, row, jnp.asarray(slot, jnp.int32))
-        return tab
+        return KindTables([tab])
 
     def _live_operand(self):
         """``(live,)`` for the decode program of a net some layer of
@@ -2045,8 +2195,18 @@ class DecodeEngine:
         has no such operand."""
         if not self._wants_live:
             return ()
-        return (jnp.asarray([int(s is not None) for s in self._slots],
-                            jnp.int32),)
+        return (self._uploaded("live", np.asarray(
+            [s is not None for s in self._slots], np.int32)),)
+
+    def _uploaded(self, name: str, host: np.ndarray):
+        """A round's small per-slot operand on the device: uploaded
+        anew only when it differs from what the last dispatch sent
+        (temperatures, top-k and the live mask change with an admission
+        or an eviction, not with a round; no program donates them)."""
+        sent = self._sent.get(name)
+        if sent is None or not np.array_equal(sent[0], host):
+            sent = self._sent[name] = (host.copy(), jnp.asarray(host))
+        return sent[1]
 
     def _add_counts(self, counts, prefill: bool = False) -> None:
         """What a program counted (device scalars, ready with its
@@ -2062,57 +2222,71 @@ class DecodeEngine:
         """The block-table operand of a paged dispatch of ``chunk``
         query positions a row: each row's ring-projected block table,
         its floor and its length (None rows — idle slots — map
-        nothing; their writes drop and their keys all mask), packed
-        into ONE int32 array (``_unpack_tables``) and uploaded ONCE,
-        whatever the number of paged layers. It enters the program as
+        nothing; their writes drop and their keys all mask), of every
+        layer kind, packed into ONE int32 array (``_unpack_tables``)
+        and uploaded ONCE, whatever the number of paged layers and of
+        kinds. It enters the program as
         an argument of its own beside the donated pool: every paged
         layer reads the same arrays, and nothing about them needs
         donating. Under tp it COMMITS replicated
         (``TPContext.replicate``), so that a plain round's operand
         and a spec round's chained verify output share one decode
         lowering."""
-        packed = np.full((len(tabs), 2 * self._ring_slots + 2), -1,
-                         np.int32)
-        packed[:, -2:] = 0                       # floor, filled
-        rows = _unpack_tables(packed)
-        for i, tab in enumerate(tabs):
-            if tab is None:
-                continue
-            rows["table"][i], rows["base"][i] = tab.arrays(
-                self._ring_slots)
-            rows["floor"][i] = tab.floor
-            rows["filled"][i] = tab.length
-        self._count_paged_walk(chunk=chunk, **rows)
+        rings = [k.ring for k in self._kinds]
+        packed = np.full((len(tabs), 2 * sum(rings) + len(rings) + 1),
+                         -1, np.int32)
+        packed[:, 2 * sum(rings):] = 0           # floors, filled
+        for k, (kind, rows) in enumerate(zip(
+                self._kinds, _unpack_tables(packed, rings))):
+            for i, tab in enumerate(tabs):
+                if tab is None:
+                    continue
+                t = tab.kinds[k]
+                rows["table"][i], rows["base"][i] = t.arrays(kind.ring)
+                rows["floor"][i] = t.floor
+                rows["filled"][i] = t.length
+            self._count_paged_walk(kind, chunk=chunk, **rows)
         self.stats["table_uploads"] += 1
         if self.tp_ctx is not None:
             return self.tp_ctx.replicate(packed)
         return jnp.asarray(packed)
 
-    def _count_paged_walk(self, table, base, floor, filled,
-                          chunk: int) -> None:
-        """``paged_blocks_live`` / ``paged_blocks_walked``: what the
-        widest-window layer's kernel call does with these tables
+    def _count_paged_walk(self, kind: _KvKind, table, base, floor,
+                          filled, chunk: int) -> None:
+        """``paged_blocks_live`` / ``paged_blocks_walked``: what ONE
+        layer's kernel call of ``kind`` does with the kind's tables
         (``paged_walk_counts``; the gather program reads the same live
         blocks), and ``paged_steps_paid``, the grid steps and loop
-        trips it pays for them (``paged_steps_paid``). The geometry is
-        the first pool leaf's, local to a tp shard."""
-        pk = next(iter(self._pool.values()))["pk"]
+        trips it pays for them (``paged_steps_paid``), each summed over
+        the kinds (one layer's call of each); where the kinds are
+        several, ``paged_blocks_live`` also by kind, under
+        ``paged_blocks_live_w<window>`` (and the part of that which
+        admissions' chunks counted under ``prefill_paged_...``), for a
+        reader that weighs a kind by its layers. The geometry is the
+        kind's first pool leaf's, local to a tp shard;
+        ``paged_blocks_per_step`` and ``paged_steps_per_row`` are the
+        widest kind's."""
+        pk = self._pool[kind.layers[0]]["pk"]
         bt = self.block_tokens
-        ntab = _paged_table_entries(self._ring_slots, self._wmax, bt,
-                                    chunk)
+        ntab = _paged_table_entries(kind.ring, kind.window, bt, chunk)
         per_step = _paged_blocks_per_step(
             bt, pk.shape[2] // self.tp, pk.shape[3], pk.dtype, ntab)
-        if chunk == 1:
+        if chunk == 1 and kind is self._kinds[0]:
             self.stats["paged_blocks_per_step"] = per_step
             self.stats["paged_steps_per_row"] = -(-ntab // per_step)
-        geometry = dict(block_tokens=bt, window=self._wmax,
+        geometry = dict(block_tokens=bt, window=kind.window,
                         blocks_per_step=per_step, chunk=chunk)
-        live, walked = paged_walk_counts(table, base, floor, filled,
-                                         **geometry)
+        live, walked, steps = paged_walk_stats(table, base, floor,
+                                               filled, **geometry)
         self.stats["paged_blocks_live"] += live
         self.stats["paged_blocks_walked"] += walked
-        self.stats["paged_steps_paid"] += paged_steps_paid(
-            table, base, floor, filled, **geometry)
+        self.stats["paged_steps_paid"] += steps
+        # (a decode dispatch is one position a row; an admission's
+        # chunk is wider, and counts under ``prefill_`` as well)
+        name = f"paged_blocks_live_w{kind.window}"
+        self.stats[name] += live
+        if chunk > 1:
+            self.stats["prefill_" + name] += live
 
     def _strip_pool(self, rnn):
         """What a program hands back (pool leaves and, for a net
@@ -2127,15 +2301,21 @@ class DecodeEngine:
                 if name not in self._state_layers}
 
     def _paged_stats_refresh(self) -> None:
-        pool = self.block_pool
-        self.stats["blocks_free"] = pool.free_blocks
-        self.stats["blocks_used"] = pool.used_blocks
+        pools = [k.pool for k in self._kinds]
+        self.stats["blocks_free"] = sum(p.free_blocks for p in pools)
+        self.stats["blocks_used"] = sum(p.used_blocks for p in pools)
+        pool = self.block_pool     # (only one-kind nets share blocks)
         self.stats["cow_copies"] = pool.stats["cow_copies"]
         self.stats["prefix_blocks_spliced"] = pool.stats["spliced"]
-        tabs = list(self._kv_tabs) + [p.tab for p in self._pending]
-        if self.prefix_cache is not None:
-            tabs.extend(self.prefix_cache._payloads.values())
-        self.stats["frag_tokens"] = pool.fragmentation_tokens(tabs)
+        tabs = [t for t in list(self._kv_tabs)
+                + [p.tab for p in self._pending] if t is not None]
+        # (a trie entry's table is one of the widest kind's pool)
+        held = (list(self.prefix_cache._payloads.values())
+                if self.prefix_cache is not None else [])
+        self.stats["frag_tokens"] = sum(
+            p.fragmentation_tokens(
+                [t.kinds[k] for t in tabs] + (held if k == 0 else []))
+            for k, p in enumerate(pools))
         if self.kv_tier is not None:
             t = self.kv_tier.stats
             self.stats["kv_tier_spills"] = t["spills"]
@@ -2146,6 +2326,16 @@ class DecodeEngine:
             self.stats["kv_tier_hits_disk"] = t["hits_disk"]
             self.stats["kv_tier_host_bytes"] = self.kv_tier.host_bytes
             self.stats["kv_tier_disk_bytes"] = self.kv_tier.disk_bytes
+
+    def _one_kind_only(self, what: str) -> None:
+        """Refuse, by its name, what holds one kind of KV block to a
+        net that has several."""
+        if len(self._kinds) > 1:
+            raise NotImplementedError(
+                f"{what} is not supported for this net: its attention "
+                f"layers have windows {[k.window for k in self._kinds]}"
+                ", and the transfer, tier, snapshot and dense-row "
+                "formats hold one kind of KV block")
 
     # -- cross-replica KV transfer (ISSUE 14) --------------------------
     def export_kv(self, prompt,
@@ -2159,6 +2349,7 @@ class DecodeEngine:
         gather. Layout-invariant: a TP=N engine exports full logical
         blocks (host reassembly), so the receiver's width need not
         match."""
+        self._one_kind_only("export_kv")
         from deeplearning4j_tpu.serving.kv_transfer import (
             KVTransferTooLarge,
             export_prefix,
@@ -2192,6 +2383,7 @@ class DecodeEngine:
         .KVTransferError` on a malformed frame or geometry mismatch —
         either way the caller's recompute path still covers
         correctness."""
+        self._one_kind_only("import_kv")
         from deeplearning4j_tpu.serving.kv_transfer import import_prefix
 
         return import_prefix(self, payload)
@@ -2368,13 +2560,14 @@ class DecodeEngine:
                     # first write if it is still shared)
                     matched = hit.matched
                     bt = self.block_tokens
-                    tab = BlockTable(bt, length=matched,
-                                     floor=payload.floor)
+                    mine = BlockTable(bt, length=matched,
+                                      floor=payload.floor)
+                    tab = KindTables([mine])
                     spliced = 0
                     for g, bid in payload.blocks.items():
                         if (g * bt < matched
                                 and (g + 1) * bt > payload.floor):
-                            tab.blocks[g] = bid
+                            mine.blocks[g] = bid
                             self.block_pool.ref(bid)
                             spliced += 1
                     self.block_pool.stats["spliced"] += spliced
@@ -2390,6 +2583,14 @@ class DecodeEngine:
                 else:
                     self.prefix_cache.release(hit)
                     hit = None
+        if tab is None and len(self._kinds) > 1:
+            # several kinds: a cold admission streams through the
+            # block tables from its first token (a dense row would hold
+            # every layer's keys for the whole prompt, and take no
+            # band), each chunk's programs banding a layer by its window
+            self._ensure_paged_pool()
+            tab = KindTables(BlockTable(self.block_tokens)
+                             for _ in self._kinds)
         pending = _Pending(request, slot, None, None, 0, matched, hit,
                            tab=tab)
         if self.prefill_chunk:
@@ -2479,6 +2680,9 @@ class DecodeEngine:
         if warm:
             self._pool = self._strip_pool(rnn)
             pending.tab.length += len(seg)
+            # (a prompt longer than a kind's window leaves blocks
+            # behind it chunk by chunk)
+            self._free_expired_blocks(pending.tab)
         else:
             pending.rnn = rnn
         pending.tok = tok
@@ -2488,23 +2692,34 @@ class DecodeEngine:
         self.stats["chunks_scheduled"] += 1
         return True
 
-    def _ensure_paged_pool(self, rnn1) -> None:
+    def _ensure_paged_pool(self, rnn1=None) -> None:
         """Create the device block pool lazily from the first dense
         B=1 streaming state, which says each layer's heads and dtype
-        (shapes per layer: ``[kv_blocks, block_tokens, H, dh]``)."""
+        (shapes per layer: ``[its kind's blocks, block_tokens, H,
+        dh]``); where no dense row is ever made (several kinds: every
+        admission is paged), from the shapes a smallest cold prefill
+        WOULD give, traced and not run."""
         if self._pool is not None:
             return
         bt = self.block_tokens
+        if rnn1 is None:
+            x, mask = self._encode_prompt([0], self.scheduler.bucket_of(1))
+            one = jnp.ones((1,), jnp.float32)
+            _, rnn1, _ = jax.eval_shape(
+                self._prefill_jit, self._params, self._state, x, mask,
+                one, one.astype(jnp.int32), self._key)
+        blocks = {name: kind.pool.n_blocks for kind in self._kinds
+                  for name in kind.layers}
 
-        def make(st):
+        def make(name, st):
             k = st["k"]                          # [1, H, W, dh]
-            shape = (self.kv_blocks, bt, k.shape[1], k.shape[3])
+            shape = (blocks[name], bt, k.shape[1], k.shape[3])
             return {"pk": jnp.zeros(shape, k.dtype),
                     "pv": jnp.zeros(shape, st["v"].dtype)}
 
         kv, row = self._split_row(rnn1)
         self._pool = self._place(
-            {name: make(st) for name, st in kv.items()})
+            {name: make(name, st) for name, st in kv.items()})
         self._slot_state = jax.tree_util.tree_map(
             lambda a: jnp.zeros((self.n_slots,) + a.shape[1:], a.dtype),
             row)
@@ -2536,7 +2751,8 @@ class DecodeEngine:
             # zero-copy insert: the trie references the slot's own
             # blocks; the slot's next append CoWs the shared
             # boundary block instead of corrupting the entry
-            self.prefix_cache.insert_blocks(request.prompt, tab)
+            self.prefix_cache.insert_blocks(request.prompt,
+                                            tab.kinds[0])
         self._kv_tabs[slot] = tab
         self._reserved.discard(slot)
         # fetch the first token BEFORE stamping TTFT: the value fetch
@@ -3125,10 +3341,9 @@ class DecodeEngine:
         # only a round left in flight still has the device to wait for
         with (self._span("serving.token_sync") if self.async_rounds
               else contextlib.nullcontext()):
-            seq = np.asarray(inf.seq)
-            n_valid = (np.asarray(inf.n_valid)
-                       if inf.n_valid is not None else None)
-            self._add_counts(inf.counts or {})
+            seq, n_valid, counts = jax.device_get(
+                (inf.seq, inf.n_valid, inf.counts or {}))  # one fetch
+            self._add_counts(counts)
         with self._span("serving.commit", active=len(inf.active)):
             self._commit_round(inf, seq, n_valid, t_sync0)
 
@@ -3187,11 +3402,13 @@ class DecodeEngine:
         # n_rounds * decode_chunk under a fused scan — + verify's
         # accepted+bonus) into the host tables, and release blocks
         # that slid out of every window
-        for slot in active:
-            tab = self._kv_tabs[slot]
-            tab.length += inf.decode_tokens + (
-                int(v_n[slot]) if v_n is not None else 0)
-            self._free_expired_blocks(tab)
+        with self._span("serving.kv_release"):
+            for slot in active:
+                tab = self._kv_tabs[slot]
+                tab.length += inf.decode_tokens + (
+                    int(v_n[slot]) if v_n is not None else 0)
+                self._free_expired_blocks(tab)
+            self._count_kv_held(active)
         if self.paranoid:
             active = self._quarantine(active)
         emitted = 0
@@ -3421,8 +3638,8 @@ class DecodeEngine:
                     # beside the KV leaves (``_strip_pool`` parts them)
                     pool_op = dict(pool_op, **self._slot_state)
                 live = self._live_operand()
-                temps = jnp.asarray(self._temps)
-                top_ks = jnp.asarray(self._top_ks)
+                temps = self._uploaded("temps", self._temps)
+                top_ks = self._uploaded("top_ks", self._top_ks)
             if spec_round:
                 # verify dispatch chains into the decode dispatch
                 # below (the scan resumes from the verified state), so
@@ -3485,8 +3702,10 @@ class DecodeEngine:
                             self._next_key(), *live)
                 if not self.async_rounds:
                     with self._span("serving.token_sync"):
-                        seq = np.asarray(seq)  # [B, T]; forces the
-                        #           whole round (verify included) done
+                        # [B, T]; forces the whole round (verify
+                        # included) done, and brings what the program
+                        # counted in the same fetch
+                        seq, counts = jax.device_get((seq, counts))
             self._pool = self._strip_pool(pool_op)
             inf = _InflightRound(
                 active=list(active),
@@ -3739,7 +3958,7 @@ class DecodeEngine:
         if tab is None:
             return    # pool too small for this entry: skip —
             #           the cache is a cache, not state
-        self.prefix_cache.insert_blocks(prefix, tab)
+        self.prefix_cache.insert_blocks(prefix, tab.kinds[0])
         self._free_table(tab)
 
     def _rebuild_slot(self, slot: int, request: Request,
@@ -3795,6 +4014,7 @@ class DecodeEngine:
         deliberately NOT captured: ``restore`` rebuilds KV state by
         re-prefilling recorded tokens, which is smaller, portable, and
         exactly reproducible."""
+        self._one_kind_only("snapshot")
         if self._inflight is not None:
             # an async engine snapshots LANDED state: commit the
             # dispatched round first so the wire format carries every
@@ -3886,9 +4106,9 @@ class DecodeEngine:
                 "tables": {
                     str(slot): {"length": tab.length,
                                 "floor": tab.floor,
-                                "blocks": {str(g): int(b)
-                                           for g, b
-                                           in tab.blocks.items()}}
+                                "blocks": {
+                                    str(g): int(b) for g, b
+                                    in tab.kinds[0].blocks.items()}}
                     for slot, tab in enumerate(self._kv_tabs)
                     if tab is not None},
                 "refcounts": {

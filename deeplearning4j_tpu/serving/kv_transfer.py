@@ -361,7 +361,7 @@ def import_prefix(engine, payload: bytes) -> Dict[str, Any]:
         engine.stats["kv_import_declined"] = engine.stats.get(
             "kv_import_declined", 0) + 1
         return result(False, "no_blocks")
-    from deeplearning4j_tpu.serving.block_pool import BlockTable
+    from deeplearning4j_tpu.serving.block_pool import BlockTable, KindTables
 
     import jax.numpy as jnp
 
@@ -391,7 +391,7 @@ def import_prefix(engine, payload: bytes) -> Dict[str, Any]:
         engine._pool = engine._kv_import_jit(
             engine._pool, new, jnp.asarray(ids))
     ok = engine.prefix_cache.insert_blocks(tokens, tab)
-    engine._free_table(tab)
+    engine._free_table(KindTables([tab]))
     if not ok:
         engine.stats["kv_import_declined"] = engine.stats.get(
             "kv_import_declined", 0) + 1

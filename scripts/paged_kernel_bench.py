@@ -23,11 +23,14 @@ read, and those bytes over the time as a share of the chip's HBM peak
 (``benchmark/peaks.py``). ``--context N`` gives every live row N tokens
 (``--live 48 --context 2047``: a full batch of full-window rows). The
 geometry comes from a benchmark configuration file (heads, width,
-window, ``deployment`` slots, block size and pool blocks); nothing here
+window, ``deployment`` slots, block size and pool blocks); ``--heads``,
+``--kv-heads`` and ``--window`` time the grouped form (a KV head's
+query heads ride its tile) and a layer's own window; nothing here
 is read by the benchmark.
 
     python scripts/paged_kernel_bench.py --live 5,24,36
     python scripts/paged_kernel_bench.py --live 48 --context 2047
+    python scripts/paged_kernel_bench.py --heads 48 --kv-heads 8 --window 512
     python scripts/paged_kernel_bench.py --package-root _parent  # another tree
 
 Fails off the TPU; ``--rehearse`` runs the configuration's rehearsal
@@ -67,6 +70,7 @@ def build_case(rng, geo: dict, contexts: np.ndarray, t: int):
     import jax.numpy as jnp
 
     b, h, dh = geo["n_slots"], geo["n_head"], geo["head_dim"]
+    hk = geo["kv_heads"]       # the pool's heads; ``h`` query heads
     bt, nb, tm = geo["block_tokens"], geo["kv_blocks"], geo["window"]
     s_ring = 2 * -(-tm // bt) + 4
     filled = np.zeros(b, np.int32)
@@ -108,10 +112,10 @@ def build_case(rng, geo: dict, contexts: np.ndarray, t: int):
     pool_dtype = jnp.dtype(geo["pool_dtype"])
     q_dtype = jnp.dtype(geo["compute_dtype"])
     return {
-        "q": draw(q_dtype, b, h, t, dh), "k": draw(q_dtype, b, h, t, dh),
-        "v": draw(q_dtype, b, h, t, dh),
-        "pk": draw(pool_dtype, nb, bt, h, dh),
-        "pv": draw(pool_dtype, nb, bt, h, dh),
+        "q": draw(q_dtype, b, h, t, dh), "k": draw(q_dtype, b, hk, t, dh),
+        "v": draw(q_dtype, b, hk, t, dh),
+        "pk": draw(pool_dtype, nb, bt, hk, dh),
+        "pv": draw(pool_dtype, nb, bt, hk, dh),
         "table": jnp.asarray(table), "base": jnp.asarray(base),
         "floor": jnp.asarray(floor), "filled": jnp.asarray(filled),
         "bid": jnp.asarray(np.where(bval, tb, 0).astype(np.int32)),
@@ -123,7 +127,7 @@ def build_case(rng, geo: dict, contexts: np.ndarray, t: int):
         "live_blocks": live_blocks, "ntab": ntab,
         "shared_blocks": bool(shared),
         "host_tables": (table, base, floor, filled),
-        "block_bytes": 2 * bt * h * dh * pool_dtype.itemsize,
+        "block_bytes": 2 * bt * hk * dh * pool_dtype.itemsize,
     }
 
 
@@ -134,7 +138,7 @@ def walk_steps(att, geo: dict, case: dict, t: int) -> dict:
     grid step has no count of paid steps: its grid is a step a (row,
     query tile, compute block), computed here."""
     per_step = att._paged_blocks_per_step(
-        geo["block_tokens"], geo["n_head"], geo["head_dim"],
+        geo["block_tokens"], geo["kv_heads"], geo["head_dim"],
         geo["pool_dtype"], case["ntab"])
     geometry = dict(block_tokens=geo["block_tokens"], window=geo["window"],
                     blocks_per_step=per_step, chunk=t)
@@ -177,6 +181,15 @@ def main() -> int:
     ap.add_argument("--chunk", type=int, default=1,
                     help="query rows a call (1 = decode)")
     ap.add_argument("--pool-dtype", default="float32")
+    ap.add_argument("--heads", type=int, default=None,
+                    help="query heads (default: the configuration's)")
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="KV heads the pool holds, each serving heads / "
+                         "kv-heads query heads (default: one a query "
+                         "head); the width stays heads x head_dim")
+    ap.add_argument("--window", type=int, default=None,
+                    help="the layer's window (default: the "
+                         "configuration's context)")
     ap.add_argument("--seed", type=int, default=25)
     ap.add_argument("--iters", type=int, default=48)
     ap.add_argument("--package-root", default=ROOT,
@@ -209,11 +222,13 @@ def main() -> int:
         conf = {**conf, **conf["rehearsal"], "deployment": dep}
         traffic = {**traffic, **traffic["rehearsal"]}
     dep = conf["deployment"]
+    heads = args.heads or conf["n_head"]
     geo = {
-        "n_slots": dep["n_slots"], "n_head": conf["n_head"],
+        "n_slots": dep["n_slots"], "n_head": heads,
+        "kv_heads": args.kv_heads or heads,
         "head_dim": conf["n_embd"] // conf["n_head"],
         "block_tokens": dep["block_tokens"], "kv_blocks": dep["kv_blocks"],
-        "window": conf["n_positions"],
+        "window": args.window or conf["n_positions"],
         "compute_dtype": conf["compute_dtype"],
         "pool_dtype": args.pool_dtype,
     }
@@ -222,7 +237,7 @@ def main() -> int:
     n_chain = 2 if args.rehearse else CHAIN
     peak = None if args.rehearse else peaks_of(dev.device_kind)
     lc = att.MultiHeadSelfAttention(
-        n_in=conf["n_embd"], n_out=conf["n_embd"], n_heads=conf["n_head"],
+        n_in=conf["n_embd"], n_out=conf["n_embd"], n_heads=heads,
         stream_max_t=geo["window"])
     for n_live in (int(x) for x in args.live.split(",")):
         rng = np.random.default_rng([args.seed, n_live])
@@ -274,6 +289,8 @@ def main() -> int:
                 "program": name, "live_rows": n_live,
                 "mean_context": round(float(ctx.mean()), 1) if n_live else 0,
                 "chunk": args.chunk, "pool_dtype": args.pool_dtype,
+                "heads": heads, "kv_heads": geo["kv_heads"],
+                "window": geo["window"],
                 "ntab": case["ntab"], "live_blocks": case["live_blocks"],
                 "live_bytes": live_bytes,
             }

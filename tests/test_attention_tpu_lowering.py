@@ -93,6 +93,26 @@ def test_paged_kernel_compiles_for_v5e(one_chip, b, h, t, pool_dtype):
     assert "tpu_custom_call" in text
 
 
+# grouped KV heads (PR 31): a KV head's query heads ride its tile. The
+# Trinity cell's geometry (48 query / 8 KV heads of 128, bf16 pool, 32
+# slots): decode on the window kind (a 259-entry walk) and on the full
+# kind (1,026 entries), and one 1,024-token admission chunk (eight query
+# tiles, one MXU product a query head); the hybrid cell's group of 4 at
+# its 64 slots
+@pytest.mark.parametrize("b,hq,hk,t,tm,nb,ntab", [
+    (32, 48, 8, 1, 4096, 10344, 258), (32, 48, 8, 1, 16384, 32920, 1026),
+    (1, 48, 8, 1024, 4096, 10344, 322), (64, 32, 8, 1, 2048, 8192, 130)])
+def test_grouped_paged_kernel_compiles_for_v5e(one_chip, b, hq, hk, t, tm,
+                                               nb, ntab):
+    q, pool, _, tab, _, row = _paged_avals(
+        b, hk, t, 128, nb, 16, ntab, jnp.bfloat16, jnp.bfloat16)[:6]
+    q = jax.ShapeDtypeStruct((b, hq, t, 128), jnp.bfloat16)
+    text = _compile_for(
+        one_chip, lambda *a: _paged_flash_attention(*a, tm=tm),
+        q, pool, pool, tab, tab, row, row, row, row)
+    assert "tpu_custom_call" in text
+
+
 def _compile_for(one_chip, fn, *avals):
     """``fn`` compiled for the described chip; its HLO text. A described
     device's executable cannot be read back from the persistent cache:

@@ -479,6 +479,29 @@ class TestPagedObservability:
         del res, rid
 
 
+    def test_one_kind_of_layer_is_a_list_of_one_kind(self):
+        """A net whose attention layers agree on their window holds a
+        slot's blocks as ``KindTables`` of ONE table and counts by kind
+        as any other net does: the kind's live blocks are all the live
+        blocks, and within the window every spanned block is held."""
+        from deeplearning4j_tpu.serving.block_pool import KindTables
+
+        eng = DecodeEngine(_net(), n_slots=2, decode_chunk=2, seed=0,
+                           block_tokens=8)
+        eng.submit(Request([1, 4, 7, 2, 5, 9, 3], 12))
+        eng.step({})
+        tab = eng._kv_tabs[0]
+        assert isinstance(tab, KindTables) and len(tab.kinds) == 1
+        assert set(tab.blocks) == {(0, g) for g in tab.kinds[0].blocks}
+        eng.run()
+        (kind,) = eng._kinds
+        w = kind.window
+        assert eng.stats[f"paged_blocks_live_w{w}"] == eng.stats[
+            "paged_blocks_live"] > 0
+        assert eng.stats[f"kv_blocks_held_w{w}"] == eng.stats[
+            f"kv_blocks_spanned_w{w}"] > 0
+
+
 class TestPagedUnits:
     def test_block_pool_validation(self):
         with pytest.raises(ValueError, match="power of two"):

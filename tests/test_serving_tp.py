@@ -579,6 +579,7 @@ class TestPagedFlashKernel:
             for tab in tabs:
                 if tab is None:
                     continue
+                tab, = tab.kinds        # one kind of layer: one table
                 lo_blk = max(tab.floor, tab.length - tm + 1, 0) // bt
                 hit = [g - lo_blk for g in tab.blocks
                        if g >= lo_blk
