@@ -401,9 +401,13 @@ def gateway_from_args(args):
 
     tenants = tenants_from_args(args)
 
+    def net():
+        # the engine adopts this net and only serves it: no moments
+        return restore_model(args.model, updater_state=False)
+
     def engine():
         return DecodeEngine(
-            restore_model(args.model), n_slots=args.slots,
+            net(), n_slots=args.slots,
             decode_chunk=args.decode_chunk,
             prefix_cache_rows=args.prefix_cache_rows,
             prefill_chunk=args.prefill_chunk,
@@ -428,7 +432,7 @@ def gateway_from_args(args):
 
     return ServingGateway.boot(
         engine, snapshot_path=args.snapshot,
-        net_factory=lambda: restore_model(args.model),
+        net_factory=net,
         # the HOST wins layout knobs on restore: the snapshot wire
         # format is tp-invariant, so a drain taken at one width
         # restores at whatever this host can shard. The tenant

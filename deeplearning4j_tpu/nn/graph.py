@@ -128,6 +128,29 @@ class ComputationGraph:
         return self
 
     # ------------------------------------------------------------------
+    @property
+    def _out_f32_vertices(self) -> set:
+        # Output-layer vertices run at the master dtype (same rationale
+        # as MultiLayerNetwork._out_at_master_dtype: a bf16 softmax
+        # quantizes probabilities coarsely enough to stall training).
+        return (set(self.conf.network_outputs)
+                if self._compute_dtype is not None else set())
+
+    def compute_params(self, params):
+        """``params`` as the forward pass computes with them (the rule
+        of ``MultiLayerNetwork.compute_params``, by vertex): every
+        floating leaf at the compute dtype except the output vertices',
+        a leaf already there returned as the same array."""
+        if self._compute_dtype is None:
+            return params
+        cast = functools.partial(
+            _cast_floating, dtype=self._compute_dtype)
+        keep = self._out_f32_vertices
+        return {
+            k: (sub if k in keep else jax.tree_util.tree_map(cast, sub))
+            for k, sub in params.items()
+        }
+
     def _forward_fn(
         self,
         params,
@@ -144,23 +167,13 @@ class ComputationGraph:
         per-vertex recurrent carry (reference ComputationGraph
         rnnActivateUsingStoredState :1233: stored state fed back in for
         streaming inference and truncated-BPTT window chaining)."""
-        # Output-layer vertices run at the master dtype (same rationale
-        # as MultiLayerNetwork._forward_fn: a bf16 softmax quantizes
-        # probabilities coarsely enough to stall training).
-        out_f32_vertices = (
-            set(self.conf.network_outputs)
-            if self._compute_dtype is not None else set())
+        out_f32_vertices = self._out_f32_vertices
+        # Mixed precision: bf16 compute, f32 master params (same
+        # scheme as MultiLayerNetwork._forward_fn)
+        params = self.compute_params(params)
         if self._compute_dtype is not None:
-            # Mixed precision: bf16 compute, f32 master params (same
-            # scheme as MultiLayerNetwork._forward_fn)
-            cast = functools.partial(
-                _cast_floating, dtype=self._compute_dtype)
-            params = {
-                k: (sub if k in out_f32_vertices
-                    else jax.tree_util.tree_map(cast, sub))
-                for k, sub in params.items()
-            }
-            inputs = {k: cast(v) for k, v in inputs.items()}
+            inputs = {k: _cast_floating(v, self._compute_dtype)
+                      for k, v in inputs.items()}
         acts: Dict[str, Array] = dict(inputs)
         new_state = dict(state) if state else {}
         new_rnn: Dict[str, Any] = {}

@@ -62,8 +62,10 @@ def _dtype_of(name: str):
 
 
 def _cast_floating(a, dtype):
-    """Cast floating arrays, leave ints/bools (masks, indices) alone."""
-    if hasattr(a, "dtype") and jnp.issubdtype(a.dtype, jnp.floating):
+    """Cast floating arrays, leave ints/bools (masks, indices) alone.
+    An array already at ``dtype`` comes back as it is, not a copy."""
+    if (hasattr(a, "dtype") and jnp.issubdtype(a.dtype, jnp.floating)
+            and a.dtype != dtype):
         return a.astype(dtype)
     return a
 
@@ -181,6 +183,40 @@ class MultiLayerNetwork:
     def n_layers(self) -> int:
         return len(self.conf.confs)
 
+    @property
+    def _out_at_master_dtype(self) -> bool:
+        # The OUTPUT layer always runs at the master dtype: a bf16
+        # softmax quantizes probabilities coarsely enough to stall
+        # training at a calibration plateau (measured on LeNet/MNIST:
+        # bf16-everywhere pins at 0.905 accuracy / 1.76 loss while f32
+        # head converges to ~1.0; the conv/dense bulk keeps the MXU
+        # bf16 rate). Casting AFTER the softmax (the loss-side cast
+        # in ``_loss_fn``) is too late — the quantization already
+        # happened.
+        return (self._compute_dtype is not None
+                and isinstance(self.conf.confs[-1].layer,
+                               L.BaseOutputLayer))
+
+    def compute_params(self, params):
+        """``params`` as the forward pass computes with them: under
+        mixed precision every floating leaf at the compute dtype,
+        except the output layer's, which stay at the master dtype. The
+        one place that rule lives: ``_forward_fn`` applies it to what
+        it is handed, and a holder of resident weights (the serving
+        engine) applies it once. A leaf already at its target dtype
+        comes back as the same array, so a tree that went through here
+        goes through again as itself."""
+        cd = self._compute_dtype
+        if cd is None:
+            return params
+        cast = functools.partial(_cast_floating, dtype=cd)
+        keep = ({str(self.n_layers - 1)} if self._out_at_master_dtype
+                else ())
+        return {
+            si: (sub if si in keep else jax.tree_util.tree_map(cast, sub))
+            for si, sub in params.items()
+        }
+
     # ------------------------------------------------------------------
     # Pure functional forward (traced under jit)
     # ------------------------------------------------------------------
@@ -207,28 +243,14 @@ class MultiLayerNetwork:
         dict that layers add what they counted in this pass into, by
         name. Both go to the layers whose bean has ``wants_live``."""
         cd = self._compute_dtype
-        # The OUTPUT layer always runs at the master dtype: a bf16
-        # softmax quantizes probabilities coarsely enough to stall
-        # training at a calibration plateau (measured on LeNet/MNIST:
-        # bf16-everywhere pins at 0.905 accuracy / 1.76 loss while f32
-        # head converges to ~1.0; the conv/dense bulk keeps the MXU
-        # bf16 rate). Casting AFTER the softmax (the loss-side cast
-        # below) is too late — the quantization already happened.
-        out_f32 = (cd is not None
-                   and isinstance(self.conf.confs[-1].layer,
-                                  L.BaseOutputLayer))
+        out_f32 = self._out_at_master_dtype
         last_si = str(self.n_layers - 1)
+        # Mixed precision: compute in cd (bf16 on the MXU), master
+        # params stay f32 — the cast's transpose accumulates grads
+        # back in f32.
+        params = self.compute_params(params)
         if cd is not None:
-            # Mixed precision: compute in cd (bf16 on the MXU), master
-            # params stay f32 — the cast's transpose accumulates grads
-            # back in f32.
-            cast = functools.partial(_cast_floating, dtype=cd)
-            params = {
-                si: (sub if (out_f32 and si == last_si)
-                     else jax.tree_util.tree_map(cast, sub))
-                for si, sub in params.items()
-            }
-            x = cast(x)
+            x = _cast_floating(x, cd)
         acts = []
         new_state = dict(state) if state else {}
         new_rnn = {}

@@ -397,7 +397,9 @@ class Tracer:
     ``serving.commit``, ``serving.round_end``; on a handler's thread
     ``gateway.submit`` with its child ``gateway.lock_wait`` (the
     interval a result's ``timing.gateway_wait_s`` carries, as
-    ``timing.first_delta_s`` carries submit to first delta out). A
+    ``timing.first_delta_s`` carries submit to first delta out); on
+    the thread that builds the engine, once, ``serving.weights_cast``
+    (float32 masters cast to the compute dtype). A
     training loop feeds a tracer through
     ``optimize/listeners.py:TracingIterationListener``; taking the
     device trace itself is ``benchmark/common.py:SubTrace``."""

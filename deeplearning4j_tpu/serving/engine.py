@@ -582,6 +582,20 @@ class DecodeEngine:
     advances that many tokens per dispatch (amortizing host round
     trips) and admissions/evictions happen at chunk boundaries.
 
+    The engine ADOPTS its net. Under mixed precision (float32 masters,
+    a bf16 ``compute_dtype``) construction casts the weights to the
+    compute dtype once (``net.compute_params``: every layer but the
+    output layer, which keeps its master dtype) and rebinds
+    ``net.params`` to that tree, releasing the masters: every program
+    computes with the values it computed with before, rounded once and
+    not once a dispatch, and the served model holds 2 bytes a weight.
+    A served net is therefore no longer a training net (``fit`` on it
+    would train the bf16 parameters); whoever serves a model owns the
+    net it hands over, as ``dl4j-tpu serve`` owns the one it restores.
+    A net already resident at its compute dtype is left as it is.
+    ``stats["param_bytes"]`` / ``["param_bytes_cast"]`` say what is
+    resident and how much of it construction cast.
+
     Keys and values live in a pool of ``kv_blocks`` blocks of
     ``block_tokens`` tokens per attention layer (the engine's only KV
     layout); a slot's block table grows a block at a time and frees
@@ -940,9 +954,10 @@ class DecodeEngine:
         if use_flash_paged is not None:
             for _, bean in attn_items:
                 bean.use_flash_paged = use_flash_paged
-        #: sharded (tp > 1) or plain (tp == 1) views of the net's
-        #: params/state: every dispatch reads THESE, so the weights are
-        #: resident per-shard once, not re-sharded per call
+        cast_bytes = self._adopt_weights()
+        #: the weights every dispatch reads: the net's, resident at
+        #: its compute dtype (``_adopt_weights``), sharded where
+        #: tp > 1, so that no program casts or re-shards them per call
         self._params = (self.tp_ctx.place(net.params)
                         if self.tp_ctx else net.params)
         self._state = (self.tp_ctx.place(net.state)
@@ -1221,6 +1236,13 @@ class DecodeEngine:
             # operand, summed over paged dispatches: one a dispatch,
             # whatever the number of paged layers (ISSUE 28)
             "table_uploads": 0,
+            # the resident weights: bytes of ``_params``, and bytes of
+            # the masters that construction cast to the compute dtype
+            # (0 for a net that was already resident there)
+            "param_bytes": sum(
+                leaf.size * leaf.dtype.itemsize      # (shapes count too)
+                for leaf in jax.tree.leaves(self._params)),
+            "param_bytes_cast": cast_bytes,
             # by layer kind (its window): the kind's part of
             # ``paged_blocks_live`` and, summed over rounds, the blocks
             # its live contexts span and hold (``_count_kv_held``)
@@ -1257,6 +1279,32 @@ class DecodeEngine:
         for key in self.FAILURE_KEYS:
             self.stats[key] = 0
         self._build_jits()
+
+    def _adopt_weights(self) -> int:
+        """Make the net's weights resident at its compute dtype, once:
+        ``net.compute_params`` is the rule every forward pass applies
+        to what it is handed, and applied here it leaves the programs
+        nothing to cast (a net of float32 masters otherwise pays the
+        whole cast in every dispatch). ``net.params`` is rebound to the
+        result a layer at a time, so a master is released as soon as
+        its copy exists and the two trees never stand side by side; a
+        net already resident at its compute dtype keeps every array it
+        had. Returns the bytes of the masters that were cast."""
+        net, cast_bytes = self.net, 0
+        with self._span("serving.weights_cast"):
+            for key in list(net.params):
+                sub = net.params[key]
+                done = net.compute_params({key: sub})[key]
+                cast_bytes += sum(
+                    old.nbytes for old, new in zip(
+                        jax.tree.leaves(sub), jax.tree.leaves(done))
+                    if new is not old)
+                net.params[key] = done
+            if cast_bytes:
+                # the span ends when the copies exist, not when their
+                # casts were enqueued
+                jax.block_until_ready(net.params)
+        return cast_bytes
 
     # -- jitted computations (fixed executables; see module docstring) -
     def _jit(self, fn, donate_argnums=()):
@@ -3782,7 +3830,8 @@ class DecodeEngine:
                     "preempted", "paged_admit_deferred",
                     "paged_blocks_live", "paged_blocks_walked",
                     "paged_blocks_per_step", "paged_steps_per_row",
-                    "paged_steps_paid", "table_uploads"):
+                    "paged_steps_paid", "table_uploads",
+                    "param_bytes", "param_bytes_cast"):
             self.tracer.counter(f"serving_{key}", self.stats[key])
         if self.prefix_cache is not None:
             for key in ("hits", "misses", "evictions"):

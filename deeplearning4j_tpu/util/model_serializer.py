@@ -116,8 +116,13 @@ def write_model(net, path: str) -> None:
     write_snapshot(snapshot(net), path)
 
 
-def restore_model(path: str):
-    """Load a model zip back into the right network class."""
+def restore_model(path: str, updater_state: bool = True):
+    """Load a model zip back into the right network class.
+    ``updater_state=False`` is for a net that will only be served: the
+    optimizer's moments (Adam: twice the parameters) are neither put on
+    the device nor kept from ``init()``, and ``net.updater_state``
+    comes back empty, so ``fit`` on that net fails rather than training
+    from zeroed moments."""
     with zipfile.ZipFile(path) as z:
         kind = z.read("type").decode()
         conf_json = z.read("conf.json").decode()
@@ -145,7 +150,9 @@ def restore_model(path: str):
         ).init()
 
     net.params = _merge_into(net.params, params)
-    net.updater_state = jax.tree.map(jnp.asarray, extras["updater_state"])
+    net.updater_state = (
+        jax.tree.map(jnp.asarray, extras["updater_state"])
+        if updater_state else {})
     net.state = jax.tree.map(jnp.asarray, extras["state"])
     net.iteration = int(extras["iteration"])
     return net

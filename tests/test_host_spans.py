@@ -188,8 +188,12 @@ class TestRoundSpans:
             range(len(rounds)))
         names = {s["name"] for s in spans}
         assert ROUND_LEAVES <= names
-        inside = [s for s in spans if s["name"] != "serving.round"]
-        for s in inside:   # every span lies inside one round
+        # construction's one span comes before the first round
+        (cast,) = tracer.spans("serving.weights_cast")
+        assert cast["ts"] + cast["dur"] <= rounds[0]["ts"]
+        inside = [s for s in spans if s["name"] not in (
+            "serving.round", "serving.weights_cast")]
+        for s in inside:   # every other span lies inside one round
             assert any(r["ts"] <= s["ts"] and s["ts"] + s["dur"]
                        <= r["ts"] + r["dur"] for r in rounds), s
         for s in tracer.spans("serving.admit"):

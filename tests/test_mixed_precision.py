@@ -6,8 +6,10 @@ updater state, and the loss stay at the master dtype. Convergence must
 track the f32 run closely, params must never leave f32, and the conf knob
 must survive the JSON wire format."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf import layers as L
@@ -30,6 +32,23 @@ def _conf(compute_dtype=None, with_bn=False):
     lb.layer(idx, L.OutputLayer(n_in=16, n_out=3, activation="softmax",
                                 loss_function=LossFunction.MCXENT))
     return lb.build()
+
+
+def _graph_conf(compute_dtype="bfloat16"):
+    b = NeuralNetConfiguration.Builder().seed(3).learning_rate(0.1)
+    if compute_dtype:
+        b = b.compute_dtype(compute_dtype)
+    return (
+        b.graph_builder()
+        .add_inputs("in")
+        .add_layer("h", L.DenseLayer(n_in=8, n_out=16,
+                                     activation="relu"), "in")
+        .add_layer("out", L.OutputLayer(
+            n_in=16, n_out=3, activation="softmax",
+            loss_function=LossFunction.MCXENT), "h")
+        .set_outputs("out")
+        .build()
+    )
 
 
 def _data(n=64, seed=0):
@@ -90,20 +109,7 @@ class TestMixedPrecisionGraph:
     def test_graph_bf16_compute(self):
         from deeplearning4j_tpu.nn.graph import ComputationGraph
 
-        conf = (
-            NeuralNetConfiguration.Builder().seed(3).learning_rate(0.1)
-            .compute_dtype("bfloat16")
-            .graph_builder()
-            .add_inputs("in")
-            .add_layer("h", L.DenseLayer(n_in=8, n_out=16,
-                                         activation="relu"), "in")
-            .add_layer("out", L.OutputLayer(
-                n_in=16, n_out=3, activation="softmax",
-                loss_function=LossFunction.MCXENT), "h")
-            .set_outputs("out")
-            .build()
-        )
-        net = ComputationGraph(conf).init()
+        net = ComputationGraph(_graph_conf()).init()
         x, y = _data(32)
         for _ in range(5):
             net.fit(x, y)
@@ -266,3 +272,81 @@ class TestF32OutputHead:
         acts, _, _ = g._forward_fn(g.params, {}, x, None, False, None)
         assert acts["h"].dtype == jnp.bfloat16
         assert acts["out"].dtype == jnp.float32
+
+
+def _mln(compute_dtype="bfloat16"):
+    net = MultiLayerNetwork(_conf(compute_dtype)).init()
+    x, _ = _data()
+    return (net, str(net.n_layers - 1),
+            lambda p: net._forward_fn(p, {}, jnp.asarray(x), None,
+                                      False)[0])
+
+
+def _graph(compute_dtype="bfloat16"):
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+    net = ComputationGraph(_graph_conf(compute_dtype)).init()
+    x, _ = _data()
+    return (net, "out",
+            lambda p: net._forward_fn(p, {}, {"in": jnp.asarray(x)},
+                                      None, False)[0]["out"])
+
+
+class TestComputeParams:
+    """The cast rule of mixed precision is one method of a net
+    (``compute_params``): ``_forward_fn`` applies it to what it is
+    handed, a holder of resident weights (the serving engine) applies
+    it once, and applied twice it is the identity on ARRAYS."""
+
+    @pytest.mark.parametrize("build", [_mln, _graph],
+                             ids=["multilayer", "graph"])
+    def test_forward_agrees_bit_for_bit_on_cast_params(self, build):
+        net, head, forward = build()
+        cast = net.compute_params(net.params)
+        for key, sub in cast.items():
+            for name, leaf in sub.items():
+                if key == head:     # the output layer: master, untouched
+                    assert leaf is net.params[key][name]
+                else:
+                    assert leaf.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(forward(net.params)),
+                                      np.asarray(forward(cast)))
+        again = net.compute_params(cast)
+        assert all(a is b for a, b in zip(jax.tree.leaves(again),
+                                          jax.tree.leaves(cast)))
+
+    @pytest.mark.parametrize("build", [_mln, _graph],
+                             ids=["multilayer", "graph"])
+    def test_a_net_without_a_compute_dtype_hands_back_its_tree(
+            self, build):
+        net, _, _ = build(None)
+        assert net.compute_params(net.params) is net.params
+
+    def test_the_scanned_step_lowers_as_with_the_rule_inlined(self):
+        """``fit_scan``'s step, lowered with the rule factored out (the
+        code as it is) and with the rule written into the forward pass
+        (as it was before ``compute_params``): the same text."""
+        x, y = _data(16)
+        feats, labels = jnp.asarray(x[None]), jnp.asarray(y[None])
+
+        def lowered(net):
+            return net._train_steps_scan.lower(
+                net.params, net.state, net.updater_state, 0,
+                jax.random.key(0), feats, labels, 1.0).as_text()
+
+        factored = lowered(MultiLayerNetwork(
+            _conf("bfloat16", with_bn=True)).init())
+        net = MultiLayerNetwork(_conf("bfloat16", with_bn=True)).init()
+        forward, last = net._forward_fn, str(net.n_layers - 1)
+
+        def inlined(params, *args, **kwargs):
+            params = {
+                si: (sub if si == last else jax.tree_util.tree_map(
+                    lambda a: a.astype(jnp.bfloat16), sub))
+                for si, sub in params.items()}
+            return forward(params, *args, **kwargs)
+
+        net._forward_fn = inlined
+        net.compute_params = lambda params: params
+        text = lowered(net)
+        assert "bf16" in text and text == factored

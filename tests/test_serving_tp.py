@@ -49,14 +49,14 @@ from deeplearning4j_tpu.serving import (
 V = 12
 
 
-def _net(seed=7, stream_max_t=64):
-    net = MultiLayerNetwork(transformer_lm(
-        n_in=V, width=32, n_layers=2, n_heads=4, n_classes=V,
-        seed=seed)).init()
-    for c in net.conf.confs:
+def _net(seed=7, stream_max_t=64, compute_dtype=None):
+    conf = transformer_lm(n_in=V, width=32, n_layers=2, n_heads=4,
+                          n_classes=V, seed=seed)
+    for c in conf.confs:
+        c.compute_dtype = compute_dtype
         if hasattr(c.layer, "stream_max_t"):
             c.layer.stream_max_t = stream_max_t
-    return net
+    return MultiLayerNetwork(conf).init()
 
 
 # shared-prefix workload: splice + CoW + cold admissions under TP
@@ -78,11 +78,13 @@ def rig():
     cache = {}
 
     def get(tp=1, spec=0, prefill_chunk=0, policy="ttft",
-            use_flash_paged=None):
-        key = (tp, spec, prefill_chunk, policy, use_flash_paged)
+            use_flash_paged=None, compute_dtype=None):
+        key = (tp, spec, prefill_chunk, policy, use_flash_paged,
+               compute_dtype)
         if key not in cache:
             eng = DecodeEngine(
-                _net(), n_slots=2, decode_chunk=2, seed=0,
+                _net(compute_dtype=compute_dtype), n_slots=2,
+                decode_chunk=2, seed=0,
                 prefix_cache_rows=4, block_tokens=8,
                 spec_draft_len=spec, prefill_chunk=prefill_chunk,
                 admission_policy=policy, tp=tp,
@@ -275,10 +277,24 @@ class TestPagedFlashKernel:
     """The pallas paged-attention kernel (interpret mode = the CPU
     parity hook) vs the XLA gather program."""
 
-    def test_kernel_bit_parity_sharded(self, rig):
-        _, ref = rig(1)
-        _, got = rig(2, use_flash_paged="interpret")
+    @pytest.mark.parametrize("compute_dtype", [None, "bfloat16"])
+    def test_kernel_bit_parity_sharded(self, rig, compute_dtype):
+        """(bfloat16: float32 masters, cast once at construction; the
+        tp engine places the cast tree, head-sliced, not the masters)"""
+        one, ref = rig(1, compute_dtype=compute_dtype)
+        eng, got = rig(2, use_flash_paged="interpret",
+                       compute_dtype=compute_dtype)
         assert got == ref
+        cast = eng.stats["param_bytes_cast"]
+        assert cast == one.stats["param_bytes_cast"]
+        assert (cast > 0) == (compute_dtype is not None)
+        assert eng.stats["param_bytes"] == one.stats["param_bytes"]
+        if compute_dtype:
+            wq = eng._params["0"]["Wq"]
+            assert wq.dtype == jnp.bfloat16
+            assert wq.addressable_shards[0].data.shape[1] * 2 \
+                == wq.shape[1]
+            assert eng.net.params["0"]["Wq"].dtype == jnp.bfloat16
 
     def test_kernel_bit_parity_spec_chunked(self, rig):
         _, ref = rig(1, 3, 4, "decode")
