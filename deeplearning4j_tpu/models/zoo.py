@@ -496,6 +496,111 @@ def afmoe_lm(
     return conf
 
 
+def lfm2_moe_lm(
+    vocab_size: int = 64,
+    hidden_size: int = 64,
+    layer_types: Sequence[str] = ("conv", "conv", "full_attention", "conv"),
+    layers: Optional[Sequence[int]] = None,
+    num_dense_layers: int = 1,
+    num_attention_heads: int = 4,
+    num_key_value_heads: int = 2,
+    conv_L_cache: int = 3,
+    rope_theta: float = 1000000.0,
+    intermediate_size: int = 128,
+    moe_intermediate_size: int = 32,
+    num_experts: int = 8,
+    num_experts_per_tok: int = 2,
+    routed_scaling_factor: float = 1.0,
+    experts_held=None,
+    freeze_router: bool = False,
+    norm_eps: float = 1e-5,
+    max_position_embeddings: int = 512,
+    initializer_range: float = 0.02,
+    dtype: str = "float32",
+    compute_dtype: Optional[str] = None,
+    lr: float = 3e-4,
+    warmup_steps: int = 100,
+    total_steps: int = 1000,
+    remat: bool = False,
+    seed: int = 12345,
+):
+    """An ``lfm2_moe`` LM (HF ``Lfm2MoeForCausalLM``, LFM2-8B-A1B),
+    trained, under its config's own key names: the token embedding, one
+    ``HybridMoeBlock`` a layer, a head tied to the embedding.
+    ``layer_types[i]`` says layer ``i``'s mixer: ``"conv"``, the gated
+    short convolution of ``conv_L_cache`` taps, or
+    ``"full_attention"``, grouped-KV causal attention of
+    ``hidden_size / num_attention_heads`` a head with QK-norm and
+    rotary positions. Layers below ``num_dense_layers`` have a dense
+    gated feed-forward of ``intermediate_size``, the others sigmoid
+    top-k routing (a selection bias, the picked scores normalised over
+    their sum plus 1e-6, times ``routed_scaling_factor``) over
+    ``num_experts`` outputs with no shared expert. ``layers`` picks
+    which published layer indices are built (None = all of
+    ``layer_types``); ``experts_held`` the ``[lo, hi)`` of the router's
+    outputs whose experts this chip holds. ``freeze_router`` takes the
+    routing out of learning (the gates constants to the gradient, the
+    routers' weights not moved): one chip's share trained WITHOUT the
+    exchange adds only its held experts to the output, so any gradient
+    through the gates teaches the stack to pick them.
+
+    It trains through ``fit_scan`` / ``fit`` on token ids
+    ``[.., N, T]`` and label ids of the same shape, with Adam under a
+    linear warm-up and cosine decay; ``compute_dtype`` "bfloat16" keeps
+    float32 masters and computes in bfloat16; ``remat`` recomputes a
+    layer's activations on the way back."""
+    from deeplearning4j_tpu.nn.conf.distribution import NormalDistribution
+    from deeplearning4j_tpu.nn.layers.hybrid import (
+        HybridMoeBlock,
+        TiedLMHead,
+    )
+
+    mixers = {"conv": "short_conv", "full_attention": "attention"}
+    picked = list(range(len(layer_types))) if layers is None else list(
+        layers)
+    b = (
+        NeuralNetConfiguration.Builder()
+        .seed(seed)
+        .learning_rate(lr)
+        .lr_policy("warmup_cosine")
+        .lr_warmup_steps(warmup_steps)
+        .lr_total_steps(total_steps)
+        .updater(Updater.ADAM)
+        .activation("identity")
+        .list()
+    )
+    b.layer(0, L.EmbeddingLayer(
+        n_in=vocab_size, n_out=hidden_size, sequence=True,
+        weight_init=WeightInit.DISTRIBUTION,
+        dist=NormalDistribution(0.0, initializer_range)))
+    for i, idx in enumerate(picked):
+        dense = idx < num_dense_layers
+        b.layer(i + 1, HybridMoeBlock(
+            n_in=hidden_size, n_out=hidden_size,
+            mixer=mixers[layer_types[idx]], rms_eps=norm_eps,
+            n_heads=num_attention_heads, n_kv_heads=num_key_value_heads,
+            stream_max_t=max_position_embeddings, rope_theta=rope_theta,
+            qk_norm=True, conv_kernel=conv_L_cache,
+            n_router=0 if dense else num_experts,
+            top_k=num_experts_per_tok, d_expert=moe_intermediate_size,
+            d_shared=intermediate_size if dense else 0,
+            experts_held=(None if dense or experts_held is None
+                          else tuple(experts_held)),
+            gate_rule="sigmoid_bias", route_scale=routed_scaling_factor,
+            route_eps=1e-6, freeze_router=freeze_router,
+            init_std=initializer_range))
+    b.layer(len(picked) + 1, TiedLMHead(
+        n_in=hidden_size, n_out=vocab_size, tie_to=0, rms_eps=norm_eps,
+        init_std=initializer_range, activation="softmax",
+        loss_function=LossFunction.MCXENT))
+    conf = b.remat(remat).build()
+    for c in conf.confs:
+        c.dtype = dtype
+        if compute_dtype:
+            c.compute_dtype = compute_dtype
+    return conf
+
+
 def moe_transformer_lm(
     n_in: int = 64,
     width: int = 128,

@@ -4,7 +4,16 @@
 grouped-KV attention or Mamba-2 and whose feed-forward is a dropless
 share of gated experts, and a tied head out. The same block, by its
 bean's options, is the ``afmoe`` family's (sliding-window and full
-attention layers in one net, below).
+attention layers in one net, below) and the ``lfm2_moe`` family's
+(``mixer="short_conv"``, the gated short convolution
+:func:`short_conv_mixer`, beside attention layers with QK-norm and
+rotary positions; no shared expert, ``route_eps`` 1e-6), which is also
+TRAINED: the block runs under ``jax.value_and_grad`` through
+``fit_scan`` (nn/layers/moe.py ``dropless_moe``), its head is scored on
+label ids, and ``expert_bias`` is a leaf no updater moves
+(``HybridMoeBlockImpl.frozen_leaves``; under ``freeze_router`` the
+router's weights are another, and the gates are constants to the
+gradient).
 
 Both keep the framework's ``[N, C, T]`` recurrent layout at their
 edges, so they compose with ``MultiLayerNetwork._forward_fn``, the
@@ -42,7 +51,8 @@ outputs become picks and gates (nn/layers/moe.py ``route``):
 its own ``E``.
 
 **State, rows and counters.** A block's streaming state is its mixer's
-and nothing else (the attention cache, or ``{"conv", "ssm"}``). What a
+and nothing else (the attention cache, ``{"conv", "ssm"}``, or the short
+convolution's ``{"conv"}``, its last ``conv_kernel - 1`` gated inputs). What a
 caller that batches slots has to say and wants to know travels beside
 it, as two keywords of ``apply`` that ``_forward_fn`` hands to a layer
 whose bean has ``wants_live``: ``live`` ``[B]``, which rows exist (an
@@ -50,7 +60,8 @@ idle serving slot routes to no expert and its recurrent state is left
 alone), and ``counters``, a dict the block adds this call's int32
 scalars into (``moe_picks``, ``moe_picks_held``,
 ``moe_experts_touched``, ``moe_load_max``, ``moe_layer_steps``,
-``ssm_state_rows``).
+``ssm_state_rows``). A training step hands them back with its
+gradient-health scalars (``MultiLayerNetwork._step_body``).
 """
 
 from __future__ import annotations
@@ -75,8 +86,9 @@ from deeplearning4j_tpu.nn.layers.moe import (
     gated_ffn,
     moe_shapes,
 )
+from deeplearning4j_tpu.ops.losses import label_cross_entropy
 
-MIXERS = ("attention", "mamba2")
+MIXERS = ("attention", "mamba2", "short_conv")
 
 
 def rms_norm(x, w, eps: float):
@@ -97,6 +109,35 @@ def _residual(x, branch, multiplier: float):
 
 def _normal(key, shape, std, dtype):
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+
+def short_conv_shapes(width: int, kernel: int) -> dict:
+    return {"W_in": (width, 3 * width), "conv_w": (kernel, width),
+            "W_out": (width, width)}
+
+
+def short_conv_mixer(params, hn, state, mask):
+    """The gated short convolution (``lfm2``'s ``conv`` layers) on
+    ``hn`` ``[N, T, D]`` (already normed)::
+
+        [B | C | u] = h W_in                                  (D each)
+        y_t = C_t * sum_{j < K} w[j] (B u)_{t - (K - 1) + j}  (depthwise, causal, no bias)
+        out = y W_out
+
+    ``state`` is ``{"conv": [N, K - 1, D]}``, the last ``K - 1`` gated
+    inputs ``B u``, or None (a fresh row); ``mask`` ``[N, T]`` marks
+    each row's valid prefix. The taps are summed and gated in float32,
+    one rounding to ``hn``'s dtype. Returns ``(out, new state)``."""
+    proj = hn @ params["W_in"]
+    d = proj.shape[-1] // 3
+    bu = proj[..., :d] * proj[..., 2 * d:]
+    lengths = (None if mask is None
+               else jnp.sum(mask.astype(jnp.int32), axis=1))
+    acc, tail = mamba2.conv_taps(
+        bu, None if state is None else state["conv"], params["conv_w"],
+        lengths)
+    y = (proj[..., d:2 * d].astype(jnp.float32) * acc).astype(hn.dtype)
+    return y @ params["W_out"], {"conv": tail}
 
 
 def rope(q, k, start, theta: float):
@@ -129,12 +170,22 @@ class TiedLMHead(BaseOutputLayer):
     ``[N, n_in, T]``, ``E`` being layer ``tie_to``'s ``W`` ``[n_out,
     n_in]`` (``MultiLayerNetwork._forward_fn`` hands it over as
     ``params["E"]``; the head's own leaf is the norm's weight).
-    ``tie_to=None``: an untied head, ``E`` its own leaf."""
+    ``tie_to=None``: an untied head, ``E`` its own leaf.
+
+    It is scored on class IDS (``takes_label_ids``: ``fit`` and
+    ``fit_scan`` keep its labels ``[N, T]`` whole numbers), from its
+    float32 logits: the training pass stops at :meth:`TiedLMHeadImpl.
+    logits` and the loss is the log-softmax gathered at the label
+    (ops/losses.py ``label_cross_entropy``), so no ``[N, T, V]``
+    one-hot or probability is made on the host or the device."""
 
     tie_to: Optional[int] = 0
     init_std: float = 0.02
     logits_scaling: float = 1.0
     rms_eps: float = 1e-5
+
+    #: read off the bean by ``MultiLayerNetwork``, as ``takes_token_ids``
+    takes_label_ids = True
 
 
 class TiedLMHeadImpl(LayerImplBase):
@@ -164,6 +215,12 @@ class TiedLMHeadImpl(LayerImplBase):
         probs = jax.nn.softmax(cls.logits(conf, params, x), axis=-1)
         return jnp.transpose(probs, (0, 2, 1)), state
 
+    @classmethod
+    def loss(cls, conf, logits, labels, mask=None):
+        """The score of :meth:`logits` ``[N, T, V]`` against label ids
+        ``[N, T]`` (``mask`` ``[N, T]``)."""
+        return label_cross_entropy(logits, labels, mask)
+
 
 # ---------------------------------------------------------------------
 # the block
@@ -172,7 +229,8 @@ class TiedLMHeadImpl(LayerImplBase):
 @dataclasses.dataclass
 class HybridMoeBlock(BaseRecurrentLayer):
     """Conf bean: one pre-RMSNorm residual block of width ``n_in ==
-    n_out``: ``mixer`` ("attention" or "mamba2"), then dropless top-k
+    n_out``: ``mixer`` ("attention", "mamba2" or "short_conv", the
+    gated short convolution of width ``conv_kernel``), then dropless top-k
     routing over ``n_router`` outputs of which this chip holds the
     experts ``experts_held = [lo, hi)`` (None = all), plus a shared
     expert of width ``d_shared`` (0 = none). The gates follow
@@ -209,6 +267,8 @@ class HybridMoeBlock(BaseRecurrentLayer):
     ssm_groups: int = 1
     ssm_d_conv: int = 4
     ssm_chunk: int = 256
+    # short_conv mixer: the taps (the config's ``conv_L_cache``)
+    conv_kernel: int = 3
     # experts
     n_router: int = 8
     top_k: int = 2
@@ -217,6 +277,14 @@ class HybridMoeBlock(BaseRecurrentLayer):
     experts_held: Optional[tuple] = None
     gate_rule: str = "softmax_topk"
     route_scale: float = 1.0
+    route_eps: float = 1e-20
+    #: True: routing takes no part in learning. The gates are constants
+    #: to the gradient (the router's weights take none, the layer's
+    #: input none through them) and the router is a leaf no updater
+    #: moves: what a share of the experts trained WITHOUT its exchange
+    #: needs, where only held picks add to the output and any gradient
+    #: through the gates teaches the stack to pick the held experts
+    freeze_router: bool = False
     #: the two Pallas kernels (the one-step state update, the grouped
     #: expert product): None = on a TPU, the plain programs elsewhere;
     #: True / False force; "interpret" = Pallas interpret mode
@@ -233,7 +301,8 @@ class HybridMoeBlock(BaseRecurrentLayer):
     def serving_state(self) -> str:
         """``"kv"``: an attention cache, paged by the engine;
         ``"slot"``: one row a slot, carried whole (the Mamba-2 mixer's
-        convolution tail and SSM state)."""
+        convolution tail and SSM state, the short convolution's
+        tail)."""
         return "kv" if self.mixer == "attention" else "slot"
 
     @property
@@ -247,6 +316,17 @@ class HybridMoeBlock(BaseRecurrentLayer):
 
 
 class HybridMoeBlockImpl(LayerImplBase):
+    @classmethod
+    def frozen_leaves(cls, lc) -> tuple:
+        """Leaves no updater moves (``MultiLayerNetwork._apply_updates``
+        asks the impl): the router's selection bias reaches the loss
+        through the picks alone, so its gradient is 0 by construction,
+        and whoever balances the experts' loads moves it by a rule
+        outside the gradient; under ``freeze_router`` the router's
+        weights too (their gradient is 0 there as well)."""
+        return ("expert_bias", "router") if lc.freeze_router else (
+            "expert_bias",)
+
     @classmethod
     def shapes(cls, lc) -> dict:
         d = lc.n_out
@@ -263,6 +343,8 @@ class HybridMoeBlockImpl(LayerImplBase):
             mix = mamba2.mixer_shapes(d, lc.ssm_heads, lc.ssm_d_head,
                                       lc.ssm_d_state, lc.ssm_groups,
                                       lc.ssm_d_conv)
+        elif lc.mixer == "short_conv":
+            mix = short_conv_shapes(d, lc.conv_kernel)
         else:
             raise ValueError(
                 f"mixer {lc.mixer!r}: expected one of {MIXERS}")
@@ -362,6 +444,8 @@ class HybridMoeBlockImpl(LayerImplBase):
                 live = state["filled"] > 0   # an idle slot caches nothing
             mixed, new_state = cls._attention(lc, params, hn, state,
                                               train, mask)
+        elif lc.mixer == "short_conv":
+            mixed, new_state = short_conv_mixer(params, hn, state, mask)
         else:
             mixed, new_state = mamba2.mamba2_mixer(
                 params, hn, state, mask, n_heads=lc.ssm_heads,
@@ -388,7 +472,8 @@ class HybridMoeBlockImpl(LayerImplBase):
                 None if valid is None else valid.reshape(n * t),
                 top_k=lc.top_k, experts_held=lc.held,
                 kernel=lc.use_kernels, gate_rule=lc.gate_rule,
-                route_scale=lc.route_scale)
+                route_scale=lc.route_scale, route_eps=lc.route_eps,
+                detach_scores=lc.freeze_router)
             counts.update(moe_counts,
                           moe_layer_steps=jnp.asarray(1, jnp.int32))
         else:

@@ -91,26 +91,33 @@ def _pack_columns(v, d_head: int, r: int):
 # ---------------------------------------------------------------------
 # the convolution
 # ---------------------------------------------------------------------
-def causal_conv(xbc, tail, w, b, lengths=None):
-    """Depthwise causal convolution of ``xbc`` ``[B, T, C]`` continuing
+def conv_taps(x, tail, w, lengths=None):
+    """Depthwise causal convolution of ``x`` ``[B, T, C]`` continuing
     ``tail`` ``[B, K - 1, C]`` (the inputs before it; None = zeros).
-    ``w`` is ``[K, C]`` with ``w[K - 1]`` on the current input, ``b``
-    ``[C]``. Returns ``(silu(conv + b), new tail)``; the new tail holds
+    ``w`` is ``[K, C]`` with ``w[K - 1]`` on the current input. Returns
+    ``(the taps' sum [B, T, C] float32, new tail)``; the new tail holds
     the last ``K - 1`` inputs before each row's ``lengths`` (None =
     ``T``), so right-padding never enters it."""
-    bsz, t, c = xbc.shape
+    bsz, t, c = x.shape
     k = w.shape[0]
     if tail is None:
-        tail = jnp.zeros((bsz, k - 1, c), xbc.dtype)
-    seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+        tail = jnp.zeros((bsz, k - 1, c), x.dtype)
+    seq = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     acc = sum(seq[:, j:j + t, :].astype(jnp.float32)
               * w[j].astype(jnp.float32) for j in range(k))
-    out = jax.nn.silu(acc + b.astype(jnp.float32)).astype(xbc.dtype)
     if lengths is None:
         new_tail = seq[:, t:, :]
     else:
         at = lengths[:, None] + jnp.arange(k - 1)[None, :]
         new_tail = jnp.take_along_axis(seq, at[:, :, None], axis=1)
+    return acc, new_tail
+
+
+def causal_conv(xbc, tail, w, b, lengths=None):
+    """``silu(conv_taps(xbc) + b)`` at ``xbc``'s dtype (``b`` ``[C]``)
+    and the new tail: Mamba-2's convolution."""
+    acc, new_tail = conv_taps(xbc, tail, w, lengths)
+    out = jax.nn.silu(acc + b.astype(jnp.float32)).astype(xbc.dtype)
     return out, new_tail
 
 

@@ -201,7 +201,7 @@ GATE_RULES = ("softmax_topk", "sigmoid_bias")
 
 
 def route(logits, top_k: int, rule: str = "softmax_topk", bias=None,
-          scale: float = 1.0):
+          scale: float = 1.0, eps: float = 1e-20):
     """``(gates [M, k] float32, idx [M, k])`` from the router's float32
     ``logits`` ``[M, E]`` under a gate rule:
 
@@ -209,8 +209,14 @@ def route(logits, top_k: int, rule: str = "softmax_topk", bias=None,
       are the softmax over the picked logits (granitemoehybrid);
     - ``"sigmoid_bias"``: score ``s = sigmoid(logits)``, pick the
       ``top_k`` largest ``s + bias`` (``bias`` ``[E]``, a selection
-      term only), the gates are the picked SCORES over their sum
-      (plus 1e-20), times ``scale`` (afmoe, DeepSeek-V3's router)."""
+      term only: it moves picks, never gates, and takes no gradient),
+      the gates are the picked SCORES over their sum plus ``eps`` (the
+      normaliser's epsilon: afmoe and DeepSeek-V3's router 1e-20,
+      lfm2_moe 1e-6), times ``scale``.
+
+    Under a gradient the gates are differentiable in ``logits`` (so the
+    router learns through them) and the picks are not: ``idx`` is
+    whole numbers."""
     if rule == "softmax_topk":
         top, idx = jax.lax.top_k(logits, top_k)
         return jax.nn.softmax(top, axis=-1), idx
@@ -219,15 +225,31 @@ def route(logits, top_k: int, rule: str = "softmax_topk", bias=None,
                          f"{GATE_RULES}")
     score = jax.nn.sigmoid(logits)
     pick = score if bias is None else score + bias.astype(jnp.float32)
-    _, idx = jax.lax.top_k(pick, top_k)
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(pick), top_k)
     g = jnp.take_along_axis(score, idx, axis=-1)
-    return g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20) * scale, idx
+    return g / (jnp.sum(g, axis=-1, keepdims=True) + eps) * scale, idx
+
+
+@jax.custom_vjp
+def _cotangent_where(keep, x):
+    """``x`` itself going forward; going back, its cotangent where
+    ``keep`` and 0 elsewhere. For the rows a grouped product never
+    writes: forward they are dropped after the second product, and a
+    served program is what it was; transposed, nothing may come back
+    through them."""
+    return x
+
+
+_cotangent_where.defvjp(
+    lambda keep, x: (x, keep),
+    lambda keep, g: (None, jnp.where(keep, g, 0)))
 
 
 def dropless_moe(params, tokens, valid=None, *, top_k: int,
                  experts_held: Tuple[int, int], kernel=None,
                  gate_rule: str = "softmax_topk",
-                 route_scale: float = 1.0):
+                 route_scale: float = 1.0, route_eps: float = 1e-20,
+                 detach_scores: bool = False):
     """Routed plus shared experts on ``tokens`` ``[M, D]``.
 
     ``params``: ``router`` ``[D, E]`` (all ``E`` outputs, whatever is
@@ -239,8 +261,19 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
 
     Returns ``(y [M, D], counts)``: the gates follow ``gate_rule``
     (:func:`route`; ``"sigmoid_bias"`` reads ``params["expert_bias"]``
-    ``[E]`` and ``route_scale``), in float32; no token is dropped,
-    whatever the load. ``counts`` are int32 scalars: ``moe_picks`` (token
+    ``[E]``, ``route_scale`` and ``route_eps``), in float32; no token is
+    dropped, whatever the load. It trains: under ``jax.grad`` the
+    grouped products transpose (the library's rule for its kernel:
+    the same kernel on the transposed weights for the rows, ``tgmm``
+    for the weights; ``ragged_dot``'s own elsewhere), the router gets
+    its gradient through the gates, ``expert_bias`` none, and a pick on
+    an expert held elsewhere adds nothing to the value or to any
+    gradient: the rows past the held groups, which the kernel never
+    writes going either way, are SELECTED away from the value and from
+    each product's cotangents (:func:`_cotangent_where`). Under
+    ``detach_scores`` the gates are constants to the gradient: neither
+    the router nor ``tokens`` takes one through them (the block's
+    ``freeze_router``). ``counts`` are int32 scalars: ``moe_picks`` (token
     x pick pairs routed), ``moe_picks_held`` (those on held experts),
     ``moe_experts_touched`` (held experts with at least one row),
     ``moe_load_max`` (the fullest held expert's rows)."""
@@ -253,8 +286,10 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
             f"layer holds {params['We_in'].shape[0]}")
     logits = jnp.dot(tokens, params["router"],
                      preferred_element_type=jnp.float32)
+    if detach_scores:
+        logits = jax.lax.stop_gradient(logits)
     gates, idx = route(logits, top_k, gate_rule,              # [M, k]
-                       params.get("expert_bias"), route_scale)
+                       params.get("expert_bias"), route_scale, route_eps)
     routed = (jnp.ones((m, 1), bool) if valid is None
               else valid.astype(bool)[:, None])
     held = (idx >= lo) & (idx < hi) & routed
@@ -263,14 +298,17 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
     order = jnp.argsort(expert, stable=True)
     sizes = jnp.bincount(expert, length=n_held + 1)[:n_held].astype(
         jnp.int32)
-    xs = tokens[order // top_k]
-    gu = grouped_product(xs, params["We_in"], sizes, kernel)
+    # rows past the groups are never written by the kernel, forward or
+    # transposed, and may hold anything: select them away (do not
+    # scale) from the value, and from each product's cotangents
+    inside = (jnp.arange(m * top_k) < jnp.sum(sizes))[:, None]
+    xs = _cotangent_where(inside, tokens[order // top_k])
+    gu = _cotangent_where(
+        inside, grouped_product(xs, params["We_in"], sizes, kernel))
     f = gu.shape[-1] // 2
     act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(tokens.dtype)
     ys = grouped_product(act, params["We_out"], sizes, kernel)
-    # rows past the groups were never written: select, do not scale
-    ys = jnp.where((jnp.arange(m * top_k) < jnp.sum(sizes))[:, None],
-                   ys, 0)
+    ys = jnp.where(inside, ys, 0)
     back = jnp.argsort(order)
     picked = ys[back].reshape(m, top_k, d).astype(jnp.float32)
     y = jnp.sum(picked * jnp.where(held, gates, 0.0)[..., None], axis=1)

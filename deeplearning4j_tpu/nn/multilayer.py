@@ -233,6 +233,7 @@ class MultiLayerNetwork:
         head_at=None,
         live=None,
         counters=None,
+        logits: bool = False,
     ):
         """Returns (final_or_all_activations, new_state, new_rnn_state).
 
@@ -241,7 +242,12 @@ class MultiLayerNetwork:
         runs once a row and not once a position. ``live`` ``[N]`` (a
         caller that batches slots): which rows exist; ``counters``: a
         dict that layers add what they counted in this pass into, by
-        name. Both go to the layers whose bean has ``wants_live``."""
+        name. Both go to the layers whose bean has ``wants_live``,
+        training or not (under a gradient the caller hands the dict
+        back as part of the loss's auxiliary output). ``logits``: the
+        LAST layer stops at its ``logits`` ``[N, T, V]`` float32 (a
+        head scored on label ids, ``_loss_fn``) and makes no
+        probabilities."""
         cd = self._compute_dtype
         out_f32 = self._out_at_master_dtype
         last_si = str(self.n_layers - 1)
@@ -272,16 +278,23 @@ class MultiLayerNetwork:
             is_recurrent = isinstance(c.layer, L.RECURRENT_LAYER_TYPES)
             mask = feature_mask if is_recurrent else None
 
-            rows = ({"live": live,
-                     "counters": None if train else counters}
-                    if getattr(c.layer, "wants_live", False) else {})
-
-            def _apply(p, xin, lst, lrng, lmask, _c=c, _impl=impl,
-                       _rows=rows):
-                return _impl.apply(
-                    _c, p, xin, state=lst, train=train, rng=lrng,
-                    mask=lmask, **_rows,
-                )
+            if logits and si == last_si:
+                def _apply(p, xin, lst, lrng, lmask, _c=c, _impl=impl):
+                    return _impl.logits(_c, p, xin), None, {}
+            else:
+                def _apply(p, xin, lst, lrng, lmask, _c=c, _impl=impl,
+                           _wants=getattr(c.layer, "wants_live", False)):
+                    # what the layer counts comes back as an OUTPUT (a
+                    # dict filled from outside would leak a tracer out
+                    # of a recomputed layer) and is added up below
+                    counted = {}
+                    rows = ({"live": live, "counters": counted}
+                            if _wants else {})
+                    out, lst = _impl.apply(
+                        _c, p, xin, state=lst, train=train, rng=lrng,
+                        mask=lmask, **rows,
+                    )
+                    return out, lst, counted
 
             if self.conf.remat:
                 _apply = jax.checkpoint(_apply)
@@ -297,10 +310,13 @@ class MultiLayerNetwork:
                 x = jnp.take_along_axis(
                     x, head_at.astype(jnp.int32)[:, None, None], axis=2)
                 mask = None
-            x, st = _apply(
+            x, st, counted = _apply(
                 layer_params, x, layer_state,
                 rngs[i] if train else None, mask,
             )
+            if counters is not None:
+                for name, value in counted.items():
+                    counters[name] = counters.get(name, 0) + value
             if st is not None:
                 if cd is not None:
                     # keep carried state at the master dtype so repeated
@@ -317,10 +333,17 @@ class MultiLayerNetwork:
         return (acts if collect else x), new_state, new_rnn
 
     def _loss_fn(
-        self, params, state, rng, features, labels, feature_mask, label_mask
+        self, params, state, rng, features, labels, feature_mask, label_mask,
+        counters=None,
     ):
+        """The training score and the layers' new state. A head whose
+        bean has ``takes_label_ids`` is scored on its float32 logits
+        against labels that are class ids; every other output layer on
+        its activations against labels of their shape. ``counters``: a
+        dict the layers add this pass's counts into (``_step_body``)."""
         out, new_state, _ = self._forward_fn(
-            params, state, features, rng, True, feature_mask
+            params, state, features, rng, True, feature_mask,
+            counters=counters, logits=self.takes_label_ids,
         )
         out_conf = self.conf.confs[-1]
         impl = self._impls[-1]
@@ -368,20 +391,35 @@ class MultiLayerNetwork:
             new_params[si] = jax.tree.map(
                 lambda p, u: p - u, params[si], updates
             )
+            # a leaf the layer's impl names is no updater's to move,
+            # whatever the rule (weight decay, a gradient that is not
+            # exactly 0): it stays the array it was
+            frozen = getattr(self._impls[i], "frozen_leaves", None)
+            for name in frozen(c.layer) if frozen else ():
+                if name in params[si]:
+                    new_params[si][name] = params[si][name]
         return new_params, new_upd
 
     def _step_body(self, params, state, upd_state, iteration, rng, features,
                    labels, feature_mask, label_mask, grad_scale=1.0):
-        (score, new_state), grads = jax.value_and_grad(
-            self._loss_fn, has_aux=True
-        )(params, state, rng, features, labels, feature_mask, label_mask)
+        def loss(p):
+            counted = {}
+            score, new_state = self._loss_fn(
+                p, state, rng, features, labels, feature_mask,
+                label_mask, counters=counted)
+            return score, (new_state, counted)
+
+        (score, (new_state, counted)), grads = jax.value_and_grad(
+            loss, has_aux=True)(params)
         new_params, new_upd = self._apply_updates(
             params, upd_state, grads, iteration, grad_scale)
         # Gradient-health scalars ride as extra outputs of THE SAME
         # executable whether a listener is attached or not: telemetry
         # on/off cannot change compile counts or the param trajectory
         # (ISSUE 8 invariant). Unfetched, they cost a few reduction ops.
-        health = grad_health(grads, params, new_params)
+        # What the layers counted in the forward pass (an expert
+        # block's ``moe_*``) rides with them, under the layers' names.
+        health = dict(grad_health(grads, params, new_params), **counted)
         return new_params, new_state, new_upd, score, health
 
     @functools.cached_property
@@ -438,12 +476,21 @@ class MultiLayerNetwork:
     def fit_scan(self, features_stacked, labels_stacked,
                  features_mask_stacked=None, labels_mask_stacked=None,
                  grad_scale: float = 1.0):
-        """Run one scanned pass over pre-stacked batches
-        ([K, B, ...], [K, B, n_out], optional masks [K, B, T]); returns
-        the K per-step scores as a device array (convert with np.asarray
-        to force a sync — kept lazy here so chained calls pipeline
-        without a host round-trip each). Plain-SGD fast path — use fit()
-        when tBPTT or a second-order solver is configured."""
+        """Run one scanned pass over pre-stacked batches; returns the K
+        per-step scores as a device array (convert with np.asarray to
+        force a sync — kept lazy here so chained calls pipeline without
+        a host round-trip each).
+
+        Features are ``[K, B, ...]`` at the net's dtype, or ``[K, B, T]``
+        token ids for a net whose first layer embeds them
+        (``takes_token_ids``): ids stay whole numbers into the gather.
+        Labels are ``[K, B, n_out]`` / ``[K, B, n_out, T]`` at the
+        net's dtype, or ``[K, B, T]`` class ids for a head scored on
+        ids (``takes_label_ids``: the loss is the log-softmax of the
+        head's float32 logits gathered at the label, no one-hot).
+        Optional masks ``[K, B, T]``. Every first-order updater rule
+        (SGD, Adam, ...) runs here; use fit() when tBPTT or a
+        second-order solver is configured."""
         if self.conf.backprop_type == BackpropType.TRUNCATED_BPTT:
             raise ValueError(
                 "fit_scan is the full-BPTT SGD fast path; truncated-BPTT "
@@ -453,8 +500,8 @@ class MultiLayerNetwork:
             raise ValueError(
                 f"fit_scan only supports SGD, not {algo}; use fit()")
         self.init()
-        feats = jnp.asarray(features_stacked, self._dtype)
-        labels = jnp.asarray(labels_stacked, self._dtype)
+        feats = self._as_input(features_stacked)
+        labels = self._as_labels(labels_stacked)
         self._key, sub = jax.random.split(self._key)
         start = self.iteration
         if features_mask_stacked is not None or labels_mask_stacked is not None:
@@ -479,7 +526,8 @@ class MultiLayerNetwork:
              health) = step_fn(
                 self.params, self.state, self.updater_state,
                 self.iteration, sub, feats, labels, *extra, grad_scale)
-        k, examples, tokens = window_counts(feats.shape)
+        k, examples, tokens = window_counts(
+            feats.shape, ids=self.takes_token_ids)
         self.train_telemetry.record_step(
             dispatch_s=time.perf_counter() - t0, steps=k,
             examples=examples, tokens=tokens, health=health)
@@ -633,11 +681,11 @@ class MultiLayerNetwork:
             Solver(self).optimize(ds)
             return
         n_iter = max(1, self.conf.confs[0].num_iterations)
-        feats = jnp.asarray(ds.features, self._dtype)
-        labels = jnp.asarray(ds.labels, self._dtype)
+        feats = self._as_input(ds.features)
+        labels = self._as_labels(ds.labels)
         fm = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
         lm = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
-        examples, tokens = batch_counts(feats)
+        examples, tokens = batch_counts(feats, ids=self.takes_token_ids)
         for _ in range(n_iter):
             self._key, sub = jax.random.split(self._key)
             t0 = time.perf_counter()
@@ -757,6 +805,19 @@ class MultiLayerNetwork:
             return jnp.asarray(x, jnp.int32)
         return jnp.asarray(x, self._dtype)
 
+    @property
+    def takes_label_ids(self) -> bool:
+        """True where the output layer is scored on class ids
+        (``[N, T]`` int32 labels) from its logits rather than on
+        one-hot columns from its activations."""
+        return bool(getattr(self.conf.confs[-1].layer,
+                            "takes_label_ids", False))
+
+    def _as_labels(self, y) -> Array:
+        if self.takes_label_ids:
+            return jnp.asarray(y, jnp.int32)
+        return jnp.asarray(y, self._dtype)
+
     def output(self, x, train: bool = False) -> Array:
         self.init()
         x = self._as_input(x)
@@ -765,7 +826,7 @@ class MultiLayerNetwork:
     def feed_forward(self, x, train: bool = False) -> List[Array]:
         """All layer activations, input first (reference feedForward)."""
         self.init()
-        x = jnp.asarray(x, self._dtype)
+        x = self._as_input(x)
         acts, _, _ = self._forward_fn(
             self.params, self.state, x, None, False, collect=True
         )
@@ -780,8 +841,8 @@ class MultiLayerNetwork:
         if ds is None:
             return float(self.score_value)
         self.init()
-        feats = jnp.asarray(ds.features, self._dtype)
-        labels = jnp.asarray(ds.labels, self._dtype)
+        feats = self._as_input(ds.features)
+        labels = self._as_labels(ds.labels)
         fm = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
         lm = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
         s, _ = self._loss_eval(self.params, self.state, feats, labels, fm, lm)
@@ -790,7 +851,8 @@ class MultiLayerNetwork:
     @functools.cached_property
     def _loss_eval(self):
         def f(params, state, x, y, fm, lm):
-            out, _, _ = self._forward_fn(params, state, x, None, False, fm)
+            out, _, _ = self._forward_fn(params, state, x, None, False, fm,
+                                         logits=self.takes_label_ids)
             if self._compute_dtype is not None:
                 out = _cast_floating(out, dtype=self._dtype)  # loss in f32
             impl = self._impls[-1]
@@ -805,8 +867,8 @@ class MultiLayerNetwork:
     # ------------------------------------------------------------------
     def compute_gradient_and_score(self, ds) -> Tuple[float, Gradient]:
         self.init()
-        feats = jnp.asarray(ds.features, self._dtype)
-        labels = jnp.asarray(ds.labels, self._dtype)
+        feats = self._as_input(ds.features)
+        labels = self._as_labels(ds.labels)
         fm = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
         lm = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
         score, grads, _ = self._grad_and_score(

@@ -129,6 +129,55 @@ _LOSSES: dict[LossFunction, Callable] = {
 }
 
 
+@jax.custom_vjp
+def _label_nll_sum(logits: Array, labels: Array, weights: Array) -> Array:
+    """``sum_i weights_i (logsumexp(logits_i) - logits_i[labels_i])``
+    over ``logits`` ``[M, V]`` float32, ``labels`` ``[M]`` whole
+    numbers, ``weights`` ``[M]``. Its own transpose rule, so that what
+    is kept for the way back is the logits and one number a row, and
+    what comes back is ONE ``[M, V]`` array, ``(softmax - hit) x
+    weight``: the hit is a comparison fused into that pass, never a
+    one-hot array."""
+    return _label_nll_fwd(logits, labels, weights)[0]
+
+
+def _label_nll_fwd(logits, labels, weights):
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+    return jnp.sum((lse - picked) * weights), (logits, lse, labels,
+                                               weights)
+
+
+def _label_nll_bwd(res, g):
+    logits, lse, labels, weights = res
+    p = jnp.exp(logits - lse[:, None])
+    hit = jax.lax.broadcasted_iota(labels.dtype, logits.shape,
+                                   1) == labels[:, None]
+    return (jnp.where(hit, p - 1.0, p) * (g * weights)[:, None], None,
+            None)
+
+
+_label_nll_sum.defvjp(_label_nll_fwd, _label_nll_bwd)
+
+
+def label_cross_entropy(logits: Array, labels: Array,
+                        mask: Optional[Array] = None) -> Array:
+    """MCXENT for a head that hands over its LOGITS ``[..., V]``
+    (float32) and labels that are class ids ``[...]``: the mean over
+    (masked) positions of ``-log softmax(logits)[label]``, the same
+    number ``mcxent`` gives for softmax outputs and one-hot labels,
+    with nothing of the logits' shape made but their gradient."""
+    v = logits.shape[-1]
+    flat = logits.reshape(-1, v).astype(jnp.float32)
+    ids = labels.reshape(-1).astype(jnp.int32)
+    if mask is None:
+        weights = jnp.full(ids.shape, 1.0 / ids.shape[0], jnp.float32)
+    else:
+        m = mask.reshape(-1).astype(jnp.float32)
+        weights = m / jnp.maximum(jnp.sum(m), 1.0)
+    return _label_nll_sum(flat, ids, weights)
+
+
 def loss_fn(which: LossFunction | str) -> Callable[..., Array]:
     """Look up ``(activations, labels, mask=None) -> scalar`` by name."""
     if isinstance(which, str):

@@ -133,7 +133,12 @@ class TracingIterationListener(IterationListener):
       batched ``observe(value, n=steps)`` form so a fused fit_scan
       window of K steps costs one lock acquisition,
     - fetches the step's gradient-health outputs (computed INSIDE the
-      already-run jitted step; the fetch rides the same sync domain),
+      already-run jitted step; the fetch rides the same sync domain)
+      and, with them, what the layers counted in the step's forward
+      pass (an expert block's ``moe_picks``, ``moe_picks_held``,
+      ``moe_experts_touched``, ``moe_load_max``, ``moe_layer_steps``):
+      summed over the window, they go into the record, onto the
+      ``train.dispatch`` span's args and into ``train_<name>`` counters,
     - emits a ``train.step`` span carrying the full breakdown in its
       args plus contiguous ``train.data_wait`` / ``train.dispatch`` /
       ``train.sync`` child spans for Perfetto,
@@ -189,6 +194,11 @@ class TracingIterationListener(IterationListener):
                 snap["data_wait_s"] / steps, steps)
             health = T.fetch_health(snap["health"])
             nonfinite = 0.0
+            # what the layers counted in the step's forward pass (an
+            # expert block's ``moe_*``), summed over the window's steps
+            counted = {key: sum(values) for key, values in
+                       (health or {}).items() if key not in T.HEALTH_KEYS}
+            record.update(counted)
             if health:
                 for key, track in (
                         ("grad_norm", "train_grad_norm"),
@@ -210,14 +220,14 @@ class TracingIterationListener(IterationListener):
             )
             if self.tracer is not None:
                 self._emit_trace(iteration, score, snap, sync_s,
-                                 nonfinite)
+                                 nonfinite, counted)
         elif self.tracer is not None:
             self.tracer.counter("train_score", score)
         if self.metrics_log is not None:
             self.metrics_log.write(record)
 
     def _emit_trace(self, iteration, score, snap, sync_s,
-                    nonfinite) -> None:
+                    nonfinite, counted) -> None:
         tracer = self.tracer
         wall_us = snap["wall_s"] * 1e6
         end_us = tracer.now_us()
@@ -235,7 +245,7 @@ class TracingIterationListener(IterationListener):
                         snap["data_wait_s"] * 1e6)
         tracer.complete("train.dispatch",
                         start_us + snap["data_wait_s"] * 1e6,
-                        snap["dispatch_s"] * 1e6)
+                        snap["dispatch_s"] * 1e6, **counted)
         tracer.complete("train.sync", end_us - sync_s * 1e6,
                         sync_s * 1e6)
         tracer.counter("train_score", score)
@@ -247,6 +257,8 @@ class TracingIterationListener(IterationListener):
         tracer.incr("train_steps_total", snap["steps"])
         if nonfinite:
             tracer.incr("train_nonfinite_grads", nonfinite)
+        for key, value in counted.items():
+            tracer.incr(f"train_{key}", value)
 
 
 class BestScoreIterationListener(IterationListener):

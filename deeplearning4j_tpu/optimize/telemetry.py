@@ -242,27 +242,32 @@ class TrainTelemetry:
         return snap
 
 
-def batch_counts(features) -> tuple:
+def batch_counts(features, ids: bool = False) -> tuple:
     """(examples, tokens) of one batch: tokens is B*T for EXACTLY
-    rank-3 ([B, C, T]) time-series features; any other rank (2-D
+    rank-3 ([B, C, T]) time-series features, or for ``[B, T]`` token
+    ids (``ids``: the net's ``takes_token_ids``); any other rank (2-D
     dense, 4-D conv images) counts tokens == examples — a [B, C, H, W]
     image batch must not report B*H as a token rate."""
     shape = getattr(features, "shape", None)
     if not shape:
         return 0, 0
     examples = int(shape[0])
+    if ids and len(shape) == 2:
+        return examples, examples * int(shape[1])
     tokens = examples * int(shape[2]) if len(shape) == 3 else examples
     return examples, tokens
 
 
-def window_counts(shape) -> tuple:
+def window_counts(shape, ids: bool = False) -> tuple:
     """(steps, examples, tokens) of one stacked fit_scan window
     ([K, B, ...]; tokens = K*B*T only for exactly [K, B, C, T] time
-    series, mirroring :func:`batch_counts`). Shape-only — never slices
-    a device array (a host-side ``feats[0]`` would dispatch a gather
-    executable just to read a shape)."""
+    series or [K, B, T] token ids, mirroring :func:`batch_counts`).
+    Shape-only — never slices a device array (a host-side ``feats[0]``
+    would dispatch a gather executable just to read a shape)."""
     k = int(shape[0])
     examples = k * int(shape[1])
+    if ids and len(shape) == 3:
+        return k, examples, examples * int(shape[2])
     tokens = (examples * int(shape[3]) if len(shape) == 4
               else examples)
     return k, examples, tokens

@@ -23,6 +23,7 @@ EXAMPLES = [
     ("long_context_transformer.py", []),
     ("mnist_mlp.py", []),
     ("moe_expert_parallel.py", []),
+    ("moe_lm_on_token_ids.py", []),
     ("native_pjrt_client.py", []),
     ("pipeline_4d_training.py", []),
     ("sequence_parallel_transformer.py", []),

@@ -159,6 +159,53 @@ class TestTelemetryExactness:
         assert (dark._train_step._cache_size()
                 == observed._train_step._cache_size() == 1)
 
+    def test_expert_counters_ride_the_same_step_on_and_off(self, tmp_path):
+        """An expert net's step returns what its blocks counted
+        (``moe_*``) beside the health scalars: with the listener on
+        they reach the record, the ``train.dispatch`` span and the
+        ``train_<name>`` counters; on or off, one executable and the
+        same parameters (the invariant ``_step_body`` states)."""
+        from deeplearning4j_tpu.models.zoo import lfm2_moe_lm
+        from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+        def net_of():
+            return MultiLayerNetwork(lfm2_moe_lm(
+                vocab_size=32, hidden_size=32, layers=[1, 2, 3],
+                experts_held=(2, 6), warmup_steps=1, remat=True)).init()
+
+        toks = np.random.default_rng(0).integers(0, 32, (3, 2, 13))
+        dark, observed = net_of(), net_of()
+        tracer = Tracer()
+        path = str(tmp_path / "moe.jsonl")
+        with MetricsLog(path) as log:
+            observed.set_listeners(TracingIterationListener(
+                tracer=tracer, metrics_log=log))
+            for net in (dark, observed):
+                for _ in range(2):
+                    net.fit_scan(toks[:, :, :-1], toks[:, :, 1:])
+        assert (dark._train_steps_scan._cache_size()
+                == observed._train_steps_scan._cache_size() == 1)
+        for a, b in zip(jax.tree.leaves(dark.params),
+                        jax.tree.leaves(observed.params)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+        names = ("moe_picks", "moe_picks_held", "moe_experts_touched",
+                 "moe_load_max", "moe_layer_steps")
+        records = MetricsLog.read(path)
+        assert len(records) == 2
+        for rec in records:
+            # 3 steps x 3 expert layers; 2 x 12 tokens x top 2 a layer
+            assert rec["moe_layer_steps"] == 9
+            assert rec["moe_picks"] == 9 * 2 * 12 * 2
+            assert 0 < rec["moe_picks_held"] < rec["moe_picks"]
+            assert 0 < rec["moe_experts_touched"] <= 9 * 4
+        spans = [e for e in tracer.events()
+                 if e["ph"] == "X" and e["name"] == "train.dispatch"]
+        assert len(spans) == 2
+        assert all(set(names) <= set(e["args"]) for e in spans)
+        assert tracer.latest_counters()["train_moe_picks"] == 2 * 432
+        # the dark net carries the same counts, unfetched
+        assert set(names) <= set(dark.train_telemetry.health)
+
     def test_no_retrace_with_telemetry_on(self, assert_no_retrace):
         ds = _batch()
         net = _mlp()
