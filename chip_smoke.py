@@ -209,11 +209,11 @@ def kernel_phase(size, log) -> None:
         def draw(dtype, *shape):
             return jnp.asarray(rng.normal(size=shape), dtype)
 
-        # the engine's dtypes: a bf16 chunk against the float32 pool
-        # (carried state stays at the master dtype, nn/multilayer.py)
+        # the engine's dtypes: a bf16 chunk against the bf16 pool (the
+        # pool is made at the compute dtype, serving/engine.py)
         q, k, v = (draw(jnp.bfloat16, b, h, t, dh) for _ in range(3))
-        pk = draw(jnp.float32, nb, bt, h, dh).at[free[0]].set(jnp.nan)
-        pv = draw(jnp.float32, nb, bt, h, dh).at[free[0]].set(jnp.nan)
+        pk = draw(jnp.bfloat16, nb, bt, h, dh).at[free[0]].set(jnp.nan)
+        pv = draw(jnp.bfloat16, nb, bt, h, dh).at[free[0]].set(jnp.nan)
         lens = rng.integers(1, t + 1, b)
         lens[0] = t
         mask = (None if t == 1 else jnp.asarray(
@@ -237,8 +237,9 @@ def kernel_phase(size, log) -> None:
         log(f"kernel: {what}: paged kernel vs gather program max|diff| "
             f"{diff:.4f} (mean|out| "
             f"{float(np.abs(outs[False]).mean()):.4f}; bf16 chunk, "
-            "f32 pool)")
-        # a few bf16 ulps at |out| ~ 1; an all-bf16 run read 0.016
+            "bf16 pool)")
+        # a few bf16 ulps at |out| ~ 1 (the gather program rounds its
+        # scores to bf16, the kernel lifts to float32)
         check(diff <= 0.0625, f"{what}: kernel differs from the gather "
               f"program by {diff}")
 

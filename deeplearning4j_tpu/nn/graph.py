@@ -41,6 +41,7 @@ from deeplearning4j_tpu.nn.gradient import Gradient
 from deeplearning4j_tpu.nn.layers import get_impl
 from deeplearning4j_tpu.nn.multilayer import (
     _REGULARIZED_KEYS,
+    _carried_state,
     _cast_floating,
     _dtype_of,
     _resolve_compute_dtype,
@@ -166,7 +167,13 @@ class ComputationGraph:
         (activation dict, new_state, new_rnn_state) — ``rnn_state`` is the
         per-vertex recurrent carry (reference ComputationGraph
         rnnActivateUsingStoredState :1233: stored state fed back in for
-        streaming inference and truncated-BPTT window chaining)."""
+        streaming inference and truncated-BPTT window chaining).
+
+        Under mixed precision a state leaf a layer was handed leaves
+        the pass at the dtype it came in with, and a leaf the pass
+        created (no incoming state) at the master dtype
+        (``multilayer._carried_state``, the same rule as
+        ``MultiLayerNetwork._forward_fn``)."""
         out_f32_vertices = self._out_f32_vertices
         # Mixed precision: bf16 compute, f32 master params (same
         # scheme as MultiLayerNetwork._forward_fn)
@@ -228,11 +235,11 @@ class ComputationGraph:
                 )
                 if st is not None:
                     if self._compute_dtype is not None:
-                        # carried state stays at master dtype so repeated
-                        # steps see stable input dtypes (no recompiles)
-                        st = jax.tree_util.tree_map(
-                            functools.partial(_cast_floating,
-                                              dtype=self._dtype), st)
+                        # carried state goes out at the dtype it came
+                        # in with (created here: the master dtype), so
+                        # repeated steps see stable input dtypes (no
+                        # recompiles)
+                        st = _carried_state(st, layer_state, self._dtype)
                     if name in new_state:
                         new_state[name] = st
                     else:

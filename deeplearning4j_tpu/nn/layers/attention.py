@@ -826,18 +826,41 @@ def _paged_table_entries(ring_slots: int, window: int,
 
 
 def _paged_blocks_per_step(block_tokens: int, n_heads: int,
-                           head_dim: int, pool_dtype, ntab: int) -> int:
+                           head_dim: int, pool_dtype, ntab: int,
+                           grp: int = 1, t: int = 1) -> int:
     """P, the table entries (pool blocks) one compute block of the paged
-    kernel holds: as many as keep the double-buffered K and V scratch
-    inside ``_PAGED_POOL_VMEM`` at the pool's tiled size (the head axis
-    pads to the dtype's sublane tile, the head dim to 128 lanes), at
-    most ``_PAGED_MAX_BLOCKS`` and never more than the walk is long."""
+    kernel holds, for ``n_heads`` KV heads each serving ``grp`` query
+    heads and a chunk of ``t`` queries: as many as keep the
+    double-buffered K and V scratch inside ``_PAGED_POOL_VMEM`` at the
+    pool's tiled size (the head axis pads to the dtype's sublane tile,
+    the head dim to 128 lanes), at most ``_PAGED_MAX_BLOCKS`` and never
+    more than the walk is long. The short form lifts a compute block to
+    float32 before any arithmetic, so there the block is reckoned at 4
+    bytes a number whatever the pool holds: its time is the vector
+    unit's work a key, and on a bf16 pool a block of 8 entries beat one
+    of 16 at every occupancy but a full batch of full windows (my chip
+    run, PR 35: ``PERF.md`` section 6)."""
     itemsize = jnp.dtype(pool_dtype).itemsize
+    if _paged_tile_form(grp, _paged_q_tile(t), n_heads) == "short":
+        itemsize = max(itemsize, 4)
     sublanes = 8 * max(1, 4 // itemsize)
     entry = (block_tokens * -(-n_heads // sublanes) * sublanes
              * -(-head_dim // 128) * 128 * itemsize)
     return max(1, min(_PAGED_POOL_VMEM // (4 * entry),
                       _PAGED_MAX_BLOCKS, ntab))
+
+
+def _paged_tile_form(grp: int, tq: int, n_kv_heads: int) -> str:
+    """How the paged kernel scores a compute block (its docstring has
+    the three forms): ``"short"``, a short tile of ungrouped heads on
+    the vector unit in float32; ``"flat"``, a short tile in ONE MXU
+    product over the block's (key, head) rows, for grouped heads; and
+    ``"tile"``, a product a KV head, for everything else."""
+    if tq > _PAGED_SHORT_TILE:
+        return "tile"
+    if grp == 1:
+        return "short"
+    return "flat" if n_kv_heads & (n_kv_heads - 1) == 0 else "tile"
 
 
 def _paged_walk_tiles(table, base, floor, filled, bt, tm, p_blk, t):
@@ -1065,10 +1088,9 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
     # the three forms of a compute block's scoring (the docstring):
     # short, one key head a query head, on the vector unit; flat, a
     # short tile of GROUPED heads, all heads in one MXU product; tile
-    short = grp == 1 and tq <= _PAGED_SHORT_TILE
-    flat = (grp > 1 and tq <= _PAGED_SHORT_TILE
-            and h_sz & (h_sz - 1) == 0)
-    p_blk = _paged_blocks_per_step(bt, h_sz, dh, pk.dtype, ntab)
+    form = _paged_tile_form(grp, tq, h_sz)
+    short, flat = form == "short", form == "flat"
+    p_blk = _paged_blocks_per_step(bt, h_sz, dh, pk.dtype, ntab, grp, t)
     n_keys = p_blk * bt
     scale = dh ** -0.5
     # a row's first and last mapped entry bound its walk (none mapped,

@@ -70,6 +70,25 @@ def _cast_floating(a, dtype):
     return a
 
 
+def _carried_state(new, handed, master_dtype):
+    """A layer's new state as a mixed-precision pass hands it on: a
+    floating leaf the layer was HANDED (same place in ``handed``) goes
+    out at the dtype it came in with, a leaf the pass created at the
+    master dtype. What goes in comes out, so repeated steps see stable
+    input dtypes and compile once, whatever dtype the holder of the
+    state keeps it at."""
+    came = (dict(jax.tree_util.tree_flatten_with_path(handed)[0])
+            if handed is not None else {})
+
+    def carry(path, leaf):
+        was = getattr(came.get(path), "dtype", None)
+        if was is None or not jnp.issubdtype(was, jnp.floating):
+            was = master_dtype
+        return _cast_floating(leaf, was)
+
+    return jax.tree_util.tree_map_with_path(carry, new)
+
+
 def _resolve_compute_dtype(master_dtype, compute_dtype_name):
     """Mixed-precision compute dtype, or None when it matches master."""
     if not compute_dtype_name:
@@ -247,7 +266,12 @@ class MultiLayerNetwork:
         back as part of the loss's auxiliary output). ``logits``: the
         LAST layer stops at its ``logits`` ``[N, T, V]`` float32 (a
         head scored on label ids, ``_loss_fn``) and makes no
-        probabilities."""
+        probabilities.
+
+        Under mixed precision a state leaf a layer was handed leaves
+        the pass at the dtype it came in with, and a leaf the pass
+        created (no incoming state) at the master dtype
+        (``_carried_state``)."""
         cd = self._compute_dtype
         out_f32 = self._out_at_master_dtype
         last_si = str(self.n_layers - 1)
@@ -319,11 +343,11 @@ class MultiLayerNetwork:
                     counters[name] = counters.get(name, 0) + value
             if st is not None:
                 if cd is not None:
-                    # keep carried state at the master dtype so repeated
-                    # steps see stable input dtypes (no recompiles)
-                    st = jax.tree_util.tree_map(
-                        functools.partial(_cast_floating,
-                                          dtype=self._dtype), st)
+                    # carried state goes out at the dtype it came in
+                    # with (created here: the master dtype), so
+                    # repeated steps see stable input dtypes (no
+                    # recompiles)
+                    st = _carried_state(st, layer_state, self._dtype)
                 if state and si in state:
                     new_state[si] = st
                 else:

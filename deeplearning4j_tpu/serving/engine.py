@@ -176,6 +176,29 @@ from deeplearning4j_tpu.serving.tp import TPContext
 _UNSET = object()
 
 
+#: VMEM of a TPU core (v5e: 128 MiB): the compiler may stage a whole
+#: buffer that fits it through it
+_TPU_VMEM_BYTES = 128 << 20
+
+
+def _compiler_options(leaf_bytes: int) -> Optional[Dict[str, str]]:
+    """What the engine tells the TPU's compiler about its programs,
+    given the bytes of its smallest pool leaf: where a leaf FITS the
+    chip's VMEM (the block's serving cell's at bf16: 92 MB), that a
+    buffer is not to be staged through VMEM for a use that touches
+    under nine tenths of it. Every program takes the pool, donated, and
+    writes a few rows of it; such a leaf the compiler otherwise copies
+    in for the append's scatter and out again, a whole leaf each way a
+    step, on the HBM the weights' products are bound by (my chip runs,
+    PR 35: a decode round 43.7 -> 41.6 ms; a leaf of 137 MB never
+    moved). None for a pool of larger leaves, whose programs compile as
+    they always did, and off the TPU, where the option does not
+    exist."""
+    if jax.default_backend() != "tpu" or leaf_bytes > _TPU_VMEM_BYTES:
+        return None
+    return {"xla_tpu_msa_inefficient_use_to_copy_ratio": "0.9"}
+
+
 @dataclasses.dataclass
 class _Slot:
     request: Request
@@ -563,6 +586,12 @@ class _KvKind:
 
     window: int
     layers: List[str]
+    #: query heads a KV head of these layers serves (the paged kernel's
+    #: form, and with it its compute block, follows it)
+    group: int = 1
+    #: numbers a token's keys (or values) are in one layer: KV heads x
+    #: head dim
+    token_width: int = 0
     ring: int = 0
     slot_worst: int = 0
     pool: Optional[BlockPool] = None
@@ -599,7 +628,13 @@ class DecodeEngine:
     Keys and values live in a pool of ``kv_blocks`` blocks of
     ``block_tokens`` tokens per attention layer (the engine's only KV
     layout); a slot's block table grows a block at a time and frees
-    blocks that slide out of every layer's window.
+    blocks that slide out of every layer's window. A cell of the pool
+    has the dtype the layers compute keys and values in (the net's
+    compute dtype where it has one, else its master dtype), so a token
+    costs ``2 x KV heads x head dim x that width`` bytes a layer:
+    ``stats["kv_bytes_per_token"]`` over all layers and
+    ``["kv_dtype_bytes"]`` say what the pool holds (a float32-master /
+    bf16-compute net: 2 bytes a number, the numbers the layers made).
     ``prefix_cache_rows > 0`` enables the radix prefix cache (a trie
     of at most that many entries, each leasing pool blocks;
     serving/prefix_cache.py):
@@ -861,10 +896,19 @@ class DecodeEngine:
         #: slot has a block table a kind, and a kind its own pool of
         #: blocks, so that a narrow window's layers hold only what they
         #: can still reach (one kind: every net whose layers agree)
+        def kind_of(w):
+            mine = [(name, bean) for name, bean in attn_items
+                    if bean.stream_max_t == w]
+            bean = mine[0][1]
+            kv_heads = getattr(bean, "n_kv_heads", bean.n_heads)
+            return _KvKind(
+                w, [name for name, _ in mine],
+                group=bean.n_heads // kv_heads,
+                token_width=kv_heads * (getattr(bean, "head_dim", 0)
+                                        or bean.n_out // bean.n_heads))
+
         self._kinds: List[_KvKind] = [
-            _KvKind(w, [name for name, bean in attn_items
-                        if bean.stream_max_t == w])
-            for w in sorted(set(windows), reverse=True)]
+            kind_of(w) for w in sorted(set(windows), reverse=True)]
         # the longest prompt: the narrowest window where a cold
         # admission prefills a dense row (it takes no band); the widest
         # where several kinds make every admission a paged one, whose
@@ -1058,6 +1102,9 @@ class DecodeEngine:
                 f"kv_blocks {self.kv_blocks} cannot hold one "
                 f"slot's window + one round of writes "
                 f"({slot_worst} blocks of {bt} tokens)")
+        #: the compiler options of the programs built from here on
+        #: (``_jit``): decided once the kinds' pools are sized
+        self._jit_options = None
         left = self.kv_blocks
         for i, kind in enumerate(self._kinds):
             n = (left if i == len(self._kinds) - 1 else max(
@@ -1065,6 +1112,10 @@ class DecodeEngine:
                 self.kv_blocks * kind.slot_worst // slot_worst))
             kind.pool = BlockPool(n, bt, jit_wrap=self._jit)
             left -= n
+        cell = jnp.dtype(net._compute_dtype or net._dtype).itemsize
+        self._jit_options = _compiler_options(min(
+            k.pool.n_blocks * bt * k.token_width * cell // self.tp
+            for k in self._kinds))
         #: the widest kind's allocator (a one-kind net's only one)
         self.block_pool = self._kinds[0].pool
         #: the prefix trie: entries lease pool BLOCKS (zero-copy); the
@@ -1232,6 +1283,9 @@ class DecodeEngine:
             "paged_blocks_live": 0, "paged_blocks_walked": 0,
             "paged_blocks_per_step": 0, "paged_steps_per_row": 0,
             "paged_steps_paid": 0,
+            # the pool's bytes a token over all KV layers and the
+            # width of one of its cells; both land with the pool
+            "kv_bytes_per_token": 0, "kv_dtype_bytes": 0,
             # host-to-device transfers made for the block-table
             # operand, summed over paged dispatches: one a dispatch,
             # whatever the number of paged layers (ISSUE 28)
@@ -1318,7 +1372,8 @@ class DecodeEngine:
         the compile-count discipline reads through unchanged."""
         if self.tp_ctx is not None:
             return self.tp_ctx.wrap(fn, donate_argnums=donate_argnums)
-        return jax.jit(fn, donate_argnums=donate_argnums)
+        return jax.jit(fn, donate_argnums=donate_argnums,
+                       compiler_options=self._jit_options)
 
     def _place(self, tree):
         """Commit a fresh device pytree onto the TP mesh under its
@@ -2318,7 +2373,8 @@ class DecodeEngine:
         bt = self.block_tokens
         ntab = _paged_table_entries(kind.ring, kind.window, bt, chunk)
         per_step = _paged_blocks_per_step(
-            bt, pk.shape[2] // self.tp, pk.shape[3], pk.dtype, ntab)
+            bt, pk.shape[2] // self.tp, pk.shape[3], pk.dtype, ntab,
+            kind.group, chunk)
         if chunk == 1 and kind is self._kinds[0]:
             self.stats["paged_blocks_per_step"] = per_step
             self.stats["paged_steps_per_row"] = -(-ntab // per_step)
@@ -2742,11 +2798,20 @@ class DecodeEngine:
 
     def _ensure_paged_pool(self, rnn1=None) -> None:
         """Create the device block pool lazily from the first dense
-        B=1 streaming state, which says each layer's heads and dtype
-        (shapes per layer: ``[its kind's blocks, block_tokens, H,
-        dh]``); where no dense row is ever made (several kinds: every
-        admission is paged), from the shapes a smallest cold prefill
-        WOULD give, traced and not run."""
+        B=1 streaming state, which says each layer's heads (shapes per
+        layer: ``[its kind's blocks, block_tokens, H, dh]``); where no
+        dense row is ever made (several kinds: every admission is
+        paged), from the shapes a smallest cold prefill WOULD give,
+        traced and not run.
+
+        The KV leaves are made at the dtype the attention layers
+        compute keys and values in: the net's compute dtype where it
+        has one, else the dense row's (the master dtype). A pool cell
+        then holds the number the layer made and no zero bits behind
+        it, and every program that takes the pool hands it back at the
+        dtype it came in with (``_forward_fn``). The slot-state rows (a
+        recurrent state is accumulated into, not copied) stay at the
+        dense row's dtype."""
         if self._pool is not None:
             return
         bt = self.block_tokens
@@ -2759,15 +2824,27 @@ class DecodeEngine:
         blocks = {name: kind.pool.n_blocks for kind in self._kinds
                   for name in kind.layers}
 
+        computed = self.net._compute_dtype
+
+        def held(a):
+            return a.dtype if computed is None else computed
+
         def make(name, st):
             k = st["k"]                          # [1, H, W, dh]
             shape = (blocks[name], bt, k.shape[1], k.shape[3])
-            return {"pk": jnp.zeros(shape, k.dtype),
-                    "pv": jnp.zeros(shape, st["v"].dtype)}
+            return {"pk": jnp.zeros(shape, held(k)),
+                    "pv": jnp.zeros(shape, held(st["v"]))}
 
         kv, row = self._split_row(rnn1)
         self._pool = self._place(
             {name: make(name, st) for name, st in kv.items()})
+        leaves = jax.tree.leaves(self._pool)
+        # what a token costs the pool over all KV layers, and the width
+        # of a cell (a net of several kinds: its first kind's leaves)
+        self.stats["kv_bytes_per_token"] = sum(
+            int(np.prod(leaf.shape[2:])) * leaf.dtype.itemsize
+            for leaf in leaves)
+        self.stats["kv_dtype_bytes"] = leaves[0].dtype.itemsize
         self._slot_state = jax.tree_util.tree_map(
             lambda a: jnp.zeros((self.n_slots,) + a.shape[1:], a.dtype),
             row)
@@ -3831,7 +3908,8 @@ class DecodeEngine:
                     "paged_blocks_live", "paged_blocks_walked",
                     "paged_blocks_per_step", "paged_steps_per_row",
                     "paged_steps_paid", "table_uploads",
-                    "param_bytes", "param_bytes_cast"):
+                    "param_bytes", "param_bytes_cast",
+                    "kv_bytes_per_token", "kv_dtype_bytes"):
             self.tracer.counter(f"serving_{key}", self.stats[key])
         if self.prefix_cache is not None:
             for key in ("hits", "misses", "evictions"):

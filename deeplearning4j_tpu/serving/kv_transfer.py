@@ -297,6 +297,8 @@ def import_prefix(engine, payload: bytes) -> Dict[str, Any]:
     geometry mismatch with this engine) raise
     :class:`KVTransferError` instead: those are deployment bugs the
     HTTP layer maps to 400, and recompute still covers correctness."""
+    import jax.numpy as jnp
+
     if engine.prefix_cache is None:
         raise KVTransferError(
             "receiver has no prefix trie (prefix_cache_rows required)")
@@ -334,7 +336,12 @@ def import_prefix(engine, payload: bytes) -> Dict[str, Any]:
             raise KVTransferError(
                 f"layer {name}: shipped block shape "
                 f"{pk.shape[1:]} != receiver {tuple(leaf.shape[1:])}")
-        if str(pk.dtype) != str(leaf.dtype):
+        # a floating payload of another width is cast into the pool
+        # by the import program (float32 cells that a mixed-precision
+        # donor filled with bf16 numbers land in a bf16 pool exactly)
+        if str(pk.dtype) != str(leaf.dtype) and not (
+                jnp.issubdtype(pk.dtype, jnp.floating)
+                and jnp.issubdtype(leaf.dtype, jnp.floating)):
             raise KVTransferError(
                 f"layer {name}: shipped dtype {pk.dtype} != "
                 f"receiver {leaf.dtype}")
@@ -362,8 +369,6 @@ def import_prefix(engine, payload: bytes) -> Dict[str, Any]:
             "kv_import_declined", 0) + 1
         return result(False, "no_blocks")
     from deeplearning4j_tpu.serving.block_pool import BlockTable, KindTables
-
-    import jax.numpy as jnp
 
     tab = BlockTable(bt, length=length, floor=floor)
     for g in header["blocks"]:

@@ -12,13 +12,24 @@ nothing about results or times.
 The programs by the configuration's ``model``: ``lfm2_moe`` (a training
 cell): ``plain`` and ``remat``, ``fit_scan``'s one step without and with
 a layer's recomputation (``--batch N`` for another batch); ``afmoe`` (a
-served cell): ``decode`` and ``chunk``, the engine's two.
+served cell): ``decode`` and ``chunk``, the engine's two; ``cgpt_block``:
+``decode`` (the served cell: the pool at the dtype the engine makes it,
+``--pool-dtype`` for another) or ``step`` (the trained cell).
+
+``--hash`` lowers only and prints the SHA-256 of each program's text,
+the Pallas kernels' serialized bodies left out (two trees that print
+the same hash run the same program around the same kernel calls);
+``--package-root <dir>`` imports ``deeplearning4j_tpu`` from another
+tree, the benchmark's files from this one.
 """
-import argparse, os, sys, time
+import argparse, hashlib, os, re, sys, time
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+if "--package-root" in sys.argv:             # before the package loads
+    sys.path.insert(0, os.path.abspath(
+        sys.argv[sys.argv.index("--package-root") + 1]))
 import jax, jax.numpy as jnp, numpy as np
 from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
@@ -64,7 +75,17 @@ def struct_net(model, cfg, dtype, head: dict, optimizer=None):
     return net
 
 
+HASH_ONLY = "--hash" in sys.argv
+
+
 def report(name, lowered):
+    if HASH_ONLY:
+        # (a Mosaic kernel's serialized body names its source file's
+        # path, which differs from tree to tree: taken out)
+        text = re.sub(r'backend_config = "[^"]*"', "", lowered.as_text())
+        print(name, "lowered", hashlib.sha256(text.encode()).hexdigest(),
+              flush=True)
+        return
     t0 = time.time()
     c = lowered.compile()
     m = c.memory_analysis()
@@ -130,13 +151,64 @@ def afmoe(cfg, mix, model, which, batch):
             S((1,), "float32"), S((1,), "int32"), key_struct()))
 
 
-MODELS = {"lfm2_moe": lfm2_moe, "afmoe": afmoe}
+def cgpt_block(cfg, mix, model, which, batch, pool_dtype=None):
+    from deeplearning4j_tpu.serving import DecodeEngine
+
+    W = model.weights
+    make = W.make_params
+    serve = "deployment" in cfg
+    cd = cfg["compute_dtype"]
+
+    def struct_params(*a):
+        # a served net as the engine holds it: every layer but the head
+        # at the compute dtype already (nothing to cast without arrays)
+        tree = jax.eval_shape(lambda: make(*a))
+        head = max(tree, key=int)
+        return {k: {n: S(l.shape, l.dtype if not serve or k == head
+                         else cd) for n, l in sub.items()}
+                for k, sub in tree.items()}
+
+    W.make_params = struct_params
+    if not serve:
+        # (the moments by hand: an updater's ``init`` would make arrays)
+        net = model.build_net(cfg, 1)
+        net.updater_state = {
+            si: ({"m": sub, "v": sub} if sub else {})
+            for si, sub in net.params.items()}
+        b, t, v = batch or mix["batch"], mix["seq_len"], cfg["vocab_size"]
+        x = S((1, b, v, t), "uint8")
+        report(f"step batch {b}", net._train_steps_scan.lower(
+            net.params, net.state, net.updater_state, 0, key_struct(),
+            x, x, 1.0))
+        return
+    net = model.build_net(cfg, 1)
+    dep = {k: v for k, v in cfg["deployment"].items() if k != "why"}
+    eng = DecodeEngine(net, seed=1, **dep)
+    held = pool_dtype or str(jnp.dtype(net._compute_dtype or net._dtype))
+    h = cfg["n_head"]
+    shp = (eng.kv_blocks, eng.block_tokens, h, cfg["n_embd"] // h)
+    pool = {name: {"pk": S(shp, held), "pv": S(shp, held)}
+            for k in eng._kinds for name in k.layers}
+    print("pool", held, "GiB", sum(
+        int(np.prod(l.shape)) * l.dtype.itemsize
+        for l in jax.tree.leaves(pool)) / 2**30)
+    B, ring = eng.n_slots, eng._kinds[0].ring
+    report("decode", eng._decode_jit.lower(
+        eng._params, eng._state, pool, S((B, 2 * ring + 2), "int32"),
+        S((B,), "int32"), S((B,), "float32"), S((B,), "int32"),
+        key_struct()))
+
+
+MODELS = {"lfm2_moe": lfm2_moe, "afmoe": afmoe, "cgpt_block": cgpt_block}
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--batch", type=int, default=0)
+    ap.add_argument("--pool-dtype", default=None)
+    ap.add_argument("--hash", action="store_true")
+    ap.add_argument("--package-root", default=ROOT)
     ap.add_argument("programs", nargs="*")
     args = ap.parse_args()
     cell, cfg, mix, model = common.find_cell(
@@ -144,7 +216,9 @@ def main() -> int:
     if cfg["model"] not in MODELS:
         raise SystemExit(f"no programs listed for model {cfg['model']!r}: "
                          f"one of {sorted(MODELS)}")
-    MODELS[cfg["model"]](cfg, mix, model, args.programs, args.batch)
+    extra = ({"pool_dtype": args.pool_dtype} if args.pool_dtype else {})
+    MODELS[cfg["model"]](cfg, mix, model, args.programs, args.batch,
+                         **extra)
     return 0
 
 

@@ -137,9 +137,12 @@ def walk_steps(att, geo: dict, case: dict, t: int) -> dict:
     and those that score keys. A tree from before the loop inside a
     grid step has no count of paid steps: its grid is a step a (row,
     query tile, compute block), computed here."""
+    # (a tree since PR 35 sizes the compute block by the form too)
+    form = ((geo["n_head"] // geo["kv_heads"], t)
+            if hasattr(att, "_paged_tile_form") else ())
     per_step = att._paged_blocks_per_step(
         geo["block_tokens"], geo["kv_heads"], geo["head_dim"],
-        geo["pool_dtype"], case["ntab"])
+        geo["pool_dtype"], case["ntab"], *form)
     geometry = dict(block_tokens=geo["block_tokens"], window=geo["window"],
                     blocks_per_step=per_step, chunk=t)
     _, walked = att.paged_walk_counts(*case["host_tables"], **geometry)
@@ -180,7 +183,9 @@ def main() -> int:
                          "from the traffic file's lengths)")
     ap.add_argument("--chunk", type=int, default=1,
                     help="query rows a call (1 = decode)")
-    ap.add_argument("--pool-dtype", default="float32")
+    ap.add_argument("--pool-dtype", default=None,
+                    help="the pool's cells (default: the configuration's "
+                         "compute dtype, as the engine makes its pool)")
     ap.add_argument("--heads", type=int, default=None,
                     help="query heads (default: the configuration's)")
     ap.add_argument("--kv-heads", type=int, default=None,
@@ -230,7 +235,7 @@ def main() -> int:
         "block_tokens": dep["block_tokens"], "kv_blocks": dep["kv_blocks"],
         "window": args.window or conf["n_positions"],
         "compute_dtype": conf["compute_dtype"],
-        "pool_dtype": args.pool_dtype,
+        "pool_dtype": args.pool_dtype or conf["compute_dtype"],
     }
     toggle = "interpret" if args.rehearse else True
     iters = 2 if args.rehearse else args.iters
@@ -288,7 +293,7 @@ def main() -> int:
             line = {
                 "program": name, "live_rows": n_live,
                 "mean_context": round(float(ctx.mean()), 1) if n_live else 0,
-                "chunk": args.chunk, "pool_dtype": args.pool_dtype,
+                "chunk": args.chunk, "pool_dtype": geo["pool_dtype"],
                 "heads": heads, "kv_heads": geo["kv_heads"],
                 "window": geo["window"],
                 "ntab": case["ntab"], "live_blocks": case["live_blocks"],

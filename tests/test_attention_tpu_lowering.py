@@ -81,10 +81,12 @@ def one_chip():
 # Mosaic's own objections (a broadcast it cannot lay out, a scratch that
 # does not fit) show only in a real compile: the decode and one-tile
 # prefill programs of the serving cell, a verify chunk at the tp=4 local
-# head count, and decode on an all-bf16 pool
+# head count, and decode on an all-bf16 pool (since ISSUE 35 the
+# serving cell's own), with a verify chunk on it
 @pytest.mark.parametrize("b,h,t,pool_dtype", [
     (48, 16, 1, jnp.float32), (1, 16, 128, jnp.float32),
-    (8, 4, 5, jnp.float32), (48, 16, 1, jnp.bfloat16)])
+    (8, 4, 5, jnp.float32), (48, 16, 1, jnp.bfloat16),
+    (48, 16, 5, jnp.bfloat16)])
 def test_paged_kernel_compiles_for_v5e(one_chip, b, h, t, pool_dtype):
     text = _compile_for(
         one_chip, lambda *a: _paged_flash_attention(*a, tm=2048),
