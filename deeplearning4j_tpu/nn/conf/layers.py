@@ -60,6 +60,11 @@ class Layer:
     gradient_normalization: Optional[GradientNormalization] = None
     gradient_normalization_threshold: Optional[float] = None
 
+    #: the group of ``profiler/scopes.py`` that
+    #: ``MultiLayerNetwork._forward_fn`` puts around the layer in the
+    #: device trace; None for a layer whose impl names its own parts
+    scope_group = "ffn"
+
     def num_params(self) -> str:
         raise NotImplementedError
 
@@ -131,6 +136,8 @@ class BaseOutputLayer(FeedForwardLayer):
 
     loss_function: LossFunction = LossFunction.NEGATIVELOGLIKELIHOOD
 
+    scope_group = "head"
+
 
 @register_bean("OutputLayer")
 @dataclasses.dataclass
@@ -159,6 +166,8 @@ class BaseRecurrentLayer(FeedForwardLayer):
     TRUNCATED BPTT; SURVEY.md §5.7)."""
 
     ring_axis: "str | None" = None
+
+    scope_group = "mixer"
 
 
 @register_bean("GravesLSTM")
@@ -213,6 +222,8 @@ class EmbeddingLayer(FeedForwardLayer):
     sequence: bool = False
     multiplier: float = 1.0
 
+    scope_group = "embed"
+
     @property
     def takes_token_ids(self) -> bool:
         return self.sequence
@@ -264,6 +275,8 @@ class LocalResponseNormalization(Layer):
     alpha: float = 1e-4
     beta: float = 0.75
 
+    scope_group = "norm"
+
 
 @register_bean("LayerNormalization")
 @dataclasses.dataclass
@@ -280,6 +293,8 @@ class LayerNormalization(FeedForwardLayer):
 
     eps: float = 1e-5
 
+    scope_group = "norm"
+
 
 @register_bean("BatchNormalization")
 @dataclasses.dataclass
@@ -294,6 +309,8 @@ class BatchNormalization(FeedForwardLayer):
     gamma: float = 1.0
     beta: float = 0.0
     lock_gamma_beta: bool = False
+
+    scope_group = "norm"
 
 
 # Layer kinds that consume/produce [N, C, T] time series. Matching on the

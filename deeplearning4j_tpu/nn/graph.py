@@ -10,6 +10,7 @@ sees a flat fused graph.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import pickle
@@ -57,6 +58,7 @@ from deeplearning4j_tpu.optimize.telemetry import (
     grad_health,
     window_counts,
 )
+from deeplearning4j_tpu.profiler.scopes import scope
 
 Array = jax.Array
 
@@ -224,15 +226,20 @@ class ComputationGraph:
                 mask = in_mask if is_recurrent else None
                 if name in out_f32_vertices:
                     x = _cast_floating(x, self._dtype)
-                out, st = impl.apply(
-                    vertex.conf,
-                    params[name],
-                    x,
-                    state=layer_state,
-                    train=train,
-                    rng=layer_keys.get(name) if train else None,
-                    mask=mask,
-                )
+                # as ``MultiLayerNetwork._forward_fn``: the bean's
+                # group around a layer that names nothing itself
+                group = vertex.conf.layer.scope_group
+                with (scope(group) if group
+                      else contextlib.nullcontext()):
+                    out, st = impl.apply(
+                        vertex.conf,
+                        params[name],
+                        x,
+                        state=layer_state,
+                        train=train,
+                        rng=layer_keys.get(name) if train else None,
+                        mask=mask,
+                    )
                 if st is not None:
                     if self._compute_dtype is not None:
                         # carried state goes out at the dtype it came
@@ -322,6 +329,7 @@ class ComputationGraph:
         return reg
 
     # ------------------------------------------------------------------
+    @scope("update/step")
     def _apply_updates(self, params, upd_state, grads, iteration,
                        grad_scale=1.0):
         """Per-vertex normalize → scale → updater → subtract (shared by

@@ -27,6 +27,7 @@ from deeplearning4j_tpu.nn.conf.layers import BaseRecurrentLayer
 from deeplearning4j_tpu.nn.conf.serde import register_bean
 from deeplearning4j_tpu.nn.layers.base import LayerImplBase
 from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.profiler.scopes import scope
 
 # -- tensor-parallel head sharding (serving TP, ISSUE 12) --------------
 #
@@ -120,6 +121,8 @@ class MultiHeadSelfAttention(BaseRecurrentLayer):
     #: what the serving engine reads off a recurrent bean: "kv" = an
     #: attention cache it may page; "slot" = one state row a slot
     serving_state = "kv"
+    #: the impl names its own parts (``attn/qkv`` ...)
+    scope_group = None
 
 
 class AttentionImpl(LayerImplBase):
@@ -150,43 +153,45 @@ class AttentionImpl(LayerImplBase):
         tp = _tp_scope()
         if tp is not None:
             h = _tp_local_heads(h, tp)
-        x = cls.maybe_dropout(conf, x, train, rng)
-        xt = jnp.transpose(x, (0, 2, 1))  # [N, T, C]
+        with scope("attn/qkv"):
+            x = cls.maybe_dropout(conf, x, train, rng)
+            xt = jnp.transpose(x, (0, 2, 1))  # [N, T, C]
 
-        def split_heads(m):
-            y = xt @ m  # [N, T, D] (local D/TP under tp head sharding)
-            return jnp.transpose(
-                y.reshape(y.shape[0], y.shape[1], h, dh), (0, 2, 1, 3)
-            )  # [N, H, T, dh]
+            def split_heads(m):
+                y = xt @ m  # [N, T, D] (local D/TP under tp head sharding)
+                return jnp.transpose(
+                    y.reshape(y.shape[0], y.shape[1], h, dh), (0, 2, 1, 3)
+                )  # [N, H, T, dh]
 
-        q = split_heads(params["Wq"])
-        k = split_heads(params["Wk"])
-        v = split_heads(params["Wv"])
+            q = split_heads(params["Wq"])
+            k = split_heads(params["Wk"])
+            v = split_heads(params["Wv"])
+        # (from outside every scope: ``_attend_core`` places its own)
         o, state = cls._attend_core(lc, q, k, v, state, train, mask)
-
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(
-            o.shape[0], o.shape[2], h * dh
-        )  # [N, T, D] (local heads under tp)
-        if tp is not None:
-            # row-parallel output projection: each shard's o covers
-            # its own heads, the matmul yields a partial [N, T, D]
-            # sum — ONE all-reduce completes it (bias added once,
-            # after). Partials accumulate AND all-reduce in f32,
-            # rounding to the compute dtype once: bf16 partials
-            # rounded per shard then summed double-round, and the
-            # extra noise flips argmaxes vs the single-chip engine
-            # (the bench id-match gate caught it at tp=2/bf16)
-            out = jax.lax.dot_general(
-                o, params["Wo"], (((2,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            out = jax.lax.psum(out, tp[0]).astype(o.dtype)
-        else:
-            out = o @ params["Wo"]
-        out = out + params["b"]
-        out = cls.activation_of(conf)(out)
-        out = jnp.transpose(out, (0, 2, 1))  # [N, D, T]
-        if mask is not None:
-            out = out * mask[:, None, :]
+        with scope("attn/out"):
+            o = jnp.transpose(o, (0, 2, 1, 3)).reshape(
+                o.shape[0], o.shape[2], h * dh
+            )  # [N, T, D] (local heads under tp)
+            if tp is not None:
+                # row-parallel output projection: each shard's o covers
+                # its own heads, the matmul yields a partial [N, T, D]
+                # sum — ONE all-reduce completes it (bias added once,
+                # after). Partials accumulate AND all-reduce in f32,
+                # rounding to the compute dtype once: bf16 partials
+                # rounded per shard then summed double-round, and the
+                # extra noise flips argmaxes vs the single-chip engine
+                # (the bench id-match gate caught it at tp=2/bf16)
+                out = jax.lax.dot_general(
+                    o, params["Wo"], (((2,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                out = jax.lax.psum(out, tp[0]).astype(o.dtype)
+            else:
+                out = o @ params["Wo"]
+            out = out + params["b"]
+            out = cls.activation_of(conf)(out)
+            out = jnp.transpose(out, (0, 2, 1))  # [N, D, T]
+            if mask is not None:
+                out = out * mask[:, None, :]
         return out, state
 
     @classmethod
@@ -194,7 +199,10 @@ class AttentionImpl(LayerImplBase):
         """Attention-core dispatch on [N, H, T, dh] q/k/v, shared with
         TransformerBlockImpl: streaming continuation, ring/Ulysses
         sequence parallelism, pallas flash, or dense — plus the serving
-        KV-cache prefill."""
+        KV-cache prefill. It places its own scopes (``attn/core``,
+        ``attn/cache``, ``tables``) and is called from outside every
+        scope, because the flash program must stay bare
+        (profiler/scopes.py)."""
         if state is not None:
             # Streaming continuation (rnn_time_step): attend over the
             # carried KV cache + this chunk — the attention analogue of
@@ -212,41 +220,47 @@ class AttentionImpl(LayerImplBase):
                 ulysses_attention,
             )
 
-            if lc.sp_mode == "ulysses":
-                if lc.ring_block_size:
+            with scope("attn/core"):
+                if lc.sp_mode == "ulysses":
+                    if lc.ring_block_size:
+                        raise ValueError(
+                            "ring_block_size bounds the RING schedule's "
+                            "score memory; ulysses materializes the "
+                            "full [T, T] scores of its local heads — "
+                            "unset ring_block_size or use "
+                            "sp_mode='ring'")
+                    o = ulysses_attention(
+                        q, k, v, lc.ring_axis, causal=lc.causal,
+                        key_mask=mask,
+                    )
+                elif lc.sp_mode == "ring":
+                    o = ring_attention(
+                        q, k, v, lc.ring_axis, causal=lc.causal,
+                        key_mask=mask, block_size=lc.ring_block_size,
+                    )
+                else:
                     raise ValueError(
-                        "ring_block_size bounds the RING schedule's "
-                        "score memory; ulysses materializes the "
-                        "full [T, T] scores of its local heads — "
-                        "unset ring_block_size or use "
-                        "sp_mode='ring'")
-                o = ulysses_attention(
-                    q, k, v, lc.ring_axis, causal=lc.causal,
-                    key_mask=mask,
-                )
-            elif lc.sp_mode == "ring":
-                o = ring_attention(
-                    q, k, v, lc.ring_axis, causal=lc.causal,
-                    key_mask=mask, block_size=lc.ring_block_size,
-                )
-            else:
-                raise ValueError(
-                    f"sp_mode {lc.sp_mode!r}: expected 'ring' or "
-                    "'ulysses'")
+                        f"sp_mode {lc.sp_mode!r}: expected 'ring' or "
+                        "'ulysses'")
             return o, None
         # grouped KV heads: the dense and flash programs take one key
         # head a query head, so the group's keys are repeated for them;
         # the cache below keeps the KV heads only
-        ke, ve = _repeat_kv_heads(q, k, v)
+        with scope("attn/core"):
+            ke, ve = _repeat_kv_heads(q, k, v)
         # a SLIDING layer (its bean says so) bands every program by its
         # window; the flash program takes no band, so a sequence past
         # the window stays on the plain one
         band = (lc.stream_max_t if getattr(lc, "sliding", False)
                 and q.shape[2] > lc.stream_max_t else None)
         if band is None and _should_use_flash(lc.use_flash, q, mask):
+            # bare: a scope here would rename the forward kernel's
+            # instruction under a gradient; a reader charges the
+            # library's entry by ``LIBRARY_SCOPES``
             o = _flash_attention(q, ke, ve, lc.causal)
         else:
-            o = _dense_attention(q, ke, ve, lc.causal, mask, band)
+            with scope("attn/core"):
+                o = _dense_attention(q, ke, ve, lc.causal, mask, band)
         new_state = None
         if not train:
             # Prefill: expose the (right-aligned, fixed-size) KV
@@ -259,7 +273,8 @@ class AttentionImpl(LayerImplBase):
             # streaming call reaches _stream_attend's explicit
             # cannot-stream error instead of silently attending
             # chunk-locally.)
-            new_state = cls._prefill_cache(lc, k, v, mask)
+            with scope("attn/cache"):
+                new_state = cls._prefill_cache(lc, k, v, mask)
         return o, new_state
 
     # -- rnn_time_step streaming (fixed-size sliding KV cache) ---------
@@ -372,33 +387,37 @@ class AttentionImpl(LayerImplBase):
         s_ring = table.shape[1]
         pkf = pk.reshape(n_tok, h, dh)
         pvf = pv.reshape(n_tok, h, dh)
-        if mask is None:
-            lengths = jnp.full((b,), t, jnp.int32)
-        else:
-            lengths = jnp.sum(mask.astype(jnp.int32), axis=1)
         # -- scatter the chunk's K/V to their absolute positions ------
-        pos = filled[:, None] + jnp.arange(t)[None, :]        # [B, t]
-        blk = jnp.take_along_axis(table, (pos // bt) % s_ring, axis=1)
-        writable = (jnp.arange(t)[None, :] < lengths[:, None]) & (
-            blk >= 0)
-        widx = jnp.where(writable, blk * bt + pos % bt, n_tok)
-        kt = jnp.swapaxes(k, 1, 2).reshape(b * t, h, dh)
-        vt = jnp.swapaxes(v, 1, 2).reshape(b * t, h, dh)
-        pkf = pkf.at[widx.reshape(-1)].set(kt.astype(pkf.dtype),
-                                           mode="drop")
-        pvf = pvf.at[widx.reshape(-1)].set(vt.astype(pvf.dtype),
-                                           mode="drop")
+        with scope("tables"):
+            if mask is None:
+                lengths = jnp.full((b,), t, jnp.int32)
+            else:
+                lengths = jnp.sum(mask.astype(jnp.int32), axis=1)
+            pos = filled[:, None] + jnp.arange(t)[None, :]    # [B, t]
+            blk = jnp.take_along_axis(table, (pos // bt) % s_ring,
+                                      axis=1)
+            writable = (jnp.arange(t)[None, :] < lengths[:, None]) & (
+                blk >= 0)
+            widx = jnp.where(writable, blk * bt + pos % bt, n_tok)
+        with scope("attn/cache"):
+            kt = jnp.swapaxes(k, 1, 2).reshape(b * t, h, dh)
+            vt = jnp.swapaxes(v, 1, 2).reshape(b * t, h, dh)
+            pkf = pkf.at[widx.reshape(-1)].set(kt.astype(pkf.dtype),
+                                               mode="drop")
+            pvf = pvf.at[widx.reshape(-1)].set(vt.astype(pvf.dtype),
+                                               mode="drop")
         # -- gather each row's reachable window -----------------------
         # consecutive logical blocks from the earliest any query needs
         # (bounded per-executable: ~window + chunk tokens, NOT the
         # whole ring — the decode step reads ~window keys like dense)
         ntab = _paged_table_entries(s_ring, tm, bt, t)
-        lo = jnp.maximum(floor, jnp.maximum(filled - tm + 1, 0))
-        lo_blk = lo // bt
-        g = lo_blk[:, None] + jnp.arange(ntab)[None, :]    # [B, ntab]
-        tb = jnp.take_along_axis(table, g % s_ring, axis=1)
-        bb = jnp.take_along_axis(base, g % s_ring, axis=1)
-        bval = (tb >= 0) & (bb == g * bt)          # ring slot holds g
+        with scope("tables"):
+            lo = jnp.maximum(floor, jnp.maximum(filled - tm + 1, 0))
+            lo_blk = lo // bt
+            g = lo_blk[:, None] + jnp.arange(ntab)[None, :]  # [B, ntab]
+            tb = jnp.take_along_axis(table, g % s_ring, axis=1)
+            bb = jnp.take_along_axis(base, g % s_ring, axis=1)
+            bval = (tb >= 0) & (bb == g * bt)      # ring slot holds g
         toggle = getattr(lc, "use_flash_paged", None)
         if _should_use_flash_paged(toggle, bt, dh, t):
             # fused pallas kernel (ISSUE 12; ISSUE 25: a compute
@@ -411,60 +430,69 @@ class AttentionImpl(LayerImplBase):
             # same value-level NaN masking, online softmax; parity vs
             # the gather program is argmax-level (different float
             # reduction shape — the PR 6 paged-parity convention).
-            o = _paged_flash_attention(
-                q, pkf.reshape(nb, bt, h, dh),
-                pvf.reshape(nb, bt, h, dh),
-                jnp.where(bval, tb, 0).astype(jnp.int32),
-                bval.astype(jnp.int32), lo_blk.astype(jnp.int32),
-                floor.astype(jnp.int32), filled.astype(jnp.int32),
-                lengths.astype(jnp.int32), tm=tm,
-                interpret=(toggle == "interpret"))
+            with scope("attn/core"):
+                pools = (pkf.reshape(nb, bt, h, dh),
+                         pvf.reshape(nb, bt, h, dh))
+            with scope("tables"):     # the kernel's scalar operands
+                scalars = (
+                    jnp.where(bval, tb, 0).astype(jnp.int32),
+                    bval.astype(jnp.int32), lo_blk.astype(jnp.int32),
+                    floor.astype(jnp.int32), filled.astype(jnp.int32),
+                    lengths.astype(jnp.int32))
+            with scope("attn/core"):
+                o = _paged_flash_attention(
+                    q, *pools, *scalars, tm=tm,
+                    interpret=(toggle == "interpret"))
+            with scope("tables"):
+                return o, {"pk": pkf.reshape(nb, bt, h, dh),
+                           "pv": pvf.reshape(nb, bt, h, dh),
+                           "table": table, "base": base, "floor": floor,
+                           "filled": filled + lengths}
+        with scope("tables"):
+            off = jnp.arange(bt)
+            gidx = (jnp.where(bval, tb, 0)[:, :, None] * bt
+                    + off[None, None, :]).reshape(b, ntab * bt)
+            kpos = (g[:, :, None] * bt
+                    + off[None, None, :]).reshape(b, ntab * bt)
+            kval = jnp.repeat(bval, bt, axis=1)        # [B, ntab*bt]
+        with scope("attn/core"):
+            ek = jnp.swapaxes(pkf[gidx], 1, 2)         # [B, H, K, dh]
+            ev = jnp.swapaxes(pvf[gidx], 1, 2)
+            # gather lanes outside each row's WRITTEN span carry foreign
+            # data: invalid-block lanes read a placeholder block, and a
+            # freshly (re)allocated tail block holds whatever its previous
+            # owner left there — possibly NaN under fault injection, since
+            # eviction releases blocks by reference without scrubbing. A
+            # NaN value survives a zero softmax weight (0 * NaN = NaN), so
+            # values must be zeroed at the VALUE level over the full
+            # validity rule — block mapped AND position inside
+            # [floor, filled + written) — or a recycled dirty block
+            # silently corrupts its next owner through masked lanes
+            # (caught by the chaos gate and the paranoid-off regression).
+            # The pallas kernel above enforces the SAME rule on its DMA'd
+            # V blocks (`vlive` in _paged_flash_attention) — the two paths
+            # share the contract, and the kernel parity tests poison a
+            # freed block to prove it holds there too
+            vlive = (kval
+                     & (kpos < (filled + lengths)[:, None])
+                     & (kpos >= floor[:, None]))
+            ev = jnp.where(vlive[:, None, :, None], ev, 0)
+            qpos = filled[:, None] + jnp.arange(t)[None, :]
+            scores = _grouped_scores(q, ek) / jnp.sqrt(
+                jnp.asarray(dh, q.dtype))
+            ok = (kval[:, None, :]
+                  & (kpos[:, None, :] <= qpos[:, :, None])      # causal
+                  & (kpos[:, None, :] > qpos[:, :, None] - tm)  # window
+                  & (kpos[:, None, :] >= floor[:, None, None]))
+            neg = jnp.asarray(-1e30, q.dtype)
+            scores = jnp.where(ok[:, None], scores, neg)
+            w = jax.nn.softmax(scores, axis=-1)
+            o = _grouped_values(w, ev)
+        with scope("tables"):
             return o, {"pk": pkf.reshape(nb, bt, h, dh),
                        "pv": pvf.reshape(nb, bt, h, dh),
                        "table": table, "base": base, "floor": floor,
                        "filled": filled + lengths}
-        off = jnp.arange(bt)
-        gidx = (jnp.where(bval, tb, 0)[:, :, None] * bt
-                + off[None, None, :]).reshape(b, ntab * bt)
-        kpos = (g[:, :, None] * bt
-                + off[None, None, :]).reshape(b, ntab * bt)
-        kval = jnp.repeat(bval, bt, axis=1)        # [B, ntab*bt]
-        ek = jnp.swapaxes(pkf[gidx], 1, 2)         # [B, H, K, dh]
-        ev = jnp.swapaxes(pvf[gidx], 1, 2)
-        # gather lanes outside each row's WRITTEN span carry foreign
-        # data: invalid-block lanes read a placeholder block, and a
-        # freshly (re)allocated tail block holds whatever its previous
-        # owner left there — possibly NaN under fault injection, since
-        # eviction releases blocks by reference without scrubbing. A
-        # NaN value survives a zero softmax weight (0 * NaN = NaN), so
-        # values must be zeroed at the VALUE level over the full
-        # validity rule — block mapped AND position inside
-        # [floor, filled + written) — or a recycled dirty block
-        # silently corrupts its next owner through masked lanes
-        # (caught by the chaos gate and the paranoid-off regression).
-        # The pallas kernel above enforces the SAME rule on its DMA'd
-        # V blocks (`vlive` in _paged_flash_attention) — the two paths
-        # share the contract, and the kernel parity tests poison a
-        # freed block to prove it holds there too
-        vlive = (kval
-                 & (kpos < (filled + lengths)[:, None])
-                 & (kpos >= floor[:, None]))
-        ev = jnp.where(vlive[:, None, :, None], ev, 0)
-        qpos = filled[:, None] + jnp.arange(t)[None, :]
-        scores = _grouped_scores(q, ek) / jnp.sqrt(
-            jnp.asarray(dh, q.dtype))
-        ok = (kval[:, None, :]
-              & (kpos[:, None, :] <= qpos[:, :, None])      # causal
-              & (kpos[:, None, :] > qpos[:, :, None] - tm)  # window
-              & (kpos[:, None, :] >= floor[:, None, None]))
-        neg = jnp.asarray(-1e30, q.dtype)
-        scores = jnp.where(ok[:, None], scores, neg)
-        w = jax.nn.softmax(scores, axis=-1)
-        o = _grouped_values(w, ev)
-        return o, {"pk": pkf.reshape(nb, bt, h, dh),
-                   "pv": pvf.reshape(nb, bt, h, dh),
-                   "table": table, "base": base, "floor": floor,
-                   "filled": filled + lengths}
 
     @classmethod
     def _stream_attend(cls, lc, q, k, v, cache, mask=None):
@@ -517,46 +545,49 @@ class AttentionImpl(LayerImplBase):
         # would drop cached keys still inside the sliding window of the
         # chunk's EARLY queries (chunked streaming would diverge from
         # one-token-at-a-time streaming once the window saturates).
-        ek = jnp.concatenate([cache["k"], k], axis=2)   # [N,H,tm+t,dh]
-        ev = jnp.concatenate([cache["v"], v], axis=2)
-        prev = cache["filled"]                    # [N] per-slot lengths
-        if mask is None:
-            lengths = jnp.full(q.shape[:1], t, jnp.int32)
-        else:
-            lengths = jnp.sum(mask.astype(jnp.int32), axis=1)  # [N]
-        filled = jnp.minimum(prev + lengths, tm)
-        scores = _grouped_scores(q, ek) / jnp.sqrt(
-            jnp.asarray(q.shape[-1], q.dtype)
-        )
-        j = jnp.arange(tm + t)                    # extension positions
-        i = jnp.arange(t)                         # query i at ext tm+i
-        ok = (
-            (j[None, :] <= tm + i[:, None])       # causal
-            & (j[None, :] >= i[:, None] + 1)      # its last-tm window
-        )                                         # [t, tm+t]
-        # per-slot validity: cache zeros (or an idle/evicted slot's
-        # stale rows — filled == 0 invalidates the whole window) never
-        # receive weight, so slots at different fill levels share one
-        # batched step without contaminating each other
-        ok = ok[None] & (j[None, None, :] >= tm - prev[:, None, None])
-        if mask is not None:
-            # chunk pad (positions past each row's true chunk length)
-            # is invalid too — a padded chunk attends exactly like its
-            # unpadded counterpart
-            ok = ok & ((j[None, None, :] < tm)
-                       | (j[None, None, :] - tm
-                          < lengths[:, None, None]))
-        neg = jnp.asarray(-1e30, q.dtype)
-        scores = jnp.where(ok[:, None], scores, neg)
-        w = jax.nn.softmax(scores, axis=-1)
-        o = _grouped_values(w, ev)
-        if mask is None:
-            ck, cv = ek[:, :, -tm:, :], ev[:, :, -tm:, :]
-        else:
-            # rotate each row's chunk pad out of view before windowing
-            # (see _right_align — shared with _prefill_cache)
-            ek, ev = cls._right_align(t - lengths, ek, ev)
-            ck, cv = ek[:, :, -tm:, :], ev[:, :, -tm:, :]
+        with scope("attn/cache"):
+            ek = jnp.concatenate([cache["k"], k], axis=2)   # [N,H,tm+t,dh]
+            ev = jnp.concatenate([cache["v"], v], axis=2)
+        with scope("attn/core"):
+            prev = cache["filled"]                    # [N] per-slot lengths
+            if mask is None:
+                lengths = jnp.full(q.shape[:1], t, jnp.int32)
+            else:
+                lengths = jnp.sum(mask.astype(jnp.int32), axis=1)  # [N]
+            filled = jnp.minimum(prev + lengths, tm)
+            scores = _grouped_scores(q, ek) / jnp.sqrt(
+                jnp.asarray(q.shape[-1], q.dtype)
+            )
+            j = jnp.arange(tm + t)                    # extension positions
+            i = jnp.arange(t)                         # query i at ext tm+i
+            ok = (
+                (j[None, :] <= tm + i[:, None])       # causal
+                & (j[None, :] >= i[:, None] + 1)      # its last-tm window
+            )                                         # [t, tm+t]
+            # per-slot validity: cache zeros (or an idle/evicted slot's
+            # stale rows — filled == 0 invalidates the whole window) never
+            # receive weight, so slots at different fill levels share one
+            # batched step without contaminating each other
+            ok = ok[None] & (j[None, None, :] >= tm - prev[:, None, None])
+            if mask is not None:
+                # chunk pad (positions past each row's true chunk length)
+                # is invalid too — a padded chunk attends exactly like its
+                # unpadded counterpart
+                ok = ok & ((j[None, None, :] < tm)
+                           | (j[None, None, :] - tm
+                              < lengths[:, None, None]))
+            neg = jnp.asarray(-1e30, q.dtype)
+            scores = jnp.where(ok[:, None], scores, neg)
+            w = jax.nn.softmax(scores, axis=-1)
+            o = _grouped_values(w, ev)
+        with scope("attn/cache"):
+            if mask is None:
+                ck, cv = ek[:, :, -tm:, :], ev[:, :, -tm:, :]
+            else:
+                # rotate each row's chunk pad out of view before windowing
+                # (see _right_align — shared with _prefill_cache)
+                ek, ev = cls._right_align(t - lengths, ek, ev)
+                ck, cv = ek[:, :, -tm:, :], ev[:, :, -tm:, :]
         return o, {"k": ck, "v": cv, "filled": filled}
 
 
@@ -595,6 +626,7 @@ class TransformerBlock(BaseRecurrentLayer):
     stream_max_t: int = 512
 
     serving_state = "kv"
+    scope_group = None
 
 
 def _layer_norm(x, g, b, eps=1e-5):
@@ -644,10 +676,13 @@ class TransformerBlockImpl(LayerImplBase):
         tp = _tp_scope()
         if tp is not None:
             h = _tp_local_heads(h, tp)
-        x = cls.maybe_dropout(conf, x, train, rng)
-        xt = jnp.transpose(x, (0, 2, 1))  # [N, T, C]
-        if "Wi" in params:
-            xt = xt @ params["Wi"]
+        # an interior block's entry re-layout goes with the norm that
+        # reads it
+        with scope("embed" if "Wi" in params else "norm"):
+            x = cls.maybe_dropout(conf, x, train, rng)
+            xt = jnp.transpose(x, (0, 2, 1))  # [N, T, C]
+            if "Wi" in params:
+                xt = xt @ params["Wi"]
 
         hn = _layer_norm(xt, params["ln1_g"], params["ln1_b"])
 
@@ -657,38 +692,42 @@ class TransformerBlockImpl(LayerImplBase):
                 y.reshape(y.shape[0], y.shape[1], h, dh), (0, 2, 1, 3)
             )  # [N, H, T, dh]
 
-        q = split_heads(params["Wq"])
-        k = split_heads(params["Wk"])
-        v = split_heads(params["Wv"])
+        with scope("attn/qkv"):
+            q = split_heads(params["Wq"])
+            k = split_heads(params["Wk"])
+            v = split_heads(params["Wv"])
+        # (from outside every scope: ``_attend_core`` places its own)
         o, state = AttentionImpl._attend_core(
             lc, q, k, v, state, train, mask)
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(
-            o.shape[0], o.shape[2], h * dh)  # [N, T, D] (local heads)
-        if tp is not None:
-            # row-parallel Wo: one all-reduce per block completes the
-            # partial sum; LN params, biases, and the (replicated) FFN
-            # see the full-width activation — the Megatron block with
-            # only the attention heads sharded (the KV cache is the
-            # memory that matters in serving; serving/tp.py). f32
-            # accumulate + f32 psum + one rounding, as in
-            # AttentionImpl.apply — per-shard bf16 rounding before the
-            # sum flips argmaxes vs single-chip
-            attn = jax.lax.dot_general(
-                o, params["Wo"], (((2,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            attn = jax.lax.psum(attn, tp[0]).astype(o.dtype)
-        else:
-            attn = o @ params["Wo"]
-        xt = xt + (attn + params["bo"])
+        with scope("attn/out"):
+            o = jnp.transpose(o, (0, 2, 1, 3)).reshape(
+                o.shape[0], o.shape[2], h * dh)  # [N, T, D] (local heads)
+            if tp is not None:
+                # row-parallel Wo: one all-reduce per block completes the
+                # partial sum; LN params, biases, and the (replicated) FFN
+                # see the full-width activation — the Megatron block with
+                # only the attention heads sharded (the KV cache is the
+                # memory that matters in serving; serving/tp.py). f32
+                # accumulate + f32 psum + one rounding, as in
+                # AttentionImpl.apply — per-shard bf16 rounding before the
+                # sum flips argmaxes vs single-chip
+                attn = jax.lax.dot_general(
+                    o, params["Wo"], (((2,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                attn = jax.lax.psum(attn, tp[0]).astype(o.dtype)
+            else:
+                attn = o @ params["Wo"]
+            xt = xt + (attn + params["bo"])
 
         h2 = _layer_norm(xt, params["ln2_g"], params["ln2_b"])
-        ffn = activation(lc.ffn_activation)(
-            h2 @ params["W1"] + params["b1"])
-        xt = xt + (ffn @ params["W2"] + params["b2"])
+        with scope("ffn"):
+            ffn = activation(lc.ffn_activation)(
+                h2 @ params["W1"] + params["b1"])
+            xt = xt + (ffn @ params["W2"] + params["b2"])
 
-        out = jnp.transpose(xt, (0, 2, 1))  # [N, D, T]
-        if mask is not None:
-            out = out * mask[:, None, :]
+            out = jnp.transpose(xt, (0, 2, 1))  # [N, D, T]
+            if mask is not None:
+                out = out * mask[:, None, :]
         return out, state
 
 

@@ -87,10 +87,12 @@ from deeplearning4j_tpu.nn.layers.moe import (
     moe_shapes,
 )
 from deeplearning4j_tpu.ops.losses import label_cross_entropy
+from deeplearning4j_tpu.profiler.scopes import scope
 
 MIXERS = ("attention", "mamba2", "short_conv")
 
 
+@scope("norm")
 def rms_norm(x, w, eps: float):
     """``x / sqrt(mean(x^2) + eps) * w`` over the last axis, the mean
     square in float32."""
@@ -128,18 +130,23 @@ def short_conv_mixer(params, hn, state, mask):
     inputs ``B u``, or None (a fresh row); ``mask`` ``[N, T]`` marks
     each row's valid prefix. The taps are summed and gated in float32,
     one rounding to ``hn``'s dtype. Returns ``(out, new state)``."""
-    proj = hn @ params["W_in"]
-    d = proj.shape[-1] // 3
-    bu = proj[..., :d] * proj[..., 2 * d:]
-    lengths = (None if mask is None
-               else jnp.sum(mask.astype(jnp.int32), axis=1))
-    acc, tail = mamba2.conv_taps(
-        bu, None if state is None else state["conv"], params["conv_w"],
-        lengths)
-    y = (proj[..., d:2 * d].astype(jnp.float32) * acc).astype(hn.dtype)
-    return y @ params["W_out"], {"conv": tail}
+    with scope("mixer/proj"):
+        proj = hn @ params["W_in"]
+        d = proj.shape[-1] // 3
+    with scope("mixer/conv"):
+        bu = proj[..., :d] * proj[..., 2 * d:]
+        lengths = (None if mask is None
+                   else jnp.sum(mask.astype(jnp.int32), axis=1))
+        acc, tail = mamba2.conv_taps(
+            bu, None if state is None else state["conv"],
+            params["conv_w"], lengths)
+        y = (proj[..., d:2 * d].astype(jnp.float32)
+             * acc).astype(hn.dtype)
+    with scope("mixer/proj"):
+        return y @ params["W_out"], {"conv": tail}
 
 
+@scope("attn/rope")
 def rope(q, k, start, theta: float):
     """Rotary positions (rotate-half) on ``q``/``k`` ``[N, H, T, dh]``
     whose first position is ``start`` ``[N]``: the angles in float32,
@@ -186,6 +193,8 @@ class TiedLMHead(BaseOutputLayer):
 
     #: read off the bean by ``MultiLayerNetwork``, as ``takes_token_ids``
     takes_label_ids = True
+    #: the impl names its own parts (``norm``, ``head/logits``)
+    scope_group = None
 
 
 class TiedLMHeadImpl(LayerImplBase):
@@ -203,17 +212,20 @@ class TiedLMHeadImpl(LayerImplBase):
         lc = conf.layer
         hn = rms_norm(jnp.transpose(x, (0, 2, 1)), params["norm_w"],
                       lc.rms_eps)
-        e = params["E"]
-        z = jax.lax.dot_general(
-            hn.astype(e.dtype), e, (((2,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [N, T, V]
-        return z / lc.logits_scaling
+        with scope("head/logits"):
+            e = params["E"]
+            z = jax.lax.dot_general(
+                hn.astype(e.dtype), e, (((2,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [N, T, V]
+            return z / lc.logits_scaling
 
     @classmethod
     def apply(cls, conf, params, x, state=None, train=False, rng=None,
               mask=None):
-        probs = jax.nn.softmax(cls.logits(conf, params, x), axis=-1)
-        return jnp.transpose(probs, (0, 2, 1)), state
+        z = cls.logits(conf, params, x)
+        with scope("head/logits"):
+            probs = jax.nn.softmax(z, axis=-1)
+            return jnp.transpose(probs, (0, 2, 1)), state
 
     @classmethod
     def loss(cls, conf, logits, labels, mask=None):
@@ -296,6 +308,9 @@ class HybridMoeBlock(BaseRecurrentLayer):
     #: layer is not sharded over ``tp``
     wants_live = True
     shards_over_tp = False
+    #: the impl names its own parts (``norm``, ``attn/*``, ``mixer/*``,
+    #: ``moe/*``, ``ffn``)
+    scope_group = None
 
     @property
     def serving_state(self) -> str:
@@ -399,9 +414,10 @@ class HybridMoeBlockImpl(LayerImplBase):
             y = hn @ w
             return jnp.transpose(y.reshape(n, t, h, dh), (0, 2, 1, 3))
 
-        q = heads(params["Wq"], lc.n_heads)
-        k = heads(params["Wk"], lc.n_kv_heads)
-        v = heads(params["Wv"], lc.n_kv_heads)
+        with scope("attn/qkv"):
+            q = heads(params["Wq"], lc.n_heads)
+            k = heads(params["Wk"], lc.n_kv_heads)
+            v = heads(params["Wv"], lc.n_kv_heads)
         if lc.qk_norm:
             q = rms_norm(q, params["q_norm_w"], lc.rms_eps)
             k = rms_norm(k, params["k_norm_w"], lc.rms_eps)
@@ -409,39 +425,49 @@ class HybridMoeBlockImpl(LayerImplBase):
         if lc.rope_theta:
             # the chunk's first absolute position, a row: the paged
             # tables' ``filled``, the dense row cache's ``pos``
-            start = (jnp.zeros((n,), jnp.int32) if state is None
-                     else state["filled" if "pk" in state else "pos"])
+            with scope("attn/rope"):
+                start = (jnp.zeros((n,), jnp.int32) if state is None
+                         else state["filled" if "pk" in state else "pos"])
             q, k = rope(q, k, start, lc.rope_theta)
         if lc.attention_multiplier:
             # the core divides by sqrt(d_head): hand it q scaled so
             # that the scores come out times the multiplier
-            q = (q.astype(jnp.float32) * (
-                lc.attention_multiplier * math.sqrt(dh))).astype(q.dtype)
+            with scope("attn/qkv"):
+                q = (q.astype(jnp.float32) * (
+                    lc.attention_multiplier
+                    * math.sqrt(dh))).astype(q.dtype)
+        # (from outside every scope: ``_attend_core`` places its own)
         o, state = AttentionImpl._attend_core(lc, q, k, v, state, train,
                                               mask)
         if start is not None and state is not None and "pk" not in state:
             # the dense row cache caps ``filled`` at the window: the
             # absolute position rides beside it
-            written = (t if mask is None
-                       else jnp.sum(mask.astype(jnp.int32), axis=1))
-            state = dict(state, pos=start + written)
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(n, t, lc.n_heads * dh)
-        if lc.gated_attention:
-            gate = jax.nn.sigmoid((hn @ params["Wg"]).astype(jnp.float32))
-            o = (o.astype(jnp.float32) * gate).astype(o.dtype)
-        return o @ params["Wo"], state
+            with scope("attn/cache"):
+                written = (t if mask is None
+                           else jnp.sum(mask.astype(jnp.int32), axis=1))
+                state = dict(state, pos=start + written)
+        with scope("attn/out"):
+            o = jnp.transpose(o, (0, 2, 1, 3)).reshape(
+                n, t, lc.n_heads * dh)
+            if lc.gated_attention:
+                gate = jax.nn.sigmoid(
+                    (hn @ params["Wg"]).astype(jnp.float32))
+                o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+            return o @ params["Wo"], state
 
     @classmethod
     def apply(cls, conf, params, x, state=None, train=False, rng=None,
               mask=None, live=None, counters=None):
         lc = conf.layer
-        xt = jnp.transpose(x, (0, 2, 1))                     # [N, T, D]
+        with scope("norm"):     # the entry re-layout, with what reads it
+            xt = jnp.transpose(x, (0, 2, 1))                 # [N, T, D]
         n, t, d = xt.shape
         hn = rms_norm(xt, params["norm1_w"], lc.rms_eps)
         counts = {}
         if lc.mixer == "attention":
             if live is None and state is not None:
-                live = state["filled"] > 0   # an idle slot caches nothing
+                with scope("tables"):
+                    live = state["filled"] > 0   # an idle slot: no cache
             mixed, new_state = cls._attention(lc, params, hn, state,
                                               train, mask)
         elif lc.mixer == "short_conv":
@@ -452,19 +478,24 @@ class HybridMoeBlockImpl(LayerImplBase):
                 d_head=lc.ssm_d_head, d_state=lc.ssm_d_state,
                 n_groups=lc.ssm_groups, chunk=lc.ssm_chunk,
                 eps=lc.rms_eps, live=live, kernel=lc.use_kernels)
-            counts["ssm_state_rows"] = (
-                jnp.asarray(n, jnp.int32) if live is None
-                else jnp.sum((live > 0).astype(jnp.int32)))
+            with scope("mixer/ssm"):
+                counts["ssm_state_rows"] = (
+                    jnp.asarray(n, jnp.int32) if live is None
+                    else jnp.sum((live > 0).astype(jnp.int32)))
         if lc.post_norms:
             mixed = rms_norm(mixed, params["post1_w"], lc.rms_eps)
-        xt = _residual(xt, mixed, lc.residual_multiplier)
+        # a branch's residual sum is its group's last operation
+        with scope("attn/out" if lc.mixer == "attention"
+                   else "mixer/proj"):
+            xt = _residual(xt, mixed, lc.residual_multiplier)
 
-        valid = None
-        if mask is not None:
-            valid = mask > 0
-        if live is not None:
-            rows = jnp.broadcast_to((live > 0)[:, None], (n, t))
-            valid = rows if valid is None else valid & rows
+        with scope("moe/route" if lc.n_router else "ffn"):
+            valid = None
+            if mask is not None:
+                valid = mask > 0
+            if live is not None:
+                rows = jnp.broadcast_to((live > 0)[:, None], (n, t))
+                valid = rows if valid is None else valid & rows
         h2 = rms_norm(xt, params["norm2_w"], lc.rms_eps)
         if lc.n_router:
             y, moe_counts = dropless_moe(
@@ -477,16 +508,18 @@ class HybridMoeBlockImpl(LayerImplBase):
             counts.update(moe_counts,
                           moe_layer_steps=jnp.asarray(1, jnp.int32))
         else:
-            y = gated_ffn(h2, params["Ws_in"], params["Ws_out"])
+            with scope("ffn"):
+                y = gated_ffn(h2, params["Ws_in"], params["Ws_out"])
         y = y.reshape(n, t, d)
         if lc.post_norms:
             y = rms_norm(y, params["post2_w"], lc.rms_eps)
-        xt = _residual(xt, y, lc.residual_multiplier)
-        if counters is not None:
-            for name, v in counts.items():
-                counters[name] = counters.get(name, 0) + v
+        with scope("moe/combine" if lc.n_router else "ffn"):
+            xt = _residual(xt, y, lc.residual_multiplier)
+            if counters is not None:
+                for name, v in counts.items():
+                    counters[name] = counters.get(name, 0) + v
 
-        out = jnp.transpose(xt, (0, 2, 1))
-        if mask is not None:
-            out = out * mask[:, None, :].astype(out.dtype)
+            out = jnp.transpose(xt, (0, 2, 1))
+            if mask is not None:
+                out = out * mask[:, None, :].astype(out.dtype)
         return out, (None if train else new_state)

@@ -45,6 +45,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.profiler.scopes import scope
+
 _HI = jax.lax.Precision.HIGHEST
 #: heads one grid step of the decode kernel updates
 _STEP_HEAD_BLOCK = 64
@@ -346,6 +348,7 @@ def mixer_shapes(width: int, n_heads: int, d_head: int, d_state: int,
             "norm_w": (d_inner,), "W_out": (d_inner, width)}
 
 
+@scope("norm")
 def gated_rms_norm(y, z, w, n_groups: int, eps: float):
     """``RMSNorm(y * silu(z)) * w``, the mean square taken over each of
     ``n_groups`` equal parts of the last axis, in float32."""
@@ -366,31 +369,40 @@ def mamba2_mixer(params, hn, state, mask, *, n_heads: int, d_head: int,
     bsz, t, _ = hn.shape
     d_inner = n_heads * d_head
     gn = n_groups * d_state
-    proj = hn @ params["W_in"]
-    z = proj[..., :d_inner]
-    xbc = proj[..., d_inner:2 * d_inner + 2 * gn]
-    dt = proj[..., 2 * d_inner + 2 * gn:]
-    lengths = (None if mask is None
-               else jnp.sum(mask.astype(jnp.int32), axis=1))
-    xbc, conv = causal_conv(xbc, None if state is None else state["conv"],
-                            params["conv_w"], params["conv_b"], lengths)
-    x = xbc[..., :d_inner].reshape(bsz, t, n_heads, d_head)
-    bm = xbc[..., d_inner:d_inner + gn].reshape(bsz, t, n_groups, d_state)
-    cm = xbc[..., d_inner + gn:].reshape(bsz, t, n_groups, d_state)
-    dt = jax.nn.softplus(dt.astype(jnp.float32)
-                         + params["dt_bias"].astype(jnp.float32))
-    if mask is not None:
-        dt = dt * mask.astype(jnp.float32)[:, :, None]
-    a = -jnp.exp(params["A_log"].astype(jnp.float32))
-    if state is not None and t == 1:
-        y, ssm = ssm_step(state["ssm"], x[:, 0], dt[:, 0], a, bm[:, 0],
-                          cm[:, 0], params["D"], live, kernel)
-        y = y[:, None]
-    else:
-        s0 = (jnp.zeros((bsz, n_heads, d_head, d_state), jnp.float32)
-              if state is None else unpack_state(state["ssm"], d_head))
-        y, s_t = ssm_chunk_scan(x, dt, a, bm, cm, params["D"], s0, chunk)
-        ssm = pack_state(s_t)
+    with scope("mixer/proj"):
+        proj = hn @ params["W_in"]
+        z = proj[..., :d_inner]
+        xbc = proj[..., d_inner:2 * d_inner + 2 * gn]
+        dt = proj[..., 2 * d_inner + 2 * gn:]
+    with scope("mixer/conv"):
+        lengths = (None if mask is None
+                   else jnp.sum(mask.astype(jnp.int32), axis=1))
+        xbc, conv = causal_conv(
+            xbc, None if state is None else state["conv"],
+            params["conv_w"], params["conv_b"], lengths)
+    with scope("mixer/ssm"):
+        x = xbc[..., :d_inner].reshape(bsz, t, n_heads, d_head)
+        bm = xbc[..., d_inner:d_inner + gn].reshape(
+            bsz, t, n_groups, d_state)
+        cm = xbc[..., d_inner + gn:].reshape(bsz, t, n_groups, d_state)
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + params["dt_bias"].astype(jnp.float32))
+        if mask is not None:
+            dt = dt * mask.astype(jnp.float32)[:, :, None]
+        a = -jnp.exp(params["A_log"].astype(jnp.float32))
+        if state is not None and t == 1:
+            y, ssm = ssm_step(state["ssm"], x[:, 0], dt[:, 0], a,
+                              bm[:, 0], cm[:, 0], params["D"], live,
+                              kernel)
+            y = y[:, None]
+        else:
+            s0 = (jnp.zeros((bsz, n_heads, d_head, d_state), jnp.float32)
+                  if state is None
+                  else unpack_state(state["ssm"], d_head))
+            y, s_t = ssm_chunk_scan(x, dt, a, bm, cm, params["D"], s0,
+                                    chunk)
+            ssm = pack_state(s_t)
     y = gated_rms_norm(y.reshape(bsz, t, d_inner), z, params["norm_w"],
                        n_groups, eps).astype(hn.dtype)
-    return y @ params["W_out"], {"conv": conv, "ssm": ssm}
+    with scope("mixer/proj"):
+        return y @ params["W_out"], {"conv": conv, "ssm": ssm}

@@ -49,6 +49,7 @@ from deeplearning4j_tpu.nn.conf.serde import register_bean
 from deeplearning4j_tpu.nn.layers.base import LayerImplBase
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.parallel.expert_parallel import moe_apply
+from deeplearning4j_tpu.profiler.scopes import scope
 
 
 @register_bean("MoeDense")
@@ -64,6 +65,8 @@ class MoeDense(FeedForwardLayer):
     aux_weight: float = 0.01    # weight of the load-balancing loss
     residual: bool = True
     ep_axis: Optional[str] = None  # expert-parallel mesh axis
+
+    scope_group = "moe"
 
 
 class MoeDenseImpl(LayerImplBase):
@@ -284,40 +287,54 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
         raise ValueError(
             f"experts_held {experts_held} names {n_held} experts, the "
             f"layer holds {params['We_in'].shape[0]}")
-    logits = jnp.dot(tokens, params["router"],
-                     preferred_element_type=jnp.float32)
-    if detach_scores:
-        logits = jax.lax.stop_gradient(logits)
-    gates, idx = route(logits, top_k, gate_rule,              # [M, k]
-                       params.get("expert_bias"), route_scale, route_eps)
-    routed = (jnp.ones((m, 1), bool) if valid is None
-              else valid.astype(bool)[:, None])
-    held = (idx >= lo) & (idx < hi) & routed
-    # sort the pairs by held expert; what is not held sorts last
-    expert = jnp.where(held, idx - lo, n_held).reshape(-1)
-    order = jnp.argsort(expert, stable=True)
-    sizes = jnp.bincount(expert, length=n_held + 1)[:n_held].astype(
-        jnp.int32)
-    # rows past the groups are never written by the kernel, forward or
-    # transposed, and may hold anything: select them away (do not
-    # scale) from the value, and from each product's cotangents
-    inside = (jnp.arange(m * top_k) < jnp.sum(sizes))[:, None]
-    xs = _cotangent_where(inside, tokens[order // top_k])
-    gu = _cotangent_where(
-        inside, grouped_product(xs, params["We_in"], sizes, kernel))
-    f = gu.shape[-1] // 2
-    act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(tokens.dtype)
-    ys = grouped_product(act, params["We_out"], sizes, kernel)
-    ys = jnp.where(inside, ys, 0)
-    back = jnp.argsort(order)
-    picked = ys[back].reshape(m, top_k, d).astype(jnp.float32)
-    y = jnp.sum(picked * jnp.where(held, gates, 0.0)[..., None], axis=1)
+    with scope("moe/route"):
+        logits = jnp.dot(tokens, params["router"],
+                         preferred_element_type=jnp.float32)
+        if detach_scores:
+            logits = jax.lax.stop_gradient(logits)
+        gates, idx = route(logits, top_k, gate_rule,          # [M, k]
+                           params.get("expert_bias"), route_scale,
+                           route_eps)
+        routed = (jnp.ones((m, 1), bool) if valid is None
+                  else valid.astype(bool)[:, None])
+        held = (idx >= lo) & (idx < hi) & routed
+    with scope("moe/sort"):
+        # sort the pairs by held expert; what is not held sorts last
+        expert = jnp.where(held, idx - lo, n_held).reshape(-1)
+        order = jnp.argsort(expert, stable=True)
+        sizes = jnp.bincount(expert, length=n_held + 1)[:n_held].astype(
+            jnp.int32)
+        # rows past the groups are never written by the kernel, forward
+        # or transposed, and may hold anything: select them away (do
+        # not scale) from the value, and from each product's cotangents
+        inside = (jnp.arange(m * top_k) < jnp.sum(sizes))[:, None]
+        xs = tokens[order // top_k]
+    with scope("moe/combine"):
+        xs = _cotangent_where(inside, xs)
+    with scope("moe/experts"):
+        gu = grouped_product(xs, params["We_in"], sizes, kernel)
+    with scope("moe/combine"):
+        gu = _cotangent_where(inside, gu)
+    with scope("moe/experts"):
+        f = gu.shape[-1] // 2
+        act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(tokens.dtype)
+        ys = grouped_product(act, params["We_out"], sizes, kernel)
+    with scope("moe/combine"):
+        ys = jnp.where(inside, ys, 0)
+        back = jnp.argsort(order)
+        picked = ys[back].reshape(m, top_k, d).astype(jnp.float32)
+        y = jnp.sum(picked * jnp.where(held, gates, 0.0)[..., None],
+                    axis=1)
     if "Ws_in" in params:
-        y = y + gated_ffn(tokens, params["Ws_in"],
-                          params["Ws_out"]).astype(jnp.float32)
-    counts = {
-        "moe_picks": jnp.sum(routed.astype(jnp.int32)) * top_k,
-        "moe_picks_held": jnp.sum(held.astype(jnp.int32)),
-        "moe_experts_touched": jnp.sum((sizes > 0).astype(jnp.int32)),
-        "moe_load_max": jnp.max(sizes)}
-    return y.astype(tokens.dtype), counts
+        with scope("moe/shared"):
+            y = y + gated_ffn(tokens, params["Ws_in"],
+                              params["Ws_out"]).astype(jnp.float32)
+    with scope("moe/route"):
+        counts = {
+            "moe_picks": jnp.sum(routed.astype(jnp.int32)) * top_k,
+            "moe_picks_held": jnp.sum(held.astype(jnp.int32)),
+            "moe_experts_touched": jnp.sum(
+                (sizes > 0).astype(jnp.int32)),
+            "moe_load_max": jnp.max(sizes)}
+    with scope("moe/combine"):
+        return y.astype(tokens.dtype), counts

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.nn.layers.base import LayerImplBase
+from deeplearning4j_tpu.profiler.scopes import scope
 
 
 class BatchNormImpl(LayerImplBase):
@@ -61,6 +62,7 @@ class BatchNormImpl(LayerImplBase):
         return out, new_state
 
 
+@scope("norm")
 def layer_norm(x, g, b, axis: int = -1, eps: float = 1e-5):
     """LayerNorm over ``axis``; moments at >= f32 so the bf16 compute
     path keeps a stable normalizer (promote, don't hard-cast — the f64

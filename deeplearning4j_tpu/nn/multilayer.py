@@ -15,6 +15,7 @@ for updater/gradient-check parity.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import io
 import json
@@ -35,6 +36,7 @@ from deeplearning4j_tpu.optimize.telemetry import (
     grad_health,
     window_counts,
 )
+from deeplearning4j_tpu.profiler.scopes import scope
 from deeplearning4j_tpu.profiler.tracer import annotate
 from deeplearning4j_tpu.nn.conf.enums import BackpropType, OptimizationAlgorithm
 from deeplearning4j_tpu.nn.conf.multi_layer import MultiLayerConfiguration
@@ -278,9 +280,10 @@ class MultiLayerNetwork:
         # Mixed precision: compute in cd (bf16 on the MXU), master
         # params stay f32 — the cast's transpose accumulates grads
         # back in f32.
-        params = self.compute_params(params)
-        if cd is not None:
-            x = _cast_floating(x, cd)
+        with scope("cast"):
+            params = self.compute_params(params)
+            if cd is not None:
+                x = _cast_floating(x, cd)
         acts = []
         new_state = dict(state) if state else {}
         new_rnn = {}
@@ -293,7 +296,8 @@ class MultiLayerNetwork:
             si = str(i)
             pp = self.conf.preprocessor_for(i)
             if pp is not None:
-                x = pp.pre_process(x, rngs[i] if train else None)
+                with scope("embed"):
+                    x = pp.pre_process(x, rngs[i] if train else None)
             layer_state = None
             if state and si in state:
                 layer_state = state[si]
@@ -323,7 +327,8 @@ class MultiLayerNetwork:
             if self.conf.remat:
                 _apply = jax.checkpoint(_apply)
             if out_f32 and si == last_si:
-                x = _cast_floating(x, self._dtype)
+                with scope("cast"):
+                    x = _cast_floating(x, self._dtype)
             layer_params = params[si]
             tie = getattr(c.layer, "tie_to", None)
             if tie is not None:
@@ -331,13 +336,20 @@ class MultiLayerNetwork:
                 layer_params = dict(layer_params,
                                     E=params[str(tie)]["W"])
             if head_at is not None and si == last_si:
-                x = jnp.take_along_axis(
-                    x, head_at.astype(jnp.int32)[:, None, None], axis=2)
+                with scope("head/logits"):
+                    x = jnp.take_along_axis(
+                        x, head_at.astype(jnp.int32)[:, None, None],
+                        axis=2)
                 mask = None
-            x, st, counted = _apply(
-                layer_params, x, layer_state,
-                rngs[i] if train else None, mask,
-            )
+            # a layer that names nothing inside itself gets its bean's
+            # group here; one that does (``scope_group`` None) is
+            # called bare, as the flash program under it must be
+            group = c.layer.scope_group
+            with (scope(group) if group else contextlib.nullcontext()):
+                x, st, counted = _apply(
+                    layer_params, x, layer_state,
+                    rngs[i] if train else None, mask,
+                )
             if counters is not None:
                 for name, value in counted.items():
                     counters[name] = counters.get(name, 0) + value
@@ -347,7 +359,9 @@ class MultiLayerNetwork:
                     # with (created here: the master dtype), so
                     # repeated steps see stable input dtypes (no
                     # recompiles)
-                    st = _carried_state(st, layer_state, self._dtype)
+                    with scope("cast"):
+                        st = _carried_state(st, layer_state,
+                                            self._dtype)
                 if state and si in state:
                     new_state[si] = st
                 else:
@@ -376,10 +390,12 @@ class MultiLayerNetwork:
                 "Last layer must be an output layer to compute a score"
             )
         if self._compute_dtype is not None:
-            out = _cast_floating(out, dtype=self._dtype)  # loss in f32
-        score = impl.loss(out_conf, out, labels, label_mask)
-        score = score + self._reg_score(params)
-        score = score + self._aux_score(new_state)
+            with scope("cast"):
+                out = _cast_floating(out, dtype=self._dtype)  # f32 loss
+        with scope("head/loss"):
+            score = impl.loss(out_conf, out, labels, label_mask)
+            score = score + self._reg_score(params)
+            score = score + self._aux_score(new_state)
         return score, new_state
 
     def _reg_score(self, params):
@@ -402,6 +418,7 @@ class MultiLayerNetwork:
     # ------------------------------------------------------------------
     # The jitted train step (whole §3.1 stack as one XLA computation)
     # ------------------------------------------------------------------
+    @scope("update/step")
     def _apply_updates(self, params, upd_state, grads, iteration,
                        grad_scale=1.0):
         """Per-layer normalize → scale → updater → subtract (shared by
@@ -782,11 +799,13 @@ class MultiLayerNetwork:
                 params, state, f, rng, True, fm, rnn_state=rnn_state
             )
             if self._compute_dtype is not None:
-                out = _cast_floating(out, dtype=self._dtype)  # loss in f32
+                with scope("cast"):
+                    out = _cast_floating(out, dtype=self._dtype)  # f32
             impl = self._impls[-1]
-            score = impl.loss(self.conf.confs[-1], out, y, lm)
-            score = score + self._reg_score(params)
-            score = score + self._aux_score(new_state)
+            with scope("head/loss"):
+                score = impl.loss(self.conf.confs[-1], out, y, lm)
+                score = score + self._reg_score(params)
+                score = score + self._aux_score(new_state)
             return score, (new_state, new_rnn)
 
         def step(params, state, upd_state, iteration, rng, f, y, fm, lm,

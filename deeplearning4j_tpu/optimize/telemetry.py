@@ -35,6 +35,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from deeplearning4j_tpu.profiler.scopes import scope
+
 #: The five training histogram tracks (ISSUE 8 tentpole): latency-style
 #: phases in seconds plus gradient-health value distributions.
 TRAIN_HISTOGRAMS = (
@@ -78,6 +80,7 @@ HEALTH_KEYS = ("grad_norm", "update_ratio", "param_norm",
 VALUE_BOUNDS = tuple(10.0 ** (e / 4.0) for e in range(-32, 17))
 
 
+@scope("update/health")
 def grad_health(grads, params, new_params):
     """Gradient-health scalars, traced INSIDE the jitted train step.
 

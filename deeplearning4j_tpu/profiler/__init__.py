@@ -11,11 +11,19 @@ from a training loop) and adds what a TPU framework actually needs:
   program's spans sit on the device planes' clock.
 - ``annotate``: the same annotation for call sites that hold no
   ``Tracer``.
+- ``scope``: the device's side of the same trace. A ``jax.named_scope``
+  from one vocabulary (``profiler/scopes.py``: ``attn/qkv``,
+  ``moe/experts``, ``update/step``, the engine programs' ``admit`` /
+  ``decode``), which every layer, the training step and the engine's
+  programs put around their parts: compile-time metadata that names
+  each device operation's layer in a profile (its ``tf_op``), read back
+  by ``benchmark/opscopes.py``.
 
 Taking the XLA/TPU-level trace itself (start, stop, reduce) is the
 benchmark's ``benchmark/common.py`` ``SubTrace``.
 """
 
+from deeplearning4j_tpu.profiler.scopes import scope
 from deeplearning4j_tpu.profiler.tracer import Tracer, annotate
 
-__all__ = ["Tracer", "annotate"]
+__all__ = ["Tracer", "annotate", "scope"]

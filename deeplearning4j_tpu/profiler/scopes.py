@@ -1,0 +1,98 @@
+"""The names the program gives its parts in the device trace.
+
+A scope is ``jax.named_scope``: compile-time metadata on the operations
+traced inside it (an HLO instruction's ``op_name``, the ``tf_op`` of its
+events in a profile), no operation, no operand, nothing at run time. The
+lowered text without locations is the same with and without them.
+
+One vocabulary, here, so that a reader of a trace
+(``benchmark/opscopes.py``) and the layers agree on it and a typo fails
+where it is written. Names are KINDS, never layer indices: the copies of
+a block in unrolled layers add up.
+
+``GROUPS``: a group and, under it, the children a layer may name.
+``PHASES``: what an engine program is for, the first scope of its body.
+A path is ``[phase/]group[/child]``; a reader takes the FIRST group
+after the phase, so a norm inside ``attn/qkv`` is attention's.
+
+- ``embed``: the ids' gather / one-hot projection, its scale, a layer's
+  preprocessor.
+- ``norm``: LayerNorm, RMSNorm, gated RMSNorm, QK-norm.
+- ``attn`` (``qkv``, ``rope``, ``core``, ``cache``, ``out``): the
+  projections and the head split; rotary positions; the attention
+  program (flash, paged kernel, gather, dense); the writes into the
+  pool / the ring; ``Wo``, bias, gate, residual.
+- ``ffn``: the dense feed-forward.
+- ``moe`` (``route``, ``sort``, ``experts``, ``combine``, ``shared``):
+  the router; the pairs' sort and gather; the grouped products; the
+  scatter back, the gate-weighted sum and the cotangents' selects; the
+  shared expert.
+- ``mixer`` (``proj``, ``conv``, ``ssm``): the in/out projections and
+  their split; the causal / short convolution; the chunked scan and the
+  one-step update.
+- ``head`` (``logits``, ``loss``, ``sample``): the output layer; the
+  score; ``sample_tokens``.
+- ``cast``: masters to the compute dtype, activations' casts.
+- ``update`` (``step``, ``health``): normalise, updater, subtract;
+  ``grad_health``.
+- ``tables``: what a paged layer derives from the block tables, the
+  engine's ``seen`` / ``kept``.
+
+**The one call no scope may wrap.** A Pallas call's HLO instruction is
+named after the innermost entry of its name stack, wrappers and all:
+under a gradient the library's flash program is
+``jvp(jit(flash_attention))`` and its forward kernel
+``jvp_jit_flash_attention__``; a scope between the ``jvp`` and the call
+takes the ``jvp`` onto itself and the kernel becomes ``flash_attention``.
+So ``AttentionImpl._attend_core`` leaves that call bare, its callers
+call it from outside every scope, and a reader charges the library's
+own entry by ``LIBRARY_SCOPES``. The kernels jitted under their own
+names (``_paged_flash_attention``, ``gmm`` / ``tgmm``,
+``_ssm_step_update``) keep them inside a scope.
+"""
+
+from __future__ import annotations
+
+import contextlib
+
+import jax
+
+GROUPS = {
+    "embed": (),
+    "norm": (),
+    "attn": ("qkv", "rope", "core", "cache", "out"),
+    "ffn": (),
+    "moe": ("route", "sort", "experts", "combine", "shared"),
+    "mixer": ("proj", "conv", "ssm"),
+    "head": ("logits", "loss", "sample"),
+    "cast": (),
+    "update": ("step", "health"),
+    "tables": (),
+}
+PHASES = ("admit", "decode")
+#: name-stack entries of library code that stand for a path of the
+#: vocabulary (the module docstring says why no scope is around them)
+LIBRARY_SCOPES = {"jit(flash_attention)": "attn/core"}
+
+
+def scope(path: str):
+    """A ``jax.named_scope`` an entry of ``path``, which is a phase, a
+    group or ``group/child`` of the vocabulary; anything else raises,
+    here and not on entry. (One scope an entry: a gradient wraps the
+    first entry after it, ``transpose(jvp(attn))/qkv``, and a reader
+    cuts a path at ``/``.)"""
+    group, _, child = path.partition("/")
+    if not (path in PHASES or group in GROUPS
+            and (not child or child in GROUPS[group])):
+        raise ValueError(
+            f"scope {path!r} is not in the vocabulary: a phase "
+            f"{PHASES}, a group or group/child of {GROUPS}")
+    return _entered(path.split("/"))
+
+
+@contextlib.contextmanager
+def _entered(names):
+    with contextlib.ExitStack() as stack:
+        for name in names:
+            stack.enter_context(jax.named_scope(name))
+        yield

@@ -402,7 +402,21 @@ class Tracer:
     (float32 masters cast to the compute dtype). A
     training loop feeds a tracer through
     ``optimize/listeners.py:TracingIterationListener``; taking the
-    device trace itself is ``benchmark/common.py:SubTrace``."""
+    device trace itself is ``benchmark/common.py:SubTrace``.
+
+    The device's side of that profile is named by SCOPES
+    (``profiler/scopes.py``, compile-time metadata, always there): an
+    engine program's body starts with its phase, ``admit``
+    (``prefill``, ``chunk_prefill``, ``scatter_row``, ``state_admit``,
+    ``put_tok``) or ``decode`` (``decode``, ``fused_decode``), and
+    every layer, the training step and the programs put their parts
+    under ``embed``, ``norm``, ``attn`` (``qkv``, ``rope``, ``core``,
+    ``cache``, ``out``), ``ffn``, ``moe`` (``route``, ``sort``,
+    ``experts``, ``combine``, ``shared``), ``mixer`` (``proj``,
+    ``conv``, ``ssm``), ``head`` (``logits``, ``loss``, ``sample``),
+    ``cast``, ``update`` (``step``, ``health``) and ``tables``;
+    ``benchmark/opscopes.py`` reads them back from each device
+    operation's metadata."""
 
     #: ``max_events=None`` keeps every event (the Chrome-trace use
     #: case: finite runs you dump with ``save``). A long-lived SERVER

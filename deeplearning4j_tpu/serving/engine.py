@@ -147,6 +147,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
     paged_walk_stats,
 )
 from deeplearning4j_tpu.nn.streaming import scan_length_bucket
+from deeplearning4j_tpu.profiler.scopes import scope
 from deeplearning4j_tpu.serving.block_pool import (
     BlockPool,
     BlockTable,
@@ -395,7 +396,6 @@ SERVING_TRACK_HELP = {
     "serving_tokens_generated": "tokens committed across all requests",
     "serving_admitted": "requests admitted into a slot",
     "serving_evicted": "slots freed (finish, cancel, quarantine)",
-    "serving_tokens_per_sec": "per-round decode throughput",
     "serving_prefill_tokens": "prompt tokens prefilled",
     "serving_prefill_tokens_skipped": "prompt tokens served from the "
                                       "prefix cache instead",
@@ -1397,8 +1397,9 @@ class DecodeEngine:
             # themselves where it embeds them, else one-hot columns
             if ids_in:
                 return tok[:, None]
-            return jax.nn.one_hot(
-                tok, self.vocab, dtype=self.net._dtype)[:, :, None]
+            with scope("embed"):
+                return jax.nn.one_hot(
+                    tok, self.vocab, dtype=self.net._dtype)[:, :, None]
 
         def seen(pool, tabs, filled=None):
             # the per-layer state the forward pass sees: every paged
@@ -1407,8 +1408,9 @@ class DecodeEngine:
             # packed operand, unpacked here). ``filled`` is a scan's
             # carried copy of the only table operand a step advances
             shared = {}
-            for kind, ops in zip(self._kinds,
-                                 _unpack_tables(tabs, rings)):
+            with scope("tables"):
+                unpacked = _unpack_tables(tabs, rings)
+            for kind, ops in zip(self._kinds, unpacked):
                 if filled is not None:
                     ops["filled"] = filled
                 shared.update(dict.fromkeys(kind.layers, ops))
@@ -1427,6 +1429,9 @@ class DecodeEngine:
                            if "pk" in st else st)
                     for name, st in rnn.items()}, filled
 
+        # (a phase is the first scope of a program's body: what the
+        # device's time is for, whatever the program is called)
+        @scope("admit")
         def chunk_prefill(params, state, x, mask, rnn, tabs, temp,
                           top_k, key):
             # masked prefill resuming a carried cache: a warm
@@ -1435,7 +1440,8 @@ class DecodeEngine:
             # net's own streaming cache, from the chunk before (None
             # at its first). Forward, then sample at each row's last
             # VALID position
-            length = jnp.sum(mask.astype(jnp.int32), axis=1)
+            with scope("tables"):
+                length = jnp.sum(mask.astype(jnp.int32), axis=1)
             cold = tabs is None
             rows = {}
             if not cold:
@@ -1444,7 +1450,8 @@ class DecodeEngine:
                     # an admission's row holds a request, whatever its
                     # tables say (a paged one's first chunk starts at
                     # ``filled`` 0, which reads as an idle slot)
-                    rows["live"] = jnp.ones(x.shape[:1], jnp.int32)
+                    with scope("tables"):
+                        rows["live"] = jnp.ones(x.shape[:1], jnp.int32)
             if ids_in:
                 # the head at the sampled position only: a vocabulary
                 # this wide is not worth a column per prompt position
@@ -1455,9 +1462,12 @@ class DecodeEngine:
             else:
                 out, new_rnn, counts = forward(params, state, x, mask,
                                                rnn, **rows)
-                probs = jnp.take_along_axis(
-                    out, (length - 1)[:, None, None], axis=2)[:, :, 0]
-            tok = sample_tokens(probs, temp, top_k, key)
+                with scope("head/logits"):
+                    probs = jnp.take_along_axis(
+                        out, (length - 1)[:, None, None],
+                        axis=2)[:, :, 0]
+            with scope("head/sample"):
+                tok = sample_tokens(probs, temp, top_k, key)
             return tok, new_rnn if cold else kept(new_rnn)[0], counts
 
         def prefill(params, state, x, mask, temp, top_k, key):
@@ -1467,6 +1477,7 @@ class DecodeEngine:
             return chunk_prefill(params, state, x, mask, None, None,
                                  temp, top_k, key)
 
+        @scope("decode")
         def decode(params, state, pool, tabs, toks, temps, top_ks,
                    key, live=None):
             # ``tabs``: the dispatch's block tables; ``live`` [B]:
@@ -1474,22 +1485,26 @@ class DecodeEngine:
             # request, for the layers that ask (one operand each a
             # dispatch). Of the tables only ``filled`` is carried:
             # the rest is the same at every step
-            keys = jax.random.split(key, chunk)
+            with scope("head/sample"):
+                keys = jax.random.split(key, chunk)
 
             def body(carry, k):
                 pool, filled, tok = carry
                 out, new_rnn, counts = forward(
                     params, state, encode(tok), None,
                     seen(pool, tabs, filled), live=live)
-                nxt = sample_tokens(out[:, :, -1], temps, top_ks, k)
+                with scope("head/sample"):
+                    nxt = sample_tokens(out[:, :, -1], temps, top_ks, k)
                 return (*kept(new_rnn), nxt), (nxt, counts)
 
             (pool, _, tok), (seq, counts) = jax.lax.scan(
                 body, (pool, tabs[:, -1], toks), keys)
             # what the layers counted, summed over the chunk's steps
             counts = {name: jnp.sum(v) for name, v in counts.items()}
-            return pool, tok, jnp.swapaxes(seq, 0, 1), counts
+            with scope("head/sample"):
+                return pool, tok, jnp.swapaxes(seq, 0, 1), counts
 
+        @scope("decode")
         def fused_decode(params, state, pool, tabs, toks, temps,
                          top_ks, eos_ids, remaining, keys):
             # fused multi-round decode (ISSUE 16): K stepped rounds
@@ -1508,28 +1523,31 @@ class DecodeEngine:
             # slots rest on) and their overshoot is dropped at
             # landing, exactly like a chunk overshooting eos today.
             k_rounds = keys.shape[0]
-            flat = jax.vmap(
-                lambda kk: jax.random.split(kk, chunk))(keys)
-            flat = flat.reshape(k_rounds * chunk)
+            with scope("head/sample"):
+                flat = jax.vmap(
+                    lambda kk: jax.random.split(kk, chunk))(keys)
+                flat = flat.reshape(k_rounds * chunk)
 
             def body(carry, k):
                 pool, filled, tok = carry
                 out, new_rnn, _ = forward(params, state, encode(tok),
                                           None, seen(pool, tabs, filled))
-                nxt = sample_tokens(out[:, :, -1], temps, top_ks, k)
+                with scope("head/sample"):
+                    nxt = sample_tokens(out[:, :, -1], temps, top_ks, k)
                 return (*kept(new_rnn), nxt), nxt
 
             (pool, _, tok), seq = jax.lax.scan(
                 body, (pool, tabs[:, -1], toks), flat)
-            seq = jnp.swapaxes(seq, 0, 1)       # [B, K * chunk]
-            t = k_rounds * chunk
-            pos = jnp.arange(t)
-            is_eos = seq == eos_ids[:, None]
-            eos_pos = jnp.min(
-                jnp.where(is_eos, pos[None, :], t), axis=1)
-            n_valid = jnp.minimum(
-                jnp.minimum(eos_pos + 1, t),
-                jnp.clip(remaining, 0, t)).astype(jnp.int32)
+            with scope("head/sample"):
+                seq = jnp.swapaxes(seq, 0, 1)       # [B, K * chunk]
+                t = k_rounds * chunk
+                pos = jnp.arange(t)
+                is_eos = seq == eos_ids[:, None]
+                eos_pos = jnp.min(
+                    jnp.where(is_eos, pos[None, :], t), axis=1)
+                n_valid = jnp.minimum(
+                    jnp.minimum(eos_pos + 1, t),
+                    jnp.clip(remaining, 0, t)).astype(jnp.int32)
             return pool, tok, seq, n_valid
 
         self._prefill_jit = self._jit(prefill)
@@ -1551,6 +1569,8 @@ class DecodeEngine:
             if self.fused_rounds else None)
         self._state_admit_jit = None
         if self._state_layers:
+            @scope("admit")
+            @scope("mixer")
             def state_admit(slots, row, slot):
                 # a prefilled row's recurrent state into its slot
                 def put(p, o):
@@ -1635,6 +1655,8 @@ class DecodeEngine:
             self._verify_jit = self._jit(verify, donate_argnums=(2,))
         bt, s_ring = self.block_tokens, self._ring_slots
 
+        @scope("admit")
+        @scope("attn/cache")
         def scatter_row(pool, rnn1, table_row, length):
             # a cold admission's one whole-row write: a dense B=1
             # post-prefill row's valid window tokens to their
@@ -1664,6 +1686,8 @@ class DecodeEngine:
                              "pv": pvf.reshape(nbk, bt, h, dh)}
             return out
 
+        @scope("admit")
+        @scope("head/sample")
         def put_tok(toks, tok1, slot):
             return jax.lax.dynamic_update_slice(
                 toks, tok1.astype(toks.dtype), (slot,))
@@ -3594,8 +3618,6 @@ class DecodeEngine:
         occ = len(active) / self.n_slots
         self.stats["occupancy_sum"] += occ
         if self.tracer is not None:
-            self.tracer.counter("slot_occupancy", occ)
-            self.tracer.rate("serving_tokens_per_sec", emitted, dt)
             self._emit_counters()
 
     def step(self, results: Optional[Dict[int, GenerationResult]] = None

@@ -4,8 +4,9 @@ the net is built on the benchmark's normal path with
 ``ShapeDtypeStruct`` leaves in place of the seeded weights, and the
 jitted programs the cell runs are lowered with the shapes its traffic
 gives them. Prints each program's compile time, the compiler's memory
-analysis and which custom calls it holds. Nothing runs: this says
-nothing about results or times.
+analysis, which custom calls it holds and the Pallas calls' instruction
+names (a scope around a call can rename it: profiler/scopes.py). Nothing
+runs: this says nothing about results or times.
 
     JAX_PLATFORMS=cpu python scripts/compile_cell.py --workload <cell> [program ...]
 
@@ -14,7 +15,9 @@ cell): ``plain`` and ``remat``, ``fit_scan``'s one step without and with
 a layer's recomputation (``--batch N`` for another batch); ``afmoe`` (a
 served cell): ``decode`` and ``chunk``, the engine's two; ``cgpt_block``:
 ``decode`` (the served cell: the pool at the dtype the engine makes it,
-``--pool-dtype`` for another) or ``step`` (the trained cell).
+``--pool-dtype`` for another) or ``step`` (the trained cell);
+``granite_hybrid`` (a served cell): ``decode`` and ``prefill``, the cold
+prefill at one prompt bucket (``--batch N`` for a bucket of N tokens).
 
 ``--hash`` lowers only and prints the SHA-256 of each program's text,
 the Pallas kernels' serialized bodies left out (two trees that print
@@ -78,6 +81,18 @@ def struct_net(model, cfg, dtype, head: dict, optimizer=None):
 HASH_ONLY = "--hash" in sys.argv
 
 
+def kernel_instructions(txt):
+    """The Pallas calls' HLO instruction names without their numbers
+    (what the benchmark's readers find a kernel by), with counts."""
+    names = re.findall(
+        r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', txt)
+    out = {}
+    for n in names:
+        n = re.sub(r"\.\d+$", "", n)
+        out[n] = out.get(n, 0) + 1
+    return out
+
+
 def report(name, lowered):
     if HASH_ONLY:
         # (a Mosaic kernel's serialized body names its source file's
@@ -96,7 +111,8 @@ def report(name, lowered):
     print(name, "compiled in %.1fs" % (time.time() - t0),
           "args %.2f GiB out %.2f temp %.2f alias %.2f" % tuple(sizes),
           "total %.2f" % (sizes[0] + sizes[1] + sizes[2] - sizes[3]),
-          "custom calls:", {n: txt.count(n) for n in CALLS}, flush=True)
+          "custom calls:", {n: txt.count(n) for n in CALLS},
+          "kernel instructions:", kernel_instructions(txt), flush=True)
 
 
 def lfm2_moe(cfg, mix, model, which, batch):
@@ -199,7 +215,41 @@ def cgpt_block(cfg, mix, model, which, batch, pool_dtype=None):
         key_struct()))
 
 
-MODELS = {"lfm2_moe": lfm2_moe, "afmoe": afmoe, "cgpt_block": cgpt_block}
+def granite_hybrid(cfg, mix, model, which, batch):
+    from deeplearning4j_tpu.serving import DecodeEngine
+
+    dt, d = cfg["dtype"], cfg["hidden_size"]
+    net = struct_net(model, cfg, dt, {"norm_w": (d,)})
+    dep = {k: v for k, v in cfg["deployment"].items() if k != "why"}
+    eng = DecodeEngine(net, seed=1, **dep)
+    bucket = batch or 256
+    row = (S((1, bucket), "int32"), S((1, bucket), "float32"),
+           S((1,), "float32"), S((1,), "int32"), key_struct())
+    which = which or ["decode", "prefill"]
+    if "prefill" in which:
+        report(f"prefill bucket {bucket}", eng._prefill_jit.lower(
+            eng._params, eng._state, *row))
+    if "decode" in which:
+        # the pool as ``_ensure_pool`` makes it from a prefilled row:
+        # KV leaves a block, the slot-state layers' rows a slot
+        _, rnn1, _ = jax.eval_shape(eng._prefill_jit, eng._params,
+                                    eng._state, *row)
+        kv, slots = eng._split_row(rnn1)
+        pool = {name: {leaf: S((eng.kv_blocks, eng.block_tokens,
+                                st[leaf[1]].shape[1], st[leaf[1]].shape[3]),
+                               dt) for leaf in ("pk", "pv")}
+                for name, st in kv.items()}
+        pool.update(jax.tree.map(
+            lambda a: S((eng.n_slots,) + a.shape[1:], a.dtype), slots))
+        B, ring = eng.n_slots, eng._kinds[0].ring
+        report("decode", eng._decode_jit.lower(
+            eng._params, eng._state, pool, S((B, 2 * ring + 2), "int32"),
+            S((B,), "int32"), S((B,), "float32"), S((B,), "int32"),
+            key_struct(), S((B,), "int32")))
+
+
+MODELS = {"lfm2_moe": lfm2_moe, "afmoe": afmoe, "cgpt_block": cgpt_block,
+          "granite_hybrid": granite_hybrid}
 
 
 def main() -> int:
