@@ -248,6 +248,47 @@ _cotangent_where.defvjp(
     lambda keep, g: (None, jnp.where(keep, g, 0)))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_of_pairs(top_k, tokens, order):
+    """``tokens[order // top_k]``: a token's row for each of its
+    ``top_k`` (token, pick) pairs, in the sorted order. ``order`` is a
+    permutation of the pairs, so going back nothing is scattered: a
+    token's cotangent is the sum of its pairs' rows, gathered by the
+    inverse permutation. The rule's own forward makes the inverse, the
+    ``argsort`` the combine makes again (one operation once compiled),
+    so that forward alone the program is plain indexing's. The gathers
+    going back are traced under the scope of the call."""
+    return tokens[order // top_k]
+
+
+def _rows_of_pairs_bwd(top_k, back, g):
+    # one gather a pick, summed in float32 and cast once: a gather of
+    # all the pairs would be re-laid out as [M, k, D] before its sum
+    picks = back.reshape(-1, top_k)
+    total = g[picks[:, 0]].astype(jnp.float32)
+    for i in range(1, top_k):
+        total = total + g[picks[:, i]].astype(jnp.float32)
+    return total.astype(g.dtype), None
+
+
+_rows_of_pairs.defvjp(
+    lambda top_k, tokens, order: (tokens[order // top_k],
+                                  jnp.argsort(order)),
+    _rows_of_pairs_bwd)
+
+
+@jax.custom_vjp
+def _rows_in_token_order(ys, order, back):
+    """``ys[back]``: the sorted pairs' rows back in (token, pick) order.
+    Going back, the gather by the inverse permutation, ``g[order]``."""
+    return ys[back]
+
+
+_rows_in_token_order.defvjp(
+    lambda ys, order, back: (ys[back], order),
+    lambda order, g: (g[order], None, None))
+
+
 def dropless_moe(params, tokens, valid=None, *, top_k: int,
                  experts_held: Tuple[int, int], kernel=None,
                  gate_rule: str = "softmax_topk",
@@ -273,7 +314,10 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
     an expert held elsewhere adds nothing to the value or to any
     gradient: the rows past the held groups, which the kernel never
     writes going either way, are SELECTED away from the value and from
-    each product's cotangents (:func:`_cotangent_where`). Under
+    each product's cotangents (:func:`_cotangent_where`). The two row
+    movements by the sort's permutation state their own transposes too
+    (:func:`_rows_of_pairs`, :func:`_rows_in_token_order`): a gather by
+    the inverse permutation where autodiff would scatter-add. Under
     ``detach_scores`` the gates are constants to the gradient: neither
     the router nor ``tokens`` takes one through them (the block's
     ``freeze_router``). ``counts`` are int32 scalars: ``moe_picks`` (token
@@ -308,7 +352,7 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
         # or transposed, and may hold anything: select them away (do
         # not scale) from the value, and from each product's cotangents
         inside = (jnp.arange(m * top_k) < jnp.sum(sizes))[:, None]
-        xs = tokens[order // top_k]
+        xs = _rows_of_pairs(top_k, tokens, order)
     with scope("moe/combine"):
         xs = _cotangent_where(inside, xs)
     with scope("moe/experts"):
@@ -322,7 +366,8 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
     with scope("moe/combine"):
         ys = jnp.where(inside, ys, 0)
         back = jnp.argsort(order)
-        picked = ys[back].reshape(m, top_k, d).astype(jnp.float32)
+        picked = _rows_in_token_order(ys, order, back).reshape(
+            m, top_k, d).astype(jnp.float32)
         y = jnp.sum(picked * jnp.where(held, gates, 0.0)[..., None],
                     axis=1)
     if "Ws_in" in params:

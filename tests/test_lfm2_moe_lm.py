@@ -12,6 +12,7 @@ updater moves."""
 
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -200,6 +201,100 @@ def test_four_shares_add_up_to_the_uncut_reference():
     np.testing.assert_allclose(gx_sum, gx_ref, rtol=2e-4, atol=2e-6)
     np.testing.assert_allclose(gr_sum, gp_ref["router"], rtol=2e-4,
                                atol=2e-6)
+
+
+# ---------------------------------------------------------------------
+# the row movements' transpose rule: gathers by the inverse permutation
+# ---------------------------------------------------------------------
+D_ROWS = 20            # the layer's width here; no other size is 20
+
+
+def _plain_indexing(patch):
+    """The layer as plain indexing writes it: autodiff transposes each
+    of the two gathers into a scatter-add."""
+    patch.setattr(moe, "_rows_of_pairs",
+                  lambda top_k, tokens, order: tokens[order // top_k])
+    patch.setattr(moe, "_rows_in_token_order",
+                  lambda ys, order, back: ys[back])
+
+
+def _layer_loss(p, x, probe, valid, held, top_k, rule):
+    y, _ = moe.dropless_moe(p, x, valid, top_k=top_k, experts_held=held,
+                            kernel=False, gate_rule=rule, route_eps=1e-6)
+    return jnp.sum(y * probe)
+
+
+@pytest.mark.parametrize("rule", moe.GATE_RULES)
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("top_k", [1, 3])
+@pytest.mark.parametrize("held", [(0, 8), (2, 6), (5, 8)])
+def test_row_movements_transpose_as_plain_indexing_does(
+        monkeypatch, held, top_k, masked, rule):
+    full, x, probe = _expert_layer(seed=7, d=D_ROWS)
+    p = _share(full, *held)
+    valid = (jnp.asarray(np.random.default_rng(8).random(x.shape[0]) < 0.7)
+             if masked else None)
+
+    def grads():
+        return jax.grad(_layer_loss, argnums=(0, 1))(
+            p, x, probe, valid, held, top_k, rule)
+
+    gp, gx = grads()
+    with monkeypatch.context() as patch:
+        _plain_indexing(patch)
+        gp_plain, gx_plain = grads()
+    np.testing.assert_allclose(gx, gx_plain, rtol=2e-5, atol=2e-6)
+    for name in ("router", "We_in", "We_out"):
+        np.testing.assert_allclose(gp[name], gp_plain[name], rtol=2e-5,
+                                   atol=2e-6, err_msg=name)
+    assert np.any(np.asarray(gx)) and np.any(np.asarray(gp["We_out"]))
+    if masked:      # a row that does not exist takes no gradient
+        assert not np.any(np.asarray(gx)[~np.asarray(valid)])
+
+
+def _row_scatters(text):
+    """The operand types of a lowered program's ``scatter`` operations
+    over floating rows of the layer's width."""
+    operands = re.findall(
+        r'"stablehlo\.scatter"\(.*?\}\) : \((tensor<[^>]*>)', text,
+        re.DOTALL)
+    return [t for t in operands
+            if re.fullmatch(rf"tensor<\d+x{D_ROWS}x(f|bf)\d+>", t)]
+
+
+@pytest.mark.parametrize("top_k", [1, 3])
+def test_the_layers_gradient_scatters_no_row(monkeypatch, top_k):
+    """The rule is engaged: ``jax.grad`` of the layer lowers to gathers
+    alone over the pairs' rows (the integer ``bincount`` and the gates'
+    ``[M, E]`` scatter stay), where plain indexing's holds the two
+    scatter-adds; forward, the layer's program is plain indexing's."""
+    full, x, probe = _expert_layer(seed=7, d=D_ROWS)
+    held = (2, 6)
+    args = (_share(full, *held), x, probe, None, held, top_k,
+            "sigmoid_bias")
+
+    def lowered():
+        # (a jit of its own each time: nothing traced before the swap)
+        grad = jax.jit(jax.grad(_layer_loss, argnums=(0, 1)),
+                       static_argnums=(4, 5, 6)).lower(*args).as_text()
+        fwd = jax.jit(_layer_loss,
+                      static_argnums=(4, 5, 6)).lower(*args).as_text()
+        return grad, fwd
+
+    grad, fwd = lowered()
+    with monkeypatch.context() as patch:
+        _plain_indexing(patch)
+        grad_plain, fwd_plain = lowered()
+    assert _row_scatters(grad) == []
+    assert "stablehlo.scatter" in grad       # bincount's, the gates'
+    assert len(_row_scatters(grad_plain)) == 2
+    gather = '"stablehlo.gather"('
+    # (a gather for the rows back in token order, one a pick for the
+    # tokens' cotangent)
+    assert grad.count(gather) == grad_plain.count(gather) + 1 + top_k
+    # forward only (a served program): the same gathers, nothing more
+    assert fwd.count(gather) == fwd_plain.count(gather) >= 2
+    assert fwd == fwd_plain
 
 
 def test_route_names_its_epsilon():
