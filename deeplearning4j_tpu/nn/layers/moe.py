@@ -248,45 +248,103 @@ _cotangent_where.defvjp(
     lambda keep, g: (None, jnp.where(keep, g, 0)))
 
 
+def _held_pick(rows, picks, held, i):
+    """Pick ``i``'s rows of the sorted pairs' ``rows``, one ``[M, D]``
+    gather by column ``i`` of the inverse permutation ``picks``, in
+    float32; 0, selected, where the pick is not held."""
+    return jnp.where(held[:, i:i + 1], rows[picks[:, i]], 0).astype(
+        jnp.float32)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _rows_of_pairs(top_k, tokens, order):
+def _rows_of_pairs(top_k, tokens, order, held):
     """``tokens[order // top_k]``: a token's row for each of its
     ``top_k`` (token, pick) pairs, in the sorted order. ``order`` is a
     permutation of the pairs, so going back nothing is scattered: a
-    token's cotangent is the sum of its pairs' rows, gathered by the
-    inverse permutation. The rule's own forward makes the inverse, the
-    ``argsort`` the combine makes again (one operation once compiled),
-    so that forward alone the program is plain indexing's. The gathers
-    going back are traced under the scope of the call."""
+    token's cotangent is the sum of its HELD pairs' rows, gathered by
+    the inverse permutation a pick at a time (``held`` ``[M, top_k]``;
+    a pair not held sorts past the groups, where the grouped product's
+    transpose writes nothing, and is selected away after its gather).
+    The rule's own forward makes the inverse, the ``argsort`` the
+    combine is handed too (one operation once compiled), so that forward
+    alone the program is plain indexing's. The gathers going back are
+    traced under the scope of the call."""
     return tokens[order // top_k]
 
 
-def _rows_of_pairs_bwd(top_k, back, g):
-    # one gather a pick, summed in float32 and cast once: a gather of
-    # all the pairs would be re-laid out as [M, k, D] before its sum
+def _rows_of_pairs_bwd(top_k, res, g):
+    # one gather a pick, selected, summed in float32 and cast once: a
+    # gather of all the pairs would be re-laid out as [M, k, D] before
+    # its sum, and a select before it is a pass over the pairs' rows
+    back, held = res
     picks = back.reshape(-1, top_k)
-    total = g[picks[:, 0]].astype(jnp.float32)
-    for i in range(1, top_k):
-        total = total + g[picks[:, i]].astype(jnp.float32)
-    return total.astype(g.dtype), None
+    total = functools.reduce(jnp.add, [
+        _held_pick(g, picks, held, i) for i in range(top_k)])
+    return total.astype(g.dtype), None, None
 
 
 _rows_of_pairs.defvjp(
-    lambda top_k, tokens, order: (tokens[order // top_k],
-                                  jnp.argsort(order)),
+    lambda top_k, tokens, order, held: (
+        tokens[order // top_k], (jnp.argsort(order), held)),
     _rows_of_pairs_bwd)
 
 
 @jax.custom_vjp
-def _rows_in_token_order(ys, order, back):
-    """``ys[back]``: the sorted pairs' rows back in (token, pick) order.
-    Going back, the gather by the inverse permutation, ``g[order]``."""
-    return ys[back]
+def _combine_picks(ys, gates, held, inside, order):
+    """``sum over a token's held picks of gate x ys[the pick's sorted
+    row]``, float32 ``[M, D]``. ``ys`` ``[M k, D]`` are the sorted
+    pairs' rows, those past the held groups never written: they may
+    hold anything and are selected away, not scaled. ``gates`` and
+    ``held`` are ``[M, k]``; ``order`` is the sort's permutation, the
+    pairs not held last, so that ``inside`` ``[M k, 1]``, the rows
+    within the groups, is ``held`` in the sorted order.
+
+    With no gradient asked this is plain indexing: the select over the
+    sorted rows, ONE gather of all the pairs by the inverse
+    permutation, their float32 ``[M, k, D]`` and its weighted sum (a
+    served program is what it was). Under a gradient the rule works a
+    pick at a time on gathered ``[M, D]`` rows. Forward: ``top_k``
+    gathers, each selected by its column of ``held``, scaled and added
+    in float32. Back: a sorted pair's cotangent is its gate times its
+    token's row of ``dy``, one gather from ``[M, D]``, the product in
+    float32 rounded once to ``ys``' dtype (``dy`` holds no unwritten
+    row, so a pair not held takes a gate of 0 there; and ``dy`` is
+    read at ``ys``' dtype, which is exact where the caller casts this
+    sum to that dtype, as the layer does); a gate's is its pick's row
+    against ``dy``. No float32 ``[M, k, D]`` either way, no select over
+    ``[M k, D]``, and no pass over the pairs' rows but the one that
+    writes their cotangent."""
+    m, top_k = gates.shape
+    ys = jnp.where(inside, ys, 0)
+    back = jnp.argsort(order)
+    picked = ys[back].reshape(m, top_k, -1).astype(jnp.float32)
+    return jnp.sum(picked * jnp.where(held, gates, 0.0)[..., None], axis=1)
 
 
-_rows_in_token_order.defvjp(
-    lambda ys, order, back: (ys[back], order),
-    lambda order, g: (g[order], None, None))
+def _combine_picks_fwd(ys, gates, held, inside, order):
+    picks = jnp.argsort(order).reshape(gates.shape)
+    y = functools.reduce(jnp.add, [
+        _held_pick(ys, picks, held, i) * gates[:, i:i + 1]
+        for i in range(gates.shape[1])])
+    return y, (ys, gates, held, order, picks)
+
+
+def _combine_picks_bwd(res, dy):
+    ys, gates, held, order, picks = res
+    top_k = gates.shape[1]
+    # dy holds no unwritten row: the pairs not held take a gate of 0
+    gate = jnp.where(held, gates, 0.0).reshape(-1)[order]
+    # the layer casts the sum to ys' dtype, so dy is such a value lifted
+    # to float32: its rows are gathered at that dtype, nothing rounded
+    rows = dy.astype(ys.dtype)[order // top_k]
+    d_ys = (gate[:, None] * rows).astype(ys.dtype)
+    d_gates = jnp.stack(
+        [jnp.sum(dy * _held_pick(ys, picks, held, i), axis=-1)
+         for i in range(top_k)], axis=1)
+    return d_ys, d_gates.astype(gates.dtype), None, None, None
+
+
+_combine_picks.defvjp(_combine_picks_fwd, _combine_picks_bwd)
 
 
 def dropless_moe(params, tokens, valid=None, *, top_k: int,
@@ -314,17 +372,24 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
     an expert held elsewhere adds nothing to the value or to any
     gradient: the rows past the held groups, which the kernel never
     writes going either way, are SELECTED away from the value and from
-    each product's cotangents (:func:`_cotangent_where`). The two row
-    movements by the sort's permutation state their own transposes too
-    (:func:`_rows_of_pairs`, :func:`_rows_in_token_order`): a gather by
-    the inverse permutation where autodiff would scatter-add. Under
+    each product's cotangents. The row movement by the sort's
+    permutation and the gate-weighted combine state their own rules
+    (:func:`_rows_of_pairs`, :func:`_combine_picks`): under a gradient
+    both work a pick at a time on gathered ``[M, D]`` rows, by the
+    inverse permutation where autodiff would scatter-add, each gather
+    selected by its column of ``held`` inside the float32 sum that
+    follows it, so that no ``[M, k, D]`` array is made and no select
+    passes over the pairs' ``[M k, D]`` rows; with no gradient asked
+    both are plain indexing. (The select on the first product's
+    cotangent, :func:`_cotangent_where`, rides the pass that puts the
+    activation's two halves side by side.) Under
     ``detach_scores`` the gates are constants to the gradient: neither
     the router nor ``tokens`` takes one through them (the block's
     ``freeze_router``). ``counts`` are int32 scalars: ``moe_picks`` (token
     x pick pairs routed), ``moe_picks_held`` (those on held experts),
     ``moe_experts_touched`` (held experts with at least one row),
     ``moe_load_max`` (the fullest held expert's rows)."""
-    m, d = tokens.shape
+    m = tokens.shape[0]
     lo, hi = experts_held
     n_held = hi - lo
     if params["We_in"].shape[0] != n_held:
@@ -351,10 +416,10 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
         # rows past the groups are never written by the kernel, forward
         # or transposed, and may hold anything: select them away (do
         # not scale) from the value, and from each product's cotangents
+        # (as ``inside`` over the sorted rows, or as ``held`` over a
+        # pick's gathered rows: the same pairs)
         inside = (jnp.arange(m * top_k) < jnp.sum(sizes))[:, None]
-        xs = _rows_of_pairs(top_k, tokens, order)
-    with scope("moe/combine"):
-        xs = _cotangent_where(inside, xs)
+        xs = _rows_of_pairs(top_k, tokens, order, held)
     with scope("moe/experts"):
         gu = grouped_product(xs, params["We_in"], sizes, kernel)
     with scope("moe/combine"):
@@ -364,12 +429,7 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
         act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(tokens.dtype)
         ys = grouped_product(act, params["We_out"], sizes, kernel)
     with scope("moe/combine"):
-        ys = jnp.where(inside, ys, 0)
-        back = jnp.argsort(order)
-        picked = _rows_in_token_order(ys, order, back).reshape(
-            m, top_k, d).astype(jnp.float32)
-        y = jnp.sum(picked * jnp.where(held, gates, 0.0)[..., None],
-                    axis=1)
+        y = _combine_picks(ys, gates, held, inside, order)
     if "Ws_in" in params:
         with scope("moe/shared"):
             y = y + gated_ffn(tokens, params["Ws_in"],

@@ -209,47 +209,129 @@ def test_four_shares_add_up_to_the_uncut_reference():
 D_ROWS = 20            # the layer's width here; no other size is 20
 
 
+def _plain_rows_of_pairs(top_k, tokens, order, held):
+    inside = (jnp.arange(order.shape[0]) < jnp.sum(held))[:, None]
+    return moe._cotangent_where(inside, tokens[order // top_k])
+
+
+def _plain_combine(ys, gates, held, inside, order):
+    m, top_k = gates.shape
+    picked = jnp.where(inside, ys, 0)[jnp.argsort(order)].reshape(
+        m, top_k, -1).astype(jnp.float32)
+    return jnp.sum(picked * jnp.where(held, gates, 0.0)[..., None], axis=1)
+
+
 def _plain_indexing(patch):
-    """The layer as plain indexing writes it: autodiff transposes each
-    of the two gathers into a scatter-add."""
-    patch.setattr(moe, "_rows_of_pairs",
-                  lambda top_k, tokens, order: tokens[order // top_k])
-    patch.setattr(moe, "_rows_in_token_order",
-                  lambda ys, order, back: ys[back])
+    """The layer as plain indexing writes it: a select over the sorted
+    pairs' rows on either side, one gather of all the pairs each way
+    (autodiff transposes each into a scatter-add) and the float32
+    ``[M, k, D]`` under the weighted sum."""
+    patch.setattr(moe, "_rows_of_pairs", _plain_rows_of_pairs)
+    patch.setattr(moe, "_combine_picks", _plain_combine)
 
 
-def _layer_loss(p, x, probe, valid, held, top_k, rule):
+def _layer_loss(p, x, probe, valid, held, top_k, rule, frozen=False):
     y, _ = moe.dropless_moe(p, x, valid, top_k=top_k, experts_held=held,
-                            kernel=False, gate_rule=rule, route_eps=1e-6)
+                            kernel=False, gate_rule=rule, route_eps=1e-6,
+                            detach_scores=frozen)
     return jnp.sum(y * probe)
 
 
+@pytest.mark.parametrize("frozen", [True, False],
+                         ids=["frozen", "learning"])
 @pytest.mark.parametrize("rule", moe.GATE_RULES)
 @pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
 @pytest.mark.parametrize("top_k", [1, 3])
 @pytest.mark.parametrize("held", [(0, 8), (2, 6), (5, 8)])
 def test_row_movements_transpose_as_plain_indexing_does(
-        monkeypatch, held, top_k, masked, rule):
+        monkeypatch, held, top_k, masked, rule, frozen):
+    """The combine's rule and the row movement's against plain
+    indexing: the value, the input's gradient and every leaf's. The
+    router's gradient is the rule's ``d_gates`` through :func:`route`;
+    with the routing frozen it is zero on both sides."""
     full, x, probe = _expert_layer(seed=7, d=D_ROWS)
     p = _share(full, *held)
     valid = (jnp.asarray(np.random.default_rng(8).random(x.shape[0]) < 0.7)
              if masked else None)
 
     def grads():
-        return jax.grad(_layer_loss, argnums=(0, 1))(
-            p, x, probe, valid, held, top_k, rule)
+        return jax.value_and_grad(_layer_loss, argnums=(0, 1))(
+            p, x, probe, valid, held, top_k, rule, frozen)
 
-    gp, gx = grads()
+    value, (gp, gx) = grads()
     with monkeypatch.context() as patch:
         _plain_indexing(patch)
-        gp_plain, gx_plain = grads()
+        value_plain, (gp_plain, gx_plain) = grads()
+    np.testing.assert_allclose(value, value_plain, rtol=2e-5)
     np.testing.assert_allclose(gx, gx_plain, rtol=2e-5, atol=2e-6)
     for name in ("router", "We_in", "We_out"):
         np.testing.assert_allclose(gp[name], gp_plain[name], rtol=2e-5,
                                    atol=2e-6, err_msg=name)
     assert np.any(np.asarray(gx)) and np.any(np.asarray(gp["We_out"]))
+    if frozen or top_k > 1:     # (one softmax pick's gate is 1, whatever)
+        assert np.any(np.asarray(gp["router"])) != frozen
+        assert np.any(np.asarray(gp_plain["router"])) != frozen
     if masked:      # a row that does not exist takes no gradient
         assert not np.any(np.asarray(gx)[~np.asarray(valid)])
+
+
+def _poisoned(rows, n_inside):
+    return rows.at[n_inside:].set(jnp.nan)
+
+
+@pytest.mark.parametrize("top_k", [1, 3])
+def test_rows_never_written_reach_neither_value_nor_gradient(top_k):
+    """Select, do not scale: the sorted pairs' rows past the held groups
+    are never written by the grouped kernel, going either way. With
+    ``nan`` in every such row of ``ys`` the combine's value and its
+    gradients (the rows', the gates') are finite and the clean input's,
+    and so is the tokens' cotangent from a poisoned cotangent of
+    ``xs``, for a share with picks not held and a ``valid`` mask."""
+    rng = np.random.default_rng(12)
+    m, d, e, lo, hi = 24, D_ROWS, 8, 2, 6
+    idx = jnp.asarray(rng.integers(0, e, (m, top_k)))
+    valid = jnp.asarray(rng.random(m) < 0.7)
+    held = (idx >= lo) & (idx < hi) & valid[:, None]
+    n_inside = int(jnp.sum(held))
+    assert 0 < n_inside < m * top_k
+    order = jnp.argsort(jnp.where(held, idx - lo, hi - lo).reshape(-1),
+                        stable=True)
+    inside = (jnp.arange(m * top_k) < n_inside)[:, None]
+    gates = jnp.asarray(rng.random((m, top_k)) + 0.1, jnp.float32)
+    ys = jnp.asarray(rng.normal(size=(m * top_k, d)), jnp.float32)
+    probe = jnp.asarray(rng.normal(size=(m, d)), jnp.float32)
+
+    def combined(ys, gates):
+        y = moe._combine_picks(ys, gates, held, inside, order)
+        return jnp.sum(y * probe), y
+
+    (_, y), (g_ys, g_gates) = jax.value_and_grad(
+        combined, argnums=(0, 1), has_aux=True)(ys, gates)
+    (_, y_bad), (g_ys_bad, g_gates_bad) = jax.value_and_grad(
+        combined, argnums=(0, 1), has_aux=True)(
+            _poisoned(ys, n_inside), gates)
+    # (no gradient asked: the rule's primal selects too)
+    y_primal = moe._combine_picks(_poisoned(ys, n_inside), gates, held,
+                                  inside, order)
+    for got, want in ((y_bad, y), (g_ys_bad, g_ys), (g_gates_bad, g_gates),
+                      (y_primal, y)):
+        assert np.all(np.isfinite(np.asarray(got)))
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+    assert np.any(np.asarray(g_gates)) and np.any(np.asarray(g_ys))
+    # a pair not held takes nothing back, and gives its gate nothing
+    assert not np.any(np.asarray(g_ys)[n_inside:])
+    assert not np.any(np.asarray(g_gates)[~np.asarray(held)])
+
+    tokens = jnp.asarray(rng.normal(size=(m, d)), jnp.float32)
+    cot = jnp.asarray(rng.normal(size=(m * top_k, d)), jnp.float32)
+    xs, back = jax.vjp(
+        lambda t: moe._rows_of_pairs(top_k, t, order, held), tokens)
+    np.testing.assert_array_equal(xs, tokens[order // top_k])
+    (g_tokens,) = back(jnp.where(inside, cot, 0))
+    (g_tokens_bad,) = back(_poisoned(cot, n_inside))
+    assert np.all(np.isfinite(np.asarray(g_tokens_bad)))
+    np.testing.assert_allclose(g_tokens_bad, g_tokens, rtol=1e-6)
+    assert not np.any(np.asarray(g_tokens)[~np.asarray(valid)])
 
 
 def _row_scatters(text):
@@ -262,20 +344,35 @@ def _row_scatters(text):
             if re.fullmatch(rf"tensor<\d+x{D_ROWS}x(f|bf)\d+>", t)]
 
 
+def _row_results(text, op):
+    """How many rows each ``op`` of a lowered program gives (a line's
+    last type is its result's), for those whose result is floating
+    rows of the layer's width."""
+    results = re.findall(
+        rf'stablehlo\.{op}\b[^\n]*tensor<(\d+)x{D_ROWS}x(?:f|bf)\d+>\n',
+        text)
+    return sorted(int(n) for n in results)
+
+
 @pytest.mark.parametrize("top_k", [1, 3])
 def test_the_layers_gradient_scatters_no_row(monkeypatch, top_k):
-    """The rule is engaged: ``jax.grad`` of the layer lowers to gathers
-    alone over the pairs' rows (the integer ``bincount`` and the gates'
-    ``[M, E]`` scatter stay), where plain indexing's holds the two
-    scatter-adds; forward, the layer's program is plain indexing's."""
+    """The rules are engaged: ``jax.grad`` of the layer lowers to
+    gathers alone over the pairs' rows (the integer ``bincount`` and
+    the gates' ``[M, E]`` scatter stay), a pick at a time, where plain
+    indexing's holds the two scatter-adds, the float32 ``[M, k, D]``
+    and selects over the sorted pairs' rows; forward, the layer's
+    program is plain indexing's."""
     full, x, probe = _expert_layer(seed=7, d=D_ROWS)
     held = (2, 6)
     args = (_share(full, *held), x, probe, None, held, top_k,
             "sigmoid_bias")
+    m = x.shape[0]
 
     def lowered():
         # (a jit of its own each time: nothing traced before the swap)
-        grad = jax.jit(jax.grad(_layer_loss, argnums=(0, 1)),
+        # (the value too, as a training step asks: the probe's loss is
+        # linear in the layer's value, whose forward would else be dead)
+        grad = jax.jit(jax.value_and_grad(_layer_loss, argnums=(0, 1)),
                        static_argnums=(4, 5, 6)).lower(*args).as_text()
         fwd = jax.jit(_layer_loss,
                       static_argnums=(4, 5, 6)).lower(*args).as_text()
@@ -288,10 +385,24 @@ def test_the_layers_gradient_scatters_no_row(monkeypatch, top_k):
     assert _row_scatters(grad) == []
     assert "stablehlo.scatter" in grad       # bincount's, the gates'
     assert len(_row_scatters(grad_plain)) == 2
+    # plain indexing: all the pairs' rows at once, forward, both ways
+    assert _row_results(grad_plain, "gather") == [m * top_k] * 2
+    # the rules: the tokens' rows for the pairs and, going back, the
+    # pairs' rows of the [M, D] cotangent; [M, D] rows a pick for the
+    # value, for the gates' cotangent and for the tokens'
+    assert _row_results(grad, "gather") == sorted(
+        [m * top_k] * 2 + [m] * (3 * top_k))
     gather = '"stablehlo.gather"('
-    # (a gather for the rows back in token order, one a pick for the
-    # tokens' cotangent)
-    assert grad.count(gather) == grad_plain.count(gather) + 1 + top_k
+    # (and the pairs' gates in the sorted order, numbers)
+    assert grad.count(gather) == grad_plain.count(gather) + 3 * top_k + 1
+    # no float32 [M, k, D], and no select over the sorted pairs' rows
+    # (the one on ``gu`` is as wide as the experts, not as the layer)
+    picked = f"tensor<{m}x{top_k}x{D_ROWS}xf32>"
+    assert picked in grad_plain and picked not in grad
+    if top_k > 1:
+        assert m * top_k in _row_results(grad_plain, "select")
+        assert m * top_k not in _row_results(grad, "select")
+        assert m in _row_results(grad, "select")
     # forward only (a served program): the same gathers, nothing more
     assert fwd.count(gather) == fwd_plain.count(gather) >= 2
     assert fwd == fwd_plain
