@@ -496,6 +496,76 @@ def afmoe_lm(
     return conf
 
 
+def evabyte_lm(
+    vocab_size: int = 320,
+    hidden_size: int = 64,
+    num_hidden_layers: int = 2,
+    num_attention_heads: int = 4,
+    head_dim: int = 16,
+    intermediate_size: int = 128,
+    window_size: int = 32,
+    chunk_size: int = 4,
+    num_pred_heads: int = 8,
+    rope_theta: float = 100000.0,
+    rms_norm_eps: float = 1e-5,
+    max_position_embeddings: int = 512,
+    norm_add_unit_offset: bool = True,
+    fp32_skip_add: bool = True,
+    initializer_range: float = 0.02,
+    dtype: str = "float32",
+    seed: int = 12345,
+):
+    """An ``evabyte`` LM (HF ``EvaByteForCausalLM``, ``attention_class``
+    ``eva``), served or scored, under its config's own key names: the
+    byte embedding, one ``HybridMoeBlock(mixer="eva")`` a layer (EVA
+    attention over an aligned window of ``window_size`` bytes and one
+    learned summary a ``chunk_size`` bytes of every earlier window,
+    rotary positions, a dense SwiGLU of ``intermediate_size``; RMSNorm
+    with the unit offset, the residual sum in float32), and an untied
+    head of ``num_pred_heads x vocab_size`` rows of which head 0, the
+    next byte's, is what ``output`` and the serving engine read
+    (``TiedLMHeadImpl.all_logits`` has all of them; multi-byte
+    self-speculation is not built). ``max_position_embeddings`` is the
+    longest context a streaming state has room for."""
+    from deeplearning4j_tpu.nn.conf.distribution import NormalDistribution
+    from deeplearning4j_tpu.nn.layers.hybrid import (
+        HybridMoeBlock,
+        TiedLMHead,
+    )
+
+    b = (
+        NeuralNetConfiguration.Builder()
+        .seed(seed)
+        .updater(Updater.ADAM)
+        .activation("identity")
+        .list()
+    )
+    b.layer(0, L.EmbeddingLayer(
+        n_in=vocab_size, n_out=hidden_size, sequence=True,
+        weight_init=WeightInit.DISTRIBUTION,
+        dist=NormalDistribution(0.0, initializer_range)))
+    for i in range(num_hidden_layers):
+        b.layer(i + 1, HybridMoeBlock(
+            n_in=hidden_size, n_out=hidden_size, mixer="eva",
+            rms_eps=rms_norm_eps, n_heads=num_attention_heads,
+            n_kv_heads=num_attention_heads, d_head=head_dim,
+            rope_theta=rope_theta, eva_window=window_size,
+            eva_chunk=chunk_size, stream_max_t=max_position_embeddings,
+            norm_unit_offset=norm_add_unit_offset,
+            fp32_residual=fp32_skip_add, n_router=0,
+            d_shared=intermediate_size, init_std=initializer_range))
+    b.layer(num_hidden_layers + 1, TiedLMHead(
+        n_in=hidden_size, n_out=vocab_size, tie_to=None,
+        n_pred_heads=num_pred_heads,
+        norm_unit_offset=norm_add_unit_offset, rms_eps=rms_norm_eps,
+        init_std=initializer_range, activation="softmax",
+        loss_function=LossFunction.MCXENT))
+    conf = b.build()
+    for c in conf.confs:
+        c.dtype = dtype
+    return conf
+
+
 def lfm2_moe_lm(
     vocab_size: int = 64,
     hidden_size: int = 64,

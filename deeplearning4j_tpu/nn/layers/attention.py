@@ -1029,10 +1029,12 @@ def _should_use_flash_paged(toggle, block_tokens: int,
 # jitted on its own so that a program of many layers traces and lowers
 # the kernel once (same shapes, same ``tm``), not once a layer: the
 # body's per-entry branches make a trace cost about a second
-@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tm", "interpret", "causal",
+                                             "stats"))
 def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
                            filled, lengths, *, tm: int,
-                           interpret: bool = False):
+                           interpret: bool = False, causal: bool = True,
+                           stats: bool = False):
     """Fused pallas paged-attention kernel (ISSUE 12, regridded in
     ISSUE 25, the walk moved into the body in ISSUE 30; pallas_guide.md,
     boom_attention_tricks.md §8-12 — the in-repo flash kernel's decode
@@ -1099,6 +1101,17 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
       one MXU product a KV head scores the whole group against its
       keys. ``grp`` 1 is the kernel of ISSUE 30, trace for trace.
 
+    - TWO MORE FORMS (ISSUE 41), both off by default and both decided
+      while tracing, so the call without them is the program it was.
+      ``causal=False``: a table read WHOLE up to ``filled`` by every
+      query of the chunk (entry ``e`` is seen iff ``floor <= e <
+      filled``; ``lengths`` 0): a cache that is not a sequence of the
+      queries' own positions, EVA's summaries (nn/layers/eva.py).
+      ``stats=True``: the call also hands back each query row's
+      ``log(sum exp)`` of its scores (``-1e30`` where it saw nothing)
+      and its output in float32, so that two walks (two tables, two
+      pools) merge into ONE softmax outside; ungrouped heads only.
+
     Shapes: q [B, Hq, t, dh]; pk/pv [nb, bt, Hkv, dh] (post-scatter);
     bid/bval [B, ntab] int32 (pool block per logical block, validity;
     padded here to whole compute blocks, and reduced here to each
@@ -1129,6 +1142,9 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
     # short tile of GROUPED heads, all heads in one MXU product; tile
     form = _paged_tile_form(grp, tq, h_sz)
     short, flat = form == "short", form == "flat"
+    if stats and grp > 1:
+        raise NotImplementedError(
+            "stats=True hands back ungrouped heads' sums only")
     p_blk = _paged_blocks_per_step(bt, h_sz, dh, pk.dtype, ntab, grp, t)
     n_keys = p_blk * bt
     scale = dh ** -0.5
@@ -1169,10 +1185,21 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
 
     def kernel(bid_ref, bval_ref, lo_ref, floor_ref, filled_ref,
                len_ref, elo_ref, ehi_ref, q_ref, pk_ref, pv_ref, o_ref,
-               kbuf, vbuf, sem, m_ref, l_ref, acc_ref, slot_ref):
+               *rest):
+        # (``stats``: the sums' output rides before the scratch)
+        lse_ref = rest[0] if stats else None
+        kbuf, vbuf, sem, m_ref, l_ref, acc_ref, slot_ref = rest[-7:]
         b = pl.program_id(0)
         i = pl.program_id(1)
         cur = b * nq + i
+
+        def tile_start(bb, ii):
+            """The first query position of tile ``ii`` of row ``bb``;
+            without a causal edge every query stands at the table's
+            last entry."""
+            if causal:
+                return filled_ref[bb] + ii * tq
+            return filled_ref[bb] - 1
 
         def span(bb, ii):
             """Entries ``[lo, hi]`` of row ``bb`` that some query of
@@ -1181,11 +1208,15 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             row's first and last mapped entry, and the compute blocks
             ``[j_lo, j_end)`` that hold them: none (``j_end == j_lo``)
             for an idle slot or a tile below its row's floor."""
-            q0 = filled_ref[bb] + ii * tq
+            q0 = tile_start(bb, ii)
             lo = jnp.maximum(jnp.maximum(q0 - tm + 1, 0) // bt
                              - lo_ref[bb], elo_ref[bb])
-            hi = jnp.minimum((q0 + tq - 1) // bt - lo_ref[bb],
-                             ehi_ref[bb])
+            if causal:
+                hi = jnp.minimum((q0 + tq - 1) // bt - lo_ref[bb],
+                                 ehi_ref[bb])
+            else:       # (an empty table: ``q0`` -1, no entry to reach)
+                hi = jnp.where(q0 < 0, -1, jnp.minimum(
+                    q0 // bt - lo_ref[bb], ehi_ref[bb]))
             j_lo = lo // p_blk
             return lo, hi, j_lo, jnp.where(hi >= lo, hi // p_blk + 1,
                                            j_lo)
@@ -1235,7 +1266,7 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        q0 = filled_ref[b] + i * tq            # tile's first position
+        q0 = tile_start(b, i)                  # tile's first position
         written = filled_ref[b] + len_ref[b]
 
         def short_block(k0, slot):
@@ -1251,7 +1282,7 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             reachable = kpos >= floor_ref[b]
 
             def row(r, carry):
-                qpos = q0 + r
+                qpos = q0 + r if causal else q0
                 ok = reachable & (kpos <= qpos) & (kpos > qpos - tm)
                 qv = q_ref[0, r].astype(jnp.float32) * scale  # [H, dh]
                 s = jnp.sum(kb * qv[None], axis=2, keepdims=True)
@@ -1292,7 +1323,7 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             row = jax.lax.broadcasted_iota(jnp.int32, (n_q, 1), 0)
             qpos = q0 + functools.reduce(
                 jnp.add, [(row >= i * hq_sz).astype(jnp.int32)
-                          for i in range(1, t)], 0)
+                          for i in range(1, t)], 0) if causal else q0
             ok = (((col & (h_sz - 1)) == (row & (h_sz - 1)))
                   & (kpos <= qpos) & (kpos > qpos - tm)
                   & (kpos >= floor_ref[b]))             # [rows, cols]
@@ -1324,7 +1355,7 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             kpos = positions(k0, jax.lax.broadcasted_iota(
                 jnp.int32, (1, n_keys), 1))
             qrow = jax.lax.broadcasted_iota(jnp.int32, (n_rows, 1), 0)
-            qpos = q0 + (qrow % tq if grp > 1 else qrow)
+            qpos = q0 + (qrow % tq if grp > 1 else qrow) if causal else q0
             ok = ((kpos <= qpos) & (kpos > qpos - tm)
                   & (kpos >= floor_ref[b]))              # [rows, keys]
             # value-level masking (see docstring): one [keys, 1]
@@ -1388,6 +1419,11 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
         l = l_ref[...][..., :1]
         o_ref[0] = (acc_ref[...] / jnp.where(l == 0, 1.0, l)
                     ).astype(o_ref.dtype)
+        if stats:
+            lse_ref[0] = jnp.where(
+                l_ref[...] == 0, -1e30,
+                m_ref[...] + jnp.log(jnp.where(l_ref[...] == 0, 1.0,
+                                               l_ref[...])))
 
     def q_map(b, i, *refs):
         if short or flat:
@@ -1395,6 +1431,13 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
         return (b * nq + i, 0, 0, 0) if grp > 1 else (b, 0, i, 0)
 
     pool_rows = (n_keys * h_sz,) if flat else (n_keys, h_sz)
+    o_spec = pl.BlockSpec((1, *rows, dh), q_map)
+    o_shape = jax.ShapeDtypeStruct(
+        q.shape, jnp.float32 if stats else q.dtype)
+    if stats:
+        o_spec = [o_spec, pl.BlockSpec((1, *rows, 128), q_map)]
+        o_shape = [o_shape, jax.ShapeDtypeStruct(
+            (*q.shape[:-1], 128), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=8,
         grid=grid,
@@ -1403,7 +1446,7 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, *rows, dh), q_map),
+        out_specs=o_spec,
         scratch_shapes=[
             pltpu.VMEM((2, *pool_rows, dh), pk.dtype),
             pltpu.VMEM((2, *pool_rows, dh), pv.dtype),
@@ -1416,12 +1459,18 @@ def _paged_flash_attention(q, pk, pv, bid, bval, lo_blk, floor,
     )
     o = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=o_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=_PAGED_VMEM_LIMIT),
         interpret=interpret,
     )(bid, bval, lo_blk, floor, filled, lengths, e_lo, e_hi, q, pk, pv)
+    if stats:
+        # ``[B, H, t, dh]`` float32 and ``[B, H, t]``, whatever the form
+        o, lse = o[0], o[1][..., 0]
+        if short:
+            return jnp.swapaxes(o, 1, 2), jnp.swapaxes(lse, 1, 2)
+        return o, lse
     if short:
         return jnp.swapaxes(o, 1, 2)
     if flat:

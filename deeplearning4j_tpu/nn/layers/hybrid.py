@@ -50,9 +50,20 @@ outputs become picks and gates (nn/layers/moe.py ``route``):
 ``route_scale``). ``TiedLMHead(tie_to=None)`` is an untied head with
 its own ``E``.
 
+**The ``evabyte`` options.** ``mixer="eva"``: EVA attention
+(nn/layers/eva.py: exact keys inside an aligned window of ``eva_window``
+tokens, one learned summary an ``eva_chunk`` of every earlier window,
+one softmax over both; ungrouped heads, rotary positions, the two
+pooling vectors ``eva_mu`` / ``eva_phi`` a head). ``norm_unit_offset``:
+every RMSNorm's gain is ``1 + w``. ``fp32_residual``: the block hands
+on a float32 stream whatever dtype its weights have, and its products
+read it rounded to theirs. ``TiedLMHead(n_pred_heads=P)`` holds ``P x
+n_out`` rows and serves the first ``n_out`` (the next token's).
+
 **State, rows and counters.** A block's streaming state is its mixer's
-and nothing else (the attention cache, ``{"conv", "ssm"}``, or the short
-convolution's ``{"conv"}``, its last ``conv_kernel - 1`` gated inputs). What a
+and nothing else (the attention cache, ``{"conv", "ssm"}``, the short
+convolution's ``{"conv"}``, its last ``conv_kernel - 1`` gated inputs, or
+EVA's window, summaries and position). What a
 caller that batches slots has to say and wants to know travels beside
 it, as two keywords of ``apply`` that ``_forward_fn`` hands to a layer
 whose bean has ``wants_live``: ``live`` ``[B]``, which rows exist (an
@@ -60,7 +71,7 @@ idle serving slot routes to no expert and its recurrent state is left
 alone), and ``counters``, a dict the block adds this call's int32
 scalars into (``moe_picks``, ``moe_picks_held``,
 ``moe_experts_touched``, ``moe_load_max``, ``moe_layer_steps``,
-``ssm_state_rows``). A training step hands them back with its
+``ssm_state_rows``, ``eva_summaries_written``). A training step hands them back with its
 gradient-health scalars (``MultiLayerNetwork._step_body``).
 """
 
@@ -78,7 +89,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
     BaseRecurrentLayer,
 )
 from deeplearning4j_tpu.nn.conf.serde import register_bean
-from deeplearning4j_tpu.nn.layers import mamba2
+from deeplearning4j_tpu.nn.layers import eva, mamba2
 from deeplearning4j_tpu.nn.layers.attention import AttentionImpl
 from deeplearning4j_tpu.nn.layers.base import LayerImplBase
 from deeplearning4j_tpu.nn.layers.moe import (
@@ -89,16 +100,17 @@ from deeplearning4j_tpu.nn.layers.moe import (
 from deeplearning4j_tpu.ops.losses import label_cross_entropy
 from deeplearning4j_tpu.profiler.scopes import scope
 
-MIXERS = ("attention", "mamba2", "short_conv")
+MIXERS = ("attention", "mamba2", "short_conv", "eva")
 
 
 @scope("norm")
-def rms_norm(x, w, eps: float):
+def rms_norm(x, w, eps: float, unit_offset: bool = False):
     """``x / sqrt(mean(x^2) + eps) * w`` over the last axis, the mean
-    square in float32."""
+    square in float32; ``unit_offset``: the gain is ``1 + w``."""
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * w.astype(jnp.float32)).astype(x.dtype)
+    gain = w.astype(jnp.float32)
+    return (y * (1.0 + gain if unit_offset else gain)).astype(x.dtype)
 
 
 def _residual(x, branch, multiplier: float):
@@ -146,6 +158,13 @@ def short_conv_mixer(params, hn, state, mask):
         return y @ params["W_out"], {"conv": tail}
 
 
+def _heads(hn, w, n_heads: int, d_head: int):
+    """``hn @ w`` ``[N, T, H x dh]`` split into heads ``[N, H, T, dh]``."""
+    n, t, _ = hn.shape
+    return jnp.transpose((hn @ w).reshape(n, t, n_heads, d_head),
+                         (0, 2, 1, 3))
+
+
 @scope("attn/rope")
 def rope(q, k, start, theta: float):
     """Rotary positions (rotate-half) on ``q``/``k`` ``[N, H, T, dh]``
@@ -190,6 +209,13 @@ class TiedLMHead(BaseOutputLayer):
     init_std: float = 0.02
     logits_scaling: float = 1.0
     rms_eps: float = 1e-5
+    #: the norm's gain is ``1 + w`` (``norm_add_unit_offset``)
+    norm_unit_offset: bool = False
+    #: an untied head of ``n_pred_heads x n_out`` rows, head ``p``
+    #: scoring the token ``p + 1`` positions on (a multi-byte
+    #: predictor's). ``logits`` and ``apply`` are head 0's, the next
+    #: token's; :meth:`TiedLMHeadImpl.all_logits` has every head's
+    n_pred_heads: int = 1
 
     #: read off the bean by ``MultiLayerNetwork``, as ``takes_token_ids``
     takes_label_ids = True
@@ -201,23 +227,37 @@ class TiedLMHeadImpl(LayerImplBase):
     @classmethod
     def init(cls, key, conf, dtype=jnp.float32) -> dict:
         lc = conf.layer
-        params = {"norm_w": jnp.ones((lc.n_in,), dtype)}
+        params = {"norm_w": (jnp.zeros if lc.norm_unit_offset
+                             else jnp.ones)((lc.n_in,), dtype)}
+        if lc.n_pred_heads > 1 and lc.tie_to is not None:
+            raise ValueError("n_pred_heads > 1 needs an untied head "
+                             "(tie_to=None)")
         if lc.tie_to is None:
-            params["E"] = _normal(key, (lc.n_out, lc.n_in), lc.init_std,
-                                  dtype)
+            params["E"] = _normal(
+                key, (lc.n_pred_heads * lc.n_out, lc.n_in), lc.init_std,
+                dtype)
         return params
 
     @classmethod
-    def logits(cls, conf, params, x):
+    def logits(cls, conf, params, x, every_head: bool = False):
         lc = conf.layer
         hn = rms_norm(jnp.transpose(x, (0, 2, 1)), params["norm_w"],
-                      lc.rms_eps)
+                      lc.rms_eps, lc.norm_unit_offset)
         with scope("head/logits"):
             e = params["E"]
+            if lc.n_pred_heads > 1 and not every_head:
+                e = e[:lc.n_out]        # head 0: the next token's rows
             z = jax.lax.dot_general(
                 hn.astype(e.dtype), e, (((2,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)          # [N, T, V]
             return z / lc.logits_scaling
+
+    @classmethod
+    def all_logits(cls, conf, params, x):
+        """Every prediction head's float32 logits ``[N, T, P, V]``."""
+        z = cls.logits(conf, params, x, every_head=True)
+        return z.reshape(*z.shape[:2], conf.layer.n_pred_heads,
+                         conf.layer.n_out)
 
     @classmethod
     def apply(cls, conf, params, x, state=None, train=False, rng=None,
@@ -241,8 +281,9 @@ class TiedLMHeadImpl(LayerImplBase):
 @dataclasses.dataclass
 class HybridMoeBlock(BaseRecurrentLayer):
     """Conf bean: one pre-RMSNorm residual block of width ``n_in ==
-    n_out``: ``mixer`` ("attention", "mamba2" or "short_conv", the
-    gated short convolution of width ``conv_kernel``), then dropless top-k
+    n_out``: ``mixer`` ("attention", "mamba2", "short_conv", the
+    gated short convolution of width ``conv_kernel``, or "eva", EVA
+    attention), then dropless top-k
     routing over ``n_router`` outputs of which this chip holds the
     experts ``experts_held = [lo, hi)`` (None = all), plus a shared
     expert of width ``d_shared`` (0 = none). The gates follow
@@ -272,6 +313,19 @@ class HybridMoeBlock(BaseRecurrentLayer):
     qk_norm: bool = False
     gated_attention: bool = False
     post_norms: bool = False
+    # eva mixer (nn/layers/eva.py): ``n_heads`` heads of ``d_head``,
+    # rotary positions by ``rope_theta``; exact keys inside an aligned
+    # window of ``eva_window`` tokens, one learned summary a chunk of
+    # ``eva_chunk`` tokens of every earlier window. ``stream_max_t`` is
+    # the longest context (what the summaries' cache has room for)
+    eva_window: int = 2048
+    eva_chunk: int = 16
+    #: every RMSNorm's gain is ``1 + w`` (``norm_add_unit_offset``)
+    norm_unit_offset: bool = False
+    #: the residual stream stays float32 between the blocks under a
+    #: narrower compute dtype (``fp32_skip_add``): the block hands on
+    #: float32 and rounds only what its products read
+    fp32_residual: bool = False
     # mamba2 mixer
     ssm_heads: int = 8
     ssm_d_head: int = 16
@@ -317,7 +371,12 @@ class HybridMoeBlock(BaseRecurrentLayer):
         """``"kv"``: an attention cache, paged by the engine;
         ``"slot"``: one row a slot, carried whole (the Mamba-2 mixer's
         convolution tail and SSM state, the short convolution's
-        tail)."""
+        tail); ``"eva"``: TWO paged caches in one layer, the window's
+        keys (``eva_window``, released whole at its aligned end) and one
+        entry an ``eva_chunk`` of every token up to ``stream_max_t``
+        (never released while the row lives)."""
+        if self.mixer == "eva":
+            return "eva"
         return "kv" if self.mixer == "attention" else "slot"
 
     @property
@@ -360,6 +419,11 @@ class HybridMoeBlockImpl(LayerImplBase):
                                       lc.ssm_d_conv)
         elif lc.mixer == "short_conv":
             mix = short_conv_shapes(d, lc.conv_kernel)
+        elif lc.mixer == "eva":
+            dh = lc.head_dim
+            mix = {"Wq": (d, lc.n_heads * dh), "Wk": (d, lc.n_heads * dh),
+                   "Wv": (d, lc.n_heads * dh), "Wo": (lc.n_heads * dh, d),
+                   "eva_mu": (lc.n_heads, dh), "eva_phi": (lc.n_heads, dh)}
         else:
             raise ValueError(
                 f"mixer {lc.mixer!r}: expected one of {MIXERS}")
@@ -391,7 +455,8 @@ class HybridMoeBlockImpl(LayerImplBase):
             k = jax.random.fold_in(key, j)
             if name in ("norm1_w", "norm2_w", "norm_w", "D", "post1_w",
                         "post2_w", "q_norm_w", "k_norm_w"):
-                params[name] = jnp.ones(shape, dtype)
+                params[name] = (jnp.zeros if lc.norm_unit_offset
+                                else jnp.ones)(shape, dtype)
             elif name == "A_log":
                 params[name] = jnp.log(jax.random.uniform(
                     k, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
@@ -410,14 +475,10 @@ class HybridMoeBlockImpl(LayerImplBase):
         n, t, _ = hn.shape
         dh = lc.head_dim
 
-        def heads(w, h):
-            y = hn @ w
-            return jnp.transpose(y.reshape(n, t, h, dh), (0, 2, 1, 3))
-
         with scope("attn/qkv"):
-            q = heads(params["Wq"], lc.n_heads)
-            k = heads(params["Wk"], lc.n_kv_heads)
-            v = heads(params["Wv"], lc.n_kv_heads)
+            q = _heads(hn, params["Wq"], lc.n_heads, dh)
+            k = _heads(hn, params["Wk"], lc.n_kv_heads, dh)
+            v = _heads(hn, params["Wv"], lc.n_kv_heads, dh)
         if lc.qk_norm:
             q = rms_norm(q, params["q_norm_w"], lc.rms_eps)
             k = rms_norm(k, params["k_norm_w"], lc.rms_eps)
@@ -456,15 +517,63 @@ class HybridMoeBlockImpl(LayerImplBase):
             return o @ params["Wo"], state
 
     @classmethod
+    def _eva(cls, lc, params, hn, state, train, mask):
+        """The EVA mixer (nn/layers/eva.py) on ``hn`` ``[N, T, D]``:
+        ``(out, new state, summaries written or None)``. The state it
+        is handed says which program runs: none, the net's own
+        streaming state, or the engine's two pools."""
+        n, t, _ = hn.shape
+        dh = lc.head_dim
+        if lc.eva_window % lc.eva_chunk:
+            raise ValueError(
+                f"eva_chunk {lc.eva_chunk} does not divide eva_window "
+                f"{lc.eva_window}")
+        with scope("eva/qkv"):
+            q, k, v = (_heads(hn, params[w], lc.n_heads, dh)
+                       for w in ("Wq", "Wk", "Wv"))
+            paged = state is not None and "pk" in state
+            start = (jnp.zeros((n,), jnp.int32) if state is None
+                     else state["filled" if paged else "pos"])
+        if lc.rope_theta:
+            q, k = rope(q, k, start, lc.rope_theta)
+        sizes = dict(window=lc.eva_window, chunk=lc.eva_chunk, mask=mask)
+        mu, phi = params["eva_mu"], params["eva_phi"]
+        written = None
+        if state is None:
+            o, state = eva.full(q, k, v, mu, phi, **sizes,
+                                capacity=lc.stream_max_t,
+                                want_state=not train)
+        elif paged:
+            o, state, written = eva.paged(q, k, v, state, mu, phi, **sizes,
+                                          toggle=lc.use_flash_paged)
+        else:
+            o, state = eva.stream(q, k, v, state, mu, phi, **sizes)
+        with scope("eva/out"):
+            o = jnp.transpose(o, (0, 2, 1, 3)).reshape(
+                n, t, lc.n_heads * dh)
+            return o @ params["Wo"], state, written
+
+    @classmethod
     def apply(cls, conf, params, x, state=None, train=False, rng=None,
               mask=None, live=None, counters=None):
         lc = conf.layer
         with scope("norm"):     # the entry re-layout, with what reads it
             xt = jnp.transpose(x, (0, 2, 1))                 # [N, T, D]
+            if lc.fp32_residual:
+                xt = xt.astype(jnp.float32)
         n, t, d = xt.shape
-        hn = rms_norm(xt, params["norm1_w"], lc.rms_eps)
+        # (under ``fp32_residual`` the products read the stream rounded
+        # to the weights' dtype)
+        wide = params["norm1_w"].dtype
+        hn = rms_norm(xt, params["norm1_w"], lc.rms_eps,
+                      lc.norm_unit_offset).astype(wide)
         counts = {}
-        if lc.mixer == "attention":
+        if lc.mixer == "eva":
+            mixed, new_state, written = cls._eva(lc, params, hn, state,
+                                                 train, mask)
+            if written is not None:
+                counts["eva_summaries_written"] = written
+        elif lc.mixer == "attention":
             if live is None and state is not None:
                 with scope("tables"):
                     live = state["filled"] > 0   # an idle slot: no cache
@@ -483,9 +592,11 @@ class HybridMoeBlockImpl(LayerImplBase):
                     jnp.asarray(n, jnp.int32) if live is None
                     else jnp.sum((live > 0).astype(jnp.int32)))
         if lc.post_norms:
-            mixed = rms_norm(mixed, params["post1_w"], lc.rms_eps)
+            mixed = rms_norm(mixed, params["post1_w"], lc.rms_eps,
+                             lc.norm_unit_offset)
         # a branch's residual sum is its group's last operation
         with scope("attn/out" if lc.mixer == "attention"
+                   else "eva/out" if lc.mixer == "eva"
                    else "mixer/proj"):
             xt = _residual(xt, mixed, lc.residual_multiplier)
 
@@ -496,7 +607,8 @@ class HybridMoeBlockImpl(LayerImplBase):
             if live is not None:
                 rows = jnp.broadcast_to((live > 0)[:, None], (n, t))
                 valid = rows if valid is None else valid & rows
-        h2 = rms_norm(xt, params["norm2_w"], lc.rms_eps)
+        h2 = rms_norm(xt, params["norm2_w"], lc.rms_eps,
+                      lc.norm_unit_offset).astype(wide)
         if lc.n_router:
             y, moe_counts = dropless_moe(
                 params, h2.reshape(n * t, d),
@@ -512,7 +624,8 @@ class HybridMoeBlockImpl(LayerImplBase):
                 y = gated_ffn(h2, params["Ws_in"], params["Ws_out"])
         y = y.reshape(n, t, d)
         if lc.post_norms:
-            y = rms_norm(y, params["post2_w"], lc.rms_eps)
+            y = rms_norm(y, params["post2_w"], lc.rms_eps,
+                         lc.norm_unit_offset)
         with scope("moe/combine" if lc.n_router else "ffn"):
             xt = _residual(xt, y, lc.residual_multiplier)
             if counters is not None:

@@ -30,6 +30,12 @@ after the phase, so a norm inside ``attn/qkv`` is attention's.
 - ``mixer`` (``proj``, ``conv``, ``ssm``): the in/out projections and
   their split; the causal / short convolution; the chunked scan and the
   one-step update.
+- ``eva`` (``qkv``, ``window``, ``summaries``, ``write``, ``out``): the
+  EVA mixer (nn/layers/eva.py): its projections; the exact keys of the
+  aligned window (the paged kernel's causal call, the gather, the
+  tile); the summaries' walk and the merge of the two into one
+  softmax; the chunk's keys into the pool and a completed chunk pooled
+  to its summary entry; ``Wo`` and the residual.
 - ``head`` (``logits``, ``loss``, ``sample``): the output layer; the
   score; ``sample_tokens``.
 - ``cast``: masters to the compute dtype, activations' casts.
@@ -64,6 +70,7 @@ GROUPS = {
     "ffn": (),
     "moe": ("route", "sort", "experts", "combine", "shared"),
     "mixer": ("proj", "conv", "ssm"),
+    "eva": ("qkv", "window", "summaries", "write", "out"),
     "head": ("logits", "loss", "sample"),
     "cast": (),
     "update": ("step", "health"),

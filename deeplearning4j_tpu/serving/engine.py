@@ -554,6 +554,11 @@ def _lm_shape_of(net):
     return forward, vocab, beans
 
 
+#: what a paged layer's state holds of the pools (an ``eva`` layer all
+#: four); the rest of it is the dispatch's table operands
+_POOL_LEAVES = ("pk", "pv", "sk", "sv")
+
+
 def _unpack_tables(tabs, rings=None):
     """The four block-table operands of a paged dispatch
     (``AttentionImpl._paged_attend`` says what each holds), a dict a
@@ -595,6 +600,18 @@ class _KvKind:
     ring: int = 0
     slot_worst: int = 0
     pool: Optional[BlockPool] = None
+    #: tokens one block of the kind covers: ``block_tokens``, or for a
+    #: kind that holds one ENTRY a chunk of tokens (an ``eva`` layer's
+    #: summaries) ``block_tokens`` chunks
+    span: int = 0
+    #: the window is ALIGNED (an ``eva`` layer's exact keys): the row
+    #: reads from the last multiple of ``window`` up, and every block
+    #: below it is released when the row has crossed it
+    aligned: bool = False
+    #: the pool leaves of the kind's layers and the names its table
+    #: operands take in a layer's state (a layer of two kinds holds both)
+    leaves: Tuple[str, str] = ("pk", "pv")
+    operands: Tuple[str, str] = ("table", "base")
 
 
 class DecodeEngine:
@@ -868,6 +885,9 @@ class DecodeEngine:
         self._wants_live = any(getattr(bean, "wants_live", False)
                                for _, bean in beans)
         attn_items = []
+        #: layers that hold TWO paged caches (``serving_state`` "eva":
+        #: the aligned window's keys and one summary entry a chunk)
+        eva_items = []
         for name, bean in beans:
             # carried-state recurrents only: RnnOutputLayer is
             # recurrent-typed but stateless, so it streams fine
@@ -879,6 +899,10 @@ class DecodeEngine:
             kind = getattr(bean, "serving_state", None)
             if kind == "slot":
                 self._state_layers.append(name)
+                continue
+            if kind == "eva":
+                eva_items.append((name, bean))
+                windows.append(bean.stream_max_t)
                 continue
             if kind != "kv":
                 raise ValueError(
@@ -908,7 +932,10 @@ class DecodeEngine:
                                         or bean.n_out // bean.n_heads))
 
         self._kinds: List[_KvKind] = [
-            kind_of(w) for w in sorted(set(windows), reverse=True)]
+            kind_of(w) for w in sorted(set(windows), reverse=True)
+        ] if not eva_items else self._eva_kinds(eva_items, attn_items,
+                                                block_tokens)
+        self._eva = bool(eva_items)
         # the longest prompt: the narrowest window where a cold
         # admission prefills a dense row (it takes no band); the widest
         # where several kinds make every admission a paged one, whose
@@ -966,6 +993,26 @@ class DecodeEngine:
             refused.append(
                 ("tp", tp if tp > 1 else 0,
                  "experts and grouped KV heads are not sharded over tp"))
+        if eva_items:
+            bean = eva_items[0][1]
+            w, c = bean.eva_window, bean.eva_chunk
+            # a chunk is pooled from ONE pool block, and every query of
+            # a dispatch reads from one aligned floor
+            for option, value, bad, why in (
+                    ("block_tokens", block_tokens,
+                     block_tokens % c != 0,
+                     f"eva_chunk {c} does not divide it: a completed "
+                     "chunk must lie inside one pool block"),
+                    ("prefill_chunk", prefill_chunk,
+                     prefill_chunk < 1 or w % prefill_chunk != 0,
+                     f"an admission goes through the pools in chunks "
+                     f"that divide eva_window {w}, so that a chunk "
+                     "never straddles a window's end")):
+                if bad:
+                    raise ValueError(
+                        f"{option}={value!r} is not supported for this "
+                        f"net (layers {[n for n, _ in eva_items]}): "
+                        f"{why}")
         for option, value, why in refused:
             if value:
                 layers = (self._state_layers or unsharded
@@ -996,7 +1043,7 @@ class DecodeEngine:
         #: beans — the engine owns its net in serving deployments.
         self.use_flash_paged = use_flash_paged
         if use_flash_paged is not None:
-            for _, bean in attn_items:
+            for _, bean in attn_items + eva_items:
                 bean.use_flash_paged = use_flash_paged
         cast_bytes = self._adopt_weights()
         #: the weights every dispatch reads: the net's, resident at
@@ -1068,19 +1115,22 @@ class DecodeEngine:
         dispatch = (self.window if len(self._kinds) == 1
                     else self.prefill_chunk or self.window)
         for kind in self._kinds:
+            kind.span = span = kind.span or bt
             kind.ring = (
-                -(-kind.window // bt) + -(-dispatch // bt)
-                + -(-round_write // bt) + 3)
+                -(-kind.window // span) + -(-dispatch // span)
+                + -(-round_write // span) + 3)
             # one slot's worst-case residency: a full window of
             # blocks, one dispatch of appends, plus boundary slack
             # (the ring width is ADDRESSING span, not occupancy:
             # slid-out blocks free as they expire). A one-kind net's
             # prompts fit its window, so there the dispatch is one
-            # round of decode/verify writes
-            kind.slot_worst = (-(-kind.window // bt)
+            # round of decode/verify writes; so it is under an ALIGNED
+            # window, whose admission chunks never straddle its end and
+            # find the window before it released
+            kind.slot_worst = (-(-kind.window // span)
                                + -(-(round_write if kind.window
-                                     >= self.window else
-                                     max(round_write, dispatch)) // bt)
+                                     >= self.window or kind.aligned else
+                                     max(round_write, dispatch)) // span)
                                + 3)
         self._ring_slots = self._kinds[0].ring
         slot_worst = sum(k.slot_worst for k in self._kinds)
@@ -1088,7 +1138,7 @@ class DecodeEngine:
             # default: a whole window for every slot and every trie
             # entry, with per-slot append slack, of every kind
             kv_blocks = max(
-                sum(-(-k.window // bt) for k in self._kinds)
+                sum(-(-k.window // k.span) for k in self._kinds)
                 * (self.n_slots + int(prefix_cache_rows))
                 + len(self._kinds) * self.n_slots
                 * (-(-round_write // bt) + 2),
@@ -1110,7 +1160,7 @@ class DecodeEngine:
             n = (left if i == len(self._kinds) - 1 else max(
                 kind.slot_worst,
                 self.kv_blocks * kind.slot_worst // slot_worst))
-            kind.pool = BlockPool(n, bt, jit_wrap=self._jit)
+            kind.pool = BlockPool(n, kind.span, jit_wrap=self._jit)
             left -= n
         cell = jnp.dtype(net._compute_dtype or net._dtype).itemsize
         self._jit_options = _compiler_options(min(
@@ -1315,7 +1365,26 @@ class DecodeEngine:
             **{prefix + name: 0 for prefix in ("", "prefill_")
                for name in ("moe_picks", "moe_picks_held",
                             "moe_experts_touched", "moe_layer_steps",
-                            "moe_load_max", "ssm_state_rows")},
+                            "moe_load_max", "ssm_state_rows",
+                            # an ``eva`` layer's (ISSUE 41): summary
+                            # entries the programs wrote (layers x
+                            # chunks completed), and what ONE layer's
+                            # attention reads for a dispatch's tables,
+                            # a decode dispatch step by step: exact
+                            # keys of the aligned window, and summaries
+                            "eva_summaries_written",
+                            "eva_window_entries_read",
+                            "eva_summary_entries_read",
+                            # ... and the (query, entry) pairs it
+                            # scores: a step's one query an entry, an
+                            # admission chunk's every query that may
+                            # read it
+                            "eva_window_pairs_scored",
+                            "eva_summary_pairs_scored")},
+            # blocks of the aligned window's kind allocated, and those
+            # released because their row crossed the window's end
+            "eva_window_blocks_allocated": 0,
+            "eva_window_blocks_released": 0,
             # KV transfer plane (ISSUE 14): cross-replica prefix
             # shipping counters (nonzero only when export/import run)
             "kv_exports": 0, "kv_exported_tokens": 0,
@@ -1333,6 +1402,38 @@ class DecodeEngine:
         for key in self.FAILURE_KEYS:
             self.stats[key] = 0
         self._build_jits()
+
+    @staticmethod
+    def _eva_kinds(eva_items, attn_items, block_tokens: int
+                   ) -> List[_KvKind]:
+        """The two kinds of block an ``eva`` layer holds, widest first:
+        its summaries (one entry an ``eva_chunk`` of tokens, so a block
+        covers ``block_tokens`` chunks; "window" the longest context,
+        ``stream_max_t``: never released while the row lives) and its
+        window's exact keys (aligned). Every ``eva`` layer of a net has
+        both, under one table each a slot."""
+        names = [name for name, _ in eva_items]
+        bean = eva_items[0][1]
+        sizes = {(b.eva_window, b.eva_chunk, b.stream_max_t, b.n_heads,
+                  b.head_dim) for _, b in eva_items}
+        if attn_items or len(sizes) > 1:
+            raise ValueError(
+                f"layers {names} hold a window's keys and chunk "
+                "summaries (serving_state 'eva'); the engine serves "
+                "them where every paged layer is one of them and all "
+                f"agree on their sizes (got {sorted(sizes)}, beside "
+                f"attention layers {[n for n, _ in attn_items]})")
+        if bean.stream_max_t <= bean.eva_window:
+            raise ValueError(
+                f"stream_max_t {bean.stream_max_t} (the longest "
+                f"context) must pass eva_window {bean.eva_window}")
+        width = bean.n_heads * bean.head_dim
+        return [
+            _KvKind(bean.stream_max_t, list(names), token_width=width,
+                    span=int(block_tokens) * bean.eva_chunk,
+                    leaves=("sk", "sv"), operands=("stable", "sbase")),
+            _KvKind(bean.eva_window, list(names), token_width=width,
+                    aligned=True)]
 
     def _adopt_weights(self) -> int:
         """Make the net's weights resident at its compute dtype, once:
@@ -1413,7 +1514,13 @@ class DecodeEngine:
             for kind, ops in zip(self._kinds, unpacked):
                 if filled is not None:
                     ops["filled"] = filled
-                shared.update(dict.fromkeys(kind.layers, ops))
+                if kind.operands != ("table", "base"):
+                    # a second kind of the same layers: its table rides
+                    # beside the first's, under its own names
+                    ops = dict(zip(kind.operands,
+                                   (ops["table"], ops["base"])))
+                for name in kind.layers:
+                    shared.setdefault(name, {}).update(ops)
             return {name: dict(st, **shared[name]) if "pk" in st else st
                     for name, st in pool.items()}
 
@@ -1425,7 +1532,8 @@ class DecodeEngine:
             # program hands a layer's tables back
             filled = next(st["filled"] for st in rnn.values()
                           if "pk" in st)
-            return {name: ({"pk": st["pk"], "pv": st["pv"]}
+            return {name: ({leaf: st[leaf] for leaf in _POOL_LEAVES
+                            if leaf in st}
                            if "pk" in st else st)
                     for name, st in rnn.items()}, filled
 
@@ -2239,6 +2347,8 @@ class DecodeEngine:
             if bid is None:
                 raise AssertionError("reserved allocation failed")
             tab.blocks[g] = bid
+            if kind.aligned:
+                self.stats["eva_window_blocks_allocated"] += 1
         return True
 
     def _free_expired_blocks(self, tab: KindTables) -> None:
@@ -2247,13 +2357,19 @@ class DecodeEngine:
         within a round — the verify rewind lands before this runs — so
         a released block can never swing back into reach)."""
         for kind, t in zip(self._kinds, tab.kinds):
-            edge = t.length - kind.window
-            if edge < self.block_tokens:
+            # (an ALIGNED window's lower edge is the last multiple of
+            # the window the row has reached: everything below it goes
+            # at once, the round after the row crossed it)
+            edge = (t.length // kind.window * kind.window if kind.aligned
+                    else t.length - kind.window)
+            if edge < kind.span:
                 continue    # the context has not left the window yet
             for g in itertools.takewhile(
-                    lambda g: (g + 1) * self.block_tokens <= edge,
+                    lambda g: (g + 1) * kind.span <= edge,
                     sorted(t.blocks)):
                 self._release_block(t.blocks.pop(g), kind)
+                if kind.aligned:
+                    self.stats["eva_window_blocks_released"] += 1
 
     def _count_kv_held(self, active: List[int]) -> None:
         """By kind, summed over rounds as ``occupancy_sum`` is:
@@ -2262,12 +2378,11 @@ class DecodeEngine:
         slots' tables still map (blocks reserved ahead of the context
         are neither). The difference is what the kind's window
         released."""
-        bt = self.block_tokens
         for k, kind in enumerate(self._kinds):
             spanned = held = 0
             for slot in active:
                 t = self._kv_tabs[slot].kinds[k]
-                span = -(-t.length // bt)
+                span = -(-t.length // kind.span)
                 ahead = span
                 while ahead in t.blocks:
                     ahead += 1
@@ -2345,7 +2460,8 @@ class DecodeEngine:
             if prefill:
                 self.stats["prefill_" + name] += int(v)
 
-    def _paged_tables(self, tabs, chunk: int = 1):
+    def _paged_tables(self, tabs, chunk: int = 1,
+                      tokens: Optional[int] = None):
         """The block-table operand of a paged dispatch of ``chunk``
         query positions a row: each row's ring-projected block table,
         its floor and its length (None rows — idle slots — map
@@ -2372,7 +2488,10 @@ class DecodeEngine:
                 rows["table"][i], rows["base"][i] = t.arrays(kind.ring)
                 rows["floor"][i] = t.floor
                 rows["filled"][i] = t.length
-            self._count_paged_walk(kind, chunk=chunk, **rows)
+            if not self._eva:
+                self._count_paged_walk(kind, chunk=chunk, **rows)
+        if self._eva:
+            self._count_eva_reads(tabs, chunk, tokens)
         self.stats["table_uploads"] += 1
         if self.tp_ctx is not None:
             return self.tp_ctx.replicate(packed)
@@ -2415,6 +2534,48 @@ class DecodeEngine:
         self.stats[name] += live
         if chunk > 1:
             self.stats["prefill_" + name] += live
+
+    def _count_eva_reads(self, tabs, chunk: int,
+                         tokens: Optional[int] = None) -> None:
+        """What ONE ``eva`` layer's attention does for a dispatch's
+        tables: ``eva_window_entries_read``, the exact keys from each
+        row's aligned floor up, ``eva_summary_entries_read``, the
+        summaries of the windows before it, and the (query, entry)
+        pairs it scores of each (``eva_*_pairs_scored``). A decode
+        dispatch (``chunk`` 1) is ``decode_chunk`` steps, each a
+        position on, a query a row. An admission's chunk of ``tokens``
+        queries reads each entry once (what its last query sees) and
+        scores every pair under the causal edge; it counts under
+        ``prefill_`` as well."""
+        summary, window = self._kinds
+        w, per = window.window, window.window // (
+            summary.span // self.block_tokens)
+        counts = dict.fromkeys(("window_entries_read",
+                                "summary_entries_read",
+                                "window_pairs_scored",
+                                "summary_pairs_scored"), 0)
+        for tab in tabs:
+            if tab is None:
+                continue
+            if chunk > 1:   # (a chunk never straddles a window's end)
+                n = chunk if tokens is None else tokens
+                at, seen = tab.length % w, tab.length // w * per
+                counts["window_entries_read"] += at + n
+                counts["summary_entries_read"] += seen
+                counts["window_pairs_scored"] += n * at + n * (n + 1) // 2
+                counts["summary_pairs_scored"] += n * seen
+                continue
+            for j in range(self.decode_chunk):   # a position on a step
+                exact = (tab.length + j) % w + 1
+                seen = (tab.length + j) // w * per
+                counts["window_entries_read"] += exact
+                counts["summary_entries_read"] += seen
+                counts["window_pairs_scored"] += exact
+                counts["summary_pairs_scored"] += seen
+        for name, n in counts.items():
+            self.stats["eva_" + name] += n
+            if chunk > 1:
+                self.stats["prefill_eva_" + name] += n
 
     def _strip_pool(self, rnn):
         """What a program hands back (pool leaves and, for a net
@@ -2717,8 +2878,8 @@ class DecodeEngine:
             # every layer's keys for the whole prompt, and take no
             # band), each chunk's programs banding a layer by its window
             self._ensure_paged_pool()
-            tab = KindTables(BlockTable(self.block_tokens)
-                             for _ in self._kinds)
+            tab = KindTables(BlockTable(kind.span)
+                             for kind in self._kinds)
         pending = _Pending(request, slot, None, None, 0, matched, hit,
                            tab=tab)
         if self.prefill_chunk:
@@ -2780,7 +2941,8 @@ class DecodeEngine:
                                     rid=req.id):
                 return False
             carried = self._pool
-            tables = self._paged_tables([pending.tab], chunk=width)
+            tables = self._paged_tables([pending.tab], chunk=width,
+                                        tokens=len(seg))
         else:
             carried, tables = pending.rnn, None
         t0 = self._clock()
@@ -2845,9 +3007,6 @@ class DecodeEngine:
             _, rnn1, _ = jax.eval_shape(
                 self._prefill_jit, self._params, self._state, x, mask,
                 one, one.astype(jnp.int32), self._key)
-        blocks = {name: kind.pool.n_blocks for kind in self._kinds
-                  for name in kind.layers}
-
         computed = self.net._compute_dtype
 
         def held(a):
@@ -2855,9 +3014,16 @@ class DecodeEngine:
 
         def make(name, st):
             k = st["k"]                          # [1, H, W, dh]
-            shape = (blocks[name], bt, k.shape[1], k.shape[3])
-            return {"pk": jnp.zeros(shape, held(k)),
-                    "pv": jnp.zeros(shape, held(st["v"]))}
+            out = {}
+            # (a layer of two kinds holds a pair of leaves of each)
+            for kind in self._kinds:
+                if name in kind.layers:
+                    shape = (kind.pool.n_blocks, bt, k.shape[1],
+                             k.shape[3])
+                    out.update({kind.leaves[0]: jnp.zeros(shape, held(k)),
+                                kind.leaves[1]: jnp.zeros(
+                                    shape, held(st["v"]))})
+            return out
 
         kv, row = self._split_row(rnn1)
         self._pool = self._place(

@@ -17,7 +17,9 @@ served cell): ``decode`` and ``chunk``, the engine's two; ``cgpt_block``:
 ``decode`` (the served cell: the pool at the dtype the engine makes it,
 ``--pool-dtype`` for another) or ``step`` (the trained cell);
 ``granite_hybrid`` (a served cell): ``decode`` and ``prefill``, the cold
-prefill at one prompt bucket (``--batch N`` for a bucket of N tokens).
+prefill at one prompt bucket (``--batch N`` for a bucket of N tokens);
+``evabyte`` (a served cell): ``decode`` and ``chunk``, over its layers'
+two pools each.
 
 ``--hash`` lowers only and prints the SHA-256 of each program's text,
 the Pallas kernels' serialized bodies left out (two trees that print
@@ -105,6 +107,10 @@ def report(name, lowered):
     c = lowered.compile()
     m = c.memory_analysis()
     txt = c.as_text()
+    if os.environ.get("COMPILE_CELL_DUMP"):     # the compiled text, kept
+        with open(os.path.join(os.environ["COMPILE_CELL_DUMP"],
+                               name.replace(" ", "_") + ".hlo"), "w") as f:
+            f.write(txt)
     sizes = [x / 2**30 for x in (
         m.argument_size_in_bytes, m.output_size_in_bytes,
         m.temp_size_in_bytes, m.alias_size_in_bytes)]
@@ -149,6 +155,57 @@ def afmoe(cfg, mix, model, which, batch):
                cfg["num_key_value_heads"], cfg["head_dim"])
         for name in k.layers:
             pool[name] = {"pk": S(shp, dt), "pv": S(shp, dt)}
+    print("pool GiB", sum(int(np.prod(l.shape)) * 2
+                          for l in jax.tree.leaves(pool)) / 2**30)
+    rings = [k.ring for k in eng._kinds]
+    width = 2 * sum(rings) + len(rings) + 1
+    B, c = eng.n_slots, eng.prefill_chunk
+    which = which or ["decode", "chunk"]
+    if "decode" in which:
+        report("decode", eng._decode_jit.lower(
+            eng._params, eng._state, pool, S((B, width), "int32"),
+            S((B,), "int32"), S((B,), "float32"), S((B,), "int32"),
+            key_struct(), S((B,), "int32")))
+    if "chunk" in which:
+        report("chunk_prefill", eng._chunk_jit.lower(
+            eng._params, eng._state, S((1, c), "int32"),
+            S((1, c), "float32"), pool, S((1, width), "int32"),
+            S((1,), "float32"), S((1,), "int32"), key_struct()))
+
+
+def evabyte(cfg, mix, model, which, batch):
+    from deeplearning4j_tpu.serving import DecodeEngine
+
+    dt, d = cfg["dtype"], cfg["hidden_size"]
+    W = model.weights
+
+    def struct_params(seed, cfg):
+        n = cfg["num_hidden_layers"]
+        v = cfg["vocab_size"]
+        p = {"0": {"W": S((v, d), dt)},
+             str(n + 1): {"norm_w": S((d,), dt),
+                          "E": S((cfg["num_pred_heads"] * v, d), dt)}}
+        for i in range(n):
+            p[str(i + 1)] = {name: S(shape, dt) for name, shape
+                             in W.layer_shapes(cfg).items()}
+        return p
+
+    W.make_params = struct_params
+    net = model.build_net(cfg, 1)
+    n_par = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(net.params))
+    print("parameters", n_par, "GiB at 2 B", n_par * 2 / 2**30)
+    dep = dict(cfg["deployment"])
+    dep.pop("why")
+    eng = DecodeEngine(net, seed=1, **dep)
+    print("kinds", [(k.window, k.span, k.leaves, k.ring, k.slot_worst,
+                     k.pool.n_blocks) for k in eng._kinds])
+    pool = {}
+    for k in eng._kinds:
+        shp = (k.pool.n_blocks, eng.block_tokens,
+               cfg["num_attention_heads"], W.head_dim(cfg))
+        for name in k.layers:
+            pool.setdefault(name, {}).update(
+                {leaf: S(shp, dt) for leaf in k.leaves})
     print("pool GiB", sum(int(np.prod(l.shape)) * 2
                           for l in jax.tree.leaves(pool)) / 2**30)
     rings = [k.ring for k in eng._kinds]
@@ -249,7 +306,7 @@ def granite_hybrid(cfg, mix, model, which, batch):
 
 
 MODELS = {"lfm2_moe": lfm2_moe, "afmoe": afmoe, "cgpt_block": cgpt_block,
-          "granite_hybrid": granite_hybrid}
+          "granite_hybrid": granite_hybrid, "evabyte": evabyte}
 
 
 def main() -> int:

@@ -115,6 +115,27 @@ def test_grouped_paged_kernel_compiles_for_v5e(one_chip, b, hq, hk, t, tm,
     assert "tpu_custom_call" in text
 
 
+# EVA's two calls a layer (PR 41) at the evabyte cell's geometry (32
+# ungrouped heads of 128, bf16 pools of 16-entry blocks, 8 slots): the
+# aligned window's causal walk (196 ring entries) and the summaries'
+# walk without a causal edge (72), each handing back its sums, for a
+# decode step and for a 1,024-token admission chunk (eight query tiles)
+@pytest.mark.parametrize("b,t", [(8, 1), (1, 1024)])
+@pytest.mark.parametrize("causal,tm,nb,ntab", [
+    (True, 2048, 1056, 130), (False, 72 * 16, 544, 72)])
+def test_eva_forms_of_the_paged_kernel_compile_for_v5e(
+        one_chip, b, t, causal, tm, nb, ntab):
+    if causal and t > 1:
+        ntab = 194       # a window and a chunk of entries
+    avals = _paged_avals(b, 32, t, 128, nb, 16, ntab, jnp.bfloat16,
+                         jnp.bfloat16)
+    text = _compile_for(
+        one_chip, lambda *a: _paged_flash_attention(
+            *a, tm=tm, causal=causal, stats=True), *avals)
+    assert "tpu_custom_call" in text
+    assert "f32[%d,32,%d,128]" % (b, t) in text.replace(" ", "")
+
+
 def _compile_for(one_chip, fn, *avals):
     """``fn`` compiled for the described chip; its HLO text. A described
     device's executable cannot be read back from the persistent cache:
