@@ -23,8 +23,9 @@ weights from a seed, data from ``datasets/markov.py``):
 2. **train** — the width-2048 x 8 flagship at B=16, T=512: one
    ``fit_scan`` window and a few ``fit`` steps on the Markov task, loss
    finite and lower at the end; then one ``fit`` step at T=2048, which
-   the attention layer routes to the stock pallas flash kernel (forward
-   and backward), its lowered program checked for the pallas call.
+   the attention layer routes to the library's block-sparse (splash)
+   attention kernel, forward and fused backward, its lowered program
+   checked for the pallas calls.
 
 Without flags it refuses to run unless jax's default backend is ``tpu``:
 there is no CPU fallback. ``--tiny`` is the rehearsal switch — small
@@ -444,9 +445,10 @@ def train_phase(size, log, on_tpu: bool) -> None:
     check(losses[-1] < losses[0],
           f"loss did not fall: {losses[0]:.4f} -> {losses[-1]:.4f}")
 
-    # one step at a length the attention layer routes to the stock
-    # flash kernel on a TPU (T >= 2048): forward + backward at head
-    # dim 128. Lower the step first to count its pallas calls.
+    # one step at a length the attention layer routes to the library's
+    # block-sparse (splash) kernel on a TPU (T >= 2048): forward + fused
+    # backward at head dim 128. Lower the step first to count its
+    # pallas calls.
     fb, ft = size["flash_batch"], size["flash_seq"]
     lf, ll, _ = markov_lm_batches(vocab, n_seq=fb, seq_len=ft,
                                   seed=0, sample_seed=2)
@@ -461,10 +463,11 @@ def train_phase(size, log, on_tpu: bool) -> None:
     t_flash = time.perf_counter() - t0
     check(np.isfinite(flash_loss), f"T={ft} step loss {flash_loss}")
     if on_tpu:
-        # three distinct kernels: forward, and backward's dq and dkv
-        # (the layers share one lowered function for each)
-        check(n_pallas >= 3,
-              f"T={ft} step holds {n_pallas} pallas calls, want >= 3")
+        # two distinct kernels: forward, and the backward that forms
+        # dq, dk and dv in one pass (the layers share one lowered
+        # function for each)
+        check(n_pallas >= 2,
+              f"T={ft} step holds {n_pallas} pallas calls, want >= 2")
         log(f"train: T={ft} step {t_flash:.1f}s, loss "
             f"{flash_loss:.4f}, {n_pallas} tpu_custom_call (flash "
             "kernel forward + backward: compiled and ran)")

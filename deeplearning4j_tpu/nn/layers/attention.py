@@ -243,23 +243,22 @@ class AttentionImpl(LayerImplBase):
                         f"sp_mode {lc.sp_mode!r}: expected 'ring' or "
                         "'ulysses'")
             return o, None
-        # grouped KV heads: the dense and flash programs take one key
-        # head a query head, so the group's keys are repeated for them;
-        # the cache below keeps the KV heads only
-        with scope("attn/core"):
-            ke, ve = _repeat_kv_heads(q, k, v)
         # a SLIDING layer (its bean says so) bands every program by its
         # window; the flash program takes no band, so a sequence past
         # the window stays on the plain one
         band = (lc.stream_max_t if getattr(lc, "sliding", False)
                 and q.shape[2] > lc.stream_max_t else None)
         if band is None and _should_use_flash(lc.use_flash, q, mask):
-            # bare: a scope here would rename the forward kernel's
-            # instruction under a gradient; a reader charges the
-            # library's entry by ``LIBRARY_SCOPES``
-            o = _flash_attention(q, ke, ve, lc.causal)
+            # bare: the library's program is charged to ``attn/core`` by
+            # its own entry (``LIBRARY_SCOPES``). Grouped KV heads go in
+            # as they are: the kernel runs a KV head over its group
+            o = _flash_attention(q, k, v, lc.causal)
         else:
+            # the dense program takes one key head a query head, so a
+            # group's keys are repeated for it; the cache below keeps
+            # the KV heads only
             with scope("attn/core"):
+                ke, ve = _repeat_kv_heads(q, k, v)
                 o = _dense_attention(q, ke, ve, lc.causal, mask, band)
         new_state = None
         if not train:
@@ -754,13 +753,12 @@ def guard_streamable(named_layer_beans) -> None:
 
 
 def _should_use_flash(use_flash, q, mask) -> bool:
-    """Training/prefill flash dispatch. The PAGED decode analogue is
-    :func:`_should_use_flash_paged` below — same toggle philosophy
-    (None = auto, False = XLA always, True = force the kernel), but
-    auto mode gates on BACKEND + tile health rather than sequence
-    length: a decode chunk is a handful of queries over ~window keys,
-    so the kernel's win is skipping the [B, ntab*bt, H, dh] gather
-    materialization (HBM bandwidth), not O(T²) score memory."""
+    """Whether an unmasked whole sequence takes the kernel
+    (:func:`_flash_attention`): ``use_flash`` None = this rule, False =
+    the dense program always, True = the kernel or an error. Static a
+    program, so a trace's kernel names say which ran. (The paged decode
+    analogue is :func:`_should_use_flash_paged`: it gates on the backend
+    and tile health, not on length.)"""
     if use_flash is False:
         return False
     t, dh = q.shape[2], q.shape[3]
@@ -773,55 +771,91 @@ def _should_use_flash(use_flash, q, mask) -> bool:
             "sequence length >= 256 divisible by 128, and head dim "
             "<= 128 or divisible by 128")
     if use_flash is None:
-        # Auto mode: flash is the LONG-context enabler — it removes the
-        # O(T²) score materialization that stops dense attention at
-        # ~16k+ tokens. With the tuned 1024-element block sizes (the
-        # kernel defaults were pathological — see _flash_attention) it
-        # reaches speed parity by T~512-1024 and wins ~2x at T=4096;
-        # keep a conservative 2048 threshold where the win is clear
-        # beyond dispatch noise and the memory savings start to matter.
-        # The t % 512 == 0 condition guarantees a healthy block size:
-        # a T like 2176 (=128*17) would degrade the kernel to
-        # 128-blocks — the pathological regime — where dense is faster.
-        # Above 8192 that tradeoff inverts: even degraded-block flash
-        # beats dense's O(T²) score materialization (4.3 GB at 8k,
-        # OOM by 32k), so memory safety overrides block health there.
+        # Auto mode: from the published training contexts up, where
+        # the kernel keeps the [T, T] scores out of HBM (what this chip
+        # measured there is in ``_flash_kernel``; below 2048 the two
+        # were never timed against each other on it). t % 512 == 0
+        # keeps the tiles at 512 or more: tiles of 512 already lose
+        # 4-16% to 1024, and a T like 2176 (= 128 * 17) would run tiles
+        # of 128. From 8192 up the dense program's float32 scores are
+        # 4.3 GB a row of 16 heads, so memory overrides tile health.
         return kernel_ok and t >= 2048 and (t % 512 == 0 or t >= 8192)
     return bool(use_flash)
 
 
-def _flash_attention(q, k, v, causal):
-    """Pallas TPU flash-attention kernel: O(T) memory instead of the
-    dense O(T²) score matrix (pallas_guide.md; long-context fast path —
-    SURVEY.md §5.7).
+@functools.lru_cache(maxsize=None)
+def _flash_kernel(heads: int, t: int, causal: bool, grouped: bool,
+                  interpret: bool):
+    """The library's block-sparse kernel over one sequence of ``t``, its
+    backward fused: for ``heads`` query heads each with a KV head of its
+    own or, ``grouped``, for the ``heads`` of ONE KV head's group (the
+    library's MQA form). The mask information is host-side numpy, made
+    once a geometry and kept (0.5 s at 32 heads of 8,192; identical
+    heads share one table): concrete arrays, whatever trace asks first.
 
-    Block sizes are pinned to the largest of (1024, 512, 256, 128)
-    dividing T: the kernel's defaults measured PATHOLOGICAL at long
-    context on v5e — T=16384 forward 584 ms default vs 47 ms at
-    1024-blocks (12x), fwd+bwd 177 ms vs 48 ms (3.7x); 2048-blocks
-    fails to compile (VMEM). Auto mode engages only where T yields
-    >= 512 blocks BELOW 8192; at T >= 8192 it engages unconditionally
-    (degraded 128/256-blocks included — dense's O(T²) scores OOM there,
-    so a slow flash beats no flash). A forced use_flash=True accepts
-    whatever divisor T offers. Measured in an earlier round's BENCHMARKS.md."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        BlockSizes,
-        flash_attention,
+    Tiles are the largest of (1024, 512, 256, 128) dividing ``t`` for
+    queries and keys alike, the forward scoring 512 keys at a time, the
+    backward the whole tile. Swept on one v5e at both training cells'
+    geometries, forward + backward of one layer (``PERF.md`` section 6,
+    PR 43): 8 x 16 heads of 128 at T = 2,048 5.55 ms, 2 x 32 heads of 64
+    over 8 KV heads at T = 8,192 31.3 ms, where the kernel this replaced
+    (``pallas.ops.tpu.flash_attention`` at 1,024-tiles, keys repeated a
+    group) took 9.80 and 47.4. Tiles of 512 score fewer masked pairs and
+    are slower (5.77, 36.4): a grid step costs more than the pairs it
+    saves; of the 2,048-tiles most do not fit VMEM and the rest are
+    slower; the two-kernel backward reads 7.11 and 37.1; key tiles of
+    2,048 in the backward halve the fused form's partial dQ at T = 8,192
+    for the same time (31.5) and lose at 2,048 (6.19), so one rule
+    serves both."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash,
+        splash_attention_mask as masks,
     )
 
-    t = q.shape[2]
-    # largest block <= 1024 that divides T (T % 128 == 0 guaranteed by
-    # _should_use_flash, so 128 always divides)
     n = next(b for b in (1024, 512, 256, 128) if t % b == 0)
-    bs = BlockSizes(
-        block_q=n, block_k_major=n, block_k=n, block_b=1,
-        block_q_major_dkv=n, block_k_major_dkv=n,
-        block_k_dkv=n, block_q_dkv=n,
-        block_k_major_dq=n, block_k_dq=n, block_q_dq=n,
-    )
-    return flash_attention(
-        q, k, v, causal=causal, sm_scale=q.shape[-1] ** -0.5,
-        block_sizes=bs)
+    blocks = splash.BlockSizes(
+        block_q=n, block_kv=n, block_kv_compute=min(n, 512),
+        block_q_dkv=n, block_kv_dkv=n, block_kv_dkv_compute=n,
+        use_fused_bwd_kernel=True)
+    one = (masks.CausalMask if causal else masks.FullMask)((t, t))
+    make = (splash.make_splash_mqa_single_device if grouped
+            else splash.make_splash_mha_single_device)
+    with jax.ensure_compile_time_eval():
+        return make(masks.MultiHeadMask([one] * heads),
+                    block_sizes=blocks, interpret=interpret)
+
+
+def _flash_attention(q, k, v, causal, *, interpret: bool = False):
+    """Whole-sequence attention without the O(T²) score matrix: the
+    library's block-sparse ("splash") Pallas kernel
+    (``jax.experimental.pallas.ops.tpu.splash_attention``) a batch row.
+    A tile no query of which sees a key is neither fetched nor scored
+    and only the diagonal tiles apply the mask; the backward forms dQ,
+    dK and dV in ONE pass over the scores (five products and one
+    ``exp`` where a dq and a dkv kernel take seven and two) and reads
+    the forward's log-sum-exp one row a head. bf16 operands, float32
+    scores, sums and accumulators. ``interpret`` runs the kernel through
+    the Pallas interpreter (the CPU parity tests).
+
+    ``k`` / ``v`` may hold fewer heads than ``q``: the keys are never
+    repeated, the kernel runs a KV head at a time over its group's
+    query heads and dK, dV come back a KV head. (The library's MHA form
+    takes such ``k`` as it is and its kernels read the same time alone,
+    31.0 ms against 31.3; in LFM2's step the grouped call is 1.9%
+    faster end to end, 62.8 k tokens/s against 61.6 k: the rotary and
+    projection fusions XLA builds around the call's operands come out
+    cheaper, ``PERF.md`` section 6, PR 43.)"""
+    b, h, t, dh = q.shape
+    hk = k.shape[1]
+    with scope("attn/core"):
+        q = q * dh ** -0.5
+    if hk == h:
+        kernel = _flash_kernel(h, t, causal, False, interpret)
+        return jax.vmap(kernel)(q, k, v)
+    kernel = _flash_kernel(h // hk, t, causal, True, interpret)
+    o = jax.vmap(jax.vmap(kernel))(
+        q.reshape(b, hk, h // hk, t, dh), k, v)
+    return o.reshape(b, h, t, dh)
 
 
 #: query-tile rows of the paged kernel: a chunk that is a multiple of

@@ -201,15 +201,61 @@ def test_paged_auto_rule_only_selects_shapes_that_lower(monkeypatch):
         _should_use_flash_paged(True, 16, 128, 200)
 
 
-def test_flash_kernel_lowers_for_tpu_forward_and_backward(monkeypatch):
-    q = jax.ShapeDtypeStruct((2, 8, 2048, 128), jnp.bfloat16)
+# the two training cells' geometries (Cerebras-GPT-1.3B: 8 x 2048, 16
+# heads of 128; LFM2-8B-A1B: 2 x 8192, 32 query heads of 64 over 8 KV
+# heads, a KV head at a time over its group): the block-sparse forward
+# and its ONE fused backward kernel (dQ, dK, dV from one pass over S)
+@pytest.mark.parametrize("b,h,hk,t,dh", [
+    (8, 16, 16, 2048, 128), (2, 32, 8, 8192, 64)])
+def test_flash_kernel_lowers_for_tpu_forward_and_backward(
+        monkeypatch, b, h, hk, t, dh):
+    q = jax.ShapeDtypeStruct((b, h, t, dh), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, hk, t, dh), jnp.bfloat16)
 
     def loss(q, k, v):
         return jnp.sum(_flash_attention(q, k, v, True)
                        .astype(jnp.float32))
 
-    text = _tpu_module(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
-    # forward, and the backward pass's dq and dkv kernels
-    assert text.count("tpu_custom_call") >= 3
+    text = _tpu_module(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
+    assert text.count("tpu_custom_call") == 2
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert _should_use_flash(None, q, None)
+
+
+# numerics, through the Pallas interpreter at a small size: output and
+# the three gradients against the plain program, heads of 128 and of
+# 64, causal and not, grouped KV heads and not; T = 1536 is three tiles
+# a side, so fully masked tiles are skipped and only the diagonal ones
+# mask
+@pytest.mark.parametrize("t,dh,causal,hk", [
+    (512, 128, True, 4), (512, 128, False, 4), (512, 64, True, 4),
+    (512, 64, False, 4), (512, 128, True, 2), (512, 128, False, 2),
+    (512, 64, True, 1), (512, 64, False, 2), (1536, 128, True, 4)])
+def test_flash_kernel_agrees_with_the_dense_program(t, dh, causal, hk):
+    from deeplearning4j_tpu.nn.layers.attention import (
+        _dense_attention,
+        _repeat_kv_heads,
+    )
+
+    b, h = (1, 4)
+    keys = jax.random.split(jax.random.PRNGKey(t + dh + hk), 4)
+    q, w = (jax.random.normal(key, (b, h, t, dh)) for key in keys[:2])
+    k, v = (jax.random.normal(key, (b, hk, t, dh)) for key in keys[2:])
+
+    def flash(q, k, v):
+        return _flash_attention(q, k, v, causal, interpret=True)
+
+    def dense(q, k, v):
+        return _dense_attention(q, *_repeat_kv_heads(q, k, v), causal,
+                                None, None)
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2)))(q, k, v)
+
+    out = jax.jit(flash)(q, k, v)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    assert jnp.max(jnp.abs(out - dense(q, k, v))) < 2e-5
+    for got, want in zip(grads(flash), grads(dense)):
+        assert got.shape == want.shape
+        assert jnp.max(jnp.abs(got - want)) < 2e-5

@@ -241,6 +241,46 @@ def test_a_gradient_wraps_a_scope_and_the_reader_takes_it_off(lowered):
 
 
 # ---------------------------------------------------------------------
+# the library's attention program, which no scope of ours wraps
+# ---------------------------------------------------------------------
+@pytest.mark.parametrize("entry", ["jit(_splash_attention)",
+                                   "jit(flash_attention)"])
+def test_a_library_entry_stands_for_the_attention_core(entry):
+    assert scopes.LIBRARY_SCOPES[entry] == "attn/core"
+    forward = f"jit(steps)/while/body/jvp(vmap({entry}))/pallas_call"
+    assert opscopes.cut(forward) == (None, "attn", "core", False)
+    backward = (f"jit(steps)/while/body/transpose(jvp(vmap({entry})))/"
+                "splash_mha_dkv_no_residuals/pallas_call")
+    assert opscopes.cut(backward) == (None, "attn", "core", True)
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2])   # MHA; a group a KV head
+def test_the_flash_programs_kernels_fall_under_the_attention_core(
+        kv_heads):
+    """Read from the program, not written down: the kernels
+    ``_flash_attention`` lowers to for the TPU, under a gradient inside
+    a loop as a training step holds them, are charged to ``attn/core``
+    through ``LIBRARY_SCOPES``, the backward one as backward."""
+    from deeplearning4j_tpu.nn.layers.attention import _flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 4, 512, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, kv_heads, 512, 64), jnp.bfloat16)
+
+    def steps(q, k, v):
+        def body(c, _):
+            g = jax.grad(lambda q: jnp.sum(_flash_attention(
+                q, k, v, True).astype(jnp.float32)))(q + c)
+            return g[0, 0, 0, 0], None
+        return jax.lax.scan(body, jnp.zeros((), q.dtype), None, length=2)
+
+    low = jax.jit(steps).trace(q, k, k).lower(lowering_platforms=("tpu",))
+    kernels = [opscopes.cut(path) for op, path in _paths(low)
+               if op == "custom-call"]
+    assert sorted(kernels) == [(None, "attn", "core", False),
+                               (None, "attn", "core", True)]
+
+
+# ---------------------------------------------------------------------
 # (b) a scope is metadata only
 # ---------------------------------------------------------------------
 @pytest.mark.parametrize("cell", TRAINED + SERVED)
