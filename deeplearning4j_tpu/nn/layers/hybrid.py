@@ -71,7 +71,8 @@ idle serving slot routes to no expert and its recurrent state is left
 alone), and ``counters``, a dict the block adds this call's int32
 scalars into (``moe_picks``, ``moe_picks_held``,
 ``moe_experts_touched``, ``moe_load_max``, ``moe_layer_steps``,
-``ssm_state_rows``, ``eva_summaries_written``). A training step hands them back with its
+``ssm_state_rows``, ``eva_summaries_written``; under ``train`` also
+``moe_pair_rows_worked``). A training step hands them back with its
 gradient-health scalars (``MultiLayerNetwork._step_body``).
 """
 
@@ -617,6 +618,11 @@ class HybridMoeBlockImpl(LayerImplBase):
                 kernel=lc.use_kernels, gate_rule=lc.gate_rule,
                 route_scale=lc.route_scale, route_eps=lc.route_eps,
                 detach_scores=lc.freeze_router)
+            if not train:
+                # the rows a TRAINING step's passes covered: a served
+                # program returns what it returned (and the engine's
+                # ``stats`` name what they named)
+                del moe_counts["moe_pair_rows_worked"]
             counts.update(moe_counts,
                           moe_layer_steps=jnp.asarray(1, jnp.int32))
         else:

@@ -233,19 +233,73 @@ def route(logits, top_k: int, rule: str = "softmax_topk", bias=None,
     return g / (jnp.sum(g, axis=-1, keepdims=True) + eps) * scale, idx
 
 
-@jax.custom_vjp
-def _cotangent_where(keep, x):
-    """``x`` itself going forward; going back, its cotangent where
-    ``keep`` and 0 elsewhere. For the rows a grouped product never
-    writes: forward they are dropped after the second product, and a
-    served program is what it was; transposed, nothing may come back
-    through them."""
-    return x
+#: the most rows of the sorted pairs one trip of a training pass takes
+#: (:func:`_held_slabs`): few enough that the passes stop within a
+#: sixteenth of a 65,536-pair layer of the last held pair, enough that
+#: a trip's two dozen megabytes hide what starting it costs
+_SLAB_ROWS = 4096
 
 
-_cotangent_where.defvjp(
-    lambda keep, x: (x, keep),
-    lambda keep, g: (None, jnp.where(keep, g, 0)))
+def _slab_rows(rows: int) -> int:
+    """The rows a slab: the largest divisor of ``rows`` that is at most
+    ``_SLAB_ROWS`` (whole slabs, so that no row is worked twice), or
+    all the ``rows`` as one slab where the count has no divisor within
+    a quarter of that."""
+    most = min(_SLAB_ROWS, rows)
+    size = next(s for s in range(most, 0, -1) if rows % s == 0)
+    return size if 4 * size >= most else rows
+
+
+def _slab(x, start):
+    return jax.lax.dynamic_slice_in_dim(x, start, _slab_rows(x.shape[0]))
+
+
+def _slabs_to(n_in, rows: int):
+    """The slabs of ``rows`` rows that reach row ``n_in``."""
+    size = _slab_rows(rows)
+    return (n_in + size - 1) // size
+
+
+def _held_slabs(n_in, out, slab_of):
+    """``out`` with its rows below ``n_in`` (the held pairs: the sorted
+    pairs' rows within the groups) made ``slab_of``'s, a slab of rows at
+    a time in place, by a loop whose trip count is read from ``n_in``:
+    the work follows the held pairs, whatever ``out.shape[0]``.
+    ``slab_of(start, out)`` gives the blocks of columns, side by side,
+    of rows ``start`` to ``start + _slab_rows(out.shape[0])`` (``out``
+    as the slabs before left it: a pass that overwrites its own input
+    reads it there). Rows past the last slab are left as they were:
+    where ``out`` starts uninitialised (:func:`_fresh_slabs`) they may
+    hold anything, like the rows a grouped product never writes, and
+    whoever reads the result reads rows below ``n_in`` only, or
+    selects."""
+    size = _slab_rows(out.shape[0])
+
+    def step(i, out):
+        col = 0
+        # (a slab's values are final before a row of ``out`` changes:
+        # else the compiler may read a pass's own input again between
+        # two blocks' writes, and copy all of ``out`` to do so)
+        for block in jax.lax.optimization_barrier(slab_of(i * size, out)):
+            out = jax.lax.dynamic_update_slice(out, block, (i * size, col))
+            col += block.shape[1]
+        return out
+
+    return jax.lax.fori_loop(0, _slabs_to(n_in, out.shape[0]), step, out)
+
+
+def _fresh_slabs(n_in, shape, dtype, slab_of):
+    """:func:`_held_slabs` into a buffer that starts uninitialised
+    (nothing zeroes it). The buffer is made inside a conditional on
+    ``n_in``: the compiler allocates such a buffer at the top of the
+    computation that holds it, which for a backward pass's would else
+    be the top of the whole step, a forward pass before its first
+    write."""
+    def empty():
+        return jax.lax.empty(shape, dtype)
+
+    return jax.lax.cond(
+        n_in > 0, lambda: _held_slabs(n_in, empty(), slab_of), empty)
 
 
 def _held_pick(rows, picks, held, i):
@@ -256,48 +310,126 @@ def _held_pick(rows, picks, held, i):
         jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _rows_of_pairs(top_k, tokens, order, held):
-    """``tokens[order // top_k]``: a token's row for each of its
-    ``top_k`` (token, pick) pairs, in the sorted order. ``order`` is a
-    permutation of the pairs, so going back nothing is scattered: a
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _sorted_pairs(top_k, n_held, tokens, expert, held):
+    """The (token, pick) pairs sorted by held expert, ``expert``
+    ``[M k]`` being a pair's held expert or ``n_held`` where it is not
+    held, so that the pairs not held sort past the groups. Returns
+    ``(order, sizes, inside, xs, worked)``: the sort's permutation, the
+    held experts' group sizes, ``inside`` ``[M k, 1]`` (the sorted rows
+    within the groups), ``xs = tokens[order // top_k]`` (a token's row
+    for each of its pairs, in the sorted order) and the rows of ``xs``
+    that were made.
+
+    With no gradient asked this is plain indexing, a served program's
+    text: ``bincount`` for the sizes, one gather of all ``M k`` rows.
+    Under a gradient the sizes are counted by comparison (no
+    scatter-add of 65,536 ones), and ``xs`` is made for the held pairs
+    only (:func:`_fresh_slabs`: the first product reads no other row).
+    Going back nothing is scattered, ``order`` being a permutation: a
     token's cotangent is the sum of its HELD pairs' rows, gathered by
     the inverse permutation a pick at a time (``held`` ``[M, top_k]``;
     a pair not held sorts past the groups, where the grouped product's
     transpose writes nothing, and is selected away after its gather).
     The rule's own forward makes the inverse, the ``argsort`` the
-    combine is handed too (one operation once compiled), so that forward
-    alone the program is plain indexing's. The gathers going back are
-    traced under the scope of the call."""
-    return tokens[order // top_k]
+    combine makes too (one operation once compiled). The gathers going
+    back are traced under the scope of the call."""
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.bincount(expert, length=n_held + 1)[:n_held].astype(
+        jnp.int32)
+    inside = (jnp.arange(expert.shape[0]) < jnp.sum(sizes))[:, None]
+    return (order, sizes, inside, tokens[order // top_k],
+            jnp.int32(expert.shape[0]))
 
 
-def _rows_of_pairs_bwd(top_k, res, g):
+def _sorted_pairs_fwd(top_k, n_held, tokens, expert, held):
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.sum(expert == jnp.arange(n_held)[:, None], axis=1,
+                    dtype=jnp.int32)
+    n_in = jnp.sum(sizes)
+    inside = (jnp.arange(expert.shape[0]) < n_in)[:, None]
+    xs = _fresh_slabs(
+        n_in, (expert.shape[0], tokens.shape[1]), tokens.dtype,
+        lambda start, _: [tokens[_slab(order, start) // top_k]])
+    worked = _slabs_to(n_in, expert.shape[0]) * _slab_rows(expert.shape[0])
+    return (order, sizes, inside, xs, worked), (jnp.argsort(order), held)
+
+
+def _sorted_pairs_bwd(top_k, n_held, res, g):
     # one gather a pick, selected, summed in float32 and cast once: a
     # gather of all the pairs would be re-laid out as [M, k, D] before
     # its sum, and a select before it is a pass over the pairs' rows
     back, held = res
+    d_xs = g[3]
     picks = back.reshape(-1, top_k)
     total = functools.reduce(jnp.add, [
-        _held_pick(g, picks, held, i) for i in range(top_k)])
-    return total.astype(g.dtype), None, None
+        _held_pick(d_xs, picks, held, i) for i in range(top_k)])
+    return total.astype(d_xs.dtype), None, None
 
 
-_rows_of_pairs.defvjp(
-    lambda top_k, tokens, order, held: (
-        tokens[order // top_k], (jnp.argsort(order), held)),
-    _rows_of_pairs_bwd)
+_sorted_pairs.defvjp(_sorted_pairs_fwd, _sorted_pairs_bwd)
+
+
+def _gated(g, u):
+    return jax.nn.silu(g) * u
 
 
 @jax.custom_vjp
-def _combine_picks(ys, gates, held, inside, order):
+def _gated_rows(gu, sizes):
+    """``silu(g) * u`` with ``[g | u] = gu``, the first product's
+    ``[M k, 2 F]`` rows, of which those within the groups (``sizes``:
+    the first ``n_in = sum(sizes)``) were written. With no gradient
+    asked: the expression over all the rows. Under a gradient, forward
+    and back, the same expression and ITS transpose over the held
+    pairs' rows only (:func:`_fresh_slabs`, and :func:`_held_slabs` over
+    the dead ``gu``): the cotangent's two halves are written side by
+    side over ``gu``'s own rows, ONE ``[M k, 2 F]`` array whose rows
+    past the last slab still hold ``gu``: like the rows past the groups
+    of every array the products' transposes read, they may hold
+    anything (the transposes read none of them)."""
+    f = gu.shape[1] // 2
+    # (written out, not ``_gated`` of two slices: a served program's
+    # text slices the second half after it has activated the first)
+    return jax.nn.silu(gu[:, :f]) * gu[:, f:]
+
+
+def _gated_rows_fwd(gu, sizes):
+    f = gu.shape[1] // 2
+    n_in = jnp.sum(sizes)
+
+    def slab_of(start, _):
+        rows = _slab(gu, start)
+        return [_gated(rows[:, :f], rows[:, f:])]
+
+    act = _fresh_slabs(n_in, (gu.shape[0], f), gu.dtype, slab_of)
+    return act, (gu, n_in)
+
+
+def _gated_rows_bwd(res, d_act):
+    gu, n_in = res
+    f = gu.shape[1] // 2
+
+    def slab_of(start, gu):
+        rows = _slab(gu, start)
+        _, back = jax.vjp(_gated, rows[:, :f], rows[:, f:])
+        return back(_slab(d_act, start))
+
+    # over ``gu`` itself, which nothing reads after this
+    return _held_slabs(n_in, gu, slab_of), None
+
+
+_gated_rows.defvjp(_gated_rows_fwd, _gated_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_picks(ys, gates, held, inside, order, sizes):
     """``sum over a token's held picks of gate x ys[the pick's sorted
     row]``, float32 ``[M, D]``. ``ys`` ``[M k, D]`` are the sorted
     pairs' rows, those past the held groups never written: they may
     hold anything and are selected away, not scaled. ``gates`` and
     ``held`` are ``[M, k]``; ``order`` is the sort's permutation, the
     pairs not held last, so that ``inside`` ``[M k, 1]``, the rows
-    within the groups, is ``held`` in the sorted order.
+    within the groups (``sizes``), is ``held`` in the sorted order.
 
     With no gradient asked this is plain indexing: the select over the
     sorted rows, ONE gather of all the pairs by the inverse
@@ -307,13 +439,13 @@ def _combine_picks(ys, gates, held, inside, order):
     gathers, each selected by its column of ``held``, scaled and added
     in float32. Back: a sorted pair's cotangent is its gate times its
     token's row of ``dy``, one gather from ``[M, D]``, the product in
-    float32 rounded once to ``ys``' dtype (``dy`` holds no unwritten
-    row, so a pair not held takes a gate of 0 there; and ``dy`` is
-    read at ``ys``' dtype, which is exact where the caller casts this
-    sum to that dtype, as the layer does); a gate's is its pick's row
-    against ``dy``. No float32 ``[M, k, D]`` either way, no select over
-    ``[M k, D]``, and no pass over the pairs' rows but the one that
-    writes their cotangent."""
+    float32 rounded once to ``ys``' dtype, made for the held pairs only
+    (:func:`_fresh_slabs`: the second product's transposes read no other
+    row; ``dy`` is read at ``ys``' dtype, which is exact where the
+    caller casts this sum to that dtype, as the layer does); a gate's
+    is its pick's row against ``dy``. No float32 ``[M, k, D]`` either
+    way, no select over ``[M k, D]``, and no pass over the pairs' rows
+    but the one that writes the held pairs' cotangent."""
     m, top_k = gates.shape
     ys = jnp.where(inside, ys, 0)
     back = jnp.argsort(order)
@@ -321,27 +453,32 @@ def _combine_picks(ys, gates, held, inside, order):
     return jnp.sum(picked * jnp.where(held, gates, 0.0)[..., None], axis=1)
 
 
-def _combine_picks_fwd(ys, gates, held, inside, order):
+def _combine_picks_fwd(ys, gates, held, inside, order, sizes):
     picks = jnp.argsort(order).reshape(gates.shape)
     y = functools.reduce(jnp.add, [
         _held_pick(ys, picks, held, i) * gates[:, i:i + 1]
         for i in range(gates.shape[1])])
-    return y, (ys, gates, held, order, picks)
+    return y, (ys, gates, held, order, picks, jnp.sum(sizes))
 
 
 def _combine_picks_bwd(res, dy):
-    ys, gates, held, order, picks = res
+    ys, gates, held, order, picks, n_in = res
     top_k = gates.shape[1]
-    # dy holds no unwritten row: the pairs not held take a gate of 0
-    gate = jnp.where(held, gates, 0.0).reshape(-1)[order]
     # the layer casts the sum to ys' dtype, so dy is such a value lifted
     # to float32: its rows are gathered at that dtype, nothing rounded
-    rows = dy.astype(ys.dtype)[order // top_k]
-    d_ys = (gate[:, None] * rows).astype(ys.dtype)
+    rows = dy.astype(ys.dtype)
+    gate = gates.reshape(-1)
+
+    def slab_of(start, _):
+        pairs = _slab(order, start)
+        return [(gate[pairs][:, None] * rows[pairs // top_k]).astype(
+            ys.dtype)]
+
+    d_ys = _fresh_slabs(n_in, ys.shape, ys.dtype, slab_of)
     d_gates = jnp.stack(
         [jnp.sum(dy * _held_pick(ys, picks, held, i), axis=-1)
          for i in range(top_k)], axis=1)
-    return d_ys, d_gates.astype(gates.dtype), None, None, None
+    return d_ys, d_gates.astype(gates.dtype), None, None, None, None
 
 
 _combine_picks.defvjp(_combine_picks_fwd, _combine_picks_bwd)
@@ -372,23 +509,41 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
     an expert held elsewhere adds nothing to the value or to any
     gradient: the rows past the held groups, which the kernel never
     writes going either way, are SELECTED away from the value and from
-    each product's cotangents. The row movement by the sort's
-    permutation and the gate-weighted combine state their own rules
-    (:func:`_rows_of_pairs`, :func:`_combine_picks`): under a gradient
-    both work a pick at a time on gathered ``[M, D]`` rows, by the
-    inverse permutation where autodiff would scatter-add, each gather
-    selected by its column of ``held`` inside the float32 sum that
-    follows it, so that no ``[M, k, D]`` array is made and no select
-    passes over the pairs' ``[M k, D]`` rows; with no gradient asked
-    both are plain indexing. (The select on the first product's
-    cotangent, :func:`_cotangent_where`, rides the pass that puts the
-    activation's two halves side by side.) Under
-    ``detach_scores`` the gates are constants to the gradient: neither
-    the router nor ``tokens`` takes one through them (the block's
-    ``freeze_router``). ``counts`` are int32 scalars: ``moe_picks`` (token
-    x pick pairs routed), ``moe_picks_held`` (those on held experts),
-    ``moe_experts_touched`` (held experts with at least one row),
-    ``moe_load_max`` (the fullest held expert's rows)."""
+    the tokens' cotangent, and nothing else reads them. The sort with
+    the row movement by its permutation, the gated activation between
+    the two products and the gate-weighted combine state their own
+    rules (:func:`_sorted_pairs`, :func:`_gated_rows`,
+    :func:`_combine_picks`). With no gradient asked all three are plain
+    indexing over all ``M k`` pairs' rows (a served program's text).
+    Under a gradient what runs over which rows:
+
+    - over the HELD pairs' rows only, ``n_in = sum(sizes)`` of the
+      ``M k``, a slab of ``_SLAB_ROWS`` at a time by a loop whose trip
+      count is read from ``n_in`` (:func:`_held_slabs`; all ``M k`` may
+      be held, and then every slab runs): the gather of ``xs``, the
+      activation forward and its transpose (the cotangent written over
+      ``gu``), and the pass that writes ``ys``' cotangent; rows past the
+      last slab are never written, zeroed or read;
+    - over the groups' rows, by its own grid: the grouped kernel and
+      its transposes;
+    - a pick at a time on gathered ``[M, D]`` rows, by the inverse
+      permutation where autodiff would scatter-add, each gather
+      selected by its column of ``held`` inside the float32 sum that
+      follows it: the combine's value, the gates' cotangent and the
+      tokens' (no ``[M, k, D]`` array, no select over ``[M k, D]``);
+    - numbers only: the sort of the ``M k`` keys and its inverse; the
+      sizes are counted by comparison (``bincount``'s scatter-add is
+      the served program's).
+
+    Under ``detach_scores`` the gates are constants to the gradient:
+    neither the router nor ``tokens`` takes one through them (the
+    block's ``freeze_router``). ``counts`` are int32 scalars:
+    ``moe_picks`` (token x pick pairs routed), ``moe_picks_held`` (those
+    on held experts), ``moe_experts_touched`` (held experts with at
+    least one row), ``moe_load_max`` (the fullest held expert's rows),
+    ``moe_pair_rows_worked`` (the sorted pairs' rows the passes around
+    the products covered: slabs x slab rows under a gradient, within a
+    slab of ``moe_picks_held``; ``M k`` with none asked)."""
     m = tokens.shape[0]
     lo, hi = experts_held
     n_held = hi - lo
@@ -408,28 +563,21 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
                   else valid.astype(bool)[:, None])
         held = (idx >= lo) & (idx < hi) & routed
     with scope("moe/sort"):
-        # sort the pairs by held expert; what is not held sorts last
+        # sort the pairs by held expert; what is not held sorts last.
+        # Rows past the groups are never written by the kernel, forward
+        # or transposed, nor by a training pass around it, and may hold
+        # anything: select them away (do not scale) from the value (as
+        # ``inside`` over the sorted rows, or as ``held`` over a pick's
+        # gathered rows: the same pairs); going back nothing reads them
         expert = jnp.where(held, idx - lo, n_held).reshape(-1)
-        order = jnp.argsort(expert, stable=True)
-        sizes = jnp.bincount(expert, length=n_held + 1)[:n_held].astype(
-            jnp.int32)
-        # rows past the groups are never written by the kernel, forward
-        # or transposed, and may hold anything: select them away (do
-        # not scale) from the value, and from each product's cotangents
-        # (as ``inside`` over the sorted rows, or as ``held`` over a
-        # pick's gathered rows: the same pairs)
-        inside = (jnp.arange(m * top_k) < jnp.sum(sizes))[:, None]
-        xs = _rows_of_pairs(top_k, tokens, order, held)
+        order, sizes, inside, xs, worked = _sorted_pairs(
+            top_k, n_held, tokens, expert, held)
     with scope("moe/experts"):
         gu = grouped_product(xs, params["We_in"], sizes, kernel)
-    with scope("moe/combine"):
-        gu = _cotangent_where(inside, gu)
-    with scope("moe/experts"):
-        f = gu.shape[-1] // 2
-        act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(tokens.dtype)
+        act = _gated_rows(gu, sizes).astype(tokens.dtype)
         ys = grouped_product(act, params["We_out"], sizes, kernel)
     with scope("moe/combine"):
-        y = _combine_picks(ys, gates, held, inside, order)
+        y = _combine_picks(ys, gates, held, inside, order, sizes)
     if "Ws_in" in params:
         with scope("moe/shared"):
             y = y + gated_ffn(tokens, params["Ws_in"],
@@ -440,6 +588,7 @@ def dropless_moe(params, tokens, valid=None, *, top_k: int,
             "moe_picks_held": jnp.sum(held.astype(jnp.int32)),
             "moe_experts_touched": jnp.sum(
                 (sizes > 0).astype(jnp.int32)),
-            "moe_load_max": jnp.max(sizes)}
+            "moe_load_max": jnp.max(sizes),
+            "moe_pair_rows_worked": worked}
     with scope("moe/combine"):
         return y.astype(tokens.dtype), counts

@@ -136,7 +136,8 @@ class TracingIterationListener(IterationListener):
       already-run jitted step; the fetch rides the same sync domain)
       and, with them, what the layers counted in the step's forward
       pass (an expert block's ``moe_picks``, ``moe_picks_held``,
-      ``moe_experts_touched``, ``moe_load_max``, ``moe_layer_steps``):
+      ``moe_experts_touched``, ``moe_load_max``, ``moe_layer_steps``,
+      ``moe_pair_rows_worked``):
       summed over the window, they go into the record, onto the
       ``train.dispatch`` span's args and into ``train_<name>`` counters,
     - emits a ``train.step`` span carrying the full breakdown in its

@@ -188,6 +188,55 @@ def test_grouped_expert_product_compiles_for_v5e(one_chip, rows):
     assert text.count('custom_call_target="tpu_custom_call"') >= 2
 
 
+def test_the_expert_layers_training_passes_stay_in_place_on_v5e(one_chip):
+    """The LFM2 cell's expert layer under a gradient (16,384 tokens of
+    2,048, top 4 of 32 outputs, 8 held experts of 2 x 1,792), compiled
+    for the described chip: the four passes around the grouped products
+    are loops over slabs of the held pairs' rows whose every write is
+    an in-place update. No pass copies an array of all the pairs' rows
+    (a loop that read its own input again between two writes did: 470 MB
+    a trip); three of the four buffers start uninitialised
+    (``AllocateBuffer``: nothing zeroes them), each inside a branch of
+    its conditional and not at the top of the program, where it would be
+    held from the first instruction on; the fourth is the dead ``gu``."""
+    import re
+
+    from deeplearning4j_tpu.nn.layers import moe
+
+    bf16, S = jnp.bfloat16, jax.ShapeDtypeStruct
+    m, d, f, top_k = 16384, 2048, 1792, 4
+
+    def loss(p, x, probe):
+        y, counts = moe.dropless_moe(
+            p, x, top_k=top_k, experts_held=(0, 8), kernel=True,
+            gate_rule="sigmoid_bias", route_eps=1e-6, detach_scores=True)
+        # (not linear in y: as in a step, the value's cotangent waits
+        # for the value, so every forward reader of ``ys`` is done)
+        return jnp.sum(jnp.square((y * probe).astype(jnp.float32))), counts
+
+    def grads(router, bias, w_in, w_out, x, probe):
+        p = {"router": router, "expert_bias": bias, "We_in": w_in,
+             "We_out": w_out}
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            p, x, probe)
+
+    text = _compile_for(
+        one_chip, grads, S((d, 32), bf16), S((32,), jnp.float32),
+        S((8, d, 2 * f), bf16), S((8, f, d), bf16), S((m, d), bf16),
+        S((m, d), bf16))
+    pairs = rf"bf16\[{m * top_k},(?:{d}|{f}|{2 * f})\]"
+    assert re.findall(rf"{pairs}[^\n]* copy\(", text) == []
+    made = re.findall(
+        rf'{pairs}[^\n]*custom_call_target="AllocateBuffer"[^\n]*', text)
+    assert len(made) == 6 and all("/cond/branch_" in line for line in made)
+    assert text.count(" conditional(") == 3
+    slabs = [line for line in text.splitlines()
+             if " while(" in line and "/while" in line
+             and "searchsorted" not in line]
+    assert len(slabs) == 4
+    assert text.count('custom_call_target="tpu_custom_call"') >= 6
+
+
 def test_paged_auto_rule_only_selects_shapes_that_lower(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for t in (1, 5, 8, 64, 128, 256, 2048):

@@ -4,7 +4,9 @@ against a literal sum and through the streaming state; ``jax.grad``
 through the dropless expert layer with the grouped kernel in
 ``interpret`` against ``ragged_dot``; the chip's share tied to the
 model (four shares' outputs and gradients add up to the uncut
-reference's); integer labels against the one-hot loss, and no one-hot
+reference's); the layer's rules under a gradient against its full-width
+form (the held pairs' slabs, ``nan`` in what they leave unwritten, a
+served program's text); integer labels against the one-hot loss, and no one-hot
 of the vocabulary in the step's program; the program's logits, loss,
 every leaf's gradient and three Adam steps against the plain reference
 (``benchmark/models/lfm2_moe_reference.py``); ``expert_bias`` a leaf no
@@ -209,12 +211,36 @@ def test_four_shares_add_up_to_the_uncut_reference():
 D_ROWS = 20            # the layer's width here; no other size is 20
 
 
-def _plain_rows_of_pairs(top_k, tokens, order, held):
-    inside = (jnp.arange(order.shape[0]) < jnp.sum(held))[:, None]
-    return moe._cotangent_where(inside, tokens[order // top_k])
+@jax.custom_vjp
+def _cotangent_inside(sizes, x):
+    """``x`` itself going forward; going back, its cotangent's rows
+    within the groups (the first ``sum(sizes)``) and 0 past them."""
+    return x
 
 
-def _plain_combine(ys, gates, held, inside, order):
+_cotangent_inside.defvjp(
+    lambda sizes, x: (x, sizes),
+    lambda sizes, g: (None, jnp.where(
+        (jnp.arange(g.shape[0]) < jnp.sum(sizes))[:, None], g, 0)))
+
+
+def _plain_sorted_pairs(top_k, n_held, tokens, expert, held):
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.bincount(expert, length=n_held + 1)[:n_held].astype(
+        jnp.int32)
+    inside = (jnp.arange(expert.shape[0]) < jnp.sum(sizes))[:, None]
+    return (order, sizes, inside,
+            _cotangent_inside(sizes, tokens[order // top_k]),
+            jnp.int32(expert.shape[0]))
+
+
+def _plain_gated_rows(gu, sizes):
+    gu = _cotangent_inside(sizes, gu)
+    f = gu.shape[1] // 2
+    return jax.nn.silu(gu[:, :f]) * gu[:, f:]
+
+
+def _plain_combine(ys, gates, held, inside, order, sizes):
     m, top_k = gates.shape
     picked = jnp.where(inside, ys, 0)[jnp.argsort(order)].reshape(
         m, top_k, -1).astype(jnp.float32)
@@ -222,19 +248,27 @@ def _plain_combine(ys, gates, held, inside, order):
 
 
 def _plain_indexing(patch):
-    """The layer as plain indexing writes it: a select over the sorted
-    pairs' rows on either side, one gather of all the pairs each way
-    (autodiff transposes each into a scatter-add) and the float32
-    ``[M, k, D]`` under the weighted sum."""
-    patch.setattr(moe, "_rows_of_pairs", _plain_rows_of_pairs)
+    """The layer in its full-width form, as plain indexing writes it:
+    every pass over all ``M k`` pairs' rows, a select over the sorted
+    pairs' rows on either side and on the first product's cotangent,
+    one gather of all the pairs each way (autodiff transposes each into
+    a scatter-add) and the float32 ``[M, k, D]`` under the weighted
+    sum."""
+    patch.setattr(moe, "_sorted_pairs", _plain_sorted_pairs)
+    patch.setattr(moe, "_gated_rows", _plain_gated_rows)
     patch.setattr(moe, "_combine_picks", _plain_combine)
 
 
-def _layer_loss(p, x, probe, valid, held, top_k, rule, frozen=False):
-    y, _ = moe.dropless_moe(p, x, valid, top_k=top_k, experts_held=held,
-                            kernel=False, gate_rule=rule, route_eps=1e-6,
-                            detach_scores=frozen)
-    return jnp.sum(y * probe)
+def _loss_and_counts(p, x, probe, valid, held, top_k,
+                     rule="sigmoid_bias", frozen=False, kernel=False):
+    y, counts = moe.dropless_moe(
+        p, x, valid, top_k=top_k, experts_held=held, kernel=kernel,
+        gate_rule=rule, route_eps=1e-6, detach_scores=frozen)
+    return jnp.sum(y * probe), counts
+
+
+def _layer_loss(*args):
+    return _loss_and_counts(*args)[0]
 
 
 @pytest.mark.parametrize("frozen", [True, False],
@@ -275,18 +309,102 @@ def test_row_movements_transpose_as_plain_indexing_does(
         assert not np.any(np.asarray(gx)[~np.asarray(valid)])
 
 
+# ---------------------------------------------------------------------
+# the training passes around the products: the held pairs' rows only
+# ---------------------------------------------------------------------
+SLAB = 8               # ``moe._SLAB_ROWS`` here, so that 24 pairs are 3
+
+
+#: the held range by the share of the picks it holds; ``none`` holds
+#: experts no token picks (their selection bias is -100)
+SHARES = {"none": (2, 6), "quarter": (0, 2), "mid_slab": (3, 8),
+          "every": (0, 8)}
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("top_k", [1, 3])
+@pytest.mark.parametrize("share", list(SHARES))
+def test_training_passes_cover_the_held_pairs_rows_and_no_others(
+        monkeypatch, share, top_k, masked):
+    """Under a gradient the gather of ``xs``, the activation forward and
+    back and the combine's ``d_ys`` run a slab of rows at a time as far
+    as the held pairs reach: the layer's value and EVERY gradient
+    (tokens, ``We_in``, ``We_out``, the router's through the learning
+    gates) are the full-width form's, whether no pick is held, a
+    quarter, a share that ends inside a slab, or every pick; and
+    ``moe_pair_rows_worked`` reads the slabs' rows, within one slab of
+    ``moe_picks_held`` (the full-width form works all ``M k``)."""
+    monkeypatch.setattr(moe, "_SLAB_ROWS", SLAB)
+    full, x, probe = _expert_layer(seed=7, d=D_ROWS)
+    held = SHARES[share]
+    if share == "none":
+        full["expert_bias"] = full["expert_bias"].at[
+            held[0]:held[1]].set(-100.0)
+    p = _share(full, *held)
+    valid = (jnp.asarray(np.random.default_rng(8).random(x.shape[0]) < 0.7)
+             if masked else None)
+    pairs = x.shape[0] * top_k
+
+    def grads():
+        return jax.value_and_grad(_loss_and_counts, argnums=(0, 1),
+                                  has_aux=True)(
+            p, x, probe, valid, held, top_k)
+
+    (value, counts), (gp, gx) = grads()
+    with monkeypatch.context() as patch:
+        _plain_indexing(patch)
+        (value_plain, counts_plain), (gp_plain, gx_plain) = grads()
+    np.testing.assert_allclose(value, value_plain, rtol=2e-5)
+    np.testing.assert_allclose(gx, gx_plain, rtol=2e-5, atol=2e-6)
+    for name in ("router", "We_in", "We_out"):
+        np.testing.assert_allclose(gp[name], gp_plain[name], rtol=2e-5,
+                                   atol=2e-6, err_msg=name)
+    n_in, worked = (int(counts[name]) for name in (
+        "moe_picks_held", "moe_pair_rows_worked"))
+    assert worked == -(-n_in // SLAB) * SLAB <= pairs
+    assert int(counts_plain["moe_pair_rows_worked"]) == pairs
+    assert int(counts_plain["moe_picks_held"]) == n_in
+    if share == "none":
+        assert n_in == worked == 0 and not np.any(np.asarray(gx))
+    else:
+        assert np.any(np.asarray(gx)) and np.any(np.asarray(gp["We_in"]))
+        if top_k > 1:
+            assert np.any(np.asarray(gp["router"]))
+    if share == "quarter":
+        assert 0 < n_in < pairs // 2 and worked < pairs
+    if share == "mid_slab":
+        assert n_in % SLAB and worked < pairs
+    if share == "every" and not masked:
+        assert n_in == worked == pairs
+
+
 def _poisoned(rows, n_inside):
     return rows.at[n_inside:].set(jnp.nan)
 
 
+def _unwritten_is_nan(patch):
+    """What a training pass never writes holds ``nan`` (a buffer the
+    program starts uninitialised: here, made of ``nan``)."""
+    patch.setattr(jax.lax, "empty", lambda shape, dtype: jnp.full(
+        shape, jnp.nan, dtype))
+
+
 @pytest.mark.parametrize("top_k", [1, 3])
-def test_rows_never_written_reach_neither_value_nor_gradient(top_k):
-    """Select, do not scale: the sorted pairs' rows past the held groups
-    are never written by the grouped kernel, going either way. With
-    ``nan`` in every such row of ``ys`` the combine's value and its
-    gradients (the rows', the gates') are finite and the clean input's,
-    and so is the tokens' cotangent from a poisoned cotangent of
-    ``xs``, for a share with picks not held and a ``valid`` mask."""
+def test_rows_never_written_reach_neither_value_nor_gradient(
+        monkeypatch, top_k):
+    """Select, do not scale, and read no row past the groups: the
+    sorted pairs' rows past the held groups are never written by the
+    grouped kernel, going either way, nor by the training passes
+    around it. With ``nan`` in every such row of ``ys`` the combine's
+    value and its gradients (the held rows', the gates') are finite and
+    the clean input's; so are the activation's value and cotangent
+    below the groups' end from ``nan`` past it in ``gu`` and in the
+    cotangent of ``act``, and the tokens' cotangent from a poisoned
+    cotangent of ``xs``, for a share with picks not held and a
+    ``valid`` mask; and what the passes leave unwritten is left alone,
+    not zeroed."""
+    monkeypatch.setattr(moe, "_SLAB_ROWS", SLAB)
+    _unwritten_is_nan(monkeypatch)
     rng = np.random.default_rng(12)
     m, d, e, lo, hi = 24, D_ROWS, 8, 2, 6
     idx = jnp.asarray(rng.integers(0, e, (m, top_k)))
@@ -294,15 +412,18 @@ def test_rows_never_written_reach_neither_value_nor_gradient(top_k):
     held = (idx >= lo) & (idx < hi) & valid[:, None]
     n_inside = int(jnp.sum(held))
     assert 0 < n_inside < m * top_k
-    order = jnp.argsort(jnp.where(held, idx - lo, hi - lo).reshape(-1),
-                        stable=True)
+    expert = jnp.where(held, idx - lo, hi - lo).reshape(-1)
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.bincount(expert, length=hi - lo + 1)[:hi - lo]
     inside = (jnp.arange(m * top_k) < n_inside)[:, None]
+    #: where the last slab ends: rows past it are never written
+    n_worked = min(-(-n_inside // SLAB) * SLAB, m * top_k)
     gates = jnp.asarray(rng.random((m, top_k)) + 0.1, jnp.float32)
     ys = jnp.asarray(rng.normal(size=(m * top_k, d)), jnp.float32)
     probe = jnp.asarray(rng.normal(size=(m, d)), jnp.float32)
 
     def combined(ys, gates):
-        y = moe._combine_picks(ys, gates, held, inside, order)
+        y = moe._combine_picks(ys, gates, held, inside, order, sizes)
         return jnp.sum(y * probe), y
 
     (_, y), (g_ys, g_gates) = jax.value_and_grad(
@@ -312,26 +433,94 @@ def test_rows_never_written_reach_neither_value_nor_gradient(top_k):
             _poisoned(ys, n_inside), gates)
     # (no gradient asked: the rule's primal selects too)
     y_primal = moe._combine_picks(_poisoned(ys, n_inside), gates, held,
-                                  inside, order)
-    for got, want in ((y_bad, y), (g_ys_bad, g_ys), (g_gates_bad, g_gates),
-                      (y_primal, y)):
+                                  inside, order, sizes)
+    for got, want in ((y_bad, y), (g_ys_bad[:n_inside], g_ys[:n_inside]),
+                      (g_gates_bad, g_gates), (y_primal, y)):
         assert np.all(np.isfinite(np.asarray(got)))
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
     assert np.any(np.asarray(g_gates)) and np.any(np.asarray(g_ys))
-    # a pair not held takes nothing back, and gives its gate nothing
-    assert not np.any(np.asarray(g_ys)[n_inside:])
+    # a held pair's cotangent is its gate times its token's row of the
+    # value's; past the last slab nothing is written, and a pair not
+    # held gives its gate nothing
+    np.testing.assert_allclose(
+        g_ys[:n_inside], (gates.reshape(-1)[:, None] * jnp.repeat(
+            probe, top_k, axis=0))[order][:n_inside], rtol=1e-6)
+    assert np.all(np.isnan(np.asarray(g_ys)[n_worked:]))
     assert not np.any(np.asarray(g_gates)[~np.asarray(held)])
+
+    # the gated activation between the products
+    gu = jnp.asarray(rng.normal(size=(m * top_k, 2 * d)), jnp.float32)
+    cot = jnp.asarray(rng.normal(size=(m * top_k, d)), jnp.float32)
+    act_plain, back_plain = jax.vjp(
+        lambda gu: jax.nn.silu(gu[:, :d]) * gu[:, d:], gu)
+    act, back = jax.vjp(lambda gu: moe._gated_rows(gu, sizes),
+                        _poisoned(gu, n_inside))
+    (d_gu,), (d_gu_plain,) = back(_poisoned(cot, n_inside)), back_plain(cot)
+    for got, want in ((act, act_plain), (d_gu, d_gu_plain)):
+        assert np.all(np.isfinite(np.asarray(got)[:n_inside]))
+        np.testing.assert_allclose(got[:n_inside], want[:n_inside],
+                                   rtol=1e-6, atol=1e-7)
+        # (``act`` starts uninitialised; the cotangent is written over
+        # ``gu``, poisoned here)
+        assert np.all(np.isnan(np.asarray(got)[n_worked:]))
+    np.testing.assert_array_equal(     # no gradient asked: every row
+        moe._gated_rows(gu, sizes), act_plain)
 
     tokens = jnp.asarray(rng.normal(size=(m, d)), jnp.float32)
     cot = jnp.asarray(rng.normal(size=(m * top_k, d)), jnp.float32)
-    xs, back = jax.vjp(
-        lambda t: moe._rows_of_pairs(top_k, t, order, held), tokens)
-    np.testing.assert_array_equal(xs, tokens[order // top_k])
-    (g_tokens,) = back(jnp.where(inside, cot, 0))
-    (g_tokens_bad,) = back(_poisoned(cot, n_inside))
+    (order_r, sizes_r, inside_r, xs, worked), back = jax.vjp(
+        lambda t: moe._sorted_pairs(top_k, hi - lo, t, expert, held),
+        tokens)
+    np.testing.assert_array_equal(order_r, order)
+    np.testing.assert_array_equal(sizes_r, sizes)
+    np.testing.assert_array_equal(inside_r, inside)
+    assert int(worked) == -(-n_inside // SLAB) * SLAB
+    np.testing.assert_array_equal(xs[:n_inside],
+                                  tokens[order // top_k][:n_inside])
+    assert np.all(np.isnan(np.asarray(xs)[n_worked:]))
+    no = [np.zeros(a.shape, jax.dtypes.float0)
+          for a in (order_r, sizes_r, inside_r)]
+
+    def tokens_cotangent(d_xs):
+        return back((*no, d_xs, np.zeros((), jax.dtypes.float0)))[0]
+
+    g_tokens = tokens_cotangent(jnp.where(inside, cot, 0))
+    g_tokens_bad = tokens_cotangent(_poisoned(cot, n_inside))
     assert np.all(np.isfinite(np.asarray(g_tokens_bad)))
     np.testing.assert_allclose(g_tokens_bad, g_tokens, rtol=1e-6)
     assert not np.any(np.asarray(g_tokens)[~np.asarray(valid)])
+
+
+@pytest.mark.parametrize("kernel", [False, "interpret"])
+def test_the_layer_reads_nothing_its_passes_left_unwritten(
+        monkeypatch, kernel):
+    """The whole layer under a gradient, ``nan`` wherever a training
+    pass wrote nothing (``xs``, ``act``, the cotangents of ``gu`` and
+    of ``ys``, past the held pairs' last slab): the value and every
+    gradient are finite and are those of buffers that start as zeros,
+    through ``ragged_dot`` and through the library's kernel and its
+    transposes."""
+    monkeypatch.setattr(moe, "_SLAB_ROWS", SLAB)
+    full, x, probe = _expert_layer()
+    held, top_k = (2, 6), 3
+    valid = jnp.asarray(np.random.default_rng(8).random(x.shape[0]) < 0.7)
+
+    def grads():
+        return jax.value_and_grad(_loss_and_counts, argnums=(0, 1),
+                                  has_aux=True)(
+            _share(full, *held), x, probe, valid, held, top_k,
+            kernel=kernel)
+
+    (value, counts), (gp, gx) = grads()
+    assert int(counts["moe_pair_rows_worked"]) < x.shape[0] * top_k
+    with monkeypatch.context() as patch:
+        _unwritten_is_nan(patch)
+        (value_bad, _), (gp_bad, gx_bad) = grads()
+    for got, want in ((value_bad, value), (gx_bad, gx), *(
+            (gp_bad[name], gp[name]) for name in gp)):
+        assert np.all(np.isfinite(np.asarray(got)))
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+    assert np.any(np.asarray(gx)) and np.any(np.asarray(gp["router"]))
 
 
 def _row_scatters(text):
@@ -357,11 +546,12 @@ def _row_results(text, op):
 @pytest.mark.parametrize("top_k", [1, 3])
 def test_the_layers_gradient_scatters_no_row(monkeypatch, top_k):
     """The rules are engaged: ``jax.grad`` of the layer lowers to
-    gathers alone over the pairs' rows (the integer ``bincount`` and
-    the gates' ``[M, E]`` scatter stay), a pick at a time, where plain
-    indexing's holds the two scatter-adds, the float32 ``[M, k, D]``
-    and selects over the sorted pairs' rows; forward, the layer's
-    program is plain indexing's."""
+    gathers alone over the pairs' rows (the gates' ``[M, E]`` scatter
+    stays, the ONE scatter left: the sizes are counted by comparison),
+    a pick at a time, and to the four loops over the held pairs' slabs,
+    where plain indexing's holds the two scatter-adds, ``bincount``'s,
+    the float32 ``[M, k, D]``, selects over the sorted pairs' rows and
+    no loop; forward, the layer's program is plain indexing's."""
     full, x, probe = _expert_layer(seed=7, d=D_ROWS)
     held = (2, 6)
     args = (_share(full, *held), x, probe, None, held, top_k,
@@ -383,8 +573,16 @@ def test_the_layers_gradient_scatters_no_row(monkeypatch, top_k):
         _plain_indexing(patch)
         grad_plain, fwd_plain = lowered()
     assert _row_scatters(grad) == []
-    assert "stablehlo.scatter" in grad       # bincount's, the gates'
+    scatter = '"stablehlo.scatter"('
+    assert grad.count(scatter) == 1          # the gates'
+    assert grad_plain.count(scatter) == 4    # ... bincount's, the rows'
     assert len(_row_scatters(grad_plain)) == 2
+    # the gather of xs, the activation forward and back, d_ys; each
+    # but the one over ``gu`` in a conditional that scopes its buffer
+    assert grad.count("stablehlo.while") == 4
+    assert grad.count("stablehlo.case") == 3
+    assert "stablehlo.while" not in grad_plain + fwd
+    assert "stablehlo.case" not in grad_plain + fwd
     # plain indexing: all the pairs' rows at once, forward, both ways
     assert _row_results(grad_plain, "gather") == [m * top_k] * 2
     # the rules: the tokens' rows for the pairs and, going back, the
@@ -393,7 +591,7 @@ def test_the_layers_gradient_scatters_no_row(monkeypatch, top_k):
     assert _row_results(grad, "gather") == sorted(
         [m * top_k] * 2 + [m] * (3 * top_k))
     gather = '"stablehlo.gather"('
-    # (and the pairs' gates in the sorted order, numbers)
+    # (and the held pairs' gates in the sorted order, numbers)
     assert grad.count(gather) == grad_plain.count(gather) + 3 * top_k + 1
     # no float32 [M, k, D], and no select over the sorted pairs' rows
     # (the one on ``gu`` is as wide as the experts, not as the layer)
@@ -406,6 +604,41 @@ def test_the_layers_gradient_scatters_no_row(monkeypatch, top_k):
     # forward only (a served program): the same gathers, nothing more
     assert fwd.count(gather) == fwd_plain.count(gather) >= 2
     assert fwd == fwd_plain
+
+
+@pytest.mark.parametrize("rule", moe.GATE_RULES)
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("top_k", [1, 3])
+def test_with_no_gradient_asked_the_layer_is_plain_indexings_program(
+        monkeypatch, top_k, masked, rule):
+    """A served program is what it was: with no gradient asked the
+    layer, its value and the counts a served block returns, lowers to
+    the text of plain indexing character for character, whatever rules
+    stand by for a gradient: no loop, no uninitialised buffer, the
+    sizes by ``bincount``. (``scripts/compile_cell.py --hash`` says the
+    same of the served cells' programs at their real sizes.)"""
+    full, x, _ = _expert_layer(seed=7, d=D_ROWS)
+    held = (2, 6)
+    valid = (jnp.asarray(np.random.default_rng(8).random(x.shape[0]) < 0.7)
+             if masked else None)
+
+    def served(p, x, valid):
+        y, counts = moe.dropless_moe(
+            p, x, valid, top_k=top_k, experts_held=held, kernel=False,
+            gate_rule=rule, route_eps=1e-6)
+        del counts["moe_pair_rows_worked"]      # as the block does
+        return y, counts
+
+    def lowered():
+        return jax.jit(served).lower(_share(full, *held), x,
+                                     valid).as_text()
+
+    text = lowered()
+    with monkeypatch.context() as patch:
+        _plain_indexing(patch)
+        assert text == lowered()
+    assert "stablehlo.while" not in text and "empty" not in text.lower()
+    assert text.count('"stablehlo.scatter"(') == 1      # bincount's
 
 
 def test_route_names_its_epsilon():

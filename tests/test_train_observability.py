@@ -189,7 +189,7 @@ class TestTelemetryExactness:
                         jax.tree.leaves(observed.params)):
             assert np.array_equal(np.asarray(a), np.asarray(b))
         names = ("moe_picks", "moe_picks_held", "moe_experts_touched",
-                 "moe_load_max", "moe_layer_steps")
+                 "moe_load_max", "moe_layer_steps", "moe_pair_rows_worked")
         records = MetricsLog.read(path)
         assert len(records) == 2
         for rec in records:
@@ -198,11 +198,17 @@ class TestTelemetryExactness:
             assert rec["moe_picks"] == 9 * 2 * 12 * 2
             assert 0 < rec["moe_picks_held"] < rec["moe_picks"]
             assert 0 < rec["moe_experts_touched"] <= 9 * 4
+            # the training passes' slabs reach as far as the held pairs
+            # (one slab a layer at this size: all its 48 pairs' rows)
+            assert (rec["moe_picks_held"] <= rec["moe_pair_rows_worked"]
+                    <= rec["moe_picks"])
         spans = [e for e in tracer.events()
                  if e["ph"] == "X" and e["name"] == "train.dispatch"]
         assert len(spans) == 2
         assert all(set(names) <= set(e["args"]) for e in spans)
         assert tracer.latest_counters()["train_moe_picks"] == 2 * 432
+        assert (tracer.latest_counters()["train_moe_pair_rows_worked"]
+                == sum(rec["moe_pair_rows_worked"] for rec in records))
         # the dark net carries the same counts, unfetched
         assert set(names) <= set(dark.train_telemetry.health)
 
