@@ -129,7 +129,7 @@ def decode_pallas_calls(eng) -> int:
 
     lowered = eng._decode_jit.lower(
         eng._params, eng._state, eng._pool,
-        eng._paged_tables(eng._kv_tabs), eng._toks,
+        eng.kv.pack(eng._kv_tabs), eng._toks,
         jnp.asarray(eng._temps), jnp.asarray(eng._top_ks),
         jax.random.key(0)).as_text()
     return lowered.count("tpu_custom_call")

@@ -169,6 +169,41 @@ class BaseRecurrentLayer(FeedForwardLayer):
 
     scope_group = "mixer"
 
+    def serving_caches(self):
+        """What the serving engine holds for this layer between
+        dispatches. None: nothing it can serve (the layer is refused);
+        ``()``: one state row a slot, carried whole; else a tuple of
+        :class:`PagedCache`, the caches it pages."""
+        return None
+
+
+@dataclasses.dataclass(frozen=True)
+class PagedCache:
+    """One cache of a layer that a serving engine keeps in pool blocks
+    through a block table a slot (serving/kv_memory.py groups the
+    layers' caches that agree into kinds, a pool each)."""
+
+    #: tokens back a query reads; ``aligned``: from the last multiple of
+    #: ``window`` up (a row that crosses it releases every block below)
+    window: int
+    #: tokens one entry covers: 1, or a chunk of them (a summary)
+    entry_tokens: int = 1
+    aligned: bool = False
+    #: KV heads x head dim, and the query heads a KV head serves
+    token_width: int = 0
+    group: int = 1
+    #: the pool leaves and the table operands' names in the layer's state
+    leaves: tuple = ("pk", "pv")
+    operands: tuple = ("table", "base")
+    #: the engine's stats count the cache's blocks under
+    #: ``<name>_blocks_allocated`` / ``_released`` ("": not apart)
+    name: str = ""
+    #: where the layer counts what ONE layer's attention reads of a
+    #: dispatch itself: ``(lengths, queries=, tokens=, steps=)`` ->
+    #: counters by name. None: the paged kernel's walk is counted
+    #: (nn/layers/attention.py ``paged_walk_stats``)
+    reads: object = dataclasses.field(default=None, compare=False)
+
 
 @register_bean("GravesLSTM")
 @dataclasses.dataclass

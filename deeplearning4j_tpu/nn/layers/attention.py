@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.nn.conf.layers import BaseRecurrentLayer
+from deeplearning4j_tpu.nn.conf.layers import BaseRecurrentLayer, PagedCache
 from deeplearning4j_tpu.nn.conf.serde import register_bean
 from deeplearning4j_tpu.nn.layers.base import LayerImplBase
 from deeplearning4j_tpu.nn.weights import init_weights
@@ -118,11 +118,11 @@ class MultiHeadSelfAttention(BaseRecurrentLayer):
     # tokens older than this many steps fall out of the window
     stream_max_t: int = 512
 
-    #: what the serving engine reads off a recurrent bean: "kv" = an
-    #: attention cache it may page; "slot" = one state row a slot
-    serving_state = "kv"
     #: the impl names its own parts (``attn/qkv`` ...)
     scope_group = None
+
+    def serving_caches(self):
+        return (attention_cache(self),)
 
 
 class AttentionImpl(LayerImplBase):
@@ -624,8 +624,20 @@ class TransformerBlock(BaseRecurrentLayer):
     use_flash_paged: Optional[object] = None
     stream_max_t: int = 512
 
-    serving_state = "kv"
     scope_group = None
+
+    def serving_caches(self):
+        return (attention_cache(self),)
+
+
+def attention_cache(bean) -> PagedCache:
+    """The one cache an attention layer holds in a served engine: keys
+    and values a token, ``stream_max_t`` tokens back."""
+    kv_heads = getattr(bean, "n_kv_heads", bean.n_heads)
+    return PagedCache(
+        bean.stream_max_t, group=bean.n_heads // kv_heads,
+        token_width=kv_heads * (getattr(bean, "head_dim", 0)
+                                or bean.n_out // bean.n_heads))
 
 
 def _layer_norm(x, g, b, eps=1e-5):

@@ -50,9 +50,13 @@ state a call is handed:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from deeplearning4j_tpu.nn.conf.layers import PagedCache
 from deeplearning4j_tpu.nn.layers.attention import (
     _paged_flash_attention,
     _paged_table_entries,
@@ -256,6 +260,61 @@ def visible(pos, window: int, chunk: int):
     """Summaries a query at ``pos`` reads: those of every window before
     its own."""
     return pos // window * (window // chunk)
+
+
+def paged_caches(*, window: int, chunk: int, longest: int,
+                 token_width: int):
+    """The two caches an EVA layer holds in a served engine: its
+    summaries (one entry a ``chunk`` of tokens, up to the longest
+    context: never released while the row lives) and its window's exact
+    keys (aligned)."""
+    if longest <= window:
+        raise ValueError(
+            f"stream_max_t {longest} (the longest context) must pass "
+            f"eva_window {window}")
+    reads = functools.partial(_reads, window=window, chunk=chunk)
+    return (
+        PagedCache(longest, entry_tokens=chunk, token_width=token_width,
+                   leaves=("sk", "sv"), operands=("stable", "sbase"),
+                   reads=functools.partial(reads, "summary")),
+        PagedCache(window, aligned=True, token_width=token_width,
+                   name="eva_window",
+                   reads=functools.partial(reads, "window")))
+
+
+def _reads(which: str, lengths, *, window: int, chunk: int,
+           queries: int = 1, tokens=None, steps: int = 1):
+    """What ONE layer's attention reads of one cache for a dispatch
+    whose rows hold ``lengths`` tokens (host numpy): the entries (the
+    window's exact keys from each row's aligned floor up, or the
+    summaries of the windows before it) and the (query, entry) pairs it
+    scores. A decode dispatch (``queries`` 1) is ``steps`` steps, each a
+    position on, a query a row. An admission's chunk of ``tokens``
+    queries (it never straddles a window's end) reads each entry once
+    and scores every pair under the causal edge."""
+    n = 1 if queries == 1 else queries if tokens is None else tokens
+    pos = (np.asarray(lengths, np.int64)[:, None]
+           + np.arange(steps if queries == 1 else 1))
+    if which == "window":
+        read = pos % window + n
+        pairs = n * (pos % window) + n * (n + 1) // 2
+    else:
+        read = visible(pos, window, chunk)
+        pairs = n * read
+    return {f"eva_{which}_entries_read": int(np.sum(read)),
+            f"eva_{which}_pairs_scored": int(np.sum(pairs))}
+
+
+#: the keys a served engine's stats hold for an EVA layer's caches, at
+#: zero (whatever the net, so that a reader finds them): the summaries
+#: the programs wrote and what ``_reads`` counts, each also under
+#: ``prefill_``, and the window's blocks (``PagedCache.name``)
+STATS = {
+    **{prefix + name: 0 for prefix in ("", "prefill_")
+       for name in ("eva_summaries_written",
+                    "eva_window_entries_read", "eva_summary_entries_read",
+                    "eva_window_pairs_scored", "eva_summary_pairs_scored")},
+    "eva_window_blocks_allocated": 0, "eva_window_blocks_released": 0}
 
 
 def paged(q, k, v, cache, mu, phi, *, window: int, chunk: int,

@@ -91,7 +91,10 @@ from deeplearning4j_tpu.nn.conf.layers import (
 )
 from deeplearning4j_tpu.nn.conf.serde import register_bean
 from deeplearning4j_tpu.nn.layers import eva, mamba2
-from deeplearning4j_tpu.nn.layers.attention import AttentionImpl
+from deeplearning4j_tpu.nn.layers.attention import (
+    AttentionImpl,
+    attention_cache,
+)
 from deeplearning4j_tpu.nn.layers.base import LayerImplBase
 from deeplearning4j_tpu.nn.layers.moe import (
     dropless_moe,
@@ -367,18 +370,18 @@ class HybridMoeBlock(BaseRecurrentLayer):
     #: ``moe/*``, ``ffn``)
     scope_group = None
 
-    @property
-    def serving_state(self) -> str:
-        """``"kv"``: an attention cache, paged by the engine;
-        ``"slot"``: one row a slot, carried whole (the Mamba-2 mixer's
-        convolution tail and SSM state, the short convolution's
-        tail); ``"eva"``: TWO paged caches in one layer, the window's
-        keys (``eva_window``, released whole at its aligned end) and one
-        entry an ``eva_chunk`` of every token up to ``stream_max_t``
-        (never released while the row lives)."""
+    def serving_caches(self):
+        """By the mixer: an attention cache; the EVA mixer's two; or
+        ``()``, one row a slot (the Mamba-2 mixer's convolution tail
+        and SSM state, the short convolution's tail)."""
+        if self.mixer == "attention":
+            return (attention_cache(self),)
         if self.mixer == "eva":
-            return "eva"
-        return "kv" if self.mixer == "attention" else "slot"
+            return eva.paged_caches(
+                window=self.eva_window, chunk=self.eva_chunk,
+                longest=self.stream_max_t,
+                token_width=self.n_heads * self.head_dim)
+        return ()
 
     @property
     def held(self):

@@ -131,7 +131,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-import itertools
 import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
@@ -140,20 +139,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.nn.layers.attention import (
-    _paged_blocks_per_step,
-    _paged_table_entries,
-    guard_streamable,
-    paged_walk_stats,
-)
+from deeplearning4j_tpu.nn.layers.attention import guard_streamable
 from deeplearning4j_tpu.nn.streaming import scan_length_bucket
 from deeplearning4j_tpu.profiler.scopes import scope
-from deeplearning4j_tpu.serving.block_pool import (
-    BlockPool,
-    BlockTable,
-    KindTables,
-)
+from deeplearning4j_tpu.serving.block_pool import KindTables
 from deeplearning4j_tpu.serving.faults import FaultEvent, FaultPlan, poison_rows
+from deeplearning4j_tpu.serving.kv_memory import KvMemory
 from deeplearning4j_tpu.serving.prefix_cache import RadixPrefixCache
 from deeplearning4j_tpu.serving.sampler import (
     residual_sample,
@@ -554,66 +545,6 @@ def _lm_shape_of(net):
     return forward, vocab, beans
 
 
-#: what a paged layer's state holds of the pools (an ``eva`` layer all
-#: four); the rest of it is the dispatch's table operands
-_POOL_LEAVES = ("pk", "pv", "sk", "sv")
-
-
-def _unpack_tables(tabs, rings=None):
-    """The four block-table operands of a paged dispatch
-    (``AttentionImpl._paged_attend`` says what each holds), a dict a
-    layer KIND, out of the ONE int32 array they travel in: for each
-    kind ``table`` and ``base`` ``[B, S_k]``, then a ``floor`` column a
-    kind, then ONE ``filled`` column, the same for every kind (one
-    kind: ``[B, 2 S + 2]``). ``rings`` are the kinds' ring widths
-    ``S_k`` (None: one kind, its width read off the shape). Slices of a
-    device array inside a program, writable views of a numpy array on
-    the host."""
-    if rings is None:
-        rings = ((tabs.shape[1] - 2) // 2,)
-    floors = 2 * sum(rings)
-    out, at = [], 0
-    for k, s in enumerate(rings):
-        out.append({"table": tabs[:, at:at + s],
-                    "base": tabs[:, at + s:at + 2 * s],
-                    "floor": tabs[:, floors + k],
-                    "filled": tabs[:, floors + len(rings)]})
-        at += 2 * s
-    return out
-
-
-@dataclasses.dataclass
-class _KvKind:
-    """The attention layers of one window (``stream_max_t``): they
-    share a block table a slot, and a pool of blocks that no other kind
-    allocates from. ``ring`` is the table's ring width, ``slot_worst``
-    the most blocks one slot can hold of it."""
-
-    window: int
-    layers: List[str]
-    #: query heads a KV head of these layers serves (the paged kernel's
-    #: form, and with it its compute block, follows it)
-    group: int = 1
-    #: numbers a token's keys (or values) are in one layer: KV heads x
-    #: head dim
-    token_width: int = 0
-    ring: int = 0
-    slot_worst: int = 0
-    pool: Optional[BlockPool] = None
-    #: tokens one block of the kind covers: ``block_tokens``, or for a
-    #: kind that holds one ENTRY a chunk of tokens (an ``eva`` layer's
-    #: summaries) ``block_tokens`` chunks
-    span: int = 0
-    #: the window is ALIGNED (an ``eva`` layer's exact keys): the row
-    #: reads from the last multiple of ``window`` up, and every block
-    #: below it is released when the row has crossed it
-    aligned: bool = False
-    #: the pool leaves of the kind's layers and the names its table
-    #: operands take in a layer's state (a layer of two kinds holds both)
-    leaves: Tuple[str, str] = ("pk", "pv")
-    operands: Tuple[str, str] = ("table", "base")
-
-
 class DecodeEngine:
     """Slot-multiplexed batched decoding for one LM-shaped network.
 
@@ -872,76 +803,50 @@ class DecodeEngine:
         guard_streamable(iter(beans))
         from deeplearning4j_tpu.nn.conf.layers import BaseRecurrentLayer
 
-        windows = []
-        #: layers whose streaming state is one row a SLOT (a Mamba-2
-        #: mixer's convolution tail and SSM state), kept slot-major
-        #: beside the paged KV leaves; only attention layers get block
-        #: tables. Their prefill is masked (nn/layers/mamba2.py), which
-        #: is what a right-padded bucket asks of a carried state
+        #: layers whose streaming state is one row a SLOT (a recurrent
+        #: mixer's convolution tail and state), kept slot-major beside
+        #: the paged KV leaves; only paged layers get block tables.
+        #: Their prefill is masked (nn/layers/hybrid.py), which is what
+        #: a right-padded bucket asks of a carried state
         self._state_layers: List[str] = []
         #: some layer is told which slots hold a request (a block whose
         #: experts route live rows only): the decode program takes the
         #: ``live`` operand
         self._wants_live = any(getattr(bean, "wants_live", False)
                                for _, bean in beans)
-        attn_items = []
-        #: layers that hold TWO paged caches (``serving_state`` "eva":
-        #: the aligned window's keys and one summary entry a chunk)
-        eva_items = []
+        #: the layers that page caches, by what each declares
+        paged = []
         for name, bean in beans:
             # carried-state recurrents only: RnnOutputLayer is
             # recurrent-typed but stateless, so it streams fine
             if not isinstance(bean, BaseRecurrentLayer):
                 continue
             # the engine reads what a bean says of itself, never its
-            # class: "kv" = an attention cache (paged here), "slot" =
-            # one row a slot
-            kind = getattr(bean, "serving_state", None)
-            if kind == "slot":
-                self._state_layers.append(name)
-                continue
-            if kind == "eva":
-                eva_items.append((name, bean))
-                windows.append(bean.stream_max_t)
-                continue
-            if kind != "kv":
+            # class: the caches it pages, or ``()``, one row a slot
+            caches = bean.serving_caches()
+            if caches is None:
                 raise ValueError(
                     f"DecodeEngine streams through the attention KV "
                     f"cache; layer {name} "
                     f"({type(bean).__name__}) carries a recurrent "
                     "state this engine's masked slot prefill does not "
                     "support")
-            attn_items.append((name, bean))
-            windows.append(bean.stream_max_t)
-        if not windows:
+            if caches:
+                paged.append((name, bean, caches))
+            else:
+                self._state_layers.append(name)
+        if not paged:
             raise ValueError(
                 "DecodeEngine requires at least one attention layer")
-        #: the attention layers by KIND, one a window, widest first: a
-        #: slot has a block table a kind, and a kind its own pool of
-        #: blocks, so that a narrow window's layers hold only what they
-        #: can still reach (one kind: every net whose layers agree)
-        def kind_of(w):
-            mine = [(name, bean) for name, bean in attn_items
-                    if bean.stream_max_t == w]
-            bean = mine[0][1]
-            kv_heads = getattr(bean, "n_kv_heads", bean.n_heads)
-            return _KvKind(
-                w, [name for name, _ in mine],
-                group=bean.n_heads // kv_heads,
-                token_width=kv_heads * (getattr(bean, "head_dim", 0)
-                                        or bean.n_out // bean.n_heads))
-
-        self._kinds: List[_KvKind] = [
-            kind_of(w) for w in sorted(set(windows), reverse=True)
-        ] if not eva_items else self._eva_kinds(eva_items, attn_items,
-                                                block_tokens)
-        self._eva = bool(eva_items)
-        # the longest prompt: the narrowest window where a cold
-        # admission prefills a dense row (it takes no band); the widest
-        # where several kinds make every admission a paged one, whose
-        # programs band each layer by its own window
-        self.window = (min(windows) if len(self._kinds) == 1
-                       else max(windows))
+        #: the KV memory: the paged layers' caches by KIND, a slot's
+        #: block tables, the pools (serving/kv_memory.py)
+        self.kv = KvMemory([(name, caches) for name, _, caches in paged],
+                           block_tokens)
+        # the longest prompt: the widest kind's window (a cold admission
+        # of a one-kind net prefills a dense row, which takes no band;
+        # several kinds make every admission a paged one, whose programs
+        # band each layer by its own window)
+        self.window = self.kv.wmax
         #: token ids in through a gather (the first layer embeds them)
         #: or, for a net whose first layer takes ``n_in == vocab``
         #: columns, one-hot
@@ -963,7 +868,7 @@ class DecodeEngine:
                  "recurrent state"),
                 ("fused_rounds", fused_rounds,
                  "the fused scan has no counters or live-row operand")]
-        if len(self._kinds) > 1:
+        if len(self.kv.kinds) > 1:
             two = "over two kinds of KV block"
             refused += [
                 ("prefix_cache_rows", prefix_cache_rows,
@@ -985,7 +890,7 @@ class DecodeEngine:
                 raise ValueError(
                     f"layers {self._state_layers} carry a slot state "
                     f"and the attention layers have windows "
-                    f"{[k.window for k in self._kinds]}: a paged "
+                    f"{[k.window for k in self.kv.kinds]}: a paged "
                     "admission does not carry slot state yet")
         unsharded = [name for name, bean in beans
                      if not getattr(bean, "shards_over_tp", True)]
@@ -993,33 +898,16 @@ class DecodeEngine:
             refused.append(
                 ("tp", tp if tp > 1 else 0,
                  "experts and grouped KV heads are not sharded over tp"))
-        if eva_items:
-            bean = eva_items[0][1]
-            w, c = bean.eva_window, bean.eva_chunk
-            # a chunk is pooled from ONE pool block, and every query of
-            # a dispatch reads from one aligned floor
-            for option, value, bad, why in (
-                    ("block_tokens", block_tokens,
-                     block_tokens % c != 0,
-                     f"eva_chunk {c} does not divide it: a completed "
-                     "chunk must lie inside one pool block"),
-                    ("prefill_chunk", prefill_chunk,
-                     prefill_chunk < 1 or w % prefill_chunk != 0,
-                     f"an admission goes through the pools in chunks "
-                     f"that divide eva_window {w}, so that a chunk "
-                     "never straddles a window's end")):
-                if bad:
-                    raise ValueError(
-                        f"{option}={value!r} is not supported for this "
-                        f"net (layers {[n for n, _ in eva_items]}): "
-                        f"{why}")
-        for option, value, why in refused:
-            if value:
-                layers = (self._state_layers or unsharded
-                          or [k.layers for k in self._kinds])
-                raise ValueError(
-                    f"{option}={value!r} is not supported for this net "
-                    f"(layers {layers}): {why}")
+        layers = (self._state_layers or unsharded
+                  or [k.layers for k in self.kv.kinds])
+        bad = list(self.kv.misfits(prefill_chunk)) + [
+            (option, value, layers, why)
+            for option, value, why in refused if value]
+        if bad:
+            option, value, layers, why = bad[0]
+            raise ValueError(
+                f"{option}={value!r} is not supported for this net "
+                f"(layers {layers}): {why}")
         # -- tensor-parallel head sharding (ISSUE 12; default tp=1 =
         # the bit-identical single-chip engine) -----------------------
         if tp < 1:
@@ -1027,23 +915,23 @@ class DecodeEngine:
         self.tp = int(tp)
         self.tp_ctx: Optional[TPContext] = None
         if self.tp > 1:
-            for name, bean in attn_items:
+            for name, bean, _ in paged:
                 if bean.n_heads % self.tp:
                     raise ValueError(
                         f"tp {self.tp} does not divide layer {name}'s "
                         f"n_heads ({bean.n_heads}): head sharding "
                         "slices whole heads")
             self.tp_ctx = TPContext(self.tp,
-                                    [name for name, _ in attn_items])
+                                    [name for name, _, _ in paged])
         #: pallas paged-attention kernel toggle (ISSUE 12 satellite):
         #: None = auto (TPU only; the XLA gather path is the off-TPU
         #: fallback), True = force (TPU), False = gather always,
         #: "interpret" = run the kernel in pallas interpret mode (the
-        #: CPU parity-testing hook). Stamped onto the net's attention
+        #: CPU parity-testing hook). Stamped onto the net's paging
         #: beans — the engine owns its net in serving deployments.
         self.use_flash_paged = use_flash_paged
         if use_flash_paged is not None:
-            for _, bean in attn_items + eva_items:
+            for _, bean, _ in paged:
                 bean.use_flash_paged = use_flash_paged
         cast_bytes = self._adopt_weights()
         #: the weights every dispatch reads: the net's, resident at
@@ -1088,92 +976,42 @@ class DecodeEngine:
         self._tenant_hists: Dict[str, Any] = {}
         self.tenant_stats: Dict[str, Dict[str, int]] = {}
         # -- the KV block pool (ISSUE 6) ------------------------------
-        self.block_tokens = bt = int(block_tokens)
-        self._wmax = max(windows)      # the widest kind's window
-        self._kv_tabs: List[Optional[KindTables]] = (
-            [None] * self.n_slots)
-        if bt < 1 or (bt & (bt - 1)):
-            raise ValueError(
-                f"block_tokens {bt} must be a power of two")
-        if bt > self.window:
-            raise ValueError(
-                f"block_tokens {bt} exceeds the cache window "
-                f"({self.window}) — a block must fit inside it")
-        # ring width: the window, plus the widest single dispatch
-        # (a blocking-mode suffix chunk can be a whole window) plus
-        # one round's decode/verify writes — sized so a logical
-        # block is never recycled while any in-flight query can
-        # still reach it (see AttentionImpl._paged_attend). A fused
-        # scan writes K rounds of decode tokens before the host sees
-        # any of them: the ring covers the widest single dispatch,
-        # whichever path issues it
+        # what one round writes of a slot's cache: a fused scan writes
+        # K rounds of decode tokens before the host sees any of them
         round_write = max(
             self.decode_chunk + self.spec_draft_len + 1,
             self.fused_rounds * self.decode_chunk)
-        # (with several kinds every admission is paged, and a chunked
-        # one's widest dispatch is its chunk)
-        dispatch = (self.window if len(self._kinds) == 1
-                    else self.prefill_chunk or self.window)
-        for kind in self._kinds:
-            kind.span = span = kind.span or bt
-            kind.ring = (
-                -(-kind.window // span) + -(-dispatch // span)
-                + -(-round_write // span) + 3)
-            # one slot's worst-case residency: a full window of
-            # blocks, one dispatch of appends, plus boundary slack
-            # (the ring width is ADDRESSING span, not occupancy:
-            # slid-out blocks free as they expire). A one-kind net's
-            # prompts fit its window, so there the dispatch is one
-            # round of decode/verify writes; so it is under an ALIGNED
-            # window, whose admission chunks never straddle its end and
-            # find the window before it released
-            kind.slot_worst = (-(-kind.window // span)
-                               + -(-(round_write if kind.window
-                                     >= self.window or kind.aligned else
-                                     max(round_write, dispatch)) // span)
-                               + 3)
-        self._ring_slots = self._kinds[0].ring
-        slot_worst = sum(k.slot_worst for k in self._kinds)
-        if kv_blocks is None:
-            # default: a whole window for every slot and every trie
-            # entry, with per-slot append slack, of every kind
-            kv_blocks = max(
-                sum(-(-k.window // k.span) for k in self._kinds)
-                * (self.n_slots + int(prefix_cache_rows))
-                + len(self._kinds) * self.n_slots
-                * (-(-round_write // bt) + 2),
-                slot_worst)
-        #: blocks of all kinds together; several kinds share them out
-        #: by what a slot can hold of each (``slot_worst``), so that
-        #: every kind runs out at the same number of full slots
-        self.kv_blocks = int(kv_blocks)
-        if self.kv_blocks < slot_worst:
-            raise ValueError(
-                f"kv_blocks {self.kv_blocks} cannot hold one "
-                f"slot's window + one round of writes "
-                f"({slot_worst} blocks of {bt} tokens)")
         #: the compiler options of the programs built from here on
         #: (``_jit``): decided once the kinds' pools are sized
         self._jit_options = None
-        left = self.kv_blocks
-        for i, kind in enumerate(self._kinds):
-            n = (left if i == len(self._kinds) - 1 else max(
-                kind.slot_worst,
-                self.kv_blocks * kind.slot_worst // slot_worst))
-            kind.pool = BlockPool(n, kind.span, jit_wrap=self._jit)
-            left -= n
+        self.block_tokens = bt = self.kv.block_tokens
+        #: the engine's counters, and those the KV memory keeps in it
+        self.stats: Dict[str, Any] = {}
+        self.kv.size(
+            kv_blocks=kv_blocks, n_slots=self.n_slots,
+            # (with several kinds every admission is paged, and a
+            # chunked one's widest dispatch is its chunk)
+            dispatch=(self.window if len(self.kv.kinds) == 1
+                      else self.prefill_chunk or self.window),
+            round_write=round_write, trie_rows=prefix_cache_rows,
+            decode_steps=self.decode_chunk, stats=self.stats,
+            jit_wrap=self._jit, tp_ctx=self.tp_ctx,
+            relieve=self._paged_reserve, span=self._span)
+        self.kv_blocks = self.kv.kv_blocks
+        #: one entry a slot (``kv.tabs`` itself): its block tables
+        self._kv_tabs = self.kv.tabs
         cell = jnp.dtype(net._compute_dtype or net._dtype).itemsize
         self._jit_options = _compiler_options(min(
             k.pool.n_blocks * bt * k.token_width * cell // self.tp
-            for k in self._kinds))
+            for k in self.kv.kinds))
         #: the widest kind's allocator (a one-kind net's only one)
-        self.block_pool = self._kinds[0].pool
+        self.block_pool = self.kv.kinds[0].pool
         #: the prefix trie: entries lease pool BLOCKS (zero-copy); the
         #: row count caps entries, the block pool caps bytes
         self.prefix_cache = (
             RadixPrefixCache(prefix_cache_rows, bt,
                              ref_block=self.block_pool.ref,
-                             release_block=self._release_block)
+                             release_block=self.kv.release)
             if prefix_cache_rows else None)
         # -- tiered KV spill store (ISSUE 17; default off = the
         # evict-to-recompute engine). Trie victims export via the
@@ -1312,34 +1150,15 @@ class DecodeEngine:
         #: queue_timeout_s bounds time-to-FIRST-admission only, so a
         #: fault-retried request waiting in the queue again is exempt
         self._started: set = set()
-        self.stats: Dict[str, Any] = {
+        self.stats.update({
             "tokens_generated": 0, "requests_finished": 0,
             "decode_time_s": 0.0, "chunks": 0, "occupancy_sum": 0.0,
             "admitted": 0, "evicted": 0, "prefill_tokens": 0,
             "prefill_tokens_skipped": 0, "chunks_scheduled": 0,
             "spec_rounds": 0, "spec_fallback_rounds": 0,
             "spec_drafted": 0, "spec_accepted": 0,
-            # block-pool gauges (gateway /v1/metrics exports them)
-            "blocks_free": self.kv_blocks, "blocks_used": 0,
-            "cow_copies": 0, "prefix_blocks_spliced": 0,
-            "frag_tokens": 0, "preempted": 0,
-            "paged_admit_deferred": 0, "qos_preempted": 0,
-            # the paged kernel's walk (ISSUE 25): pool blocks a
-            # dispatch's tables make one layer's call copy, and the
-            # blocks' worth of keys it scores (whole compute blocks),
-            # summed over dispatches, with the grid steps and loop
-            # trips the call pays (ISSUE 30); the compute block's size
-            # and a decode row's longest walk land with the pool
-            "paged_blocks_live": 0, "paged_blocks_walked": 0,
-            "paged_blocks_per_step": 0, "paged_steps_per_row": 0,
-            "paged_steps_paid": 0,
-            # the pool's bytes a token over all KV layers and the
-            # width of one of its cells; both land with the pool
-            "kv_bytes_per_token": 0, "kv_dtype_bytes": 0,
-            # host-to-device transfers made for the block-table
-            # operand, summed over paged dispatches: one a dispatch,
-            # whatever the number of paged layers (ISSUE 28)
-            "table_uploads": 0,
+            "preempted": 0, "paged_admit_deferred": 0,
+            "qos_preempted": 0,
             # the resident weights: bytes of ``_params``, and bytes of
             # the masters that construction cast to the compute dtype
             # (0 for a net that was already resident there)
@@ -1347,14 +1166,6 @@ class DecodeEngine:
                 leaf.size * leaf.dtype.itemsize      # (shapes count too)
                 for leaf in jax.tree.leaves(self._params)),
             "param_bytes_cast": cast_bytes,
-            # by layer kind (its window): the kind's part of
-            # ``paged_blocks_live`` and, summed over rounds, the blocks
-            # its live contexts span and hold (``_count_kv_held``)
-            **{f"{name}_w{k.window}": 0
-               for k in self._kinds
-               for name in ("paged_blocks_live",
-                            "prefill_paged_blocks_live",
-                            "kv_blocks_spanned", "kv_blocks_held")},
             # what the jitted programs count themselves and return
             # with the tokens (nn/layers/hybrid.py ``counters``):
             # routed (row, pick) pairs, those on held experts, held
@@ -1365,26 +1176,7 @@ class DecodeEngine:
             **{prefix + name: 0 for prefix in ("", "prefill_")
                for name in ("moe_picks", "moe_picks_held",
                             "moe_experts_touched", "moe_layer_steps",
-                            "moe_load_max", "ssm_state_rows",
-                            # an ``eva`` layer's (ISSUE 41): summary
-                            # entries the programs wrote (layers x
-                            # chunks completed), and what ONE layer's
-                            # attention reads for a dispatch's tables,
-                            # a decode dispatch step by step: exact
-                            # keys of the aligned window, and summaries
-                            "eva_summaries_written",
-                            "eva_window_entries_read",
-                            "eva_summary_entries_read",
-                            # ... and the (query, entry) pairs it
-                            # scores: a step's one query an entry, an
-                            # admission chunk's every query that may
-                            # read it
-                            "eva_window_pairs_scored",
-                            "eva_summary_pairs_scored")},
-            # blocks of the aligned window's kind allocated, and those
-            # released because their row crossed the window's end
-            "eva_window_blocks_allocated": 0,
-            "eva_window_blocks_released": 0,
+                            "moe_load_max", "ssm_state_rows")},
             # KV transfer plane (ISSUE 14): cross-replica prefix
             # shipping counters (nonzero only when export/import run)
             "kv_exports": 0, "kv_exported_tokens": 0,
@@ -1398,42 +1190,10 @@ class DecodeEngine:
             "kv_tier_host_bytes": 0, "kv_tier_disk_bytes": 0,
             "kv_tier_spill_skipped": 0, "kv_tier_reload_declined": 0,
             "kv_tier_reload_faults": 0, "kv_tier_exports": 0,
-        }
+        })
         for key in self.FAILURE_KEYS:
             self.stats[key] = 0
         self._build_jits()
-
-    @staticmethod
-    def _eva_kinds(eva_items, attn_items, block_tokens: int
-                   ) -> List[_KvKind]:
-        """The two kinds of block an ``eva`` layer holds, widest first:
-        its summaries (one entry an ``eva_chunk`` of tokens, so a block
-        covers ``block_tokens`` chunks; "window" the longest context,
-        ``stream_max_t``: never released while the row lives) and its
-        window's exact keys (aligned). Every ``eva`` layer of a net has
-        both, under one table each a slot."""
-        names = [name for name, _ in eva_items]
-        bean = eva_items[0][1]
-        sizes = {(b.eva_window, b.eva_chunk, b.stream_max_t, b.n_heads,
-                  b.head_dim) for _, b in eva_items}
-        if attn_items or len(sizes) > 1:
-            raise ValueError(
-                f"layers {names} hold a window's keys and chunk "
-                "summaries (serving_state 'eva'); the engine serves "
-                "them where every paged layer is one of them and all "
-                f"agree on their sizes (got {sorted(sizes)}, beside "
-                f"attention layers {[n for n, _ in attn_items]})")
-        if bean.stream_max_t <= bean.eva_window:
-            raise ValueError(
-                f"stream_max_t {bean.stream_max_t} (the longest "
-                f"context) must pass eva_window {bean.eva_window}")
-        width = bean.n_heads * bean.head_dim
-        return [
-            _KvKind(bean.stream_max_t, list(names), token_width=width,
-                    span=int(block_tokens) * bean.eva_chunk,
-                    leaves=("sk", "sv"), operands=("stable", "sbase")),
-            _KvKind(bean.eva_window, list(names), token_width=width,
-                    aligned=True)]
 
     def _adopt_weights(self) -> int:
         """Make the net's weights resident at its compute dtype, once:
@@ -1491,7 +1251,7 @@ class DecodeEngine:
     def _build_jits(self):
         forward, chunk = self._forward, self.decode_chunk
         ids_in = self._ids_in
-        rings = tuple(k.ring for k in self._kinds)
+        kv = self.kv
 
         def encode(tok):
             # one position a row for the net's first layer: the ids
@@ -1505,24 +1265,12 @@ class DecodeEngine:
         def seen(pool, tabs, filled=None):
             # the per-layer state the forward pass sees: every paged
             # layer's pool leaves beside its KIND's block tables, ONE
-            # set a kind and dispatch (``tabs``: ``_paged_tables``'
-            # packed operand, unpacked here). ``filled`` is a scan's
-            # carried copy of the only table operand a step advances
-            shared = {}
-            with scope("tables"):
-                unpacked = _unpack_tables(tabs, rings)
-            for kind, ops in zip(self._kinds, unpacked):
-                if filled is not None:
-                    ops["filled"] = filled
-                if kind.operands != ("table", "base"):
-                    # a second kind of the same layers: its table rides
-                    # beside the first's, under its own names
-                    ops = dict(zip(kind.operands,
-                                   (ops["table"], ops["base"])))
-                for name in kind.layers:
-                    shared.setdefault(name, {}).update(ops)
-            return {name: dict(st, **shared[name]) if "pk" in st else st
-                    for name, st in pool.items()}
+            # set a kind and dispatch (``tabs``: ``KvMemory.pack``'s
+            # operand, unpacked here). ``filled`` is a scan's carried
+            # copy of the only table operand a step advances
+            shared = kv.operands(tabs, filled)
+            return {name: dict(st, **shared[name]) if name in shared
+                    else st for name, st in pool.items()}
 
         def kept(rnn):
             # a pass's new state parted into what the engine carries
@@ -1530,11 +1278,11 @@ class DecodeEngine:
             # the advanced ``filled``: every paged layer added the
             # same lengths, so the first one's stands for all and no
             # program hands a layer's tables back
-            filled = next(st["filled"] for st in rnn.values()
-                          if "pk" in st)
-            return {name: ({leaf: st[leaf] for leaf in _POOL_LEAVES
+            filled = next(st["filled"] for name, st in rnn.items()
+                          if name in kv.layers)
+            return {name: ({leaf: st[leaf] for leaf in kv.leaves
                             if leaf in st}
-                           if "pk" in st else st)
+                           if name in kv.layers else st)
                     for name, st in rnn.items()}, filled
 
         # (a phase is the first scope of a program's body: what the
@@ -1761,7 +1509,7 @@ class DecodeEngine:
                 return new_pool, tabs, bonus, emitted, acc
 
             self._verify_jit = self._jit(verify, donate_argnums=(2,))
-        bt, s_ring = self.block_tokens, self._ring_slots
+        bt, s_ring = self.block_tokens, kv.kinds[0].ring
 
         @scope("admit")
         @scope("attn/cache")
@@ -2193,7 +1941,7 @@ class DecodeEngine:
         prefix-cache lease and free the reserved slot."""
         if pending.hit is not None and self.prefix_cache is not None:
             self.prefix_cache.release(pending.hit)
-        self._free_table(pending.tab)
+        self.kv.free(pending.tab)
         pending.tab = None
         self._reserved.discard(pending.slot)
         self._pending.remove(pending)
@@ -2213,7 +1961,7 @@ class DecodeEngine:
 
     def _release_slot(self, slot: int) -> None:
         """What eviction and preemption share."""
-        self._free_table(self._kv_tabs[slot])
+        self.kv.free(self._kv_tabs[slot])
         self._kv_tabs[slot] = None
         self._slots[slot] = None
         self._temps[slot] = 0.0
@@ -2221,30 +1969,16 @@ class DecodeEngine:
         if self.spec is not None:
             self.spec.drop(slot)
 
-    # -- paged block-pool plumbing (ISSUE 6) ---------------------------
-    def _release_block(self, bid: int,
-                       kind: Optional[_KvKind] = None) -> None:
-        """Drop one reference to a block of ``kind``'s pool (the
-        widest's, a one-kind net's only one, where none is named); a
-        block whose LAST reference drops is returned to the free list —
-        scrubbed first if the paranoid sweep flagged it (never scrubbed
-        while an innocent sharer still reads it; the sweep runs for
-        one-kind nets only)."""
-        pool = (kind or self._kinds[0]).pool
-        if pool.deref(bid):
-            if bid in pool.poisoned and self._pool is not None:
-                self._pool = pool.scrub_block_device(self._pool, bid)
+    # -- the KV memory's device pool and its relief (ISSUE 6) ----------
+    @property
+    def _pool(self):    # (``kv.pool``: every program's donated operand)
+        return self.kv.pool
 
-    def _free_table(self, tab: Optional[KindTables]) -> None:
-        if tab is None:
-            return
-        for kind, t in zip(self._kinds, tab.kinds):
-            for bid in list(t.blocks.values()):
-                self._release_block(bid, kind)
-            t.blocks.clear()
+    @_pool.setter
+    def _pool(self, tree) -> None:
+        self.kv.pool = tree
 
-    def _paged_reserve(self, n: int, protect=(),
-                       kind: Optional[_KvKind] = None) -> bool:
+    def _paged_reserve(self, n: int, protect=(), kind=None) -> bool:
         """Make ``n`` blocks of ``kind``'s pool allocatable: first
         evict LRU prefix-trie
         entries (references only — shared blocks stay resident), then
@@ -2252,7 +1986,7 @@ class DecodeEngine:
         requests (greedy re-admissions regenerate identical ids, so
         preemption is invisible to results — the continuous-batching
         analogue of vLLM's recompute preemption)."""
-        pool = (kind or self._kinds[0]).pool
+        pool = (kind or self.kv.kinds[0]).pool
         while pool.free_blocks < n and self.prefix_cache is not None:
             if not self.prefix_cache.evict_one():
                 break
@@ -2300,97 +2034,6 @@ class DecodeEngine:
             clock.new_attempt(self._clock(), "preempted")
         self._requeue.append((self._round + 1, state.request))
 
-    def _ensure_tab(self, tab: KindTables, n_tokens: int,
-                    protect=(), rid: Optional[int] = None) -> bool:
-        """Make ``tab`` writable for the next ``n_tokens`` appends:
-        copy-on-write the partial tail block if the trie or another
-        slot still references it (the ONLY device copy sharing ever
-        costs — one block, not one row), and allocate the fresh blocks
-        the appends will cross into. False = the pool could not be
-        relieved (caller defers or preempts).
-
-        Invariant the sizing math rests on: no single append exceeds
-        the window (prompts are validated <= window at submit, chunk
-        widths are window-clamped), so one append's new blocks always
-        fit the ``slot_worst`` floor enforced on ``kv_blocks`` at
-        construction — after evicting/preempting everything else a
-        lone admission can always proceed (no defer livelock) — and
-        one dispatch can never wrap the ring onto itself."""
-        return all(self._ensure_kind(kind, t, n_tokens, protect, rid)
-                   for kind, t in zip(self._kinds, tab.kinds))
-
-    def _ensure_kind(self, kind: _KvKind, tab: BlockTable,
-                     n_tokens: int, protect, rid) -> bool:
-        """:meth:`_ensure_tab` for one kind's table and pool (a block
-        is shared, and so copied on write, in a one-kind net only: the
-        trie is refused to any other)."""
-        pool = kind.pool
-        tail = tab.tail_block() if n_tokens > 0 else None
-        cow = tail is not None and pool.refcount(tail[1]) > 1
-        need = len(tab.new_logical_blocks(n_tokens)) + (1 if cow else 0)
-        if need and not self._paged_reserve(need, protect, kind):
-            return False
-        if cow:
-            g, src = tab.tail_block()
-            dst = pool.alloc()
-            with self._span("serving.cow_copy", rid=rid, src=src,
-                            dst=dst):
-                self._pool = pool.copy_block_device(self._pool, src,
-                                                    dst)
-            tab.blocks[g] = dst
-            self._release_block(src, kind)
-        for g in tab.new_logical_blocks(n_tokens):
-            old = g - kind.ring
-            if old in tab.blocks:   # safety: expired ring predecessor
-                self._release_block(tab.blocks.pop(old), kind)
-            bid = pool.alloc()
-            if bid is None:
-                raise AssertionError("reserved allocation failed")
-            tab.blocks[g] = bid
-            if kind.aligned:
-                self.stats["eva_window_blocks_allocated"] += 1
-        return True
-
-    def _free_expired_blocks(self, tab: KindTables) -> None:
-        """Release, kind by kind, the blocks that slid entirely out of
-        the kind's window, each to its kind's pool (length is monotone
-        within a round — the verify rewind lands before this runs — so
-        a released block can never swing back into reach)."""
-        for kind, t in zip(self._kinds, tab.kinds):
-            # (an ALIGNED window's lower edge is the last multiple of
-            # the window the row has reached: everything below it goes
-            # at once, the round after the row crossed it)
-            edge = (t.length // kind.window * kind.window if kind.aligned
-                    else t.length - kind.window)
-            if edge < kind.span:
-                continue    # the context has not left the window yet
-            for g in itertools.takewhile(
-                    lambda g: (g + 1) * kind.span <= edge,
-                    sorted(t.blocks)):
-                self._release_block(t.blocks.pop(g), kind)
-                if kind.aligned:
-                    self.stats["eva_window_blocks_released"] += 1
-
-    def _count_kv_held(self, active: List[int]) -> None:
-        """By kind, summed over rounds as ``occupancy_sum`` is:
-        ``kv_blocks_spanned_w<window>``, the blocks the live contexts
-        span, and ``kv_blocks_held_w<window>``, those of them the
-        slots' tables still map (blocks reserved ahead of the context
-        are neither). The difference is what the kind's window
-        released."""
-        for k, kind in enumerate(self._kinds):
-            spanned = held = 0
-            for slot in active:
-                t = self._kv_tabs[slot].kinds[k]
-                span = -(-t.length // kind.span)
-                ahead = span
-                while ahead in t.blocks:
-                    ahead += 1
-                spanned += span
-                held += len(t.blocks) - (ahead - span)
-            self.stats[f"kv_blocks_spanned_w{kind.window}"] += spanned
-            self.stats[f"kv_blocks_held_w{kind.window}"] += held
-
     def _split_row(self, rnn1):
         """A dense B=1 prefill state split into (its attention layers'
         caches, its slot-state layers' rows)."""
@@ -2400,26 +2043,20 @@ class DecodeEngine:
 
     def _write_row(self, rnn1, length: int,
                    slot: Optional[int] = None) -> Optional[KindTables]:
-        """A cold admission's (or a restore's) one whole-row write: a
-        fresh BlockTable covering the last ``min(length, wmax)``
-        absolute positions (what a dense B=1 prefill row holds), the
-        row's keys and values scattered into its blocks, its recurrent
-        state into the slot's row (a trie entry being re-primed has no
-        slot, and no such state). None when the pool cannot be
-        relieved."""
+        """A cold admission's (or a restore's) one whole-row write:
+        fresh tables over what a dense B=1 prefill row holds
+        (``KvMemory.cover``), the row's keys and values scattered into
+        their blocks, its recurrent state into the slot's row (a trie
+        entry being re-primed has no slot, and no such state). None
+        when the pool cannot be relieved."""
         self._one_kind_only("writing a dense prefill row into block "
                             "tables (restore, a trie entry's re-priming)")
         self._ensure_paged_pool(rnn1)
-        bt = self.block_tokens
-        floor = max(0, length - self._wmax)
-        gs = list(range(floor // bt, (length - 1) // bt + 1))
-        if not self._paged_reserve(len(gs)):
+        tab = self.kv.cover(length)
+        if tab is None:
             return None
-        tab = BlockTable(bt, length=length, floor=floor)
-        for g in gs:
-            tab.blocks[g] = self.block_pool.alloc()
         kv, row = self._split_row(rnn1)
-        table_row, _ = tab.arrays(self._ring_slots)
+        table_row, _ = tab.kinds[0].arrays(self.kv.kinds[0].ring)
         self._pool = self._scatter_jit(
             self._pool, kv, jnp.asarray(table_row),
             jnp.asarray(tab.length, jnp.int32))
@@ -2427,7 +2064,7 @@ class DecodeEngine:
             with self._span("serving.state_admit", slot=slot):
                 self._slot_state = self._state_admit_jit(
                     self._slot_state, row, jnp.asarray(slot, jnp.int32))
-        return KindTables([tab])
+        return tab
 
     def _live_operand(self):
         """``(live,)`` for the decode program of a net some layer of
@@ -2460,123 +2097,6 @@ class DecodeEngine:
             if prefill:
                 self.stats["prefill_" + name] += int(v)
 
-    def _paged_tables(self, tabs, chunk: int = 1,
-                      tokens: Optional[int] = None):
-        """The block-table operand of a paged dispatch of ``chunk``
-        query positions a row: each row's ring-projected block table,
-        its floor and its length (None rows — idle slots — map
-        nothing; their writes drop and their keys all mask), of every
-        layer kind, packed into ONE int32 array (``_unpack_tables``)
-        and uploaded ONCE, whatever the number of paged layers and of
-        kinds. It enters the program as
-        an argument of its own beside the donated pool: every paged
-        layer reads the same arrays, and nothing about them needs
-        donating. Under tp it COMMITS replicated
-        (``TPContext.replicate``), so that a plain round's operand
-        and a spec round's chained verify output share one decode
-        lowering."""
-        rings = [k.ring for k in self._kinds]
-        packed = np.full((len(tabs), 2 * sum(rings) + len(rings) + 1),
-                         -1, np.int32)
-        packed[:, 2 * sum(rings):] = 0           # floors, filled
-        for k, (kind, rows) in enumerate(zip(
-                self._kinds, _unpack_tables(packed, rings))):
-            for i, tab in enumerate(tabs):
-                if tab is None:
-                    continue
-                t = tab.kinds[k]
-                rows["table"][i], rows["base"][i] = t.arrays(kind.ring)
-                rows["floor"][i] = t.floor
-                rows["filled"][i] = t.length
-            if not self._eva:
-                self._count_paged_walk(kind, chunk=chunk, **rows)
-        if self._eva:
-            self._count_eva_reads(tabs, chunk, tokens)
-        self.stats["table_uploads"] += 1
-        if self.tp_ctx is not None:
-            return self.tp_ctx.replicate(packed)
-        return jnp.asarray(packed)
-
-    def _count_paged_walk(self, kind: _KvKind, table, base, floor,
-                          filled, chunk: int) -> None:
-        """``paged_blocks_live`` / ``paged_blocks_walked``: what ONE
-        layer's kernel call of ``kind`` does with the kind's tables
-        (``paged_walk_counts``; the gather program reads the same live
-        blocks), and ``paged_steps_paid``, the grid steps and loop
-        trips it pays for them (``paged_steps_paid``), each summed over
-        the kinds (one layer's call of each); where the kinds are
-        several, ``paged_blocks_live`` also by kind, under
-        ``paged_blocks_live_w<window>`` (and the part of that which
-        admissions' chunks counted under ``prefill_paged_...``), for a
-        reader that weighs a kind by its layers. The geometry is the
-        kind's first pool leaf's, local to a tp shard;
-        ``paged_blocks_per_step`` and ``paged_steps_per_row`` are the
-        widest kind's."""
-        pk = self._pool[kind.layers[0]]["pk"]
-        bt = self.block_tokens
-        ntab = _paged_table_entries(kind.ring, kind.window, bt, chunk)
-        per_step = _paged_blocks_per_step(
-            bt, pk.shape[2] // self.tp, pk.shape[3], pk.dtype, ntab,
-            kind.group, chunk)
-        if chunk == 1 and kind is self._kinds[0]:
-            self.stats["paged_blocks_per_step"] = per_step
-            self.stats["paged_steps_per_row"] = -(-ntab // per_step)
-        geometry = dict(block_tokens=bt, window=kind.window,
-                        blocks_per_step=per_step, chunk=chunk)
-        live, walked, steps = paged_walk_stats(table, base, floor,
-                                               filled, **geometry)
-        self.stats["paged_blocks_live"] += live
-        self.stats["paged_blocks_walked"] += walked
-        self.stats["paged_steps_paid"] += steps
-        # (a decode dispatch is one position a row; an admission's
-        # chunk is wider, and counts under ``prefill_`` as well)
-        name = f"paged_blocks_live_w{kind.window}"
-        self.stats[name] += live
-        if chunk > 1:
-            self.stats["prefill_" + name] += live
-
-    def _count_eva_reads(self, tabs, chunk: int,
-                         tokens: Optional[int] = None) -> None:
-        """What ONE ``eva`` layer's attention does for a dispatch's
-        tables: ``eva_window_entries_read``, the exact keys from each
-        row's aligned floor up, ``eva_summary_entries_read``, the
-        summaries of the windows before it, and the (query, entry)
-        pairs it scores of each (``eva_*_pairs_scored``). A decode
-        dispatch (``chunk`` 1) is ``decode_chunk`` steps, each a
-        position on, a query a row. An admission's chunk of ``tokens``
-        queries reads each entry once (what its last query sees) and
-        scores every pair under the causal edge; it counts under
-        ``prefill_`` as well."""
-        summary, window = self._kinds
-        w, per = window.window, window.window // (
-            summary.span // self.block_tokens)
-        counts = dict.fromkeys(("window_entries_read",
-                                "summary_entries_read",
-                                "window_pairs_scored",
-                                "summary_pairs_scored"), 0)
-        for tab in tabs:
-            if tab is None:
-                continue
-            if chunk > 1:   # (a chunk never straddles a window's end)
-                n = chunk if tokens is None else tokens
-                at, seen = tab.length % w, tab.length // w * per
-                counts["window_entries_read"] += at + n
-                counts["summary_entries_read"] += seen
-                counts["window_pairs_scored"] += n * at + n * (n + 1) // 2
-                counts["summary_pairs_scored"] += n * seen
-                continue
-            for j in range(self.decode_chunk):   # a position on a step
-                exact = (tab.length + j) % w + 1
-                seen = (tab.length + j) // w * per
-                counts["window_entries_read"] += exact
-                counts["summary_entries_read"] += seen
-                counts["window_pairs_scored"] += exact
-                counts["summary_pairs_scored"] += seen
-        for name, n in counts.items():
-            self.stats["eva_" + name] += n
-            if chunk > 1:
-                self.stats["prefill_eva_" + name] += n
-
     def _strip_pool(self, rnn):
         """What a program hands back (pool leaves and, for a net
         with slot-state layers, the state rows that rode the same
@@ -2590,21 +2110,10 @@ class DecodeEngine:
                 if name not in self._state_layers}
 
     def _paged_stats_refresh(self) -> None:
-        pools = [k.pool for k in self._kinds]
-        self.stats["blocks_free"] = sum(p.free_blocks for p in pools)
-        self.stats["blocks_used"] = sum(p.used_blocks for p in pools)
-        pool = self.block_pool     # (only one-kind nets share blocks)
-        self.stats["cow_copies"] = pool.stats["cow_copies"]
-        self.stats["prefix_blocks_spliced"] = pool.stats["spliced"]
-        tabs = [t for t in list(self._kv_tabs)
-                + [p.tab for p in self._pending] if t is not None]
-        # (a trie entry's table is one of the widest kind's pool)
-        held = (list(self.prefix_cache._payloads.values())
-                if self.prefix_cache is not None else [])
-        self.stats["frag_tokens"] = sum(
-            p.fragmentation_tokens(
-                [t.kinds[k] for t in tabs] + (held if k == 0 else []))
-            for k, p in enumerate(pools))
+        self.kv.refresh_stats(
+            admitting=[p.tab for p in self._pending],
+            leased=(self.prefix_cache._payloads.values()
+                    if self.prefix_cache is not None else ()))
         if self.kv_tier is not None:
             t = self.kv_tier.stats
             self.stats["kv_tier_spills"] = t["spills"]
@@ -2619,10 +2128,10 @@ class DecodeEngine:
     def _one_kind_only(self, what: str) -> None:
         """Refuse, by its name, what holds one kind of KV block to a
         net that has several."""
-        if len(self._kinds) > 1:
+        if len(self.kv.kinds) > 1:
             raise NotImplementedError(
                 f"{what} is not supported for this net: its attention "
-                f"layers have windows {[k.window for k in self._kinds]}"
+                f"layers have windows {[k.window for k in self.kv.kinds]}"
                 ", and the transfer, tier, snapshot and dense-row "
                 "formats hold one kind of KV block")
 
@@ -2841,25 +2350,9 @@ class DecodeEngine:
                 payload = self.prefix_cache.payload(hit.row)
                 if hit.matched > payload.floor:
                     # ZERO-COPY warm hit: reference the entry's blocks
-                    # up to the matched length — no gather, no row
-                    # copy; a stored entry rewinds exactly to any
-                    # shorter prefix of itself by referencing only
-                    # blocks below `matched` (suffix chunks append
-                    # through the table, CoW-ing the boundary block on
-                    # first write if it is still shared)
+                    # up to the matched length (``KvMemory.splice``)
                     matched = hit.matched
-                    bt = self.block_tokens
-                    mine = BlockTable(bt, length=matched,
-                                      floor=payload.floor)
-                    tab = KindTables([mine])
-                    spliced = 0
-                    for g, bid in payload.blocks.items():
-                        if (g * bt < matched
-                                and (g + 1) * bt > payload.floor):
-                            mine.blocks[g] = bid
-                            self.block_pool.ref(bid)
-                            spliced += 1
-                    self.block_pool.stats["spliced"] += spliced
+                    tab, spliced = self.kv.splice(payload, matched)
                     self.stats["prefill_tokens_skipped"] += matched
                     with self._span("serving.prefix_splice",
                                     rid=request.id, row=hit.row,
@@ -2872,14 +2365,13 @@ class DecodeEngine:
                 else:
                     self.prefix_cache.release(hit)
                     hit = None
-        if tab is None and len(self._kinds) > 1:
+        if tab is None and len(self.kv.kinds) > 1:
             # several kinds: a cold admission streams through the
             # block tables from its first token (a dense row would hold
             # every layer's keys for the whole prompt, and take no
             # band), each chunk's programs banding a layer by its window
             self._ensure_paged_pool()
-            tab = KindTables(BlockTable(kind.span)
-                             for kind in self._kinds)
+            tab = self.kv.new_table()
         pending = _Pending(request, slot, None, None, 0, matched, hit,
                            tab=tab)
         if self.prefill_chunk:
@@ -2903,7 +2395,7 @@ class DecodeEngine:
         if pending.hit is not None and self.prefix_cache is not None:
             self.prefix_cache.release(pending.hit)
             pending.hit = None
-        self._free_table(pending.tab)
+        self.kv.free(pending.tab)
         pending.tab = None
         self._reserved.discard(pending.slot)
         if pending in self._pending:
@@ -2937,11 +2429,11 @@ class DecodeEngine:
             # freshly allocated ones) — no dense scratch row ever
             # materializes, which is what makes the warm path
             # zero-whole-row-copy
-            if not self._ensure_tab(pending.tab, len(seg),
+            if not self.kv.ensure(pending.tab, len(seg),
                                     rid=req.id):
                 return False
             carried = self._pool
-            tables = self._paged_tables([pending.tab], chunk=width,
+            tables = self.kv.pack([pending.tab], chunk=width,
                                         tokens=len(seg))
         else:
             carried, tables = pending.rnn, None
@@ -2972,7 +2464,7 @@ class DecodeEngine:
             pending.tab.length += len(seg)
             # (a prompt longer than a kind's window leaves blocks
             # behind it chunk by chunk)
-            self._free_expired_blocks(pending.tab)
+            self.kv.expire(pending.tab)
         else:
             pending.rnn = rnn
         pending.tok = tok
@@ -2983,58 +2475,24 @@ class DecodeEngine:
         return True
 
     def _ensure_paged_pool(self, rnn1=None) -> None:
-        """Create the device block pool lazily from the first dense
-        B=1 streaming state, which says each layer's heads (shapes per
-        layer: ``[its kind's blocks, block_tokens, H, dh]``); where no
-        dense row is ever made (several kinds: every admission is
-        paged), from the shapes a smallest cold prefill WOULD give,
-        traced and not run.
-
-        The KV leaves are made at the dtype the attention layers
-        compute keys and values in: the net's compute dtype where it
-        has one, else the dense row's (the master dtype). A pool cell
-        then holds the number the layer made and no zero bits behind
-        it, and every program that takes the pool hands it back at the
-        dtype it came in with (``_forward_fn``). The slot-state rows (a
-        recurrent state is accumulated into, not copied) stay at the
-        dense row's dtype."""
+        """Create the device block pool (``KvMemory.make_pool``) lazily
+        from the first dense B=1 streaming state, which says each
+        layer's heads; where no dense row is ever made (several kinds:
+        every admission is paged), from the shapes a smallest cold
+        prefill WOULD give, traced and not run. Every program that takes
+        the pool hands it back at the dtype it came in with
+        (``_forward_fn``). The slot-state rows (a recurrent state is
+        accumulated into, not copied) stay at the dense row's dtype."""
         if self._pool is not None:
             return
-        bt = self.block_tokens
         if rnn1 is None:
             x, mask = self._encode_prompt([0], self.scheduler.bucket_of(1))
             one = jnp.ones((1,), jnp.float32)
             _, rnn1, _ = jax.eval_shape(
                 self._prefill_jit, self._params, self._state, x, mask,
                 one, one.astype(jnp.int32), self._key)
-        computed = self.net._compute_dtype
-
-        def held(a):
-            return a.dtype if computed is None else computed
-
-        def make(name, st):
-            k = st["k"]                          # [1, H, W, dh]
-            out = {}
-            # (a layer of two kinds holds a pair of leaves of each)
-            for kind in self._kinds:
-                if name in kind.layers:
-                    shape = (kind.pool.n_blocks, bt, k.shape[1],
-                             k.shape[3])
-                    out.update({kind.leaves[0]: jnp.zeros(shape, held(k)),
-                                kind.leaves[1]: jnp.zeros(
-                                    shape, held(st["v"]))})
-            return out
-
         kv, row = self._split_row(rnn1)
-        self._pool = self._place(
-            {name: make(name, st) for name, st in kv.items()})
-        leaves = jax.tree.leaves(self._pool)
-        # what a token costs the pool over all KV layers, and the width
-        # of a cell (a net of several kinds: its first kind's leaves)
-        self.stats["kv_bytes_per_token"] = sum(
-            int(np.prod(leaf.shape[2:])) * leaf.dtype.itemsize
-            for leaf in leaves)
-        self.stats["kv_dtype_bytes"] = leaves[0].dtype.itemsize
+        self.kv.make_pool(kv, self.net._compute_dtype)
         self._slot_state = jax.tree_util.tree_map(
             lambda a: jnp.zeros((self.n_slots,) + a.shape[1:], a.dtype),
             row)
@@ -3597,14 +3055,14 @@ class DecodeEngine:
             n_tok = max(fuse_k, 1) * self.decode_chunk
             if spec_round:
                 n_tok += len(drafts.get(slot, ())) + 1
-            if self._ensure_tab(
+            if self.kv.ensure(
                     self._kv_tabs[slot], n_tok,
                     protect=ensured | {slot},
                     rid=self._slots[slot].request.id):
                 ensured.add(slot)
             else:
                 self._preempt_slot(slot)
-        # preemption (by _ensure_tab or explicit) may have emptied
+        # preemption (by the reserve or explicit) may have emptied
         # slots mid-list — rebuild the round's view
         active = [s for s in active if self._slots[s] is not None]
         if drafts is not None:
@@ -3722,8 +3180,8 @@ class DecodeEngine:
                 tab = self._kv_tabs[slot]
                 tab.length += inf.decode_tokens + (
                     int(v_n[slot]) if v_n is not None else 0)
-                self._free_expired_blocks(tab)
-            self._count_kv_held(active)
+                self.kv.expire(tab)
+            self.kv.count_held(active)
         if self.paranoid:
             active = self._quarantine(active)
         emitted = 0
@@ -3944,7 +3402,7 @@ class DecodeEngine:
                 # the round's ONE upload of the block tables, shared
                 # by every layer and by the verify and decode
                 # dispatches
-                tables = self._paged_tables(self._kv_tabs)
+                tables = self.kv.pack(self._kv_tabs)
                 pool_op = self._pool
                 if self._slot_state:
                     # the slot-state layers' rows ride the dispatch
@@ -4274,7 +3732,7 @@ class DecodeEngine:
             return    # pool too small for this entry: skip —
             #           the cache is a cache, not state
         self.prefix_cache.insert_blocks(prefix, tab.kinds[0])
-        self._free_table(tab)
+        self.kv.free(tab)
 
     def _rebuild_slot(self, slot: int, request: Request,
                       tokens: List[int], prefix_reused: int,

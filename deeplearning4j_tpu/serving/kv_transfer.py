@@ -315,10 +315,10 @@ def import_prefix(engine, payload: bytes) -> Dict[str, Any]:
         raise KVTransferError(
             f"prefix ids {bad[:4]} outside vocab [0, {engine.vocab})")
     length, floor = int(header["length"]), int(header["floor"])
-    if length - floor > engine._wmax:
+    if length - floor > engine.kv.wmax:
         raise KVTransferError(
             f"prefix spans {length - floor} tokens, wider than the "
-            f"receiver's cache window ({engine._wmax})")
+            f"receiver's cache window ({engine.kv.wmax})")
     if engine._pool is None:
         # a freshly booted receiver has no device pool yet (it
         # allocates lazily at first admission): establish it through
@@ -396,7 +396,7 @@ def import_prefix(engine, payload: bytes) -> Dict[str, Any]:
         engine._pool = engine._kv_import_jit(
             engine._pool, new, jnp.asarray(ids))
     ok = engine.prefix_cache.insert_blocks(tokens, tab)
-    engine._free_table(KindTables([tab]))
+    engine.kv.free(KindTables([tab]))
     if not ok:
         engine.stats["kv_import_declined"] = engine.stats.get(
             "kv_import_declined", 0) + 1

@@ -148,16 +148,16 @@ def afmoe(cfg, mix, model, which, batch):
     dep.pop("why")
     eng = DecodeEngine(net, seed=1, **dep)
     print("kinds", [(k.window, k.layers, k.ring, k.pool.n_blocks)
-                    for k in eng._kinds])
+                    for k in eng.kv.kinds])
     pool = {}
-    for k in eng._kinds:
+    for k in eng.kv.kinds:
         shp = (k.pool.n_blocks, eng.block_tokens,
                cfg["num_key_value_heads"], cfg["head_dim"])
         for name in k.layers:
             pool[name] = {"pk": S(shp, dt), "pv": S(shp, dt)}
     print("pool GiB", sum(int(np.prod(l.shape)) * 2
                           for l in jax.tree.leaves(pool)) / 2**30)
-    rings = [k.ring for k in eng._kinds]
+    rings = [k.ring for k in eng.kv.kinds]
     width = 2 * sum(rings) + len(rings) + 1
     B, c = eng.n_slots, eng.prefill_chunk
     which = which or ["decode", "chunk"]
@@ -198,9 +198,9 @@ def evabyte(cfg, mix, model, which, batch):
     dep.pop("why")
     eng = DecodeEngine(net, seed=1, **dep)
     print("kinds", [(k.window, k.span, k.leaves, k.ring, k.slot_worst,
-                     k.pool.n_blocks) for k in eng._kinds])
+                     k.pool.n_blocks) for k in eng.kv.kinds])
     pool = {}
-    for k in eng._kinds:
+    for k in eng.kv.kinds:
         shp = (k.pool.n_blocks, eng.block_tokens,
                cfg["num_attention_heads"], W.head_dim(cfg))
         for name in k.layers:
@@ -208,7 +208,7 @@ def evabyte(cfg, mix, model, which, batch):
                 {leaf: S(shp, dt) for leaf in k.leaves})
     print("pool GiB", sum(int(np.prod(l.shape)) * 2
                           for l in jax.tree.leaves(pool)) / 2**30)
-    rings = [k.ring for k in eng._kinds]
+    rings = [k.ring for k in eng.kv.kinds]
     width = 2 * sum(rings) + len(rings) + 1
     B, c = eng.n_slots, eng.prefill_chunk
     which = which or ["decode", "chunk"]
@@ -261,11 +261,11 @@ def cgpt_block(cfg, mix, model, which, batch, pool_dtype=None):
     h = cfg["n_head"]
     shp = (eng.kv_blocks, eng.block_tokens, h, cfg["n_embd"] // h)
     pool = {name: {"pk": S(shp, held), "pv": S(shp, held)}
-            for k in eng._kinds for name in k.layers}
+            for k in eng.kv.kinds for name in k.layers}
     print("pool", held, "GiB", sum(
         int(np.prod(l.shape)) * l.dtype.itemsize
         for l in jax.tree.leaves(pool)) / 2**30)
-    B, ring = eng.n_slots, eng._kinds[0].ring
+    B, ring = eng.n_slots, eng.kv.kinds[0].ring
     report("decode", eng._decode_jit.lower(
         eng._params, eng._state, pool, S((B, 2 * ring + 2), "int32"),
         S((B,), "int32"), S((B,), "float32"), S((B,), "int32"),
@@ -298,7 +298,7 @@ def granite_hybrid(cfg, mix, model, which, batch):
                 for name, st in kv.items()}
         pool.update(jax.tree.map(
             lambda a: S((eng.n_slots,) + a.shape[1:], a.dtype), slots))
-        B, ring = eng.n_slots, eng._kinds[0].ring
+        B, ring = eng.n_slots, eng.kv.kinds[0].ring
         report("decode", eng._decode_jit.lower(
             eng._params, eng._state, pool, S((B, 2 * ring + 2), "int32"),
             S((B,), "int32"), S((B,), "float32"), S((B,), "int32"),

@@ -184,7 +184,7 @@ def test_a_window_kind_holds_its_window_and_serves_what_holding_all_does(
         net):
     reqs = prompts([90, 70, 12], seed=4)
     eng = engine(net, prefill_chunk=16)
-    wide, narrow = eng._kinds
+    wide, narrow = eng.kv.kinds
     assert (wide.window, narrow.window) == (128, WINDOW)
     assert wide.layers == ["3"] and narrow.layers == ["1", "2", "4"]
     most = {0: 0, 1: 0}
@@ -206,8 +206,8 @@ def test_a_window_kind_holds_its_window_and_serves_what_holding_all_does(
                for p in (wide.pool, narrow.pool))    # all given back
     # a run that releases nothing serves the same logits
     keep = engine(net, prefill_chunk=16)
-    keep._free_expired_blocks = lambda tab: None
-    keep._kinds[1].ring = keep._kinds[0].ring    # room for every block
+    keep.kv.expire = lambda tab: None
+    keep.kv.kinds[1].ring = keep.kv.kinds[0].ring    # room for every block
     keep._build_jits()
     assert serve(keep, reqs) == served
     gaps, _ = MODEL.served_gaps(SEED, CFG, list(zip(reqs, served)))
@@ -219,8 +219,8 @@ def test_one_upload_a_dispatch_whatever_the_kinds(net):
     serve(eng, prompts([20, 50]), n_new=5)
     dispatches = (eng.stats["chunks"] + eng.stats["chunks_scheduled"])
     assert eng.stats["table_uploads"] == dispatches
-    rings = [k.ring for k in eng._kinds]
-    tabs = eng._paged_tables([None] * 3)
+    rings = [k.ring for k in eng.kv.kinds]
+    tabs = eng.kv.pack([None] * 3)
     assert tabs.shape == (3, 2 * sum(rings) + len(rings) + 1)
 
 
@@ -247,15 +247,15 @@ def test_each_refused_method_raises_with_its_name(net, method, args):
 def test_a_one_kind_nets_tables_are_one_kinds():
     """The packing of one kind is the parent's ``[B, 2 S + 2]``: table,
     base, floor, filled."""
-    from deeplearning4j_tpu.serving.engine import _unpack_tables
+    from deeplearning4j_tpu.serving.kv_memory import unpack_tables
 
     packed = np.arange(3 * 12).reshape(3, 12)
-    (one,) = _unpack_tables(packed)
+    (one,) = unpack_tables(packed)
     assert (one["table"] == packed[:, :5]).all()
     assert (one["base"] == packed[:, 5:10]).all()
     assert (one["floor"] == packed[:, 10]).all()
     assert (one["filled"] == packed[:, 11]).all()
-    two = _unpack_tables(np.arange(3 * 17).reshape(3, 17), (4, 3))
+    two = unpack_tables(np.arange(3 * 17).reshape(3, 17), (4, 3))
     assert [t["table"].shape[1] for t in two] == [4, 3]
     assert (two[0]["filled"] == two[1]["filled"]).all()
     assert (two[1]["floor"] == np.arange(3 * 17).reshape(3, 17)[:, 15]).all()

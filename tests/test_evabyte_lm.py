@@ -206,12 +206,12 @@ def test_engine_serves_the_reference_at_every_position(net, how,
     done = sum((len(p) + 24 - 1) // C for p in reqs)
     assert eng.stats["eva_summaries_written"] >= layers * done
     assert eng.stats["eva_summary_entries_read"] > 0
-    assert all(k.pool.used_blocks == 0 for k in eng._kinds)
+    assert all(k.pool.used_blocks == 0 for k in eng.kv.kinds)
 
 
 def test_pool_accounting_at_boundaries_cancel_and_preemption(net):
     eng = engine(net, n_slots=2)
-    summary, window = eng._kinds
+    summary, window = eng.kv.kinds
     assert (summary.span, window.span) == (C * C, C)
     assert (summary.leaves, window.aligned) == (("sk", "sv"), True)
     seen = []
@@ -245,11 +245,11 @@ def test_pool_accounting_at_boundaries_cancel_and_preemption(net):
     assert eng.cancel(ids[0])
     eng.step(res)
     assert eng._kv_tabs.count(None) == 1
-    used = [k.pool.used_blocks for k in eng._kinds]
+    used = [k.pool.used_blocks for k in eng.kv.kinds]
     # a preempted row gives back every block of both kinds
     slot = next(i for i, t in enumerate(eng._kv_tabs) if t is not None)
     eng._preempt_slot(slot)
-    assert [k.pool.used_blocks for k in eng._kinds] == [0, 0] != used
+    assert [k.pool.used_blocks for k in eng.kv.kinds] == [0, 0] != used
     while eng.has_work():
         eng.step(res)
     assert res[ids[0]].finish_reason == "cancelled"
@@ -257,7 +257,7 @@ def test_pool_accounting_at_boundaries_cancel_and_preemption(net):
                                 [(reqs[1], list(res[ids[1]].tokens))])
     assert gaps.size == 60 and gaps.max() <= TOL
     assert eng.stats["preempted"] == 1
-    assert all(k.pool.used_blocks == 0 for k in eng._kinds)
+    assert all(k.pool.used_blocks == 0 for k in eng.kv.kinds)
     assert (eng.stats["eva_window_blocks_released"]
             < eng.stats["eva_window_blocks_allocated"])
 

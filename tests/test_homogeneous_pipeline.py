@@ -6,6 +6,10 @@ Same verification pattern as tests/test_pipeline_expert.py for the
 packed trainer: single-device trajectory parity, per-device memory
 accounting (1/(S*T) here), and validation errors."""
 
+import os
+import subprocess
+import sys
+
 import jax
 import numpy as np
 import pytest
@@ -421,7 +425,24 @@ class TestSequenceParallelComposition:
         self._parity({"pp": 2, "sp": 2})
 
     def test_dp_pp_sp_matches_single_device(self):
-        self._parity({"dp": 2, "pp": 2, "sp": 2})
+        """The same ``_parity``, in a process of its own: all 8 virtual
+        devices on three axes rendezvous on ONE thread pool, and inside
+        a worker of the six-worker suite that process aborted (XLA ends
+        a process whose collective has waited 40 s for a participant).
+        The child has a client and a pool to itself, waits for a starved
+        participant as long as its own limit, and cannot take the
+        worker's other tests down with it."""
+        env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=(
+            "--xla_force_host_platform_device_count=8 "
+            "--xla_cpu_collective_call_terminate_timeout_seconds=600"))
+        p = subprocess.run(
+            [sys.executable, "-c",
+             "from tests.test_homogeneous_pipeline import "
+             "TestSequenceParallelComposition as T; "
+             "T()._parity({'dp': 2, 'pp': 2, 'sp': 2})"],
+            env=env, cwd=os.path.dirname(os.path.dirname(__file__)),
+            capture_output=True, text=True, timeout=600)
+        assert p.returncode == 0, p.stderr[-4000:]
 
     def test_pp_sp_tp_matches_single_device(self):
         self._parity({"pp": 2, "sp": 2, "tp": 2}, tp_axis="tp")

@@ -560,7 +560,7 @@ def test_the_engine_counts_the_walk_with_the_kernels_compute_block():
     eng = DecodeEngine(net, seed=5, **dep)
     rid = eng.submit(Request([1, 2, 3, 4, 5, 6, 7], 4))
     assert len(eng.run()[rid].tokens) == 4
-    kind = eng._kinds[0]
+    kind = eng.kv.kinds[0]
     assert kind.group == 1
     pk = eng._pool[kind.layers[0]]["pk"]
     ntab = att._paged_table_entries(kind.ring, kind.window,

@@ -494,7 +494,7 @@ class TestPagedObservability:
         assert isinstance(tab, KindTables) and len(tab.kinds) == 1
         assert set(tab.blocks) == {(0, g) for g in tab.kinds[0].blocks}
         eng.run()
-        (kind,) = eng._kinds
+        (kind,) = eng.kv.kinds
         w = kind.window
         assert eng.stats[f"paged_blocks_live_w{w}"] == eng.stats[
             "paged_blocks_live"] > 0
@@ -615,7 +615,7 @@ class TestSharedTables:
         # counters — no layer's table operands
         out = jax.eval_shape(
             eng._decode_jit, eng._params, eng._state, eng._pool,
-            eng._paged_tables(eng._kv_tabs), eng._toks,
+            eng.kv.pack(eng._kv_tabs), eng._toks,
             jnp.asarray(eng._temps), jnp.asarray(eng._top_ks),
             jax.random.key(0))
         names = {str(getattr(k, "key", k))
@@ -634,13 +634,13 @@ class TestSharedTables:
                            block_tokens=8,
                            spec_draft_len=3)
         uploaded = []
-        upload = eng._paged_tables
+        upload = eng.kv.pack
 
         def uploading(tabs, chunk=1):
             uploaded.append(upload(tabs, chunk))
             return uploaded[-1]
 
-        eng._paged_tables = uploading
+        eng.kv.pack = uploading
         verified = _count_paged_calls(eng, "_verify_jit")
         decoded = _count_paged_calls(eng, "_decode_jit")
         ids = [eng.submit(Request(p, n)) for p, n in CASES]
@@ -672,7 +672,7 @@ class TestSharedTables:
             assert res[rid].tokens == _solo_generate(p, n)
         assert eng.stats["prefix_blocks_spliced"] > 0
         assert warm, "no admission streamed through a block table"
-        assert all(t.shape == (1, 2 * eng._ring_slots + 2)
+        assert all(t.shape == (1, 2 * eng.kv.kinds[0].ring + 2)
                    for t in warm)
         assert (eng.stats["table_uploads"]
                 == eng.stats["chunks"] + len(warm))

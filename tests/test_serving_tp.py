@@ -174,8 +174,8 @@ class TestTpCompileDiscipline:
         assert counts["decode"] == 1, counts
         assert 1 <= counts["verify"] <= 3, counts
         assert eng.stats["table_uploads"] >= eng.stats["chunks"]
-        tables = eng._paged_tables(eng._kv_tabs)
-        assert tables.shape == (2, 2 * eng._ring_slots + 2)
+        tables = eng.kv.pack(eng._kv_tabs)
+        assert tables.shape == (2, 2 * eng.kv.kinds[0].ring + 2)
         assert len(tables.sharding.device_set) == tp
         assert tables.sharding.is_fully_replicated
 
@@ -566,10 +566,10 @@ class TestPagedFlashKernel:
         served = dict(eng.stats)
         assert (served["paged_steps_paid"] * served["paged_blocks_per_step"]
                 > served["paged_blocks_walked"] > 0)
-        eng._paged_tables([None] * 3)
+        eng.kv.pack([None] * 3)
         assert eng.stats["paged_steps_paid"] - served[
             "paged_steps_paid"] == 3
-        eng._paged_tables([None] * 3, chunk=2 * _PAGED_Q_TILE)
+        eng.kv.pack([None] * 3, chunk=2 * _PAGED_Q_TILE)
         assert eng.stats["paged_steps_paid"] - served[
             "paged_steps_paid"] == 3 + 3 * 2
         assert (eng.stats["paged_blocks_walked"]
@@ -582,16 +582,16 @@ class TestPagedFlashKernel:
         eng = DecodeEngine(_net(), n_slots=3, decode_chunk=2, seed=0,
                            block_tokens=8,
                            prefill_chunk=4, prefix_cache_rows=4)
-        bt, tm = eng.block_tokens, eng._wmax
+        bt, tm = eng.block_tokens, eng.kv.wmax
         want = {"live": 0, "walked": 0}
-        inner = eng._paged_tables
+        inner = eng.kv.pack
 
         def spy(tabs, chunk=1):
             out = inner(tabs, chunk)
             pk = next(iter(eng._pool.values()))["pk"]
             per_step = _paged_blocks_per_step(
                 bt, pk.shape[2], pk.shape[3], pk.dtype,
-                min(eng._ring_slots, (tm + chunk - 2) // bt + 2))
+                min(eng.kv.kinds[0].ring, (tm + chunk - 2) // bt + 2))
             for tab in tabs:
                 if tab is None:
                     continue
@@ -606,11 +606,11 @@ class TestPagedFlashKernel:
                     {e // per_step for e in hit})
             return out
 
-        eng._paged_tables = spy
+        eng.kv.pack = spy
         _submit_run(eng)
         assert eng.stats["paged_blocks_per_step"] >= 1
         assert eng.stats["paged_steps_per_row"] == -(-min(
-            eng._ring_slots, (tm - 1) // bt + 2)
+            eng.kv.kinds[0].ring, (tm - 1) // bt + 2)
             // eng.stats["paged_blocks_per_step"])
         assert want["live"] > 0
         assert eng.stats["paged_blocks_live"] == want["live"]
